@@ -20,10 +20,10 @@ Stages:
 
 - ``device_step``  — the fused grouped decide step chained under
   ``lax.scan`` (the pure device plane), slope-fitted across two scan
-  lengths so per-dispatch overhead cancels; run per available
-  ``decide_impl`` (the Pallas megakernel only compiles on TPU —
-  interpret mode is recorded when measured but NEVER gates, same rule
-  as ``bench.py``'s sketch cell).
+  lengths so per-dispatch overhead cancels; run per ``decide_impl`` the
+  selector offers on this backend (``engine.decide.explain_decide_impl``;
+  a megakernel it does not offer is recorded as skipped, with the reason
+  the selector gives — nothing here runs under the interpreter).
 - ``sharded_step`` — the same step through ``make_sharded_decide`` over
   every local device (the v5e-8 scaling arm; on a forced multi-device
   CPU host this measures dispatch overhead, not scaling, and says so).
@@ -124,8 +124,6 @@ def measure_device_step(config, impl: str, iters_lo: int, iters_hi: int,
         step_ms = t_hi / iters_hi
     return {
         "impl": impl,
-        "mode": ("compiled" if impl == "xla"
-                 or jax_backend() == "tpu" else "interpret"),
         "step_ms": round(step_ms, 4),
         "decisions_per_sec": round(N / (step_ms / 1e3)),
     }
@@ -236,7 +234,7 @@ def verdict(doc: dict) -> tuple:
     stages = doc["stages"]
     best_dps = max(
         (s["decisions_per_sec"] for s in stages["device_step"]
-         if s.get("mode") != "interpret"),
+         if not s.get("skipped")),
         default=0,
     )
     shard = stages.get("sharded_step") or {}
@@ -288,15 +286,11 @@ def run(smoke: bool = False, flows: int = TARGET_FLOWS,
     import numpy as np
 
     from benchmarks.step_ablation import hbm_bytes_model
+    from sentinel_tpu.core.compile_cache import ensure_compile_cache
     from sentinel_tpu.engine import EngineConfig
+    from sentinel_tpu.engine.decide import explain_decide_impl
 
-    cache = os.path.join(REPO, ".jax_cache")
-    try:
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass
+    ensure_compile_cache()
 
     if smoke:
         flows, batch = min(flows, 4096), min(batch, 1024)
@@ -330,18 +324,18 @@ def run(smoke: bool = False, flows: int = TARGET_FLOWS,
         "hbm_budget": hbm_bytes_model(config, batch),
     }
 
-    # stage 1: pure device step per impl. The megakernel only earns a
-    # compiled cell on TPU; off-TPU it would run interpret mode, which is
-    # excluded from gates (bench.py's rule) and pointless to time here.
-    impls = ["xla"] + (["pallas"] if backend == "tpu" else [])
+    # stage 1: pure device step per impl the selector offers here. The
+    # megakernel earns a cell only where "auto" could resolve to it;
+    # elsewhere its row says why not, in the selector's words.
+    auto_impl, auto_why = explain_decide_impl("auto")
+    impls = ["xla"] + (["pallas"] if auto_impl == "pallas" else [])
     doc["stages"]["device_step"] = [
         measure_device_step(config, impl, iters_lo, iters_hi, reps, rng)
         for impl in impls
     ]
-    if backend != "tpu":
+    if auto_impl != "pallas":
         doc["stages"]["device_step"].append({
-            "impl": "pallas", "mode": "interpret", "skipped": True,
-            "why": "interpret-mode timing gates nothing off-TPU",
+            "impl": "pallas", "skipped": True, "why": auto_why,
         })
 
     # stage 2: the mesh arm

@@ -312,7 +312,7 @@ def main() -> int:
                          "gates the floor with the Pallas megakernel "
                          "compiled into the build (the production "
                          "selector picks per backend); 'pallas' forces "
-                         "it — interpret mode off-TPU, correctness only")
+                         "it — compiled by Mosaic or an error, TPU only")
     ap.add_argument("--trace-overhead-gate", type=float, default=None,
                     metavar="FRAC",
                     help="with tracing off, gate verdicts/s >= floor x "

@@ -13,22 +13,15 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 def main() -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir", os.path.join(REPO, ".jax_cache")
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass
+    from sentinel_tpu.core.compile_cache import ensure_compile_cache
 
+    ensure_compile_cache()
     from sentinel_tpu.engine import (
         ClusterFlowRule,
         EngineConfig,

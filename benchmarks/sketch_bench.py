@@ -5,8 +5,10 @@ on fixed-seed Zipf streams (``sentinel_tpu/sketch/parity.py``) and emits a
 BENCH-style artifact: per-key overestimate CDF vs an exact reference,
 effective key cardinality at equal HBM bytes (the SALSA memory win),
 update/query timings, and the SF slim twin's stats. Both impls are
-covered — ``pallas`` runs in interpret mode off-TPU, so its streams are
-kept small there (the numbers prove semantics, not speed).
+covered — off the TPU this script asks JAX for the Pallas interpreter
+itself (``pltpu.force_tpu_interpret_mode``; nothing in the package picks
+interpret mode), so the ``pallas`` streams are kept small there (the
+numbers prove semantics, not speed).
 
 ``--smoke`` is the CI ``sketch-parity`` gate: exit nonzero unless
 
@@ -28,6 +30,7 @@ if _REPO not in _sys.path:
     _sys.path.insert(0, _REPO)
 
 import argparse
+import contextlib
 import json
 import os
 import time
@@ -43,6 +46,7 @@ SMOKE_SLIM_ERR_FLOOR_FRAC = 0.25
 def run(smoke: bool = False) -> dict:
     import jax
     import numpy as np
+    from jax.experimental.pallas import tpu as pltpu
 
     from sentinel_tpu.engine.param import ParamConfig
     from sentinel_tpu.sketch import VARIANTS, sketch_stats
@@ -71,42 +75,48 @@ def run(smoke: bool = False) -> dict:
 
     for sketch in VARIANTS:
         for impl in ("jax", "pallas"):
-            # interpret-mode pallas is ~50× slower than the XLA path
-            # (BENCH_r05) — keep its stream small off-TPU
+            # off the TPU the pallas variants run under the interpreter,
+            # asked for here and orders of magnitude slower than the XLA
+            # path — keep their streams small
             small = impl == "pallas" and not on_tpu
-            cfg = ParamConfig(
-                max_param_rules=8,
-                depth=2,
-                width=64 if small else 512,
-                sketch=sketch,
-                impl=impl,
+            interpreter = (
+                pltpu.force_tpu_interpret_mode() if small
+                else contextlib.nullcontext()
             )
-            n_keys, n_events = (48, 1024) if small else (256, 8192)
-            with_slim = impl == "jax"  # one slim measurement per variant
-            rep = stream_report(
-                cfg,
-                n_keys=n_keys,
-                n_events=n_events,
-                seed=DEFAULT_SEED,
-                batch=256 if small else 512,
-                with_slim=with_slim,
-            )
-            # timings on a warm jit: feed the identical stream twice, time
-            # the second pass; host query timed over every distinct key
-            hashes, _ = zipf_stream(n_keys, n_events, seed=DEFAULT_SEED)
-            state = run_stream(cfg, hashes, batch=256 if small else 512,
-                               maintain_slim=with_slim)
-            t0 = time.perf_counter()
-            state = run_stream(cfg, hashes, batch=256 if small else 512,
-                               maintain_slim=with_slim)
-            update_ns = (time.perf_counter() - t0) * 1e9 / n_events
-            keys = key_hashes(n_keys, DEFAULT_SEED)
-            t0 = time.perf_counter()
-            query_np(cfg, state, 0, keys, 1_000)
-            query_ns = (time.perf_counter() - t0) * 1e9 / n_keys
-            rep["updateNsPerEvent"] = round(update_ns, 1)
-            rep["hostQueryNsPerKey"] = round(query_ns, 1)
-            rep["sketchStats"] = sketch_stats(cfg, state)
+            with interpreter:
+                cfg = ParamConfig(
+                    max_param_rules=8,
+                    depth=2,
+                    width=64 if small else 512,
+                    sketch=sketch,
+                    impl=impl,
+                )
+                n_keys, n_events = (48, 1024) if small else (256, 8192)
+                with_slim = impl == "jax"  # one slim measurement per variant
+                rep = stream_report(
+                    cfg,
+                    n_keys=n_keys,
+                    n_events=n_events,
+                    seed=DEFAULT_SEED,
+                    batch=256 if small else 512,
+                    with_slim=with_slim,
+                )
+                # timings on a warm jit: feed the identical stream twice, time
+                # the second pass; host query timed over every distinct key
+                hashes, _ = zipf_stream(n_keys, n_events, seed=DEFAULT_SEED)
+                state = run_stream(cfg, hashes, batch=256 if small else 512,
+                                   maintain_slim=with_slim)
+                t0 = time.perf_counter()
+                state = run_stream(cfg, hashes, batch=256 if small else 512,
+                                   maintain_slim=with_slim)
+                update_ns = (time.perf_counter() - t0) * 1e9 / n_events
+                keys = key_hashes(n_keys, DEFAULT_SEED)
+                t0 = time.perf_counter()
+                query_np(cfg, state, 0, keys, 1_000)
+                query_ns = (time.perf_counter() - t0) * 1e9 / n_keys
+                rep["updateNsPerEvent"] = round(update_ns, 1)
+                rep["hostQueryNsPerKey"] = round(query_ns, 1)
+                rep["sketchStats"] = sketch_stats(cfg, state)
             out["variants"][f"{sketch}/{impl}"] = rep
 
             if rep["undercounts"]:

@@ -353,14 +353,9 @@ def measure(batch_size: int = 32768, n_flows: int = 100_000,
     import jax.numpy as jnp
     import numpy as np
 
-    cache = os.path.join(REPO, ".jax_cache")
-    try:
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass
+    from sentinel_tpu.core.compile_cache import ensure_compile_cache
 
+    ensure_compile_cache()
     from sentinel_tpu.engine import (
         ClusterFlowRule,
         EngineConfig,

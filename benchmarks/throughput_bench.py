@@ -29,13 +29,14 @@ import argparse
 import json
 import multiprocessing as mp
 import os
-import sys
 import time
 
 
 def _client_worker(k: int, port: int, batch: int, pipeline: int,
                    seconds: float, n_flows: int, out_q) -> None:
-    # child process: only sockets + numpy — never touches jax
+    # child process: only sockets + numpy. One process per chip: pinned to
+    # the CPU before anything can import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import threading
 
     import numpy as np
@@ -93,15 +94,8 @@ def run(n_clients: int = 8, batch: int = 1024, pipeline: int = 3,
         ns_max_qps=1e12,
     )
     if native:
-        from sentinel_tpu.cluster.server_native import (
-            NativeTokenServer,
-            native_available,
-        )
-
-        if not native_available():
-            print("native library not built; falling back to asyncio",
-                  file=sys.stderr)
-            native = False
+        # asked for by name: not loadable is an error, not another door
+        from sentinel_tpu.cluster.server_native import NativeTokenServer
     if native:
         server = NativeTokenServer(service, host="127.0.0.1", port=port,
                                    max_batch=max_batch)
@@ -116,7 +110,9 @@ def run(n_clients: int = 8, batch: int = 1024, pipeline: int = 3,
     from sentinel_tpu.metrics.server import server_metrics
     server_metrics().reset()
 
-    ctx = mp.get_context("fork")  # children use sockets+numpy only
+    # spawn, not fork: the parent has initialised the backend by now (it
+    # holds the chip, and has threads), which a forked child would inherit
+    ctx = mp.get_context("spawn")
     out_q = ctx.Queue()
     procs = [
         ctx.Process(target=_client_worker,

@@ -24,10 +24,10 @@ lane** pulls decoded frames from the C++ door and hands copies to the
 ``fuse_depth`` pulls of host prep), concatenates it, and issues ONE
 dispatch — the token service's fusion ladder then folds full engine
 frames into a single chained ``lax.scan`` device step, so the fixed
-per-dispatch overhead (20–50ms/bucket in BENCH_r05) is paid once per
-fused group. ``n_dispatchers`` **reply lanes** block on the async
-verdicts, slice them back per pull, and submit — so host-side prep and
-reply encoding overlap device time instead of serializing behind it.
+per-dispatch overhead is paid once per fused group. ``n_dispatchers``
+**reply lanes** block on the async verdicts, slice them back per pull, and
+submit — so host-side prep and reply encoding overlap device time instead
+of serializing behind it.
 Fusion depth adapts to load by construction: an idle queue yields
 single-frame dispatches (no added latency), a backed-up queue yields
 deep fused steps (max amortization).
@@ -98,8 +98,11 @@ class NativeTokenServer:
         shm_spin_us: Optional[int] = None,
         push: bool = True,
     ):
-        from sentinel_tpu.native.lib import Frontdoor  # raises if unbuilt
+        from sentinel_tpu.native.lib import Frontdoor, require
 
+        # a door asked for by name that cannot be had is an error carrying
+        # the build command and the compiler's output — now, not at start()
+        require()
         self._Frontdoor = Frontdoor
         # opt-in shared-memory ring door for co-located sidecar clients:
         # one extra intake lane pulls from the ring poller and drains into

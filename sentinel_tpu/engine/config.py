@@ -34,13 +34,13 @@ class EngineConfig(NamedTuple):
     prefix_impl: str = "auto"
     # decision-step backend: "xla" (the `_decide_core` pipeline — one XLA
     # pass per subsystem), "pallas" (the one-HBM-traversal megakernel in
-    # ops/decide_pallas.py: window reads, roll, admission math and the
-    # event scatters fused into a single kernel over the flow plane), or
-    # "auto" (SENTINEL_DECIDE_IMPL env var wins; off-TPU picks "xla"
-    # outright — interpret-mode pallas is orders of magnitude slower; on
-    # TPU both are micro-probed once per process and the faster wins).
-    # The pallas step requires grouped batches; non-grouped callers fall
-    # back to "xla" regardless of this setting.
+    # ops/decide_pallas.py: compiled by Mosaic or it raises — there is no
+    # hand-back), or "auto" (SENTINEL_DECIDE_IMPL env var wins; otherwise
+    # the XLA pipeline on every platform, see
+    # engine.decide.explain_decide_impl for the reason it gives). The
+    # pallas step serves grouped batches of at most
+    # decide_pallas.MAX_BATCH rows; engine.decide.decide_core_name says
+    # which core a given step is built from.
     decide_impl: str = "auto"
 
     @property

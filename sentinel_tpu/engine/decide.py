@@ -770,22 +770,31 @@ def _decide_core(
     return new_state, verdicts
 
 
-_AUTO_DECIDE_IMPL: dict = {}  # backend platform → probed choice (per process)
+# What "auto" resolves to on a TPU, and why — a stated constant, not a probe:
+# the chip refuses the megakernel (chip run, PR 21: TPU v5 lite, jax 0.9.0,
+# libtpu 0.0.34; benchmarks/kernel_chip_parity.py prints the compiler's words
+# again on every run). ROADMAP Design 2 disposes of the kernel.
+_AUTO_ON_TPU = (
+    "xla",
+    "ops/decide_pallas.py is not offered: Mosaic refuses it "
+    "(NotImplementedError: Unimplemented primitive in Pallas TPU lowering "
+    "for KernelType.TC: dynamic_slice)",
+)
 
 
-def resolve_decide_impl(impl: str) -> str:
-    """Resolve ``EngineConfig.decide_impl`` to a concrete step backend
-    ("xla" | "pallas") — same selection discipline as
-    ``engine.param.resolve_param_impl``.
+def explain_decide_impl(impl: str) -> tuple:
+    """Resolve ``EngineConfig.decide_impl`` to ``(backend, reason)`` with
+    ``backend`` in ("xla" | "pallas") — same selection discipline as
+    ``engine.param.explain_param_impl``.
 
-    "auto" picks per platform: the ``SENTINEL_DECIDE_IMPL`` env var wins if
-    set; off-TPU the XLA pipeline is chosen outright (interpret-mode pallas
-    exists for parity testing, not serving); on TPU both steps are
-    micro-probed once per process and the faster one is cached. A megakernel
-    that fails to compile (Mosaic version skew) simply loses the probe.
+    An explicit "xla"/"pallas" (config or ``SENTINEL_DECIDE_IMPL``) is taken
+    as given: a forced "pallas" whose kernel Mosaic refuses raises the
+    compiler's error at the first step that builds it — there is no
+    hand-back to XLA. "auto" is the XLA pipeline: off-TPU because Mosaic
+    compiles for the TPU only, on TPU by :data:`_AUTO_ON_TPU`.
     """
     if impl in ("xla", "pallas"):
-        return impl
+        return impl, f"decide_impl={impl!r} set explicitly"
     if impl != "auto":
         raise ValueError(
             f"unknown decide impl {impl!r}; use 'auto'|'xla'|'pallas'"
@@ -794,62 +803,34 @@ def resolve_decide_impl(impl: str) -> str:
 
     env = os.environ.get("SENTINEL_DECIDE_IMPL", "").strip().lower()
     if env in ("xla", "pallas"):
-        return env
+        return env, f"SENTINEL_DECIDE_IMPL={env}"
     platform = jax.default_backend()
-    choice = _AUTO_DECIDE_IMPL.get(platform)
-    if choice is None:
-        choice = "xla" if platform != "tpu" else _probe_decide_impl()
-        _AUTO_DECIDE_IMPL[platform] = choice
-    return choice
+    if platform == "tpu":
+        return _AUTO_ON_TPU
+    return "xla", f"platform {platform!r}: Mosaic compiles for TPU only"
 
 
-def _probe_decide_impl() -> str:
-    """Time one warm grouped step of each backend on the live backend (small
-    probe shapes — the comparison is kernel-vs-kernel, not absolute)."""
-    import time as _time
+def resolve_decide_impl(impl: str) -> str:
+    """The backend half of :func:`explain_decide_impl`."""
+    return explain_decide_impl(impl)[0]
 
-    from sentinel_tpu.engine.rules import build_rule_table
-    from sentinel_tpu.engine.state import make_state
 
-    best_dt = None
-    choice = "xla"
-    for name in ("xla", "pallas"):
-        cfg = EngineConfig(
-            max_flows=256, batch_size=64, decide_impl=name
-        )
-        try:
-            core = _core_for(cfg, grouped=True)
-            step = jax.jit(
-                partial(core, cfg, axis_name=None, grouped=True,
-                        uniform=False)
-            )
-            state = make_state(cfg)
-            rules, _ = build_rule_table(cfg, [])
-            batch = make_batch(cfg, [0, 1, 2])
-            _, v = step(state, rules, batch, jnp.int32(1000))  # compile+warm
-            jax.block_until_ready(v.status)
-            t0 = _time.perf_counter()
-            for _ in range(3):
-                _, v = step(state, rules, batch, jnp.int32(1000))
-            jax.block_until_ready(v.status)
-            dt = _time.perf_counter() - t0
-        except Exception:
-            continue  # backend unusable here: the other wins
-        if best_dt is None or dt < best_dt:
-            best_dt, choice = dt, name
-    return choice
+def decide_core_name(config: EngineConfig, grouped: bool) -> str:
+    """Which core :func:`_core_for` builds for this config: "pallas" or
+    "xla". The megakernel needs the grouped-batch contract (same-flow rows
+    contiguous — its segment-tail read-modify-write scatter is only
+    race-free then) and a batch within its VMEM cap; every other step is
+    the XLA pipeline, whatever ``decide_impl`` says."""
+    if not grouped or resolve_decide_impl(config.decide_impl) != "pallas":
+        return "xla"
+    from sentinel_tpu.ops.decide_pallas import MAX_BATCH
+
+    return "pallas" if config.batch_size <= MAX_BATCH else "xla"
 
 
 def _core_for(config: EngineConfig, grouped: bool):
-    """The decide-core callable for this config's resolved backend.
-
-    The Pallas megakernel depends on the grouped-batch contract (same-flow
-    rows contiguous — its segment-tail read-modify-write scatter is only
-    race-free then), so non-grouped callers always get the XLA pipeline.
-    Batches above the kernel's VMEM cap also fall back inside the pallas
-    core itself (see ``ops/decide_pallas.py``).
-    """
-    if grouped and resolve_decide_impl(config.decide_impl) == "pallas":
+    """The decide-core callable :func:`decide_core_name` names."""
+    if decide_core_name(config, grouped) == "pallas":
         from sentinel_tpu.ops.decide_pallas import decide_core_pallas
 
         return decide_core_pallas

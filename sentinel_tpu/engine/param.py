@@ -61,12 +61,10 @@ class ParamConfig(NamedTuple):
     bucket_ms: int = 500
     n_buckets: int = 2  # 1s sliding window like the local second-level
     # "jax" = pure-XLA path below; "pallas" = ops/cms_pallas.py kernel
-    # (interpret mode off-TPU); "auto" = measured selection. Off-TPU,
-    # "auto" resolves straight to "jax" — BENCH_r05 measured the
-    # interpret-mode pallas step ~50× slower (76.7ms vs 1.54ms) — and on
-    # TPU it micro-probes both kernels once per process, so production
-    # never runs a kernel that was never timed on its own backend (the
-    # VERDICT r4 concern about a blind selector). SENTINEL_PARAM_IMPL=
+    # (compiled by Mosaic, or it raises); "auto" = measured selection: off
+    # the TPU it resolves straight to "jax", on the TPU it micro-probes
+    # both kernels once per process, so production never runs a kernel
+    # that was never timed on its own backend. SENTINEL_PARAM_IMPL=
     # jax|pallas overrides the probe for deployments that pin a choice.
     impl: str = "auto"
     # "cms" = plain int32 count-min (the seed); "salsa" = self-adjusting
@@ -147,20 +145,9 @@ def param_decide(
     that pass ``idx_slim=None`` (probes, micro-benchmarks) skip the twin
     entirely — on a primary the twin is then simply not maintained.
     """
-    impl = resolve_param_impl(config.impl)
-    if config.sketch == "salsa":
-        from sentinel_tpu.sketch.salsa import (
-            salsa_decide_jax,
-            salsa_decide_pallas,
-        )
-
-        core = salsa_decide_pallas if impl == "pallas" else salsa_decide_jax
-    elif config.sketch == "cms":
-        core = _param_decide_pallas if impl == "pallas" else _param_decide_jax
-    else:
-        raise ValueError(
-            f"unknown param sketch {config.sketch!r}; use 'cms'|'salsa'"
-        )
+    core = _param_cores(config.sketch)[
+        resolve_param_impl(config.impl, config.sketch)
+    ]
     if idx_slim is None or not config.slim_enabled:
         return core(config, state, rule_slot, idx, acquire, threshold, valid,
                     now)
@@ -178,43 +165,83 @@ def param_decide(
     return state2._replace(slim=slim2), admit, est_fat + est_slim
 
 
-_AUTO_IMPL: dict = {}  # backend platform → probed choice (process-cached)
+# Per-process cache: (backend platform, sketch) → (choice, reason).
+_AUTO_IMPL: dict = {}
 
 
-def resolve_param_impl(impl: str) -> str:
-    """Resolve ``impl`` to a concrete kernel ("jax" | "pallas").
+def explain_param_impl(impl: str, sketch: str = "cms") -> tuple:
+    """Resolve ``impl`` to ``(kernel, reason)`` with ``kernel`` in
+    ("jax" | "pallas").
 
-    "auto" picks per platform: the ``SENTINEL_PARAM_IMPL`` env var wins if
-    set; off-TPU the XLA path is chosen outright (BENCH_r05: interpret-mode
-    pallas is ~50× slower there); on TPU both kernels are micro-probed once
-    per process and the faster one is cached. A pallas kernel that fails to
-    compile (Mosaic version skew) simply loses the probe.
+    An explicit "jax"/"pallas" (config or ``SENTINEL_PARAM_IMPL``) is taken
+    as given — a forced "pallas" whose kernel Mosaic refuses raises the
+    compiler's error, it is never served from XLA instead. "auto" picks per
+    platform: off-TPU the XLA path outright (Mosaic compiles for the TPU
+    only); on TPU both kernels of ``sketch`` are micro-probed once per
+    process and the faster one is cached. A kernel that fails to build
+    loses the probe out loud: the compiler's message is logged and
+    returned in the reason.
     """
     if impl in ("jax", "pallas"):
-        return impl
+        return impl, f"impl={impl!r} set explicitly"
     if impl != "auto":
         raise ValueError(
             f"unknown param impl {impl!r}; use 'auto'|'jax'|'pallas'"
         )
     env = os.environ.get("SENTINEL_PARAM_IMPL", "").strip().lower()
     if env in ("jax", "pallas"):
-        return env
+        return env, f"SENTINEL_PARAM_IMPL={env}"
     platform = jax.default_backend()
-    choice = _AUTO_IMPL.get(platform)
-    if choice is None:
-        choice = "jax" if platform != "tpu" else _probe_param_impl()
-        _AUTO_IMPL[platform] = choice
-    return choice
+    resolved = _AUTO_IMPL.get((platform, sketch))
+    if resolved is None:
+        if platform != "tpu":
+            resolved = (
+                "jax", f"platform {platform!r}: Mosaic compiles for TPU only"
+            )
+        else:
+            resolved = _probe_param_impl(sketch)
+        _AUTO_IMPL[(platform, sketch)] = resolved
+    return resolved
 
 
-def _probe_param_impl() -> str:
-    """Time one warm step of each kernel on the live backend (small probe
-    shapes — the comparison is kernel-vs-kernel, not absolute)."""
+def resolve_param_impl(impl: str, sketch: str = "cms") -> str:
+    """The kernel half of :func:`explain_param_impl`."""
+    return explain_param_impl(impl, sketch)[0]
+
+
+def _param_cores(sketch: str) -> dict:
+    """``{"jax": core, "pallas": core}`` for one sketch encoding."""
+    if sketch == "salsa":
+        from sentinel_tpu.sketch.salsa import (
+            salsa_decide_jax,
+            salsa_decide_pallas,
+        )
+
+        return {"jax": salsa_decide_jax, "pallas": salsa_decide_pallas}
+    if sketch == "cms":
+        return {"jax": _param_decide_jax, "pallas": _param_decide_pallas}
+    raise ValueError(
+        f"unknown param sketch {sketch!r}; use 'cms'|'salsa'"
+    )
+
+
+# rows of the probe batch: request_params_token pads a request's values to a
+# power of two ≥ 8, and 64 values in one request is already unusual
+_PROBE_ROWS = 64
+
+
+def _probe_param_impl(sketch: str) -> tuple:
+    """Time one warm step of each kernel of ``sketch`` on the live backend
+    at the default geometry and the widest batch the serving path commonly
+    pads to. Returns ``(choice, reason)``."""
     import time as _time
 
-    cfg = ParamConfig(impl="jax")
+    from sentinel_tpu.core.log import record_log
+    from sentinel_tpu.ops import KERNEL_BUILD_ERRORS
+
+    cfg = ParamConfig(impl="jax", sketch=sketch)
     state = make_param_state(cfg)
-    n = 8
+    n = _PROBE_ROWS
     args = (
         jnp.zeros(n, jnp.int32),
         jnp.zeros((n, cfg.depth), jnp.int32),
@@ -223,23 +250,33 @@ def _probe_param_impl() -> str:
         jnp.zeros(n, bool),  # nothing valid → probe leaves state unchanged
         jnp.int32(0),
     )
-    best_dt = None
-    choice = "jax"
-    for name, fn in (("jax", _param_decide_jax),
-                     ("pallas", _param_decide_pallas)):
+    times = {}
+    refused = None
+    for name, fn in _param_cores(sketch).items():
         try:
             _, ok, _ = fn(cfg, state, *args)  # compile + warm
-            jax.block_until_ready(ok)
-            t0 = _time.perf_counter()
-            for _ in range(3):
-                _, ok, _ = fn(cfg, state, *args)
-            jax.block_until_ready(ok)
-            dt = _time.perf_counter() - t0
-        except Exception:
-            continue  # kernel unusable on this backend: the other wins
-        if best_dt is None or dt < best_dt:
-            best_dt, choice = dt, name
-    return choice
+        except KERNEL_BUILD_ERRORS as e:
+            if name == "jax":
+                raise  # no kernel at all: nothing to fall back to
+            refused = f"{type(e).__name__}: {e}"
+            record_log.error(
+                "[param] %s pallas kernel refused by the compiler, auto "
+                "resolves to jax: %s", sketch, refused,
+            )
+            continue
+        jax.block_until_ready(ok)
+        t0 = _time.perf_counter()
+        for _ in range(3):
+            _, ok, _ = fn(cfg, state, *args)
+        jax.block_until_ready(ok)
+        times[name] = (_time.perf_counter() - t0) / 3
+    choice = min(times, key=times.get)
+    reason = f"probe ({sketch}, {n} rows): " + ", ".join(
+        f"{k} {v * 1e3:.3f} ms/step" for k, v in times.items()
+    )
+    if refused is not None:
+        reason += f"; pallas refused: {refused}"
+    return choice, reason
 
 
 @partial(jax.jit, static_argnames=("config",))
@@ -279,7 +316,6 @@ def _param_decide_pallas(
         D=D,
         W=W,
         bucket_ms=config.bucket_ms,
-        interpret=jax.default_backend() != "tpu",
     )
     counts = jnp.transpose(planes.reshape(B, D, P, W), (2, 0, 1, 3))
     return state._replace(starts=starts, counts=counts), admit, est

@@ -17,7 +17,7 @@ reduce passes because XLA's 1-D scan lowering costs ~0.3ms at N=16k):
   all, just the cumsum + rebase. This is the serving fast path.
 - ``pallas``: the tiled kernel in ``ops/prefix_pallas.py`` — same math as
   ``matmul`` but the [N, N] mask is built tile-by-tile in VMEM and never
-  touches HBM (interpret mode off-TPU).
+  touches HBM (compiled by Mosaic: TPU only).
 
 Contributions must be **non-negative** float32 (exact for counts < 2^24):
 the segment rebase recovers each row's segment-head offset with a running
@@ -76,10 +76,8 @@ def segment_prefix_builder(keys: jax.Array, impl: str = "auto"):
     if impl == "pallas":
         from sentinel_tpu.ops.prefix_pallas import segment_prefix_pallas
 
-        interpret = jax.default_backend() != "tpu"
-
         def prefix_pallas(contrib: jax.Array) -> jax.Array:
-            return segment_prefix_pallas(keys, contrib, interpret=interpret)
+            return segment_prefix_pallas(keys, contrib)
 
         return prefix_pallas
 

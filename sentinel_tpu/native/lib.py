@@ -22,6 +22,7 @@ _SO_PATH = os.environ.get(
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+_load_error = ""  # why load() returned None — the compiler's output included
 
 
 def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -179,45 +180,76 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
+def _stale() -> bool:
+    """True when the autobuilt library is missing or older than any of its
+    sources. The ``.so`` is gitignored but sits in working trees, so after a
+    pull that touched ``native/src`` the file on disk is yesterday's build —
+    it loads fine and only lacks the newer exports."""
+    from sentinel_tpu.native.build import SOURCES
+
+    if not os.path.exists(_SO_PATH):
+        return True
+    built = os.path.getmtime(_SO_PATH)
+    return any(
+        os.path.exists(src) and os.path.getmtime(src) > built
+        for src in SOURCES
+    )
+
+
 def load() -> Optional[ctypes.CDLL]:
-    """Load (once) the native library; on a fresh checkout, build it first.
+    """Load (once) the native library, building it first when it is missing
+    or older than ``native/src/*.cpp``.
 
     The ``.so`` is a build artifact (gitignored), so first use on a clean
     tree compiles it with the ambient C++ toolchain (~seconds; same
-    command as ``make -C native``). Failures degrade to the pure-Python
-    paths exactly as a missing library always has. Set
-    ``SENTINEL_NATIVE_AUTOBUILD=0`` to disable, or ``SENTINEL_NATIVE_SO``
-    to point at a prebuilt library (never auto-built over).
+    command as ``make -C native``). A failure degrades the optional
+    accelerations (codecs, windows) to their pure-Python paths and is kept
+    for :func:`require`, which the native doors call — a door asked for by
+    name is never quietly swapped for another. Set
+    ``SENTINEL_NATIVE_AUTOBUILD=0`` to disable building, or
+    ``SENTINEL_NATIVE_SO`` to point at a prebuilt library (never built over).
     """
-    global _lib, _load_failed
+    global _lib, _load_failed, _load_error
     if _lib is not None or _load_failed:
         return _lib
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_SO_PATH):
-            if (
-                "SENTINEL_NATIVE_SO" in os.environ
-                or os.environ.get("SENTINEL_NATIVE_AUTOBUILD") == "0"
-            ):
-                _load_failed = True
-                return None
-            try:
-                from sentinel_tpu.native.build import build
+        autobuild = not (
+            "SENTINEL_NATIVE_SO" in os.environ
+            or os.environ.get("SENTINEL_NATIVE_AUTOBUILD") == "0"
+        )
+        if autobuild and _stale():
+            import subprocess
 
+            from sentinel_tpu.native.build import build
+
+            try:
                 build(verbose=False)
-            except Exception:
-                _load_failed = True
-                return None
-        if not os.path.exists(_SO_PATH):
-            _load_failed = True
-            return None
-        try:
-            _lib = _configure(ctypes.CDLL(_SO_PATH))
-        except OSError:
-            _load_failed = True
-            return None
+            except subprocess.CalledProcessError as e:
+                _load_error = (
+                    f"{' '.join(e.cmd)} exited {e.returncode}:\n{e.stderr}"
+                )
+            except (OSError, RuntimeError) as e:
+                _load_error = f"{type(e).__name__}: {e}"
+        if not _load_error and not os.path.exists(_SO_PATH):
+            _load_error = f"{_SO_PATH} does not exist"
+        if not _load_error:
+            try:
+                _lib = _configure(ctypes.CDLL(_SO_PATH))
+            except OSError as e:
+                _load_error = f"dlopen {_SO_PATH}: {e}"
+        _load_failed = _lib is None
     return _lib
+
+
+def require() -> ctypes.CDLL:
+    """:func:`load`, or raise with the reason (build command and compiler
+    output) when the library cannot be had."""
+    lib = load()
+    if lib is None:
+        raise RuntimeError(f"native library not loadable: {_load_error}")
+    return lib
 
 
 def available() -> bool:
@@ -294,9 +326,7 @@ class NativeWindow:
                  "interval_ms")
 
     def __init__(self, bucket_ms: int, n_buckets: int, n_channels: int):
-        lib = load()
-        if lib is None:
-            raise RuntimeError("native library not built")
+        lib = require()
         self._lib = lib
         self._h = lib.sn_window_create(bucket_ms, n_buckets, n_channels)
         if not self._h:
@@ -355,9 +385,7 @@ class NativeTokenBuckets:
     __slots__ = ("_lib", "_h", "n_slots")
 
     def __init__(self, n_slots: int):
-        lib = load()
-        if lib is None:
-            raise RuntimeError("native library not built")
+        lib = require()
         self._lib = lib
         self._h = lib.sn_tb_create(n_slots)
         if not self._h:
@@ -395,9 +423,7 @@ class NativePacerArray:
     __slots__ = ("_lib", "_h", "n_slots")
 
     def __init__(self, n_slots: int):
-        lib = load()
-        if lib is None:
-            raise RuntimeError("native library not built")
+        lib = require()
         self._lib = lib
         self._h = lib.sn_pacer_create(n_slots)
         if not self._h:
@@ -445,9 +471,7 @@ class Frontdoor:
                  arena_cap: int = 65536):
         import numpy as np
 
-        lib = load()
-        if lib is None:
-            raise RuntimeError("native library not built")
+        lib = require()
         self._lib = lib
         # the arena must fit at least one max-size frame or a full frame
         # could never be admitted and its connection would park forever
@@ -726,9 +750,7 @@ class ShmDoor:
         # the syscall entirely in the steady state.
         if spin_us is None:
             spin_us = 0 if (os.cpu_count() or 1) <= 1 else 100
-        lib = load()
-        if lib is None:
-            raise RuntimeError("native library not built")
+        lib = require()
         if not getattr(lib, "_sn_has_shm", False):
             raise RuntimeError(
                 "native library predates the shm door — rebuild with "
@@ -929,9 +951,7 @@ class ShmRingClient:
                  n_slots: int = 16, spin_us: Optional[int] = None):
         if spin_us is None:  # same adaptive rule as ShmDoor
             spin_us = 0 if (os.cpu_count() or 1) <= 1 else 50
-        lib = load()
-        if lib is None:
-            raise RuntimeError("native library not built")
+        lib = require()
         if not getattr(lib, "_sn_has_shm", False):
             raise RuntimeError(
                 "native library predates the shm door — rebuild with "

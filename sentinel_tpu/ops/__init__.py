@@ -11,11 +11,33 @@ arrays, CAS window loops — map to device kernels here, not to C/C++):
   one-hot MXU matmuls.
 
 Every kernel has a pure-jax reference implementation elsewhere in the tree
-(`engine/prefix.py`, `engine/param.py`); the kernels are selected on TPU
-backends and fall back to interpret mode in tests.
+(`engine/prefix.py`, `engine/param.py`). A kernel a config selects is
+compiled by Mosaic or raises: nothing here derives ``interpret=`` from the
+backend. The CPU parity tests ask for the interpreter themselves
+(``interpret=True`` on the kernel entry points, or the ``pallas_interpret``
+fixture in ``tests/conftest.py`` around a config-selected step).
 """
+
+import jax
+from jax._src.pallas.mosaic.lowering import LoweringException
 
 from sentinel_tpu.ops.prefix_pallas import segment_prefix_pallas
 from sentinel_tpu.ops.cms_pallas import cms_decide_update_pallas
 
-__all__ = ["segment_prefix_pallas", "cms_decide_update_pallas"]
+# What a kernel that cannot be built for the chip raises: Mosaic's own
+# compile error (layout, VMEM) arrives as JaxRuntimeError; a Pallas lowering
+# rule that rejects an op raises LoweringException or a builtin below. The
+# "auto" probes catch exactly these, report the message and pick XLA.
+KERNEL_BUILD_ERRORS = (
+    jax.errors.JaxRuntimeError,
+    LoweringException,
+    NotImplementedError,
+    ValueError,
+    TypeError,
+)
+
+__all__ = [
+    "KERNEL_BUILD_ERRORS",
+    "segment_prefix_pallas",
+    "cms_decide_update_pallas",
+]

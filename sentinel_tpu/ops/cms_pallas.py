@@ -23,12 +23,12 @@ Kernel design (vs. the pure-XLA fallback):
   loop as the fallback (subset-of-greedy guarantee, ``engine/decide.py``),
   with the [N, N] same-key mask built in VMEM (N is capped so it fits).
 
-Backend selection: off-TPU this kernel runs in interpret mode and BENCH_r05
-measured it ~50× slower than the XLA path (76.7ms vs 1.54ms per step), so
-``ParamConfig(impl="auto")`` (the default) never picks it there; on TPU the
-two are micro-probed once per process and the faster wins. See
-``engine.param.resolve_param_impl`` — pin explicitly with ``impl=`` or the
-``SENTINEL_PARAM_IMPL`` env var.
+Backend selection: Mosaic compiles this kernel for the TPU only, so
+``ParamConfig(impl="auto")`` (the default) resolves to the XLA path anywhere
+else; on TPU the two are micro-probed once per process and the faster wins.
+See ``engine.param.explain_param_impl`` — pin explicitly with ``impl=`` or
+the ``SENTINEL_PARAM_IMPL`` env var. Selected, the kernel is compiled or it
+raises; only the CPU parity tests ask for ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -41,9 +41,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# [N, N] f32 prefix mask + [N, W] one-hots must fit VMEM next to a [P, W]
-# plane; 1024 keeps the mask at 4 MB.
+# The kernel's VMEM stack grows with N: the [N, N] f32 prefix mask, D
+# [N, W] one-hots and the gathered [N, W] rows next to a [P, W] plane. At
+# the default ParamConfig that is ~23 MB at N=1024 — past Mosaic's 16 MiB
+# default scoped limit, which refuses the kernel with RESOURCE_EXHAUSTED —
+# so the call asks for 64 MiB (a v5e core has 128 MiB of VMEM).
 MAX_BATCH = 1024
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+# The one-hot matmuls carry integer counts through the MXU; its default
+# single bf16 pass keeps 8 mantissa bits (cells above 256 would round).
+# HIGHEST keeps integer-valued f32 exact up to 2^24.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _make_kernel(P: int, B: int, D: int, W: int, bucket_ms: int, refine_iters: int):
@@ -112,6 +121,7 @@ def _make_kernel(P: int, B: int, D: int, W: int, bucket_ms: int, refine_iters: i
                     oh_slot,
                     plane_buf[0].astype(jnp.float32),
                     preferred_element_type=jnp.float32,
+                    precision=_EXACT,
                 )  # [N, W]
                 cell = jnp.sum(rows * oh_idx[d], axis=1)
                 acc = acc + jnp.where(ok, cell, 0.0)
@@ -131,7 +141,8 @@ def _make_kernel(P: int, B: int, D: int, W: int, bucket_ms: int, refine_iters: i
         for _ in range(refine_iters):
             contrib = jnp.where(admit, acq, 0.0)
             prefix = jnp.dot(
-                mask, contrib[:, None], preferred_element_type=jnp.float32
+                mask, contrib[:, None], preferred_element_type=jnp.float32,
+                precision=_EXACT,
             )[:, 0]
             admit = live & (est + prefix + acq <= thr)
 
@@ -149,6 +160,7 @@ def _make_kernel(P: int, B: int, D: int, W: int, bucket_ms: int, refine_iters: i
                 oh_slot.T,
                 oh_idx[d] * contrib[:, None],
                 preferred_element_type=jnp.float32,
+                precision=_EXACT,
             )  # [P, W]
             plane_buf[0] = old + delta.astype(jnp.int32)
             dma_out = pltpu.make_async_copy(
@@ -227,6 +239,7 @@ def cms_decide_update_pallas(
             bytes_accessed=4 * P * W * (B * D + 2 * D),
             transcendentals=0,
         ),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(
         counts,

@@ -38,19 +38,23 @@ What stays outside the kernel, by design:
   already has its own fused one-pass kernels (``cms_pallas``/
   ``salsa_pallas`` — the SALSA int16 packed-cell encoding lives there).
 
-Parity discipline (the ``ops/cms_pallas.py`` twin contract): off-TPU the
-kernel runs in interpret mode and ``tests/test_ops_decide_pallas.py`` asserts
+Parity discipline (the ``ops/cms_pallas.py`` twin contract):
+``tests/test_ops_decide_pallas.py`` runs the kernel under the Pallas
+interpreter on the CPU (its ``pallas_interpret`` fixture) and asserts
 *bitwise* equality of verdicts and every state leaf against the XLA
 pipeline over seeded mixed-behavior streams, including fused ``lax.scan``
-depth and 8-virtual-device ``shard_map``. All cross-backend sums are
-integer-valued float32 (< 2^24), where addition order cannot change the
-result; ``lax.cond``-gated XLA arms are replaced by unconditional
+depth and 8-virtual-device ``shard_map``. On the chip Mosaic refuses the
+kernel today (``benchmarks/kernel_chip_parity.py`` tries the same
+comparison compiled, and prints the compiler's message). All
+cross-backend sums are integer-valued float32 (< 2^24), where addition
+order cannot change the result; ``lax.cond``-gated XLA arms are replaced by unconditional
 compute + select, which is bitwise-identical because the gated-off values
 coincide (see ``_warmup_curve``'s docstring).
 
-Backend selection mirrors the sketch plane: ``EngineConfig.decide_impl``
-("auto" probes on TPU, picks XLA elsewhere; ``SENTINEL_DECIDE_IMPL``
-overrides) — see ``engine.decide.resolve_decide_impl``.
+Backend selection: ``EngineConfig.decide_impl`` ("auto" is the XLA
+pipeline everywhere, with the reason stated; ``SENTINEL_DECIDE_IMPL``
+overrides) — see ``engine.decide.explain_decide_impl``. Nothing here picks
+interpret mode: selected, the kernel is compiled by Mosaic or raises.
 """
 
 from __future__ import annotations
@@ -78,7 +82,8 @@ from sentinel_tpu.stats.window import WindowState
 
 # Per-request VMEM row buffers: [N, B, E] i32 must fit next to the scratch
 # planes (1024 × 64 buckets × 6 events × 4B ≈ 1.5 MB at the deepest serve
-# config). Larger batches fall back to the XLA pipeline.
+# config). Steps with larger batches are built from the XLA core
+# (``engine.decide.decide_core_name``).
 MAX_BATCH = 1024
 
 # stale-column zero pass: flow rows zeroed per DMA burst
@@ -431,7 +436,6 @@ def _call_decide_kernel(
     wtok_rows: jax.Array,
     wfill_rows: jax.Array,
     uniform: bool,
-    interpret: bool,
 ):
     F, B, E = flow_counts.shape
     N = safe_slot.shape[0]
@@ -486,7 +490,6 @@ def _call_decide_kernel(
             bytes_accessed=4 * (2 * N * B * (E + 1) + N * E + F * E // B),
             transcendentals=0,
         ),
-        interpret=interpret,
     )(
         flow_counts,
         occ_counts,
@@ -528,9 +531,9 @@ def decide_core_pallas(
     """Drop-in ``_decide_core`` twin backed by the megakernel.
 
     Same signature, same pytree outputs, bitwise-equal results. Requires
-    the grouped-batch contract; non-grouped calls and batches beyond the
-    kernel's VMEM cap fall back to the XLA pipeline (so ``decide_impl=
-    "pallas"`` can never produce wrong answers, only a slower path).
+    the grouped-batch contract and a batch within ``MAX_BATCH``; the choice
+    between this core and the XLA one is made where it can be reported,
+    in ``engine.decide.decide_core_name``.
     """
     # lazy (mutual recursion with engine.decide's backend dispatch), and via
     # importlib because the package re-exports a `decide` FUNCTION that
@@ -541,9 +544,10 @@ def decide_core_pallas(
 
     N = batch.valid.shape[0]
     if not grouped or N > MAX_BATCH:
-        return D._decide_core(
-            config, state, rules, batch, now, axis_name=axis_name,
-            grouped=grouped, uniform=uniform,
+        raise ValueError(
+            f"decide_core_pallas needs a grouped batch of at most "
+            f"{MAX_BATCH} rows (got grouped={grouped}, {N} rows); "
+            "engine.decide._core_for picks the core"
         )
 
     spec = flow_spec(config)
@@ -614,7 +618,6 @@ def decide_core_pallas(
     next_in = jnp.concatenate([in_range[1:], jnp.zeros((1,), bool)])
     write_ok = in_range & ~(next_same & next_in)
 
-    interpret = jax.default_backend() != "tpu"
     (
         flow_counts_out, fstarts_out,
         admit_o, canocc_o, paceacc_o, pacewait_o,
@@ -644,7 +647,6 @@ def decide_core_pallas(
         state.shaping.warm_tokens[safe_slot],
         state.shaping.warm_filled[safe_slot],
         uniform,
-        interpret,
     )
 
     admit = admit_o[:, 0] != 0

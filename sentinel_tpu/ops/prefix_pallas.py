@@ -27,6 +27,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 TILE_R = 256
 TILE_C = 512
+# contributions are integer token counts: keep them exact through the MXU
+# (its default bf16 pass rounds values above 256)
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _kernel(keys_row_ref, keys_col_ref, contrib_col_ref, out_ref):
@@ -46,6 +49,7 @@ def _kernel(keys_row_ref, keys_col_ref, contrib_col_ref, out_ref):
         mask.astype(jnp.float32),
         contrib_col_ref[:],
         preferred_element_type=jnp.float32,
+        precision=_EXACT,
     )
 
 

@@ -10,9 +10,10 @@ over a decoded int32 view of the plane.
 Pair arithmetic avoids minor-dimension strided slices (which Mosaic may
 refuse) by operating on full-width lane vectors: a cell's pair partner is a
 parity-selected ``jnp.roll`` by ±1 lane, and even/odd masks come from a
-lane iota. The decode/encode is therefore pure elementwise + roll — if a
-Mosaic version can't lower it, the kernel simply loses the ``impl="auto"``
-probe (``engine.param.resolve_param_impl``) and the XLA core serves.
+lane iota. The decode/encode is therefore pure elementwise + roll. A kernel
+Mosaic refuses loses the ``impl="auto"`` probe out loud
+(``engine.param.explain_param_impl`` logs and returns the compiler's
+message); forced with ``impl="pallas"`` it raises.
 
 Estimates travel through f32 accumulators exactly like the cms kernel, so
 they are exact below 2^24 — far above any admissible window threshold, and
@@ -32,6 +33,10 @@ from jax.experimental.pallas import tpu as pltpu
 from sentinel_tpu.sketch.salsa import CAP, MERGE_CEIL, SAT
 
 MAX_BATCH = 1024
+# same VMEM request and matmul precision as ops/cms_pallas.py, for the same
+# reasons (the [N, C] one-hots are twice as wide here)
+_VMEM_LIMIT = 64 * 1024 * 1024
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _make_kernel(P: int, B: int, D: int, C: int, bucket_ms: int,
@@ -121,7 +126,8 @@ def _make_kernel(P: int, B: int, D: int, C: int, bucket_ms: int,
                 dma.wait()
                 qdec, _m = _qdecode(plane_buf[0])
                 rows = jnp.dot(
-                    oh_slot, qdec, preferred_element_type=jnp.float32
+                    oh_slot, qdec, preferred_element_type=jnp.float32,
+                    precision=_EXACT,
                 )  # [N, C]
                 cell = jnp.sum(rows * oh_idx[d], axis=1)
                 acc = acc + jnp.where(ok, cell, 0.0)
@@ -141,7 +147,8 @@ def _make_kernel(P: int, B: int, D: int, C: int, bucket_ms: int,
         for _ in range(refine_iters):
             contrib = jnp.where(admit, acq, 0.0)
             prefix = jnp.dot(
-                mask, contrib[:, None], preferred_element_type=jnp.float32
+                mask, contrib[:, None], preferred_element_type=jnp.float32,
+                precision=_EXACT,
             )[:, 0]
             admit = live & (est + prefix + acq <= thr)
 
@@ -166,6 +173,7 @@ def _make_kernel(P: int, B: int, D: int, C: int, bucket_ms: int,
                 oh_slot,
                 merged.astype(jnp.float32),
                 preferred_element_type=jnp.float32,
+                precision=_EXACT,
             )  # [N, C]
             flag = jnp.sum(mrows * oh_idx[d], axis=1) > 0.5  # [N]
             idx_d = idx_ref[:, d]
@@ -178,6 +186,7 @@ def _make_kernel(P: int, B: int, D: int, C: int, bucket_ms: int,
                 oh_slot.T,
                 oh_eff * contrib[:, None],
                 preferred_element_type=jnp.float32,
+                precision=_EXACT,
             )  # [P, C]
             dec = dec + delta.astype(jnp.int32)
             # re-encode with merge-on-saturation
@@ -279,6 +288,7 @@ def salsa_decide_update_pallas(
             bytes_accessed=2 * P * C * (B * D + 2 * D),
             transcendentals=0,
         ),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(
         counts,

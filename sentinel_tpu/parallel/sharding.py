@@ -28,31 +28,6 @@ from sentinel_tpu.engine.rules import RuleTable
 from sentinel_tpu.engine.state import BreakerState, EngineState, ShapingState
 from sentinel_tpu.stats.window import WindowState
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across jax versions.
-
-    The verdict outputs are replicated *by value* (every shard psums the same
-    global answer) but the checker cannot statically infer that through the
-    cond-gated namespace guard, so it must be disabled. The kwarg that does
-    that was renamed (``check_rep`` → ``check_vma``) across jax releases;
-    probe for whichever this jax accepts.
-    """
-    for kw in ("check_vma", "check_rep"):
-        try:
-            return _shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{kw: False}
-            )
-        except TypeError:
-            continue
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 def make_flow_mesh(devices=None, axis: str = "flows") -> Mesh:
     devices = devices if devices is not None else jax.devices()
     return Mesh(np.asarray(devices), (axis,))
@@ -221,7 +196,10 @@ def make_sharded_decide(
     # br_* columns (degrade rules loaded) and without (None columns, so the
     # compile skips the breaker arm). Built lazily on first use of each.
     def _build(br: bool):
-        mapped = shard_map(
+        # check_vma off: the verdict outputs are replicated *by value*
+        # (every shard psums the same global answer), which the checker
+        # cannot infer through the cond-gated namespace guard
+        mapped = jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(
@@ -234,6 +212,7 @@ def make_sharded_decide(
                 _state_specs(axis),
                 VerdictBatch(status=P(), wait_ms=P(), remaining=P()),
             ),
+            check_vma=False,
         )
         return jax.jit(mapped, donate_argnums=(0,) if donate else ())
 

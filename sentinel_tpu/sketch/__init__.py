@@ -4,7 +4,7 @@
 seed's plain int32 count-min) or ``"salsa"`` (:mod:`sentinel_tpu.sketch.salsa`,
 int16 self-adjusting counters at the same HBM bytes) — and
 ``ParamConfig.impl`` independently selects the kernel ("jax" | "pallas" |
-"auto", probed by ``engine.param.resolve_param_impl``). The SF slim twin
+"auto", probed by ``engine.param.explain_param_impl``). The SF slim twin
 (:mod:`sentinel_tpu.sketch.slim`) composes around either variant; the
 accuracy harness (:mod:`sentinel_tpu.sketch.parity`) proves every
 combination keeps the one-sided (never-undercount) guarantee.

@@ -204,7 +204,6 @@ def salsa_decide_pallas(
         D=D,
         C=C,
         bucket_ms=config.bucket_ms,
-        interpret=jax.default_backend() != "tpu",
     )
     counts = jnp.transpose(planes.reshape(B, D, P, C), (2, 0, 1, 3))
     return (

@@ -263,20 +263,13 @@ _EMBEDDED_LOCK = threading.Lock()
 
 def _server_class():
     """Transport selection: ``csp.sentinel.cluster.server.native=true``
-    serves through the native epoll front door (C++ data plane) when the
-    native library is built; default is the asyncio transport."""
+    serves through the native epoll front door (C++ data plane); default is
+    the asyncio transport. A native door asked for and not loadable is an
+    error at construction (``native.lib.require``), never a quiet switch."""
     if SentinelConfig.get_bool("csp.sentinel.cluster.server.native"):
-        from sentinel_tpu.cluster.server_native import (
-            NativeTokenServer,
-            native_available,
-        )
+        from sentinel_tpu.cluster.server_native import NativeTokenServer
 
-        if native_available():
-            return NativeTokenServer
-        record_log.warning(
-            "csp.sentinel.cluster.server.native requested but the native "
-            "library is not built; using the asyncio transport"
-        )
+        return NativeTokenServer
     from sentinel_tpu.cluster.server import TokenServer
 
     return TokenServer

@@ -6,8 +6,9 @@ and every state leaf comes back *bit-identical*, across mixed control
 behaviors (DEFAULT / WARM_UP / RATE_LIMITER / WARM_UP_RATE_LIMITER),
 prioritized occupy borrows, namespace-guard boundary crossings, window
 rolls and idle gaps, the fused ``lax.scan`` depth, and the 8-virtual-device
-sharded step. Off-TPU the kernel runs in interpret mode (same twin
-discipline as ``tests/test_ops_pallas.py``).
+sharded step. The kernel runs under the ``pallas_interpret`` fixture here
+(same twin discipline as ``tests/test_ops_pallas.py``); compiled parity on
+the chip is ``benchmarks/kernel_chip_parity.py``.
 
 Equality is ``==`` on raw arrays, never ``allclose``: any divergence is a
 semantics drift in one of the twins, not float noise.
@@ -45,6 +46,8 @@ from sentinel_tpu.parallel import (
     shard_rules,
     shard_state,
 )
+
+pytestmark = pytest.mark.usefixtures("pallas_interpret")
 
 G = ThresholdMode.GLOBAL
 CB = ControlBehavior
@@ -415,26 +418,22 @@ class TestBackendSelection:
         assert _core_for(CFG_P, grouped=True) is decide_core_pallas
         assert _core_for(CFG_X, grouped=True) is _decide_core
 
-    def test_oversized_batch_falls_back(self):
-        """Batches beyond the kernel's VMEM cap fall back to the XLA core
-        inside decide_core_pallas — identical results, no error."""
-        cfg = EngineConfig(
-            max_flows=16, max_namespaces=4, batch_size=MAX_BATCH + 64,
-        )
-        table, _ = build_rule_table(
-            cfg, [ClusterFlowRule(flow_id=0, count=9.0, mode=G)]
-        )
-        st = make_state(cfg)
-        batch = make_batch(cfg, [0, 0, 0])
-        st_p, v_p = jax.jit(
-            lambda s, t, b: decide_core_pallas(
-                cfg, s, t, b, jnp.int32(5_000), grouped=True
+    def test_oversized_batch_core_is_chosen_in_the_open(self):
+        """Above the kernel's VMEM cap a forced "pallas" config builds the
+        XLA core, and says so: the choice is made in ``decide_core_name``
+        (where the service can report it per bucket), not inside the
+        Pallas core, which refuses the shape outright."""
+        from sentinel_tpu.engine.decide import _core_for, decide_core_name
+
+        cfg = CFG_P._replace(batch_size=MAX_BATCH + 64)
+        assert decide_core_name(cfg, grouped=True) == "xla"
+        assert _core_for(cfg, grouped=True) is _decide_core
+        assert decide_core_name(
+            CFG_P._replace(batch_size=MAX_BATCH), grouped=True
+        ) == "pallas"
+        table, _ = build_rule_table(cfg, [])
+        with pytest.raises(ValueError, match="at most"):
+            decide_core_pallas(
+                cfg, make_state(cfg), table, make_batch(cfg, [0]),
+                jnp.int32(5_000), grouped=True,
             )
-        )(st, table, batch)
-        st_x, v_x = jax.jit(
-            lambda s, t, b: _decide_core(
-                cfg, s, t, b, jnp.int32(5_000), grouped=True
-            )
-        )(make_state(cfg), table, batch)
-        _assert_trees_equal(v_x, v_p, "fallback verdicts")
-        _assert_trees_equal(st_x, st_p, "fallback state")

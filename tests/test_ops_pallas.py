@@ -1,7 +1,9 @@
 """Pallas kernels vs. their pure-jax reference implementations.
 
-Runs in interpret mode on the CPU mesh (conftest); the compiled path is the
-same kernel code on TPU.
+Runs in interpret mode on the CPU mesh, asked for explicitly (``interpret=True``
+on the kernel entry points, the ``pallas_interpret`` fixture for
+config-selected kernels); the compiled path is checked on the chip by
+``benchmarks/kernel_chip_parity.py``.
 """
 
 import jax.numpy as jnp
@@ -17,6 +19,8 @@ from sentinel_tpu.engine.param import (
 )
 from sentinel_tpu.engine.prefix import segment_prefix_builder
 from sentinel_tpu.ops.prefix_pallas import segment_prefix_pallas
+
+pytestmark = pytest.mark.usefixtures("pallas_interpret")
 
 
 def _ref_prefix(keys, contrib):

@@ -677,6 +677,7 @@ class TestHeartbeatBackoff:
         assert hb._consecutive_failures == 0
 
 
+@pytest.mark.usefixtures("pallas_interpret")
 class TestMegakernelStateArtifactParity:
     """Fused-kernel state contract, host-serialization layer: the decide
     megakernel (``decide_impl="pallas"``) must leave the service's state

@@ -443,6 +443,7 @@ class TestMeshBackedService:
         svc.close()
 
 
+@pytest.mark.usefixtures("pallas_interpret")
 class TestMegakernelStateContract:
     """The fused decide megakernel (``ops/decide_pallas.py``) as a drop-in
     for the XLA pipeline at the state-management layer: donation must

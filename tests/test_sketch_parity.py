@@ -84,6 +84,7 @@ def test_no_undercount_jax(sketch):
     assert rep["slim"]["undercounts"] == 0
 
 
+@pytest.mark.usefixtures("pallas_interpret")
 @pytest.mark.parametrize("sketch", VARIANTS)
 def test_no_undercount_pallas_interpret(sketch):
     # interpret mode is slow — a small stream still drives the whole
@@ -198,6 +199,7 @@ def test_sketch_provider_survives_dead_service():
 
 
 # -- impl parity: both kernels, same math -------------------------------------
+@pytest.mark.usefixtures("pallas_interpret")
 @pytest.mark.parametrize("sketch", VARIANTS)
 def test_jax_and_pallas_agree_bitwise(sketch):
     cfg_j = _cfg(sketch, impl="jax", width=128)

@@ -1,0 +1,547 @@
+"""One load-generator process: numpy and sockets, never JAX.
+
+Started by ``cellbench/run.py`` with a plan file; builds every frame it will
+send from the seed while the server warms up, connects when the server's
+port file appears, then takes commands on standard input (``warm``,
+``burst <rows>``, ``measure <t0> <seconds>``, ``quit``) and answers each with
+one JSON line. The measured window's raw samples go to an ``.npz`` beside
+the plan. ``loadgen.py witness`` is the process that only sleeps (see
+``witness``).
+
+Failure accounting (ISSUE 23, A.3), all of it at the client:
+
+* attempted - every row the schedule made due inside the window (closed
+  loop: every row sent inside it);
+* failed - every such row that did not come back as a verdict the device
+  decided: a send skipped because the in-flight window was full, no reply
+  within ``timeout_ms``, a connection error, a status that is not a decision
+  (OVERLOAD, FAIL, STANDBY, MOVED, anything unknown), and a brownout pass:
+  status OK with ``remaining == 0`` on an unmetered flow, which only the
+  overload ladder's local answer produces;
+* latency - from the time the frame was due (closed loop: sent) to its
+  reply, every row of a frame carrying the frame's latency, failed rows left
+  out of the percentiles.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import socket
+import sys
+import threading
+import time
+
+import numpy as np
+
+if __package__ in (None, ""):
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from cellbench import deploy, traffic, wire  # noqa: E402
+
+_DECIDED = np.zeros(256, bool)
+_DECIDED[list(deploy.DECISIONS)] = True
+BIN_S = 0.1  # reply-time bins of the admitted-token ledger
+N_STATUS = 16
+_SINGLE_POOL = 1 << 16
+_SENT_RING = 1 << 16
+
+
+class Ledger:
+    """What one run counts, shared by its connection threads."""
+
+    def __init__(self, dep, t0: float, t_end: float):
+        self.dep = dep
+        self.t0 = t0
+        self.t_end = t_end
+        self.lock = threading.Lock()
+        self.attempted = 0
+        self.failed = {"skipped": 0, "timeout": 0, "connection": 0,
+                       "status": 0, "brownout_pass": 0, "short_reply": 0}
+        self.decided_in_window = 0
+        self.decided = 0
+        self.status_hist = np.zeros(N_STATUS, np.int64)
+        self.unmetered_blocked = 0
+        self.duplicates = 0
+        n_bins = int((t_end - t0) / BIN_S) + 200
+        self.admitted = np.zeros(
+            (len(dep.metered_count_of_index()), n_bins), np.float64)
+        self.lat_max = np.zeros(n_bins)  # slowest reply of each bin
+        self.lat = []  # (latency seconds array, weight array)
+        self.lags = []
+
+    def rows_back(self, t: float, lat, ids, acq, status, remaining) -> None:
+        """Account the verdicts of rows that came back at ``t``; ``lat`` is
+        one latency for all of them or an array."""
+        st = status.astype(np.uint8)
+        metered = self.dep.is_metered(ids)
+        decided = _DECIDED[st]
+        brown = (st == deploy.OK) & (remaining == 0) & ~metered
+        good = decided & ~brown
+        n_good = int(good.sum())
+        ok_m = metered & (st == deploy.OK)
+        with self.lock:
+            self.status_hist += np.bincount(
+                np.minimum(st, N_STATUS - 1), minlength=N_STATUS)
+            self.failed["status"] += int((~decided).sum())
+            self.failed["brownout_pass"] += int(brown.sum())
+            self.unmetered_blocked += int(
+                ((st == deploy.BLOCKED) & ~metered).sum())
+            self.decided += n_good
+            if t <= self.t_end:
+                self.decided_in_window += n_good
+            b = min(max(int((t - self.t0) / BIN_S), 0),
+                    self.admitted.shape[1] - 1)
+            self.lat_max[b] = max(self.lat_max[b], float(np.max(lat)))
+            if ok_m.any():
+                np.add.at(self.admitted[:, b],
+                          self.dep.metered_index(ids[ok_m]), acq[ok_m])
+            if n_good:
+                if np.ndim(lat) == 0:
+                    self.lat.append((np.array([lat]), np.array([n_good])))
+                else:
+                    self.lat.append((np.asarray(lat)[good],
+                                     np.ones(n_good, np.int64)))
+
+    def fail(self, reason: str, rows: int) -> None:
+        with self.lock:
+            self.failed[reason] += int(rows)
+
+    def summary(self) -> dict:
+        lat = (np.concatenate([a for a, _ in self.lat]) if self.lat
+               else np.empty(0))
+        w = (np.concatenate([b for _, b in self.lat]) if self.lat
+             else np.empty(0, np.int64))
+        lags = np.concatenate(self.lags) if self.lags else np.empty(0)
+        return {
+            "attempted": int(self.attempted),
+            "failed": dict(self.failed),
+            "failed_rows": int(sum(self.failed.values())),
+            "decided": int(self.decided),
+            "decided_in_window": int(self.decided_in_window),
+            "status_hist": self.status_hist.tolist(),
+            "unmetered_blocked": int(self.unmetered_blocked),
+            "duplicates": int(self.duplicates),
+        }, {"lat_s": lat.astype(np.float64), "lat_w": w.astype(np.int64),
+            "lag_s": lags.astype(np.float64), "admitted": self.admitted,
+            "lat_max": self.lat_max}
+
+
+class Conn:
+    def __init__(self, port: int):
+        self.sock = socket.create_connection(("127.0.0.1", port), timeout=10)
+        self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+        self.sock.settimeout(0.2)
+        self.split = wire.Splitter()
+        self.dead = False
+
+    def send(self, data) -> bool:
+        try:
+            self.sock.settimeout(10.0)
+            self.sock.sendall(data)
+            self.sock.settimeout(0.2)
+            return True
+        except OSError:
+            self.dead = True
+            return False
+
+    def recv(self):
+        """``(batch, singles)``, ``None`` on a quiet 0.2 s, ``False`` when
+        the connection is gone."""
+        try:
+            data = self.sock.recv(1 << 18)
+        except socket.timeout:
+            return None
+        except OSError:
+            self.dead = True
+            return False
+        if not data:
+            self.dead = True
+            return False
+        return self.split.feed(data)
+
+    def close(self) -> None:
+        try:
+            self.sock.close()
+        except OSError:
+            pass
+
+
+class Generator:
+    def __init__(self, plan: dict):
+        self.plan = plan
+        self.t = plan["traffic"]
+        self.dep = deploy.Deployment(deploy.load_json(plan["config_file"]))
+        self.proc = int(plan["proc"])
+        self.n_procs = int(self.t["processes"])
+        self.seed = int(plan["seed"])
+        self.single = self.t["msg"] == "single"
+        self.open = self.t["loop"] == "open"
+        self.rows = 1 if self.single else int(self.t["frame_rows"])
+        self.timeout_s = float(self.t["timeout_ms"]) / 1000.0
+        self.next_xid = 1 + self.proc * 200_000_000
+        self.conns = []
+        self._build(float(plan["seconds"]), float(plan["warm_seconds"]))
+
+    # -- frames, made before the server is up -------------------------------
+    def _build(self, seconds: float, warm_seconds: float) -> None:
+        mix = traffic.Mix(self.t, self.dep, self.seed, 1 + self.proc)
+        if self.open:
+            due = traffic.open_schedule(self.t, seconds)
+            mine = np.arange(len(due)) % self.n_procs == self.proc
+            # tenants are apportioned over ALL frames, then split by process
+            who = traffic.Mix(self.t, self.dep, self.seed,
+                              0).frame_tenants(len(due))[mine]
+            self.main = (due[mine],) + mix.rows(who)
+            wdue = traffic.open_schedule(self.t, warm_seconds)
+            wmine = np.arange(len(wdue)) % self.n_procs == self.proc
+            self.warm = (wdue[wmine],) + mix.frames(int(wmine.sum()))
+        else:
+            n_pool = (_SINGLE_POOL * int(self.t["connections"]) if self.single
+                      else int(self.t.get("pool_frames", 512)))
+            self.pool = mix.frames(n_pool)
+        self.burst_mix = mix
+
+    def planned_rows(self) -> int:
+        return int(len(self.main[0]) * self.rows) if self.open else 0
+
+    def connect(self, port: int) -> None:
+        self.conns = [Conn(port) for _ in range(int(self.t["connections"]))]
+
+    def _xids(self, n: int) -> int:
+        x0 = self.next_xid
+        self.next_xid += n
+        return x0
+
+    # -- open loop -----------------------------------------------------------
+    def run_open(self, t0: float, due, ids, acq, window: int,
+                 seconds: float) -> Ledger:
+        n = len(due)
+        x0 = self._xids(n)
+        enc = traffic.encode_frames(ids, acq, x0)
+        led = Ledger(self.dep, t0, t0 + seconds)
+        led.attempted = n * self.rows
+        due_abs = t0 + due
+        was_sent = np.zeros(n, bool)
+        replied = np.zeros(n, bool)
+        state = {"inflight": 0, "done": False}
+        conns = self.conns
+
+        def reader(c: Conn) -> None:
+            while not state["done"] and not c.dead:
+                got = c.recv()
+                if not got:
+                    continue
+                now = time.monotonic()
+                for xid, rows in got[0]:
+                    k = xid - x0
+                    if not 0 <= k < n:
+                        continue
+                    with led.lock:
+                        dup = replied[k]
+                        replied[k] = True
+                        if dup:
+                            led.duplicates += 1
+                        else:
+                            state["inflight"] -= 1
+                    if dup:
+                        continue
+                    m = min(len(rows), self.rows)
+                    if m < self.rows:
+                        led.fail("short_reply", self.rows - m)
+                    led.rows_back(now, now - due_abs[k], ids[k][:m],
+                                  acq[k][:m], rows["status"][:m],
+                                  rows["remaining"][:m])
+
+        threads = [threading.Thread(target=reader, args=(c,), daemon=True)
+                   for c in conns]
+        for th in threads:
+            th.start()
+        lag = np.zeros(n)
+        for k in range(n):
+            wait = due_abs[k] - time.monotonic()
+            if wait > 0:
+                time.sleep(wait)
+            c = conns[k % len(conns)]
+            with led.lock:
+                full = state["inflight"] >= window
+                if not full and not c.dead:
+                    state["inflight"] += 1
+            if full:
+                led.fail("skipped", self.rows)
+                replied[k] = True
+                continue
+            if c.dead:
+                led.fail("connection", self.rows)
+                replied[k] = True
+                continue
+            now = time.monotonic()
+            was_sent[k] = True
+            lag[k] = now - due_abs[k]
+            if not c.send(enc[k]):
+                with led.lock:
+                    state["inflight"] -= 1
+                    replied[k] = True
+                led.fail("connection", self.rows)
+        deadline = max(due_abs[-1], time.monotonic()) + self.timeout_s
+        while time.monotonic() < deadline and not replied.all():
+            if all(c.dead for c in conns):
+                break
+            time.sleep(0.005)
+        state["done"] = True
+        for th in threads:
+            th.join(timeout=2.0)
+        lost = int((~replied).sum())
+        if lost:
+            dead_conn = np.array([conns[k % len(conns)].dead
+                                  for k in np.flatnonzero(~replied)])
+            led.fail("connection", int(dead_conn.sum()) * self.rows)
+            led.fail("timeout", int((~dead_conn).sum()) * self.rows)
+        led.lags.append(lag[was_sent])
+        return led
+
+    # -- closed loop ---------------------------------------------------------
+    def run_closed(self, t0: float, seconds: float) -> Ledger:
+        led = Ledger(self.dep, t0, t0 + seconds)
+        body = self._closed_single if self.single else self._closed_batch
+        threads = [
+            threading.Thread(target=body, args=(led, ci, c), daemon=True)
+            for ci, c in enumerate(self.conns)
+        ]
+        wait = t0 - time.monotonic()
+        if wait > 0:
+            time.sleep(wait)
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=seconds + self.timeout_s + 10)
+        return led
+
+    def _closed_batch(self, led: Ledger, ci: int, c: Conn) -> None:
+        ids, acq = self.pool
+        n_conn = len(self.conns)
+        depth = int(self.t["outstanding"])
+        x0 = self._xids_block()
+        pending = {}  # xid -> (pool index, sent at)
+        j = 0
+        attempted = 0
+
+        def send_one() -> bool:
+            nonlocal j, attempted
+            p = (ci + j * n_conn) % len(ids)
+            xid = x0 + j
+            j += 1
+            now = time.monotonic()
+            if now >= led.t_end:
+                return False
+            pending[xid] = (p, now)
+            attempted += self.rows
+            if not c.send(wire.encode_batch(xid, ids[p], acq[p])):
+                return False
+            return True
+
+        for _ in range(depth):
+            send_one()
+        give_up = led.t_end + self.timeout_s
+        while pending and not c.dead and time.monotonic() < give_up:
+            got = c.recv()
+            if not got:
+                continue
+            now = time.monotonic()
+            for xid, rows in got[0]:
+                item = pending.pop(xid, None)
+                if item is None:
+                    with led.lock:
+                        led.duplicates += 1
+                    continue
+                p, at = item
+                m = min(len(rows), self.rows)
+                if m < self.rows:
+                    led.fail("short_reply", self.rows - m)
+                led.rows_back(now, now - at, ids[p][:m], acq[p][:m],
+                              rows["status"][:m], rows["remaining"][:m])
+                send_one()
+        with led.lock:
+            led.attempted += attempted
+        if pending:
+            led.fail("connection" if c.dead else "timeout",
+                     len(pending) * self.rows)
+
+    def _xids_block(self) -> int:
+        """A range of xids for one connection's closed loop."""
+        with _XID_LOCK:
+            return self._xids(20_000_000)
+
+    def _closed_single(self, led: Ledger, ci: int, c: Conn) -> None:
+        ids_all, acq_all = self.pool
+        ids = ids_all[ci * _SINGLE_POOL:(ci + 1) * _SINGLE_POOL, 0]
+        acq = acq_all[ci * _SINGLE_POOL:(ci + 1) * _SINGLE_POOL, 0]
+        reqs = wire.encode_singles(0, ids, acq)
+        depth = int(self.t["outstanding"])
+        x0 = self._xids_block()
+        sent_at = np.zeros(_SENT_RING)
+        seq = 0  # requests sent so far
+        back = 0  # responses seen so far
+
+        def send(m: int) -> bool:
+            nonlocal seq
+            now = time.monotonic()
+            if now >= led.t_end or m <= 0:
+                return False
+            at = np.arange(seq, seq + m)
+            chunk = reqs[at % _SINGLE_POOL].copy()
+            chunk["xid"] = x0 + at
+            sent_at[at % _SENT_RING] = now
+            seq += m
+            return c.send(chunk.tobytes())
+
+        send(depth)
+        give_up = led.t_end + self.timeout_s
+        while back < seq and not c.dead and time.monotonic() < give_up:
+            got = c.recv()
+            if not got or got[1] is None:
+                continue
+            now = time.monotonic()
+            rsp = got[1]
+            k = rsp["xid"].astype(np.int64) - x0
+            known = (k >= 0) & (k < seq)
+            if not known.all():
+                with led.lock:
+                    led.duplicates += int((~known).sum())
+                rsp, k = rsp[known], k[known]
+            back += len(k)
+            led.rows_back(now, now - sent_at[k % _SENT_RING],
+                          ids[k % _SINGLE_POOL], acq[k % _SINGLE_POOL],
+                          rsp["status"], rsp["remaining"])
+            send(len(k))
+        with led.lock:
+            led.attempted += seq
+        if back < seq:
+            led.fail("connection" if c.dead else "timeout", seq - back)
+
+    # -- commands ------------------------------------------------------------
+    def cmd_warm(self) -> dict:
+        t0 = time.monotonic() + 0.05
+        secs = float(self.plan["warm_seconds"])
+        if self.open:
+            due, ids, acq = self.warm
+            led = self.run_open(t0, due, ids, acq,
+                                int(self.t["inflight_window_frames"]), secs)
+        else:
+            led = self.run_closed(t0, secs)
+        return led.summary()[0]
+
+    def cmd_burst(self, rows: int) -> dict:
+        """A backlog: ``rows`` rows of the mix at once (open loop), or the
+        closed loop's own in-flight cap for half a second."""
+        t0 = time.monotonic() + 0.02
+        if self.open:
+            n = max(1, rows // self.rows)
+            ids, acq = self.burst_mix.frames(n)
+            led = self.run_open(t0, np.zeros(n), ids, acq, n, 0.5)
+        else:
+            led = self.run_closed(t0, 0.5)
+        return led.summary()[0]
+
+    def cmd_measure(self, t0: float, seconds: float, out: str) -> dict:
+        # a sleeper beside the load: its gaps say whether this process was
+        # held up too when the server stood still (then the whole machine did)
+        gaps = []
+        on = threading.Event()
+        th = threading.Thread(target=tick_gaps, args=(gaps, on.is_set, t0),
+                              daemon=True)
+        th.start()
+        try:
+            summary = self._measure(t0, seconds, out)
+        finally:
+            on.set()
+            th.join(timeout=1.0)
+        summary["tick_gaps"] = gaps
+        return summary
+
+    def _measure(self, t0: float, seconds: float, out: str) -> dict:
+        if self.open:
+            due, ids, acq = self.main
+            led = self.run_open(t0, due, ids, acq,
+                                int(self.t["inflight_window_frames"]),
+                                seconds)
+        else:
+            led = self.run_closed(t0, seconds)
+        summary, arrays = led.summary()
+        np.savez(out, **arrays)
+        return summary
+
+
+_XID_LOCK = threading.Lock()
+TICK_S = 0.02
+TICK_GAP_S = 0.1
+
+
+def tick_gaps(gaps: list, stopped, t0: float, emit=None) -> None:
+    """Sleep ``TICK_S`` at a time until ``stopped()``; every sleep that took
+    over ``TICK_GAP_S`` goes to ``gaps`` as ``(start - t0, length)``."""
+    while not stopped():
+        t = time.monotonic()
+        time.sleep(TICK_S)
+        gap = time.monotonic() - t
+        if gap > TICK_GAP_S:
+            gaps.append((t - t0, gap))
+            if emit is not None:
+                emit(t, gap)
+
+
+def witness() -> None:
+    """A process that does nothing but sleep and say when it could not:
+    ``{"start": monotonic seconds, "len": seconds}`` a line, until its
+    standard input closes. It touches neither the server nor the load, so a
+    gap here at the moment of a gap there is the machine's."""
+    done = threading.Event()
+
+    def emit(t: float, gap: float) -> None:
+        print(json.dumps({"start": t, "len": gap}), flush=True)
+
+    th = threading.Thread(target=tick_gaps, args=([], done.is_set, 0.0, emit),
+                          daemon=True)
+    th.start()
+    print(json.dumps({"witness": True}), flush=True)
+    sys.stdin.read()
+    done.set()
+    th.join(timeout=1.0)
+
+
+def main() -> None:
+    if sys.argv[1] == "witness":
+        return witness()
+    plan = deploy.load_json(sys.argv[1])
+    gen = Generator(plan)
+    print(json.dumps({"built": True, "planned_rows": gen.planned_rows()}),
+          flush=True)
+    deadline = time.monotonic() + float(plan.get("port_wait_s", 1100))
+    while not os.path.exists(plan["port_file"]):
+        if time.monotonic() > deadline:
+            raise SystemExit("server port file never appeared")
+        time.sleep(0.05)
+    with open(plan["port_file"], encoding="utf-8") as f:
+        gen.connect(int(f.read().strip()))
+    print(json.dumps({"connected": len(gen.conns)}), flush=True)
+    for line in sys.stdin:
+        words = line.split()
+        if not words:
+            continue
+        if words[0] == "quit":
+            break
+        if words[0] == "warm":
+            out = gen.cmd_warm()
+        elif words[0] == "burst":
+            out = gen.cmd_burst(int(words[1]))
+        elif words[0] == "measure":
+            out = gen.cmd_measure(float(words[1]), float(words[2]), words[3])
+        else:
+            out = {"error": f"unknown command {words[0]!r}"}
+        print(json.dumps(out), flush=True)
+    for c in gen.conns:
+        c.close()
+
+
+if __name__ == "__main__":
+    main()

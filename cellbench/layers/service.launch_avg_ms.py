@@ -1,0 +1,18 @@
+"""Mean time the service lock is held per dispatch: the jitted call (argument
+transfer and enqueue), the engine clock, dirty-set bookkeeping: the server's ``launch_ms``
+phase histogram over the whole window. None where the program has no such
+histogram (a tree from before PR 24)."""
+
+NAME = "service.launch_avg_ms"
+UNIT = "ms"
+LAYER = "service"
+MOVES = "verdict_latency_p50_ms"
+SOURCE = "program_counter"
+
+
+def reduce(snap):
+    a = snap["before"]["stages"].get("launch_ms")
+    b = snap["after"]["stages"].get("launch_ms")
+    if a is None or b is None or b["count"] - a["count"] <= 0:
+        return None
+    return (b["sum"] - a["sum"]) / (b["count"] - a["count"])

@@ -561,7 +561,7 @@ class NativeTokenServer:
                         break
                     if item is self._SENTINEL:
                         continue
-                    pulls, lengths, _mat = item
+                    pulls, lengths = item[:2]
                     self.overload.note_done(sum(lengths))
                     _SM.count_shed("lane_abandon", sum(lengths))
                     for p in pulls:
@@ -810,7 +810,12 @@ class NativeTokenServer:
         for paths that never call ``mat`` (dispatch exception handled by
         the caller, abandoned-shutdown drop). ``overlapped`` reports
         whether the permit wait found earlier work still in flight."""
+        t0 = time.monotonic_ns()
         overlapped = self._acquire_device_permit()
+        waited_ns = time.monotonic_ns() - t0
+        _SM.permit_wait_ms.record(waited_ns * 1e-6)
+        if _TR.ARMED:  # flight recorder: permit granted (aux = wait, us)
+            _TR.record(_TR.PERMIT, aux=min(waited_ns // 1000, 2**31 - 1))
         done = [False]
 
         def release():
@@ -1056,8 +1061,11 @@ class NativeTokenServer:
                     # this group's whole dispatch arm ran while the prior
                     # group still computed — the pipelining win
                     _SM.count_overlap_saved_ms(dt_ms)
+                # the stamp rides the item: reply_queue_wait_ms runs from
+                # here to a reply lane's get() returning
                 if not self._lane_put(
-                    self._reply_q, (pulls, lengths, mat)
+                    self._reply_q,
+                    (pulls, lengths, mat, time.monotonic_ns()),
                 ):
                     # abandoned shutdown drop: nobody will materialize or
                     # answer these rows — account for them and park the
@@ -1093,7 +1101,10 @@ class NativeTokenServer:
             if item is self._SENTINEL:
                 rq.put(item)  # release sibling reply lanes
                 return
-            pulls, lengths, mat = item
+            pulls, lengths, mat, t_put = item
+            _SM.reply_queue_wait_ms.record(
+                (time.monotonic_ns() - t_put) * 1e-6
+            )
             t0 = time.perf_counter()
             try:
                 status, remaining, wait = mat()

@@ -26,6 +26,7 @@ import numpy as np
 
 from sentinel_tpu import chaos as _chaos
 from sentinel_tpu.core import clock as _clock
+from sentinel_tpu.core import compile_cache as _compile_cache
 from sentinel_tpu.engine import (
     ClusterFlowRule,
     DegradeRule,
@@ -50,6 +51,9 @@ from sentinel_tpu.metrics.stat_logger import log_cluster
 from sentinel_tpu.trace import ring as _TR
 
 _SM = server_metrics()
+# flight-recorder identity of a service (the ``shard`` field of its phase
+# events): dispatch sequence numbers are per service
+_SERVICE_IDS = itertools.count(1)
 
 
 class _PrepCache:
@@ -267,6 +271,11 @@ class DefaultTokenService(TokenService):
         lease_fraction: float = 0.5,
     ):
         self.config = config or EngineConfig()
+        _compile_cache.install_compile_listener()
+        # dispatches issued so far, bumped under the service lock: with
+        # _trace_sid it joins one dispatch's phase events across threads
+        self._dispatch_seq = 0
+        self._trace_sid = next(_SERVICE_IDS) & 0x7FFF
         # serving shape buckets: a lightly-loaded step pads to the smallest
         # bucket that fits instead of the full batch size (the decide cost is
         # shape-proportional — ~4× cheaper at 64 than 1024 — and state
@@ -912,6 +921,7 @@ class DefaultTokenService(TokenService):
         First-compile latency (~1s on CPU, tens of seconds on TPU) must not be
         paid by the first real request — it would blow the 20ms client budget
         *and* let early traffic slip through an expired window."""
+        _SM.set_warm(False)
         with self._lock:
             now = self._engine_now()
             # compile both serving variants (uniform acquire and mixed) for
@@ -978,6 +988,9 @@ class DefaultTokenService(TokenService):
                 jnp.int32(now),
                 idx_slim=idx_slim,
             )
+        # from here on a compile is one in front of live traffic: counted
+        # (compiles_after_warmup_total) and logged by name
+        _SM.set_warm(True)
 
     def request_token(self, flow_id, acquire=1, prioritized=False) -> TokenResult:
         return self.request_batch([(flow_id, acquire, prioritized)])[0]
@@ -1038,7 +1051,7 @@ class DefaultTokenService(TokenService):
         """
         if _chaos.ARMED:  # device_stall injection: a slow/preempted step
             _chaos.maybe_sleep("device_stall")
-        t_dispatch = time.monotonic()
+        t_enter = time.monotonic_ns()
         flow_ids = np.asarray(flow_ids, np.int64)
         n = flow_ids.shape[0]
         if n == 0:
@@ -1075,8 +1088,11 @@ class DefaultTokenService(TokenService):
         step = self._step_fn(bucket, uniform)
         slots_ns = slots  # pre-mask slots: verdict→namespace attribution
         moved_mask = moved_epochs = None
+        t_prep = time.monotonic_ns()
         # -- device step: the only serialized section --
         with self._lock:
+            t_locked = time.monotonic_ns()
+            seq = self._dispatch_seq = self._dispatch_seq + 1
             if self._lookup is not lookup_snap:
                 # rules reloaded between prep and step: slot assignments may
                 # have moved, so redo the slot-dependent prep against the
@@ -1111,12 +1127,13 @@ class DefaultTokenService(TokenService):
                     self._dirty.setdefault("breaker", set()).update(
                         s for s in touched if s in self._breaker_slots
                     )
-        if _TR.ARMED:  # flight recorder: device step submitted
-            _TR.record(_TR.DEVICE_IN, aux=n)
+        self._dispatched(t_enter, t_prep, t_locked, seq, n)
 
         def _materialize():
             # blocks on the async dispatch; runs outside the lock
+            t_mat = time.monotonic_ns()
             status_sorted = np.asarray(verdicts.status)[:n]
+            t_ready = time.monotonic_ns()
             remaining_sorted = np.asarray(verdicts.remaining)[:n]
             wait_sorted = np.asarray(verdicts.wait_ms)[:n]
             if order is None:
@@ -1143,43 +1160,79 @@ class DefaultTokenService(TokenService):
                 ha_metrics().count_rebalance_redirects(
                     int(moved_mask.sum())
                 )
-            # per-namespace verdict counters (sentinel_server_verdicts_total):
-            # attribute each request's verdict to its rule's namespace via
-            # the lock-free slot→namespace snapshot. `slots_ns` is request-
-            # order and PRE-mask, so MOVED verdicts land on their namespace.
-            ns_names, slot_ns = self._ns_snapshot
-            ns_idx = np.where(
-                slots_ns >= 0, slot_ns[np.maximum(slots_ns, 0)], np.int32(-1)
+            self._account(
+                status, wait, slots_ns, seq, n, t_enter, t_mat, t_ready
             )
-            _SM.record_verdict_batch(
-                status, ns_idx, ns_names,
-                latency_ms=(time.monotonic() - t_dispatch) * 1e3,
-                wait_ms=wait,
-            )
-            if _TR.ARMED:  # flight recorder: device step materialized
-                _TR.record(_TR.DEVICE_OUT, aux=n)
-            # cluster server stat log (ClusterServerStatLogUtil analog): one
-            # aggregated counter per verdict class per window
-            n_degraded = 0
-            for event, code in (
-                ("pass", int(TokenStatus.OK)),
-                ("block", int(TokenStatus.BLOCKED)),
-                ("occupied", int(TokenStatus.SHOULD_WAIT)),
-                ("tooManyRequest", int(TokenStatus.TOO_MANY_REQUEST)),
-                ("degraded", int(TokenStatus.DEGRADED)),
-            ):
-                hits = int((status == code).sum())
-                if hits:
-                    log_cluster(event, count=hits)
-                    if event == "degraded":
-                        n_degraded = hits
-            if n_degraded:
-                # breaker activity observed: fold the device transitions
-                # into the host transition counters / blackbox plane
-                self._breaker_scan()
             return status, remaining, wait
 
         return _materialize
+
+    def _dispatched(self, t_enter, t_prep, t_locked, seq, rows) -> None:
+        """One dispatch left the service lock: its three dispatch-side
+        phases (``monotonic_ns`` stamps of entry, prep done, lock acquired)
+        go to the always-on histograms and, armed, to the flight recorder
+        with the same stamps."""
+        t_out = time.monotonic_ns()
+        _SM.prep_ms.record((t_prep - t_enter) * 1e-6)
+        _SM.lock_wait_ms.record((t_locked - t_prep) * 1e-6)
+        _SM.launch_ms.record((t_out - t_locked) * 1e-6)
+        if _TR.ARMED:
+            sid, aux = self._trace_sid, seq & 0x7FFFFFFF
+            _TR.record(_TR.PREP, shard=sid, aux=aux, t_ns=t_prep)
+            _TR.record(_TR.LOCKED, shard=sid, aux=aux, t_ns=t_locked)
+            _TR.record(_TR.DEVICE_IN, aux=rows, t_ns=t_out)
+
+    def _account(
+        self, status, wait, slots_ns, seq, rows, t_enter, t_mat, t_ready
+    ) -> None:
+        """The accounting tail of a materializer, and its three phases.
+        ``slots_ns`` is request-order and PRE-mask, so MOVED verdicts land
+        on their namespace (a fused span passes its frames' slots as a
+        list); ``t_enter``/``t_mat``/``t_ready`` are the
+        ``monotonic_ns`` stamps of the dispatch's entry, the materializer's
+        entry and the first verdict array reaching the host."""
+        t_fetched = time.monotonic_ns()
+        if isinstance(slots_ns, list):
+            slots_ns = np.concatenate(slots_ns)
+        # per-namespace verdict counters (sentinel_server_verdicts_total):
+        # attribute each request's verdict to its rule's namespace via the
+        # lock-free slot→namespace snapshot
+        ns_names, slot_ns = self._ns_snapshot
+        ns_idx = np.where(
+            slots_ns >= 0, slot_ns[np.maximum(slots_ns, 0)], np.int32(-1)
+        )
+        _SM.record_verdict_batch(
+            status, ns_idx, ns_names,
+            latency_ms=(time.monotonic_ns() - t_enter) * 1e-6,
+            wait_ms=wait,
+        )
+        if _TR.ARMED:  # flight recorder: verdicts on the host and counted
+            sid, aux = self._trace_sid, seq & 0x7FFFFFFF
+            _TR.record(_TR.READY, shard=sid, aux=aux, t_ns=t_ready)
+            _TR.record(_TR.FETCHED, shard=sid, aux=aux, t_ns=t_fetched)
+            _TR.record(_TR.DEVICE_OUT, aux=rows)
+        # cluster server stat log (ClusterServerStatLogUtil analog): one
+        # aggregated counter per verdict class per window
+        n_degraded = 0
+        for event, code in (
+            ("pass", int(TokenStatus.OK)),
+            ("block", int(TokenStatus.BLOCKED)),
+            ("occupied", int(TokenStatus.SHOULD_WAIT)),
+            ("tooManyRequest", int(TokenStatus.TOO_MANY_REQUEST)),
+            ("degraded", int(TokenStatus.DEGRADED)),
+        ):
+            hits = int((status == code).sum())
+            if hits:
+                log_cluster(event, count=hits)
+                if event == "degraded":
+                    n_degraded = hits
+        if n_degraded:
+            # breaker activity observed: fold the device transitions
+            # into the host transition counters / blackbox plane
+            self._breaker_scan()
+        _SM.device_wait_ms.record((t_ready - t_mat) * 1e-6)
+        _SM.fetch_ms.record((t_fetched - t_ready) * 1e-6)
+        _SM.account_ms.record((time.monotonic_ns() - t_fetched) * 1e-6)
 
     def _dispatch_oversized(self, flow_ids, acq, pr, n, cap):
         """Split an oversized burst into ``cap``-sized frames and fold runs
@@ -1236,7 +1289,7 @@ class DefaultTokenService(TokenService):
         one pull arrived together, so this only collapses sub-millisecond
         clock skew a per-frame loop would have read anyway.
         """
-        t_dispatch = time.monotonic()
+        t_enter = time.monotonic_ns()
         lookup_snap = self._lookup
         # a fused span is uniform only if acquire is constant across ALL its
         # frames; mixed spans scan the general (refining) body for every
@@ -1273,8 +1326,11 @@ class DefaultTokenService(TokenService):
         _fill(preps)
         step = self._fused_step_fn(depth, uniform)
         moved_span = moved_epochs_span = span_ns = None
+        t_prep = time.monotonic_ns()
         # -- device step: the only serialized section --
         with self._lock:
+            t_locked = time.monotonic_ns()
+            seq = self._dispatch_seq = self._dispatch_seq + 1
             if self._lookup is not lookup_snap:
                 # rules reloaded between prep and step (see
                 # dispatch_batch_arrays): redo slot-dependent prep against
@@ -1340,16 +1396,18 @@ class DefaultTokenService(TokenService):
                     self._dirty.setdefault("breaker", set()).update(
                         s for s in touched if s in self._breaker_slots
                     )
+        self._dispatched(t_enter, t_prep, t_locked, seq, depth * cap)
         _SM.record_fused(depth)
         if _TR.ARMED:  # flight recorder: fused group submitted
             _TR.record(_TR.FUSE, aux=depth)
-            _TR.record(_TR.DEVICE_IN, aux=depth * cap)
 
         def _materialize():
             # blocks on the async dispatch; runs outside the lock. Verdict
             # leaves are [depth, cap]; unsort each frame back to request
             # order and lay the frames out contiguously.
+            t_mat = time.monotonic_ns()
             status_all = np.asarray(verdicts.status)
+            t_ready = time.monotonic_ns()
             remaining_all = np.asarray(verdicts.remaining)
             wait_all = np.asarray(verdicts.wait_ms)
             # verdicts are ready → the device has consumed the staging
@@ -1378,40 +1436,13 @@ class DefaultTokenService(TokenService):
                     int(moved_span.sum())
                 )
             # per-namespace verdict counters + cluster stat log, once for
-            # the whole span (mirrors dispatch_batch_arrays._materialize);
-            # span_ns is the PRE-mask slot span when a move masked rows
-            slots_span = (
-                span_ns if span_ns is not None
-                else np.concatenate([p[0] for p in preps])
+            # the whole span; span_ns is the PRE-mask slot span when a move
+            # masked rows
+            self._account(
+                status, wait,
+                span_ns if span_ns is not None else [p[0] for p in preps],
+                seq, total, t_enter, t_mat, t_ready,
             )
-            ns_names, slot_ns = self._ns_snapshot
-            ns_idx = np.where(
-                slots_span >= 0,
-                slot_ns[np.maximum(slots_span, 0)],
-                np.int32(-1),
-            )
-            _SM.record_verdict_batch(
-                status, ns_idx, ns_names,
-                latency_ms=(time.monotonic() - t_dispatch) * 1e3,
-                wait_ms=wait,
-            )
-            if _TR.ARMED:  # flight recorder: fused group materialized
-                _TR.record(_TR.DEVICE_OUT, aux=depth * cap)
-            n_degraded = 0
-            for event, code in (
-                ("pass", int(TokenStatus.OK)),
-                ("block", int(TokenStatus.BLOCKED)),
-                ("occupied", int(TokenStatus.SHOULD_WAIT)),
-                ("tooManyRequest", int(TokenStatus.TOO_MANY_REQUEST)),
-                ("degraded", int(TokenStatus.DEGRADED)),
-            ):
-                hits = int((status == code).sum())
-                if hits:
-                    log_cluster(event, count=hits)
-                    if event == "degraded":
-                        n_degraded = hits
-            if n_degraded:
-                self._breaker_scan()
             return status, remaining, wait
 
         return _materialize

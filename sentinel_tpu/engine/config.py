@@ -46,3 +46,11 @@ class EngineConfig(NamedTuple):
     @property
     def interval_ms(self) -> int:
         return self.bucket_ms * self.n_buckets
+
+
+def named(fn, name: str):
+    """``fn`` under ``name``: what ``jax.jit`` calls the program it builds
+    from it (``jit_<name>`` in a device trace, ``fun_name`` in a compile
+    event). A ``functools.partial`` has no name and reads ``jit__unknown``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn

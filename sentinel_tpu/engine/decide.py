@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sentinel_tpu.engine.config import EngineConfig
+from sentinel_tpu.engine.config import EngineConfig, named
 from sentinel_tpu.engine.rules import RuleTable, ThresholdMode
 from sentinel_tpu.engine.state import (
     ClusterEvent,
@@ -375,29 +375,36 @@ def _decide_core(
 
     if axis_name is not None:
         offset = jax.lax.axis_index(axis_name).astype(jnp.int32) * f_local
-        psum = partial(jax.lax.psum, axis_name=axis_name)
+
+        def psum(x):
+            # every [N]-sized collective that stitches the shards together
+            # reads <arm>/psum_stitch in a device trace
+            with jax.named_scope("psum_stitch"):
+                return jax.lax.psum(x, axis_name=axis_name)
+
         pmax = partial(jax.lax.pmax, axis_name=axis_name)
     else:
         offset = jnp.int32(0)
         psum = lambda x: x  # noqa: E731
         pmax = lambda x: x  # noqa: E731
 
-    local_slot = batch.flow_slot - offset
-    in_range = (batch.flow_slot >= 0) & (local_slot >= 0) & (local_slot < f_local)
-    safe_slot = jnp.where(in_range, local_slot, 0)
-    owned = in_range & rules.valid[safe_slot]
-    has_rule = psum(owned.astype(jnp.int32)) > 0
-    live = batch.valid & has_rule
-    no_rule = batch.valid & ~has_rule
+    with jax.named_scope("roll_guard"):
+        local_slot = batch.flow_slot - offset
+        in_range = (batch.flow_slot >= 0) & (local_slot >= 0) & (local_slot < f_local)
+        safe_slot = jnp.where(in_range, local_slot, 0)
+        owned = in_range & rules.valid[safe_slot]
+        has_rule = psum(owned.astype(jnp.int32)) > 0
+        live = batch.valid & has_rule
+        no_rule = batch.valid & ~has_rule
 
-    acquire_f = batch.acquire.astype(jnp.float32)
+        acquire_f = batch.acquire.astype(jnp.float32)
 
-    ns_id, ns_ok, seg_ns_sum = _ns_guard(
-        config, spec, state.ns, rules, now, psum, owned, safe_slot, live
-    )
-    too_many = live & ~ns_ok
-    ns_admitted = live & ns_ok  # global mask — identical on every device
-    active = ns_admitted & owned  # flow evaluation happens on the owner
+        ns_id, ns_ok, seg_ns_sum = _ns_guard(
+            config, spec, state.ns, rules, now, psum, owned, safe_slot, live
+        )
+        too_many = live & ~ns_ok
+        ns_admitted = live & ns_ok  # global mask — identical on every device
+        active = ns_admitted & owned  # flow evaluation happens on the owner
 
     if config.prefix_impl == "grouped":
         # "grouped" is only sound when the host batcher sorted the batch —
@@ -422,27 +429,29 @@ def _decide_core(
     #     inside breaker_gate on a mesh-uniform "any breaker row"
     #     predicate.
     # ------------------------------------------------------------------
-    degraded, br_retry, breaker_ws = _breaker_gate(
-        config, spec, state, rules, now, safe_slot, active, flow_prefix, psum
-    )
-    active = active & ~degraded
+    with jax.named_scope("breaker"):
+        degraded, br_retry, breaker_ws = _breaker_gate(
+            config, spec, state, rules, now, safe_slot, active, flow_prefix, psum
+        )
+        active = active & ~degraded
 
     # ------------------------------------------------------------------
     # 2. per-request threshold (ClusterFlowChecker.java:38-48)
     # ------------------------------------------------------------------
-    conn = rules.ns_connected[ns_id].astype(jnp.float32)
-    factor = jnp.where(
-        rules.mode[safe_slot] == int(ThresholdMode.AVG_LOCAL), conn, 1.0
-    )
+    with jax.named_scope("threshold"):
+        conn = rules.ns_connected[ns_id].astype(jnp.float32)
+        factor = jnp.where(
+            rules.mode[safe_slot] == int(ThresholdMode.AVG_LOCAL), conn, 1.0
+        )
 
-    passed = (
-        W.window_sum_at(spec, state.flow, now, ClusterEvent.PASS, safe_slot)
-        + W.window_sum_at(spec, state.occupy, now, 0, safe_slot)  # matured borrows
-        # wire rev 5: tokens delegated to clients as local-admission leases
-        # are pre-paid — charged at grant time — so they occupy the window
-        # exactly like passed tokens until they expire or are credited back
-        + W.window_sum_at(spec, state.flow, now, ClusterEvent.LEASED, safe_slot)
-    ).astype(jnp.float32)
+        passed = (
+            W.window_sum_at(spec, state.flow, now, ClusterEvent.PASS, safe_slot)
+            + W.window_sum_at(spec, state.occupy, now, 0, safe_slot)  # matured borrows
+            # wire rev 5: tokens delegated to clients as local-admission leases
+            # are pre-paid — charged at grant time — so they occupy the window
+            # exactly like passed tokens until they expire or are credited back
+            + W.window_sum_at(spec, state.flow, now, ClusterEvent.LEASED, safe_slot)
+        ).astype(jnp.float32)
 
     # ------------------------------------------------------------------
     # 2b. traffic shaping (FlowRule.controlBehavior): WARM_UP modulates the
@@ -452,75 +461,77 @@ def _decide_core(
     #     batch" predicates, so a reject-only batch pays two [N] psums and
     #     nothing else.
     # ------------------------------------------------------------------
-    beh = rules.behavior[safe_slot].astype(jnp.int32)
-    is_warm = (beh == 1) | (beh == 3)
-    is_pace = (beh == 2) | (beh == 3)
-    warm_rows = active & is_warm
-    pace_try = active & is_pace
-    active_window = active & ~is_pace
-    any_warm = jnp.any(psum(warm_rows.astype(jnp.int32)) > 0)
-    any_pace = jnp.any(psum(pace_try.astype(jnp.int32)) > 0)
+    with jax.named_scope("shaping"):
+        beh = rules.behavior[safe_slot].astype(jnp.int32)
+        is_warm = (beh == 1) | (beh == 3)
+        is_pace = (beh == 2) | (beh == 3)
+        warm_rows = active & is_warm
+        pace_try = active & is_pace
+        active_window = active & ~is_pace
+        any_warm = jnp.any(psum(warm_rows.astype(jnp.int32)) > 0)
+        any_pace = jnp.any(psum(pace_try.astype(jnp.int32)) > 0)
 
-    cnt = rules.count[safe_slot]
-    cnt_safe = jnp.maximum(cnt, 1e-6)
+        cnt = rules.count[safe_slot]
+        cnt_safe = jnp.maximum(cnt, 1e-6)
 
-    def warm_on(_):
-        qps_, tokens_new, do_sync, cur_sec = _warmup_curve(
-            spec, now, passed, cnt, cnt_safe,
-            rules.warning_token[safe_slot],
-            rules.max_token[safe_slot],
-            rules.slope[safe_slot],
-            rules.cold_count[safe_slot],
-            state.shaping.warm_filled[safe_slot],
-            state.shaping.warm_tokens[safe_slot],
-            warm_rows,
+        def warm_on(_):
+            qps_, tokens_new, do_sync, cur_sec = _warmup_curve(
+                spec, now, passed, cnt, cnt_safe,
+                rules.warning_token[safe_slot],
+                rules.max_token[safe_slot],
+                rules.slope[safe_slot],
+                rules.cold_count[safe_slot],
+                state.shaping.warm_filled[safe_slot],
+                state.shaping.warm_tokens[safe_slot],
+                warm_rows,
+            )
+            # duplicate same-flow rows scatter identical values (pure function
+            # of state + now), so .set stays deterministic
+            scat = jnp.where(do_sync, safe_slot, f_local)
+            wt = state.shaping.warm_tokens.at[scat].set(tokens_new, mode="drop")
+            wf = state.shaping.warm_filled.at[scat].set(cur_sec, mode="drop")
+            return qps_, wt, wf
+
+        def warm_off(_):
+            return cnt, state.shaping.warm_tokens, state.shaping.warm_filled
+
+        qps, warm_tokens_ws, warm_filled_ws = jax.lax.cond(
+            any_warm, warm_on, warm_off, None
         )
-        # duplicate same-flow rows scatter identical values (pure function
-        # of state + now), so .set stays deterministic
-        scat = jnp.where(do_sync, safe_slot, f_local)
-        wt = state.shaping.warm_tokens.at[scat].set(tokens_new, mode="drop")
-        wf = state.shaping.warm_filled.at[scat].set(cur_sec, mode="drop")
-        return qps_, wt, wf
 
-    def warm_off(_):
-        return cnt, state.shaping.warm_tokens, state.shaping.warm_filled
-
-    qps, warm_tokens_ws, warm_filled_ws = jax.lax.cond(
-        any_warm, warm_on, warm_off, None
-    )
-
-    # rule count is per-second (ClusterMetric.getAvg divides by interval
-    # seconds before comparing); the window budget scales by interval length
-    rate_qps = qps * factor * config.exceed_count
-    threshold = rate_qps * (spec.interval_ms / 1000.0)
+        # rule count is per-second (ClusterMetric.getAvg divides by interval
+        # seconds before comparing); the window budget scales by interval length
+        rate_qps = qps * factor * config.exceed_count
+        threshold = rate_qps * (spec.interval_ms / 1000.0)
 
     # ------------------------------------------------------------------
     # 3. prefix-sum admission (odd refinement count ⇒ ⊆ sequential-exact)
     # ------------------------------------------------------------------
-    if uniform:
-        # closed-form greedy admission: with one acquire size `a` per batch,
-        # the admitted set of each flow is exactly its first
-        # floor((threshold - passed)/a) active requests
-        a = jnp.max(jnp.where(live, batch.acquire, 0)).astype(jnp.float32)
-        a_safe = jnp.maximum(a, 1.0)
-        rank = flow_prefix(active_window.astype(jnp.float32))
-        admit = active_window & (passed + rank * a + a <= threshold)
-        quota = jnp.floor(jnp.maximum(threshold - passed, 0.0) / a_safe)
-        admitted_prefix = jnp.minimum(rank, quota) * a
-    else:
-        admit = active_window
-        iters = config.admission_refine_iters
-        if iters % 2 == 0:
-            raise ValueError(
-                "admission_refine_iters must be odd: an odd iteration count "
-                "makes the final admission mask a subset of the "
-                "sequential-greedy set (no-overshoot guarantee)"
-            )
-        for _ in range(iters):
-            contrib = jnp.where(admit, acquire_f, 0.0)
-            prefix = flow_prefix(contrib)  # earlier admitted same-flow tokens
-            admit = active_window & (passed + prefix + acquire_f <= threshold)
-        admitted_prefix = flow_prefix(jnp.where(admit, acquire_f, 0.0))
+    with jax.named_scope("admit"):
+        if uniform:
+            # closed-form greedy admission: with one acquire size `a` per batch,
+            # the admitted set of each flow is exactly its first
+            # floor((threshold - passed)/a) active requests
+            a = jnp.max(jnp.where(live, batch.acquire, 0)).astype(jnp.float32)
+            a_safe = jnp.maximum(a, 1.0)
+            rank = flow_prefix(active_window.astype(jnp.float32))
+            admit = active_window & (passed + rank * a + a <= threshold)
+            quota = jnp.floor(jnp.maximum(threshold - passed, 0.0) / a_safe)
+            admitted_prefix = jnp.minimum(rank, quota) * a
+        else:
+            admit = active_window
+            iters = config.admission_refine_iters
+            if iters % 2 == 0:
+                raise ValueError(
+                    "admission_refine_iters must be odd: an odd iteration count "
+                    "makes the final admission mask a subset of the "
+                    "sequential-greedy set (no-overshoot guarantee)"
+                )
+            for _ in range(iters):
+                contrib = jnp.where(admit, acquire_f, 0.0)
+                prefix = flow_prefix(contrib)  # earlier admitted same-flow tokens
+                admit = active_window & (passed + prefix + acquire_f <= threshold)
+            admitted_prefix = flow_prefix(jnp.where(admit, acquire_f, 0.0))
 
     # ------------------------------------------------------------------
     # 3b. pacing (RateLimiterController.canPass as a batch closed form):
@@ -536,58 +547,59 @@ def _decide_core(
     #     arithmetic is done relative to `now` so f32 stays exact (engine
     #     ms exceeds the f32 integer range after ~4.6h; waits never do).
     # ------------------------------------------------------------------
-    def pace_on(_):
-        cost_f = jnp.round(1000.0 * acquire_f / jnp.maximum(rate_qps, 1e-6))
-        rel0 = jnp.maximum(
-            state.shaping.lpt[safe_slot] - now, jnp.int32(-(2**20))
-        ).astype(jnp.float32)
-        maxq = rules.max_queue_ms[safe_slot].astype(jnp.float32)
+    with jax.named_scope("pacing"):
+        def pace_on(_):
+            cost_f = jnp.round(1000.0 * acquire_f / jnp.maximum(rate_qps, 1e-6))
+            rel0 = jnp.maximum(
+                state.shaping.lpt[safe_slot] - now, jnp.int32(-(2**20))
+            ).astype(jnp.float32)
+            maxq = rules.max_queue_ms[safe_slot].astype(jnp.float32)
 
-        def pace_pass(accept):
-            contrib = jnp.where(accept, cost_f, 0.0)
-            # a row's own cost always counts toward its hypothetical
-            # schedule (contrib only carries it into LATER rows' prefixes) —
-            # otherwise a rejected row sheds its own cost and oscillates
-            # back into the accepted set on the next refinement pass
-            incl = flow_prefix(contrib) + cost_f
-            rank_p = flow_prefix(accept.astype(jnp.float32))
-            first = accept & (rank_p == 0.0)
-            scat_first = jnp.where(first, safe_slot, f_local)
-            c_first = jnp.zeros((f_local,), jnp.float32).at[scat_first].set(
-                cost_f, mode="drop"
-            )[safe_slot]
-            # L_row - now, directly: base_rel = max(L0 - now, -cost_first)
-            l_rel = jnp.maximum(rel0, -c_first) + incl
-            return l_rel
+            def pace_pass(accept):
+                contrib = jnp.where(accept, cost_f, 0.0)
+                # a row's own cost always counts toward its hypothetical
+                # schedule (contrib only carries it into LATER rows' prefixes) —
+                # otherwise a rejected row sheds its own cost and oscillates
+                # back into the accepted set on the next refinement pass
+                incl = flow_prefix(contrib) + cost_f
+                rank_p = flow_prefix(accept.astype(jnp.float32))
+                first = accept & (rank_p == 0.0)
+                scat_first = jnp.where(first, safe_slot, f_local)
+                c_first = jnp.zeros((f_local,), jnp.float32).at[scat_first].set(
+                    cost_f, mode="drop"
+                )[safe_slot]
+                # L_row - now, directly: base_rel = max(L0 - now, -cost_first)
+                l_rel = jnp.maximum(rel0, -c_first) + incl
+                return l_rel
 
-        accept = pace_try
-        l_rel = pace_pass(accept)
-        for _i in range(0 if uniform else config.admission_refine_iters):
-            accept = pace_try & (l_rel <= maxq)
+            accept = pace_try
             l_rel = pace_pass(accept)
-        accept = pace_try & (l_rel <= maxq)
-        wait_i = jnp.maximum(l_rel, 0.0).astype(jnp.int32)
-        # scatter-max: the last accepted row's schedule is the flow's new
-        # latestPassedTime; non-accepted rows leave it untouched
-        scat = jnp.where(accept, safe_slot, f_local)
-        lpt_ = state.shaping.lpt.at[scat].max(
-            now + jnp.round(l_rel).astype(jnp.int32), mode="drop"
-        )
-        return accept, wait_i, lpt_
+            for _i in range(0 if uniform else config.admission_refine_iters):
+                accept = pace_try & (l_rel <= maxq)
+                l_rel = pace_pass(accept)
+            accept = pace_try & (l_rel <= maxq)
+            wait_i = jnp.maximum(l_rel, 0.0).astype(jnp.int32)
+            # scatter-max: the last accepted row's schedule is the flow's new
+            # latestPassedTime; non-accepted rows leave it untouched
+            scat = jnp.where(accept, safe_slot, f_local)
+            lpt_ = state.shaping.lpt.at[scat].max(
+                now + jnp.round(l_rel).astype(jnp.int32), mode="drop"
+            )
+            return accept, wait_i, lpt_
 
-    def pace_off(_):
-        return (
-            jnp.zeros((N,), bool),
-            jnp.zeros((N,), jnp.int32),
-            state.shaping.lpt,
-        )
+        def pace_off(_):
+            return (
+                jnp.zeros((N,), bool),
+                jnp.zeros((N,), jnp.int32),
+                state.shaping.lpt,
+            )
 
-    pace_admit, pace_wait, lpt_ws = jax.lax.cond(
-        any_pace, pace_on, pace_off, None
-    )
-    pace_now = pace_admit & (pace_wait == 0)
-    pace_later = pace_admit & (pace_wait > 0)
-    pace_reject = pace_try & ~pace_admit
+        pace_admit, pace_wait, lpt_ws = jax.lax.cond(
+            any_pace, pace_on, pace_off, None
+        )
+        pace_now = pace_admit & (pace_wait == 0)
+        pace_later = pace_admit & (pace_wait > 0)
+        pace_reject = pace_try & ~pace_admit
 
     # ------------------------------------------------------------------
     # 4. priority occupy of the next window (ClusterFlowChecker.java:84-97)
@@ -596,38 +608,39 @@ def _decide_core(
     #    property of the replicated batch and therefore a mesh-uniform
     #    predicate (safe around the pmax inside add_future)
     # ------------------------------------------------------------------
-    blocked = active_window & ~admit
-    wait_next = spec.bucket_ms - (now % spec.bucket_ms)
-    any_prio = jnp.any(batch.prioritized & batch.valid)
-    # occupy borrowing stays a DEFAULT-behavior feature: a shaped rule's
-    # admission curve is the whole point, and the reference's shapers have
-    # no occupy interplay either
-    try_occupy = blocked & batch.prioritized & (beh == 0)
+    with jax.named_scope("occupy"):
+        blocked = active_window & ~admit
+        wait_next = spec.bucket_ms - (now % spec.bucket_ms)
+        any_prio = jnp.any(batch.prioritized & batch.valid)
+        # occupy borrowing stays a DEFAULT-behavior feature: a shaped rule's
+        # admission curve is the whole point, and the reference's shapers have
+        # no occupy interplay either
+        try_occupy = blocked & batch.prioritized & (beh == 0)
 
-    def occupy_check(_):
-        next_start = now + wait_next
-        # currently-valid PASS tokens that will have expired by the next window
-        horizon = next_start - spec.interval_ms
-        cur_valid = W.valid_mask(spec, state.flow, now)
-        expiring_mask = cur_valid & (state.flow.starts <= horizon)
-        pass_rows = state.flow.counts[safe_slot, :, ClusterEvent.PASS]  # [N, B]
-        expiring = jnp.sum(
-            pass_rows * expiring_mask[None, :].astype(pass_rows.dtype), axis=1
-        ).astype(jnp.float32)
-        waiting = W.future_sum_at(spec, state.occupy, now, 0, safe_slot).astype(
-            jnp.float32
-        )
-        occ_contrib = jnp.where(try_occupy, acquire_f, 0.0)
-        occ_prefix = flow_prefix(occ_contrib)  # conservative: all triers count
-        return _occupy_feasible(
-            config, try_occupy, passed, expiring, admitted_prefix, waiting,
-            occ_prefix, acquire_f, threshold,
-        )
+        def occupy_check(_):
+            next_start = now + wait_next
+            # currently-valid PASS tokens that will have expired by the next window
+            horizon = next_start - spec.interval_ms
+            cur_valid = W.valid_mask(spec, state.flow, now)
+            expiring_mask = cur_valid & (state.flow.starts <= horizon)
+            pass_rows = state.flow.counts[safe_slot, :, ClusterEvent.PASS]  # [N, B]
+            expiring = jnp.sum(
+                pass_rows * expiring_mask[None, :].astype(pass_rows.dtype), axis=1
+            ).astype(jnp.float32)
+            waiting = W.future_sum_at(spec, state.occupy, now, 0, safe_slot).astype(
+                jnp.float32
+            )
+            occ_contrib = jnp.where(try_occupy, acquire_f, 0.0)
+            occ_prefix = flow_prefix(occ_contrib)  # conservative: all triers count
+            return _occupy_feasible(
+                config, try_occupy, passed, expiring, admitted_prefix, waiting,
+                occ_prefix, acquire_f, threshold,
+            )
 
-    can_occupy = jax.lax.cond(
-        any_prio, occupy_check, lambda _: jnp.zeros((N,), bool), None
-    )
-    hard_block = blocked & ~can_occupy
+        can_occupy = jax.lax.cond(
+            any_prio, occupy_check, lambda _: jnp.zeros((N,), bool), None
+        )
+        hard_block = blocked & ~can_occupy
 
     # ------------------------------------------------------------------
     # 5. window updates: one scatter per static event channel (the layout
@@ -640,120 +653,122 @@ def _decide_core(
     # paced rows with a wait charge the future window below (like occupy
     # borrows — they fold into the PASS read when their window matures, so
     # they are never double-counted); paced rejects count as BLOCK
-    admit_i = (admit | pace_now).astype(jnp.int32)
-    hard_i = (hard_block | pace_reject).astype(jnp.int32)
-    ev = ClusterEvent
-    row_updates = jnp.stack(
-        [
-            batch.acquire * admit_i,  # PASS
-            admit_i,  # PASS_REQUEST
-            batch.acquire * hard_i,  # BLOCK
-            hard_i,  # BLOCK_REQUEST
-        ],
-        axis=1,
-    )
-    flow_ws = W.add_event_rows(
-        spec, state.flow, now, safe_slot, row_updates,
-        channels=(ev.PASS, ev.PASS_REQUEST, ev.BLOCK, ev.BLOCK_REQUEST),
-    )
-    # OCCUPIED_PASS marks prioritized requests admitted normally (the
-    # reference's OK branch adds OCCUPIED_PASS when prioritized; the occupy
-    # path records only the future-window WAITING, which is `occupy_ws`
-    # below). Prioritized traffic is rare, so this scatter is cond-gated on
-    # the same mesh-uniform predicate as the occupy path.
-    idx_cur, _ = W.bucket_index(spec, now)
-    flow_counts = jax.lax.cond(
-        any_prio,
-        lambda c: c.at[safe_slot, idx_cur, int(ev.OCCUPIED_PASS)].add(
-            batch.acquire * (admit & batch.prioritized).astype(jnp.int32),
-            mode="drop",
-        ),
-        lambda c: c,
-        flow_ws.counts,
-    )
-    flow_ws = flow_ws._replace(counts=flow_counts)
-    # pmax over the mesh axis keeps the replicated occupy.starts identical on
-    # every device even when only the owner shard sees a borrow (each shard
-    # then also zeroes its own stale counts column for the reset slot).
-    # Paced SHOULD_WAIT admissions charge the same future-window tensor at
-    # their assigned wait — the cross-batch borrow that makes open-loop
-    # bursts unable to over-admit: the tokens are pre-paid into the window
-    # where the waiter is scheduled to pass.
-    charge_wait = jnp.where(
-        can_occupy, jnp.full((N,), wait_next, jnp.int32), pace_wait
-    )
-    charge_valid = can_occupy | pace_later
-    occupy_ws = jax.lax.cond(
-        any_prio | any_pace,
-        lambda occ: W.add_future(
-            spec, occ, now,
-            wait_ms=charge_wait,
-            resource_ids=safe_slot,
-            channel_ids=jnp.zeros((N,), jnp.int32),
-            values=batch.acquire,
-            valid=charge_valid,
-            combine_desired=pmax,
-        ),
-        lambda occ: occ,
-        state.occupy,
-    )
-    # namespace guard counts every ns-admitted request (the guard counts
-    # arrivals, not flow verdicts — GlobalRequestLimiter adds on tryPass);
-    # the mask is global, so the replicated ns window stays consistent. The
-    # per-namespace deltas ride seg_ns_sum (MXU matvec on TPU, scatter-add
-    # elsewhere).
-    ns_deltas = seg_ns_sum(ns_admitted.astype(jnp.float32))
-    ns_ws = W.add_column(spec, state.ns, now, ns_deltas)
+    with jax.named_scope("commit"):
+        admit_i = (admit | pace_now).astype(jnp.int32)
+        hard_i = (hard_block | pace_reject).astype(jnp.int32)
+        ev = ClusterEvent
+        row_updates = jnp.stack(
+            [
+                batch.acquire * admit_i,  # PASS
+                admit_i,  # PASS_REQUEST
+                batch.acquire * hard_i,  # BLOCK
+                hard_i,  # BLOCK_REQUEST
+            ],
+            axis=1,
+        )
+        flow_ws = W.add_event_rows(
+            spec, state.flow, now, safe_slot, row_updates,
+            channels=(ev.PASS, ev.PASS_REQUEST, ev.BLOCK, ev.BLOCK_REQUEST),
+        )
+        # OCCUPIED_PASS marks prioritized requests admitted normally (the
+        # reference's OK branch adds OCCUPIED_PASS when prioritized; the occupy
+        # path records only the future-window WAITING, which is `occupy_ws`
+        # below). Prioritized traffic is rare, so this scatter is cond-gated on
+        # the same mesh-uniform predicate as the occupy path.
+        idx_cur, _ = W.bucket_index(spec, now)
+        flow_counts = jax.lax.cond(
+            any_prio,
+            lambda c: c.at[safe_slot, idx_cur, int(ev.OCCUPIED_PASS)].add(
+                batch.acquire * (admit & batch.prioritized).astype(jnp.int32),
+                mode="drop",
+            ),
+            lambda c: c,
+            flow_ws.counts,
+        )
+        flow_ws = flow_ws._replace(counts=flow_counts)
+        # pmax over the mesh axis keeps the replicated occupy.starts identical on
+        # every device even when only the owner shard sees a borrow (each shard
+        # then also zeroes its own stale counts column for the reset slot).
+        # Paced SHOULD_WAIT admissions charge the same future-window tensor at
+        # their assigned wait — the cross-batch borrow that makes open-loop
+        # bursts unable to over-admit: the tokens are pre-paid into the window
+        # where the waiter is scheduled to pass.
+        charge_wait = jnp.where(
+            can_occupy, jnp.full((N,), wait_next, jnp.int32), pace_wait
+        )
+        charge_valid = can_occupy | pace_later
+        occupy_ws = jax.lax.cond(
+            any_prio | any_pace,
+            lambda occ: W.add_future(
+                spec, occ, now,
+                wait_ms=charge_wait,
+                resource_ids=safe_slot,
+                channel_ids=jnp.zeros((N,), jnp.int32),
+                values=batch.acquire,
+                valid=charge_valid,
+                combine_desired=pmax,
+            ),
+            lambda occ: occ,
+            state.occupy,
+        )
+        # namespace guard counts every ns-admitted request (the guard counts
+        # arrivals, not flow verdicts — GlobalRequestLimiter adds on tryPass);
+        # the mask is global, so the replicated ns window stays consistent. The
+        # per-namespace deltas ride seg_ns_sum (MXU matvec on TPU, scatter-add
+        # elsewhere).
+        ns_deltas = seg_ns_sum(ns_admitted.astype(jnp.float32))
+        ns_ws = W.add_column(spec, state.ns, now, ns_deltas)
 
     # ------------------------------------------------------------------
     # 6. verdicts — owner emits status+1, psum stitches shards together
     # ------------------------------------------------------------------
-    local_status = jnp.where(
-        degraded,
-        int(TokenStatus.DEGRADED) + 1,
-        jnp.where(
-            admit | pace_now,
-            int(TokenStatus.OK) + 1,
+    with jax.named_scope("verdicts"):
+        local_status = jnp.where(
+            degraded,
+            int(TokenStatus.DEGRADED) + 1,
             jnp.where(
-                can_occupy | pace_later,
-                int(TokenStatus.SHOULD_WAIT) + 1,
+                admit | pace_now,
+                int(TokenStatus.OK) + 1,
                 jnp.where(
-                    hard_block | pace_reject, int(TokenStatus.BLOCKED) + 1, 0
+                    can_occupy | pace_later,
+                    int(TokenStatus.SHOULD_WAIT) + 1,
+                    jnp.where(
+                        hard_block | pace_reject, int(TokenStatus.BLOCKED) + 1, 0
+                    ),
                 ),
             ),
-        ),
-    ).astype(jnp.int32)
-    combined = psum(local_status)
-    status = jnp.where(
-        ~batch.valid,
-        int(TokenStatus.FAIL),
-        jnp.where(
-            no_rule,
-            int(TokenStatus.NO_RULE_EXISTS),
-            jnp.where(
-                too_many,
-                int(TokenStatus.TOO_MANY_REQUEST),
-                jnp.where(combined > 0, combined - 1, int(TokenStatus.FAIL)),
-            ),
-        ),
-    ).astype(jnp.int8)
-
-    wait_ms = psum(
-        jnp.where(
-            can_occupy, wait_next, jnp.where(pace_later, pace_wait, 0)
         ).astype(jnp.int32)
-    )
-    remaining_local = jnp.clip(
-        threshold - passed - admitted_prefix - jnp.where(admit, acquire_f, 0.0),
-        0.0,
-        2**30,
-    ).astype(jnp.int32)
-    # blockedResult() in the reference always carries remaining=0 — and so
-    # do paced admissions (RateLimiterController has no token count to
-    # report); DEGRADED rows carry retry-after-ms instead
-    remaining = psum(
-        jnp.where(admit, remaining_local, jnp.where(degraded, br_retry, 0))
-    )
+        combined = psum(local_status)
+        status = jnp.where(
+            ~batch.valid,
+            int(TokenStatus.FAIL),
+            jnp.where(
+                no_rule,
+                int(TokenStatus.NO_RULE_EXISTS),
+                jnp.where(
+                    too_many,
+                    int(TokenStatus.TOO_MANY_REQUEST),
+                    jnp.where(combined > 0, combined - 1, int(TokenStatus.FAIL)),
+                ),
+            ),
+        ).astype(jnp.int8)
+
+        wait_ms = psum(
+            jnp.where(
+                can_occupy, wait_next, jnp.where(pace_later, pace_wait, 0)
+            ).astype(jnp.int32)
+        )
+        remaining_local = jnp.clip(
+            threshold - passed - admitted_prefix - jnp.where(admit, acquire_f, 0.0),
+            0.0,
+            2**30,
+        ).astype(jnp.int32)
+        # blockedResult() in the reference always carries remaining=0 — and so
+        # do paced admissions (RateLimiterController has no token count to
+        # report); DEGRADED rows carry retry-after-ms instead
+        remaining = psum(
+            jnp.where(admit, remaining_local, jnp.where(degraded, br_retry, 0))
+        )
 
     new_state = EngineState(
         flow=flow_ws, occupy=occupy_ws, ns=ns_ws,
@@ -859,6 +874,15 @@ def decide(
     )
 
 
+def step_name(kind: str, config: EngineConfig, uniform: bool,
+              depth: Optional[int] = None) -> str:
+    """The name a serving step is jitted under, so that a device trace reads
+    ``jit_decide_b1024_mixed`` and not ``jit__unknown``:
+    ``<kind>[_d<depth>]_b<batch_size>_<uniform|mixed>``."""
+    d = "" if depth is None else f"_d{depth}"
+    return f"{kind}{d}_b{config.batch_size}_{'uniform' if uniform else 'mixed'}"
+
+
 def decide_donating(config: EngineConfig, grouped: bool = False,
                     uniform: bool = False):
     """A single-shard step like :func:`decide` that DONATES the state
@@ -872,11 +896,16 @@ def decide_donating(config: EngineConfig, grouped: bool = False,
     service's lock makes ``self._state, v = step(self._state, …)`` the
     only reader), and warmup-style calls must feed throwaway states.
     """
-    return jax.jit(
-        partial(
-            _core_for(config, grouped), config, axis_name=None,
+    core = _core_for(config, grouped)
+
+    def step(state, rules, batch, now):
+        return core(
+            config, state, rules, batch, now, axis_name=None,
             grouped=grouped, uniform=uniform,
-        ),
+        )
+
+    return jax.jit(
+        named(step, step_name("decide", config, uniform)),
         donate_argnums=(0,),
     )
 
@@ -914,4 +943,7 @@ def decide_fused_donating(config: EngineConfig, depth: int,
 
         return jax.lax.scan(body, state, batches, length=depth)
 
-    return jax.jit(fused, donate_argnums=(0,))
+    return jax.jit(
+        named(fused, step_name("decide_fused", config, uniform, depth)),
+        donate_argnums=(0,),
+    )

@@ -31,12 +31,10 @@ discard — padding rows cost nothing and can never poison a live slot.
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
-from sentinel_tpu.engine.config import EngineConfig
+from sentinel_tpu.engine.config import EngineConfig, named
 from sentinel_tpu.engine.prefix import segment_prefix_builder
 from sentinel_tpu.engine.rules import DegradeStrategy
 from sentinel_tpu.engine.state import (
@@ -183,4 +181,7 @@ def outcome_step_donating(config: EngineConfig):
     ``br_strategy``/``br_slow_rt_ms`` rule columns, which turns on the
     SLOW-channel scatter and HALF_OPEN probe resolution (a separate jit
     trace; the 6-arg form stays bit-identical to the pre-breaker step)."""
-    return jax.jit(partial(_outcome_core, config), donate_argnums=(0,))
+    def step(state, slots, rt, exc, valid, now, *br):
+        return _outcome_core(config, state, slots, rt, exc, valid, now, *br)
+
+    return jax.jit(named(step, "outcome_step"), donate_argnums=(0,))

@@ -285,6 +285,10 @@ class PrometheusExporter:
         return (404, "not found\n", "text/plain")
 
     def start(self) -> "PrometheusExporter":
+        # the first render pays the lazy imports behind the body (jax for
+        # build_info among them: seconds); pay them here, not inside the
+        # first scrape's timeout
+        render()
         self._service.start()
         return self
 

@@ -16,12 +16,19 @@ BOTH the device timeline and the host pipeline stages that fed it. A window
 where the device trace shows idle gaps and the span artifact shows frames
 parked between ``enqueue`` and ``dispatch`` is the host starving the
 device; without the span half that diagnosis needed a second tool.
+
+The two artifacts share one clock: right after ``start_trace`` the hook
+drops a ``sentinel.sync`` ``TraceAnnotation`` whose ``t_ns`` stat is
+``time.monotonic_ns()`` at that moment, and the span artifact records the
+same number under ``sync.monotonicNs``. ``t_ns - <the annotation's start in
+the trace>`` is what to add to a trace time to get the flight recorder's.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+import time
 from typing import Optional
 
 from sentinel_tpu.core import clock as _clock
@@ -34,6 +41,7 @@ class ProfilerHook:
         self.default_dir = default_dir
         self.trace_dir: Optional[str] = None
         self._was_armed = False
+        self._sync_ns: Optional[int] = None
 
     @property
     def active(self) -> bool:
@@ -54,6 +62,11 @@ class ProfilerHook:
             import jax.profiler
 
             jax.profiler.start_trace(target)
+            self._sync_ns = time.monotonic_ns()
+            with jax.profiler.TraceAnnotation(
+                "sentinel.sync", t_ns=self._sync_ns
+            ):
+                pass
             self.trace_dir = target
             # an operator already arming a sampled recorder keeps it; the
             # profiled window itself records everything
@@ -77,7 +90,9 @@ class ProfilerHook:
                 spans_path = trace_spans.write_artifact(
                     os.path.join(
                         target, f"trace-spans-{_clock.now_ms()}.json"
-                    )
+                    ),
+                    sync={"annotation": "sentinel.sync",
+                          "monotonicNs": self._sync_ns},
                 )
             except Exception:
                 record_log.exception("span artifact write failed")

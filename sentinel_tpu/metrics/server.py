@@ -96,7 +96,50 @@ class ServerMetrics:
         "shm_ring_occupancy", "device_inflight",
     )
 
+    # phase histograms inside one dispatch: (member, what it covers). Always
+    # on, one record per phase per dispatch, never per row. Over any window
+    # permit_wait + prep + lock_wait + launch reconciles with dispatch_ms's
+    # sum and device_wait + fetch + account with decide_ms's. The first four
+    # are timed on the dispatching thread, the rest on the materializing one.
+    # (compile_ms is no phase; it rides the same four loops below.)
+    _PHASES = (
+        ("permit_wait_ms",
+         "Device lane blocked on max_device_inflight, per dispatch (ms)."),
+        ("prep_ms",
+         "Token service host prep before the service lock: asarray, uniform "
+         "test, bucket pick, slot lookup + grouping sort, step lookup (ms)."),
+        ("lock_wait_ms",
+         "Wait for the token service lock per dispatch: leases, outcome "
+         "step, rule loads, snapshots, a compile in front of a decide (ms)."),
+        ("launch_ms",
+         "Service lock held per dispatch: re-prep after a reload, engine "
+         "clock, the jitted call (transfer + enqueue), dirty-set (ms)."),
+        ("reply_queue_wait_ms",
+         "Dispatched group parked between the device lane and a reply lane "
+         "(ms)."),
+        ("device_wait_ms",
+         "Materialize until the first verdict array is on the host: what is "
+         "left of the device step, plus the first copy (ms)."),
+        ("fetch_ms",
+         "The other device-to-host copies, unsort to request order, MOVED "
+         "overlay (ms)."),
+        ("account_ms",
+         "Always-on accounting per dispatch: namespace attribution, verdict "
+         "counters, SLO plane, timeline, stat log, breaker scan (ms)."),
+        ("compile_ms",
+         "Backend compiles (persistent-cache hits included), each (ms)."),
+    )
+
     def __init__(self):
+        for name, _help in self._PHASES:
+            setattr(self, name, LatencyHistogram(lo=0.001, hi=100_000.0))
+        # backend compiles seen by the program's own jax.monitoring listener
+        # (core/compile_cache.py); after_warmup = ended after
+        # DefaultTokenService.warmup() returned, i.e. while serving
+        self._compiles = 0
+        self._compiles_after_warmup = 0
+        self._warm = False  # see set_warm
+        self._compile_lock = threading.Lock()
         # stage histograms, all in milliseconds except batch_size (requests).
         # 1µs..10s covers a sub-100µs device step and a 1s cold compile alike.
         self.queue_wait_ms = LatencyHistogram(lo=0.001, hi=10_000.0)
@@ -208,6 +251,37 @@ class ServerMetrics:
     def fused_frames_total(self) -> int:
         with self._fused_lock:
             return self._fused_frames
+
+    def set_warm(self, warm: bool) -> None:
+        """``DefaultTokenService.warmup()`` brackets itself with
+        ``set_warm(False)`` ... ``set_warm(True)``: a compile that ends
+        while the flag is up happened in front of live traffic. ``reset()``
+        leaves the flag alone (benches reset between load points while
+        serving)."""
+        with self._compile_lock:
+            self._warm = bool(warm)
+
+    def record_compile(self, ms: float) -> bool:
+        """One backend compile that took ``ms`` (the program's
+        ``jax.monitoring`` listener calls this). True when it ended after
+        warm-up."""
+        with self._compile_lock:
+            self._compiles += 1
+            after_warmup = self._warm
+            if after_warmup:
+                self._compiles_after_warmup += 1
+        self.compile_ms.record(ms)
+        return after_warmup
+
+    @property
+    def compiles_total(self) -> int:
+        with self._compile_lock:
+            return self._compiles
+
+    @property
+    def compiles_after_warmup_total(self) -> int:
+        with self._compile_lock:
+            return self._compiles_after_warmup
 
     @property
     def wait_assigned_total(self) -> int:
@@ -641,6 +715,8 @@ class ServerMetrics:
             "verdicts": verdicts,
             "verdictsPerSec": self._rate.rate(),
             "fusedFramesTotal": self.fused_frames_total,
+            "compilesTotal": self.compiles_total,
+            "compilesAfterWarmupTotal": self.compiles_after_warmup_total,
             "shedTotal": self.shed_total,
             "shedByReason": self.shed_totals(),
             "hostCopyBytesTotal": self.host_copy_bytes_total,
@@ -677,6 +753,8 @@ class ServerMetrics:
                 "dispatch_ms": self.dispatch_ms.snapshot(),
                 "fused_depth": self.fused_depth.snapshot(),
                 "wait_assigned_ms": self.wait_assigned_ms.snapshot(),
+                **{name: getattr(self, name).snapshot()
+                   for name, _help in self._PHASES},
             },
             "waitAssignedTotal": self.wait_assigned_total,
             "gauges": self._gauge_values(),
@@ -694,6 +772,7 @@ class ServerMetrics:
             ("dispatch_ms", self.dispatch_ms),
             ("fused_depth", self.fused_depth),
             ("wait_assigned_ms", self.wait_assigned_ms),
+            *((name, getattr(self, name)) for name, _help in self._PHASES),
         ):
             snap = hist.snapshot()
             out[name] = {
@@ -704,6 +783,8 @@ class ServerMetrics:
                 "sum": round(snap["sum"], 3),
             }
         out["fused_frames_total"] = self.fused_frames_total
+        out["compiles_total"] = self.compiles_total
+        out["compiles_after_warmup_total"] = self.compiles_after_warmup_total
         out["shed_total"] = self.shed_totals()
         out["host_copy_bytes_total"] = self.host_copy_bytes_total
         out["overlap_saved_ms_total"] = round(self.overlap_saved_ms_total, 3)
@@ -1050,7 +1131,8 @@ class ServerMetrics:
              "Enqueue-to-batch-drain wait per queue item (ms).",
              self.queue_wait_ms),
             ("sentinel_server_decide_ms",
-             "Device decide step per batch, dispatch to materialized (ms).",
+             "Reply lane's whole materialize call per batch: wait for the "
+             "device, copies, unsort and the always-on accounting (ms).",
              self.decide_ms),
             ("sentinel_server_write_ms",
              "Host write-out per batch: verdict encode + socket write (ms).",
@@ -1071,8 +1153,22 @@ class ServerMetrics:
              "Wait assigned per SHOULD_WAIT verdict: paced admission or "
              "priority occupy delay (ms).",
              self.wait_assigned_ms),
+            *((f"sentinel_server_{name}", help_text, getattr(self, name))
+              for name, help_text in self._PHASES),
         ):
             lines.append(hist.render_prometheus(name, help_text))
+        for name, help_text, value in (
+            ("compiles_total",
+             "Backend compiles in this process, persistent-cache hits "
+             "included (cumulative).", self.compiles_total),
+            ("compiles_after_warmup_total",
+             "Backend compiles that ended after the token service's "
+             "warmup() returned: a step compiled while serving "
+             "(cumulative).", self.compiles_after_warmup_total),
+        ):
+            lines.append(f"# HELP sentinel_server_{name} {help_text}")
+            lines.append(f"# TYPE sentinel_server_{name} counter")
+            lines.append(f"sentinel_server_{name} {value}")
         lines.append(
             "# HELP sentinel_server_wait_assigned_total SHOULD_WAIT "
             "verdicts that carried a positive wait hint (cumulative)."
@@ -1095,6 +1191,11 @@ class ServerMetrics:
         self.dispatch_ms.reset()
         self.fused_depth.reset()
         self.wait_assigned_ms.reset()
+        for name, _help in self._PHASES:
+            getattr(self, name).reset()
+        with self._compile_lock:
+            self._compiles = 0
+            self._compiles_after_warmup = 0
         with self._fused_lock:
             self._fused_frames = 0
         with self._verdict_lock:

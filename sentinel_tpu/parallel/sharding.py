@@ -22,8 +22,13 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sentinel_tpu.engine.config import EngineConfig
-from sentinel_tpu.engine.decide import RequestBatch, VerdictBatch, _core_for
+from sentinel_tpu.engine.config import EngineConfig, named
+from sentinel_tpu.engine.decide import (
+    RequestBatch,
+    VerdictBatch,
+    _core_for,
+    step_name,
+)
 from sentinel_tpu.engine.rules import RuleTable
 from sentinel_tpu.engine.state import BreakerState, EngineState, ShapingState
 from sentinel_tpu.stats.window import WindowState
@@ -214,14 +219,25 @@ def make_sharded_decide(
             ),
             check_vma=False,
         )
-        return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+        return jax.jit(
+            named(mapped, name), donate_argnums=(0,) if donate else ()
+        )
 
+    name = step_name(
+        "decide_sharded" if depth is None else "decide_sharded_fused",
+        config, uniform, depth,
+    )
     impls = {}
 
-    def sharded_step(state, rules, batch, now):
+    def jitted(rules):
+        """The jitted program for this rule table's pytree structure."""
         br = rules.br_strategy is not None
         if br not in impls:
             impls[br] = _build(br)
-        return impls[br](state, rules, batch, now)
+        return impls[br]
 
-    return sharded_step
+    def sharded_step(state, rules, batch, now):
+        return jitted(rules)(state, rules, batch, now)
+
+    sharded_step.jitted = jitted
+    return named(sharded_step, name)

@@ -99,14 +99,15 @@ def roll(spec: WindowSpec, ws: WindowState, now: jax.Array) -> WindowState:
     Analog of the reset arm of ``LeapArray.currentWindow`` (``LeapArray.java:
     132-160``) — but a data-parallel masked zero instead of a CAS race.
     """
-    idx, cur_start = bucket_index(spec, now)
-    stale = ws.starts[idx] != cur_start
-    # scatter-multiply of ONE bucket column ([R, E]) instead of rewriting the
-    # whole [R, B, E] tensor — keeps the roll O(R·E) per step
-    keep = jnp.where(stale, 0, 1).astype(ws.counts.dtype)
-    counts = ws.counts.at[:, idx, :].multiply(keep)
-    starts = ws.starts.at[idx].set(cur_start)
-    return WindowState(starts=starts, counts=counts)
+    with jax.named_scope("window_roll"):
+        idx, cur_start = bucket_index(spec, now)
+        stale = ws.starts[idx] != cur_start
+        # scatter-multiply of ONE bucket column ([R, E]) instead of rewriting
+        # the whole [R, B, E] tensor — keeps the roll O(R·E) per step
+        keep = jnp.where(stale, 0, 1).astype(ws.counts.dtype)
+        counts = ws.counts.at[:, idx, :].multiply(keep)
+        starts = ws.starts.at[idx].set(cur_start)
+        return WindowState(starts=starts, counts=counts)
 
 
 def add_events(

@@ -39,8 +39,10 @@ import numpy as np
 CLIENT_IN = 1    # frame decoded / pulled off a door (aux = rows)
 ENQUEUE = 2      # frame handed to the batching queue (aux = queue depth)
 DISPATCH = 3     # frame's batch entered the device dispatch (aux = batch rows)
-DEVICE_IN = 4    # device step submitted (aggregate, xid=0; aux = rows)
-DEVICE_OUT = 5   # device step materialized (aggregate, xid=0; aux = rows)
+DEVICE_IN = 4    # device step submitted, service lock released (aggregate,
+#                  xid=0; aux = rows)
+DEVICE_OUT = 5   # verdicts on the host AND counted (record_verdict_batch done;
+#                  the stat-log passes follow) (aggregate, xid=0; aux = rows)
 REPLY_OUT = 6    # frame's reply encoded + submitted to its door (aux = rows)
 SHED = 7         # frame/rows refused (aux = shed-reason index)
 FUSE = 8         # fusion ladder stacked frames (aggregate; aux = depth)
@@ -52,6 +54,18 @@ PROMOTE = 13     # standby promoted to primary
 BROWNOUT = 14    # admission ladder escalated (aux = level)
 SHM_POLL = 15    # shm ring door poll/doorbell activity (aux = frames)
 OUTCOME = 16     # batched completion report ingested (aux = rows accepted)
+# Phase boundaries inside one dispatch (aggregate, xid=0). Each marks the END
+# of a phase; ``shard`` carries the service's id and ``aux`` its dispatch
+# sequence number (taken under the service lock), so one dispatch's
+# boundaries join across the dispatching thread's ring and the
+# materializing thread's (``spans.dispatch_phases``). In time order:
+# PERMIT, PREP, LOCKED, DEVICE_IN | READY, FETCHED, DEVICE_OUT.
+PERMIT = 17      # device permit acquired (native lane only; aux = wait, us)
+PREP = 18        # host prep done, about to ask for the service lock
+LOCKED = 19      # service lock acquired
+READY = 20       # first verdict array on the host (device step finished)
+FETCHED = 21     # request-order verdict arrays built (copies, unsort, MOVED)
+COMPILE = 22     # a backend compile ended (aux = ms)
 
 STAGE_NAMES: Dict[int, str] = {
     CLIENT_IN: "client_in",
@@ -70,6 +84,12 @@ STAGE_NAMES: Dict[int, str] = {
     BROWNOUT: "brownout",
     SHM_POLL: "shm_poll",
     OUTCOME: "outcome",
+    PERMIT: "permit",
+    PREP: "prep",
+    LOCKED: "locked",
+    READY: "ready",
+    FETCHED: "fetched",
+    COMPILE: "compile",
 }
 
 # one ring row: 24 bytes, fixed
@@ -147,12 +167,17 @@ def sample_xid(xid: int) -> bool:
     return ((xid * _HASH_MULT) & 0xFFFFFFFF) < _SAMPLE_LIMIT
 
 
-def record(stage: int, xid: int = 0, shard: int = 0, aux: int = 0) -> None:
+def record(stage: int, xid: int = 0, shard: int = 0, aux: int = 0,
+           t_ns: Optional[int] = None) -> None:
     """Append one event. Data-plane events (xid != 0) honor the sample;
-    control-plane events (xid == 0) always record while armed."""
+    control-plane events (xid == 0) always record while armed. ``t_ns``
+    (``time.monotonic_ns()``) back-dates a boundary whose owner could not
+    write it when it passed (readers sort by time, not by write order)."""
     if xid and ((xid * _HASH_MULT) & 0xFFFFFFFF) >= _SAMPLE_LIMIT:
         return
-    _ring().write(time.monotonic_ns(), stage, xid, shard, aux)
+    _ring().write(
+        time.monotonic_ns() if t_ns is None else t_ns, stage, xid, shard, aux
+    )
 
 
 def record_many(stage: int, xids, shard: int = 0, aux: int = 0) -> None:

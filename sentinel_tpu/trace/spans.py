@@ -72,9 +72,87 @@ def completeness(spans: List[dict]) -> dict:
     }
 
 
-def write_artifact(path: str, limit: int = 256) -> str:
+# dispatch-side boundaries land in the dispatching thread's ring, the rest
+# in the materializing thread's; (service id, sequence number) joins them
+_PHASE_STAGES = {_R.PERMIT, _R.PREP, _R.LOCKED, _R.DEVICE_IN, _R.READY,
+                 _R.FETCHED, _R.DEVICE_OUT}
+
+
+def dispatch_phases(since_ns: Optional[int] = None,
+                    limit: Optional[int] = None) -> List[dict]:
+    """Per-dispatch phase durations (ms) from the rings, oldest first.
+
+    One entry per ``(service, seq)`` seen at ``locked``: ``prepMs`` (permit
+    granted → prep done; None without the native lane's ``permit`` event),
+    ``permitWaitMs`` (the ``permit`` event's own aux), ``lockWaitMs``,
+    ``launchMs`` (lock held, to ``device_in``), ``waitMs`` (``device_in`` →
+    first verdict array on the host: reply-queue wait plus what was left of
+    the device step), ``fetchMs``, ``accountMs`` (to ``device_out``: the
+    verdict counters; the stat-log passes after it are in the always-on
+    ``account_ms`` only). ``complete`` when every boundary from ``prep`` to
+    ``device_out`` was found; a wrapped ring or a dispatch still in flight
+    leaves the missing phases None."""
+    by_thread: dict = {}
+    for e in _R.events(since_ns=since_ns, stages=_PHASE_STAGES):
+        by_thread.setdefault(e["thread"], []).append(e)
+    out: dict = {}
+
+    def ms(a, b):
+        return None if a is None or b is None else (b - a) / 1e6
+
+    for thread, evs in by_thread.items():
+        permit = None  # the last permit not yet claimed by a dispatch
+        cur = None  # the dispatch whose boundaries this thread is writing
+        for e in evs:
+            st = e["stage"]
+            if st == "permit":
+                permit = e
+            elif st in ("prep", "ready"):
+                cur = out.setdefault((e["shard"], e["aux"]), {
+                    "service": e["shard"], "seq": e["aux"]})
+                cur[st] = e["t_ns"]
+                cur[st + "Thread"] = thread
+                if st == "prep" and permit is not None:
+                    cur["permit"] = permit["t_ns"]
+                    cur["permitWaitMs"] = permit["aux"] / 1e3
+                    permit = None
+            elif cur is not None and (e["shard"], e["aux"]) == (
+                    cur["service"], cur["seq"]):
+                cur[st] = e["t_ns"]  # locked, fetched
+            elif cur is not None and st == "device_in" and "locked" in cur:
+                cur.setdefault("device_in", e["t_ns"])
+                cur["rows"] = e["aux"]
+            elif cur is not None and st == "device_out" and "fetched" in cur:
+                cur.setdefault("device_out", e["t_ns"])
+    rows = []
+    for d in sorted(out.values(), key=lambda d: d.get("prep", d.get("ready"))):
+        g = d.get
+        rows.append({
+            "service": d["service"], "seq": d["seq"], "rows": g("rows"),
+            "startNs": g("permit", g("prep")),
+            "dispatchThread": g("prepThread"),
+            "replyThread": g("readyThread"),
+            "permitWaitMs": g("permitWaitMs"),
+            "prepMs": ms(g("permit"), g("prep")),
+            "lockWaitMs": ms(g("prep"), g("locked")),
+            "launchMs": ms(g("locked"), g("device_in")),
+            "waitMs": ms(g("device_in"), g("ready")),
+            "fetchMs": ms(g("ready"), g("fetched")),
+            "accountMs": ms(g("fetched"), g("device_out")),
+            "complete": all(k in d for k in (
+                "prep", "locked", "device_in", "ready", "fetched",
+                "device_out")),
+        })
+    return rows if limit is None else rows[-limit:]
+
+
+def write_artifact(path: str, limit: int = 256,
+                   sync: Optional[dict] = None) -> str:
     """Dump recent spans + completeness to a JSON artifact (the profiler
-    hook's stop() product). Returns the written path."""
+    hook's stop() product). ``sync`` is the hook's clock tie: the
+    ``monotonicNs`` its ``sentinel.sync`` annotation carries in the device
+    trace; every ``t_ns`` / ``startNs`` here is on that clock. Returns the
+    written path."""
     from sentinel_tpu.metrics.exporter import build_info
 
     spans = assemble_recent(limit=limit)
@@ -83,8 +161,12 @@ def write_artifact(path: str, limit: int = 256) -> str:
         "wallTime": time.time(),
         "build": build_info(),
         "trace": _R.status(),
+        "sync": sync,
         "completeness": completeness(spans),
         "spans": spans,
+        "dispatches": dispatch_phases(
+            since_ns=(sync or {}).get("monotonicNs"), limit=limit
+        ),
     }
     d = os.path.dirname(os.path.abspath(path))
     os.makedirs(d, exist_ok=True)

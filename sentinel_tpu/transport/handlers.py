@@ -501,8 +501,8 @@ def cmd_cluster_server_profiler(params, body):
 
 @command_mapping(
     "cluster/server/trace",
-    "flight-recorder control; action=arm|disarm|status|spans|blackbox "
-    "[&sample=0.01][&xid=][&limit=][&dir=]",
+    "flight-recorder control; action=arm|disarm|status|spans|phases|"
+    "blackbox [&sample=0.01][&xid=][&limit=][&dir=]",
 )
 def cmd_cluster_server_trace(params, body):
     """Operator surface of the always-on flight recorder
@@ -514,6 +514,10 @@ def cmd_cluster_server_trace(params, body):
     - ``spans``: assemble sampled end-to-end spans on demand — ``xid``
       picks one, otherwise the newest ``limit`` sampled xids; ``dir``
       additionally writes the JSON artifact and returns its path;
+    - ``phases``: per-dispatch phase durations of the newest ``limit``
+      dispatches (permit wait, prep, lock wait, launch, wait for the
+      device, fetch, accounting), joined across threads by the dispatch
+      sequence number;
     - ``blackbox``: force a black-box dump now (``dir`` overrides the
       configured directory) — the same artifact brownout escalation,
       standby promotion, and MOVE aborts write automatically.
@@ -551,6 +555,9 @@ def cmd_cluster_server_trace(params, body):
             "completeness": spans.completeness(assembled),
             "spans": assembled,
         }
+    if action == "phases":
+        return {"dispatches": spans.dispatch_phases(
+            limit=int(params.get("limit", 64)))}
     if action == "blackbox":
         if not blackbox.enabled() and not params.get("dir"):
             return {"error": "no black-box dir configured; pass dir="}
@@ -560,7 +567,9 @@ def cmd_cluster_server_trace(params, body):
                 directory=params.get("dir"),
             )
         }
-    return {"error": "action must be arm|disarm|status|spans|blackbox"}
+    return {
+        "error": "action must be arm|disarm|status|spans|phases|blackbox"
+    }
 
 
 @command_mapping(

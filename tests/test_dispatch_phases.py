@@ -1,0 +1,388 @@
+"""Phases inside one dispatch (PR 24): the always-on phase histograms, the
+same boundaries in the flight recorder, names for what the device runs, the
+program's own compile counter, one clock for the operator's profile.
+"""
+
+import glob
+import json
+import logging
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sentinel_tpu.cluster.client import TokenClient
+from sentinel_tpu.cluster.server_native import (
+    NativeTokenServer,
+    native_available,
+)
+from sentinel_tpu.cluster.token_service import DefaultTokenService
+from sentinel_tpu.core.log import record_log
+from sentinel_tpu.engine import ClusterFlowRule, EngineConfig, make_batch
+from sentinel_tpu.engine import make_state
+from sentinel_tpu.engine.rules import ThresholdMode
+from sentinel_tpu.metrics.profiler import ProfilerHook
+from sentinel_tpu.metrics.server import server_metrics
+from sentinel_tpu.parallel import make_flow_mesh
+from sentinel_tpu.trace import ring, spans
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import decide_golden  # noqa: E402
+
+G = ThresholdMode.GLOBAL
+CFG = EngineConfig(max_flows=64, max_namespaces=4, batch_size=64)
+CAP = CFG.batch_size
+SM = server_metrics()
+
+SERVICE_PHASES = ("prep_ms", "lock_wait_ms", "launch_ms", "device_wait_ms",
+                  "fetch_ms", "account_ms")
+DISPATCH_SIDE = ("permit_wait_ms", "prep_ms", "lock_wait_ms", "launch_ms")
+DECIDE_SIDE = ("device_wait_ms", "fetch_ms", "account_ms")
+LANE_DISPATCHES = 200
+ARMED_DISPATCHES = 50
+
+
+def _service(**kw):
+    svc = DefaultTokenService(CFG, **kw)
+    svc.load_rules([ClusterFlowRule(flow_id=i, count=50.0, mode=G)
+                    for i in range(1, 9)])
+    return svc
+
+
+def _ids(n, seed=0):
+    return np.random.default_rng(seed).integers(1, 10, n).astype(np.int64)
+
+
+def _counts():
+    return {k: v["count"] for k, v in SM.stage_snapshot().items()
+            if isinstance(v, dict) and "count" in v}
+
+
+def _sums():
+    return {k: v["sum"] for k, v in SM.snapshot()["stages"].items()}
+
+
+# -- (a) one record per phase per dispatch ------------------------------------
+# path -> (service arguments, row counts of the calls, device dispatches)
+PATHS = {
+    "single": ({}, (10,), 1),
+    "oversized": ({"fuse_depths": ()}, (3 * CAP + 5,), 4),
+    "fused": ({}, (6 * CAP,), 2),  # scan(4) + scan(2)
+    "mesh": ({"mesh": 4}, (10, 2 * CAP), 2),  # a sharded step, a sharded scan
+}
+
+
+@pytest.fixture(scope="module")
+def per_path():
+    """Each path driven once: ``{path: growth of every histogram's count}``."""
+    grew = {}
+    for path, (kw, calls, _n) in PATHS.items():
+        kw = dict(kw)
+        if "mesh" in kw:
+            kw["mesh"] = make_flow_mesh(jax.devices()[:kw["mesh"]])
+        svc = _service(**kw)
+        before = _counts()
+        for rows in calls:
+            out = svc.request_batch_arrays(_ids(rows))
+            assert out[0].shape == (rows,)
+        after = _counts()
+        grew[path] = {k: after[k] - before[k] for k in after}
+        svc.close()
+    return grew
+
+
+@pytest.mark.parametrize("phase", SERVICE_PHASES)
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_every_service_phase_records_once_per_dispatch(per_path, path, phase):
+    assert per_path[path][phase] == PATHS[path][2]
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_lane_phases_stay_silent_without_the_native_lane(per_path, path):
+    assert per_path[path]["permit_wait_ms"] == 0
+    assert per_path[path]["reply_queue_wait_ms"] == 0
+
+
+# -- the native lane: counts, reconciliation, flight-recorder chains ----------
+@pytest.fixture(scope="module")
+def lane_run():
+    if not native_available():
+        pytest.skip("native library not built")
+    ring.reset_for_tests()
+    svc = _service()
+    server = NativeTokenServer(svc, port=0, idle_ttl_s=None)
+    server.start()
+    client = TokenClient("127.0.0.1", server.port, timeout_ms=5000)
+    run = {}
+    try:
+        def drive(n):
+            for i in range(n):
+                out = client.request_batch_arrays(_ids(40, seed=i))
+                assert out is not None
+
+        drive(1)  # settle
+        c0, s0 = _counts(), _sums()
+        drive(LANE_DISPATCHES)  # disarmed: what the histograms cost alone
+        c1, s1 = _counts(), _sums()
+        run["counts"] = {k: c1[k] - c0[k] for k in c1}
+        run["sums"] = {k: s1[k] - s0[k] for k in s1}
+        run["events_disarmed"] = ring.events()
+        ring.arm(sample=1.0)
+        drive(ARMED_DISPATCHES)
+        run["phases"] = spans.dispatch_phases()
+        run["threads"] = {e["thread"] for e in ring.events()}
+    finally:
+        client.close()
+        server.stop()
+        svc.close()
+        ring.reset_for_tests()
+    return run
+
+
+@pytest.mark.parametrize("phase", DISPATCH_SIDE + ("reply_queue_wait_ms",)
+                         + DECIDE_SIDE)
+def test_every_phase_records_once_per_lane_dispatch(lane_run, phase):
+    # one request at a time, so one lane dispatch per request
+    assert lane_run["counts"]["dispatch_ms"] == LANE_DISPATCHES
+    assert lane_run["counts"]["decide_ms"] == LANE_DISPATCHES
+    assert lane_run["counts"][phase] == LANE_DISPATCHES
+
+
+@pytest.mark.parametrize("whole,parts", [("dispatch_ms", DISPATCH_SIDE),
+                                         ("decide_ms", DECIDE_SIDE)])
+def test_the_phases_reconcile_with_the_stage_they_split(lane_run, whole,
+                                                        parts):
+    total = lane_run["sums"][whole]
+    split = sum(lane_run["sums"][p] for p in parts)
+    assert total > 0
+    assert abs(split - total) <= 0.10 * total, (whole, total, {
+        p: lane_run["sums"][p] for p in parts})
+
+
+def test_armed_rings_yield_complete_chains_joined_across_threads(lane_run):
+    chains = [d for d in lane_run["phases"] if d["complete"]]
+    assert len(chains) >= ARMED_DISPATCHES - 1  # the newest may be in flight
+    seqs = [d["seq"] for d in chains]
+    assert len(set(seqs)) == len(seqs)
+    assert sorted(seqs) == list(range(min(seqs), max(seqs) + 1))
+    for d in chains:
+        assert d["rows"] == 40
+        # dispatched by the device lane, materialized by a reply lane
+        assert d["dispatchThread"] != d["replyThread"]
+        for key in ("permitWaitMs", "prepMs", "lockWaitMs", "launchMs",
+                    "waitMs", "fetchMs", "accountMs"):
+            assert d[key] is not None and d[key] >= 0, (key, d)
+    assert len(lane_run["threads"]) >= 3  # intake, device lane, reply lane
+
+
+def test_disarmed_rings_record_nothing(lane_run):
+    assert lane_run["events_disarmed"] == []
+
+
+def test_the_trace_command_serves_the_phases():
+    import sentinel_tpu.transport.handlers  # noqa: F401  (registers commands)
+    from sentinel_tpu.transport.command import get_command
+
+    ring.reset_for_tests()
+    svc = _service()
+    try:
+        ring.arm(sample=0.0)  # aggregate events record at any sample
+        svc.request_batch_arrays(_ids(10))
+        out = get_command("cluster/server/trace")({"action": "phases"}, "")
+        (d,) = out["dispatches"]
+        assert d["complete"] and d["rows"] == 10
+        # no native lane: no permit, so prep's start is unknown
+        assert d["permitWaitMs"] is None and d["prepMs"] is None
+        json.dumps(out)
+    finally:
+        svc.close()
+        ring.reset_for_tests()
+
+
+# -- (d) the compile counter ---------------------------------------------------
+def test_compiles_after_warmup_count_a_step_forced_lazily():
+    seen = []
+
+    class Keep(logging.Handler):
+        def emit(self, record):
+            seen.append(record.getMessage())
+
+    handler = Keep(level=logging.WARNING)
+    record_log.addHandler(handler)
+    ring.reset_for_tests()
+    svc = _service()
+    try:
+        total0 = SM.compiles_total
+        after0 = SM.compiles_after_warmup_total
+        svc.warmup()
+        assert SM.compiles_total > total0
+        assert SM.compile_ms.snapshot()["count"] >= SM.compiles_total - total0
+        svc.request_batch_arrays(_ids(10))
+        svc.request_batch_arrays(_ids(10), np.arange(10, dtype=np.int32) % 3)
+        svc.request_batch_arrays(_ids(2 * CAP))  # fused, uniform: warmed
+        assert SM.compiles_after_warmup_total == after0
+        assert not seen
+        # the one variant warmup() leaves cold on one chip: a fused span of
+        # mixed acquires (PERF.md section 7)
+        ring.arm(sample=0.0)
+        svc.request_batch_arrays(
+            _ids(2 * CAP), np.arange(2 * CAP, dtype=np.int32) % 3 + 1)
+        assert SM.compiles_after_warmup_total == after0 + 1
+        assert len(seen) == 1 and "decide_fused_d2_b64_mixed" in seen[0]
+        (ev,) = ring.events(stages={ring.COMPILE})
+        assert ev["aux"] >= 0
+        stages = SM.stage_snapshot()
+        assert stages["compiles_total"] == SM.compiles_total
+        assert "sentinel_server_compiles_after_warmup_total " in SM.render()
+    finally:
+        record_log.removeHandler(handler)
+        svc.close()
+        ring.reset_for_tests()
+
+
+# -- (c) names for what the device runs ---------------------------------------
+def _lowered(kind):
+    from sentinel_tpu.engine.decide import (
+        decide_donating,
+        decide_fused_donating,
+    )
+    from sentinel_tpu.engine.outcome import outcome_step_donating
+    from sentinel_tpu.parallel import (
+        make_sharded_decide,
+        shard_rules,
+        shard_state,
+    )
+
+    cfg, table, _index = decide_golden._setup()
+    batch = make_batch(cfg, [0, 1, 2])
+    stacked = type(batch)(*(np.stack([leaf] * 2) for leaf in batch))
+    now = jnp.int32(1000)
+    if kind == "single":
+        step = decide_donating(cfg, grouped=True, uniform=False)
+        return step.__name__, step.lower(make_state(cfg), table, batch, now)
+    if kind == "fused":
+        step = decide_fused_donating(cfg, 2, grouped=True, uniform=True)
+        return step.__name__, step.lower(make_state(cfg), table, stacked, now)
+    if kind == "outcome":
+        step = outcome_step_donating(cfg)
+        z = jnp.zeros(8, jnp.int32)
+        return step.__name__, step.lower(make_state(cfg), z, z, z,
+                                         jnp.zeros(8, bool), now)
+    mesh = make_flow_mesh(jax.devices()[:4])
+    state, table = shard_state(make_state(cfg), mesh), shard_rules(table, mesh)
+    if kind == "sharded":
+        step = make_sharded_decide(cfg, mesh, grouped=True, uniform=True,
+                                   donate=True)
+        return step.__name__, step.jitted(table).lower(state, table, batch,
+                                                       now)
+    step = make_sharded_decide(cfg, mesh, grouped=True, uniform=False,
+                               donate=True, depth=2)
+    return step.__name__, step.jitted(table).lower(state, table, stacked, now)
+
+
+STEP_NAMES = {
+    "single": "decide_b64_mixed",
+    "fused": "decide_fused_d2_b64_uniform",
+    "sharded": "decide_sharded_b64_uniform",
+    "sharded_fused": "decide_sharded_fused_d2_b64_mixed",
+    "outcome": "outcome_step",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    return {kind: _lowered(kind) for kind in STEP_NAMES}
+
+
+@pytest.mark.parametrize("kind", sorted(STEP_NAMES))
+def test_the_jitted_step_carries_its_name_into_the_program(lowered, kind):
+    name, low = lowered[kind]
+    assert name == STEP_NAMES[kind]
+    assert f"@jit_{name}" in low.as_text()
+
+
+ARMS = ("roll_guard", "threshold", "shaping", "admit", "pacing",
+        "occupy", "commit", "verdicts", "window_roll")
+
+
+@pytest.mark.parametrize("kind", ("single", "sharded_fused"))
+@pytest.mark.parametrize("arm", ARMS)
+def test_every_arm_of_the_step_is_a_named_scope(lowered, kind, arm):
+    # "jit(..)/commit/scatter-add" at top level, "commit/scatter-add" inside
+    # a scan body
+    assert re.search(rf'[/"]{arm}/', lowered[kind][1].as_text(debug_info=True))
+
+
+def test_the_breaker_arm_is_a_named_scope_once_degrade_rules_load():
+    from sentinel_tpu.engine import build_rule_table
+    from sentinel_tpu.engine.decide import decide_donating
+    from sentinel_tpu.engine.rules import DegradeRule
+
+    cfg = decide_golden._setup()[0]
+    table, _index = build_rule_table(
+        cfg, [ClusterFlowRule(flow_id=1, count=5.0, mode=G)],
+        degrade_rules=[DegradeRule(flow_id=1)])
+    low = decide_donating(cfg, grouped=True, uniform=True).lower(
+        make_state(cfg), table, make_batch(cfg, [0]), jnp.int32(1000))
+    assert re.search(r'[/"]breaker/', low.as_text(debug_info=True))
+
+
+def test_the_psum_stitch_is_a_named_scope_of_the_sharded_step(lowered):
+    text = lowered["sharded"][1].as_text(debug_info=True)
+    assert "roll_guard/psum_stitch" in text
+    assert "verdicts/psum_stitch" in text
+    assert "psum_stitch" not in lowered["single"][1].as_text(debug_info=True)
+
+
+# -- metadata only: verdicts bit-equal with the tree before the names ---------
+@pytest.fixture(scope="module")
+def stream_verdicts():
+    return decide_golden.verdicts()
+
+
+GOLDEN = np.load(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              "data", "decide_golden.npz"))
+
+
+@pytest.mark.parametrize("case", sorted(GOLDEN.files))
+def test_verdicts_are_bit_equal_with_the_parent_tree(stream_verdicts, case):
+    want = GOLDEN[case]
+    assert set(np.unique(want[..., 0, :])) >= {0, 1, 2, 3, 4}  # every verdict
+    np.testing.assert_array_equal(stream_verdicts[case], want)
+
+
+# -- (e) one clock for the operator's profile ---------------------------------
+def test_a_profile_and_its_span_artifact_share_one_clock(tmp_path):
+    from jax.profiler import ProfileData
+
+    ring.reset_for_tests()
+    svc = _service()
+    hook = ProfilerHook()
+    try:
+        svc.request_batch_arrays(_ids(10))
+        assert hook.start(str(tmp_path))["profiling"] is True
+        svc.request_batch_arrays(_ids(10))
+        out = hook.stop()
+    finally:
+        svc.close()
+        ring.reset_for_tests()
+    with open(out["spans"], encoding="utf-8") as f:
+        doc = json.load(f)
+    sync_ns = doc["sync"]["monotonicNs"]
+    assert doc["sync"]["annotation"] == "sentinel.sync"
+    (d,) = doc["dispatches"]  # the dispatch inside the profiled window
+    assert d["complete"] and d["startNs"] > sync_ns
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    found = [
+        (e.start_ns, {k: v for k, v in e.stats})
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name == "sentinel.sync"
+    ]
+    assert len(found) == 1
+    assert int(found[0][1]["t_ns"]) == sync_ns

@@ -38,6 +38,7 @@ from sentinel_tpu.engine import (
     drain_pending_clear,
     make_batch,
     make_state,
+    unpack_verdicts,
 )
 from sentinel_tpu.engine.param import (
     ParamConfig,
@@ -1115,7 +1116,7 @@ class DefaultTokenService(TokenService):
                     ).astype(np.int32)
                     order, batch = self._prep_batch(cfg, slots, acq, pr)
             now = self._engine_now()
-            self._state, verdicts = step(
+            self._state, packed = step(
                 self._state, self._table, batch, np.int32(now)
             )
             if self._dirty is not None:
@@ -1127,29 +1128,16 @@ class DefaultTokenService(TokenService):
                     self._dirty.setdefault("breaker", set()).update(
                         s for s in touched if s in self._breaker_slots
                     )
+        # the verdicts' one copy to the host starts now, behind the step on
+        # the device's queue, not when a reply lane gets round to asking
+        packed.copy_to_host_async()
         self._dispatched(t_enter, t_prep, t_locked, seq, n)
 
         def _materialize():
             # blocks on the async dispatch; runs outside the lock
             t_mat = time.monotonic_ns()
-            status_sorted = np.asarray(verdicts.status)[:n]
-            t_ready = time.monotonic_ns()
-            remaining_sorted = np.asarray(verdicts.remaining)[:n]
-            wait_sorted = np.asarray(verdicts.wait_ms)[:n]
-            if order is None:
-                # copy: callers own writable results (the sorted path builds
-                # fresh arrays), and a [:n] view would pin the whole padded
-                # bucket buffer alive
-                status = np.array(status_sorted)
-                remaining = np.array(remaining_sorted, np.int32)
-                wait = np.array(wait_sorted, np.int32)
-            else:
-                status = np.empty(n, status_sorted.dtype)
-                remaining = np.empty(n, np.int32)
-                wait = np.empty(n, np.int32)
-                status[order] = status_sorted
-                remaining[order] = remaining_sorted
-                wait[order] = wait_sorted
+            t_ready, host = self._read_verdicts(packed)
+            status, wait, remaining = unpack_verdicts(host, n, order)
             if moved_mask is not None:
                 # MOVED overlay: the device saw these rows as no-rule; the
                 # client sees a redirect carrying the shard-map epoch
@@ -1166,6 +1154,18 @@ class DefaultTokenService(TokenService):
             return status, remaining, wait
 
         return _materialize
+
+    @staticmethod
+    def _read_verdicts(packed):
+        """A materializer's ONE blocking device-to-host read: the
+        dispatch's packed verdict buffer, whose copy was started at launch,
+        as host ``int32[3, rows]`` (a fused span's frames laid end to end),
+        with the ``monotonic_ns`` stamp of its arrival."""
+        ready = packed.is_ready()
+        host = np.asarray(packed)
+        t_ready = time.monotonic_ns()
+        _SM.count_verdict_read(ready)
+        return t_ready, host.reshape(3, -1)
 
     def _dispatched(self, t_enter, t_prep, t_locked, seq, rows) -> None:
         """One dispatch left the service lock: its three dispatch-side
@@ -1190,7 +1190,7 @@ class DefaultTokenService(TokenService):
         on their namespace (a fused span passes its frames' slots as a
         list); ``t_enter``/``t_mat``/``t_ready`` are the
         ``monotonic_ns`` stamps of the dispatch's entry, the materializer's
-        entry and the first verdict array reaching the host."""
+        entry and the verdict buffer reaching the host."""
         t_fetched = time.monotonic_ns()
         if isinstance(slots_ns, list):
             slots_ns = np.concatenate(slots_ns)
@@ -1385,7 +1385,7 @@ class DefaultTokenService(TokenService):
                             )
                         preps.append((slots_f, order_f, None))
             now = self._engine_now()
-            self._state, verdicts = step(
+            self._state, packed = step(
                 self._state, self._table, block, np.int32(now)
             )
             if self._dirty is not None:
@@ -1396,37 +1396,33 @@ class DefaultTokenService(TokenService):
                     self._dirty.setdefault("breaker", set()).update(
                         s for s in touched if s in self._breaker_slots
                     )
+        packed.copy_to_host_async()  # see dispatch_batch_arrays
         self._dispatched(t_enter, t_prep, t_locked, seq, depth * cap)
         _SM.record_fused(depth)
         if _TR.ARMED:  # flight recorder: fused group submitted
             _TR.record(_TR.FUSE, aux=depth)
 
         def _materialize():
-            # blocks on the async dispatch; runs outside the lock. Verdict
-            # leaves are [depth, cap]; unsort each frame back to request
-            # order and lay the frames out contiguously.
+            # blocks on the async dispatch; runs outside the lock. The
+            # buffer is [3, depth, cap] with the frames already contiguous
+            # along the span, so the per-frame grouping sorts are undone as
+            # ONE span-wide order.
             t_mat = time.monotonic_ns()
-            status_all = np.asarray(verdicts.status)
-            t_ready = time.monotonic_ns()
-            remaining_all = np.asarray(verdicts.remaining)
-            wait_all = np.asarray(verdicts.wait_ms)
+            t_ready, host = self._read_verdicts(packed)
             # verdicts are ready → the device has consumed the staging
             # block's host buffers; recycle it for the next fused group
             pool.release(block)
             total = depth * cap
-            status = np.empty(total, status_all.dtype)
-            remaining = np.empty(total, np.int32)
-            wait = np.empty(total, np.int32)
-            for f, (_slots_f, order_f, _b) in enumerate(preps):
-                dst = slice(f * cap, (f + 1) * cap)
-                if order_f is None:
-                    status[dst] = status_all[f]
-                    remaining[dst] = remaining_all[f]
-                    wait[dst] = wait_all[f]
-                else:
-                    status[dst.start : dst.stop][order_f] = status_all[f]
-                    remaining[dst.start : dst.stop][order_f] = remaining_all[f]
-                    wait[dst.start : dst.stop][order_f] = wait_all[f]
+            span_order = None
+            if any(p[1] is not None for p in preps):
+                span_order = np.concatenate([
+                    np.arange(f * cap, (f + 1) * cap) if order_f is None
+                    else order_f + f * cap
+                    for f, (_s, order_f, _b) in enumerate(preps)
+                ])
+            status, wait, remaining = unpack_verdicts(
+                host, order=span_order
+            )
             if moved_span is not None:
                 status[moved_span] = np.int8(int(TokenStatus.MOVED))
                 remaining[moved_span] = moved_epochs_span[moved_span]

@@ -31,6 +31,8 @@ from sentinel_tpu.engine.decide import (
     decide,
     make_batch,
     make_batch_into,
+    pack_verdicts,
+    unpack_verdicts,
 )
 
 __all__ = [
@@ -50,4 +52,6 @@ __all__ = [
     "TokenStatus",
     "decide",
     "make_batch",
+    "pack_verdicts",
+    "unpack_verdicts",
 ]

@@ -108,6 +108,40 @@ class VerdictBatch(NamedTuple):
     remaining: jax.Array  # int32 [N]
 
 
+def pack_verdicts(verdicts: VerdictBatch) -> jax.Array:
+    """What a serve step hands its caller: the three verdict leaves as ONE
+    ``int32[3, ...]`` array (rows in :class:`VerdictBatch` field order,
+    status widened, values unchanged), so a dispatch's verdicts cross to the
+    host in one copy instead of three. Traced inside the jitted step."""
+    return jnp.stack([leaf.astype(jnp.int32) for leaf in verdicts])
+
+
+def unpack_verdicts(packed, n: Optional[int] = None,
+                    order: Optional[np.ndarray] = None) -> VerdictBatch:
+    """Host side of :func:`pack_verdicts`: a :class:`VerdictBatch` of fresh,
+    writable numpy leaves (``int8``, ``int32``, ``int32``). Blocks until the
+    buffer is on the host unless ``packed`` is numpy already.
+
+    ``n`` keeps the first ``n`` entries of the last axis (the rest is bucket
+    padding). ``order`` undoes a grouping sort along that axis: entry ``k``
+    answers request ``order[k]``."""
+    rows = np.asarray(packed)
+    if n is not None:
+        rows = rows[..., :n]
+    if order is None:
+        # copy: the host view of a device buffer is read-only, and a [:n]
+        # view would pin the whole padded bucket alive
+        out = np.array(rows)
+    else:
+        out = np.empty_like(rows)
+        for dst, src in zip(out, rows):  # half the time of one 2-D scatter
+            dst[..., order] = src
+    status, wait_ms, remaining = out
+    return VerdictBatch(
+        status=status.astype(np.int8), wait_ms=wait_ms, remaining=remaining
+    )
+
+
 def make_batch(
     config: EngineConfig,
     flow_slots: Sequence[int],
@@ -891,7 +925,9 @@ def decide_donating(config: EngineConfig, grouped: bool = False,
     XLA must copy them first (measured 22% of a 64-bucket step at 100k
     flows on CPU; on TPU it is HBM traffic and allocator churn).
 
-    Returns a cached-callable ``step(state, rules, batch, now)``. The
+    Returns a cached-callable ``step(state, rules, batch, now) ->
+    (state', packed)``; ``packed`` is the ``int32[3, N]`` verdict buffer of
+    :func:`pack_verdicts` (host side: :func:`unpack_verdicts`). The
     caller contract: nothing else may hold the passed state (the token
     service's lock makes ``self._state, v = step(self._state, …)`` the
     only reader), and warmup-style calls must feed throwaway states.
@@ -899,10 +935,11 @@ def decide_donating(config: EngineConfig, grouped: bool = False,
     core = _core_for(config, grouped)
 
     def step(state, rules, batch, now):
-        return core(
+        state, verdicts = core(
             config, state, rules, batch, now, axis_name=None,
             grouped=grouped, uniform=uniform,
         )
+        return state, pack_verdicts(verdicts)
 
     return jax.jit(
         named(step, step_name("decide", config, uniform)),
@@ -916,11 +953,12 @@ def decide_fused_donating(config: EngineConfig, depth: int,
     over ``depth`` stacked request frames, donating the state buffers like
     :func:`decide_donating`.
 
-    Returns ``step(state, rules, batches, now) -> (state', verdicts)``
+    Returns ``step(state, rules, batches, now) -> (state', packed)``
     where every ``batches`` leaf is ``[depth, batch_size]``-shaped (the
     per-frame :class:`RequestBatch` leaves stacked along a new leading
-    axis) and the ``verdicts`` leaves come back ``[depth, batch_size]``
-    in the same frame order. Frame ``k`` sees exactly the state frame
+    axis) and ``packed`` is ONE ``int32[3, depth, batch_size]`` buffer
+    (:func:`pack_verdicts` of the ``[depth, batch_size]`` verdict leaves,
+    same frame order). Frame ``k`` sees exactly the state frame
     ``k-1`` produced — the on-device equivalent of ``depth`` consecutive
     :func:`decide_donating` calls at one shared ``now``, with the
     per-dispatch host/RTT overhead paid once for the whole chain.
@@ -941,7 +979,8 @@ def decide_fused_donating(config: EngineConfig, depth: int,
             st, verdicts = core(st, rules, batch, now)
             return st, verdicts
 
-        return jax.lax.scan(body, state, batches, length=depth)
+        state, verdicts = jax.lax.scan(body, state, batches, length=depth)
+        return state, pack_verdicts(verdicts)
 
     return jax.jit(
         named(fused, step_name("decide_fused", config, uniform, depth)),

@@ -112,17 +112,19 @@ class ServerMetrics:
          "Wait for the token service lock per dispatch: leases, outcome "
          "step, rule loads, snapshots, a compile in front of a decide (ms)."),
         ("launch_ms",
-         "Service lock held per dispatch: re-prep after a reload, engine "
-         "clock, the jitted call (transfer + enqueue), dirty-set (ms)."),
+         "Service lock acquired to dispatch issued: re-prep after a reload, "
+         "engine clock, the jitted call (transfer + enqueue), dirty-set, "
+         "and, past the lock, starting the verdict buffer's copy to the "
+         "host (ms)."),
         ("reply_queue_wait_ms",
          "Dispatched group parked between the device lane and a reply lane "
          "(ms)."),
         ("device_wait_ms",
-         "Materialize until the first verdict array is on the host: what is "
-         "left of the device step, plus the first copy (ms)."),
+         "Materialize until the one verdict buffer is on the host: what is "
+         "left of the device step and of the copy started at launch (ms)."),
         ("fetch_ms",
-         "The other device-to-host copies, unsort to request order, MOVED "
-         "overlay (ms)."),
+         "Unpack the verdict buffer, unsort to request order, MOVED "
+         "overlay; no copy from the device (ms)."),
         ("account_ms",
          "Always-on accounting per dispatch: namespace attribution, verdict "
          "counters, SLO plane, timeline, stat log, breaker scan (ms)."),
@@ -161,6 +163,11 @@ class ServerMetrics:
         )
         self._fused_frames = 0
         self._fused_lock = threading.Lock()
+        # the materializers' device-to-host reads: how many blocked, and how
+        # many found the device already done with the verdict buffer
+        self._verdict_host_reads = 0
+        self._verdict_copy_ready = 0
+        self._verdict_read_lock = threading.Lock()
         # traffic-shaping waits: every SHOULD_WAIT verdict that carried a
         # positive wait hint (paced admission or priority occupy) — count
         # plus the distribution of assigned waits (whole ms, ≥ 1)
@@ -251,6 +258,26 @@ class ServerMetrics:
     def fused_frames_total(self) -> int:
         with self._fused_lock:
             return self._fused_frames
+
+    def count_verdict_read(self, ready: bool) -> None:
+        """One materializer made its one blocking read of a dispatch's
+        verdict buffer; ``ready`` is the buffer's ``is_ready()`` on entry:
+        the device had finished before the reply lane asked, so what
+        ``device_wait_ms`` then holds is the copy and the GIL."""
+        with self._verdict_read_lock:
+            self._verdict_host_reads += 1
+            if ready:
+                self._verdict_copy_ready += 1
+
+    @property
+    def verdict_host_reads_total(self) -> int:
+        with self._verdict_read_lock:
+            return self._verdict_host_reads
+
+    @property
+    def verdict_copy_ready_total(self) -> int:
+        with self._verdict_read_lock:
+            return self._verdict_copy_ready
 
     def set_warm(self, warm: bool) -> None:
         """``DefaultTokenService.warmup()`` brackets itself with
@@ -717,6 +744,8 @@ class ServerMetrics:
             "fusedFramesTotal": self.fused_frames_total,
             "compilesTotal": self.compiles_total,
             "compilesAfterWarmupTotal": self.compiles_after_warmup_total,
+            "verdictHostReadsTotal": self.verdict_host_reads_total,
+            "verdictCopyReadyTotal": self.verdict_copy_ready_total,
             "shedTotal": self.shed_total,
             "shedByReason": self.shed_totals(),
             "hostCopyBytesTotal": self.host_copy_bytes_total,
@@ -785,6 +814,8 @@ class ServerMetrics:
         out["fused_frames_total"] = self.fused_frames_total
         out["compiles_total"] = self.compiles_total
         out["compiles_after_warmup_total"] = self.compiles_after_warmup_total
+        out["verdict_host_reads_total"] = self.verdict_host_reads_total
+        out["verdict_copy_ready_total"] = self.verdict_copy_ready_total
         out["shed_total"] = self.shed_totals()
         out["host_copy_bytes_total"] = self.host_copy_bytes_total
         out["overlap_saved_ms_total"] = round(self.overlap_saved_ms_total, 3)
@@ -1165,6 +1196,13 @@ class ServerMetrics:
              "Backend compiles that ended after the token service's "
              "warmup() returned: a step compiled while serving "
              "(cumulative).", self.compiles_after_warmup_total),
+            ("verdict_host_reads_total",
+             "Blocking device-to-host reads made by materializers: one per "
+             "dispatch (cumulative).", self.verdict_host_reads_total),
+            ("verdict_copy_ready_total",
+             "Materializations whose verdict buffer was ready on entry: the "
+             "device had finished before the reply lane asked "
+             "(cumulative).", self.verdict_copy_ready_total),
         ):
             lines.append(f"# HELP sentinel_server_{name} {help_text}")
             lines.append(f"# TYPE sentinel_server_{name} counter")
@@ -1198,6 +1236,9 @@ class ServerMetrics:
             self._compiles_after_warmup = 0
         with self._fused_lock:
             self._fused_frames = 0
+        with self._verdict_read_lock:
+            self._verdict_host_reads = 0
+            self._verdict_copy_ready = 0
         with self._verdict_lock:
             self._verdicts.clear()
             self._wait_assigned = 0

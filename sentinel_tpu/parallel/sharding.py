@@ -25,8 +25,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from sentinel_tpu.engine.config import EngineConfig, named
 from sentinel_tpu.engine.decide import (
     RequestBatch,
-    VerdictBatch,
     _core_for,
+    pack_verdicts,
     step_name,
 )
 from sentinel_tpu.engine.rules import RuleTable
@@ -154,9 +154,12 @@ def make_sharded_decide(
     out global slots, which the kernel maps to shard-local via its
     ``axis_index``).
 
-    ``donate=True`` donates the state buffers exactly like the single-shard
-    ``decide_donating`` path: XLA updates the sharded window tensors in
-    place instead of copying the full per-shard state every dispatch.
+    ``donate=True`` is the serve step, exactly like the single-shard
+    ``decide_donating``: it donates the state buffers (XLA updates the
+    sharded window tensors in place instead of copying the full per-shard
+    state every dispatch) and returns the verdicts as the one replicated
+    ``int32[3, ...]`` buffer of ``pack_verdicts`` instead of a
+    ``VerdictBatch``.
 
     ``depth=F`` builds the fused variant: one ``lax.scan`` of the sharded
     step over ``[F, batch_size]`` stacked request frames, inside a single
@@ -178,7 +181,7 @@ def make_sharded_decide(
     core = _core_for(config, grouped)
 
     if depth is None:
-        def step(state, rules, batch, now):
+        def decide_shard(state, rules, batch, now):
             return core(
                 config, state, rules, batch, now, axis_name=axis,
                 grouped=grouped, uniform=uniform,
@@ -187,7 +190,7 @@ def make_sharded_decide(
         if depth < 2:
             raise ValueError(f"fused depth must be >= 2, got {depth}")
 
-        def step(state, rules, batches, now):
+        def decide_shard(state, rules, batches, now):
             def body(st, batch):
                 st, verdicts = core(
                     config, st, rules, batch, now, axis_name=axis,
@@ -196,6 +199,10 @@ def make_sharded_decide(
                 return st, verdicts
 
             return jax.lax.scan(body, state, batches, length=depth)
+
+    def step(state, rules, batch, now):
+        state, verdicts = decide_shard(state, rules, batch, now)
+        return state, pack_verdicts(verdicts) if donate else verdicts
 
     # two spec shapes, matching the two RuleTable pytree structures: with
     # br_* columns (degrade rules loaded) and without (None columns, so the
@@ -213,10 +220,8 @@ def make_sharded_decide(
                 _batch_specs(),
                 P(),
             ),
-            out_specs=(
-                _state_specs(axis),
-                VerdictBatch(status=P(), wait_ms=P(), remaining=P()),
-            ),
+            # verdicts replicated, as a VerdictBatch or packed into one
+            out_specs=(_state_specs(axis), P()),
             check_vma=False,
         )
         return jax.jit(

@@ -63,8 +63,8 @@ OUTCOME = 16     # batched completion report ingested (aux = rows accepted)
 PERMIT = 17      # device permit acquired (native lane only; aux = wait, us)
 PREP = 18        # host prep done, about to ask for the service lock
 LOCKED = 19      # service lock acquired
-READY = 20       # first verdict array on the host (device step finished)
-FETCHED = 21     # request-order verdict arrays built (copies, unsort, MOVED)
+READY = 20       # the verdict buffer on the host (step and copy finished)
+FETCHED = 21     # request-order verdict arrays built (unpack, unsort, MOVED)
 COMPILE = 22     # a backend compile ended (aux = ms)
 
 STAGE_NAMES: Dict[int, str] = {

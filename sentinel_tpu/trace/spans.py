@@ -86,7 +86,7 @@ def dispatch_phases(since_ns: Optional[int] = None,
     granted → prep done; None without the native lane's ``permit`` event),
     ``permitWaitMs`` (the ``permit`` event's own aux), ``lockWaitMs``,
     ``launchMs`` (lock held, to ``device_in``), ``waitMs`` (``device_in`` →
-    first verdict array on the host: reply-queue wait plus what was left of
+    the verdict buffer on the host: reply-queue wait plus what was left of
     the device step), ``fetchMs``, ``accountMs`` (to ``device_out``: the
     verdict counters; the stat-log passes after it are in the always-on
     ``account_ms`` only). ``complete`` when every boundary from ``prep`` to

@@ -31,7 +31,10 @@ from sentinel_tpu.engine import (
     make_batch,
     make_state,
 )
-from sentinel_tpu.engine.decide import decide_fused_donating
+from sentinel_tpu.engine.decide import (
+    decide_fused_donating,
+    unpack_verdicts,
+)
 from sentinel_tpu.engine.outcome import outcome_step_donating
 from sentinel_tpu.engine.state import (
     BR_CLOSED,
@@ -548,7 +551,7 @@ class TestFusedParity:
         fused = decide_fused_donating(cfg, depth=3)
         batches = _stack_batches(cfg, [[s] * 16] * 3)
         state, v = fused(state, table, batches, jnp.int32(1400))
-        status = np.asarray(v.status)[:, :16]
+        status = unpack_verdicts(v).status[:, :16]
         assert int((status == int(TokenStatus.OK)).sum()) == 1
         assert status[0, 0] == int(TokenStatus.OK)
         assert int((status == int(TokenStatus.DEGRADED)).sum()) == 47
@@ -576,6 +579,7 @@ class TestFusedParity:
         fused_state, fv = fused(
             fused_state, table, _stack_batches(CFG, frames), jnp.int32(now)
         )
+        fv = unpack_verdicts(fv)
         for k in range(depth):
             np.testing.assert_array_equal(
                 np.asarray(fv.status)[k, : CFG.batch_size], seq_v[k][0]

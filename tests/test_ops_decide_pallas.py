@@ -34,6 +34,7 @@ from sentinel_tpu.engine.decide import (
     _decide_core,
     decide_fused_donating,
     resolve_decide_impl,
+    unpack_verdicts,
 )
 from sentinel_tpu.engine import DegradeRule, DegradeStrategy, TokenStatus
 from sentinel_tpu.engine.outcome import outcome_step_donating
@@ -390,7 +391,7 @@ class TestBreakerParity:
         st_p, v_p = step_p(st_p, table, batches, jnp.int32(10_400))
         _assert_trees_equal(v_x, v_p, "fused breaker verdicts")
         _assert_trees_equal(st_x, st_p, "fused breaker state")
-        status = np.asarray(v_x.status)[:, :6]
+        status = unpack_verdicts(v_x).status[:, :6]
         assert int((status == int(TokenStatus.OK)).sum()) == 1
         assert status[0, 0] == int(TokenStatus.OK)
 

@@ -24,7 +24,10 @@ from sentinel_tpu.engine import (
     make_batch,
     make_state,
 )
-from sentinel_tpu.engine.decide import decide_fused_donating
+from sentinel_tpu.engine.decide import (
+    decide_fused_donating,
+    unpack_verdicts,
+)
 from sentinel_tpu.engine.rules import ControlBehavior, ThresholdMode
 from sentinel_tpu.engine.state import flow_spec
 from sentinel_tpu.stats import window as W
@@ -696,6 +699,7 @@ class TestFusedParity:
             ],
         )
         state_f, vf = fused(make_state(CFG), table, batches, jnp.int32(now))
+        vf = unpack_verdicts(vf)
 
         for k, v in enumerate(seq_verdicts):
             np.testing.assert_array_equal(

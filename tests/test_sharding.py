@@ -19,6 +19,7 @@ from sentinel_tpu.engine import (
     make_batch,
     make_state,
 )
+from sentinel_tpu.engine.decide import unpack_verdicts
 from sentinel_tpu.engine.rules import ThresholdMode
 from sentinel_tpu.parallel import (
     make_flow_mesh,
@@ -230,7 +231,7 @@ class TestShardedDonationAndFusion:
         fused_state = shard_state(make_state(CFG), mesh)
         out_state, fv = fused(fused_state, table_8, stacked, jnp.int32(10_000))
         assert fused_state.flow.counts.is_deleted()  # donated
-        fv = jax.tree.map(np.asarray, fv)
+        fv = unpack_verdicts(fv)
         for f in range(depth):
             for leaf in ("status", "wait_ms", "remaining"):
                 np.testing.assert_array_equal(

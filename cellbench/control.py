@@ -10,11 +10,12 @@ the server as a run's probe does), lets the rule windows drain, and probes.
 Sound runs must read 0 mismatches on every seed; a control has to read more.
 The benchmark's own runs never call this.
 
-``over_admit`` breaks the guarantee the deployment's file states first (no
-admission beyond a rule's count): the service's answers pass through
-``OverAdmit``, which turns the first BLOCKED verdict of every dispatch into
-OK, one row where the answer is produced. This is the control the limit is
-held against.
+A control that breaks a guarantee is the deployment's family's (its
+``CONTROLS``). The flow family's ``over_admit`` breaks the guarantee the
+deployment's file states first (no admission beyond a rule's count): the
+service's answers pass through ``OverAdmit``, which turns the first BLOCKED
+verdict of every dispatch into OK, one row where the answer is produced. This
+is the control the limit is held against.
 
 ``lower_precision`` wraps ``jax.numpy.einsum`` and ``jax.numpy.matmul``
 before the service compiles anything, so that every ``precision=`` the
@@ -46,32 +47,6 @@ def lower_the_precision() -> None:
     jnp.matmul = without_precision(jnp.matmul)
 
 
-class OverAdmit:
-    """The service with one answer altered where it is produced: the first
-    BLOCKED verdict of every dispatch comes back OK."""
-
-    def __init__(self, service):
-        self._service = service
-
-    def __getattr__(self, name):
-        return getattr(self._service, name)
-
-    def dispatch_batch_arrays(self, ids, acq=None, prios=None):
-        mat = self._service.dispatch_batch_arrays(ids, acq, prios)
-
-        def altered():
-            status, remaining, wait = mat()
-            blocked = (status == deploy.BLOCKED).nonzero()[0]
-            if blocked.size:
-                status = status.copy()
-                status[blocked[0]] = deploy.OK
-            return status, remaining, wait
-        return altered
-
-    def request_batch_arrays(self, ids, acq=None, prios=None):
-        return self.dispatch_batch_arrays(ids, acq, prios)()
-
-
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -79,19 +54,23 @@ def main() -> None:
     ap.add_argument("--first-seed", type=int, default=2_147_480_000)
     ap.add_argument("--seconds", type=float, default=3.0)
     ap.add_argument("--control", default="none",
-                    choices=("none", "over_admit", "lower_precision"))
+                    help="none, lower_precision, or one of the family's")
     ap.add_argument("--manifest", default=manifest.ROOT + "/BENCHMARK.json")
     args = ap.parse_args()
     cell = manifest.Cell(args.manifest, args.workload)
-    dep = deploy.Deployment(deploy.load_json(cell.config_file))
+    dep = deploy.load(cell.config_file, cell.dirs)
+    controls = dep.family.CONTROLS
+    if args.control not in ("none", "lower_precision", *controls):
+        raise SystemExit(f"no control {args.control!r}; the family has "
+                         f"{sorted(controls)}")
     devices, say, compiles = run.start_jax(cell, require_chip=True)
     if args.control == "lower_precision":
         lower_the_precision()
     say(f"control: {args.control}")
     from cellbench import server as sut
 
-    built = sut.build(dep, devices, say, wrap_service=(
-        OverAdmit if args.control == "over_admit" else None))
+    built = sut.build(dep, devices, say,
+                      wrap_service=controls.get(args.control))
     readings = []
     try:
         for k in range(args.seeds):
@@ -103,7 +82,8 @@ def main() -> None:
                 if k == 0:
                     run.warm_up(built, clients, cell, dep, seed, compiles, say)
                 _t0, _c0, _c1, _s, _w, results = run.window(
-                    clients, cell, args.seconds, 0, work, compiles, say)
+                    clients, cell, args.seconds, 0, work, compiles, say,
+                    dep.family.progress(built))
                 client = run.merge_clients(results, work, clients)
             finally:
                 clients.close()

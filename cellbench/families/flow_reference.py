@@ -1,4 +1,5 @@
-"""The plain reference: what a token server with these rules must answer.
+"""The flow family's plain reference: what a token server with these rules
+must answer.
 
 A straightforward scalar implementation of the semantics the deployment's
 file states, one request at a time, in plain Python floats and ints. It

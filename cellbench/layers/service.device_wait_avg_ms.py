@@ -1,5 +1,6 @@
-"""Mean time a materializer waits until the first verdict array is on the host:
-what is left of the device step, plus the first copy: the server's ``device_wait_ms``
+"""Mean time a materializer waits until the one packed verdict buffer
+(``int32[3, rows]``, PR 25) is on the host: what is left of the device step
+and of the copy started at launch: the server's ``device_wait_ms``
 phase histogram over the whole window. None where the program has no such
 histogram (a tree from before PR 24)."""
 

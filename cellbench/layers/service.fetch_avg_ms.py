@@ -1,5 +1,6 @@
-"""Mean time per dispatch for the other device-to-host copies, the unsort to
-request order and the MOVED overlay: the server's ``fetch_ms``
+"""Mean time per dispatch to unpack the one verdict buffer that is already on
+the host (no copy from the device since PR 25): the padding sliced off, one
+unsort to request order, the MOVED overlay: the server's ``fetch_ms``
 phase histogram over the whole window. None where the program has no such
 histogram (a tree from before PR 24)."""
 

@@ -1,5 +1,7 @@
-"""Mean time the service lock is held per dispatch: the jitted call (argument
-transfer and enqueue), the engine clock, dirty-set bookkeeping: the server's ``launch_ms``
+"""Mean time per dispatch from the service lock acquired to the dispatch
+issued: the jitted call (argument transfer and enqueue), the engine clock,
+dirty-set bookkeeping and, past the lock since PR 25, the start of the
+verdict buffer's copy to the host: the server's ``launch_ms``
 phase histogram over the whole window. None where the program has no such
 histogram (a tree from before PR 24)."""
 

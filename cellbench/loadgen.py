@@ -17,7 +17,9 @@ Failure accounting (ISSUE 23, A.3), all of it at the client:
   within ``timeout_ms``, a connection error, a status that is not a decision
   (OVERLOAD, FAIL, STANDBY, MOVED, anything unknown), and a brownout pass:
   status OK with ``remaining == 0`` on an unmetered flow, which only the
-  overload ladder's local answer produces;
+  overload ladder's local answer produces (which statuses are decisions and
+  what a brownout pass looks like is the deployment's family's to say:
+  ``Deployment.ledger_view``);
 * latency - from the time the frame was due (closed loop: sent) to its
   reply, every row of a frame carrying the frame's latency, failed rows left
   out of the percentiles.
@@ -39,11 +41,10 @@ if __package__ in (None, ""):
 
 from cellbench import deploy, traffic, wire  # noqa: E402
 
-_DECIDED = np.zeros(256, bool)
-_DECIDED[list(deploy.DECISIONS)] = True
 BIN_S = 0.1  # reply-time bins of the admitted-token ledger
 N_STATUS = 16
 _SINGLE_POOL = 1 << 16
+RUN_S = 0.001  # open loop, one-row frames: the least time between two runs
 _SENT_RING = 1 << 16
 
 
@@ -61,41 +62,37 @@ class Ledger:
         self.decided_in_window = 0
         self.decided = 0
         self.status_hist = np.zeros(N_STATUS, np.int64)
-        self.unmetered_blocked = 0
+        self.never_rows = 0  # rows the family says can never come back
         self.duplicates = 0
         n_bins = int((t_end - t0) / BIN_S) + 200
         self.admitted = np.zeros(
-            (len(dep.metered_count_of_index()), n_bins), np.float64)
+            (len(dep.ledger_counts()), n_bins), np.float64)
         self.lat_max = np.zeros(n_bins)  # slowest reply of each bin
         self.lat = []  # (latency seconds array, weight array)
         self.lags = []
 
-    def rows_back(self, t: float, lat, ids, acq, status, remaining) -> None:
-        """Account the verdicts of rows that came back at ``t``; ``lat`` is
-        one latency for all of them or an array."""
+    def rows_back(self, t: float, lat, cols, status, remaining) -> None:
+        """Account the verdicts of the rows ``cols`` that came back at
+        ``t``; ``lat`` is one latency for all of them or an array."""
         st = status.astype(np.uint8)
-        metered = self.dep.is_metered(ids)
-        decided = _DECIDED[st]
-        brown = (st == deploy.OK) & (remaining == 0) & ~metered
+        decided, brown, never, keys, tokens = self.dep.ledger_view(
+            cols, st, remaining)
         good = decided & ~brown
         n_good = int(good.sum())
-        ok_m = metered & (st == deploy.OK)
         with self.lock:
             self.status_hist += np.bincount(
                 np.minimum(st, N_STATUS - 1), minlength=N_STATUS)
             self.failed["status"] += int((~decided).sum())
             self.failed["brownout_pass"] += int(brown.sum())
-            self.unmetered_blocked += int(
-                ((st == deploy.BLOCKED) & ~metered).sum())
+            self.never_rows += never
             self.decided += n_good
             if t <= self.t_end:
                 self.decided_in_window += n_good
             b = min(max(int((t - self.t0) / BIN_S), 0),
                     self.admitted.shape[1] - 1)
             self.lat_max[b] = max(self.lat_max[b], float(np.max(lat)))
-            if ok_m.any():
-                np.add.at(self.admitted[:, b],
-                          self.dep.metered_index(ids[ok_m]), acq[ok_m])
+            if len(keys):
+                np.add.at(self.admitted[:, b], keys, tokens)
             if n_good:
                 if np.ndim(lat) == 0:
                     self.lat.append((np.array([lat]), np.array([n_good])))
@@ -120,7 +117,7 @@ class Ledger:
             "decided": int(self.decided),
             "decided_in_window": int(self.decided_in_window),
             "status_hist": self.status_hist.tolist(),
-            "unmetered_blocked": int(self.unmetered_blocked),
+            "never_rows": int(self.never_rows),
             "duplicates": int(self.duplicates),
         }, {"lat_s": lat.astype(np.float64), "lat_w": w.astype(np.int64),
             "lag_s": lags.astype(np.float64), "admitted": self.admitted,
@@ -128,11 +125,12 @@ class Ledger:
 
 
 class Conn:
-    def __init__(self, port: int):
+    def __init__(self, port: int, family):
         self.sock = socket.create_connection(("127.0.0.1", port), timeout=10)
         self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         self.sock.settimeout(0.2)
-        self.split = wire.Splitter()
+        self.split = wire.Splitter(family.SINGLE_REPLIES,
+                                   family.BATCH_REPLIES)
         self.dead = False
 
     def send(self, data) -> bool:
@@ -171,13 +169,15 @@ class Generator:
     def __init__(self, plan: dict):
         self.plan = plan
         self.t = plan["traffic"]
-        self.dep = deploy.Deployment(deploy.load_json(plan["config_file"]))
+        self.dep = deploy.load(plan["config_file"],
+                               plan.get("family_dirs", ()))
+        self.fam = self.dep.family
         self.proc = int(plan["proc"])
         self.n_procs = int(self.t["processes"])
         self.seed = int(plan["seed"])
         self.single = self.t["msg"] == "single"
         self.open = self.t["loop"] == "open"
-        self.rows = 1 if self.single else int(self.t["frame_rows"])
+        self.rows = traffic.frame_rows(self.t)
         self.timeout_s = float(self.t["timeout_ms"]) / 1000.0
         self.next_xid = 1 + self.proc * 200_000_000
         self.conns = []
@@ -185,17 +185,17 @@ class Generator:
 
     # -- frames, made before the server is up -------------------------------
     def _build(self, seconds: float, warm_seconds: float) -> None:
-        mix = traffic.Mix(self.t, self.dep, self.seed, 1 + self.proc)
+        mix = self.fam.Mix(self.t, self.dep, self.seed, 1 + self.proc)
         if self.open:
             due = traffic.open_schedule(self.t, seconds)
             mine = np.arange(len(due)) % self.n_procs == self.proc
             # tenants are apportioned over ALL frames, then split by process
-            who = traffic.Mix(self.t, self.dep, self.seed,
-                              0).frame_tenants(len(due))[mine]
-            self.main = (due[mine],) + mix.rows(who)
+            who = self.fam.Mix(self.t, self.dep, self.seed,
+                               0).frame_tenants(len(due))[mine]
+            self.main = (due[mine], mix.rows(who))
             wdue = traffic.open_schedule(self.t, warm_seconds)
             wmine = np.arange(len(wdue)) % self.n_procs == self.proc
-            self.warm = (wdue[wmine],) + mix.frames(int(wmine.sum()))
+            self.warm = (wdue[wmine], mix.frames(int(wmine.sum())))
         else:
             n_pool = (_SINGLE_POOL * int(self.t["connections"]) if self.single
                       else int(self.t.get("pool_frames", 512)))
@@ -206,7 +206,8 @@ class Generator:
         return int(len(self.main[0]) * self.rows) if self.open else 0
 
     def connect(self, port: int) -> None:
-        self.conns = [Conn(port) for _ in range(int(self.t["connections"]))]
+        self.conns = [Conn(port, self.fam)
+                      for _ in range(int(self.t["connections"]))]
 
     def _xids(self, n: int) -> int:
         x0 = self.next_xid
@@ -214,11 +215,14 @@ class Generator:
         return x0
 
     # -- open loop -----------------------------------------------------------
-    def run_open(self, t0: float, due, ids, acq, window: int,
+    def run_open(self, t0: float, due, cols, window: int,
                  seconds: float) -> Ledger:
         n = len(due)
         x0 = self._xids(n)
-        enc = traffic.encode_frames(ids, acq, x0)
+        if self.single:  # one packed array, frame k at ``enc[k]``
+            enc = self.fam.encode_singles(x0, *[col[:, 0] for col in cols])
+        else:
+            enc = traffic.encode_frames(self.fam, cols, x0)
         led = Ledger(self.dep, t0, t0 + seconds)
         led.attempted = n * self.rows
         due_abs = t0 + due
@@ -233,6 +237,9 @@ class Generator:
                 if not got:
                     continue
                 now = time.monotonic()
+                if got[1] is not None:
+                    self._singles_back(led, state, replied, now, got[1], x0,
+                                       due_abs, cols)
                 for xid, rows in got[0]:
                     k = xid - x0
                     if not 0 <= k < n:
@@ -249,16 +256,40 @@ class Generator:
                     m = min(len(rows), self.rows)
                     if m < self.rows:
                         led.fail("short_reply", self.rows - m)
-                    led.rows_back(now, now - due_abs[k], ids[k][:m],
-                                  acq[k][:m], rows["status"][:m],
-                                  rows["remaining"][:m])
+                    led.rows_back(now, now - due_abs[k],
+                                  [col[k][:m] for col in cols],
+                                  rows["status"][:m], rows["remaining"][:m])
 
         threads = [threading.Thread(target=reader, args=(c,), daemon=True)
                    for c in conns]
         for th in threads:
             th.start()
         lag = np.zeros(n)
-        for k in range(n):
+        send = self._send_singles if self.single else self._send_frames
+        send(led, state, replied, was_sent, lag, enc, due_abs, window)
+        deadline = max(due_abs[-1], time.monotonic()) + self.timeout_s
+        while time.monotonic() < deadline and not replied.all():
+            if all(c.dead for c in conns):
+                break
+            time.sleep(0.005)
+        state["done"] = True
+        for th in threads:
+            th.join(timeout=2.0)
+        lost = int((~replied).sum())
+        if lost:
+            dead_conn = np.array([conns[k % len(conns)].dead
+                                  for k in np.flatnonzero(~replied)])
+            led.fail("connection", int(dead_conn.sum()) * self.rows)
+            led.fail("timeout", int((~dead_conn).sum()) * self.rows)
+        led.lags.append(lag[was_sent])
+        return led
+
+    def _send_frames(self, led, state, replied, was_sent, lag, enc, due_abs,
+                     window: int) -> None:
+        """The open loop's sender for batch frames: frame k at its due time,
+        on connection ``k % connections``."""
+        conns = self.conns
+        for k in range(len(due_abs)):
             wait = due_abs[k] - time.monotonic()
             if wait > 0:
                 time.sleep(wait)
@@ -283,22 +314,71 @@ class Generator:
                     state["inflight"] -= 1
                     replied[k] = True
                 led.fail("connection", self.rows)
-        deadline = max(due_abs[-1], time.monotonic()) + self.timeout_s
-        while time.monotonic() < deadline and not replied.all():
-            if all(c.dead for c in conns):
-                break
-            time.sleep(0.005)
-        state["done"] = True
-        for th in threads:
-            th.join(timeout=2.0)
-        lost = int((~replied).sum())
-        if lost:
-            dead_conn = np.array([conns[k % len(conns)].dead
-                                  for k in np.flatnonzero(~replied)])
-            led.fail("connection", int(dead_conn.sum()) * self.rows)
-            led.fail("timeout", int((~dead_conn).sum()) * self.rows)
-        led.lags.append(lag[was_sent])
-        return led
+
+    def _send_singles(self, led, state, replied, was_sent, lag, enc, due_abs,
+                      window: int) -> None:
+        """The open loop's sender for one-row frames, which are due faster
+        than one can be sent alone: every frame that is due goes out in one
+        run, a connection's share of the run (frame k on connection
+        ``k % connections``, as for batch frames) in one send, and the next
+        run no sooner than ``RUN_S`` later. So a frame is sent when it is
+        due while frames are further apart than that, and at most ``RUN_S``
+        late when they are not (the lag says which). Frames past the
+        in-flight window are skipped and fail, frame by frame."""
+        conns = self.conns
+        n, n_conn = len(due_abs), len(conns)
+        k = 0
+        last_run = 0.0
+        while k < n:
+            now = time.monotonic()
+            wait = max(due_abs[k], last_run + RUN_S) - now
+            if wait > 0:
+                time.sleep(wait)
+                continue
+            last_run = now
+            j = int(np.searchsorted(due_abs, now, side="right"))
+            with led.lock:
+                take = min(j - k, max(0, window - state["inflight"]))
+                state["inflight"] += take
+            if take < j - k:
+                led.fail("skipped", j - k - take)
+                replied[k + take:j] = True
+            end = k + take
+            now = time.monotonic()
+            lag[k:end] = now - due_abs[k:end]
+            for ci, c in enumerate(conns):
+                first = k + (ci - k) % n_conn
+                if first >= end:
+                    continue
+                if not c.dead and c.send(enc[first:end:n_conn].tobytes()):
+                    was_sent[first:end:n_conn] = True
+                    continue
+                lost = len(range(first, end, n_conn))
+                with led.lock:
+                    state["inflight"] -= lost
+                    replied[first:end:n_conn] = True
+                led.fail("connection", lost)
+            k = j
+
+    def _singles_back(self, led, state, replied, now: float, rsp, x0: int,
+                      due_abs, cols) -> None:
+        """One-row replies of the open loop, a chunk at a time."""
+        k = rsp["xid"].astype(np.int64) - x0
+        known = (k >= 0) & (k < len(due_abs))
+        if not known.all():
+            rsp, k = rsp[known], k[known]
+        first = np.zeros(len(k), bool)
+        first[np.unique(k, return_index=True)[1]] = True
+        with led.lock:
+            fresh = first & ~replied[k]
+            replied[k] = True
+            led.duplicates += int((~fresh).sum())
+            state["inflight"] -= int(fresh.sum())
+        if not fresh.all():
+            rsp, k = rsp[fresh], k[fresh]
+        if len(k):
+            led.rows_back(now, now - due_abs[k], [col[k, 0] for col in cols],
+                          rsp["status"], rsp["remaining"])
 
     # -- closed loop ---------------------------------------------------------
     def run_closed(self, t0: float, seconds: float) -> Ledger:
@@ -318,7 +398,8 @@ class Generator:
         return led
 
     def _closed_batch(self, led: Ledger, ci: int, c: Conn) -> None:
-        ids, acq = self.pool
+        cols = self.pool
+        n_pool = len(cols[0])
         n_conn = len(self.conns)
         depth = int(self.t["outstanding"])
         x0 = self._xids_block()
@@ -328,7 +409,7 @@ class Generator:
 
         def send_one() -> bool:
             nonlocal j, attempted
-            p = (ci + j * n_conn) % len(ids)
+            p = (ci + j * n_conn) % n_pool
             xid = x0 + j
             j += 1
             now = time.monotonic()
@@ -336,7 +417,8 @@ class Generator:
                 return False
             pending[xid] = (p, now)
             attempted += self.rows
-            if not c.send(wire.encode_batch(xid, ids[p], acq[p])):
+            if not c.send(self.fam.encode_batch(
+                    xid, *[col[p] for col in cols])):
                 return False
             return True
 
@@ -358,7 +440,7 @@ class Generator:
                 m = min(len(rows), self.rows)
                 if m < self.rows:
                     led.fail("short_reply", self.rows - m)
-                led.rows_back(now, now - at, ids[p][:m], acq[p][:m],
+                led.rows_back(now, now - at, [col[p][:m] for col in cols],
                               rows["status"][:m], rows["remaining"][:m])
                 send_one()
         with led.lock:
@@ -373,10 +455,9 @@ class Generator:
             return self._xids(20_000_000)
 
     def _closed_single(self, led: Ledger, ci: int, c: Conn) -> None:
-        ids_all, acq_all = self.pool
-        ids = ids_all[ci * _SINGLE_POOL:(ci + 1) * _SINGLE_POOL, 0]
-        acq = acq_all[ci * _SINGLE_POOL:(ci + 1) * _SINGLE_POOL, 0]
-        reqs = wire.encode_singles(0, ids, acq)
+        cols = [col[ci * _SINGLE_POOL:(ci + 1) * _SINGLE_POOL, 0]
+                for col in self.pool]
+        reqs = self.fam.encode_singles(0, *cols)
         depth = int(self.t["outstanding"])
         x0 = self._xids_block()
         sent_at = np.zeros(_SENT_RING)
@@ -411,7 +492,7 @@ class Generator:
                 rsp, k = rsp[known], k[known]
             back += len(k)
             led.rows_back(now, now - sent_at[k % _SENT_RING],
-                          ids[k % _SINGLE_POOL], acq[k % _SINGLE_POOL],
+                          [col[k % _SINGLE_POOL] for col in cols],
                           rsp["status"], rsp["remaining"])
             send(len(k))
         with led.lock:
@@ -424,8 +505,8 @@ class Generator:
         t0 = time.monotonic() + 0.05
         secs = float(self.plan["warm_seconds"])
         if self.open:
-            due, ids, acq = self.warm
-            led = self.run_open(t0, due, ids, acq,
+            due, cols = self.warm
+            led = self.run_open(t0, due, cols,
                                 int(self.t["inflight_window_frames"]), secs)
         else:
             led = self.run_closed(t0, secs)
@@ -437,8 +518,8 @@ class Generator:
         t0 = time.monotonic() + 0.02
         if self.open:
             n = max(1, rows // self.rows)
-            ids, acq = self.burst_mix.frames(n)
-            led = self.run_open(t0, np.zeros(n), ids, acq, n, 0.5)
+            led = self.run_open(t0, np.zeros(n), self.burst_mix.frames(n),
+                                n, 0.5)
         else:
             led = self.run_closed(t0, 0.5)
         return led.summary()[0]
@@ -461,8 +542,8 @@ class Generator:
 
     def _measure(self, t0: float, seconds: float, out: str) -> dict:
         if self.open:
-            due, ids, acq = self.main
-            led = self.run_open(t0, due, ids, acq,
+            due, cols = self.main
+            led = self.run_open(t0, due, cols,
                                 int(self.t["inflight_window_frames"]),
                                 seconds)
         else:

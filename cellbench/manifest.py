@@ -4,6 +4,9 @@ own, found by name in the directories ``paths`` lists, so a later PR adds
 them without editing a file that is there.
 
     configuration   ``configs[].file``                       (JSON)
+    rule family     ``<a path>/families/<family>.py``, named by the
+                    configuration file's ``"family"`` (absent: ``flow``);
+                    found by ``deploy.load`` in ``Cell.dirs``
     traffic mix     ``<a path>/traffic/<traffic>.json``
     per-layer       ``<a path>/layers/<metric name>.py`` with NAME, UNIT,
     reader          LAYER, MOVES, SOURCE and ``reduce(snap)``
@@ -34,6 +37,7 @@ class Cell:
         self.chips = int(self.cell["chips"])
         cfg = next(c for c in m["configs"] if c["name"] == self.cell["config"])
         self.config_file = os.path.join(self.root, cfg["file"])
+        self.dirs = [os.path.join(self.root, p) for p in m["paths"]]
         self.traffic_file = self._find(
             os.path.join("traffic", self.cell["traffic"] + ".json"))
         self.peaks_file = self._find("peaks.json")

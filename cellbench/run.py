@@ -8,6 +8,11 @@ door (``warmup()``), warm up by the cell's own traffic until nothing compiles
 any more, let the rule windows drain, measure for ``--seconds``, probe the
 verdicts through the same door, print one JSON line. Without an accelerator,
 or with fewer chips than the cell asks for, it exits 2 and prints no result.
+
+What a rule, a row and a frame are is the deployment's family's
+(``cellbench/families/``); this module keeps the order of a run, the timing,
+the void windows and the stall watch, and calls the family where it would
+have to know.
 """
 
 from __future__ import annotations
@@ -29,12 +34,13 @@ import numpy as np  # noqa: E402
 if __package__ in (None, ""):
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from cellbench import deploy, manifest, stallwatch, traffic  # noqa: E402
+from cellbench import deploy, manifest, stallwatch  # noqa: E402
 
 ROOT = manifest.ROOT
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_SECONDS = 3.0  # the slice of the window the profiler records
-ADMIT_SPAN_BINS = 7  # 700 ms of reply time: inside every 900..1000 ms window
+REPLY_BIN_S = 0.1  # loadgen.BIN_S: the admitted-token ledger's bins
+USUAL_REPLY_S = 0.2  # a reply is usually this fast or faster
 WARM_SECONDS = 1.5
 MAX_WARM_PASSES = 4
 FREEZE_S = 0.4  # a gap this long in every process at once: the machine's
@@ -72,7 +78,8 @@ class Clients:
         self.port_file = os.path.join(work, "port")
         for i in range(int(cell.traffic["processes"])):
             plan = {"traffic": cell.traffic, "config_file": cell.config_file,
-                    "seed": seed, "proc": i, "seconds": seconds,
+                    "family_dirs": cell.dirs, "seed": seed, "proc": i,
+                    "seconds": seconds,
                     "warm_seconds": float(cell.traffic.get(
                         "warm_seconds", WARM_SECONDS)),
                     "port_file": self.port_file}
@@ -215,39 +222,19 @@ def settle(built, dep) -> None:
     time.sleep((dep.window_ms + 2 * dep.bucket_ms) / 1000.0)
 
 
-def reachable_depths(dep, tr: dict, server) -> list:
-    """Fusion-ladder depths a dispatch through this door can reach with this
-    mix: the lane folds up to ``fuse_depth`` pulls of ``max_batch`` rows, and
-    the mix cannot have more rows in flight than its own cap."""
-    most = min(server.fuse_depth * server.max_batch,
-               traffic.reachable_rows(tr))
-    full = most // int(dep.spec["engine"]["batch_size"])
-    return sorted(d for d in dep.spec["fuse_depths"] if d <= full)
-
-
 def warm_up(built, clients, cell, dep, seed, compiles, say) -> dict:
     """Drive every kind of dispatch the mix can reach before the window:
-    the steady mix, each reachable fused depth with the mix's own acquires,
-    backlog bursts through the door; again until a pass compiles nothing."""
+    what the family drives in process (flow: each reachable fused depth with
+    the mix's own acquires), the steady mix, backlog bursts through the door;
+    again until a pass compiles nothing."""
     from sentinel_tpu.trace import ring as flight
 
     parts = {}
     t_w = time.monotonic()
     flight.arm(sample=0.0)  # aggregate stages only: FUSE, DEVICE_IN/OUT
     since = time.monotonic_ns()
-    depths = reachable_depths(dep, cell.traffic, built.server)
-    mix = traffic.Mix(cell.traffic, dep, seed, 991)
-    cap = int(dep.spec["engine"]["batch_size"])
-    rows = mix.frame_rows
-    for d in depths:
-        # a full-depth backlog of the mix's own rows, as one call of the
-        # service's public entry: what the lane hands over after a stall
-        ids, acq = mix.frames(-(-d * cap // rows))
-        n0 = len(compiles)
-        built.service.request_batch_arrays(
-            ids.reshape(-1)[:d * cap], acq.reshape(-1)[:d * cap])
-        say(f"warm-up: depth-{d} backlog of {d * cap} rows in process, "
-            f"{len(compiles) - n0} compiles")
+    depths = dep.family.drive_before_window(built, cell.traffic, dep, seed,
+                                            compiles, say)
     burst_rows = 2 * built.server.fuse_depth * built.server.max_batch
     passes = 0
     while True:
@@ -316,7 +303,7 @@ def trace_slice(work: str, t_from: float, seconds: float, sample: float,
 
 def merge_clients(results: list, work: str, clients: Clients) -> dict:
     tot = {"attempted": 0, "failed_rows": 0, "decided": 0,
-           "decided_in_window": 0, "unmetered_blocked": 0, "duplicates": 0,
+           "decided_in_window": 0, "never_rows": 0, "duplicates": 0,
            "failed": {}, "status_hist": np.zeros(16, np.int64)}
     lat, w, lag, admitted, lat_max = [], [], [], None, None
     for i, r in enumerate(results):
@@ -327,7 +314,7 @@ def merge_clients(results: list, work: str, clients: Clients) -> dict:
                 tot["failed"].get("lost_client", 0) + clients.planned[i])
             continue
         for k in ("attempted", "failed_rows", "decided", "decided_in_window",
-                  "unmetered_blocked", "duplicates"):
+                  "never_rows", "duplicates"):
             tot[k] += r[k]
         for k, v in r["failed"].items():
             tot["failed"][k] = tot["failed"].get(k, 0) + v
@@ -348,11 +335,14 @@ def merge_clients(results: list, work: str, clients: Clients) -> dict:
     return tot
 
 
-def window_invariants(client: dict, dep, say) -> bool:
-    """The guarantees, held against the window's own replies."""
+def window_invariants(client: dict, dep, say, compared=None) -> bool:
+    """The guarantees, held against the window's own replies. ``compared``
+    (a dict) takes every number compared as ``name: [number, limit]``."""
     ok = True
-    checks = [("unmetered rows BLOCKED", client["unmetered_blocked"], 0),
-              ("rows answered twice", client["duplicates"], 0)]
+    if compared is None:
+        compared = {}
+    checks = dep.window_checks(client) + [
+        ("rows answered twice", client["duplicates"], 0)]
     legal = np.zeros(16, bool)
     legal[[deploy.OK, deploy.BLOCKED, deploy.SHOULD_WAIT, deploy.NO_RULE,
            deploy.TOO_MANY, deploy.FAIL, deploy.OVERLOAD, deploy.STANDBY,
@@ -361,23 +351,28 @@ def window_invariants(client: dict, dep, say) -> bool:
                    int(client["status_hist"][~legal].sum()), 0))
     for what, got, limit in checks:
         say(f"invariant {what}: {got} (limit {limit})")
+        compared[what.replace(" ", "_")] = [int(got), limit]
         ok &= got <= limit
     adm = client["admitted"]
     if adm is not None and adm.size:
-        # Tokens admitted to a metered flow, by the time their reply came.
-        # Every window of the server spans 900..1000 ms of decisions, and a
-        # reply comes at most its latency after its decision: a 700 ms span
-        # of replies whose slowest took L holds decisions of 700 ms + L,
-        # which ceil((0.7 + L) / 0.9) windows cover. With L under 200 ms,
-        # the usual case, that is one window and the limit is the count.
-        k = ADMIT_SPAN_BINS
+        # Tokens admitted under a ledger key (a metered flow), by the time
+        # their reply came. Every window of the server spans one bucket less
+        # than ``window_ms`` up to ``window_ms`` of decisions (900..1000 ms
+        # with ten buckets of 100), and a reply comes at most its latency
+        # after its decision: a span of replies whose slowest took L holds
+        # decisions of the span + L, which ceil((span + L) / shortest window)
+        # windows cover. The span is the shortest window less a usual reply
+        # (700 ms there): with L under 200 ms, the usual case, that is one
+        # window and the limit is the count.
+        shortest = (dep.window_ms - dep.bucket_ms) / 1000.0
+        k = max(1, int(round((shortest - USUAL_REPLY_S) / REPLY_BIN_S)))
         c = np.cumsum(np.pad(adm, ((0, 0), (k, 0))), axis=1)
-        most = c[:, k:] - c[:, :-k]  # [flows, spans ending at each bin]
+        most = c[:, k:] - c[:, :-k]  # [keys, spans ending at each bin]
         lm = np.pad(client["lat_max"], (k - 1, 0))
         slowest = np.max(np.stack([lm[i:i + adm.shape[1]]
                                    for i in range(k)]), axis=0)
-        windows = np.ceil((k * 0.1 + slowest) / 0.9 - 1e-9)
-        allowed = (dep.metered_count_of_index()[:, None]
+        windows = np.ceil((k * REPLY_BIN_S + slowest) / shortest - 1e-9)
+        allowed = (dep.ledger_counts()[:, None]
                    * dep.window_ms / 1000.0 * windows[None, :])
         ratio = (most / allowed).max(axis=1)
         worst = int(np.argmax(ratio))
@@ -385,22 +380,20 @@ def window_invariants(client: dict, dep, say) -> bool:
             f"{int((most.max(axis=1) > 0).sum())} metered flows: "
             f"{ratio[worst]:.6f} (limit 1; slowest reply "
             f"{client['lat_max'].max() * 1e3:.1f} ms)")
+        compared["admitted_over_count"] = [float(ratio[worst]), 1]
         ok &= bool(ratio[worst] <= 1.0)
     return ok
 
 
 def window(clients, cell, seconds: float, trace: int, work: str,
-           compiles: list, say):
+           compiles: list, say, progress):
     """One measured window: counters before, the generators' window (with
     the traced slice inside it, and the stall watch over all of it), counters
-    after."""
-    from sentinel_tpu.metrics.server import server_metrics
-
+    after. ``progress`` is the family's count of finished work, which the
+    stall watch expects to keep rising."""
     c0 = server_counters()
     t0 = time.monotonic() + 0.25
-    done = server_metrics().decide_ms
-    watch = stallwatch.StallWatch(lambda: done.count,
-                                  os.path.join(work, "stalls.txt"))
+    watch = stallwatch.StallWatch(progress, os.path.join(work, "stalls.txt"))
     watch.start(t0)
     sliced = {}
     tracer = None
@@ -532,7 +525,7 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
     result object; raises SystemExit(2) without the chips."""
     t_proc = process_start_monotonic()
     cell = manifest.Cell(manifest_path, workload)
-    dep = deploy.Deployment(deploy.load_json(cell.config_file))
+    dep = deploy.load(cell.config_file, cell.dirs)
 
     devices, say, compiles = start_jax(cell, require_chip, out)
     kind = devices[0].device_kind
@@ -562,7 +555,8 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
         # counted and measured again, at most MAX_VOID_WINDOWS times.
         while True:
             t0, c0, c1, sliced, in_window, results = window(
-                clients, cell, seconds, trace, work, compiles, say)
+                clients, cell, seconds, trace, work, compiles, say,
+                dep.family.progress(built))
             client = merge_clients(results, work, clients)
             froze = machine_froze(
                 t0, sliced["stalls"]["gaps"],
@@ -591,7 +585,10 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
 
         probed = probe.Probe(built.server.port, dep, cell.traffic, seed,
                              say=say).run()
-        sound = window_invariants(client, dep, say)
+        compared = {"probe_" + c["check"]: [c.get("mismatches", c.get(
+            "error")), c.get("limit", 0)] for c in probed["checks"]}
+        sound = window_invariants(client, dep, say, compared)
+        compared["generators_lost"] = [len(clients.lost), 0]
         correct = bool(probed["ok"] and sound and not clients.lost)
         peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                    for d in devices)
@@ -632,6 +629,7 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
             if m["name"] in e2e:
                 result["metrics"][m["name"]] = {
                     "value": e2e[m["name"]], "unit": units[m["name"]]}
+        result["compared"] = compared
         return result
 
     # -- the traced run: per-layer metrics -----------------------------------
@@ -639,8 +637,8 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
 
     t = tr.Trace(tr.find_xplane(sliced["dir"]))
     flow = [e for e in sliced["events"] if e["stage"] in (
-        "client_in", "enqueue", "dispatch", "device_in", "device_out",
-        "reply_out")]
+        "client_in", "enqueue", "permit", "prep", "locked", "dispatch",
+        "device_in", "ready", "fetched", "device_out", "reply_out")]
     reduced = tr.reduce(
         t, sliced["lo_ns"], sliced["hi_ns"],
         np.asarray([e["t_ns"] for e in flow], np.float64),
@@ -673,6 +671,7 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
     result["breakdown"] = {"device_ops": reduced["device_ops"],
                            "idle_gaps": reduced["idle_gaps"]}
     say("programs on the median chip: " + json.dumps(reduced["modules"]))
+    result["compared"] = compared
     return result
 
 
@@ -686,6 +685,11 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     result = run_cell(args.manifest, args.workload, args.seed, args.seconds,
                       args.trace)
+    # every number compared, beside its limit: the last lines of standard
+    # error, and the last key of the result line
+    for name, (got, limit) in result["compared"].items():
+        print(f"compared {name}: {got} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
 
 

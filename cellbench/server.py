@@ -1,6 +1,9 @@
 """The system under test, built from a deployment file through the program's
 public doors: ``DefaultTokenService`` + ``NativeTokenServer`` with the
-server's own defaults for overload, age shed and fusion.
+server's own defaults for overload, age shed and fusion. Which rules are
+loaded, and which constructor arguments the file states beyond the engine's,
+is the deployment's family's (``service_args``, ``load_rules``); the door is
+not a family's.
 """
 
 from __future__ import annotations
@@ -26,8 +29,7 @@ def build(dep, devices, say, wrap_service=None) -> Built:
     broken service in the timed path's place."""
     from sentinel_tpu.cluster.server_native import NativeTokenServer
     from sentinel_tpu.cluster.token_service import DefaultTokenService
-    from sentinel_tpu.engine import ClusterFlowRule, EngineConfig
-    from sentinel_tpu.engine.rules import ThresholdMode
+    from sentinel_tpu.engine import EngineConfig
     from sentinel_tpu.native import lib as native_lib
 
     parts = {}
@@ -51,17 +53,10 @@ def build(dep, devices, say, wrap_service=None) -> Built:
     service = DefaultTokenService(
         config, serve_buckets=tuple(spec["serve_buckets"]),
         fuse_depths=tuple(spec["fuse_depths"]), mesh=mesh,
+        **dep.family.service_args(dep),
     )
     t = time.monotonic()
-    service.load_rules(
-        [ClusterFlowRule(fid, count, ThresholdMode.GLOBAL, ns,
-                         control_behavior=behaviour)
-         for fid, count, ns, behaviour in dep.rules()],
-        ns_max_qps=dep.ns_max_qps,
-    )
-    n_rules = len(service.current_rules())
-    if n_rules != dep.n_flows:
-        raise RuntimeError(f"{n_rules} rules loaded, {dep.n_flows} in the file")
+    n_rules = dep.family.load_rules(service, dep)
     parts["rule_load_s"] = time.monotonic() - t
     door = spec["door"]
     if door["kind"] != "native_tcp":

@@ -34,7 +34,7 @@ def main() -> None:
     ap.add_argument("--manifest", default=manifest.ROOT + "/BENCHMARK.json")
     args = ap.parse_args()
     cell = manifest.Cell(args.manifest, args.workload)
-    dep = deploy.Deployment(deploy.load_json(cell.config_file))
+    dep = deploy.load(cell.config_file, cell.dirs)
     devices, say, compiles = run.start_jax(cell, require_chip=True)
     from cellbench import server as sut
 
@@ -60,7 +60,8 @@ def main() -> None:
                         clients.command("warm")
                         time.sleep(dep.window_ms / 1000.0 + 0.2)
                     _t0, c0, c1, sliced, in_w, results = run.window(
-                        clients, point, args.seconds, 0, work, compiles, say)
+                        clients, point, args.seconds, 0, work, compiles, say,
+                        dep.family.progress(built))
                     client = run.merge_clients(results, work, clients)
                 finally:
                     clients.close()

@@ -8,7 +8,8 @@ import time
 import numpy as np
 import pytest
 
-from cellbench import deploy, loadgen, reference
+from cellbench import loadgen
+from cellbench.families import flow as deploy, flow_reference as reference
 
 from fake_door import FakeDoor, reference_decider
 

@@ -12,7 +12,9 @@ import os
 
 import pytest
 
-from cellbench import control, deploy, probe, reference, run
+from cellbench import probe, run
+from cellbench.families import (flow as control, flow as deploy,
+                                flow_reference as reference)
 
 from fake_door import FakeDoor, reference_decider
 

@@ -1,6 +1,6 @@
 """The plain reference against windows worked out by hand."""
 
-from cellbench import deploy, reference
+from cellbench.families import flow as deploy, flow_reference as reference
 from cellbench.deploy import (BLOCKED, DEFAULT, NO_RULE, OK, RATE_LIMITER,
                               SHOULD_WAIT, TOO_MANY)
 

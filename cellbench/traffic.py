@@ -1,32 +1,26 @@
-"""The one general traffic generator. A mix is a data file of parameters
-(``cellbench/traffic/<mix>.json``); nothing here knows a mix by name.
+"""The general part of the traffic generator. A mix is a data file of
+parameters (``<a path>/traffic/<mix>.json``); nothing here knows a mix by
+name, and what a row is belongs to the deployment's family
+(``cellbench/families/``), whose ``Mix`` draws the rows.
 
-Parameters of a mix:
+Parameters of every mix:
 
     loop        "open" (absolute schedule at ``rate_rows_per_s``) or "closed"
                 (``connections`` x ``outstanding`` frames kept in flight)
-    msg         "batch" (BATCH_FLOW frames of ``frame_rows`` rows) or
-                "single" (one-token FLOW frames)
-    tenants     {"popularity": "zipf"|"uniform", "theta": t}: the namespace a
-                frame's rows belong to (one tenant's sidecar sends a frame)
-    flows       {"dist": "zipf"|"uniform", "theta": t}: a row's flow by its
-                popularity rank inside the tenant
-    acquire     {"values": [...], "weights": [...]}: tokens a row asks for
+    msg         "batch" (frames of ``frame_rows`` rows) or "single" (one-row
+                frames)
     processes, connections, inflight_window_frames, timeout_ms, trace_sample
 
-Every seed gives the same multiset of frame sizes, arrival times and
-tenant frames; the seed permutes which tenant sends when and draws the flows
-and the acquires.
+The parameters that say which rows a frame holds are the family's (for flow
+tables ``tenants``, ``flows``, ``acquire``: ``families/flow.py``).
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from cellbench import wire
 
-
-def _pmf(kind: str, n: int, theta: float) -> np.ndarray:
+def pmf(kind: str, n: int, theta: float) -> np.ndarray:
     if kind == "uniform":
         return np.full(n, 1.0 / n)
     if kind == "zipf":  # bounded: rank k drawn in proportion to k^-theta
@@ -35,7 +29,7 @@ def _pmf(kind: str, n: int, theta: float) -> np.ndarray:
     raise ValueError(f"unknown distribution {kind!r}")
 
 
-def _apportion(p: np.ndarray, total: int) -> np.ndarray:
+def apportion(p: np.ndarray, total: int) -> np.ndarray:
     """``total`` split in proportion to ``p`` by largest remainder: the same
     counts for every seed."""
     raw = p * total
@@ -46,55 +40,11 @@ def _apportion(p: np.ndarray, total: int) -> np.ndarray:
     return base
 
 
-class Mix:
-    """Draws frames of one traffic mix over one deployment."""
-
-    def __init__(self, traffic: dict, deployment, seed: int, salt: int):
-        self.t = traffic
-        self.d = deployment
-        self.rng = np.random.default_rng([int(seed), int(salt)])
-        self.frame_rows = 1 if traffic["msg"] == "single" else int(
-            traffic["frame_rows"])
-        self.tenants = np.asarray(deployment.traffic_namespaces(), np.int64)
-        tp = traffic["tenants"]
-        self.tenant_p = _pmf(tp["popularity"], len(self.tenants),
-                             tp.get("theta", 0.0))
-        fl = traffic["flows"]
-        self.flow_cdf = np.cumsum(_pmf(
-            fl["dist"], deployment.flows_per_namespace(),
-            fl.get("theta", 0.0)))
-        acq = traffic["acquire"]
-        self.acq_values = np.asarray(acq["values"], np.int32)
-        w = np.asarray(acq["weights"], np.float64)
-        self.acq_cdf = np.cumsum(w / w.sum())
-        self.uniform_acquire = len(self.acq_values) == 1
-
-    def frame_tenants(self, n_frames: int) -> np.ndarray:
-        counts = _apportion(self.tenant_p, n_frames)
-        who = np.repeat(self.tenants, counts)
-        self.rng.shuffle(who)
-        return who
-
-    def rows(self, frame_tenants: np.ndarray):
-        """``(flow_ids, acquires)`` as ``[n_frames, frame_rows]`` arrays."""
-        shape = (len(frame_tenants), self.frame_rows)
-        rank = np.searchsorted(self.flow_cdf, self.rng.random(shape))
-        rank = np.minimum(rank, len(self.flow_cdf) - 1)
-        ids = self.d.flow_id(frame_tenants[:, None], rank)
-        if self.uniform_acquire:
-            acq = np.full(shape, self.acq_values[0], np.int32)
-        else:
-            at = np.searchsorted(self.acq_cdf, self.rng.random(shape))
-            acq = self.acq_values[np.minimum(at, len(self.acq_values) - 1)]
-        return ids, acq
-
-    def frames(self, n_frames: int):
-        return self.rows(self.frame_tenants(n_frames))
-
-
-def encode_frames(ids: np.ndarray, acq: np.ndarray, first_xid: int) -> list:
-    return [wire.encode_batch(first_xid + k, ids[k], acq[k])
-            for k in range(len(ids))]
+def encode_frames(family, cols, first_xid: int) -> list:
+    """The batch frames of ``cols`` (a mix's columns, ``[n_frames,
+    frame_rows, ...]`` each), as bytes, with consecutive xids."""
+    return [family.encode_batch(first_xid + k, *[c[k] for c in cols])
+            for k in range(len(cols[0]))]
 
 
 def open_schedule(traffic: dict, seconds: float):
@@ -103,7 +53,7 @@ def open_schedule(traffic: dict, seconds: float):
     "last send + dt". ``phases`` (optional) multiplies the rate by ``factor``
     for ``for_s`` seconds in every ``every_s``, from ``start_s`` on: a flash
     crowd. The same schedule for every seed."""
-    rows = float(traffic["frame_rows"])
+    rows = float(frame_rows(traffic))
     rate = float(traffic["rate_rows_per_s"])
     phases = traffic.get("phases")
     if not phases:
@@ -121,9 +71,13 @@ def open_schedule(traffic: dict, seconds: float):
     return np.interp(np.arange(n) * rows, sent, t)
 
 
+def frame_rows(traffic: dict) -> int:
+    return 1 if traffic["msg"] == "single" else int(traffic["frame_rows"])
+
+
 def reachable_rows(traffic: dict) -> int:
     """The most rows the mix can have in flight at the door."""
-    rows = 1 if traffic["msg"] == "single" else int(traffic["frame_rows"])
+    rows = frame_rows(traffic)
     if traffic["loop"] == "open":
         return rows * int(traffic["inflight_window_frames"])
     return rows * int(traffic["connections"]) * int(

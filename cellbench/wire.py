@@ -10,6 +10,10 @@ codec. The layout is the reference's: a 2-byte big-endian length, then
                          response status:i8 remaining:i32 wait_ms:i32
     BATCH_FLOW (type 5)  request  n:u16 then n rows of the FLOW request body
                          response n:u16 then n rows of the FLOW response body
+
+These two are the flow family's frames. A family with frames of its own
+(``cellbench/families/``) writes their encoders beside its other code and
+tells ``Splitter`` which reply types to return, and in which layout.
 """
 
 from __future__ import annotations
@@ -63,12 +67,17 @@ class Splitter:
     """Incremental splitter of the response stream of one connection.
 
     ``feed`` returns ``(batch, singles)``: a list of ``(xid, rows)`` for the
-    BATCH_FLOW responses completed by this chunk (``rows`` a ``RSP_ROW``
-    array) and a ``SINGLE_RSP`` array of the FLOW responses. Frames of any
-    other type (pushes, pings) are skipped by their length."""
+    batch responses completed by this chunk (``rows`` an array of the batch
+    row layout) and one array of the one-row responses, in their frame
+    layout. Which reply types those are, and their layouts, is the family's
+    (``SINGLE_REPLIES``, ``BATCH_REPLIES``; FLOW and BATCH_FLOW by default).
+    Frames of any other type (pushes, pings) are skipped by their length."""
 
-    def __init__(self):
+    def __init__(self, singles=((FLOW,), SINGLE_RSP),
+                 batches=((BATCH_FLOW,), RSP_ROW)):
         self._buf = bytearray()
+        self._s_types, self._s_dtype = singles
+        self._b_types, self._b_row = batches
 
     def feed(self, data: bytes):
         buf = self._buf
@@ -77,29 +86,31 @@ class Splitter:
         singles = []
         pos = 0
         end = len(buf)
+        s_types, b_types = self._s_types, self._b_types
+        size = self._s_dtype.itemsize  # a one-row reply, length prefix and all
+        len_hi, len_lo = (size - 2) >> 8, (size - 2) & 0xFF
+        row = self._b_row.itemsize
         while end - pos >= 2:
             flen = (buf[pos] << 8) | buf[pos + 1]
             if end - pos < 2 + flen:
                 break
             if flen >= 5:
                 mtype = buf[pos + 6]
-                if mtype == FLOW and flen == 14:
+                if mtype in s_types and flen == size - 2:
                     # a run of fixed-size frames: take them all at once
-                    k = 1
-                    while (end - pos - 16 * k >= 16
-                           and buf[pos + 16 * k + 1] == 14
-                           and buf[pos + 16 * k] == 0
-                           and buf[pos + 16 * k + 6] == FLOW):
-                        k += 1
-                    singles.append(np.frombuffer(
-                        bytes(buf[pos:pos + 16 * k]), SINGLE_RSP))
-                    pos += 16 * k
+                    q = pos + size
+                    while (end - q >= size and buf[q + 1] == len_lo
+                           and buf[q] == len_hi and buf[q + 6] == mtype):
+                        q += size
+                    singles.append(np.frombuffer(bytes(buf[pos:q]),
+                                                 self._s_dtype))
+                    pos = q
                     continue
-                if mtype == BATCH_FLOW and flen >= 7:
+                if mtype in b_types and flen >= 7:
                     xid = struct.unpack_from(">i", buf, pos + 2)[0]
                     n = (buf[pos + 7] << 8) | buf[pos + 8]
                     rows = np.frombuffer(
-                        bytes(buf[pos + 9:pos + 9 + 9 * n]), RSP_ROW)
+                        bytes(buf[pos + 9:pos + 9 + row * n]), self._b_row)
                     batch.append((xid, rows))
             pos += 2 + flen
         del buf[:pos]

@@ -11,7 +11,9 @@ corpus strategy:
 
 - pure random garbage (runt frames, bad types, random lengths);
 - MUTATED valid frames (bit flips in length/type/n/rows — the hardest class,
-  since most of the frame still parses);
+  since most of the frame still parses), BATCH_FLOW and, codec rev 8,
+  BATCH_PARAM_FLOW (type 27: ``n:u16 k:u8`` and rows of ``13 + 8k`` bytes,
+  so a flipped ``k`` moves every row boundary);
 - TRUNCATED valid frames followed by socket close mid-frame;
 - oversize declared n vs actual payload;
 - valid frames delivered 1–3 bytes at a time interleaved with garbage
@@ -46,6 +48,24 @@ def _valid_batch_frame(xid: int, n: int) -> bytes:
     return struct.pack(">H", len(payload)) + payload
 
 
+def _valid_param_frame(xid: int, n: int, k: int) -> bytes:
+    """One BATCH_PARAM_FLOW frame: ``n`` requests of ``k`` value hashes."""
+    rows = b"".join(
+        struct.pack(">qiB", random.randrange(0, 64), 1, 0)
+        + struct.pack(f">{k}q", *(random.randrange(1 << 40) for _ in range(k)))
+        for _ in range(n)
+    )
+    payload = struct.pack(">iB", xid, 27) + struct.pack(">HB", n, k) + rows
+    return struct.pack(">H", len(payload)) + payload
+
+
+def _valid_frame(xid: int, n: int, rng: random.Random) -> bytes:
+    """A valid batch frame of either data-plane kind."""
+    if rng.randrange(2):
+        return _valid_param_frame(xid, n, rng.randrange(1, 5))
+    return _valid_batch_frame(xid, n)
+
+
 def _valid_flow_frame(xid: int) -> bytes:
     payload = struct.pack(">iB", xid, 1) + struct.pack(">qiB", 1, 1, 0)
     return struct.pack(">H", len(payload)) + payload
@@ -60,20 +80,28 @@ def _mutate(frame: bytes, rng: random.Random) -> bytes:
 
 
 def _oracle_roundtrip(port: int, timeout: float = 5.0) -> bool:
-    """One valid BATCH_FLOW round trip on a fresh connection."""
+    """One valid BATCH_FLOW and one valid BATCH_PARAM_FLOW round trip on a
+    fresh connection: four verdict rows each, under the request's type."""
     with socket.create_connection(("127.0.0.1", port), timeout=timeout) as s:
         s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-        s.sendall(_valid_batch_frame(xid=7, n=4))
-        buf = b""
         s.settimeout(timeout)
-        while len(buf) < 2 or len(buf) < 2 + struct.unpack(">H", buf[:2])[0]:
-            chunk = s.recv(4096)
-            if not chunk:
+        for xid, mtype, frame in (
+            (7, 5, _valid_batch_frame(xid=7, n=4)),
+            (8, 27, _valid_param_frame(xid=8, n=4, k=2)),
+        ):
+            s.sendall(frame)
+            buf = b""
+            while (len(buf) < 2
+                   or len(buf) < 2 + struct.unpack(">H", buf[:2])[0]):
+                chunk = s.recv(4096)
+                if not chunk:
+                    return False
+                buf += chunk
+            flen = struct.unpack(">H", buf[:2])[0]
+            got = struct.unpack(">iBH", buf[2:9])
+            if got != (xid, mtype, 4) or flen != 7 + 4 * 9:
                 return False
-            buf += chunk
-        flen = struct.unpack(">H", buf[:2])[0]
-        xid, mtype = struct.unpack(">iB", buf[2:7])
-        return xid == 7 and mtype == 5 and flen >= 7
+        return True
     return False
 
 
@@ -87,20 +115,23 @@ def _fuzz_one_conn(port: int, rng: random.Random) -> None:
                 s.sendall(rng.randbytes(rng.randrange(1, 4096)))
             elif kind == 1:  # mutated valid frames
                 for _ in range(rng.randrange(1, 8)):
-                    f = _valid_batch_frame(rng.randrange(1, 1 << 30),
-                                           rng.randrange(0, 32))
+                    f = _valid_frame(rng.randrange(1, 1 << 30),
+                                     rng.randrange(0, 32), rng)
                     s.sendall(_mutate(f, rng))
             elif kind == 2:  # truncated frame, close mid-parse
-                f = _valid_batch_frame(1, rng.randrange(1, 64))
+                f = _valid_frame(1, rng.randrange(1, 64), rng)
                 s.sendall(f[: rng.randrange(1, len(f))])
             elif kind == 3:  # oversize declared n vs actual rows
                 n_claim = rng.randrange(64, 5000)
-                payload = (struct.pack(">iB", 1, 5)
-                           + struct.pack(">H", n_claim)
-                           + rng.randbytes(rng.randrange(0, 64)))
+                head = (struct.pack(">iBH", 1, 5, n_claim)
+                        if rng.randrange(2) else
+                        struct.pack(">iBHB", 1, 27, n_claim,
+                                    rng.randrange(0, 256)))
+                payload = head + rng.randbytes(rng.randrange(0, 64))
                 s.sendall(struct.pack(">H", len(payload)) + payload)
             else:  # drip-feed a valid frame in tiny chunks, then garbage
-                f = _valid_batch_frame(3, 8) + _valid_flow_frame(4)
+                f = (_valid_batch_frame(3, 8) + _valid_flow_frame(4)
+                     + _valid_param_frame(5, 8, 3))
                 i = 0
                 while i < len(f):
                     step = rng.randrange(1, 4)
@@ -189,13 +220,23 @@ def run_fuzz_raw(iters: int = 300, seed: int = 0,
     door = Frontdoor("127.0.0.1", 0, arena_cap=max(arena_cap, 1))
     stop = threading.Event()
 
+    cap = door.arena_cap
+    block = dict(
+        ids=np.empty(cap, np.int64), counts=np.empty(cap, np.int32),
+        prios=np.empty(cap, np.uint8), hashes=np.empty(cap, np.int64),
+        **{k: np.empty(cap, np.uint8 if k == "f_type" else np.int32)
+           for k in ("f_fd", "f_gen", "f_xid", "f_n", "f_type")},
+    )
+
     def dispatch():
+        # flow pulls and param pulls alike: every row GRANTED
         while not stop.is_set():
-            got = door.wait_batch(timeout_ms=50)
+            got = door.wait_any_into(block, timeout_ms=50)
             if got is None:
                 continue
-            ids, _counts, _prios, frames = got
-            n = len(ids)
+            n, k, _nv = got
+            frames = tuple(block[f][:k].copy() for f in (
+                "f_fd", "f_gen", "f_xid", "f_n", "f_type"))
             door.submit(frames, np.zeros(n, np.int8),
                         np.zeros(n, np.int32), np.zeros(n, np.int32))
 

@@ -14,6 +14,12 @@
 // Data plane (handled here):
 //   BATCH_FLOW (type 5): n×(flow_id:i64, count:i32, prio:u8) rows → arena
 //   FLOW       (type 1): single request → arena as a 1-row frame
+//   BATCH_PARAM_FLOW (type 27, codec rev 8): n:u16 k:u8 then n×(flow_id:i64,
+//     count:i32, prio:u8, k×hash:i64) → the param arena, BATCH_FLOW's code
+//     with a wider row. Flow rows and param rows never share a pull:
+//     sn_fd_wait_any hands out whichever arena's head frame arrived first,
+//     and of the param arena a run of frames with one k. Replies are
+//     BATCH_FLOW's rows under type 27.
 // Control plane (forwarded to Python, rare): PING, PARAM_FLOW,
 //   CONCURRENT_ACQUIRE/RELEASE, plus open/close connection events so the
 //   host keeps its ConnectionManager (namespace groups, idle sweep) exact.
@@ -36,6 +42,7 @@
 #include <sys/socket.h>
 #include <unistd.h>
 
+#include <algorithm>
 #include <atomic>
 #include <condition_variable>
 #include <cstdint>
@@ -60,6 +67,9 @@ constexpr int kReqRow = 13;        // flow_id:i64 + count:i32 + prio:u8
 constexpr int kRspRow = 9;         // status:i8 + remaining:i32 + wait:i32
 constexpr uint8_t kTypeFlow = 1;
 constexpr uint8_t kTypeBatchFlow = 5;
+constexpr uint8_t kTypeBatchParam = 27;
+// one max-size param frame holds at most (65535 - 8) / 21 * 1 .. 8190 values
+constexpr size_t kMaxFrameValues = 8192;
 constexpr size_t kMaxFrame = 65535;
 constexpr size_t kReadChunk = 1 << 16;
 // control-plane queue bound: beyond this the sender's conn parks (same
@@ -112,7 +122,20 @@ struct FrameMeta {
   uint32_t gen;
   int32_t xid;
   int32_t n;       // requests in this frame
-  uint8_t type;    // kTypeFlow | kTypeBatchFlow
+  uint8_t type;    // kTypeFlow | kTypeBatchFlow | kTypeBatchParam
+  uint8_t k;       // values per request (param frames; 0 otherwise)
+  uint64_t seq;    // arrival order over both arenas
+};
+
+// decoded rows awaiting a pull; the param arena also holds value hashes
+struct Arena {
+  std::vector<int64_t> flow_ids;
+  std::vector<int32_t> counts;
+  std::vector<uint8_t> prios;
+  std::vector<int64_t> hashes;  // param arena only: n×k per frame, in order
+  std::vector<FrameMeta> frames;
+  size_t n_requests = 0;
+  size_t n_hashes = 0;
 };
 
 // control event forwarded to Python
@@ -138,13 +161,11 @@ struct Frontdoor {
   std::mutex mu;
   std::condition_variable cv;  // signaled when arena/control non-empty
 
-  // request arena (guarded by mu)
+  // request arenas (guarded by mu): flow rows, and param rows
   size_t cap;
-  std::vector<int64_t> flow_ids;
-  std::vector<int32_t> counts;
-  std::vector<uint8_t> prios;
-  std::vector<FrameMeta> frames;
-  size_t n_requests = 0;
+  size_t hash_cap;
+  Arena flow, param;
+  uint64_t next_seq = 0;
   bool arena_was_full = false;
 
   std::deque<Control> controls;  // guarded by mu
@@ -169,11 +190,15 @@ struct Frontdoor {
   std::atomic<int64_t> idle_ttl_ms{0};
   int64_t last_sweep_ms = 0;
 
-  explicit Frontdoor(size_t arena_cap) : cap(arena_cap) {
-    flow_ids.resize(cap);
-    counts.resize(cap);
-    prios.resize(cap);
-    frames.reserve(4096);
+  explicit Frontdoor(size_t arena_cap)
+      : cap(arena_cap), hash_cap(std::max(arena_cap, kMaxFrameValues)) {
+    for (Arena *a : {&flow, &param}) {
+      a->flow_ids.resize(cap);
+      a->counts.resize(cap);
+      a->prios.resize(cap);
+      a->frames.reserve(4096);
+    }
+    param.hashes.resize(hash_cap);
   }
 };
 
@@ -218,10 +243,20 @@ bool parse_frames(Frontdoor *s, Conn &c) {
       if (avail < 2 + flen) break;
       const uint8_t *payload = p + 2;
       uint8_t type = payload[4];
-      if (type == kTypeBatchFlow || type == kTypeFlow) {
+      if (type == kTypeBatchFlow || type == kTypeFlow ||
+          type == kTypeBatchParam) {
         int32_t n;
+        int32_t k = 0;  // values per request: param frames only
         const uint8_t *rows;
-        if (type == kTypeBatchFlow) {
+        if (type == kTypeBatchParam) {
+          if (flen < size_t(kHead + 3)) return false;
+          n = be16(payload + kHead);
+          k = payload[kHead + 2];
+          if (n > 0 && k == 0) return false;  // rows without a value
+          if (flen < size_t(kHead + 3) + size_t(n) * (kReqRow + 8 * size_t(k)))
+            return false;
+          rows = payload + kHead + 3;
+        } else if (type == kTypeBatchFlow) {
           if (flen < size_t(kHead + 2)) return false;
           n = be16(payload + kHead);
           if (flen < size_t(kHead + 2) + size_t(n) * kReqRow) return false;
@@ -233,14 +268,14 @@ bool parse_frames(Frontdoor *s, Conn &c) {
         }
         int32_t xid = be32(payload);
         if (n == 0) {
-          // empty BATCH_FLOW: answer inline with an empty verdict frame —
+          // empty batch frame: answer inline with an empty verdict frame —
           // wait_batch only wakes for n_requests > 0, so queuing a
           // zero-row FrameMeta would strand it (and its sender) forever
           std::string rsp(size_t(2 + kHead + 2), '\0');
           uint8_t *q = reinterpret_cast<uint8_t *>(&rsp[0]);
           put16(q, uint16_t(kHead + 2));
           put32(q + 2, uint32_t(xid));
-          q[6] = kTypeBatchFlow;
+          q[6] = type;
           put16(q + 7, 0);
           s->outbox.emplace_back(std::make_pair(c.fd, uint32_t(c.gen)),
                                  std::move(rsp));
@@ -249,21 +284,28 @@ bool parse_frames(Frontdoor *s, Conn &c) {
           wake_self = true;
           continue;
         }
-        if (s->n_requests + size_t(n) > s->cap) {
+        Arena &a = type == kTypeBatchParam ? s->param : s->flow;
+        if (a.n_requests + size_t(n) > s->cap ||
+            a.n_hashes + size_t(n) * size_t(k) > s->hash_cap) {
           // arena full: park this conn; bytes stay buffered
           c.paused = true;
           s->arena_was_full = true;
           epoll_mod(s, c);
           break;
         }
-        size_t base = s->n_requests;
-        for (int32_t i = 0; i < n; ++i, rows += kReqRow) {
-          s->flow_ids[base + i] = be64(rows);
-          s->counts[base + i] = be32(rows + 8);
-          s->prios[base + i] = rows[12];
+        size_t base = a.n_requests;
+        int64_t *hv = k ? a.hashes.data() + a.n_hashes : nullptr;
+        for (int32_t i = 0; i < n; ++i) {
+          a.flow_ids[base + i] = be64(rows);
+          a.counts[base + i] = be32(rows + 8);
+          a.prios[base + i] = rows[12];
+          rows += kReqRow;
+          for (int32_t j = 0; j < k; ++j, rows += 8) *hv++ = be64(rows);
         }
-        s->n_requests += size_t(n);
-        s->frames.push_back({c.fd, c.gen, xid, n, type});
+        a.n_requests += size_t(n);
+        a.n_hashes += size_t(n) * size_t(k);
+        a.frames.push_back({c.fd, c.gen, xid, n, type, uint8_t(k),
+                            s->next_seq++});
         s->frames_in.fetch_add(1, std::memory_order_relaxed);
         s->requests_in.fetch_add(uint64_t(n), std::memory_order_relaxed);
         notify = true;
@@ -474,7 +516,9 @@ void io_loop(Frontdoor *s) {
       {
         std::lock_guard<std::mutex> lk(s->mu);
         out.swap(s->outbox);
-        bool arena_ok = s->arena_was_full && s->n_requests < s->cap;
+        bool arena_ok = s->arena_was_full && s->flow.n_requests < s->cap &&
+                        s->param.n_requests < s->cap &&
+                        s->param.n_hashes < s->hash_cap;
         if (arena_ok) s->arena_was_full = false;
         bool ctrl_ok =
             s->controls_was_full && s->controls.size() < kMaxControls / 2;
@@ -617,11 +661,69 @@ SN_EXPORT void sn_fd_destroy(void *h) {
   delete s;
 }
 
+namespace {
+
+// Take whole frames off the head of one arena into the caller's arrays
+// (called with mu held; the caller unlocks). Of the param arena a run of
+// frames with the head frame's k, so the pull's hashes are one [n, k]
+// array. Returns the request count; 0 when not even one frame fits.
+int32_t take_frames(Arena &a, int64_t *ids, int32_t *counts,
+                    uint8_t *prios, int64_t *hashes, int32_t max_n,
+                    int32_t max_hashes, int32_t *f_fd, int32_t *f_gen,
+                    int32_t *f_xid, int32_t *f_n, uint8_t *f_type,
+                    int32_t max_frames, int32_t *n_frames_out) {
+  size_t take_req = 0, n_take = 0, take_hashes = 0;
+  const uint8_t k = a.frames.empty() ? 0 : a.frames.front().k;
+  for (const FrameMeta &fm : a.frames) {
+    if (n_take + 1 > size_t(max_frames) || fm.k != k ||
+        take_req + size_t(fm.n) > size_t(max_n) ||
+        take_hashes + size_t(fm.n) * k > size_t(max_hashes))
+      break;
+    take_req += size_t(fm.n);
+    take_hashes += size_t(fm.n) * k;
+    n_take += 1;
+  }
+  *n_frames_out = int32_t(n_take);
+  if (n_take == 0) return 0;  // caller buffers too small (misuse)
+  memcpy(ids, a.flow_ids.data(), take_req * sizeof(int64_t));
+  memcpy(counts, a.counts.data(), take_req * sizeof(int32_t));
+  memcpy(prios, a.prios.data(), take_req);
+  if (take_hashes)
+    memcpy(hashes, a.hashes.data(), take_hashes * sizeof(int64_t));
+  for (size_t i = 0; i < n_take; ++i) {
+    f_fd[i] = a.frames[i].fd;
+    f_gen[i] = int32_t(a.frames[i].gen);
+    f_xid[i] = a.frames[i].xid;
+    f_n[i] = a.frames[i].n;
+    f_type[i] = a.frames[i].type;
+  }
+  // compact the remainder (rare: only when a burst exceeds caller capacity)
+  size_t rest_req = a.n_requests - take_req;
+  if (rest_req > 0) {
+    memmove(a.flow_ids.data(), a.flow_ids.data() + take_req,
+            rest_req * sizeof(int64_t));
+    memmove(a.counts.data(), a.counts.data() + take_req,
+            rest_req * sizeof(int32_t));
+    memmove(a.prios.data(), a.prios.data() + take_req, rest_req);
+  }
+  size_t rest_hashes = a.n_hashes - take_hashes;
+  if (rest_hashes > 0)
+    memmove(a.hashes.data(), a.hashes.data() + take_hashes,
+            rest_hashes * sizeof(int64_t));
+  a.frames.erase(a.frames.begin(), a.frames.begin() + n_take);
+  a.n_requests = rest_req;
+  a.n_hashes = rest_hashes;
+  return int32_t(take_req);
+}
+
+}  // namespace
+
 // Block until data-plane requests are queued (or timeout/stop). Copies up
-// to max_n requests + their frame list into the caller's arrays and resets
-// the arena. Returns the request count (0 on timeout/stop); *n_frames_out
-// receives the frame count. Whole frames only — a frame never splits
-// across two batches.
+// to max_n FLOW / BATCH_FLOW requests + their frame list into the caller's
+// arrays and resets the arena. Returns the request count (0 on
+// timeout/stop); *n_frames_out receives the frame count. Whole frames only —
+// a frame never splits across two batches. Param frames are not seen here:
+// a host that serves them pulls with sn_fd_wait_any.
 SN_EXPORT int32_t sn_fd_wait_batch(void *h, int32_t timeout_ms, int64_t *ids,
                                    int32_t *counts, uint8_t *prios,
                                    int32_t max_n, int32_t *f_fd,
@@ -631,54 +733,58 @@ SN_EXPORT int32_t sn_fd_wait_batch(void *h, int32_t timeout_ms, int64_t *ids,
                                    int32_t *n_frames_out) {
   auto *s = static_cast<Frontdoor *>(h);
   std::unique_lock<std::mutex> lk(s->mu);
-  if (s->n_requests == 0) {
+  if (s->flow.n_requests == 0) {
     s->cv.wait_for(lk, std::chrono::milliseconds(timeout_ms), [s] {
-      return s->n_requests > 0 || s->stopping.load(std::memory_order_acquire);
+      return s->flow.n_requests > 0 ||
+             s->stopping.load(std::memory_order_acquire);
     });
   }
-  if (s->n_requests == 0) {
-    *n_frames_out = 0;
-    return 0;
-  }
-  // take whole frames up to the caller's capacity
-  size_t take_req = 0, take_frames = 0;
-  for (const FrameMeta &fm : s->frames) {
-    if (take_frames + 1 > size_t(max_frames) ||
-        take_req + size_t(fm.n) > size_t(max_n))
-      break;
-    take_req += size_t(fm.n);
-    take_frames += 1;
-  }
-  if (take_frames == 0) {
-    *n_frames_out = 0;
-    return 0;  // caller buffers too small for even one frame (misuse)
-  }
-  memcpy(ids, s->flow_ids.data(), take_req * sizeof(int64_t));
-  memcpy(counts, s->counts.data(), take_req * sizeof(int32_t));
-  memcpy(prios, s->prios.data(), take_req);
-  for (size_t i = 0; i < take_frames; ++i) {
-    f_fd[i] = s->frames[i].fd;
-    f_gen[i] = int32_t(s->frames[i].gen);
-    f_xid[i] = s->frames[i].xid;
-    f_n[i] = s->frames[i].n;
-    f_type[i] = s->frames[i].type;
-  }
-  *n_frames_out = int32_t(take_frames);
-  // compact the remainder (rare: only when a burst exceeds caller capacity)
-  size_t rest_req = s->n_requests - take_req;
-  if (rest_req > 0) {
-    memmove(s->flow_ids.data(), s->flow_ids.data() + take_req,
-            rest_req * sizeof(int64_t));
-    memmove(s->counts.data(), s->counts.data() + take_req,
-            rest_req * sizeof(int32_t));
-    memmove(s->prios.data(), s->prios.data() + take_req, rest_req);
-  }
-  s->frames.erase(s->frames.begin(), s->frames.begin() + take_frames);
-  s->n_requests = rest_req;
+  *n_frames_out = 0;
+  if (s->flow.n_requests == 0) return 0;
+  int32_t n = take_frames(s->flow, ids, counts, prios, nullptr, max_n, 0,
+                          f_fd, f_gen, f_xid, f_n, f_type, max_frames,
+                          n_frames_out);
   bool resume = s->arena_was_full;
   lk.unlock();
   if (resume) wake(s);  // unpark conns the full arena throttled
-  return int32_t(take_req);
+  return n;
+}
+
+// sn_fd_wait_batch for a host that serves both kinds of rows: one pull is
+// either flow rows (*k_out = 0) or param rows (*k_out = values per request,
+// their hashes in ``hashes`` as [n, k]), never both: the arena whose head
+// frame arrived first is served. max_hashes bounds the values of one pull.
+SN_EXPORT int32_t sn_fd_wait_any(void *h, int32_t timeout_ms, int64_t *ids,
+                                 int32_t *counts, uint8_t *prios,
+                                 int64_t *hashes, int32_t max_n,
+                                 int32_t max_hashes, int32_t *f_fd,
+                                 int32_t *f_gen, int32_t *f_xid, int32_t *f_n,
+                                 uint8_t *f_type, int32_t max_frames,
+                                 int32_t *n_frames_out, int32_t *k_out) {
+  auto *s = static_cast<Frontdoor *>(h);
+  std::unique_lock<std::mutex> lk(s->mu);
+  auto queued = [s] { return s->flow.n_requests + s->param.n_requests > 0; };
+  if (!queued()) {
+    s->cv.wait_for(lk, std::chrono::milliseconds(timeout_ms), [s, &queued] {
+      return queued() || s->stopping.load(std::memory_order_acquire);
+    });
+  }
+  *n_frames_out = 0;
+  *k_out = 0;
+  if (!queued()) return 0;
+  bool take_param =
+      s->param.n_requests > 0 &&
+      (s->flow.n_requests == 0 ||
+       s->param.frames.front().seq < s->flow.frames.front().seq);
+  Arena &a = take_param ? s->param : s->flow;
+  if (take_param) *k_out = a.frames.front().k;
+  int32_t n = take_frames(a, ids, counts, prios, hashes, max_n, max_hashes,
+                          f_fd, f_gen, f_xid, f_n, f_type, max_frames,
+                          n_frames_out);
+  bool resume = s->arena_was_full;
+  lk.unlock();
+  if (resume) wake(s);  // unpark conns the full arena throttled
+  return n;
 }
 
 // Encode + enqueue verdict frames for the frames returned by wait_batch.
@@ -704,7 +810,7 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
       ++run_end;
     size_t total = 0;
     for (int32_t k = i; k < run_end; ++k)
-      total += (f_type[k] == kTypeBatchFlow)
+      total += (f_type[k] != kTypeFlow)
                    ? 2 + size_t(kHead) + 2 + size_t(f_n[k]) * kRspRow
                    : 2 + size_t(kHead) + kRspRow;
     std::string buf;
@@ -712,11 +818,11 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
     uint8_t *p = reinterpret_cast<uint8_t *>(&buf[0]);
     for (int32_t k = i; k < run_end; ++k) {
       int32_t n = f_n[k];
-      if (f_type[k] == kTypeBatchFlow) {
+      if (f_type[k] != kTypeFlow) {  // BATCH_FLOW or BATCH_PARAM_FLOW rows
         size_t payload = size_t(kHead) + 2 + size_t(n) * kRspRow;
         put16(p, uint16_t(payload));
         put32(p + 2, uint32_t(f_xid[k]));
-        p[6] = kTypeBatchFlow;
+        p[6] = f_type[k];
         put16(p + 7, uint16_t(n));
         uint8_t *row = p + 9;
         for (int32_t j = 0; j < n; ++j, row += kRspRow) {

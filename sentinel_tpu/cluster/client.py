@@ -378,7 +378,8 @@ class TokenClient(TokenService):
                             pending.response = rsp
                             pending.event.set()
                         continue
-                    if mtype == P.MsgType.BATCH_FLOW:
+                    if mtype in (P.MsgType.BATCH_FLOW,
+                                 P.MsgType.BATCH_PARAM_FLOW):
                         # copy + store the raw payload; the waiting thread
                         # decodes (spreads the vectorized decode across
                         # callers). Frames whose waiter already gave up
@@ -1020,6 +1021,62 @@ class TokenClient(TokenService):
                     # truncated/malformed server frame degrades to the
                     # documented None contract, never an exception out of
                     # the caller (the local-fallback path handles None)
+                    return None
+                if st.shape[0] != hi - lo:
+                    return None
+                status[lo:hi] = st
+                remaining[lo:hi] = rem
+                wait[lo:hi] = wt
+            return status, remaining, wait
+        finally:
+            for xid, _, _, _ in pendings:
+                self._pending.pop(xid, None)
+
+    def request_params_batch(self, flow_ids, counts, hashes,
+                             timeout_ms: Optional[int] = None):
+        """``n`` hot-parameter requests of ``k`` value hashes each
+        (``hashes int64[n, k]``) over BATCH_PARAM_FLOW frames (codec rev 8):
+        (status int8[n], remaining int32[n], wait_ms int32[n]) in request
+        order, or None on send failure/timeout. Batches past one frame are
+        pipelined like :meth:`request_batch_arrays`'s."""
+        import numpy as np
+
+        flow_ids = np.asarray(flow_ids, dtype=np.int64)
+        n = flow_ids.shape[0]
+        if n == 0:
+            e = np.empty(0, np.int32)
+            return np.empty(0, np.int8), e, e
+        hashes = np.asarray(hashes, dtype=np.int64).reshape(n, -1)
+        counts = np.broadcast_to(
+            np.asarray(1 if counts is None else counts, np.int32), (n,)
+        )
+        budget = (timeout_ms or self.timeout_ms) / 1000.0
+        chunk = P.max_param_rows_per_frame(hashes.shape[1])
+        pendings = []
+        try:
+            for lo in range(0, n, chunk):
+                hi = min(lo + chunk, n)
+                xid = next(self._xid)
+                pending = _Pending()
+                self._pending[xid] = pending
+                pendings.append((xid, pending, lo, hi))
+                if not self._send(P.encode_batch_param_request(
+                        xid, flow_ids[lo:hi], counts[lo:hi], hashes[lo:hi])):
+                    return None
+                self._count_rpc()
+            status = np.empty(n, np.int8)
+            remaining = np.empty(n, np.int32)
+            wait = np.empty(n, np.int32)
+            deadline = time.monotonic() + budget
+            for xid, pending, lo, hi in pendings:
+                if not pending.event.wait(max(deadline - time.monotonic(), 0)):
+                    return None
+                payload = pending.response
+                if not isinstance(payload, (bytes, bytearray)):
+                    return None  # connection died mid-batch
+                try:
+                    _, st, rem, wt = P.decode_batch_response(payload)
+                except Exception:
                     return None
                 if st.shape[0] != hi - lo:
                     return None

@@ -34,6 +34,22 @@ Verdict order matches request order. Encode/decode are vectorized (numpy
 structured dtypes, or the native C codec when built) — per-request Python
 cost is what capped the round-2 front door at ~5k rps.
 
+BATCH_PARAM_FLOW (codec rev 8, type 27; TPU extension): the batch frame of
+PARAM_FLOW, as BATCH_FLOW is FLOW's. One frame carries ``n`` hot-parameter
+requests of ``k`` value hashes each; ``k`` (1..255) is fixed per frame, so
+the rows are fixed-size and the codec a numpy structured dtype. Request
+data = ``n:uint16, k:uint8`` + n × ``(flow_id:int64, count:int32,
+priority:uint8, k × hash:int64)``; the response is BATCH_FLOW's, row for
+row (``n:uint16`` + n × ``(status:int8, remaining:int32, wait_ms:int32)``),
+under type 27, verdicts in request order. Only hashes cross the wire, as for
+PARAM_FLOW, which (type 2, the reference client's frame) is unchanged. Both
+doors decide the rows of every frame through ONE batched service entry
+(``TokenService.request_params_batch``): the native door decodes type 27 on
+its data plane and frames of every connection coalesce into one pull; a
+frame with ``k = 0`` or a short body is malformed and closes the connection,
+an empty frame (``n = 0``) is answered in line. Servers before rev 8 reject
+the type byte.
+
 Codec rev 3 — replication frames (``sentinel_tpu.ha.replication``): a
 primary token server streams state to warm standbys over the SAME wire as
 the data plane (both front doors route the new type bytes to their control
@@ -190,9 +206,9 @@ import numpy as np
 from sentinel_tpu import chaos as _chaos
 
 # codec revision this build speaks: 2 deadline trailer, 3 REPL, 4 MOVE,
-# 5 LEASE + HIER share ops, 6 OUTCOME_REPORT, 7 PUSH control plane (the
-# doc revisions above)
-WIRE_REV = 7
+# 5 LEASE + HIER share ops, 6 OUTCOME_REPORT, 7 PUSH control plane,
+# 8 BATCH_PARAM_FLOW (the doc revisions above)
+WIRE_REV = 8
 
 # 2-byte big-endian length prefix caps a frame at 65535 bytes; single-request
 # messages keep the reference's 1024-byte budget, BATCH_FLOW frames use the
@@ -217,6 +233,25 @@ _DEADLINE = struct.Struct(">I")
 BATCH_REQ_DTYPE = np.dtype([("flow_id", ">i8"), ("count", ">i4"), ("prio", "u1")])
 BATCH_RSP_DTYPE = np.dtype([("status", "i1"), ("remaining", ">i4"), ("wait_ms", ">i4")])
 MAX_BATCH_PER_FRAME = (MAX_FRAME - _HEAD.size - _BATCH_N.size) // BATCH_REQ_DTYPE.itemsize
+
+# rev-8 BATCH_PARAM_FLOW: ``n:uint16, k:uint8`` then n rows of (flow request,
+# k value hashes); k is fixed per frame, so the row is a structured dtype
+_PARAM_HEAD = struct.Struct(">HB")
+MAX_PARAM_VALUES = 255
+
+
+def batch_param_dtype(k: int) -> np.dtype:
+    """Row layout of a BATCH_PARAM_FLOW request with ``k`` values a row."""
+    return np.dtype([("flow_id", ">i8"), ("count", ">i4"), ("prio", "u1"),
+                     ("hashes", ">i8", (int(k),))])
+
+
+def max_param_rows_per_frame(k: int) -> int:
+    """The most requests of ``k`` values one BATCH_PARAM_FLOW frame holds."""
+    return (MAX_FRAME - _HEAD.size - _PARAM_HEAD.size) // (
+        BATCH_REQ_DTYPE.itemsize + 8 * int(k)
+    )
+
 
 # rev-6 outcome rows: (flow_id, rt_ms, exc) — same 13-byte shape discipline
 # as BATCH_REQ_DTYPE so one frame coalesces ~5000 completions
@@ -275,6 +310,8 @@ class MsgType(enum.IntEnum):
     RULE_EPOCH_INVALIDATE = 24
     SHARD_MAP_PUSH = 25
     BROWNOUT_ADVISORY = 26
+    # codec rev 8: the batch frame of PARAM_FLOW (data plane on both doors)
+    BATCH_PARAM_FLOW = 27
 
 
 # front doors route these type bytes to the replication applier instead of
@@ -512,6 +549,65 @@ def decode_batch_request_into(payload, ids_out, counts_out, prios_out, at=0):
     return xid, n
 
 
+def encode_batch_param_request(xid: int, flow_ids, counts, hashes,
+                               prios=None) -> bytes:
+    """One BATCH_PARAM_FLOW frame: ``n`` requests of ``k`` value hashes
+    each (``hashes`` ``int64[n, k]``, ``k`` in 1..255), numpy-vectorized."""
+    flow_ids = np.asarray(flow_ids, dtype=np.int64)
+    n = flow_ids.shape[0]
+    hashes = np.asarray(hashes, dtype=np.int64)
+    if hashes.ndim != 2:
+        hashes = hashes.reshape(n, -1)
+    k = hashes.shape[1]
+    if not 1 <= k <= MAX_PARAM_VALUES:
+        raise ValueError(f"{k} values a request; the wire takes 1..255")
+    if n > max_param_rows_per_frame(k):
+        raise ValueError(
+            f"batch of {n} x {k} values exceeds "
+            f"{max_param_rows_per_frame(k)} requests/frame"
+        )
+    rows = np.empty(n, dtype=batch_param_dtype(k))
+    rows["flow_id"] = flow_ids
+    rows["count"] = 1 if counts is None else np.asarray(counts, np.int32)
+    rows["prio"] = 0 if prios is None else np.asarray(prios, np.uint8)
+    rows["hashes"] = hashes
+    payload_len = _HEAD.size + _PARAM_HEAD.size + rows.nbytes
+    return (
+        _LEN.pack(payload_len)
+        + _HEAD.pack(xid, MsgType.BATCH_PARAM_FLOW)
+        + _PARAM_HEAD.pack(n, k)
+        + rows.tobytes()
+    )
+
+
+def decode_batch_param_request(payload: bytes):
+    """BATCH_PARAM_FLOW payload → (xid, flow_ids int64[N], counts int32[N],
+    prios bool[N], hashes int64[N, k]). Raises ``ValueError`` on ``k = 0``
+    with rows, or a body shorter than its header declares: a protocol error
+    on that connection."""
+    if len(payload) < _HEAD.size + _PARAM_HEAD.size:
+        raise ValueError("runt BATCH_PARAM_FLOW frame")
+    xid, _ = _HEAD.unpack_from(payload, 0)
+    n, k = _PARAM_HEAD.unpack_from(payload, _HEAD.size)
+    off = _HEAD.size + _PARAM_HEAD.size
+    if n and not k:
+        raise ValueError("BATCH_PARAM_FLOW rows carry no value")
+    dtype = batch_param_dtype(k)
+    if len(payload) < off + n * dtype.itemsize:
+        raise ValueError(
+            f"truncated param batch: {n} x {k} declared, "
+            f"{len(payload) - off} payload bytes"
+        )
+    rows = np.frombuffer(payload, dtype=dtype, count=n, offset=off)
+    return (
+        xid,
+        rows["flow_id"].astype(np.int64),
+        rows["count"].astype(np.int32),
+        rows["prio"].astype(bool),
+        rows["hashes"].astype(np.int64).reshape(n, k),
+    )
+
+
 def encode_outcome_report(xid: int, flow_ids, rt_ms, excs) -> bytes:
     """One OUTCOME_REPORT frame carrying N completion rows (rev 6).
 
@@ -622,9 +718,12 @@ def decode_batch_deadline(payload: bytes) -> int:
     return 0
 
 
-def encode_batch_response(xid: int, status, remaining, wait_ms) -> bytes:
+def encode_batch_response(xid: int, status, remaining, wait_ms,
+                          msg_type: int = MsgType.BATCH_FLOW) -> bytes:
+    """One batch response frame: BATCH_FLOW's, or under ``msg_type``
+    BATCH_PARAM_FLOW's, which has the same rows."""
     native = _native_codec()
-    if native is not None:
+    if native is not None and msg_type == MsgType.BATCH_FLOW:
         return native.batch_encode_rsp(xid, status, remaining, wait_ms)
     status = np.asarray(status, dtype=np.int8)
     n = status.shape[0]
@@ -635,7 +734,7 @@ def encode_batch_response(xid: int, status, remaining, wait_ms) -> bytes:
     payload_len = _HEAD.size + _BATCH_N.size + n * BATCH_RSP_DTYPE.itemsize
     return (
         _LEN.pack(payload_len)
-        + _HEAD.pack(xid, MsgType.BATCH_FLOW)
+        + _HEAD.pack(xid, msg_type)
         + _BATCH_N.pack(n)
         + rows.tobytes()
     )

@@ -40,7 +40,10 @@ import numpy as np
 from sentinel_tpu import chaos
 from sentinel_tpu.cluster import protocol as P
 from sentinel_tpu.cluster.connection import ConnectionManager
-from sentinel_tpu.cluster.token_service import TokenService
+from sentinel_tpu.cluster.token_service import (
+    TokenService,
+    decide_param_requests,
+)
 from sentinel_tpu.core.log import record_log
 from sentinel_tpu.engine import TokenStatus
 from sentinel_tpu.metrics.profiler import ProfilerHook
@@ -367,6 +370,47 @@ class _LoopWorker:
                             srv.service.report_outcomes,
                             ofids, orts, oexcs, oxid,
                         )
+                        continue
+                    if mtype == P.MsgType.BATCH_PARAM_FLOW:
+                        # codec rev 8: the rows of the frame go to the
+                        # service's batched param entry in one call, from
+                        # this connection's reader (the native door is the
+                        # one that coalesces frames across connections)
+                        try:
+                            pxid, pids, pcnts, _pprios, phashes = (
+                                P.decode_batch_param_request(payload)
+                            )
+                        except Exception:
+                            record_log.warning(
+                                "bad param batch frame; closing"
+                            )
+                            return
+                        srv.connections.touch(address)
+                        k = len(pids)
+                        try:
+                            if srv.is_standby:
+                                verdicts = (
+                                    np.full(k, _STANDBY, np.int8),
+                                    np.zeros(k, np.int32),
+                                    np.zeros(k, np.int32),
+                                )
+                            else:
+                                verdicts = await asyncio.to_thread(
+                                    srv.service.request_params_batch,
+                                    pids, pcnts, phashes,
+                                )
+                        except Exception:
+                            record_log.exception("param batch failed")
+                            verdicts = (
+                                np.full(k, int(TokenStatus.FAIL), np.int8),
+                                np.zeros(k, np.int32),
+                                np.zeros(k, np.int32),
+                            )
+                        writer.write(P.encode_batch_response(
+                            pxid, *verdicts,
+                            msg_type=P.MsgType.BATCH_PARAM_FLOW,
+                        ))
+                        await writer.drain()
                         continue
                     if mtype == P.MsgType.BATCH_FLOW:
                         # vectorized decode; no per-request Python objects
@@ -787,13 +831,7 @@ class _LoopWorker:
             # overlapped thread hops: the service locks still serialize the
             # critical sections, but responses aren't head-of-line blocked
             try:
-                if req.msg_type == P.MsgType.PARAM_FLOW:
-                    r = await asyncio.to_thread(
-                        service.request_params_token,
-                        req.flow_id, req.count, req.param_hashes,
-                    )
-                    results[i] = (int(r.status), r.remaining, r.wait_ms, 0)
-                elif req.msg_type == P.MsgType.CONCURRENT_ACQUIRE:
+                if req.msg_type == P.MsgType.CONCURRENT_ACQUIRE:
                     r = await asyncio.to_thread(
                         service.request_concurrent_token,
                         req.flow_id, req.count, req.prioritized,
@@ -809,6 +847,17 @@ class _LoopWorker:
                 record_log.exception("%s request failed", req.msg_type.name)
                 results[i] = (int(TokenStatus.FAIL), 0, 0, 0)
 
+        async def run_params(items) -> None:
+            # the single PARAM_FLOW frames of this micro-batch, in queue
+            # order: one call of the batched entry per run of equal value
+            # counts (decide_param_requests), not one dispatch per request
+            verdicts = await asyncio.to_thread(
+                decide_param_requests, service,
+                [req for _i, req in items], int(TokenStatus.FAIL),
+            )
+            for (i, _req), (st, rm, wt) in zip(items, verdicts):
+                results[i] = (st, rm, wt, 0)
+
         host_side = [
             (i, req)
             for i, (req, _w, _t, _dl) in enumerate(batch)
@@ -816,6 +865,10 @@ class _LoopWorker:
             and req.msg_type != P.MsgType.FLOW
         ]
         is_host_side = {i for i, _ in host_side}
+        param_singles = [
+            (i, req) for i, req in host_side
+            if req.msg_type == P.MsgType.PARAM_FLOW
+        ]
 
         async def write_out(indices) -> None:
             t_write = time.perf_counter()
@@ -910,7 +963,11 @@ class _LoopWorker:
         # delay another client's CONCURRENT_RELEASE, and vice versa;
         # responses are xid-correlated, order-free)
         async def host_side_then_write() -> None:
-            await asyncio.gather(*(run_one(i, req) for i, req in host_side))
+            await asyncio.gather(
+                run_params(param_singles),
+                *(run_one(i, req) for i, req in host_side
+                  if req.msg_type != P.MsgType.PARAM_FLOW),
+            )
             await write_out(is_host_side)
 
         flow_write = write_out(

@@ -46,7 +46,10 @@ import numpy as np
 from sentinel_tpu import chaos
 from sentinel_tpu.cluster import protocol as P
 from sentinel_tpu.cluster.connection import ConnectionManager
-from sentinel_tpu.cluster.token_service import TokenService
+from sentinel_tpu.cluster.token_service import (
+    TokenService,
+    decide_param_requests,
+)
 from sentinel_tpu.core.log import record_log
 from sentinel_tpu.engine import TokenStatus
 from sentinel_tpu.metrics.profiler import ProfilerHook
@@ -494,6 +497,9 @@ class NativeTokenServer:
             f_xid=np.empty(max_f, np.int32),
             f_n=np.empty(max_f, np.int32),
             f_type=np.empty(max_f, np.uint8),
+            # value hashes of a param pull (BATCH_PARAM_FLOW), request-major;
+            # one max-size frame holds under 8192 of them
+            hashes=np.empty(max(rows, 8192), np.int64),
         )
 
     def stop(self) -> None:
@@ -648,7 +654,7 @@ class NativeTokenServer:
                 try:
                     # max_batch bounds one pull (clamped to >= one max
                     # frame); the remainder stays queued for the next cycle
-                    got = door.wait_batch_into(
+                    got = door.wait_any_into(
                         block, timeout_ms=self.intake_timeout_ms,
                         max_n=self.max_batch,
                     )
@@ -661,7 +667,9 @@ class NativeTokenServer:
                     break
                 if got is None:
                     continue
-                n, k = got
+                # nv: values per request of a param pull (its rows are the
+                # requests of BATCH_PARAM_FLOW frames), 0 for a flow pull
+                n, k, nv = got
                 if chaos.ARMED:
                     chaos.maybe_sleep("lane_delay")
                     if chaos.should("frame_drop"):
@@ -676,8 +684,9 @@ class NativeTokenServer:
                 )
                 # the one host copy this path pays: C arena → staging
                 # (13B/row + 17B/frame) plus the 1B/row bool normalize
-                _SM.count_copy_bytes(n * 14 + k * 17)
-                # pull = (rows..., frames, age stamp, owning door, block):
+                _SM.count_copy_bytes(n * (14 + 8 * nv) + k * 17)
+                # pull = (rows..., frames, age stamp, owning door, block,
+                # value hashes [n, nv] of a param pull or None):
                 # the age stamp is the shed-by-age deadline proxy (the C++
                 # door strips the wire deadline); the door routes replies
                 # and refusals back to the shard that owns the connection
@@ -687,6 +696,7 @@ class NativeTokenServer:
                      block["f_xid"][:k], block["f_n"][:k],
                      block["f_type"][:k]),
                     time.monotonic(), door, block,
+                    block["hashes"][:n * nv].reshape(n, nv) if nv else None,
                 )
                 if _TR.ARMED:  # flight recorder: frames entered the host
                     if door is self._shm_door:
@@ -801,8 +811,10 @@ class NativeTokenServer:
             self._device_inflight = max(0, self._device_inflight - 1)
             self._device_cv.notify()
 
-    def _tracked_dispatch(self, dispatch, ids, counts, prios):
-        """Issue one device dispatch under the inflight bound.
+    def _tracked_dispatch(self, dispatch, ids, counts, third):
+        """Issue one device dispatch under the inflight bound: a flow
+        dispatch (``third`` the priorities) or a param dispatch (``third``
+        the value hashes ``[n, k]``).
 
         Returns ``(mat, release, overlapped)``: ``mat`` materializes the
         verdicts and releases the permit (exactly once, even if the
@@ -824,7 +836,7 @@ class NativeTokenServer:
                 self._release_device_permit()
 
         try:
-            inner = dispatch(ids, counts, prios)
+            inner = dispatch(ids, counts, third)
         except Exception:
             release()
             raise
@@ -862,7 +874,16 @@ class NativeTokenServer:
         done_shards = 0
         rr = 0
         service = self.service
-        dispatch = getattr(service, "dispatch_batch_arrays", None)
+        flow_dispatch = getattr(service, "dispatch_batch_arrays", None)
+        # a pull is either flow rows or param rows (the rows of
+        # BATCH_PARAM_FLOW frames, with their value hashes), and a dispatch
+        # is one or the other: a queued pull of another kind (or of another
+        # number of values per request) waits in ``held`` for the next turn
+        param_dispatch = getattr(service, "dispatch_params_batch", None)
+        held = None
+
+        def kind(pull) -> int:
+            return 0 if pull[7] is None else pull[7].shape[1]
 
         def pop_next():
             # every sem permit has a queued item behind it and this lane
@@ -883,11 +904,14 @@ class NativeTokenServer:
 
         try:
             while True:
-                if not sem.acquire(timeout=0.5):
-                    if self._abandon.is_set():
-                        break
-                    continue
-                item = pop_next()
+                if held is not None:
+                    item, held = held, None
+                else:
+                    if not sem.acquire(timeout=0.5):
+                        if self._abandon.is_set():
+                            break
+                        continue
+                    item = pop_next()
                 if item is None:
                     break
                 if item is self._SENTINEL:
@@ -912,7 +936,11 @@ class NativeTokenServer:
                             stop_after = True  # all intake done; finish
                             break
                         continue
+                    if kind(nxt) != kind(item):
+                        held = nxt
+                        break
                     pulls.append(nxt)
+                hashes = item[7]
                 if len(pulls) == 1:
                     ids, counts, prios = item[0], item[1], item[2]
                 else:
@@ -922,6 +950,18 @@ class NativeTokenServer:
                     _SM.count_copy_bytes(
                         ids.nbytes + counts.nbytes + prios.nbytes
                     )
+                    if hashes is not None:
+                        hashes = np.concatenate([p[7] for p in pulls])
+                        _SM.count_copy_bytes(hashes.nbytes)
+                if hashes is None:
+                    # what a dispatch takes beside ids and counts, and what
+                    # answers for a service without the dispatch/materialize
+                    # split
+                    dispatch, third = flow_dispatch, prios
+                    sync = getattr(service, "request_batch_arrays", None)
+                else:
+                    dispatch, third = param_dispatch, hashes
+                    sync = service.request_params_batch
                 lengths = [len(p[0]) for p in pulls]
                 n_rows = len(ids)
                 # deadline proxy: pulls older than shed_age_ms are answered
@@ -937,7 +977,12 @@ class NativeTokenServer:
                         shed = np.repeat(expired, lengths)
                         n_deadline = int(shed.sum())
                 level = self.overload.level()
-                ns_fn = getattr(service, "namespace_index", None)
+                # tenant attribution is the flow table's; param rules have
+                # none on this lane
+                ns_fn = (
+                    getattr(service, "namespace_index", None)
+                    if hashes is None else None
+                )
                 if _TR.ARMED:  # flight recorder: fused group dispatched
                     for p in pulls:
                         _TR.record_many(
@@ -990,15 +1035,13 @@ class NativeTokenServer:
                             if dispatch is not None:
                                 mat, permit_rel, overlapped = (
                                     self._tracked_dispatch(
-                                        dispatch, ids, counts, prios
+                                        dispatch, ids, counts, third
                                     )
                                 )
                             else:
                                 # SPI implementations without the dispatch/
                                 # materialize split run synchronously here
-                                res = service.request_batch_arrays(
-                                    ids, counts, prios
-                                )
+                                res = sync(ids, counts, third)
                                 mat = lambda res=res: res  # noqa: E731
                         else:
                             if n_deadline:
@@ -1012,12 +1055,12 @@ class NativeTokenServer:
                                     inner, permit_rel, overlapped = (
                                         self._tracked_dispatch(
                                             dispatch, ids[keep],
-                                            counts[keep], prios[keep],
+                                            counts[keep], third[keep],
                                         )
                                     )
                                 else:
-                                    res = service.request_batch_arrays(
-                                        ids[keep], counts[keep], prios[keep]
+                                    res = sync(
+                                        ids[keep], counts[keep], third[keep]
                                     )
                                     inner = lambda res=res: res  # noqa: E731
                             else:
@@ -1164,24 +1207,56 @@ class NativeTokenServer:
         # are globally unique across doors, so the session maps need no
         # per-door namespacing — only the REPLY must go out through the
         # door that owns the connection
+        #
+        # Single PARAM_FLOW frames (the reference client's, variable
+        # length) arrive here too. They are not answered one dispatch a
+        # request: what a door has queued is drained, the PARAM_FLOW
+        # requests among it are set aside in queue order, and each run of
+        # equal value counts is decided by ONE call of the service's
+        # batched entry (_answer_params).
         doors = list(self._doors)
         while not self._stop.is_set():
             got_any = False
             for door in doors:
-                try:
-                    item = door.next_control()
-                except Exception:
-                    if self._stop.is_set():
-                        return
-                    raise
-                if item is None:
-                    continue
-                got_any = True
-                self._handle_control_item(door, item)
+                params = []  # (fd, gen, request, address) in queue order
+                while True:
+                    try:
+                        item = door.next_control()
+                    except Exception:
+                        if self._stop.is_set():
+                            return
+                        raise
+                    if item is None:
+                        break
+                    got_any = True
+                    self._handle_control_item(door, item, params)
+                    if len(params) >= self.max_batch:
+                        break
+                if params:
+                    self._answer_params(door, params)
             if not got_any:
                 self._stop.wait(0.002)
 
-    def _handle_control_item(self, door, item) -> None:
+    def _answer_params(self, door, params) -> None:
+        """Decide the drained single PARAM_FLOW requests of one door, in
+        queue order, through ``decide_param_requests``: one call of the
+        service's batched entry per run of equal value counts."""
+        reqs = [req for _fd, _gen, req, _addr in params]
+        if self.is_standby:
+            verdicts = [(_STANDBY, 0, 0)] * len(reqs)
+        else:
+            verdicts = decide_param_requests(
+                self.service, reqs, int(TokenStatus.FAIL)
+            )
+        for (fd, gen, req, _addr), (st, rm, wt) in zip(params, verdicts):
+            door.send(fd, gen, P.encode_response(
+                P.FlowResponse(req.xid, req.msg_type, st, rm, wt)
+            ))
+
+    def _handle_control_item(self, door, item, params=None) -> None:
+        """One control event. A PARAM_FLOW request is not answered here but
+        appended to ``params`` (the control loop's drain), to be decided
+        with the others queued beside it."""
         kind, fd, gen, payload = item
         if kind == door.CTRL_OPEN:
             address = payload.decode("latin-1")
@@ -1309,6 +1384,11 @@ class NativeTokenServer:
             record_log.warning("bad control frame; closing %s", address)
             door.close_conn(fd, gen)
             return
+        if (params is not None and not isinstance(req, P.Ping)
+                and req.msg_type == P.MsgType.PARAM_FLOW):
+            self.connections.touch(address)
+            params.append((fd, gen, req, address))
+            return
         try:
             rsp = self._handle_control(req, address)
         except Exception:
@@ -1411,13 +1491,6 @@ class NativeTokenServer:
             # control-lane verdicts get the same closed-door refusal as the
             # data plane (PING above still answers: standbys stay pingable)
             return P.FlowResponse(req.xid, req.msg_type, _STANDBY)
-        if req.msg_type == P.MsgType.PARAM_FLOW:
-            r = service.request_params_token(
-                req.flow_id, req.count, req.param_hashes
-            )
-            return P.FlowResponse(
-                req.xid, req.msg_type, int(r.status), r.remaining, r.wait_ms
-            )
         if req.msg_type == P.MsgType.CONCURRENT_ACQUIRE:
             r = service.request_concurrent_token(
                 req.flow_id, req.count, req.prioritized

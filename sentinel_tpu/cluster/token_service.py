@@ -21,12 +21,14 @@ from dataclasses import dataclass
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from sentinel_tpu import chaos as _chaos
 from sentinel_tpu.core import clock as _clock
 from sentinel_tpu.core import compile_cache as _compile_cache
+from sentinel_tpu.core.log import record_log
 from sentinel_tpu.engine import (
     ClusterFlowRule,
     DegradeRule,
@@ -42,9 +44,11 @@ from sentinel_tpu.engine import (
 )
 from sentinel_tpu.engine.param import (
     ParamConfig,
+    explain_param_impl,
     hash_indices,
     make_param_state,
-    param_decide,
+    make_param_step,
+    pack_param_rows,
 )
 from sentinel_tpu.engine.rules import RuleIndex
 from sentinel_tpu.metrics.server import server_metrics
@@ -159,6 +163,49 @@ class TokenResult:
         )
 
 
+def params_batch_entry(service):
+    """The entry a door decides single PARAM_FLOW requests through: the
+    served object's own ``request_params_batch`` where its class defines
+    one, else the SPI default over its ``request_params_token`` — so an
+    object that forwards attributes to a service and intercepts only the
+    one-request entry (a control harness, a chaos shim, an older SPI
+    implementation) is still asked through what it intercepts."""
+    if getattr(type(service), "request_params_batch", None) is not None:
+        return service.request_params_batch
+    return partial(TokenService.request_params_batch, service)
+
+
+def decide_param_requests(service, requests, fail_status: int):
+    """Single PARAM_FLOW requests (objects with ``flow_id``, ``count``,
+    ``param_hashes``) in queue order -> ``[(status, remaining, wait_ms)]``:
+    one call of the batched entry per run of requests with the same number
+    of values, not one dispatch a request. A run whose call raises is
+    answered ``fail_status``. Both doors decide their single frames here."""
+    out = []
+    entry = params_batch_entry(service)
+    lo = 0
+    while lo < len(requests):
+        k = len(requests[lo].param_hashes)
+        hi = lo
+        while hi < len(requests) and len(requests[hi].param_hashes) == k:
+            hi += 1
+        run = requests[lo:hi]
+        try:
+            status, remaining, wait = entry(
+                np.array([r.flow_id for r in run], np.int64),
+                np.array([r.count for r in run], np.int32),
+                np.array([r.param_hashes for r in run],
+                         np.int64).reshape(len(run), k),
+            )
+            out.extend(zip(status.tolist(), remaining.tolist(),
+                           wait.tolist()))
+        except Exception:
+            record_log.exception("PARAM_FLOW requests failed")
+            out.extend([(fail_status, 0, 0)] * len(run))
+        lo = hi
+    return out
+
+
 class TokenService:
     """The SPI: local flow checkers and the transport both speak this."""
 
@@ -171,6 +218,26 @@ class TokenService:
         self, flow_id: int, acquire: int, param_hashes: Sequence[int]
     ) -> TokenResult:
         raise NotImplementedError
+
+    def request_params_batch(self, flow_ids, acquires, hashes):
+        """Array form of :meth:`request_params_token`: ``n`` requests of
+        ``k`` value hashes each (``hashes int64[n, k]``) -> (status int8[n],
+        remaining int32[n], wait_ms int32[n]) in request order. The doors
+        speak this for BATCH_PARAM_FLOW frames and for drained single
+        PARAM_FLOW frames; the default asks one request at a time, so any
+        SPI implementation serves them."""
+        n = len(flow_ids)
+        results = [
+            self.request_params_token(
+                int(flow_ids[i]), int(acquires[i]),
+                [int(h) for h in hashes[i]],
+            )
+            for i in range(n)
+        ]
+        status = np.fromiter((int(r.status) for r in results), np.int8, n)
+        remaining = np.fromiter((r.remaining for r in results), np.int32, n)
+        wait = np.fromiter((r.wait_ms for r in results), np.int32, n)
+        return status, remaining, wait
 
     def request_batch(
         self, requests: Sequence[Tuple[int, int, bool]]
@@ -380,6 +447,12 @@ class DefaultTokenService(TokenService):
         self._param_state = make_param_state(self.param_config)
         self._param_rules: Dict[int, Tuple[int, float, Dict[int, float]]] = {}
         self._param_free = list(range(self.param_config.max_param_rules - 1, -1, -1))
+        # the batched entry's view of the rules: one immutable snapshot of
+        # look-up arrays, rebuilt by load_param_rules (_param_tables)
+        self._param_lookup = self._param_tables()
+        # jitted serve steps by bucket, and what impl resolved to
+        self._param_steps: Dict[int, object] = {}
+        self._param_kernel: Optional[Tuple[str, str]] = None
         # sketch observability (sentinel_sketch_* series + the `sketch`
         # block of clusterServerStats): the process-wide ServerMetrics pulls
         # through a weakref so a dead service never pins memory; the most
@@ -964,31 +1037,20 @@ class DefaultTokenService(TokenService):
             # that shows more compiles than warmup recorded hit a cold
             # bucket (shape drift, ladder change) — visible, not silent.
             log_cluster("warmup_step_compiles", count=compiles)
-            idx = hash_indices(
-                np.zeros(1, np.int64),
-                self.param_config.depth,
-                self.param_config.cell_width,
-            )
-            idx_slim = None
-            if self.param_config.slim_enabled:
-                from sentinel_tpu.sketch.slim import slim_indices
-
-                si = slim_indices(self.param_config, np.zeros(1, np.int64))
-                idx_slim = jnp.asarray(
-                    np.broadcast_to(si, (8, si.shape[1]))
+            # the hot-parameter step, every serve bucket, on a throwaway
+            # sketch (the step donates its state)
+            self.param_impl()
+            ps = make_param_state(self.param_config, flat=True)
+            for bucket in self._serve_buckets:
+                packed = pack_param_rows(
+                    self.param_config, bucket, (), (), (),
+                    np.zeros((0, self.param_config.depth), np.int32),
+                    np.zeros((0, self.param_config.slim_depth), np.int32),
+                    now, 1, 0,
                 )
-            n_pad = 8  # matches request_params_token's minimum padded shape
-            param_decide(
-                self.param_config,
-                self._param_state,
-                jnp.zeros(n_pad, jnp.int32),
-                jnp.asarray(np.broadcast_to(idx, (n_pad, idx.shape[1]))),
-                jnp.zeros(n_pad, jnp.int32),
-                jnp.zeros(n_pad, jnp.float32),
-                jnp.zeros(n_pad, bool),  # nothing valid → state unchanged
-                jnp.int32(now),
-                idx_slim=idx_slim,
-            )
+                ps, verdicts = self._param_step_fn(bucket)(ps, packed)
+            jax.block_until_ready(verdicts)
+            del ps
         # from here on a compile is one in front of live traffic: counted
         # (compiles_after_warmup_total) and logged by name
         _SM.set_warm(True)
@@ -1167,7 +1229,8 @@ class DefaultTokenService(TokenService):
         _SM.count_verdict_read(ready)
         return t_ready, host.reshape(3, -1)
 
-    def _dispatched(self, t_enter, t_prep, t_locked, seq, rows) -> None:
+    def _dispatched(self, t_enter, t_prep, t_locked, seq, rows,
+                    lane: int = 0) -> None:
         """One dispatch left the service lock: its three dispatch-side
         phases (``monotonic_ns`` stamps of entry, prep done, lock acquired)
         go to the always-on histograms and, armed, to the flight recorder
@@ -1180,15 +1243,16 @@ class DefaultTokenService(TokenService):
             sid, aux = self._trace_sid, seq & 0x7FFFFFFF
             _TR.record(_TR.PREP, shard=sid, aux=aux, t_ns=t_prep)
             _TR.record(_TR.LOCKED, shard=sid, aux=aux, t_ns=t_locked)
-            _TR.record(_TR.DEVICE_IN, aux=rows, t_ns=t_out)
+            _TR.record(_TR.DEVICE_IN, shard=lane, aux=rows, t_ns=t_out)
 
     def _account(
-        self, status, wait, slots_ns, seq, rows, t_enter, t_mat, t_ready
+        self, status, wait, slots_ns, seq, rows, t_enter, t_mat, t_ready,
+        lane: int = 0,
     ) -> None:
         """The accounting tail of a materializer, and its three phases.
         ``slots_ns`` is request-order and PRE-mask, so MOVED verdicts land
         on their namespace (a fused span passes its frames' slots as a
-        list); ``t_enter``/``t_mat``/``t_ready`` are the
+        list, a param dispatch None); ``t_enter``/``t_mat``/``t_ready`` are the
         ``monotonic_ns`` stamps of the dispatch's entry, the materializer's
         entry and the verdict buffer reaching the host."""
         t_fetched = time.monotonic_ns()
@@ -1197,10 +1261,13 @@ class DefaultTokenService(TokenService):
         # per-namespace verdict counters (sentinel_server_verdicts_total):
         # attribute each request's verdict to its rule's namespace via the
         # lock-free slot→namespace snapshot
-        ns_names, slot_ns = self._ns_snapshot
-        ns_idx = np.where(
-            slots_ns >= 0, slot_ns[np.maximum(slots_ns, 0)], np.int32(-1)
-        )
+        if slots_ns is None:  # a param dispatch: no flow slots
+            ns_idx, ns_names = None, ()
+        else:
+            ns_names, slot_ns = self._ns_snapshot
+            ns_idx = np.where(
+                slots_ns >= 0, slot_ns[np.maximum(slots_ns, 0)], np.int32(-1)
+            )
         _SM.record_verdict_batch(
             status, ns_idx, ns_names,
             latency_ms=(time.monotonic_ns() - t_enter) * 1e-6,
@@ -1210,7 +1277,7 @@ class DefaultTokenService(TokenService):
             sid, aux = self._trace_sid, seq & 0x7FFFFFFF
             _TR.record(_TR.READY, shard=sid, aux=aux, t_ns=t_ready)
             _TR.record(_TR.FETCHED, shard=sid, aux=aux, t_ns=t_fetched)
-            _TR.record(_TR.DEVICE_OUT, aux=rows)
+            _TR.record(_TR.DEVICE_OUT, shard=lane, aux=rows)
         # cluster server stat log (ClusterServerStatLogUtil analog): one
         # aggregated counter per verdict class per window
         n_degraded = 0
@@ -1509,6 +1576,7 @@ class DefaultTokenService(TokenService):
                 items = dict(rule.item_thresholds or ())
                 self._param_rules[rule.flow_id] = (slot, rule.count, items)
             self._param_rules_src = {r.flow_id: r for r in rules}
+            self._param_lookup = self._param_tables()
             # same resync discipline as load_rules: param slot moves/frees
             # invalidate any delta collected against the old generation
             self._state_gen += 1
@@ -1553,56 +1621,229 @@ class DefaultTokenService(TokenService):
         ``ClusterParamFlowChecker``: every param value must have headroom).
         Admitted values are counted; on a mixed verdict the passed values'
         counts stand (conservative overcount, same direction as CMS error).
+        The one-row case of :meth:`request_params_batch`.
         """
         if not param_hashes:
             return TokenResult(TokenStatus.OK)
-        with self._lock:
-            entry = self._param_rules.get(int(flow_id))
-            if entry is None:
-                return TokenResult(TokenStatus.NO_RULE_EXISTS)
-            slot, count, items = entry
-            hashes = np.asarray(list(param_hashes), dtype=np.int64)
-            idx = hash_indices(
-                hashes, self.param_config.depth, self.param_config.cell_width
-            )
-            n = hashes.shape[0]
-            # pad to a power of two: param_decide's shapes are baked into its
-            # jit cache, and a client cycling value counts must not force a
-            # recompilation per count while holding the service lock
-            n_pad = max(8, 1 << (n - 1).bit_length())
-            pad = n_pad - n
-            idx = np.pad(idx, ((0, pad), (0, 0)))
-            idx_slim = None
-            if self.param_config.slim_enabled:
-                from sentinel_tpu.sketch.slim import slim_indices
+        status, remaining, wait = self.request_params_batch(
+            np.array([flow_id], np.int64), np.array([acquire], np.int32),
+            np.asarray([list(param_hashes)], np.int64),
+        )
+        return TokenResult(
+            TokenStatus(int(status[0])), int(remaining[0]), int(wait[0])
+        )
 
-                idx_slim = jnp.asarray(np.pad(
-                    slim_indices(self.param_config, hashes),
-                    ((0, pad), (0, 0)),
-                ))
-            thresholds = np.array(
-                [items.get(int(h), count) for h in hashes], dtype=np.float32
+    def request_params_batch(self, flow_ids, acquires, hashes):
+        """``n`` hot-parameter requests of ``k`` values each in one call:
+        (status int8[n], remaining int32[n], wait_ms int32[n]) in request
+        order. Dispatch + materialize; pipelining callers use
+        :meth:`dispatch_params_batch`."""
+        return self.dispatch_params_batch(flow_ids, acquires, hashes)()
+
+    def _param_tables(self):
+        """The look-up arrays of the param rules, one immutable snapshot
+        (called with the rules stable: the constructor, or
+        ``load_param_rules`` under the lock): rule ids sorted with their
+        slots and counts, and ONE sorted table of the item thresholds keyed
+        by ``slot * n_hashes + rank(hash)`` (``item_hashes`` is every item's
+        hash, sorted and unique, so the key is exact and fits an int64)."""
+        fids = np.fromiter(self._param_rules, np.int64, len(self._param_rules))
+        order = np.argsort(fids)
+        entries = list(self._param_rules.values())
+        slots = np.array([e[0] for e in entries], np.int32)[order]
+        counts = np.array([e[1] for e in entries], np.float32)[order]
+        i_slot, i_hash, i_thr = [], [], []
+        for slot, _count, items in entries:
+            i_slot.extend([slot] * len(items))
+            i_hash.extend(items.keys())
+            i_thr.extend(items.values())
+        item_hashes = np.unique(np.asarray(i_hash, np.int64))
+        keys = (np.asarray(i_slot, np.int64) * max(1, item_hashes.size)
+                + np.searchsorted(item_hashes, np.asarray(i_hash, np.int64)))
+        by_key = np.argsort(keys)
+        return (fids[order], slots, counts, item_hashes, keys[by_key],
+                np.asarray(i_thr, np.float32)[by_key])
+
+    @staticmethod
+    def _param_rows(lookup, cfg, flow_ids, acq, hashes):
+        """Host prep of one param batch against one look-up snapshot, no
+        Python per row: per request its rule slot (-1: no rule); per
+        (request, value) row the slot, acquire, threshold (the item's, else
+        the rule's count) and the sketch's cell indices."""
+        fids, slots, counts, item_hashes, item_keys, item_thr = lookup
+        n, k = hashes.shape
+        if fids.size:
+            at = np.minimum(np.searchsorted(fids, flow_ids), fids.size - 1)
+            found = fids[at] == flow_ids
+            req_slot = np.where(found, slots[at], np.int32(-1))
+            req_count = np.where(found, counts[at], np.float32(0))
+        else:
+            req_slot = np.full(n, -1, np.int32)
+            req_count = np.zeros(n, np.float32)
+        flat = hashes.reshape(-1)
+        row_slot = np.repeat(req_slot, k)
+        thr = np.repeat(req_count, k)
+        if item_keys.size:
+            rank = np.searchsorted(item_hashes, flat)
+            known = item_hashes[np.minimum(rank, item_hashes.size - 1)] == flat
+            key = row_slot.astype(np.int64) * item_hashes.size + rank
+            at = np.minimum(np.searchsorted(item_keys, key),
+                            item_keys.size - 1)
+            hit = known & (row_slot >= 0) & (item_keys[at] == key)
+            thr = np.where(hit, item_thr[at], thr)
+        idx = hash_indices(flat, cfg.depth, cfg.cell_width)
+        idx_slim = None
+        if cfg.slim_enabled:
+            from sentinel_tpu.sketch.slim import slim_indices
+
+            idx_slim = slim_indices(cfg, flat)
+        return req_slot, row_slot, np.repeat(acq, k), thr, idx, idx_slim
+
+    # The sketch as the serve step keeps it: the fat counters flat, donated
+    # to every step (engine.param.make_param_step). Everything else of the
+    # service (rule loads, snapshots, deltas, MOVE, stats) reads and writes
+    # ``_param_state``, the same state with the counters in their
+    # ``[P, B, depth, cells]`` shape: a reshape either way, on paths that
+    # are rare beside a dispatch.
+    @property
+    def _param_state(self):
+        from sentinel_tpu.engine.param import fat_shape
+
+        served = self._param_serve
+        return served._replace(
+            counts=served.counts.reshape(fat_shape(self.param_config))
+        )
+
+    @_param_state.setter
+    def _param_state(self, state) -> None:
+        self._param_serve = state._replace(counts=state.counts.reshape(-1))
+
+    def _param_bucket(self, rows: int) -> int:
+        """The serve bucket a param dispatch of ``rows`` (request, value)
+        rows pads to; past the largest (one request with more values than
+        it has rows) the next power of two, compiled when first met."""
+        for b in self._serve_buckets:
+            if rows <= b:
+                return b
+        return 1 << (rows - 1).bit_length()
+
+    def param_impl(self) -> Tuple[str, str]:
+        """``(kernel, reason)``: what ``param_config.impl`` resolved to for
+        this service's own geometry and largest serve bucket. Resolved once
+        (on a TPU "auto" times both kernels there), logged, exported."""
+        if self._param_kernel is None:
+            cfg = self.param_config
+            self._param_kernel = explain_param_impl(
+                cfg.impl, cfg.sketch, cfg, self._serve_buckets[-1]
             )
-            thresholds = np.pad(thresholds, (0, pad))
-            valid = np.zeros(n_pad, dtype=bool)
-            valid[:n] = True
+            _SM.set_param_impl(*self._param_kernel)
+            record_log.info(
+                "[param] impl %r serves %r: %s", cfg.impl,
+                *self._param_kernel,
+            )
+        return self._param_kernel
+
+    def _param_step_fn(self, bucket: int):
+        step = self._param_steps.get(bucket)
+        if step is None:
+            step = make_param_step(
+                self.param_config, bucket, self.param_impl()[0]
+            )
+            self._param_steps[bucket] = step
+        return step
+
+    def dispatch_params_batch(self, flow_ids, acquires, hashes):
+        """The hot-parameter serving path, phase 1: host prep + device
+        dispatch of ``n`` requests of ``k`` values (``hashes int64[n, k]``).
+        Returns a zero-arg **materializer** like
+        :meth:`dispatch_batch_arrays`, with the same phases and histograms.
+
+        Semantics are :meth:`request_params_token`'s, for every request: all
+        values of a request are judged together, any blocked value blocks
+        the request, the values that had headroom stay counted; requests on
+        one (rule, value) are admitted in batch order against the shared
+        budget. A request on a flow id with no param rule is answered
+        NO_RULE_EXISTS and never touches the sketch. The ``n x k`` (request,
+        value) rows are padded to the service's serve buckets and travel to
+        the step as one packed host array; a batch past the largest bucket
+        is cut into chunks of whole requests, all launched under one hold
+        of the lock, which covers the launches only."""
+        t_enter = time.monotonic_ns()
+        flow_ids = np.asarray(flow_ids, np.int64)
+        n = flow_ids.shape[0]
+        hashes = np.asarray(hashes, np.int64)
+        k = hashes.size // n if n else 0
+        hashes = hashes.reshape(n, k)
+        if n == 0 or k == 0:
+            # a request with no values passes, as the one-row entry says
+            def _trivial():
+                zero = np.zeros(n, np.int32)
+                return np.zeros(n, np.int8), zero, zero
+
+            return _trivial
+        acq = np.broadcast_to(np.asarray(acquires, np.int32), (n,))
+        cfg = self.param_config
+        per = max(1, self._serve_buckets[-1] // k)  # requests per chunk
+        cuts = list(range(0, n, per))
+
+        def prep(lookup):
+            req_slot, row_slot, row_acq, thr, idx, idx_slim = (
+                self._param_rows(lookup, cfg, flow_ids, acq, hashes)
+            )
+            packs = []
+            for lo in cuts:
+                hi = min(n, lo + per)
+                r = slice(lo * k, hi * k)
+                bucket = self._param_bucket((hi - lo) * k)
+                packs.append((bucket, hi - lo, pack_param_rows(
+                    cfg, bucket, row_slot[r], row_acq[r], thr[r], idx[r],
+                    None if idx_slim is None else idx_slim[r], 0, k, hi - lo,
+                )))
+            return req_slot, packs
+
+        lookup = self._param_lookup
+        req_slot, packs = prep(lookup)
+        steps = [self._param_step_fn(b) for b, _m, _p in packs]
+        t_prep = time.monotonic_ns()
+        with self._lock:
+            t_locked = time.monotonic_ns()
+            seq = self._dispatch_seq = self._dispatch_seq + 1
+            if self._param_lookup is not lookup:
+                # param rules reloaded between prep and step: redo the
+                # slot-dependent prep against the live tables
+                req_slot, packs = prep(self._param_lookup)
             now = self._engine_now()
-            self._param_state, admit, _est = param_decide(
-                self.param_config,
-                self._param_state,
-                jnp.full((n_pad,), slot, jnp.int32),
-                jnp.asarray(idx),
-                jnp.full((n_pad,), int(acquire), jnp.int32),
-                jnp.asarray(thresholds),
-                jnp.asarray(valid),
-                jnp.int32(now),
-                idx_slim=idx_slim,
-            )
+            outs = []
+            for step, (_b, _m, packed) in zip(steps, packs):
+                packed[-1, 0] = now
+                self._param_serve, verdicts = step(self._param_serve, packed)
+                outs.append(verdicts)
             if self._dirty is not None:
-                self._dirty["param"].add(int(slot))
-        if bool(np.asarray(admit)[:n].all()):
-            return TokenResult(TokenStatus.OK)
-        return TokenResult(TokenStatus.BLOCKED)
+                self._dirty["param"].update(
+                    np.unique(req_slot[req_slot >= 0]).tolist()
+                )
+        for verdicts in outs:
+            verdicts.copy_to_host_async()
+        self._dispatched(t_enter, t_prep, t_locked, seq, n * k,
+                         lane=_TR.PARAM_LANE)
+
+        def _materialize():
+            t_mat = time.monotonic_ns()
+            parts = []
+            for verdicts, (_b, m, _p) in zip(outs, packs):
+                t_ready, host = self._read_verdicts(verdicts)
+                parts.append(host[:, :m])
+            host = parts[0] if len(parts) == 1 else np.concatenate(parts, 1)
+            status, wait, remaining = unpack_verdicts(host)
+            _SM.count_param_dispatch(
+                n, n * k, int((status == int(TokenStatus.BLOCKED)).sum()),
+                int((req_slot < 0).sum()),
+            )
+            self._account(status, wait, None, seq, n * k, t_enter, t_mat,
+                          t_ready, lane=_TR.PARAM_LANE)
+            return status, remaining, wait
+
+        return _materialize
 
     # -- concurrent (semaphore) mode ----------------------------------------
     def load_concurrent_rules(self, rules) -> None:
@@ -3197,14 +3438,11 @@ class DefaultTokenService(TokenService):
         """Host snapshot of the param-sketch observability block: variant,
         fat/slim HBM bytes, SALSA merge counters. Pulled by the process-wide
         ``ServerMetrics`` on every scrape and by ``clusterServerStats``."""
-        from sentinel_tpu.engine.param import resolve_param_impl
         from sentinel_tpu.sketch import sketch_stats as _sketch_stats
 
         with self._lock:
             stats = _sketch_stats(self.param_config, self._param_state)
-        stats["impl"] = resolve_param_impl(
-            self.param_config.impl, self.param_config.sketch
-        )
+        stats["impl"], stats["implReason"] = self.param_impl()
         return stats
 
     def metrics_snapshot(self) -> Dict[int, Dict[str, float]]:

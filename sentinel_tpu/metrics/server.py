@@ -132,6 +132,21 @@ class ServerMetrics:
          "Backend compiles (persistent-cache hits included), each (ms)."),
     )
 
+    _PARAM_COUNTERS = {
+        "param_dispatch_total":
+            "Hot-parameter dispatches: calls of the batched param entry "
+            "that reached the sketch step (cumulative).",
+        "param_requests_total":
+            "Requests decided by hot-parameter dispatches (cumulative).",
+        "param_values_total":
+            "(request, value) rows those requests carried (cumulative).",
+        "param_blocked_total":
+            "Hot-parameter requests answered BLOCKED (cumulative).",
+        "param_no_rule_total":
+            "Hot-parameter requests on a flow id with no param rule, "
+            "answered without touching the sketch (cumulative).",
+    }
+
     def __init__(self):
         for name, _help in self._PHASES:
             setattr(self, name, LatencyHistogram(lo=0.001, hi=100_000.0))
@@ -142,6 +157,13 @@ class ServerMetrics:
         self._compiles_after_warmup = 0
         self._warm = False  # see set_warm
         self._compile_lock = threading.Lock()
+        # the hot-parameter lane (request_params_batch): dispatches, the
+        # requests and (request, value) rows they carried, requests refused
+        # by the sketch and requests on no rule; and what ParamConfig.impl
+        # resolved to for the serving geometry, with the reason
+        self._param_lock = threading.Lock()
+        self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
+        self._param_impl = ("", "")
         # stage histograms, all in milliseconds except batch_size (requests).
         # 1µs..10s covers a sub-100µs device step and a 1s cold compile alike.
         self.queue_wait_ms = LatencyHistogram(lo=0.001, hi=10_000.0)
@@ -273,6 +295,31 @@ class ServerMetrics:
     def verdict_host_reads_total(self) -> int:
         with self._verdict_read_lock:
             return self._verdict_host_reads
+
+    def count_param_dispatch(self, requests: int, values: int, blocked: int,
+                             no_rule: int) -> None:
+        """One hot-parameter dispatch was accounted."""
+        with self._param_lock:
+            p = self._param
+            p["param_dispatch_total"] += 1
+            p["param_requests_total"] += int(requests)
+            p["param_values_total"] += int(values)
+            p["param_blocked_total"] += int(blocked)
+            p["param_no_rule_total"] += int(no_rule)
+
+    def param_totals(self) -> Dict[str, int]:
+        with self._param_lock:
+            return dict(self._param)
+
+    def set_param_impl(self, kernel: str, reason: str) -> None:
+        """What ``ParamConfig.impl`` resolved to for the serving geometry."""
+        with self._param_lock:
+            self._param_impl = (str(kernel), str(reason))
+
+    @property
+    def param_impl(self) -> tuple:
+        with self._param_lock:
+            return self._param_impl
 
     @property
     def verdict_copy_ready_total(self) -> int:
@@ -816,6 +863,8 @@ class ServerMetrics:
         out["compiles_after_warmup_total"] = self.compiles_after_warmup_total
         out["verdict_host_reads_total"] = self.verdict_host_reads_total
         out["verdict_copy_ready_total"] = self.verdict_copy_ready_total
+        out.update(self.param_totals())
+        out["param_impl"], out["param_impl_reason"] = self.param_impl
         out["shed_total"] = self.shed_totals()
         out["host_copy_bytes_total"] = self.host_copy_bytes_total
         out["overlap_saved_ms_total"] = round(self.overlap_saved_ms_total, 3)
@@ -1203,10 +1252,26 @@ class ServerMetrics:
              "Materializations whose verdict buffer was ready on entry: the "
              "device had finished before the reply lane asked "
              "(cumulative).", self.verdict_copy_ready_total),
+            *((name, self._PARAM_COUNTERS[name], value)
+              for name, value in self.param_totals().items()),
         ):
             lines.append(f"# HELP sentinel_server_{name} {help_text}")
             lines.append(f"# TYPE sentinel_server_{name} counter")
             lines.append(f"sentinel_server_{name} {value}")
+        kernel, reason = self.param_impl
+        if kernel:
+            reason = reason.replace("\\", "/").replace('"', "'").replace(
+                "\n", " ")
+            lines.append(
+                "# HELP sentinel_server_param_impl_info The kernel "
+                "ParamConfig.impl resolved to for the serving geometry, and "
+                "why (constant 1)."
+            )
+            lines.append("# TYPE sentinel_server_param_impl_info gauge")
+            lines.append(
+                f'sentinel_server_param_impl_info{{impl="{kernel}",'
+                f'reason="{reason}"}} 1'
+            )
         lines.append(
             "# HELP sentinel_server_wait_assigned_total SHOULD_WAIT "
             "verdicts that carried a positive wait hint (cumulative)."
@@ -1239,6 +1304,8 @@ class ServerMetrics:
         with self._verdict_read_lock:
             self._verdict_host_reads = 0
             self._verdict_copy_ready = 0
+        with self._param_lock:
+            self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
         with self._verdict_lock:
             self._verdicts.clear()
             self._wait_assigned = 0

@@ -88,6 +88,18 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
             ],
             I32,
         ),
+        # flow OR param rows of one pull (codec rev 8, BATCH_PARAM_FLOW)
+        "sn_fd_wait_any": (
+            [
+                P, I32, ctypes.POINTER(I64), ctypes.POINTER(I32),
+                ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(I64), I32,
+                I32, ctypes.POINTER(I32), ctypes.POINTER(I32),
+                ctypes.POINTER(I32), ctypes.POINTER(I32),
+                ctypes.POINTER(ctypes.c_uint8), I32, ctypes.POINTER(I32),
+                ctypes.POINTER(I32),
+            ],
+            I32,
+        ),
         "sn_fd_submit": (
             [
                 P, I32, ctypes.POINTER(I32), ctypes.POINTER(I32),
@@ -600,6 +612,45 @@ class Frontdoor:
             return None
         return n, n_frames.value
 
+    def wait_any_into(self, staging: dict, timeout_ms: int = 100,
+                      max_n: Optional[int] = None):
+        """:meth:`wait_batch_into` for a host that serves hot-parameter
+        rows too: one pull is either flow rows or the rows of
+        BATCH_PARAM_FLOW frames, never both (the door hands out whichever
+        arrived first). Returns ``None`` on timeout, else ``(n, frames,
+        k)``: ``k`` is 0 for a flow pull, else the values per request of a
+        param pull, whose hashes then fill ``staging["hashes"][:n * k]``
+        request-major (a pull takes frames of one ``k`` and at most as
+        many values as that array holds)."""
+        from sentinel_tpu.cluster.protocol import MAX_BATCH_PER_FRAME
+
+        cap = int(staging["ids"].shape[0])
+        if max_n is None:
+            max_n = cap
+        max_n = min(
+            max(int(max_n), MAX_BATCH_PER_FRAME), cap, self.arena_cap
+        )
+        n_frames = ctypes.c_int32()
+        k = ctypes.c_int32()
+        n = self._lib.sn_fd_wait_any(
+            self._h, timeout_ms,
+            self._ptr(staging["ids"], ctypes.c_int64),
+            self._ptr(staging["counts"], ctypes.c_int32),
+            self._ptr(staging["prios"], ctypes.c_uint8),
+            self._ptr(staging["hashes"], ctypes.c_int64),
+            max_n, int(staging["hashes"].shape[0]),
+            self._ptr(staging["f_fd"], ctypes.c_int32),
+            self._ptr(staging["f_gen"], ctypes.c_int32),
+            self._ptr(staging["f_xid"], ctypes.c_int32),
+            self._ptr(staging["f_n"], ctypes.c_int32),
+            self._ptr(staging["f_type"], ctypes.c_uint8),
+            int(staging["f_fd"].shape[0]), ctypes.byref(n_frames),
+            ctypes.byref(k),
+        )
+        if n <= 0:
+            return None
+        return n, n_frames.value, k.value
+
     def submit(self, frames, status, remaining, wait_ms) -> None:
         """Encode + send verdict frames for a ``wait_batch`` result."""
         import numpy as np
@@ -775,6 +826,14 @@ class ShmDoor:
 
     _ptr = Frontdoor._ptr
     _bufs = Frontdoor._bufs
+
+    def wait_any_into(self, staging: dict, timeout_ms: int = 100,
+                      max_n: Optional[int] = None):
+        """The intake lane's pull; the ring carries flow rows only, so
+        ``k`` is always 0 (see :meth:`Frontdoor.wait_any_into`)."""
+        got = self.wait_batch_into(staging, timeout_ms, max_n)
+        return None if got is None else (got[0], got[1], 0)
+
     # identical pull/answer surface — the ctypes marshaling only differs in
     # the export name, so rebind the TCP door's methods over sn_shm_*
     def wait_batch_into(self, staging: dict, timeout_ms: int = 100,

@@ -43,6 +43,13 @@ def gather_current_estimate(config, counts, rule_slot, idx, cur_idx):
         mval = lo + CAP * (-hi - 1)
         own = jnp.where(idx % 2 == 0, lo, hi)
         per_d = jnp.where(merged, mval, own)
+    elif counts.ndim == 1:
+        # the serve step's flat cells (engine.param.make_param_step)
+        B, W = config.n_buckets, config.width
+        per_d = counts[
+            ((safe_slot[:, None] * B + cur_idx) * config.depth + d_ar) * W
+            + idx
+        ]
     else:
         per_d = counts[safe_slot[:, None], cur_idx, d_ar, idx]
     return jnp.min(per_d, axis=1)
@@ -135,10 +142,9 @@ def sketch_stats(config, state) -> Dict[str, object]:
     nz = np.nonzero(merges)[0]
     return {
         "variant": config.sketch,
-        "fatBytes": int(np.asarray(state.counts).nbytes),
-        "slimBytes": (
-            int(np.asarray(state.slim).nbytes) if config.slim_enabled else 0
-        ),
+        # sizes, not contents: never pull the sketch to the host for them
+        "fatBytes": int(state.counts.nbytes),
+        "slimBytes": int(state.slim.nbytes) if config.slim_enabled else 0,
         "slimEnabled": bool(config.slim_enabled),
         "mergesTotal": int(merges.sum()),
         "mergesBySlot": {int(s): int(merges[s]) for s in nz},

@@ -40,7 +40,8 @@ CLIENT_IN = 1    # frame decoded / pulled off a door (aux = rows)
 ENQUEUE = 2      # frame handed to the batching queue (aux = queue depth)
 DISPATCH = 3     # frame's batch entered the device dispatch (aux = batch rows)
 DEVICE_IN = 4    # device step submitted, service lock released (aggregate,
-#                  xid=0; aux = rows)
+#                  xid=0; aux = rows; shard = PARAM_LANE for a hot-parameter
+#                  dispatch, whose rows are its (request, value) rows)
 DEVICE_OUT = 5   # verdicts on the host AND counted (record_verdict_batch done;
 #                  the stat-log passes follow) (aggregate, xid=0; aux = rows)
 REPLY_OUT = 6    # frame's reply encoded + submitted to its door (aux = rows)
@@ -54,6 +55,7 @@ PROMOTE = 13     # standby promoted to primary
 BROWNOUT = 14    # admission ladder escalated (aux = level)
 SHM_POLL = 15    # shm ring door poll/doorbell activity (aux = frames)
 OUTCOME = 16     # batched completion report ingested (aux = rows accepted)
+PARAM_LANE = 1   # ``shard`` of the DEVICE_IN / DEVICE_OUT of a param dispatch
 # Phase boundaries inside one dispatch (aggregate, xid=0). Each marks the END
 # of a phase; ``shard`` carries the service's id and ``aux`` its dispatch
 # sequence number (taken under the service lock), so one dispatch's

@@ -123,11 +123,14 @@ class TestKernelsCompileOrRaise:
         record_log.addHandler(handler)
         P._param_decide_pallas.clear_cache()
         try:
-            choice, reason = P._probe_param_impl("cms")
+            choice, reason = P._probe_param_impl(
+                P.ParamConfig(max_param_rules=8, width=128), 64
+            )
         finally:
             record_log.removeHandler(handler)
         assert choice == "jax"
         assert "jax " in reason and "ms/step" in reason  # the winner's time
+        assert "cms 8x2x2x128, 64 rows" in reason  # the geometry it timed
         assert "pallas refused: ValueError" in reason
         assert "interpret mode" in reason  # the compiler's own words
         assert any("refused by the compiler" in m for m in logged)
@@ -138,13 +141,28 @@ class TestKernelsCompileOrRaise:
         monkeypatch.delenv("SENTINEL_PARAM_IMPL", raising=False)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         monkeypatch.setattr(P, "_AUTO_IMPL", {})
-        monkeypatch.setattr(
-            P, "_probe_param_impl", lambda sketch: ("jax", f"probed {sketch}")
-        )
+        probed = []
+
+        def probe(config, rows):
+            probed.append((config.sketch, config.width, rows))
+            return "jax", f"probed {config.sketch} {config.width} {rows}"
+
+        monkeypatch.setattr(P, "_probe_param_impl", probe)
         assert P.explain_param_impl("auto", "salsa") == (
-            "jax", "probed salsa"
+            "jax", "probed salsa 2048 64"
         )
         assert P.resolve_param_impl("auto", "salsa") == "jax"
+        # a service names its own geometry and largest serve bucket: timed
+        # once for that geometry, and what a caller without one then gets
+        cfg = P.ParamConfig(width=16384, depth=4, sketch="salsa")
+        for _ in range(2):
+            assert P.explain_param_impl("auto", "salsa", cfg, 4096) == (
+                "jax", "probed salsa 16384 4096"
+            )
+        assert P.explain_param_impl("auto", "salsa")[1] == (
+            "probed salsa 16384 4096"
+        )
+        assert probed == [("salsa", 2048, 64), ("salsa", 16384, 4096)]
 
     def test_decide_auto_on_a_tpu_is_a_stated_constant(self, monkeypatch):
         import importlib

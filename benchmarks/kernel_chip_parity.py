@@ -168,11 +168,12 @@ def decide_parity():
                 now += int(rng.choice([30, 120, 1_700]))
         # and once under lax.scan, as the fused step runs it
         frames = [make_batch(cfg_x, *batch()) for _ in range(2)]
-        stacked = jax.tree.map(lambda *xs: np.stack(xs), *frames)
+        stacked = D.pack_batch(
+            jax.tree.map(lambda *xs: np.stack(xs), *frames), 10_000)
         st_x, v_x = D.decide_fused_donating(cfg_x, 2, grouped=True)(
-            make_state(cfg_x), table, stacked, 10_000)
+            make_state(cfg_x), table, stacked)
         st_p, v_p = D.decide_fused_donating(cfg_p, 2, grouped=True)(
-            make_state(cfg_p), table, stacked, 10_000)
+            make_state(cfg_p), table, stacked)
         _equal(f"decide n={n} fused verdicts", v_x, v_p)
         _equal(f"decide n={n} fused state", st_x, st_p)
     return f"N = 64, 256, 1024 at F={N_FLOWS}, both uniform values, scan"

@@ -658,8 +658,8 @@ class StagingPool:
 
     ``factory()`` builds one block (any object — the native server uses a
     bundle of pinned request/frame-metadata arrays; the fused dispatcher
-    uses stacked ``[depth, batch]`` RequestBatch leaves). ``acquire`` pops a
-    recycled block or builds a fresh one when the freelist is dry (burst
+    uses one packed ``int32[lines, depth, batch]`` request block).
+    ``acquire`` pops a recycled block or builds a fresh one when the freelist is dry (burst
     absorption — the pool never blocks a lane); ``release`` returns a block
     for reuse, dropping it once ``capacity`` blocks are already parked so a
     transient burst doesn't pin its high-water memory forever.

@@ -38,10 +38,12 @@ from sentinel_tpu.engine import (
     build_rule_table,
     decide,
     drain_pending_clear,
-    make_batch,
     make_state,
+    pack_requests,
+    pack_requests_into,
     unpack_verdicts,
 )
+from sentinel_tpu.engine.decide import HEAD_NOW, ROW_HEAD
 from sentinel_tpu.engine.param import (
     ParamConfig,
     explain_param_impl,
@@ -63,16 +65,21 @@ _SERVICE_IDS = itertools.count(1)
 
 class _PrepCache:
     """Bounded LRU memo of the host-side batch prep — the ``lookup_slots``
-    resolution plus the grouping argsort and padded ``RequestBatch`` — keyed
-    by the exact (flow_ids, acquires, prios) byte content and the lookup
-    snapshot identity. Closed-loop clients (and real sidecar fleets) resend
-    the same hot flow-id vectors frame after frame, so the hit path replaces
-    an O(n log n) sort + four array passes with one memcmp verification.
+    resolution plus the grouping argsort and the padded, packed request
+    array (``pack_requests``) — keyed by the exact (flow_ids, acquires,
+    prios) byte content and the lookup snapshot identity. Closed-loop
+    clients (and real sidecar fleets) resend the same hot flow-id vectors
+    frame after frame, so the hit path replaces an O(n log n) sort + four
+    array passes with one memcmp verification.
 
     A rule reload swaps the lookup snapshot, which changes the key and
     naturally invalidates every entry (dead entries age out of the LRU).
-    Entries hold numpy arrays the device step only reads, so sharing one
-    prepped batch across dispatches is safe (batches are never donated).
+    Entries are shared across dispatches and only read; the packed array
+    is kept READ-ONLY, a template: a step's host argument also carries the
+    dispatch's clock, which therefore goes into a copy (an earlier
+    dispatch's step may still be reading its argument when the next clock
+    is written: the CPU backend aliases an aligned numpy argument
+    outright; the TPU's copies during the call, PERF.md section 6).
     """
 
     def __init__(self, capacity: int = 64):
@@ -94,7 +101,7 @@ class _PrepCache:
             if hit is not None:
                 self._map.move_to_end(key)
         if hit is not None:
-            c_ids, c_acq, c_pr, slots, order, batch = hit
+            c_ids, c_acq, c_pr, slots, order, packed = hit
             # content verification: `hash` collisions must never hand a
             # different request vector someone else's slot assignment
             if (
@@ -103,15 +110,16 @@ class _PrepCache:
                 and np.array_equal(c_pr, pr)
             ):
                 self.hits += 1
-                return key, (slots, order, batch)
+                return key, (slots, order, packed)
         self.misses += 1
         return key, None
 
-    def put(self, key, flow_ids, acq, pr, slots, order, batch) -> None:
+    def put(self, key, flow_ids, acq, pr, slots, order, packed) -> None:
         # copies: callers may hand views into reused front-door buffers
+        packed.flags.writeable = False
         entry = (
             np.array(flow_ids), np.array(acq), np.array(pr),
-            slots, order, batch,
+            slots, order, packed,
         )
         with self._lock:
             self._map[key] = entry
@@ -386,12 +394,13 @@ class DefaultTokenService(TokenService):
             reverse=True,
         ))
         self._fused_steps: Dict[Tuple[int, bool], object] = {}
-        # fused staging freelists: per scan depth, recycled [depth, batch]
-        # RequestBatch leaf blocks the fused dispatch writes prepped frames
-        # into — replaces the per-dispatch np.stack (4 fresh [depth, batch]
-        # allocations per fused group) with copies into pinned, reused
-        # memory. Blocks are released after verdict materialization (the
-        # device has definitely consumed the host buffers by then).
+        # fused staging freelists: per scan depth, recycled packed request
+        # blocks (alloc_packed_block: ONE int32[lines, depth, batch] array,
+        # the fused step's one host argument) the fused dispatch writes
+        # prepped frames into — replaces a per-dispatch np.stack with copies
+        # into reused memory. Blocks are released after verdict
+        # materialization (the device has definitely consumed the host
+        # buffer by then).
         self._fused_staging: Dict[int, object] = {}
         self._prep_cache = _PrepCache()
         self._lock = threading.Lock()
@@ -566,17 +575,18 @@ class DefaultTokenService(TokenService):
 
     @staticmethod
     def _prep_batch(cfg, slots, acq, pr):
-        """Build the device batch; returns ``(order, batch)`` where order is
-        None when slots arrived ascending-SORTED (stable argsort would be
-        the identity) — skipping an O(n log n) sort and three fancy-index
-        passes each way. Grouped-but-unsorted input still sorts.
-        Shared by the hot prep and the rare rules-reloaded re-prep so the
-        two can't diverge."""
+        """Build the device batch; returns ``(order, packed)``: the step's
+        one host argument (``pack_requests``, its clock still 0) and the
+        grouping order, None when slots arrived ascending-SORTED (stable
+        argsort would be the identity) — skipping an O(n log n) sort and
+        three fancy-index passes each way. Grouped-but-unsorted input
+        still sorts. Shared by the hot prep and the rare rules-reloaded
+        re-prep so the two can't diverge."""
         sorted_already = bool((slots[:-1] <= slots[1:]).all())
         if sorted_already:
-            return None, make_batch(cfg, slots, acq, pr)
+            return None, pack_requests(cfg, slots, acq, pr)
         order = np.argsort(slots, kind="stable")
-        return order, make_batch(cfg, slots[order], acq[order], pr[order])
+        return order, pack_requests(cfg, slots[order], acq[order], pr[order])
 
     # -- mesh placement -----------------------------------------------------
     def _place_state(self, state):
@@ -595,7 +605,9 @@ class DefaultTokenService(TokenService):
 
     def _step_fn(self, bucket: int, uniform: bool):
         """The device step for one (shape bucket, uniform) variant —
-        single-shard ``decide`` or the mesh-sharded shard_map step.
+        single-shard ``decide`` or the mesh-sharded shard_map step —
+        called ``step(state, rules, packed)`` with ONE host argument, the
+        packed request batch and clock of ``pack_requests``.
 
         Cached per variant for BOTH paths: a fresh closure + fresh config
         object per call would route every dispatch through pjit's slow
@@ -679,31 +691,33 @@ class DefaultTokenService(TokenService):
         pool = self._fused_staging.get(depth)
         if pool is None:
             from sentinel_tpu.cluster.protocol import StagingPool
-            from sentinel_tpu.engine.decide import alloc_fused_batch
+            from sentinel_tpu.engine.decide import alloc_packed_block
 
             pool = self._fused_staging.setdefault(
                 depth,
                 StagingPool(
-                    partial(alloc_fused_batch, self.config, depth),
+                    partial(alloc_packed_block, self.config, depth),
                     capacity=8,
                 ),
             )
         return pool
 
     def _prep_cached(self, lookup_snap, cfg, bucket, flow_ids, acq, pr):
-        """Host prep with the hot-vector memo: ``(slots, order, batch)`` for
-        one engine frame, served from :class:`_PrepCache` when the same
+        """Host prep with the hot-vector memo: ``(slots, order, packed)``
+        for one engine frame, served from :class:`_PrepCache` when the same
         (flow_ids, acquires, prios) vector was prepped against the same
-        lookup snapshot before."""
+        lookup snapshot before. ``packed`` is the cache's read-only
+        template: a dispatch copies it (into its own array or a staging
+        row) before the clock goes in."""
         key, hit = self._prep_cache.get(
             lookup_snap[0], bucket, flow_ids, acq, pr
         )
         if hit is not None:
             return hit
         slots = self._lookup_from(lookup_snap, flow_ids)
-        order, batch = self._prep_batch(cfg, slots, acq, pr)
-        self._prep_cache.put(key, flow_ids, acq, pr, slots, order, batch)
-        return slots, order, batch
+        order, packed = self._prep_batch(cfg, slots, acq, pr)
+        self._prep_cache.put(key, flow_ids, acq, pr, slots, order, packed)
+        return slots, order, packed
 
     # -- rule management (ClusterFlowRuleManager analog) --------------------
     def load_rules(
@@ -1010,10 +1024,10 @@ class DefaultTokenService(TokenService):
             compiles = 0
             for bucket in self._serve_buckets:
                 cfg = self.config._replace(batch_size=bucket)
-                batch = make_batch(cfg, [-1])
+                packed = pack_requests(cfg, [-1], now=now)
                 for uniform in (True, False):
                     step = self._step_fn(bucket, uniform)
-                    ws, _ = step(ws, self._table, batch, jnp.int32(now))
+                    ws, _ = step(ws, self._table, packed)
                     compiles += 1
             # fused multi-frame variants (full batch_size frames only):
             # compile the ladder's scan depths so the first oversized pull
@@ -1024,14 +1038,12 @@ class DefaultTokenService(TokenService):
             # bucket — mesh compiles are far slower, and a cold bucket in
             # the serving window would stall the whole pod's device lane.
             fused_uniforms = (True,) if self.mesh is None else (True, False)
-            base = make_batch(self.config, [-1])
+            base = pack_requests(self.config, [-1], now=now)
             for fdepth in self._fuse_depths:
-                stacked = type(base)(
-                    *(np.stack([leaf] * fdepth) for leaf in base)
-                )
+                block = np.stack([base] * fdepth, axis=1)
                 for uniform in fused_uniforms:
                     step = self._fused_step_fn(fdepth, uniform)
-                    ws, _ = step(ws, self._table, stacked, jnp.int32(now))
+                    ws, _ = step(ws, self._table, block)
                     compiles += 1
             # compile counts on the cluster stat log: a serving window
             # that shows more compiles than warmup recorded hit a cold
@@ -1145,9 +1157,10 @@ class DefaultTokenService(TokenService):
         # smallest compiled shape bucket that fits this batch
         bucket = next(b for b in self._serve_buckets if n <= b)
         cfg = self.config._replace(batch_size=bucket)
-        slots, order, batch = self._prep_cached(
+        slots, order, template = self._prep_cached(
             lookup_snap, cfg, bucket, flow_ids, acq, pr
         )
+        packed = template.copy()  # this dispatch's own: it takes the clock
         step = self._step_fn(bucket, uniform)
         slots_ns = slots  # pre-mask slots: verdict→namespace attribution
         moved_mask = moved_epochs = None
@@ -1163,7 +1176,7 @@ class DefaultTokenService(TokenService):
                 # atomicity load_rules callers had before the narrowing)
                 slots = self._lookup_from(self._lookup, flow_ids)
                 slots_ns = slots
-                order, batch = self._prep_batch(cfg, slots, acq, pr)
+                order, packed = self._prep_batch(cfg, slots, acq, pr)
             mv = self._moving_snap
             if mv is not None:
                 # live rebalance: rows of a MOVING namespace are masked out
@@ -1176,11 +1189,11 @@ class DefaultTokenService(TokenService):
                     slots = np.where(
                         moved_mask, np.int32(-1), slots
                     ).astype(np.int32)
-                    order, batch = self._prep_batch(cfg, slots, acq, pr)
-            now = self._engine_now()
-            self._state, packed = step(
-                self._state, self._table, batch, np.int32(now)
-            )
+                    order, packed = self._prep_batch(cfg, slots, acq, pr)
+            # the clock rides the one host argument; read under the lock,
+            # written into an array only this dispatch holds
+            packed[ROW_HEAD, HEAD_NOW] = self._engine_now()
+            self._state, verdicts = step(self._state, self._table, packed)
             if self._dirty is not None:
                 touched = np.unique(slots[slots >= 0]).tolist()
                 self._dirty["flow"].update(touched)
@@ -1192,13 +1205,13 @@ class DefaultTokenService(TokenService):
                     )
         # the verdicts' one copy to the host starts now, behind the step on
         # the device's queue, not when a reply lane gets round to asking
-        packed.copy_to_host_async()
+        verdicts.copy_to_host_async()
         self._dispatched(t_enter, t_prep, t_locked, seq, n)
 
         def _materialize():
             # blocks on the async dispatch; runs outside the lock
             t_mat = time.monotonic_ns()
-            t_ready, host = self._read_verdicts(packed)
+            t_ready, host = self._read_verdicts(verdicts)
             status, wait, remaining = unpack_verdicts(host, n, order)
             if moved_mask is not None:
                 # MOVED overlay: the device saw these rows as no-rule; the
@@ -1350,10 +1363,11 @@ class DefaultTokenService(TokenService):
         request-order ``(status, remaining, wait)`` for the whole span.
 
         Each frame is prepped independently (slot lookup + grouping sort,
-        through the prep cache) and the padded batches stacked into
-        ``[depth, cap]`` leaves; the single device call then replaces
-        ``depth`` dispatches. The fused group shares one ``now`` — frames in
-        one pull arrived together, so this only collapses sub-millisecond
+        through the prep cache) and its packed request lines laid into row
+        ``f`` of ONE ``[lines, depth, cap]`` staging block, the step's one
+        host argument; the single device call then replaces ``depth``
+        dispatches. The fused group shares one ``now`` — frames in one
+        pull arrived together, so this only collapses sub-millisecond
         clock skew a per-frame loop would have read anyway.
         """
         t_enter = time.monotonic_ns()
@@ -1363,34 +1377,36 @@ class DefaultTokenService(TokenService):
         # frame, which is still correct for the uniform ones among them
         uniform = bool(acq.min() == acq.max())
         cfg = self.config  # fused frames are exactly batch_size-shaped
-
-        def _prep_all(snapshot):
-            preps = []
-            for f in range(depth):
-                sl = slice(f * cap, (f + 1) * cap)
-                preps.append(
-                    self._prep_cached(
-                        snapshot, cfg, cap, flow_ids[sl], acq[sl], pr[sl]
-                    )
-                )
-            return preps
-
         pool = self._fused_block_pool(depth)
         block = pool.acquire()
+        frames = [slice(f * cap, (f + 1) * cap) for f in range(depth)]
+        preps = []
+        for f, sl in enumerate(frames):
+            p = self._prep_cached(
+                lookup_snap, cfg, cap, flow_ids[sl], acq[sl], pr[sl]
+            )
+            # the zero-alloc replacement for a per-dispatch np.stack; the
+            # head line stays the block's own
+            block[:ROW_HEAD, f] = p[2][:ROW_HEAD]
+            preps.append(p)
 
-        def _fill(preps):
-            # lay each frame's prepped leaves into its staging row — the
-            # zero-alloc replacement for per-leaf np.stack (cache hits make
-            # this the only per-frame host copy left on the fused path)
-            for f, p in enumerate(preps):
-                b = p[2]
-                block.flow_slot[f] = b.flow_slot
-                block.acquire[f] = b.acquire
-                block.prioritized[f] = b.prioritized
-                block.valid[f] = b.valid
+        def _restage(f, slots_f):
+            # the rare re-preps under the lock: bypass the cache (its
+            # entries are keyed by snapshot identity, so stale hits are
+            # impossible, but re-prepping directly keeps the rare path
+            # simple) and write straight into the staging rows
+            sl = frames[f]
+            if bool((slots_f[:-1] <= slots_f[1:]).all()):
+                order_f = None
+                pack_requests_into(block, f, slots_f, acq[sl], pr[sl])
+            else:
+                order_f = np.argsort(slots_f, kind="stable")
+                pack_requests_into(
+                    block, f, slots_f[order_f], acq[sl][order_f],
+                    pr[sl][order_f],
+                )
+            return slots_f, order_f, None
 
-        preps = _prep_all(lookup_snap)
-        _fill(preps)
         step = self._fused_step_fn(depth, uniform)
         moved_span = moved_epochs_span = span_ns = None
         t_prep = time.monotonic_ns()
@@ -1401,26 +1417,13 @@ class DefaultTokenService(TokenService):
             if self._lookup is not lookup_snap:
                 # rules reloaded between prep and step (see
                 # dispatch_batch_arrays): redo slot-dependent prep against
-                # the live table, bypassing the cache (its entries are keyed
-                # by snapshot identity, so stale hits are impossible, but
-                # re-prepping directly keeps the rare path simple). Writes
-                # land straight in the staging rows (make_batch_into).
-                from sentinel_tpu.engine.decide import make_batch_into
-
-                preps = []
-                for f in range(depth):
-                    sl = slice(f * cap, (f + 1) * cap)
-                    slots_f = self._lookup_from(self._lookup, flow_ids[sl])
-                    if bool((slots_f[:-1] <= slots_f[1:]).all()):
-                        order_f = None
-                        make_batch_into(block, f, slots_f, acq[sl], pr[sl])
-                    else:
-                        order_f = np.argsort(slots_f, kind="stable")
-                        make_batch_into(
-                            block, f, slots_f[order_f], acq[sl][order_f],
-                            pr[sl][order_f],
-                        )
-                    preps.append((slots_f, order_f, None))
+                # the live table
+                preps = [
+                    _restage(
+                        f, self._lookup_from(self._lookup, flow_ids[sl])
+                    )
+                    for f, sl in enumerate(frames)
+                ]
             mv = self._moving_snap
             if mv is not None:
                 # live rebalance (see dispatch_batch_arrays): mask MOVING-
@@ -1430,31 +1433,18 @@ class DefaultTokenService(TokenService):
                 span0 = np.concatenate([p[0] for p in preps])
                 m, eps = self._moving_mask_for(span0, mv)
                 if m is not None:
-                    from sentinel_tpu.engine.decide import make_batch_into
-
                     moved_span, moved_epochs_span, span_ns = m, eps, span0
-                    preps = []
-                    for f in range(depth):
-                        sl = slice(f * cap, (f + 1) * cap)
-                        slots_f = np.where(
-                            m[sl], np.int32(-1), span0[sl]
-                        ).astype(np.int32)
-                        if bool((slots_f[:-1] <= slots_f[1:]).all()):
-                            order_f = None
-                            make_batch_into(
-                                block, f, slots_f, acq[sl], pr[sl]
-                            )
-                        else:
-                            order_f = np.argsort(slots_f, kind="stable")
-                            make_batch_into(
-                                block, f, slots_f[order_f],
-                                acq[sl][order_f], pr[sl][order_f],
-                            )
-                        preps.append((slots_f, order_f, None))
-            now = self._engine_now()
-            self._state, packed = step(
-                self._state, self._table, block, np.int32(now)
-            )
+                    preps = [
+                        _restage(
+                            f,
+                            np.where(
+                                m[sl], np.int32(-1), span0[sl]
+                            ).astype(np.int32),
+                        )
+                        for f, sl in enumerate(frames)
+                    ]
+            block[ROW_HEAD, 0, HEAD_NOW] = self._engine_now()
+            self._state, verdicts = step(self._state, self._table, block)
             if self._dirty is not None:
                 span = np.concatenate([p[0] for p in preps])
                 touched = np.unique(span[span >= 0]).tolist()
@@ -1463,7 +1453,7 @@ class DefaultTokenService(TokenService):
                     self._dirty.setdefault("breaker", set()).update(
                         s for s in touched if s in self._breaker_slots
                     )
-        packed.copy_to_host_async()  # see dispatch_batch_arrays
+        verdicts.copy_to_host_async()  # see dispatch_batch_arrays
         self._dispatched(t_enter, t_prep, t_locked, seq, depth * cap)
         _SM.record_fused(depth)
         if _TR.ARMED:  # flight recorder: fused group submitted
@@ -1475,9 +1465,9 @@ class DefaultTokenService(TokenService):
             # along the span, so the per-frame grouping sorts are undone as
             # ONE span-wide order.
             t_mat = time.monotonic_ns()
-            t_ready, host = self._read_verdicts(packed)
+            t_ready, host = self._read_verdicts(verdicts)
             # verdicts are ready → the device has consumed the staging
-            # block's host buffers; recycle it for the next fused group
+            # block's host buffer; recycle it for the next fused group
             pool.release(block)
             total = depth * cap
             span_order = None

@@ -28,10 +28,15 @@ from sentinel_tpu.engine.decide import (
     VerdictBatch,
     TokenStatus,
     alloc_fused_batch,
+    alloc_packed_block,
     decide,
     make_batch,
     make_batch_into,
+    pack_batch,
+    pack_requests,
+    pack_requests_into,
     pack_verdicts,
+    unpack_requests,
     unpack_verdicts,
 )
 
@@ -52,6 +57,11 @@ __all__ = [
     "TokenStatus",
     "decide",
     "make_batch",
+    "alloc_packed_block",
+    "pack_batch",
+    "pack_requests",
+    "pack_requests_into",
+    "unpack_requests",
     "pack_verdicts",
     "unpack_verdicts",
 ]

@@ -171,11 +171,11 @@ def make_batch(
 
 
 def alloc_fused_batch(config: EngineConfig, depth: int) -> RequestBatch:
-    """One ``[depth, batch_size]`` stacked-frame staging block — the numpy
-    leaves :func:`decide_fused_donating` consumes. Freelist-recycled by the
-    fused dispatcher (`cluster.protocol.StagingPool`): jit copies numpy
-    arguments to device buffers during the call, so a block is safe to
-    recycle the moment the dispatch returns."""
+    """One ``[depth, batch_size]`` stacked-frame block of numpy
+    :class:`RequestBatch` leaves (filled by :func:`make_batch_into`): what
+    the non-serving fused step, ``make_sharded_decide(depth=..., donate=
+    False)``, scans. The serve steps take the packed form instead
+    (:func:`alloc_packed_block`)."""
     N = config.batch_size
     return RequestBatch(
         flow_slot=np.empty((depth, N), np.int32),
@@ -213,6 +213,110 @@ def make_batch_into(
     prio[n:] = False
     valid[:n] = True
     valid[n:] = False
+
+
+# -- the serve steps' one host argument ---------------------------------------
+# Every host argument of a jitted call is a host-to-device transfer of its
+# own, 0.13-0.16 ms of launch each whatever the rows (PERF.md), so a serve
+# step takes the request batch AND the clock as ONE ``int32[PACKED_LINES,
+# batch_size]`` array (``[PACKED_LINES, depth, batch_size]`` fused): what
+# :class:`RequestBatch` and ``now`` hold, 16 bytes a row.
+ROW_SLOT, ROW_ACQUIRE, ROW_FLAGS, ROW_HEAD = 0, 1, 2, 3
+PACKED_LINES = 4
+FLAG_PRIORITIZED, FLAG_VALID = 1, 2  # bits of a ROW_FLAGS entry
+# the head line is [now, 0...]; a fused block's clock is its frame 0's
+HEAD_NOW = 0
+
+
+def _pack_rows(out, flow_slots, acquires, prioritized) -> None:
+    """:func:`make_batch`'s padding (slot -1 / acquire 0 / no flag beyond n;
+    acquire 1 by default) written into the request lines of ``out``, an
+    ``int32[PACKED_LINES, N]`` view. The head line is the caller's."""
+    N = out.shape[-1]
+    n = len(flow_slots)
+    if n > N:
+        raise ValueError(f"batch of {n} exceeds configured size {N}")
+    slot, acq, flags = out[ROW_SLOT], out[ROW_ACQUIRE], out[ROW_FLAGS]
+    slot[:n] = flow_slots
+    slot[n:] = -1
+    acq[:n] = 1 if acquires is None else acquires
+    acq[n:] = 0
+    if prioritized is None:
+        flags[:n] = FLAG_VALID
+    else:
+        flags[:n] = np.asarray(prioritized, bool)  # FLAG_PRIORITIZED
+        flags[:n] |= FLAG_VALID
+    flags[n:] = 0
+
+
+def pack_requests(
+    config: EngineConfig,
+    flow_slots: Sequence[int],
+    acquires: Optional[Sequence[int]] = None,
+    prioritized: Optional[Sequence[bool]] = None,
+    now: int = 0,
+) -> np.ndarray:
+    """:func:`make_batch` and the clock as a serve step's one host argument:
+    a fresh ``int32[PACKED_LINES, batch_size]``. The token service packs
+    outside its lock and writes ``now`` into ``[ROW_HEAD, HEAD_NOW]`` under
+    it."""
+    out = np.empty((PACKED_LINES, config.batch_size), np.int32)
+    _pack_rows(out, flow_slots, acquires, prioritized)
+    out[ROW_HEAD] = 0
+    out[ROW_HEAD, HEAD_NOW] = now
+    return out
+
+
+def pack_batch(batch: RequestBatch, now: int) -> np.ndarray:
+    """A :class:`RequestBatch` (a frame, or ``[depth, batch_size]`` stacked
+    leaves) and the clock in the serve steps' packed form: the bridge from
+    the library entry's arguments (:func:`make_batch`, ``now``) for tests
+    and drills. The serving path packs rows directly
+    (:func:`pack_requests`)."""
+    slot = np.asarray(batch.flow_slot)
+    out = np.zeros((PACKED_LINES,) + slot.shape, np.int32)
+    out[ROW_SLOT] = slot
+    out[ROW_ACQUIRE] = batch.acquire
+    out[ROW_FLAGS] = (
+        np.asarray(batch.prioritized, bool) * FLAG_PRIORITIZED
+        + np.asarray(batch.valid, bool) * FLAG_VALID
+    )
+    out[ROW_HEAD].flat[HEAD_NOW] = now
+    return out
+
+
+def alloc_packed_block(config: EngineConfig, depth: int) -> np.ndarray:
+    """One ``int32[PACKED_LINES, depth, batch_size]`` staging block, the one
+    host argument of the fused serve steps (:func:`decide_fused_donating`):
+    frame ``f`` is ``block[:, f]``, the group's clock ``block[ROW_HEAD, 0,
+    HEAD_NOW]``. Freelist-recycled by the fused dispatcher
+    (`cluster.protocol.StagingPool`) once the group's verdicts are on the
+    host, never sooner: the CPU backend aliases an aligned numpy argument
+    outright, so a block in flight must not be written (the TPU's copies
+    during the call; PERF.md section 6)."""
+    return np.zeros((PACKED_LINES, depth, config.batch_size), np.int32)
+
+
+def pack_requests_into(out: np.ndarray, row: int, flow_slots, acquires=None,
+                       prioritized=None) -> None:
+    """:func:`pack_requests` writing frame ``row`` of a staging block
+    (:func:`alloc_packed_block`) instead of allocating; the clock is
+    written once per block, not per frame."""
+    _pack_rows(out[:, row], flow_slots, acquires, prioritized)
+
+
+def unpack_requests(packed) -> tuple:
+    """``(RequestBatch, now)`` from a packed request array, frame or fused
+    block: traced at the head of every serve step, so the cores below keep
+    the arguments they always took."""
+    flags = packed[ROW_FLAGS]
+    batch = RequestBatch(
+        flow_slot=packed[ROW_SLOT],
+        acquire=packed[ROW_ACQUIRE],
+        prioritized=(flags & FLAG_PRIORITIZED) != 0,
+        valid=(flags & FLAG_VALID) != 0,
+    )
+    return batch, packed[ROW_HEAD].ravel()[HEAD_NOW]
 
 
 from sentinel_tpu.engine.degrade import breaker_gate as _breaker_gate
@@ -925,16 +1029,20 @@ def decide_donating(config: EngineConfig, grouped: bool = False,
     XLA must copy them first (measured 22% of a 64-bucket step at 100k
     flows on CPU; on TPU it is HBM traffic and allocator churn).
 
-    Returns a cached-callable ``step(state, rules, batch, now) ->
-    (state', packed)``; ``packed`` is the ``int32[3, N]`` verdict buffer of
-    :func:`pack_verdicts` (host side: :func:`unpack_verdicts`). The
-    caller contract: nothing else may hold the passed state (the token
-    service's lock makes ``self._state, v = step(self._state, …)`` the
-    only reader), and warmup-style calls must feed throwaway states.
+    Returns a cached-callable ``step(state, rules, packed) -> (state',
+    verdicts)``: ``packed`` is the ONE host argument of a dispatch, the
+    ``int32[PACKED_LINES, N]`` array of :func:`pack_requests` (request batch
+    and clock; :func:`unpack_requests` is traced at the step's head), and
+    ``verdicts`` the ``int32[3, N]`` buffer of :func:`pack_verdicts` (host
+    side: :func:`unpack_verdicts`). The caller contract: nothing else may
+    hold the passed state (the token service's lock makes ``self._state, v
+    = step(self._state, …)`` the only reader), and warmup-style calls must
+    feed throwaway states.
     """
     core = _core_for(config, grouped)
 
-    def step(state, rules, batch, now):
+    def step(state, rules, packed):
+        batch, now = unpack_requests(packed)
         state, verdicts = core(
             config, state, rules, batch, now, axis_name=None,
             grouped=grouped, uniform=uniform,
@@ -953,10 +1061,11 @@ def decide_fused_donating(config: EngineConfig, depth: int,
     over ``depth`` stacked request frames, donating the state buffers like
     :func:`decide_donating`.
 
-    Returns ``step(state, rules, batches, now) -> (state', packed)``
-    where every ``batches`` leaf is ``[depth, batch_size]``-shaped (the
-    per-frame :class:`RequestBatch` leaves stacked along a new leading
-    axis) and ``packed`` is ONE ``int32[3, depth, batch_size]`` buffer
+    Returns ``step(state, rules, packed) -> (state', verdicts)`` where
+    ``packed`` is the ONE host argument, an ``int32[PACKED_LINES, depth,
+    batch_size]`` block (:func:`alloc_packed_block`: the per-frame
+    :func:`pack_requests` lines stacked along a new middle axis, one shared
+    clock) and ``verdicts`` is ONE ``int32[3, depth, batch_size]`` buffer
     (:func:`pack_verdicts` of the ``[depth, batch_size]`` verdict leaves,
     same frame order). Frame ``k`` sees exactly the state frame
     ``k-1`` produced — the on-device equivalent of ``depth`` consecutive
@@ -974,7 +1083,9 @@ def decide_fused_donating(config: EngineConfig, depth: int,
         uniform=uniform,
     )
 
-    def fused(state, rules, batches, now):
+    def fused(state, rules, packed):
+        batches, now = unpack_requests(packed)
+
         def body(st, batch):
             st, verdicts = core(st, rules, batch, now)
             return st, verdicts

@@ -28,6 +28,7 @@ from sentinel_tpu.engine.decide import (
     _core_for,
     pack_verdicts,
     step_name,
+    unpack_requests,
 )
 from sentinel_tpu.engine.rules import RuleTable
 from sentinel_tpu.engine.state import BreakerState, EngineState, ShapingState
@@ -155,14 +156,19 @@ def make_sharded_decide(
     ``axis_index``).
 
     ``donate=True`` is the serve step, exactly like the single-shard
-    ``decide_donating``: it donates the state buffers (XLA updates the
-    sharded window tensors in place instead of copying the full per-shard
-    state every dispatch) and returns the verdicts as the one replicated
-    ``int32[3, ...]`` buffer of ``pack_verdicts`` instead of a
-    ``VerdictBatch``.
+    ``decide_donating``: ``step(state, rules, packed)``. It donates the
+    state buffers (XLA updates the sharded window tensors in place instead
+    of copying the full per-shard state every dispatch), takes the request
+    batch and the clock as the ONE replicated host array of
+    ``pack_requests`` (a host argument is placed on every device of the
+    mesh) and returns the verdicts as the one replicated ``int32[3, ...]``
+    buffer of ``pack_verdicts``. Without it the step is the library entry
+    ``step(state, rules, batch, now)`` with a ``RequestBatch`` in and a
+    ``VerdictBatch`` out.
 
     ``depth=F`` builds the fused variant: one ``lax.scan`` of the sharded
-    step over ``[F, batch_size]`` stacked request frames, inside a single
+    step over ``[F, batch_size]`` stacked request frames (packed:
+    ``alloc_packed_block``), inside a single
     ``shard_map`` entry. Each scan iteration psum-stitches that frame's
     verdicts over ICI before the next frame decides, so per-frame verdicts
     are bit-identical to F sequential sharded dispatches — but the host
@@ -200,9 +206,16 @@ def make_sharded_decide(
 
             return jax.lax.scan(body, state, batches, length=depth)
 
-    def step(state, rules, batch, now):
-        state, verdicts = decide_shard(state, rules, batch, now)
-        return state, pack_verdicts(verdicts) if donate else verdicts
+    if donate:
+        def step(state, rules, packed):
+            batch, now = unpack_requests(packed)
+            state, verdicts = decide_shard(state, rules, batch, now)
+            return state, pack_verdicts(verdicts)
+
+        request_specs = (P(),)
+    else:
+        step = decide_shard
+        request_specs = (_batch_specs(), P())
 
     # two spec shapes, matching the two RuleTable pytree structures: with
     # br_* columns (degrade rules loaded) and without (None columns, so the
@@ -217,8 +230,7 @@ def make_sharded_decide(
             in_specs=(
                 _state_specs(axis),
                 _rules_specs(axis, br=br),
-                _batch_specs(),
-                P(),
+                *request_specs,  # replicated
             ),
             # verdicts replicated, as a VerdictBatch or packed into one
             out_specs=(_state_specs(axis), P()),
@@ -241,8 +253,8 @@ def make_sharded_decide(
             impls[br] = _build(br)
         return impls[br]
 
-    def sharded_step(state, rules, batch, now):
-        return jitted(rules)(state, rules, batch, now)
+    def sharded_step(state, rules, *request):
+        return jitted(rules)(state, rules, *request)
 
     sharded_step.jitted = jitted
     return named(sharded_step, name)

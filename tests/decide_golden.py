@@ -93,12 +93,12 @@ def _frames(cfg, index, seed, n_frames, uniform):
 def verdicts() -> dict:
     """``{case name: int32[frames, 3, batch]}`` (status, remaining, wait)."""
     import jax
-    import jax.numpy as jnp
 
-    from sentinel_tpu.engine import make_batch, make_state
+    from sentinel_tpu.engine import make_state
     from sentinel_tpu.engine.decide import (
         decide_donating,
         decide_fused_donating,
+        pack_requests,
         unpack_verdicts,
     )
     from sentinel_tpu.parallel import (
@@ -121,34 +121,33 @@ def verdicts() -> dict:
     for uniform in (True, False):
         tag = "uniform" if uniform else "mixed"
         frames = _frames(cfg, index, 7 if uniform else 11, 8, uniform)
-        batches = [make_batch(cfg, s, a, p) for s, a, p in frames]
-        times = [10_000 + 130 * i for i in range(len(batches))]
+        # the serve steps' one host argument: request batch and clock
+        batches = [pack_requests(cfg, s, a, p, now=10_000 + 130 * i)
+                   for i, (s, a, p) in enumerate(frames)]
 
         step = decide_donating(cfg, grouped=True, uniform=uniform)
         state, got = make_state(cfg), []
-        for b, now in zip(batches, times):
-            state, v = step(state, table, b, jnp.int32(now))
+        for b in batches:
+            state, v = step(state, table, b)
             got.append(pack(v))
         out[f"single_{tag}"] = np.stack(got)
 
         step = make_sharded_decide(cfg, mesh, grouped=True, uniform=uniform,
                                    donate=True)
         state, got = shard_state(make_state(cfg), mesh), []
-        for b, now in zip(batches, times):
-            state, v = step(state, table_m, b, jnp.int32(now))
+        for b in batches:
+            state, v = step(state, table_m, b)
             got.append(pack(v))
         out[f"sharded_{tag}"] = np.stack(got)
 
-        stacked = type(batches[0])(
-            *(np.stack(leaves) for leaves in zip(*batches[:4])))
+        stacked = np.stack(batches[:4], axis=1)  # the clock is frame 0's
         step = decide_fused_donating(cfg, 4, grouped=True, uniform=uniform)
-        _, v = step(make_state(cfg), table, stacked, jnp.int32(times[0]))
+        _, v = step(make_state(cfg), table, stacked)
         out[f"fused_{tag}"] = pack(v)
 
         step = make_sharded_decide(cfg, mesh, grouped=True, uniform=uniform,
                                    donate=True, depth=4)
-        _, v = step(shard_state(make_state(cfg), mesh), table_m, stacked,
-                    jnp.int32(times[0]))
+        _, v = step(shard_state(make_state(cfg), mesh), table_m, stacked)
         out[f"sharded_fused_{tag}"] = pack(v)
     return out
 

@@ -33,6 +33,7 @@ from sentinel_tpu.engine import (
 )
 from sentinel_tpu.engine.decide import (
     decide_fused_donating,
+    pack_batch,
     unpack_verdicts,
 )
 from sentinel_tpu.engine.outcome import outcome_step_donating
@@ -550,7 +551,7 @@ class TestFusedParity:
         state, _ = _decide_rows(cfg, state, table, 1050, [s])  # trip
         fused = decide_fused_donating(cfg, depth=3)
         batches = _stack_batches(cfg, [[s] * 16] * 3)
-        state, v = fused(state, table, batches, jnp.int32(1400))
+        state, v = fused(state, table, pack_batch(batches, 1400))
         status = unpack_verdicts(v).status[:, :16]
         assert int((status == int(TokenStatus.OK)).sum()) == 1
         assert status[0, 0] == int(TokenStatus.OK)
@@ -577,7 +578,7 @@ class TestFusedParity:
         fused_state = _prepared(CFG, table, index, seed=0xABC)
         fused = decide_fused_donating(CFG, depth=depth)
         fused_state, fv = fused(
-            fused_state, table, _stack_batches(CFG, frames), jnp.int32(now)
+            fused_state, table, pack_batch(_stack_batches(CFG, frames), now)
         )
         fv = unpack_verdicts(fv)
         for k in range(depth):

@@ -22,7 +22,7 @@ from sentinel_tpu.cluster.server_native import (
 )
 from sentinel_tpu.cluster.token_service import DefaultTokenService
 from sentinel_tpu.core.log import record_log
-from sentinel_tpu.engine import ClusterFlowRule, EngineConfig, make_batch
+from sentinel_tpu.engine import ClusterFlowRule, EngineConfig, pack_requests
 from sentinel_tpu.engine import make_state
 from sentinel_tpu.engine.rules import ThresholdMode
 from sentinel_tpu.metrics.profiler import ProfilerHook
@@ -258,15 +258,15 @@ def _lowered(kind):
     )
 
     cfg, table, _index = decide_golden._setup()
-    batch = make_batch(cfg, [0, 1, 2])
-    stacked = type(batch)(*(np.stack([leaf] * 2) for leaf in batch))
+    batch = pack_requests(cfg, [0, 1, 2], now=1000)
+    stacked = np.stack([batch] * 2, axis=1)
     now = jnp.int32(1000)
     if kind == "single":
         step = decide_donating(cfg, grouped=True, uniform=False)
-        return step.__name__, step.lower(make_state(cfg), table, batch, now)
+        return step.__name__, step.lower(make_state(cfg), table, batch)
     if kind == "fused":
         step = decide_fused_donating(cfg, 2, grouped=True, uniform=True)
-        return step.__name__, step.lower(make_state(cfg), table, stacked, now)
+        return step.__name__, step.lower(make_state(cfg), table, stacked)
     if kind == "outcome":
         step = outcome_step_donating(cfg)
         z = jnp.zeros(8, jnp.int32)
@@ -277,11 +277,10 @@ def _lowered(kind):
     if kind == "sharded":
         step = make_sharded_decide(cfg, mesh, grouped=True, uniform=True,
                                    donate=True)
-        return step.__name__, step.jitted(table).lower(state, table, batch,
-                                                       now)
+        return step.__name__, step.jitted(table).lower(state, table, batch)
     step = make_sharded_decide(cfg, mesh, grouped=True, uniform=False,
                                donate=True, depth=2)
-    return step.__name__, step.jitted(table).lower(state, table, stacked, now)
+    return step.__name__, step.jitted(table).lower(state, table, stacked)
 
 
 STEP_NAMES = {
@@ -327,7 +326,7 @@ def test_the_breaker_arm_is_a_named_scope_once_degrade_rules_load():
         cfg, [ClusterFlowRule(flow_id=1, count=5.0, mode=G)],
         degrade_rules=[DegradeRule(flow_id=1)])
     low = decide_donating(cfg, grouped=True, uniform=True).lower(
-        make_state(cfg), table, make_batch(cfg, [0]), jnp.int32(1000))
+        make_state(cfg), table, pack_requests(cfg, [0], now=1000))
     assert re.search(r'[/"]breaker/', low.as_text(debug_info=True))
 
 

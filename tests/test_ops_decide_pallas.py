@@ -33,6 +33,7 @@ from sentinel_tpu.engine.decide import (
     RequestBatch,
     _decide_core,
     decide_fused_donating,
+    pack_batch,
     resolve_decide_impl,
     unpack_verdicts,
 )
@@ -195,8 +196,9 @@ class TestMegakernelParity:
         batches = jax.tree.map(
             lambda *xs: np.stack(xs), *[b for _, b in frames]
         )
-        st_x, v_x = step_x(make_state(CFG_X), table, batches, now)
-        st_p, v_p = step_p(make_state(CFG_P), table, batches, now)
+        packed = pack_batch(batches, now)
+        st_x, v_x = step_x(make_state(CFG_X), table, packed)
+        st_p, v_p = step_p(make_state(CFG_P), table, packed)
         _assert_trees_equal(v_x, v_p, "fused verdicts")
         _assert_trees_equal(st_x, st_p, "fused state")
 
@@ -387,8 +389,9 @@ class TestBreakerParity:
         step_p = decide_fused_donating(CFG_P, depth, grouped=True)
         frames = [make_batch(CFG_X, np.ones(6, np.int32)) for _ in range(2)]
         batches = jax.tree.map(lambda *xs: np.stack(xs), *frames)
-        st_x, v_x = step_x(st_x, table, batches, jnp.int32(10_400))
-        st_p, v_p = step_p(st_p, table, batches, jnp.int32(10_400))
+        packed = pack_batch(batches, 10_400)
+        st_x, v_x = step_x(st_x, table, packed)
+        st_p, v_p = step_p(st_p, table, packed)
         _assert_trees_equal(v_x, v_p, "fused breaker verdicts")
         _assert_trees_equal(st_x, st_p, "fused breaker state")
         status = unpack_verdicts(v_x).status[:, :6]

@@ -26,6 +26,7 @@ from sentinel_tpu.engine import (
 )
 from sentinel_tpu.engine.decide import (
     decide_fused_donating,
+    pack_batch,
     unpack_verdicts,
 )
 from sentinel_tpu.engine.rules import ControlBehavior, ThresholdMode
@@ -698,7 +699,7 @@ class TestFusedParity:
                 for rows in frames
             ],
         )
-        state_f, vf = fused(make_state(CFG), table, batches, jnp.int32(now))
+        state_f, vf = fused(make_state(CFG), table, pack_batch(batches, now))
         vf = unpack_verdicts(vf)
 
         for k, v in enumerate(seq_verdicts):

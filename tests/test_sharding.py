@@ -19,7 +19,7 @@ from sentinel_tpu.engine import (
     make_batch,
     make_state,
 )
-from sentinel_tpu.engine.decide import unpack_verdicts
+from sentinel_tpu.engine.decide import pack_batch, unpack_verdicts
 from sentinel_tpu.engine.rules import ThresholdMode
 from sentinel_tpu.parallel import (
     make_flow_mesh,
@@ -191,7 +191,7 @@ class TestShardedDonationAndFusion:
         state = shard_state(make_state(CFG), mesh)
         table_8 = shard_rules(table, mesh)
         batch = make_batch(CFG, [index.lookup(0)] * 4)
-        new_state, _ = step(state, table_8, batch, jnp.int32(10_000))
+        new_state, _ = step(state, table_8, pack_batch(batch, 10_000))
         # the donated input's buffers are gone — XLA updated them in place
         assert state.flow.counts.is_deleted()
         assert state.occupy.counts.is_deleted()
@@ -229,7 +229,7 @@ class TestShardedDonationAndFusion:
               for k in frames[0]._fields)
         )
         fused_state = shard_state(make_state(CFG), mesh)
-        out_state, fv = fused(fused_state, table_8, stacked, jnp.int32(10_000))
+        out_state, fv = fused(fused_state, table_8, pack_batch(stacked, 10_000))
         assert fused_state.flow.counts.is_deleted()  # donated
         fv = unpack_verdicts(fv)
         for f in range(depth):
@@ -473,7 +473,7 @@ class TestMegakernelStateContract:
         state = shard_state(make_state(cfg), mesh)
         table_8 = shard_rules(table, mesh)
         batch = self._sorted_batch(index, np.random.default_rng(5))
-        new_state, _ = step(state, table_8, batch, jnp.int32(10_000))
+        new_state, _ = step(state, table_8, pack_batch(batch, 10_000))
         # the donated input's buffers are gone — the aliased pallas_call
         # updated them in place instead of forcing a defensive copy
         assert state.flow.counts.is_deleted()
@@ -488,7 +488,7 @@ class TestMegakernelStateContract:
         step = decide_donating(cfg, grouped=True, uniform=True)
         state = make_state(cfg)
         batch = self._sorted_batch(index, np.random.default_rng(6))
-        new_state, _ = step(state, table, batch, jnp.int32(10_000))
+        new_state, _ = step(state, table, pack_batch(batch, 10_000))
         assert state.flow.counts.is_deleted()
         assert not new_state.flow.counts.is_deleted()
 

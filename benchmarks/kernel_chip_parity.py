@@ -1,23 +1,21 @@
-"""The four Pallas kernels, compiled on the chip, against their XLA twins.
+"""The three Pallas kernels, compiled on the chip, against their XLA twins.
 
-What ``tests/test_ops_pallas.py`` / ``test_ops_decide_pallas.py`` /
-``test_sketch_parity.py`` check under the interpreter on the CPU, checked
-where it counts: each kernel is compiled by Mosaic (never interpreted) at the
-shapes the serving path hands it, fed seeded streams whose counts climb past
-256 (what a single bf16 MXU pass would round), and every output and state
-leaf is compared bitwise with the XLA implementation run on the same chip.
+What ``tests/test_ops_pallas.py`` / ``test_sketch_parity.py`` check under
+the interpreter on the CPU, checked where it counts: each kernel is compiled
+by Mosaic (never interpreted) at the shapes the serving path hands it, fed
+seeded streams whose counts climb past 256 (what a single bf16 MXU pass would
+round), and every output and state leaf is compared bitwise with the XLA
+implementation run on the same chip.
 
     python benchmarks/kernel_chip_parity.py
 
 Each kernel ends as ``MATCH`` or as ``REFUSED`` with the compiler's message.
-The exit code is non-zero when a kernel the selectors offer (``auto`` may
-resolve to it) is refused or differs; a kernel the selectors already keep
-away from ``auto`` is reported and does not fail the run.
+The exit code is non-zero when a kernel is refused or differs: ``auto`` may
+resolve to each of them.
 """
 
 from __future__ import annotations
 
-import importlib
 import os
 import sys
 
@@ -27,7 +25,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-N_FLOWS = 100_000
 SEED = 0
 
 
@@ -114,71 +111,6 @@ def salsa_parity():
     return _sketch_parity("salsa")
 
 
-def decide_parity():
-    import jax
-
-    from sentinel_tpu.engine import (
-        ClusterFlowRule,
-        EngineConfig,
-        build_rule_table,
-        make_batch,
-        make_state,
-    )
-    from sentinel_tpu.engine.rules import ControlBehavior, ThresholdMode
-
-    D = importlib.import_module("sentinel_tpu.engine.decide")
-    g = ThresholdMode.GLOBAL
-    rules = [ClusterFlowRule(i, 50.0 + i % 400, g, f"ns{i % 64}")
-             for i in range(N_FLOWS - 3)]
-    rules += [
-        ClusterFlowRule(N_FLOWS - 3, 40.0, g, "ns0",
-                        control_behavior=int(ControlBehavior.WARM_UP)),
-        ClusterFlowRule(N_FLOWS - 2, 25.0, g, "ns0",
-                        control_behavior=int(ControlBehavior.RATE_LIMITER)),
-        ClusterFlowRule(N_FLOWS - 1, 5.0, namespace="ns0"),  # AVG_LOCAL
-    ]
-    rng = np.random.default_rng(SEED)
-    for n in (64, 256, 1024):
-        cfg_x = EngineConfig(max_flows=N_FLOWS, max_namespaces=64,
-                             batch_size=n, decide_impl="xla")
-        cfg_p = cfg_x._replace(decide_impl="pallas")
-        table, index = build_rule_table(cfg_x, rules)
-        hot = [index.lookup(f) for f in range(N_FLOWS - 3, N_FLOWS)]
-
-        def batch():
-            slots = rng.integers(0, N_FLOWS, size=n).astype(np.int32)
-            slots[: n // 4] = rng.choice(hot + [7], size=n // 4)
-            slots.sort()
-            acq = rng.integers(1, 4, size=n).astype(np.int32)
-            return slots, acq, rng.random(n) < 0.2
-
-        for uniform in (True, False):
-            st_x, st_p = make_state(cfg_x), make_state(cfg_p)
-            now = 10_000
-            for step in range(6):
-                slots, acq, prio = batch()
-                b = make_batch(cfg_x, slots, None if uniform else acq, prio)
-                st_x, v_x = D.decide(cfg_x, st_x, table, b, now,
-                                     grouped=True, uniform=uniform)
-                st_p, v_p = D.decide(cfg_p, st_p, table, b, now,
-                                     grouped=True, uniform=uniform)
-                label = f"decide n={n} uniform={uniform} step={step}"
-                _equal(label + " verdicts", v_x, v_p)
-                _equal(label + " state", st_x, st_p)
-                now += int(rng.choice([30, 120, 1_700]))
-        # and once under lax.scan, as the fused step runs it
-        frames = [make_batch(cfg_x, *batch()) for _ in range(2)]
-        stacked = D.pack_batch(
-            jax.tree.map(lambda *xs: np.stack(xs), *frames), 10_000)
-        st_x, v_x = D.decide_fused_donating(cfg_x, 2, grouped=True)(
-            make_state(cfg_x), table, stacked)
-        st_p, v_p = D.decide_fused_donating(cfg_p, 2, grouped=True)(
-            make_state(cfg_p), table, stacked)
-        _equal(f"decide n={n} fused verdicts", v_x, v_p)
-        _equal(f"decide n={n} fused state", st_x, st_p)
-    return f"N = 64, 256, 1024 at F={N_FLOWS}, both uniform values, scan"
-
-
 def main() -> None:
     import jax
 
@@ -191,25 +123,21 @@ def main() -> None:
         sys.exit(2)
     ensure_compile_cache()
     print(f"device {dev.device_kind!r}, jax {jax.__version__}", flush=True)
-    D = importlib.import_module("sentinel_tpu.engine.decide")
-    # (kernel, check, may "auto" resolve to it?)
     kernels = (
-        ("ops/prefix_pallas.py", prefix_parity, True),
-        ("ops/cms_pallas.py", cms_parity, True),
-        ("ops/salsa_pallas.py", salsa_parity, True),
-        ("ops/decide_pallas.py", decide_parity,
-         D.explain_decide_impl("auto")[0] == "pallas"),
+        ("ops/prefix_pallas.py", prefix_parity),
+        ("ops/cms_pallas.py", cms_parity),
+        ("ops/salsa_pallas.py", salsa_parity),
     )
     failed = False
-    for name, check, offered in kernels:
+    for name, check in kernels:
         try:
             print(f"{name}: MATCH bitwise ({check()})", flush=True)
         except KERNEL_BUILD_ERRORS as e:
             print(f"{name}: REFUSED {type(e).__name__}: {e}", flush=True)
-            failed |= offered
+            failed = True
         except AssertionError as e:
             print(f"{name}: MISMATCH {e}", flush=True)
-            failed |= offered
+            failed = True
     sys.exit(1 if failed else 0)
 
 

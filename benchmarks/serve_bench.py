@@ -104,20 +104,13 @@ def build_server(n_flows: int = 100_000, max_batch: int = 16384,
                  serve_buckets=(4096, 16384), native: bool = True,
                  port: int = 0, n_dispatchers: int = 2,
                  fuse_depth: int = 4, intake_shards: int = 1,
-                 mesh_devices: int = 0, shm_dir=None,
-                 decide_impl: str = "auto"):
+                 mesh_devices: int = 0, shm_dir=None):
     """Service (100k rules — the headline's problem size) + front door.
 
     ``mesh_devices > 0`` backs the service with a flow-sharded mesh over
     that many devices (the caller must have made them visible — see
     :func:`force_virtual_cpu_devices` for the CPU-mesh recipe); the front
-    door and everything behind it is unchanged, which is the point.
-
-    ``decide_impl`` selects the decide backend (``EngineConfig``):
-    "auto" runs the production selector (the XLA pipeline — see
-    ``engine.decide.explain_decide_impl``), which is exactly what the
-    serve-smoke floor gates; "pallas" forces the megakernel, which Mosaic
-    compiles or refuses with an error."""
+    door and everything behind it is unchanged, which is the point."""
     from sentinel_tpu.cluster.server import TokenServer
     from sentinel_tpu.cluster.token_service import DefaultTokenService
     from sentinel_tpu.engine import ClusterFlowRule, EngineConfig
@@ -125,7 +118,6 @@ def build_server(n_flows: int = 100_000, max_batch: int = 16384,
 
     config = EngineConfig(
         max_flows=n_flows, max_namespaces=64, batch_size=max_batch,
-        decide_impl=decide_impl,
     )
     mesh = None
     if mesh_devices:

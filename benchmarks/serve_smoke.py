@@ -138,7 +138,7 @@ def _xid_probe_shm(shm_dir: str, n_flows: int, frames: int = 24,
 
 def run_smoke(seconds: float = 4.0, intake_shards: int = 1,
               mesh_devices: int = 0, transport: str = "tcp",
-              trace: str = "off", decide_impl: str = "auto") -> dict:
+              trace: str = "off") -> dict:
     import tempfile
 
     from benchmarks.serve_bench import (
@@ -162,7 +162,7 @@ def run_smoke(seconds: float = 4.0, intake_shards: int = 1,
         n_flows=n_flows, max_batch=4096, serve_buckets=(1024, 4096),
         native=True, n_dispatchers=2, fuse_depth=4,
         intake_shards=intake_shards, mesh_devices=mesh_devices,
-        shm_dir=shm_dir, decide_impl=decide_impl,
+        shm_dir=shm_dir,
     )
     shm_teardown_clean = None
     try:
@@ -208,7 +208,6 @@ def run_smoke(seconds: float = 4.0, intake_shards: int = 1,
             front_door + "+shm" if shm_dir is not None else front_door
         ),
         "transport": transport,
-        "decide_impl": decide_impl,
         "intake_shards": intake_shards,
         "mesh_devices": mesh_devices or None,
         "verdicts_per_sec": closed["verdicts_per_sec"],
@@ -306,13 +305,6 @@ def main() -> int:
                          "complete) plus a forced black-box dump parsing "
                          "back. Skips the rate floor: full sampling is the "
                          "diagnostic mode, not the serving default")
-    ap.add_argument("--decide-impl", choices=("auto", "xla", "pallas"),
-                    default="auto",
-                    help="decide backend behind the served path. 'auto' "
-                         "gates the floor with the Pallas megakernel "
-                         "compiled into the build (the production "
-                         "selector picks per backend); 'pallas' forces "
-                         "it — compiled by Mosaic or an error, TPU only")
     ap.add_argument("--trace-overhead-gate", type=float, default=None,
                     metavar="FRAC",
                     help="with tracing off, gate verdicts/s >= floor x "
@@ -322,7 +314,7 @@ def main() -> int:
 
     doc = run_smoke(seconds=args.seconds, intake_shards=args.intake_shards,
                     mesh_devices=args.mesh_devices, transport=args.transport,
-                    trace=args.trace, decide_impl=args.decide_impl)
+                    trace=args.trace)
     print(json.dumps(doc, indent=2))
 
     if args.trace == "sampled":

@@ -128,7 +128,6 @@ def build_service(lanes, mesh_chips: int):
 
     from sentinel_tpu.cluster.token_service import DefaultTokenService
     from sentinel_tpu.engine import ClusterFlowRule, EngineConfig
-    from sentinel_tpu.engine.decide import explain_decide_impl
     from sentinel_tpu.engine.param import explain_param_impl
     from sentinel_tpu.engine.rules import ThresholdMode
 
@@ -163,15 +162,11 @@ def build_service(lanes, mesh_chips: int):
         f"+ {len(special)} special over {N_NAMESPACES} namespaces, window "
         f"{config.n_buckets}x{config.bucket_ms}ms, ns guard {NS_MAX_QPS}/s")
 
-    impl, why = explain_decide_impl(config.decide_impl)
-    say(f"  decide_impl {config.decide_impl!r} -> {impl}: {why}")
     pcfg = service.param_config
     pimpl, pwhy = explain_param_impl(pcfg.impl, pcfg.sketch)
     say(f"  param impl {pcfg.impl!r} ({pcfg.sketch}) -> {pimpl}: {pwhy}")
-    for bucket, core in service.step_cores().items():
-        say(f"  serve bucket {bucket:>5}: {core} core")
-    say(f"  fused depths {service._fuse_depths}: "
-        f"{service.step_cores()[BATCH]} core under lax.scan")
+    say(f"  serve buckets {list(SERVE_BUCKETS)}, fused depths "
+        f"{service._fuse_depths} under lax.scan")
 
     t0 = time.perf_counter()
     service.warmup()

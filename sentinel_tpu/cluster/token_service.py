@@ -643,20 +643,6 @@ class DefaultTokenService(TokenService):
         self._sharded_steps[key] = step
         return step
 
-    def step_cores(self) -> Dict[int, str]:
-        """Which decide core ("pallas" | "xla") each serve bucket's step is
-        built from — the per-bucket answer ``decide_impl`` resolves to
-        (the megakernel serves only buckets within its VMEM cap). The
-        fused steps run at ``batch_size``, i.e. as the largest bucket."""
-        from sentinel_tpu.engine.decide import decide_core_name
-
-        return {
-            b: decide_core_name(
-                self.config._replace(batch_size=b), grouped=True
-            )
-            for b in self._serve_buckets
-        }
-
     def _fused_step_fn(self, depth: int, uniform: bool):
         """The chained multi-frame device step for one (scan depth, uniform)
         variant — ``lax.scan`` of the donated-state step over ``depth``

@@ -32,16 +32,6 @@ class EngineConfig(NamedTuple):
     # (matmul ≤ 2048, sort above). Grouped host batches bypass this and use
     # the sort-free "grouped" impl (see decide()'s grouped flag).
     prefix_impl: str = "auto"
-    # decision-step backend: "xla" (the `_decide_core` pipeline — one XLA
-    # pass per subsystem), "pallas" (the one-HBM-traversal megakernel in
-    # ops/decide_pallas.py: compiled by Mosaic or it raises — there is no
-    # hand-back), or "auto" (SENTINEL_DECIDE_IMPL env var wins; otherwise
-    # the XLA pipeline on every platform, see
-    # engine.decide.explain_decide_impl for the reason it gives). The
-    # pallas step serves grouped batches of at most
-    # decide_pallas.MAX_BATCH rows; engine.decide.decide_core_name says
-    # which core a given step is built from.
-    decide_impl: str = "auto"
 
     @property
     def interval_ms(self) -> int:

@@ -340,13 +340,10 @@ def _warmup_curve(
 ):
     """WARM_UP lazy token sync + slope curve on gathered ``[N]`` columns.
 
-    Shared verbatim by the XLA pipeline (``_decide_core``'s ``warm_on``
-    branch) and the Pallas megakernel (``ops/decide_pallas.py``) so the two
-    backends stay *bitwise* equal: the op sequence here IS the parity
-    contract. Returns ``(qps, tokens_new, do_sync, cur_sec)``; rows outside
-    ``warm_rows`` come back with ``qps = cnt`` and ``do_sync = False`` (the
-    cond-off values), which is what makes computing this unconditionally in
-    the kernel equivalent to the XLA path's ``lax.cond`` gating.
+    The body of ``_decide_core``'s ``warm_on`` branch. Returns ``(qps,
+    tokens_new, do_sync, cur_sec)``; rows outside ``warm_rows`` come back
+    with ``qps = cnt`` and ``do_sync = False``, the values the caller's
+    ``lax.cond`` gives when no WARM_UP row is in the batch.
     """
     # lazy once-per-second token sync (WarmUpController.syncToken):
     # refill below the warning line (or above it while pass qps stays
@@ -387,8 +384,7 @@ def _occupy_feasible(
     threshold,
 ):
     """The priority-occupy headroom check (``ClusterFlowChecker.canOccupy``)
-    on gathered ``[N]`` columns — shared by both decide backends (see
-    :func:`_warmup_curve` for why)."""
+    on gathered ``[N]`` columns."""
     # admitted_prefix: tokens admitted earlier in THIS batch land in the
     # current bucket, which is still valid at the next window — without
     # this term a borrow could overcommit the window the batch just filled
@@ -400,9 +396,8 @@ def _occupy_feasible(
 
 def _ns_guard(config, spec, ns_state, rules, now, psum, owned, safe_slot, live):
     """Namespace guard (request-count qps, ``GlobalRequestLimiter.java:46``)
-    — computed identically on every device from global inputs. Shared by
-    both decide backends (it is [N]/[NS]-sized prologue math; the Pallas
-    megakernel never touches the tiny replicated namespace window).
+    — computed identically on every device from global inputs
+    ([N]/[NS]-sized prologue math on the tiny replicated namespace window).
 
     Returns ``(ns_id, ns_ok, seg_ns_sum)`` where ``seg_ns_sum`` is the
     per-namespace segment-sum closure reused for the guard-counter update.
@@ -923,73 +918,6 @@ def _decide_core(
     return new_state, verdicts
 
 
-# What "auto" resolves to on a TPU, and why — a stated constant, not a probe:
-# the chip refuses the megakernel (chip run, PR 21: TPU v5 lite, jax 0.9.0,
-# libtpu 0.0.34; benchmarks/kernel_chip_parity.py prints the compiler's words
-# again on every run). ROADMAP Design 2 disposes of the kernel.
-_AUTO_ON_TPU = (
-    "xla",
-    "ops/decide_pallas.py is not offered: Mosaic refuses it "
-    "(NotImplementedError: Unimplemented primitive in Pallas TPU lowering "
-    "for KernelType.TC: dynamic_slice)",
-)
-
-
-def explain_decide_impl(impl: str) -> tuple:
-    """Resolve ``EngineConfig.decide_impl`` to ``(backend, reason)`` with
-    ``backend`` in ("xla" | "pallas") — same selection discipline as
-    ``engine.param.explain_param_impl``.
-
-    An explicit "xla"/"pallas" (config or ``SENTINEL_DECIDE_IMPL``) is taken
-    as given: a forced "pallas" whose kernel Mosaic refuses raises the
-    compiler's error at the first step that builds it — there is no
-    hand-back to XLA. "auto" is the XLA pipeline: off-TPU because Mosaic
-    compiles for the TPU only, on TPU by :data:`_AUTO_ON_TPU`.
-    """
-    if impl in ("xla", "pallas"):
-        return impl, f"decide_impl={impl!r} set explicitly"
-    if impl != "auto":
-        raise ValueError(
-            f"unknown decide impl {impl!r}; use 'auto'|'xla'|'pallas'"
-        )
-    import os
-
-    env = os.environ.get("SENTINEL_DECIDE_IMPL", "").strip().lower()
-    if env in ("xla", "pallas"):
-        return env, f"SENTINEL_DECIDE_IMPL={env}"
-    platform = jax.default_backend()
-    if platform == "tpu":
-        return _AUTO_ON_TPU
-    return "xla", f"platform {platform!r}: Mosaic compiles for TPU only"
-
-
-def resolve_decide_impl(impl: str) -> str:
-    """The backend half of :func:`explain_decide_impl`."""
-    return explain_decide_impl(impl)[0]
-
-
-def decide_core_name(config: EngineConfig, grouped: bool) -> str:
-    """Which core :func:`_core_for` builds for this config: "pallas" or
-    "xla". The megakernel needs the grouped-batch contract (same-flow rows
-    contiguous — its segment-tail read-modify-write scatter is only
-    race-free then) and a batch within its VMEM cap; every other step is
-    the XLA pipeline, whatever ``decide_impl`` says."""
-    if not grouped or resolve_decide_impl(config.decide_impl) != "pallas":
-        return "xla"
-    from sentinel_tpu.ops.decide_pallas import MAX_BATCH
-
-    return "pallas" if config.batch_size <= MAX_BATCH else "xla"
-
-
-def _core_for(config: EngineConfig, grouped: bool):
-    """The decide-core callable :func:`decide_core_name` names."""
-    if decide_core_name(config, grouped) == "pallas":
-        from sentinel_tpu.ops.decide_pallas import decide_core_pallas
-
-        return decide_core_pallas
-    return _decide_core
-
-
 @partial(jax.jit, static_argnames=("config", "grouped", "uniform"))
 def decide(
     config: EngineConfig,
@@ -1006,7 +934,7 @@ def decide(
     :func:`_decide_core`); the host batcher sets them per batch when its
     layout guarantees hold, selecting one of four compiled variants.
     """
-    return _core_for(config, grouped)(
+    return _decide_core(
         config, state, rules, batch, now, axis_name=None,
         grouped=grouped, uniform=uniform,
     )
@@ -1039,11 +967,9 @@ def decide_donating(config: EngineConfig, grouped: bool = False,
     = step(self._state, …)`` the only reader), and warmup-style calls must
     feed throwaway states.
     """
-    core = _core_for(config, grouped)
-
     def step(state, rules, packed):
         batch, now = unpack_requests(packed)
-        state, verdicts = core(
+        state, verdicts = _decide_core(
             config, state, rules, batch, now, axis_name=None,
             grouped=grouped, uniform=uniform,
         )
@@ -1073,13 +999,14 @@ def decide_fused_donating(config: EngineConfig, depth: int,
     per-dispatch host/RTT overhead paid once for the whole chain.
 
     The scanned batch VARIES per iteration, so XLA cannot hoist the
-    request-dependent chains out of the loop body (the failure mode
-    ``benchmarks/step_ablation.py`` documents for loop-constant operands).
+    request-dependent chains out of the loop body (it does hoist them for
+    a loop-constant batch, which is why a scan of one repeated batch
+    under-reports the step).
     """
     if depth < 1:
         raise ValueError(f"fused depth must be >= 1, got {depth}")
     core = partial(
-        _core_for(config, grouped), config, axis_name=None, grouped=grouped,
+        _decide_core, config, axis_name=None, grouped=grouped,
         uniform=uniform,
     )
 

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from sentinel_tpu.engine.config import EngineConfig, named
 from sentinel_tpu.engine.decide import (
     RequestBatch,
-    _core_for,
+    _decide_core,
     pack_verdicts,
     step_name,
     unpack_requests,
@@ -181,14 +181,9 @@ def make_sharded_decide(
             f"max_flows={config.max_flows} must be divisible by mesh size {n}"
         )
 
-    # decide_impl-aware: the Pallas megakernel runs per shard inside the
-    # shard_map body (its psums ride the [N]-sized verdict stitching exactly
-    # like the XLA pipeline's — the kernel itself never sees a collective)
-    core = _core_for(config, grouped)
-
     if depth is None:
         def decide_shard(state, rules, batch, now):
-            return core(
+            return _decide_core(
                 config, state, rules, batch, now, axis_name=axis,
                 grouped=grouped, uniform=uniform,
             )
@@ -198,7 +193,7 @@ def make_sharded_decide(
 
         def decide_shard(state, rules, batches, now):
             def body(st, batch):
-                st, verdicts = core(
+                st, verdicts = _decide_core(
                     config, st, rules, batch, now, axis_name=axis,
                     grouped=grouped, uniform=uniform,
                 )

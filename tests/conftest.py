@@ -59,7 +59,7 @@ def pallas_interpret(monkeypatch):
 
     Nothing in the package derives ``interpret=`` from the backend (a chip
     process compiles the kernel or raises), so a CPU parity test that drives
-    ``decide_impl="pallas"`` / ``ParamConfig(impl="pallas")`` asks for the
+    ``ParamConfig(impl="pallas")`` or ``prefix_impl="pallas"`` asks for the
     interpreter itself, here: every ``pl.pallas_call`` built while the
     fixture is active gets ``interpret=True``. (JAX's own
     ``pltpu.force_tpu_interpret_mode()`` would do, but its TPU simulator

@@ -94,23 +94,6 @@ class TestKernelsCompileOrRaise:
             P.param_decide(cfg, P.make_param_state(cfg),
                            *self._param_args(cfg))
 
-    def test_forced_decide_pallas_raises(self, monkeypatch):
-        from sentinel_tpu.engine import (
-            EngineConfig,
-            build_rule_table,
-            decide,
-            make_batch,
-            make_state,
-        )
-
-        # config or environment, a forced megakernel is never served by XLA
-        monkeypatch.setenv("SENTINEL_DECIDE_IMPL", "pallas")
-        cfg = EngineConfig(max_flows=16, max_namespaces=4, batch_size=24)
-        table, _ = build_rule_table(cfg, [])
-        with pytest.raises(ValueError, match="interpret"):
-            decide(cfg, make_state(cfg), table, make_batch(cfg, [0]),
-                   jnp.int32(1_000), grouped=True)
-
     def test_probe_reports_the_losers_reason(self):
         import logging
 
@@ -163,16 +146,6 @@ class TestKernelsCompileOrRaise:
             "probed salsa 16384 4096"
         )
         assert probed == [("salsa", 2048, 64), ("salsa", 16384, 4096)]
-
-    def test_decide_auto_on_a_tpu_is_a_stated_constant(self, monkeypatch):
-        import importlib
-
-        D = importlib.import_module("sentinel_tpu.engine.decide")
-        monkeypatch.delenv("SENTINEL_DECIDE_IMPL", raising=False)
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        impl, why = D.explain_decide_impl("auto")
-        assert impl == "xla"
-        assert "Mosaic refuses" in why
 
 
 class TestNativeLoader:

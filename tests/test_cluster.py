@@ -592,10 +592,10 @@ class TestIdleReaping:
             reaped = server.connections.sweep_idle(ttl_ms=600_000)
             assert len(reaped) == 1
             assert server.connections.connected_count("default") == 0
-            # client sees EOF and drops its socket
-            deadline = time.time() + 5
-            while client._sock is not None and time.time() < deadline:
-                time.sleep(0.02)
+            # client sees EOF and drops its socket: its reader thread ends
+            # with the connection, so wait for that thread, not for a clock
+            client._reader.join(timeout=30)
+            assert not client._reader.is_alive()
             assert client._sock is None
             client._last_connect_attempt = 0.0  # skip reconnect backoff
             assert client.request_token(1).status is not TokenStatus.FAIL

@@ -409,57 +409,75 @@ class TestPushDocSync:
         assert "#push-plane-wire-rev-7" in _doc_text()
 
 
-class TestMegakernelDocSync:
-    """docs/PERF.md round 16 ↔ code sync: the doc names the megakernel's
-    selection surface, the bytes ledger, the pipelined lane knob, and the
-    north-star acceptance artifact — each of which exists in code."""
+class TestPerfRecordSync:
+    """The root PERF.md ↔ program sync. §3 of the record names, per layer,
+    the always-on histogram or counter each per-layer metric of the ledger is
+    read from, and the program names the device-time metrics filter on. The
+    harness reads them out of ``ServerMetrics.stage_snapshot()`` and out of
+    the trace by those names, so a rename turns a metric ``null`` in the
+    ledger; it fails here first."""
 
     def _text(self):
-        with open(os.path.join(REPO, "docs", "PERF.md")) as f:
+        with open(os.path.join(REPO, "PERF.md")) as f:
             return f.read()
 
-    @pytest.mark.parametrize("needle", [
-        # the kernel and how you pick it
-        "decide_pallas",
-        "decide_impl",
-        "resolve_decide_impl",
-        "SENTINEL_DECIDE_IMPL",
-        # the bytes ledger and its headline reductions
-        "hbm_bytes_model",
-        "1.55×",
-        "1.78×",
-        # the pipelined lane and its proof-of-overlap series
-        "max_device_inflight",
-        "sentinel_server_overlap_saved_ms_total",
-        "sentinel_server_device_inflight",
-        # the acceptance bench, its artifact, and the CI gate
-        "northstar_bench.py",
-        "NORTHSTAR_r01.json",
-        "host_single_core",
-        "northstar-smoke",
-        "--decide-impl auto",
+    @pytest.mark.parametrize("name", [
+        # the eight phases inside one dispatch (PR 24)
+        "permit_wait_ms",
+        "prep_ms",
+        "lock_wait_ms",
+        "launch_ms",
+        "reply_queue_wait_ms",
+        "device_wait_ms",
+        "fetch_ms",
+        "account_ms",
+        # one packed verdict buffer per dispatch (PR 25)
+        "verdict_copy_ready_total",
+        "verdict_host_reads_total",
+        # the param lane (PR 27)
+        "param_values_total",
+        "param_dispatch_total",
+        "param_blocked_total",
+        "param_requests_total",
     ])
-    def test_doc_names_the_surface(self, needle):
-        assert needle in self._text()
+    def test_record_names_what_the_program_snapshots(self, name):
+        from sentinel_tpu.metrics.server import ServerMetrics
 
-    def test_doc_bottleneck_matches_artifact(self):
-        """The bottleneck PERF.md names is the one the committed
-        north-star artifact actually carries."""
-        path = os.path.join(REPO, "benchmarks", "results",
-                            "NORTHSTAR_r01.json")
-        with open(path) as f:
-            doc = json.load(f)
-        assert doc["verdict"]["kind"] == "BOTTLENECK"
-        assert doc["verdict"]["bottleneck"] in self._text()
+        metrics = ServerMetrics()
+        assert f"`{name}`" in self._text()
+        assert name in metrics.stage_snapshot()
+        if name.endswith("_ms"):  # a histogram: the command surface has it too
+            assert name in metrics.snapshot()["stages"]
 
-    def test_doc_reductions_match_model(self):
-        """The 1.55×/1.78× headline reductions come from the audited
-        model, not a stale copy."""
-        from benchmarks.step_ablation import hbm_bytes_model
+    def test_record_program_names_are_what_the_steps_jit_under(self):
+        """``jit_decide*``, ``jit_param_decide*`` and ``jit_outcome_step`` in
+        §3 are ``jit_`` + the names the steps are built under."""
         from sentinel_tpu.engine.config import EngineConfig
+        from sentinel_tpu.engine.decide import (
+            decide_donating,
+            decide_fused_donating,
+            step_name,
+        )
+        from sentinel_tpu.engine.outcome import outcome_step_donating
+        from sentinel_tpu.engine.param import ParamConfig, make_param_step
 
-        cfg = EngineConfig(max_flows=100_000)
-        model = hbm_bytes_model(cfg, 32_768)
-        per = model["per_decision"]
-        assert round(per["bytes_reduction"], 2) == 1.55
-        assert round(per["ops_reduction"], 2) == 1.78
+        text = self._text()
+        for prefix in ("jit_decide", "jit_param_decide", "jit_outcome_step"):
+            assert f"`{prefix}" in text
+        cfg = EngineConfig(max_flows=16, max_namespaces=4, batch_size=64)
+        assert step_name("decide", cfg, True) == "decide_b64_uniform"
+        assert step_name("decide_sharded_fused", cfg, False, 4) == (
+            "decide_sharded_fused_d4_b64_mixed"
+        )
+        programs = [
+            decide_donating(cfg, grouped=True, uniform=True),
+            decide_fused_donating(cfg, 2, grouped=True),
+        ]
+        for step in programs:
+            assert f"jit_{step.__name__}".startswith("jit_decide")
+        pcfg = ParamConfig(max_param_rules=4, width=128)
+        param_step = make_param_step(pcfg, 64, "jax")
+        assert f"jit_{param_step.__name__}" == "jit_param_decide_b64"
+        assert f"jit_{outcome_step_donating(cfg).__name__}" == (
+            "jit_outcome_step"
+        )

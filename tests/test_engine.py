@@ -459,3 +459,19 @@ class TestRuleReload:
         )
         assert index.lookup(101) == -1
         assert old_slot in index._free
+
+
+def test_engine_config_is_geometry_only():
+    """``EngineConfig`` holds sizes and admission constants; the decide step
+    is one function (``_decide_core``) with nothing to select. The one
+    selector left is ``prefix_impl`` (a named debt)."""
+    assert EngineConfig._fields == (
+        "max_flows", "max_namespaces", "batch_size", "bucket_ms", "n_buckets",
+        "max_occupy_ratio", "exceed_count", "admission_refine_iters",
+        "prefix_impl",
+    )
+    selectors = [
+        f for f, v in EngineConfig._field_defaults.items()
+        if isinstance(v, str)
+    ]
+    assert selectors == ["prefix_impl"]

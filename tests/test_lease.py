@@ -9,6 +9,7 @@ admit locally from the cached slice, every refusal falls back to the
 per-request wire path, and close() returns unused tokens early.
 """
 
+import threading
 import time
 
 import numpy as np
@@ -208,18 +209,35 @@ class TestClientLease:
 
     def test_local_admission_amortizes_rpcs(self, served):
         svc, server = served
+        # lease_want=128: the renew-ahead fires when half the slice is spent
+        # (64 tokens) or at 45 % of the 60 s TTL, so the first 40 admissions
+        # reach neither and nothing runs behind the test's back
         c = TokenClient("127.0.0.1", server.port, timeout_ms=3000,
-                        lease=True, lease_want=64)
+                        lease=True, lease_want=128)
         try:
+            assert c.ping()  # connect now: the handshake is an RPC too
+            base = c.lease_stats()["rpcs"]
             for _ in range(40):
                 assert c.request_token(1).ok
             s = c.lease_stats()
-            # one synchronous grant; renew-ahead runs in the background;
-            # everything else never touched the wire
-            assert s["granted"] == 1
-            assert s["local_admits"] >= 39
+            # one synchronous grant; everything else never touched the wire
+            assert s["granted"] == 1 and s["renewed"] == 0
+            assert s["local_admits"] == 40
             assert s["wire_rows"] == 0
-            assert s["rpcs"] <= 5  # handshake + grant + background renews
+            assert s["rpcs"] == base + 1
+            # the 64th token is the renew-ahead point: one background
+            # renew, waited for here, and still no decision on the wire
+            for _ in range(24):
+                assert c.request_token(1).ok
+            for t in threading.enumerate():
+                if t.name == "sentinel-lease-renew":
+                    t.join(timeout=10)
+            assert c.request_token(1).ok  # from the fresh slice
+            s = c.lease_stats()
+            assert s["granted"] == 1 and s["renewed"] == 1
+            assert s["local_admits"] == 65
+            assert s["wire_rows"] == 0
+            assert s["rpcs"] == base + 2
         finally:
             c.close()
 

@@ -675,72 +675,3 @@ class TestHeartbeatBackoff:
             time.sleep(0.01)
         hb.stop()
         assert hb._consecutive_failures == 0
-
-
-@pytest.mark.usefixtures("pallas_interpret")
-class TestMegakernelStateArtifactParity:
-    """Fused-kernel state contract, host-serialization layer: the decide
-    megakernel (``decide_impl="pallas"``) must leave the service's state
-    tensors byte-identical to the XLA pipeline's after the same request
-    stream, so every serialized artifact — snapshot blob, replication
-    delta blob, MOVE namespace doc — is bit-identical across impls. A
-    single diverging bit here would poison standbys and MOVE targets
-    with an impl-dependent state stream."""
-
-    def _twin(self, impl):
-        svc = DefaultTokenService(CFG._replace(decide_impl=impl))
-        svc.load_rules([
-            ClusterFlowRule(flow_id=1, count=7.0, mode=G, namespace="mv"),
-            ClusterFlowRule(flow_id=2, count=3.0, mode=G, namespace="mv"),
-            ClusterFlowRule(flow_id=3, count=1e9, mode=G),
-        ])
-        svc.replication_enable()
-        rng = np.random.default_rng(SEED)
-        for _ in range(6):
-            ids = np.sort(rng.integers(1, 4, size=24)).astype(np.int64)
-            svc.request_batch_arrays(ids)
-        return svc
-
-    def test_snapshot_delta_and_move_blobs_bit_identical(self, manual_clock):
-        svcs = {impl: self._twin(impl) for impl in ("xla", "pallas")}
-        snaps = {
-            impl: R.encode_snapshot_blob(svc.export_state())
-            for impl, svc in svcs.items()
-        }
-        assert snaps["xla"] == snaps["pallas"]
-        deltas = {
-            impl: R.encode_delta_blob(svc.export_delta())
-            for impl, svc in svcs.items()
-        }
-        assert deltas["xla"] == deltas["pallas"]
-        from sentinel_tpu.cluster.rebalance import encode_move_state_blob
-
-        moves = {
-            impl: encode_move_state_blob(svc.export_namespace_state("mv"))
-            for impl, svc in svcs.items()
-        }
-        assert moves["xla"] == moves["pallas"]
-
-    def test_pallas_primary_converges_xla_standby(self, manual_clock):
-        """Cross-impl replication: a megakernel primary's delta stream
-        must land bit-for-bit on an XLA-pipeline standby — mixed-impl
-        pods (e.g. a TPU primary with a CPU warm standby) replicate
-        through the same bytes."""
-        primary = self._twin("pallas")
-        standby = DefaultTokenService(CFG._replace(decide_impl="xla"))
-        standby.import_state(
-            R.decode_snapshot_blob(
-                R.encode_snapshot_blob(primary.export_state())
-            )
-        )
-        rng = np.random.default_rng(SEED + 1)
-        for _ in range(3):
-            ids = np.sort(rng.integers(1, 4, size=16)).astype(np.int64)
-            primary.request_batch_arrays(ids)
-        delta = R.decode_delta_blob(
-            R.encode_delta_blob(primary.export_delta())
-        )
-        standby.apply_replication_delta(delta)
-        p = primary.metrics_snapshot()
-        s = standby.metrics_snapshot()
-        assert p[1]["pass_qps"] == s[1]["pass_qps"] > 0

@@ -442,77 +442,3 @@ class TestMeshBackedService:
         assert svc.request_token(2).status == TokenStatus.OK
         assert svc.request_token(1).status == TokenStatus.OK
         svc.close()
-
-
-@pytest.mark.usefixtures("pallas_interpret")
-class TestMegakernelStateContract:
-    """The fused decide megakernel (``ops/decide_pallas.py``) as a drop-in
-    for the XLA pipeline at the state-management layer: donation must
-    still update the sharded buffers in place (no silent copy-on-alias
-    fallback when the pallas_call sits inside the donated jit), and the
-    sharded step's state must stay bit-identical to the XLA twin's so
-    every downstream consumer of state bytes (snapshots, deltas, MOVE)
-    sees one canonical stream."""
-
-    def _sorted_batch(self, index, rng, n_rules=16):
-        slots = np.sort(
-            np.asarray(
-                [index.lookup(int(f))
-                 for f in rng.integers(0, n_rules, CFG.batch_size)],
-                np.int32,
-            )
-        )
-        return make_batch(CFG, slots)
-
-    def test_sharded_donation_holds_under_pallas_step(self, mesh):
-        cfg = CFG._replace(decide_impl="pallas")
-        rules, table, index = _build(num_rules=16)
-        step = make_sharded_decide(
-            cfg, mesh, grouped=True, uniform=True, donate=True
-        )
-        state = shard_state(make_state(cfg), mesh)
-        table_8 = shard_rules(table, mesh)
-        batch = self._sorted_batch(index, np.random.default_rng(5))
-        new_state, _ = step(state, table_8, pack_batch(batch, 10_000))
-        # the donated input's buffers are gone — the aliased pallas_call
-        # updated them in place instead of forcing a defensive copy
-        assert state.flow.counts.is_deleted()
-        assert state.occupy.counts.is_deleted()
-        assert len(new_state.flow.counts.addressable_shards) == 8
-
-    def test_single_shard_donation_holds_under_pallas_step(self):
-        from sentinel_tpu.engine.decide import decide_donating
-
-        cfg = CFG._replace(decide_impl="pallas")
-        rules, table, index = _build(num_rules=16)
-        step = decide_donating(cfg, grouped=True, uniform=True)
-        state = make_state(cfg)
-        batch = self._sorted_batch(index, np.random.default_rng(6))
-        new_state, _ = step(state, table, pack_batch(batch, 10_000))
-        assert state.flow.counts.is_deleted()
-        assert not new_state.flow.counts.is_deleted()
-
-    def test_sharded_state_bytes_identical_across_impls(self, mesh):
-        """After the same stream, the full sharded EngineState pulled back
-        to host is byte-identical between impls — the property every
-        host-serialized artifact (snapshot blob, replication delta, MOVE
-        doc) inherits for the mesh-backed service."""
-        rules, table, index = _build(num_rules=16, count=6.0)
-        table_8 = shard_rules(table, mesh)
-        rng = np.random.default_rng(7)
-        batches = [
-            self._sorted_batch(index, rng) for _ in range(4)
-        ]
-        finals = {}
-        for impl in ("xla", "pallas"):
-            cfg = CFG._replace(decide_impl=impl)
-            step = make_sharded_decide(cfg, mesh, grouped=True, uniform=True)
-            st = shard_state(make_state(cfg), mesh)
-            for i, b in enumerate(batches):
-                st, _ = step(st, table_8, b, jnp.int32(10_000 + 37 * i))
-            finals[impl] = jax.tree.map(np.asarray, st)
-        for leaf_x, leaf_p in zip(
-            jax.tree.leaves(finals["xla"]), jax.tree.leaves(finals["pallas"])
-        ):
-            assert leaf_x.dtype == leaf_p.dtype
-            np.testing.assert_array_equal(leaf_x, leaf_p)

@@ -577,9 +577,20 @@ def _decide_core(
             rules.mode[safe_slot] == int(ThresholdMode.AVG_LOCAL), conn, 1.0
         )
 
+        # matured borrows: the occupy window is read only while one of its
+        # ring slots lies inside the interval. Without prioritized or paced
+        # traffic none ever does, and the step leaves the window alone (its
+        # [F, B, 1] rows gather through a layout copy of the whole window on
+        # the TPU). starts and clock are replicated: a mesh-uniform predicate
+        matured = jax.lax.cond(
+            jnp.any(W.valid_mask(spec, state.occupy, now)),
+            lambda occ: W.window_sum_at(spec, occ, now, 0, safe_slot),
+            lambda occ: jnp.zeros((N,), occ.counts.dtype),
+            state.occupy,
+        )
         passed = (
             W.window_sum_at(spec, state.flow, now, ClusterEvent.PASS, safe_slot)
-            + W.window_sum_at(spec, state.occupy, now, 0, safe_slot)  # matured borrows
+            + matured
             # wire rev 5: tokens delegated to clients as local-admission leases
             # are pre-paid — charged at grant time — so they occupy the window
             # exactly like passed tokens until they expire or are credited back
@@ -756,7 +767,9 @@ def _decide_core(
             horizon = next_start - spec.interval_ms
             cur_valid = W.valid_mask(spec, state.flow, now)
             expiring_mask = cur_valid & (state.flow.starts <= horizon)
-            pass_rows = state.flow.counts[safe_slot, :, ClusterEvent.PASS]  # [N, B]
+            pass_rows = W.rows_at(state.flow, safe_slot)[
+                :, :, ClusterEvent.PASS
+            ]  # [N, B]
             expiring = jnp.sum(
                 pass_rows * expiring_mask[None, :].astype(pass_rows.dtype), axis=1
             ).astype(jnp.float32)
@@ -776,8 +789,9 @@ def _decide_core(
         hard_block = blocked & ~can_occupy
 
     # ------------------------------------------------------------------
-    # 5. window updates: one scatter per static event channel (the layout
-    #    measured fastest on v5e — see add_event_rows), with the rare
+    # 5. window updates: one scatter per static event channel into the
+    #    current bucket's slab (the form measured fastest on v5e — see
+    #    add_event_rows: no scatter ever sees the whole window), with the rare
     #    OCCUPIED_PASS channel cond-gated. Rows whose masks are false
     #    contribute zeros (scatter targets stay in range, so no drops
     #    needed).
@@ -808,17 +822,17 @@ def _decide_core(
         # path records only the future-window WAITING, which is `occupy_ws`
         # below). Prioritized traffic is rare, so this scatter is cond-gated on
         # the same mesh-uniform predicate as the occupy path.
-        idx_cur, _ = W.bucket_index(spec, now)
-        flow_counts = jax.lax.cond(
+        flow_ws = jax.lax.cond(
             any_prio,
-            lambda c: c.at[safe_slot, idx_cur, int(ev.OCCUPIED_PASS)].add(
-                batch.acquire * (admit & batch.prioritized).astype(jnp.int32),
-                mode="drop",
+            lambda ws: W.add_event_rows(
+                spec, ws, now, safe_slot,
+                (batch.acquire
+                 * (admit & batch.prioritized).astype(jnp.int32))[:, None],
+                channels=(ev.OCCUPIED_PASS,),
             ),
-            lambda c: c,
-            flow_ws.counts,
+            lambda ws: ws,
+            flow_ws,
         )
-        flow_ws = flow_ws._replace(counts=flow_counts)
         # pmax over the mesh axis keeps the replicated occupy.starts identical on
         # every device even when only the owner shard sees a borrow (each shard
         # then also zeroes its own stale counts column for the reset slot).

@@ -110,6 +110,25 @@ def roll(spec: WindowSpec, ws: WindowState, now: jax.Array) -> WindowState:
         return WindowState(starts=starts, counts=counts)
 
 
+def _write_current(spec: WindowSpec, ws: WindowState, now: jax.Array, write):
+    """Roll, then ``write`` the current bucket's ``[R, E]`` slab: the slab is
+    taken out of the ring, handed to ``write`` and put back in place. The
+    scatters of :func:`add_event_rows` go through here, so that none sees
+    the whole window: the TPU's scatter takes a flat operand and costs a pass
+    over it, and on the tiled ``[R, B, E]`` window the compiler would copy
+    the window flat, stream all of it through the scatter and copy it back,
+    every call, whatever the batch (measured on a v5e at 100k rows: 0.46 of
+    a 0.82 ms decide step, PERF.md section 5). On the slab the cost follows
+    the batch and one bucket, not the table times the ring."""
+    ws = roll(spec, ws, now)
+    idx, _ = bucket_index(spec, now)
+    slab = jax.lax.dynamic_index_in_dim(ws.counts, idx, axis=1, keepdims=False)
+    counts = jax.lax.dynamic_update_index_in_dim(
+        ws.counts, write(slab), idx, axis=1
+    )
+    return WindowState(starts=ws.starts, counts=counts)
+
+
 def add_events(
     spec: WindowSpec,
     ws: WindowState,
@@ -146,22 +165,25 @@ def add_event_rows(
     """Scatter-add ``row_updates[i, j]`` ([K, len(channels)]) into channel
     ``channels[j]`` of the current bucket of resource ``resource_ids[i]``.
 
-    One scatter per *static* channel: measured on v5e, a scatter whose only
-    traced index dimension is the resource row costs ~70ns/row, while adding
-    the channel as a second traced index dimension (the 5N-concatenation
-    form) or as a scatter update window is 4–10× slower. This is the
-    decision kernel's write path. Rows intended as no-ops must carry zero
-    updates (or an out-of-range id to drop the row entirely).
+    One scatter per *static* channel, each into the ``[R]`` column of the
+    current bucket's slab (:func:`_write_current`) that it touches, as the
+    flat vector it is: about 9 ns a row on a v5e. Adding the channel as a
+    second traced index dimension (the 5N-concatenation form) or as a
+    scatter update window is 4–10x slower. This is the decision kernel's
+    write path. Rows intended as no-ops must carry zero updates (or an
+    out-of-range id to drop the row entirely).
     """
-    ws = roll(spec, ws, now)
-    idx, _ = bucket_index(spec, now)
-    counts = ws.counts
     chans = range(row_updates.shape[1]) if channels is None else channels
-    for j, ch in enumerate(chans):
-        counts = counts.at[resource_ids, idx, int(ch)].add(
-            row_updates[:, j].astype(counts.dtype), mode="drop"
-        )
-    return WindowState(starts=ws.starts, counts=counts)
+
+    def write(slab):
+        for j, ch in enumerate(chans):
+            column = slab[:, int(ch)].at[resource_ids].add(
+                row_updates[:, j].astype(slab.dtype), mode="drop"
+            )
+            slab = slab.at[:, int(ch)].set(column)
+        return slab
+
+    return _write_current(spec, ws, now, write)
 
 
 def add_column(
@@ -203,6 +225,19 @@ def window_sum(
     )
 
 
+def rows_at(ws: WindowState, ids: jax.Array) -> jax.Array:
+    """``[K, n_buckets, n_channels]``: the whole rows of resources ``ids``.
+
+    Readers pick their channel *after* this gather, on purpose. The TPU
+    keeps a ``[R, B, E]`` window resource-minor and tiled; a gather of
+    ``counts[ids, :, channel]`` wants another tiling and copies the whole
+    window into it first, every call (the entry ``copy`` of ``flow.counts``
+    in a decide step's trace, 74 us at 100k rows), while a gather of whole
+    rows reads the window as it lies. Reads of several channels at the same
+    ``ids`` share the one gather."""
+    return ws.counts[ids]
+
+
 def window_sum_at(
     spec: WindowSpec,
     ws: WindowState,
@@ -216,7 +251,7 @@ def window_sum_at(
     ``[n_resources, n_buckets]`` plane — the read path stays independent of
     the table size (matters at 10^5–10^6 rule slots)."""
     mask = valid_mask(spec, ws, now)
-    rows = ws.counts[ids, :, channel]  # [K, B]
+    rows = rows_at(ws, ids)[:, :, channel]  # [K, B]
     return jnp.sum(rows * mask[None, :].astype(rows.dtype), axis=1)
 
 
@@ -277,7 +312,7 @@ def future_sum_at(
     """``[K]`` future-window sums at resource rows ``ids`` (gather-first
     counterpart of :func:`future_sum`)."""
     mask = future_valid_mask(spec, ws, now)
-    rows = ws.counts[ids, :, channel]
+    rows = rows_at(ws, ids)[:, :, channel]
     return jnp.sum(rows * mask[None, :].astype(rows.dtype), axis=1)
 
 

@@ -43,7 +43,18 @@ from sentinel_tpu.engine import (
     pack_requests_into,
     unpack_verdicts,
 )
-from sentinel_tpu.engine.decide import HEAD_NOW, ROW_HEAD
+from sentinel_tpu.engine.decide import (
+    ARM_LIVE,
+    ARM_OCCUPY,
+    ARM_PACED_ROWS,
+    ARM_PACING,
+    ARM_PRIORITIZED_ROWS,
+    ARM_SHAPED_ROWS,
+    ARM_SHAPING,
+    HEAD_NOW,
+    ROW_HEAD,
+    unpack_arms,
+)
 from sentinel_tpu.engine.param import (
     ParamConfig,
     explain_param_impl,
@@ -1199,6 +1210,7 @@ class DefaultTokenService(TokenService):
             t_mat = time.monotonic_ns()
             t_ready, host = self._read_verdicts(verdicts)
             status, wait, remaining = unpack_verdicts(host, n, order)
+            arms = unpack_arms(host)
             if moved_mask is not None:
                 # MOVED overlay: the device saw these rows as no-rule; the
                 # client sees a redirect carrying the shard-map epoch
@@ -1210,7 +1222,8 @@ class DefaultTokenService(TokenService):
                     int(moved_mask.sum())
                 )
             self._account(
-                status, wait, slots_ns, seq, n, t_enter, t_mat, t_ready
+                status, wait, slots_ns, seq, n, t_enter, t_mat, t_ready,
+                arms=arms,
             )
             return status, remaining, wait
 
@@ -1246,14 +1259,15 @@ class DefaultTokenService(TokenService):
 
     def _account(
         self, status, wait, slots_ns, seq, rows, t_enter, t_mat, t_ready,
-        lane: int = 0,
+        lane: int = 0, arms=None,
     ) -> None:
         """The accounting tail of a materializer, and its three phases.
         ``slots_ns`` is request-order and PRE-mask, so MOVED verdicts land
         on their namespace (a fused span passes its frames' slots as a
         list, a param dispatch None); ``t_enter``/``t_mat``/``t_ready`` are the
         ``monotonic_ns`` stamps of the dispatch's entry, the materializer's
-        entry and the verdict buffer reaching the host."""
+        entry and the verdict buffer reaching the host. ``arms`` is what a
+        flow dispatch's step said of its cond-gated arms (``unpack_arms``)."""
         t_fetched = time.monotonic_ns()
         if isinstance(slots_ns, list):
             slots_ns = np.concatenate(slots_ns)
@@ -1272,11 +1286,20 @@ class DefaultTokenService(TokenService):
             latency_ms=(time.monotonic_ns() - t_enter) * 1e-6,
             wait_ms=wait,
         )
+        live = 0
+        if arms is not None:
+            live = int(arms[ARM_LIVE])
+            _SM.count_decide_arms(
+                rows, live & ARM_SHAPING, live & ARM_PACING,
+                live & ARM_OCCUPY, int(arms[ARM_SHAPED_ROWS]),
+                int(arms[ARM_PACED_ROWS]), int(arms[ARM_PRIORITIZED_ROWS]),
+            )
         if _TR.ARMED:  # flight recorder: verdicts on the host and counted
             sid, aux = self._trace_sid, seq & 0x7FFFFFFF
             _TR.record(_TR.READY, shard=sid, aux=aux, t_ns=t_ready)
             _TR.record(_TR.FETCHED, shard=sid, aux=aux, t_ns=t_fetched)
-            _TR.record(_TR.DEVICE_OUT, shard=lane, aux=rows)
+            _TR.record(_TR.DEVICE_OUT, aux=rows,
+                       shard=lane | live << _TR.ARM_SHIFT)
         # cluster server stat log (ClusterServerStatLogUtil analog): one
         # aggregated counter per verdict class per window
         n_degraded = 0
@@ -1466,6 +1489,7 @@ class DefaultTokenService(TokenService):
             status, wait, remaining = unpack_verdicts(
                 host, order=span_order
             )
+            arms = unpack_arms(host, depth)
             if moved_span is not None:
                 status[moved_span] = np.int8(int(TokenStatus.MOVED))
                 remaining[moved_span] = moved_epochs_span[moved_span]
@@ -1480,7 +1504,7 @@ class DefaultTokenService(TokenService):
             self._account(
                 status, wait,
                 span_ns if span_ns is not None else [p[0] for p in preps],
-                seq, total, t_enter, t_mat, t_ready,
+                seq, total, t_enter, t_mat, t_ready, arms=arms,
             )
             return status, remaining, wait
 
@@ -2436,9 +2460,11 @@ class DefaultTokenService(TokenService):
         imported counts are all attributed to *now*, so they expire at most
         one window later than they would have at the source — never
         earlier, which is what zero-over-admission needs."""
-        idx = int((now // spec.bucket_ms) % spec.n_buckets)
-        aligned = int(now - now % spec.bucket_ms)
         starts = np.asarray(ws.starts)
+        # the ring is as long as the window was made (the occupy window's
+        # is twice the flow window's: engine.state.occupy_ring)
+        idx = int((now // spec.bucket_ms) % starts.shape[0])
+        aligned = int(now - now % spec.bucket_ms)
         counts = ws.counts
         if int(starts[idx]) != aligned:
             counts = counts.at[:, idx].set(0)

@@ -108,12 +108,47 @@ class VerdictBatch(NamedTuple):
     remaining: jax.Array  # int32 [N]
 
 
-def pack_verdicts(verdicts: VerdictBatch) -> jax.Array:
+# What a step says of its cond-gated arms, as :func:`_decide_core_arms` hands it
+# out: ``int32[ARM_FIELDS]``. ``ARM_LIVE`` holds one bit per arm whose
+# ``lax.cond`` took its live branch; the rest count the rows the arms had
+# before them.
+ARM_LIVE, ARM_SHAPED_ROWS, ARM_PACED_ROWS, ARM_PRIORITIZED_ROWS = 0, 1, 2, 3
+ARM_FIELDS = 4
+ARM_SHAPING, ARM_PACING, ARM_OCCUPY = 1, 2, 4  # bits of an ARM_LIVE entry
+ARM_ALL = ARM_SHAPING | ARM_PACING | ARM_OCCUPY
+# a status is at most 12: the arm fields ride above it in the status line's
+# first entries (no frame is shorter than the smallest serve bucket)
+_ARM_SHIFT = 8
+
+
+def pack_verdicts(verdicts: VerdictBatch,
+                  arms: Optional[jax.Array] = None) -> jax.Array:
     """What a serve step hands its caller: the three verdict leaves as ONE
     ``int32[3, ...]`` array (rows in :class:`VerdictBatch` field order,
     status widened, values unchanged), so a dispatch's verdicts cross to the
-    host in one copy instead of three. Traced inside the jitted step."""
-    return jnp.stack([leaf.astype(jnp.int32) for leaf in verdicts])
+    host in one copy instead of three. Traced inside the jitted step.
+
+    ``arms`` (``int32[..., ARM_FIELDS]``, one per frame) rides in the same
+    array, in the bits above the status of each frame's first
+    ``ARM_FIELDS`` entries: no line and no copy of its own.
+    :func:`unpack_verdicts` drops those bits, :func:`unpack_arms` reads
+    them."""
+    lines = jnp.stack([leaf.astype(jnp.int32) for leaf in verdicts])
+    if arms is not None:
+        lines = lines.at[0, ..., :ARM_FIELDS].add(arms << _ARM_SHIFT)
+    return lines
+
+
+def unpack_arms(packed, frames: int = 1) -> np.ndarray:
+    """Host side of :func:`pack_verdicts`'s ``arms``: the ``int64[ARM_FIELDS]``
+    of one dispatch from its packed verdict buffer on the host (``int32[3,
+    ...]``, ``frames`` frames laid end to end). A fused span's live bits are
+    OR-ed and its row counts summed over its frames."""
+    per_frame = np.asarray(packed)[0].reshape(frames, -1)[
+        :, :ARM_FIELDS].astype(np.int64) >> _ARM_SHIFT
+    out = per_frame.sum(axis=0)
+    out[ARM_LIVE] = np.bitwise_or.reduce(per_frame[:, ARM_LIVE])
+    return out
 
 
 def unpack_verdicts(packed, n: Optional[int] = None,
@@ -137,6 +172,7 @@ def unpack_verdicts(packed, n: Optional[int] = None,
         for dst, src in zip(out, rows):  # half the time of one 2-D scatter
             dst[..., order] = src
     status, wait_ms, remaining = out
+    # the cast drops what rides above the status (pack_verdicts' ``arms``)
     return VerdictBatch(
         status=status.astype(np.int8), wait_ms=wait_ms, remaining=remaining
     )
@@ -468,7 +504,16 @@ def _ns_guard(config, spec, ns_state, rules, now, psum, owned, safe_slot, live):
     return ns_id, ns_ok, seg_ns_sum
 
 
-def _decide_core(
+def _decide_core(config, state, rules, batch, now, **kw) -> tuple:
+    """:func:`_decide_core_arms` without its ``arms``: ``(state', verdicts)``,
+    what the library entries, the benches and the tests take."""
+    state, verdicts, _arms = _decide_core_arms(
+        config, state, rules, batch, now, **kw
+    )
+    return state, verdicts
+
+
+def _decide_core_arms(
     config: EngineConfig,
     state: EngineState,
     rules: RuleTable,
@@ -500,6 +545,12 @@ def _decide_core(
       the closed form ``admit = rank < floor((threshold - passed)/acquire)``
       — ONE prefix pass, exact (the iterative refinement is only needed for
       mixed acquire sizes, where greedy admission is not associative).
+
+    Returns ``(state', verdicts, arms)``; ``arms`` (``int32[ARM_FIELDS]``) is
+    what the step's cond-gated arms did: which of the ``shaping``, ``pacing``
+    and ``occupy`` conds took its live branch, and the shaped, paced and
+    prioritized rows of the batch. The serve steps hand it out inside their
+    packed verdicts (:func:`pack_verdicts`); :func:`_decide_core` drops it.
     """
     spec = flow_spec(config)
     now = jnp.asarray(now, jnp.int32)
@@ -612,8 +663,14 @@ def _decide_core(
         warm_rows = active & is_warm
         pace_try = active & is_pace
         active_window = active & ~is_pace
-        any_warm = jnp.any(psum(warm_rows.astype(jnp.int32)) > 0)
-        any_pace = jnp.any(psum(pace_try.astype(jnp.int32)) > 0)
+        # the owner's 0/1 per row, stitched: the same two collectives the
+        # predicates always took, summed as well for the step's `arms`
+        warm_seen = psum(warm_rows.astype(jnp.int32))
+        pace_seen = psum(pace_try.astype(jnp.int32))
+        any_warm = jnp.any(warm_seen > 0)
+        n_paced = jnp.sum(pace_seen)
+        any_pace = n_paced > 0
+        n_shaped = jnp.sum(((warm_seen + pace_seen) > 0).astype(jnp.int32))
 
         cnt = rules.count[safe_slot]
         cnt_safe = jnp.maximum(cnt, 1e-6)
@@ -755,7 +812,8 @@ def _decide_core(
     with jax.named_scope("occupy"):
         blocked = active_window & ~admit
         wait_next = spec.bucket_ms - (now % spec.bucket_ms)
-        any_prio = jnp.any(batch.prioritized & batch.valid)
+        n_prio = jnp.sum((batch.prioritized & batch.valid).astype(jnp.int32))
+        any_prio = n_prio > 0
         # occupy borrowing stays a DEFAULT-behavior feature: a shaped rule's
         # admission curve is the whole point, and the reference's shapers have
         # no occupy interplay either
@@ -929,7 +987,12 @@ def _decide_core(
         breaker=breaker_ws,
     )
     verdicts = VerdictBatch(status=status, wait_ms=wait_ms, remaining=remaining)
-    return new_state, verdicts
+    # the three cond predicates above and the rows behind them: ARM_* order
+    arms = jnp.stack([
+        any_warm * ARM_SHAPING + any_pace * ARM_PACING + any_prio * ARM_OCCUPY,
+        n_shaped, n_paced, n_prio,
+    ]).astype(jnp.int32)
+    return new_state, verdicts, arms
 
 
 @partial(jax.jit, static_argnames=("config", "grouped", "uniform"))
@@ -983,11 +1046,11 @@ def decide_donating(config: EngineConfig, grouped: bool = False,
     """
     def step(state, rules, packed):
         batch, now = unpack_requests(packed)
-        state, verdicts = _decide_core(
+        state, verdicts, arms = _decide_core_arms(
             config, state, rules, batch, now, axis_name=None,
             grouped=grouped, uniform=uniform,
         )
-        return state, pack_verdicts(verdicts)
+        return state, pack_verdicts(verdicts, arms)
 
     return jax.jit(
         named(step, step_name("decide", config, uniform)),
@@ -1020,7 +1083,7 @@ def decide_fused_donating(config: EngineConfig, depth: int,
     if depth < 1:
         raise ValueError(f"fused depth must be >= 1, got {depth}")
     core = partial(
-        _decide_core, config, axis_name=None, grouped=grouped,
+        _decide_core_arms, config, axis_name=None, grouped=grouped,
         uniform=uniform,
     )
 
@@ -1028,11 +1091,13 @@ def decide_fused_donating(config: EngineConfig, depth: int,
         batches, now = unpack_requests(packed)
 
         def body(st, batch):
-            st, verdicts = core(st, rules, batch, now)
-            return st, verdicts
+            st, verdicts, arms = core(st, rules, batch, now)
+            return st, (verdicts, arms)
 
-        state, verdicts = jax.lax.scan(body, state, batches, length=depth)
-        return state, pack_verdicts(verdicts)
+        state, (verdicts, arms) = jax.lax.scan(
+            body, state, batches, length=depth
+        )
+        return state, pack_verdicts(verdicts, arms)
 
     return jax.jit(
         named(fused, step_name("decide_fused", config, uniform, depth)),

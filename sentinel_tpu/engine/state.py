@@ -129,7 +129,7 @@ class BreakerState(NamedTuple):
 
 class EngineState(NamedTuple):
     flow: WindowState  # [F, B, E] current windows
-    occupy: WindowState  # [F, B, 1] future (borrowed) windows
+    occupy: WindowState  # [F, 2B, 1] future (borrowed) windows: occupy_ring
     ns: WindowState  # [NS, B, 1] namespace request qps guard
     shaping: ShapingState  # [F] per-flow shaper clocks
     outcome: WindowState  # [F, B, N_OUTCOME_CHANNELS] completion outcomes
@@ -138,6 +138,22 @@ class EngineState(NamedTuple):
 
 def flow_spec(config: EngineConfig) -> WindowSpec:
     return WindowSpec(bucket_ms=config.bucket_ms, n_buckets=config.n_buckets)
+
+
+def occupy_ring(config: EngineConfig) -> WindowSpec:
+    """The ring the occupy window is made with: twice the flow window's.
+
+    The occupy window is written up to ``n_buckets - 1`` buckets ahead
+    (``add_future``: priority borrows, paced waits) and read back for a whole
+    interval once a bucket has matured, so ``2 * n_buckets - 1`` of its
+    buckets are live at a time. In a ring of ``n_buckets`` slots a booking
+    ``k`` buckets ahead reset the matured bucket ``n_buckets - k`` behind,
+    for every flow: booked tokens stopped counting up to ``n_buckets - 1``
+    buckets early and their flow admitted that much over its count (PR 31).
+    Reads keep :func:`flow_spec` (validity is by age); only the slot a start
+    maps to follows the ring's length."""
+    return WindowSpec(bucket_ms=config.bucket_ms,
+                      n_buckets=2 * config.n_buckets)
 
 
 def make_shaping(n_flows: int) -> ShapingState:
@@ -160,7 +176,7 @@ def make_state(config: EngineConfig) -> EngineState:
     spec = flow_spec(config)
     return EngineState(
         flow=make_window(spec, config.max_flows, N_CLUSTER_EVENTS),
-        occupy=make_window(spec, config.max_flows, 1),
+        occupy=make_window(occupy_ring(config), config.max_flows, 1),
         ns=make_window(spec, config.max_namespaces, 1),
         shaping=make_shaping(config.max_flows),
         outcome=make_window(spec, config.max_flows, N_OUTCOME_CHANNELS),

@@ -147,6 +147,39 @@ class ServerMetrics:
             "answered without touching the sketch (cumulative).",
     }
 
+    # what the decide step says of its cond-gated arms, per flow dispatch
+    # (engine.decide.ARM_*): the step hands the predicates and row counts out
+    # inside its packed verdicts, the service counts them here
+    _ARM_COUNTERS = {
+        "decide_dispatch_total":
+            "Flow dispatches accounted: single decide steps and fused "
+            "spans, one each (cumulative).",
+        "decide_rows_total":
+            "Rows those flow dispatches decided (cumulative).",
+        "decide_shaping_live_total":
+            "Flow dispatches whose step took the live branch of its "
+            "shaping cond: a WARM_UP or WARM_UP_RATE_LIMITER row was in "
+            "the batch (cumulative).",
+        "decide_pacing_live_total":
+            "Flow dispatches whose step took the live branch of its pacing "
+            "cond: a RATE_LIMITER or WARM_UP_RATE_LIMITER row was in the "
+            "batch (cumulative).",
+        "decide_occupy_live_total":
+            "Flow dispatches whose step took the live branch of its occupy "
+            "cond: a prioritized row was in the batch (cumulative).",
+        "decide_all_arms_live_total":
+            "Flow dispatches whose step took all three live branches "
+            "(cumulative).",
+        "decide_shaped_rows_total":
+            "Rows on a rule with a control behaviour other than DEFAULT "
+            "that reached the shaping or pacing arm (cumulative).",
+        "decide_paced_rows_total":
+            "Rows on a RATE_LIMITER or WARM_UP_RATE_LIMITER rule that "
+            "reached the pacing arm (cumulative).",
+        "decide_prioritized_rows_total":
+            "Prioritized rows in flow dispatches (cumulative).",
+    }
+
     def __init__(self):
         for name, _help in self._PHASES:
             setattr(self, name, LatencyHistogram(lo=0.001, hi=100_000.0))
@@ -163,6 +196,8 @@ class ServerMetrics:
         # resolved to for the serving geometry, with the reason
         self._param_lock = threading.Lock()
         self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
+        self._arm_lock = threading.Lock()
+        self._arms = dict.fromkeys(self._ARM_COUNTERS, 0)
         self._param_impl = ("", "")
         # stage histograms, all in milliseconds except batch_size (requests).
         # 1µs..10s covers a sub-100µs device step and a 1s cold compile alike.
@@ -306,6 +341,28 @@ class ServerMetrics:
             p["param_values_total"] += int(values)
             p["param_blocked_total"] += int(blocked)
             p["param_no_rule_total"] += int(no_rule)
+
+    def count_decide_arms(self, rows: int, shaping: bool, pacing: bool,
+                          occupy: bool, shaped: int, paced: int,
+                          prioritized: int) -> None:
+        """One flow dispatch was accounted: which of its step's arms ran,
+        and the rows those arms had before them."""
+        with self._arm_lock:
+            a = self._arms
+            a["decide_dispatch_total"] += 1
+            a["decide_rows_total"] += rows
+            a["decide_shaping_live_total"] += bool(shaping)
+            a["decide_pacing_live_total"] += bool(pacing)
+            a["decide_occupy_live_total"] += bool(occupy)
+            a["decide_all_arms_live_total"] += bool(
+                shaping and pacing and occupy)
+            a["decide_shaped_rows_total"] += shaped
+            a["decide_paced_rows_total"] += paced
+            a["decide_prioritized_rows_total"] += prioritized
+
+    def arm_totals(self) -> Dict[str, int]:
+        with self._arm_lock:
+            return dict(self._arms)
 
     def param_totals(self) -> Dict[str, int]:
         with self._param_lock:
@@ -864,6 +921,7 @@ class ServerMetrics:
         out["verdict_host_reads_total"] = self.verdict_host_reads_total
         out["verdict_copy_ready_total"] = self.verdict_copy_ready_total
         out.update(self.param_totals())
+        out.update(self.arm_totals())
         out["param_impl"], out["param_impl_reason"] = self.param_impl
         out["shed_total"] = self.shed_totals()
         out["host_copy_bytes_total"] = self.host_copy_bytes_total
@@ -1254,6 +1312,8 @@ class ServerMetrics:
              "(cumulative).", self.verdict_copy_ready_total),
             *((name, self._PARAM_COUNTERS[name], value)
               for name, value in self.param_totals().items()),
+            *((name, self._ARM_COUNTERS[name], value)
+              for name, value in self.arm_totals().items()),
         ):
             lines.append(f"# HELP sentinel_server_{name} {help_text}")
             lines.append(f"# TYPE sentinel_server_{name} counter")
@@ -1306,6 +1366,8 @@ class ServerMetrics:
             self._verdict_copy_ready = 0
         with self._param_lock:
             self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
+        with self._arm_lock:
+            self._arms = dict.fromkeys(self._ARM_COUNTERS, 0)
         with self._verdict_lock:
             self._verdicts.clear()
             self._wait_assigned = 0

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from sentinel_tpu.engine.config import EngineConfig, named
 from sentinel_tpu.engine.decide import (
     RequestBatch,
-    _decide_core,
+    _decide_core_arms,
     pack_verdicts,
     step_name,
     unpack_requests,
@@ -183,33 +183,37 @@ def make_sharded_decide(
 
     if depth is None:
         def decide_shard(state, rules, batch, now):
-            return _decide_core(
+            state, verdicts, arms = _decide_core_arms(
                 config, state, rules, batch, now, axis_name=axis,
                 grouped=grouped, uniform=uniform,
             )
+            return state, (verdicts, arms)
     else:
         if depth < 2:
             raise ValueError(f"fused depth must be >= 2, got {depth}")
 
         def decide_shard(state, rules, batches, now):
             def body(st, batch):
-                st, verdicts = _decide_core(
+                st, verdicts, arms = _decide_core_arms(
                     config, st, rules, batch, now, axis_name=axis,
                     grouped=grouped, uniform=uniform,
                 )
-                return st, verdicts
+                return st, (verdicts, arms)
 
             return jax.lax.scan(body, state, batches, length=depth)
 
     if donate:
         def step(state, rules, packed):
             batch, now = unpack_requests(packed)
-            state, verdicts = decide_shard(state, rules, batch, now)
-            return state, pack_verdicts(verdicts)
+            state, (verdicts, arms) = decide_shard(state, rules, batch, now)
+            return state, pack_verdicts(verdicts, arms)
 
         request_specs = (P(),)
     else:
-        step = decide_shard
+        def step(state, rules, batch, now):
+            state, (verdicts, _arms) = decide_shard(state, rules, batch, now)
+            return state, verdicts
+
         request_specs = (_batch_specs(), P())
 
     # two spec shapes, matching the two RuleTable pytree structures: with

@@ -333,12 +333,17 @@ def add_future(
     request may target a different future slot, so the roll (stale-slot zeroing)
     is computed for the union of targeted slots first, then one scatter-add.
 
-    A ring of ``B`` slots can hold the current window plus at most ``B - 1``
-    future windows, so the target window offset is clamped to
-    ``[1, B-1]`` buckets ahead — a row can never collide with the current
-    bucket's slot or wrap the ring. Rows with ``wait_ms <= 0`` or
-    ``valid=False`` are fully masked (they contribute neither counts nor slot
-    resets).
+    The target window offset is clamped to ``[1, B-1]`` buckets ahead (``B``
+    = ``spec.n_buckets``), so a row never lands on the current bucket. The
+    ring is as long as ``ws`` was made, which may be longer than ``B``: a
+    window that is also read back as *matured* (:func:`window_sum_at` over
+    the ``B`` buckets behind ``now``) holds ``B`` live buckets behind and
+    ``B - 1`` ahead, and in a ring of ``B`` slots the bucket ``k`` ahead
+    shares its slot with the live bucket ``B - k`` behind, which the reset
+    below would zero for every resource. ``engine.state.make_state`` gives
+    the occupy window ``2 B`` slots for that reason. Rows with ``wait_ms <=
+    0`` or ``valid=False`` are fully masked (they contribute neither counts
+    nor slot resets).
     """
     now = jnp.asarray(now, jnp.int32)
     wait_ms = jnp.asarray(wait_ms, jnp.int32)
@@ -352,7 +357,7 @@ def add_future(
     k = (future_time - cur_start) // spec.bucket_ms
     k = jnp.clip(k, 1, spec.n_buckets - 1)
     start = cur_start + k * spec.bucket_ms
-    idx = (start // spec.bucket_ms) % spec.n_buckets
+    idx = (start // spec.bucket_ms) % ws.starts.shape[0]
     # Masked rows must not drive the slot-reset union below.
     start = jnp.where(row_ok, start, NEVER)
 

@@ -31,7 +31,9 @@ _LOCK = threading.Lock()
 _DIR: Optional[str] = os.environ.get("SENTINEL_BLACKBOX_DIR") or None
 _WINDOW_S: float = 30.0
 _MIN_INTERVAL_S: float = 5.0
-_last_dump: float = 0.0
+# time.monotonic() starts near zero on a machine just booted: -inf, not 0.0,
+# or the first dump is refused for its first _MIN_INTERVAL_S of uptime
+_last_dump: float = float("-inf")
 dumps_written: int = 0
 last_path: Optional[str] = None
 
@@ -142,6 +144,6 @@ def reset_for_tests() -> None:
     _DIR = os.environ.get("SENTINEL_BLACKBOX_DIR") or None
     _WINDOW_S = 30.0
     _MIN_INTERVAL_S = 5.0
-    _last_dump = 0.0
+    _last_dump = float("-inf")
     dumps_written = 0
     last_path = None

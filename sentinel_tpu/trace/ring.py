@@ -43,7 +43,8 @@ DEVICE_IN = 4    # device step submitted, service lock released (aggregate,
 #                  xid=0; aux = rows; shard = PARAM_LANE for a hot-parameter
 #                  dispatch, whose rows are its (request, value) rows)
 DEVICE_OUT = 5   # verdicts on the host AND counted (record_verdict_batch done;
-#                  the stat-log passes follow) (aggregate, xid=0; aux = rows)
+#                  the stat-log passes follow) (aggregate, xid=0; aux = rows;
+#                  shard = lane | the step's live arm bits << ARM_SHIFT)
 REPLY_OUT = 6    # frame's reply encoded + submitted to its door (aux = rows)
 SHED = 7         # frame/rows refused (aux = shed-reason index)
 FUSE = 8         # fusion ladder stacked frames (aggregate; aux = depth)
@@ -56,6 +57,11 @@ BROWNOUT = 14    # admission ladder escalated (aux = level)
 SHM_POLL = 15    # shm ring door poll/doorbell activity (aux = frames)
 OUTCOME = 16     # batched completion report ingested (aux = rows accepted)
 PARAM_LANE = 1   # ``shard`` of the DEVICE_IN / DEVICE_OUT of a param dispatch
+# DEVICE_OUT of a flow dispatch: ``shard >> ARM_SHIFT`` holds the bits of the
+# decide step's cond-gated arms that took their live branch
+# (``engine.decide.ARM_SHAPING | ARM_PACING | ARM_OCCUPY``), ``shard &
+# (1 << ARM_SHIFT) - 1`` the lane
+ARM_SHIFT = 4
 # Phase boundaries inside one dispatch (aggregate, xid=0). Each marks the END
 # of a phase; ``shard`` carries the service's id and ``aux`` its dispatch
 # sequence number (taken under the service lock), so one dispatch's

@@ -439,6 +439,11 @@ class TestPerfRecordSync:
         "param_dispatch_total",
         "param_blocked_total",
         "param_requests_total",
+        # the decide step's arms (PR 31)
+        "decide_dispatch_total",
+        "decide_rows_total",
+        "decide_all_arms_live_total",
+        "decide_shaped_rows_total",
     ])
     def test_record_names_what_the_program_snapshots(self, name):
         from sentinel_tpu.metrics.server import ServerMetrics

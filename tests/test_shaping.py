@@ -75,8 +75,9 @@ class ScalarRef:
 
         self.flow_starts = np.full(Bk, NEVER, np.int64)
         self.flow_counts = np.zeros((F, Bk, 6), np.int64)
-        self.occ_starts = np.full(Bk, NEVER, np.int64)
-        self.occ_counts = np.zeros((F, Bk, 1), np.int64)
+        # the occupy ring is twice the flow window's: engine.state.occupy_ring
+        self.occ_starts = np.full(2 * Bk, NEVER, np.int64)
+        self.occ_counts = np.zeros((F, 2 * Bk, 1), np.int64)
         self.ns_starts = np.full(Bk, NEVER, np.int64)
         self.ns_counts = np.zeros((config.max_namespaces, Bk, 1), f32)
 
@@ -322,7 +323,7 @@ class ScalarRef:
                 k = (now + charge_wait[i] - cur_start) // spec.bucket_ms
                 k = min(max(int(k), 1), spec.n_buckets - 1)
                 start = cur_start + k * spec.bucket_ms
-                oi = (start // spec.bucket_ms) % spec.n_buckets
+                oi = (start // spec.bucket_ms) % len(self.occ_starts)
                 if self.occ_starts[oi] != start:
                     self.occ_counts[:, oi, :] = 0
                     self.occ_starts[oi] = start

@@ -313,7 +313,8 @@ def test_snapshot_restore_round_trip(clock):
     _drive(a, clock, 2)
     doc = a.export_state()
     assert doc["flow"]["counts"].shape == (32, 10, N_CLUSTER_EVENTS)
-    assert doc["occupy"]["counts"].shape == (32, 10, 1)
+    # the occupy ring is twice the flow window's (engine.state.occupy_ring)
+    assert doc["occupy"]["counts"].shape == (32, 20, 1)
     np.testing.assert_array_equal(
         doc["flow"]["counts"], np.asarray(a._state.flow.counts))
     b = _service(clock)
@@ -335,7 +336,7 @@ def test_replication_delta_round_trip(clock):
     _drive(a, clock, 4)
     delta = a.export_delta()
     assert delta["flow_counts"].shape[1:] == (10, N_CLUSTER_EVENTS)
-    assert delta["occupy_counts"].shape[1:] == (10, 1)
+    assert delta["occupy_counts"].shape[1:] == (20, 1)
     b.apply_replication_delta(delta)
     np.testing.assert_array_equal(
         np.asarray(b._state.flow.counts), np.asarray(a._state.flow.counts))

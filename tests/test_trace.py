@@ -219,6 +219,17 @@ class TestBlackbox:
         assert blackbox.dumps_written == 1
         assert blackbox.last_path == first
 
+    def test_first_dump_on_a_machine_just_booted(self, tmp_path, monkeypatch):
+        # time.monotonic() counts from boot on Linux: under min_interval_s
+        # of uptime a last-dump stamp of 0.0 refused the first dump
+        import time as _time
+
+        monkeypatch.setattr(_time, "monotonic", lambda: 12.5)
+        blackbox.configure(str(tmp_path), min_interval_s=3600.0)
+        assert blackbox.maybe_dump("brownout:shed_low") is not None
+        assert blackbox.maybe_dump("brownout:degrade") is None
+        assert blackbox.dumps_written == 1
+
     def test_config_fingerprint_tracks_config(self):
         from sentinel_tpu.core.config import SentinelConfig
 

@@ -12,13 +12,18 @@ size, then the step alone with its shaping arms live against dead.
    with rows on unmetered flows of the traffic namespaces to one full
    16384-row dispatch: the size a backlog gives the step in the cell.
 3. The serve step of the mix's usual bucket (1024) chained on the donated
-   state, on the cell's own rows: once with the configuration's rules and
-   priority flags (every arm live), once with the same rows unprioritized
-   on the same table loaded without shaping (every arm dead). Milliseconds a
-   step, and what each step said of its arms.
+   state, on the cell's own rows, under the profiler: with the
+   configuration's rules and priority flags (every arm live); with the same
+   rows unprioritized on the table with its warm-up taken out (the cell's
+   ``unshaped`` control: the paced rules still run ``pacing`` and
+   ``add_future``); and unprioritized with every rule DEFAULT (every arm
+   dead: cell 1's step). Wall and device milliseconds a step beside the
+   parent's, the top device ops, and what each step said of its arms.
 
 Exits 2 without a TPU, 1 on a mismatch. Its times are of the step alone, one
-thread, nothing else on the host: not a cell's. ``--cpu`` runs it on the CPU
+thread, nothing else on the host: not a cell's. The device time is the one
+a cell's ``step.decide_device_ms_per_dispatch`` reads; the wall clock of a
+chained step is never under one thread's jitted call (about 0.65 ms). ``--cpu`` runs it on the CPU
 backend at the tiny test configuration, to prove the script.
 """
 
@@ -134,18 +139,71 @@ def full_frames(dep, tr, seed: int) -> int:
     return sum(bad for _name, bad in p.checks) + (len(p.checks) != 9)
 
 
+# The parent's tree (PR 31) through this same part, TPU v5 lite (my chip run,
+# PR 32): variant -> (wall ms a step, device ms a step). Printed beside what
+# this tree reads, so a run says at once what the step has gained or lost.
+PARENT_STEP_MS = {
+    "arms live": (0.761, 0.745),
+    "warm-up out": (0.671, 0.563),
+    "arms dead": (0.700, 0.362),
+}
+
+
+def _all_default(service):
+    """Every rule loaded again as DEFAULT: no WARM_UP, no paced rule, so with
+    unprioritized rows no cond-gated arm of the step runs. This is cell 1's
+    step. (``family.unshaped``, the cell's control, only takes the warm-up
+    out: its RATE_LIMITER rules keep ``pacing`` and ``add_future`` live.)"""
+    from dataclasses import replace
+
+    service.load_rules([replace(r, control_behavior=0)
+                        for r in service.current_rules()])
+
+
+def _device_ms(profile_dir: str, program: str):
+    """``(device ms a run of ``program``, [[op, us a run]] top 8)`` from the
+    profiler's trace; ``(None, [])`` where it holds no TPU plane."""
+    from cellbench import trace as T
+
+    try:
+        planes = T.Trace(T.find_xplane(profile_dir)).devices
+    except (FileNotFoundError, ValueError):
+        planes = {}
+    for plane in planes.values():
+        names, start, dur = plane["modules"]
+        mine = [i for i, n in enumerate(names) if str(n).startswith(program)]
+        if not mine:
+            continue
+        lo, hi = start[mine].min(), (start[mine] + dur[mine]).max()
+        ops = T.top_by_time(*plane["ops"], lo, hi, 8)
+        return (float(dur[mine].sum()) / len(mine) / 1e6,
+                [[op, sec * 1e6 / len(mine)] for op, sec in ops])
+    return None, []
+
+
 def step_alone(dep, tr, seed: int, bucket: int, steps: int = 200) -> None:
+    import shutil
+    import tempfile
+
     import jax
     import numpy as np
 
     from sentinel_tpu.engine.decide import (HEAD_NOW, ROW_HEAD, pack_requests,
                                             unpack_arms)
 
+    def fmt(ms):
+        return "not measured" if ms is None else f"{ms:.3f}"
+
     ids, acq, prio = (c.reshape(-1, bucket) for c in dep.family.Mix(
         tr, dep, seed, 1).frames(steps * bucket // int(tr["frame_rows"])))
-    for label, unshaped in (("arms live", False), ("arms dead", True)):
-        service = build(dep, unshaped)
+    read = {}
+    for label in PARENT_STEP_MS:
+        live = label == "arms live"
+        service = build(dep, unshaped=(label == "warm-up out"))
+        profile = tempfile.mkdtemp(prefix="shaped_step_")
         try:
+            if label == "arms dead":
+                _all_default(service)
             cfg = service.config._replace(batch_size=bucket)
             step = service._step_fn(bucket, False)
             state, table = service._state, service._table
@@ -156,24 +214,49 @@ def step_alone(dep, tr, seed: int, bucket: int, steps: int = 200) -> None:
                 order = np.argsort(slots, kind="stable")
                 packed.append(pack_requests(
                     cfg, slots[order], acq[k][order],
-                    None if unshaped else prio[k][order]))
-            for rounds in range(2):  # the first compiles
+                    prio[k][order] if live else None))
+            for rounds in range(2):  # the first compiles, the second is traced
+                if rounds:
+                    jax.profiler.start_trace(profile)
                 t0 = time.perf_counter()
+                said = []
                 for k, rows in enumerate(packed):
                     rows[ROW_HEAD, HEAD_NOW] = 1_000 + 3 * k
                     state, verdicts = step(state, table, rows)
                     if rounds:
                         verdicts.copy_to_host_async()
+                        said.append(verdicts)
                 jax.block_until_ready(verdicts)
                 took = time.perf_counter() - t0
-            arms = unpack_arms(np.asarray(verdicts))
-            print(f"step b{bucket} {label}: {took / len(packed) * 1e3:.3f} "
-                  f"ms/step over {len(packed)} chained steps of the mix's "
-                  f"rows; the last said live bits {arms[0]}, shaped "
-                  f"{arms[1]}, paced {arms[2]}, prioritized {arms[3]} rows",
-                  flush=True)
+            jax.profiler.stop_trace()
+            arms = np.stack([unpack_arms(np.asarray(v)) for v in said])
+            wall = took / len(packed) * 1e3
+            device, ops = _device_ms(profile, f"jit_decide_b{bucket}")
+            read[label] = (wall, device)
+            was = PARENT_STEP_MS[label]
+            print(f"step b{bucket} {label}: wall {fmt(wall)} ms/step, device "
+                  f"{fmt(device)} ms/step (parent {fmt(was[0])} / "
+                  f"{fmt(was[1])}) over {len(packed)} chained steps of the "
+                  f"mix's rows; {100 * (arms[:, 0] > 0).mean():.0f} % of them "
+                  f"said an arm ran, shaped / paced / prioritized rows a "
+                  f"step {arms[:, 1].mean():.0f} / {arms[:, 2].mean():.0f} / "
+                  f"{arms[:, 3].mean():.0f}", flush=True)
+            if ops:
+                print("  top device ops, us a step: " + ", ".join(
+                    f"{op} {us:.1f}" for op, us in ops), flush=True)
         finally:
             service.close()
+            shutil.rmtree(profile, ignore_errors=True)
+    # PR 31 read 0.673 ms for "arms dead" here against 0.366 in cell 1. Both
+    # of ISSUE 32's guesses hold, from this run's own figures:
+    out, dead = read["warm-up out"], read["arms dead"]
+    print(f"0.673 against 0.366: PR 31's dead arms were not dead (its "
+          f"control takes only the warm-up out; the paced rules keep pacing "
+          f"and add_future live: device {fmt(out[1])} ms/step), and a "
+          f"chained step pays what a served one does not (one thread's "
+          f"jitted call: wall {fmt(dead[0])} ms/step with every rule DEFAULT, "
+          f"where the device works {fmt(dead[1])}, which is cell 1's step)",
+          flush=True)
 
 
 def main() -> None:

@@ -628,15 +628,22 @@ def _decide_core_arms(
             rules.mode[safe_slot] == int(ThresholdMode.AVG_LOCAL), conn, 1.0
         )
 
-        # matured borrows: the occupy window is read only while one of its
-        # ring slots lies inside the interval. Without prioritized or paced
-        # traffic none ever does, and the step leaves the window alone (its
-        # [F, B, 1] rows gather through a layout copy of the whole window on
-        # the TPU). starts and clock are replicated: a mesh-uniform predicate
-        matured = jax.lax.cond(
-            jnp.any(W.valid_mask(spec, state.occupy, now)),
-            lambda occ: W.window_sum_at(spec, occ, now, 0, safe_slot),
-            lambda occ: jnp.zeros((N,), occ.counts.dtype),
+        # the occupy window's rows at the batch's slots, fetched once for
+        # both of their readers: `matured` (borrows whose bucket has arrived
+        # count as passed, here) and `waiting` (tokens still booked ahead,
+        # read by the occupy check below). Only while the window can matter:
+        # a ring slot behind `now` inside the interval, or one ahead of it
+        # with a prioritized row to ask about it. Without prioritized or
+        # paced traffic no slot is ever live and the step leaves the window
+        # alone. starts, clock and batch are replicated: a mesh-uniform
+        # predicate
+        n_prio = jnp.sum((batch.prioritized & batch.valid).astype(jnp.int32))
+        any_prio = n_prio > 0
+        matured, waiting = jax.lax.cond(
+            jnp.any(W.valid_mask(spec, state.occupy, now))
+            | (any_prio & jnp.any(W.future_valid_mask(spec, state.occupy, now))),
+            lambda occ: W.past_and_future_sums_at(spec, occ, now, 0, safe_slot),
+            lambda occ: (jnp.zeros((N,), occ.counts.dtype),) * 2,
             state.occupy,
         )
         passed = (
@@ -812,8 +819,6 @@ def _decide_core_arms(
     with jax.named_scope("occupy"):
         blocked = active_window & ~admit
         wait_next = spec.bucket_ms - (now % spec.bucket_ms)
-        n_prio = jnp.sum((batch.prioritized & batch.valid).astype(jnp.int32))
-        any_prio = n_prio > 0
         # occupy borrowing stays a DEFAULT-behavior feature: a shaped rule's
         # admission curve is the whole point, and the reference's shapers have
         # no occupy interplay either
@@ -831,14 +836,11 @@ def _decide_core_arms(
             expiring = jnp.sum(
                 pass_rows * expiring_mask[None, :].astype(pass_rows.dtype), axis=1
             ).astype(jnp.float32)
-            waiting = W.future_sum_at(spec, state.occupy, now, 0, safe_slot).astype(
-                jnp.float32
-            )
             occ_contrib = jnp.where(try_occupy, acquire_f, 0.0)
             occ_prefix = flow_prefix(occ_contrib)  # conservative: all triers count
             return _occupy_feasible(
-                config, try_occupy, passed, expiring, admitted_prefix, waiting,
-                occ_prefix, acquire_f, threshold,
+                config, try_occupy, passed, expiring, admitted_prefix,
+                waiting.astype(jnp.float32), occ_prefix, acquire_f, threshold,
             )
 
         can_occupy = jax.lax.cond(
@@ -908,7 +910,7 @@ def _decide_core_arms(
                 spec, occ, now,
                 wait_ms=charge_wait,
                 resource_ids=safe_slot,
-                channel_ids=jnp.zeros((N,), jnp.int32),
+                channel=0,
                 values=batch.acquire,
                 valid=charge_valid,
                 combine_desired=pmax,

@@ -234,7 +234,16 @@ def rows_at(ws: WindowState, ids: jax.Array) -> jax.Array:
     window into it first, every call (the entry ``copy`` of ``flow.counts``
     in a decide step's trace, 74 us at 100k rows), while a gather of whole
     rows reads the window as it lies. Reads of several channels at the same
-    ``ids`` share the one gather."""
+    ``ids`` share the one gather.
+
+    A window of one channel (the occupy window, ``[R, 2B, 1]``) lies
+    ``T(1,128)``, and no gather the v5e compiler has reads that as it lies:
+    whole rows go through one copy of the window into row tiles (41 us at
+    100k rows, the gather itself 2 us), a gather per bucket column through
+    ``2B`` retiled columns and ``2B`` gathers (193 us), a dense masked sum
+    with two vector gathers 68 us (measured alone, PERF.md section 6,
+    PR 32). So it is read by whole rows too, and once a step for all of
+    its readers (:func:`past_and_future_sums_at`)."""
     return ws.counts[ids]
 
 
@@ -311,9 +320,28 @@ def future_sum_at(
 ) -> jax.Array:
     """``[K]`` future-window sums at resource rows ``ids`` (gather-first
     counterpart of :func:`future_sum`)."""
-    mask = future_valid_mask(spec, ws, now)
+    return past_and_future_sums_at(spec, ws, now, channel, ids)[1]
+
+
+def past_and_future_sums_at(
+    spec: WindowSpec,
+    ws: WindowState,
+    now: jax.Array,
+    channel: int,
+    ids: jax.Array,
+) -> Tuple[jax.Array, jax.Array]:
+    """``([K], [K])``: :func:`window_sum_at` and :func:`future_sum_at` of one
+    channel at resource rows ``ids``, from one fetch of the rows: the two
+    are masks (the buckets behind ``now``, the buckets ahead of it) over the
+    same ``[K, n_buckets]`` cells. The decide step reads the occupy window
+    through here, once."""
     rows = rows_at(ws, ids)[:, :, channel]
-    return jnp.sum(rows * mask[None, :].astype(rows.dtype), axis=1)
+    behind = valid_mask(spec, ws, now).astype(rows.dtype)
+    ahead = future_valid_mask(spec, ws, now).astype(rows.dtype)
+    return (
+        jnp.sum(rows * behind[None, :], axis=1),
+        jnp.sum(rows * ahead[None, :], axis=1),
+    )
 
 
 def add_future(
@@ -322,7 +350,7 @@ def add_future(
     now: jax.Array,
     wait_ms: jax.Array,
     resource_ids: jax.Array,
-    channel_ids: jax.Array,
+    channel: int,
     values: jax.Array,
     valid: Optional[jax.Array] = None,
     combine_desired=None,
@@ -330,8 +358,18 @@ def add_future(
     """Scatter-add into the bucket ``wait_ms`` ahead of ``now`` (per request).
 
     reference: ``OccupiableBucketLeapArray.addWaiting(futureTime, n)``. Each
-    request may target a different future slot, so the roll (stale-slot zeroing)
-    is computed for the union of targeted slots first, then one scatter-add.
+    request may target a different future slot, so the roll (which targeted
+    slots are stale) is computed for the union of targeted slots first. One
+    scatter-add then sorts the batch's values by target into a fresh sheet
+    of deltas, ``B - 1`` lines of ``[R]`` (a scatter costs by the row, about
+    9 ns on a v5e, so one serves all targets), and the counts are written
+    one target bucket at a time, as :func:`_write_current` writes the
+    current one: the slot's ``[R, E]`` slab is taken out of the ring, zeroed
+    if its start is stale, its ``[R]`` column of the static ``channel``
+    takes the target's line of deltas, and the slab is put back in place. A
+    target no row aims at is skipped, so neither a scatter nor a reset ever
+    takes the window as its operand (PERF.md section 5: the whole-window
+    form was the top device op of a step whose shaping arms are live).
 
     The target window offset is clamped to ``[1, B-1]`` buckets ahead (``B``
     = ``spec.n_buckets``), so a row never lands on the current bucket. The
@@ -350,39 +388,52 @@ def add_future(
     row_ok = wait_ms > 0
     if valid is not None:
         row_ok = row_ok & valid
-    values = jnp.where(row_ok, values, 0)
+    values = jnp.where(row_ok, values, 0).astype(ws.counts.dtype)
 
     _, cur_start = bucket_index(spec, now)
-    future_time = now + wait_ms
-    k = (future_time - cur_start) // spec.bucket_ms
-    k = jnp.clip(k, 1, spec.n_buckets - 1)
-    start = cur_start + k * spec.bucket_ms
-    idx = (start // spec.bucket_ms) % ws.starts.shape[0]
-    # Masked rows must not drive the slot-reset union below.
-    start = jnp.where(row_ok, start, NEVER)
+    k = (now + wait_ms - cur_start) // spec.bucket_ms
+    # masked rows aim at no target: they must not drive the reset union below
+    k = jnp.where(row_ok, jnp.clip(k, 1, spec.n_buckets - 1), 0)
+    ahead = jnp.arange(1, spec.n_buckets, dtype=jnp.int32)  # the targets
+    tgt_start = cur_start + ahead * spec.bucket_ms
+    tgt_slot = (tgt_start // spec.bucket_ms) % ws.starts.shape[0]
+    aimed = jnp.any(k[:, None] == ahead[None, :], axis=0)
 
-    # Zero any targeted slot whose recorded start differs from the target start.
-    # (Duplicate valid targets agree on `start`: after clamping, slot index k
-    # uniquely determines the start within one ring period.)
+    # The start each targeted slot must hold (targets map to distinct slots:
+    # there are fewer of them than slots in the ring).
     # `combine_desired` (e.g. a pmax over a mesh axis) lets sharded callers
     # agree on the reset union so the replicated `starts` vector cannot
     # diverge across devices when only the owner shard sees a borrow.
-    desired = jnp.full_like(ws.starts, NEVER).at[idx].max(start, mode="drop")
+    desired = jnp.full_like(ws.starts, NEVER).at[tgt_slot].set(
+        jnp.where(aimed, tgt_start, NEVER)
+    )
     if combine_desired is not None:
         desired = combine_desired(desired)
     needs_reset = (desired != NEVER) & (desired != ws.starts)
-    # A reset only happens the first time a future bucket is targeted (once
-    # per bucket_ms at most); lax.cond skips the full-tensor rewrite on the
-    # hot no-reset path.
-    keep = (~needs_reset).astype(ws.counts.dtype)
-    counts = jax.lax.cond(
-        jnp.any(needs_reset),
-        lambda c: c * keep[None, :, None],
-        lambda c: c,
-        ws.counts,
-    )
     starts = jnp.where(needs_reset, desired, ws.starts)
-    counts = counts.at[resource_ids, idx, channel_ids].add(
-        values.astype(counts.dtype), mode="drop"
-    )
+
+    # every row's value into the line of its target; masked rows and ids past
+    # the end go past the sheet's end and are dropped (a negative id counts
+    # from the end, as `.at[]` has it)
+    n_res = ws.counts.shape[0]
+    res = jnp.where(resource_ids < 0, resource_ids + n_res, resource_ids)
+    lands = (k > 0) & (res >= 0) & (res < n_res)
+    sheet = jnp.zeros(((spec.n_buckets - 1) * n_res,), ws.counts.dtype)
+    sheet = sheet.at[
+        jnp.where(lands, (k - 1) * n_res + res, sheet.shape[0])
+    ].add(values, mode="drop")
+
+    counts = ws.counts
+    for j in range(1, spec.n_buckets):
+        slot = tgt_slot[j - 1]
+
+        def write(c, slot=slot, j=j):
+            slab = jax.lax.dynamic_index_in_dim(c, slot, axis=1, keepdims=False)
+            slab = jnp.where(needs_reset[slot], jnp.zeros_like(slab), slab)
+            slab = slab.at[:, int(channel)].add(sheet[(j - 1) * n_res : j * n_res])
+            return jax.lax.dynamic_update_index_in_dim(c, slab, slot, axis=1)
+
+        # a slot some shard aims at is written on every shard (its reset is
+        # every shard's); one nobody aims at is left alone
+        counts = jax.lax.cond(desired[slot] != NEVER, write, lambda c: c, counts)
     return WindowState(starts=starts, counts=counts)

@@ -271,6 +271,134 @@ def test_compiled_step_converts_no_window_layout(compiled_for_v5e, program):
     assert report["window_layout_copies"] == [], report
 
 
+@pytest.mark.parametrize("scope", ["threshold", "occupy", "commit"])
+@pytest.mark.parametrize(
+    "program", ["jit_decide_b1024_mixed", "jit_decide_b4096_uniform"])
+def test_compiled_cond_branches_move_no_window(compiled_for_v5e, program, scope):
+    """The arms that touch the occupy window sit in ``cond`` branches, which
+    the entry computation does not show: no scatter there takes a window as
+    its operand (``add_future`` writes a target bucket's column), nothing
+    rewrites a whole window (its reset zeroes that column), and no window
+    is copied into another layout, but for the one fetch of the occupy
+    window's rows in ``threshold`` that ``matured`` and ``waiting`` share:
+    the stored ``[F, 2B, 1]`` window lies ``T(1,128)`` and every gather the
+    v5e compiler has for it retiles what it reads (PERF.md section 6,
+    PR 32)."""
+    from benchmarks.decide_hlo_check import violations
+
+    report = compiled_for_v5e[program]
+    assert [v for v in violations(report)
+            if v.startswith(scope + ":")] == [], report
+    if scope == "threshold":  # the copy that is allowed is the occupy window's
+        assert all("s32[100000,20,1]" in c
+                   for c in report["branch_layout_copies"]), report
+
+
+_HLO = """HloModule jit_step, is_scheduled=true
+
+%fused_scatter (p0: s32[4000], p1: s32[8], p2: s32[8]) -> s32[4000] {
+  %p0 = s32[4000]{0:T(1024)} parameter(0)
+  %p1 = s32[8]{0:T(128)} parameter(1)
+  %p2 = s32[8]{0:T(128)} parameter(2)
+  ROOT %scatter.1 = s32[4000]{0:T(1024)} scatter(%p0, %p1, %p2), update_window_dims={}, to_apply=%add
+}
+
+%fused_reset (q0: s32[200,20,1], q1: s32[20]) -> s32[200,20,1] {
+  %q0 = s32[200,20,1]{0,2,1:T(1,128)} parameter(0)
+  %q1 = s32[20]{0:T(128)} parameter(1)
+  %b = s32[200,20,1]{0,2,1:T(1,128)} broadcast(%q1), dimensions={1}
+  ROOT %mul = s32[200,20,1]{0,2,1:T(1,128)} multiply(%q0, %b)
+}
+
+%fused_dus (r0: s32[200,20,1], r1: s32[200]) -> s32[200,20,1] {
+  %r0 = s32[200,20,1]{0,2,1:T(1,128)} parameter(0)
+  %r1 = s32[200]{0:T(1024)} parameter(1)
+  %rs = s32[200,1,1]{0,2,1:T(1,128)} reshape(%r1)
+  ROOT %dus = s32[200,20,1]{0,2,1:T(1,128)} dynamic-update-slice(%r0, %rs, %c, %c, %c)
+}
+
+%inner_taken (t: (s32[200,20,1])) -> (s32[200,20,1]) {
+  %t = (s32[200,20,1]{0,2,1:T(1,128)}) parameter(0)
+  %w = s32[200,20,1]{0,2,1:T(1,128)} get-tuple-element(%t), index=0
+  %reset = s32[200,20,1]{0,2,1:T(1,128)} fusion(%w, %k), kind=kLoop, calls=%fused_reset
+  ROOT %out = (s32[200,20,1]{0,2,1:T(1,128)}) tuple(%reset)
+}
+
+%inner_skipped (u: (s32[200,20,1])) -> (s32[200,20,1]) {
+  ROOT %u = (s32[200,20,1]{0,2,1:T(1,128)}) parameter(0)
+}
+
+%commit_taken (a: (s32[200,20,1], s32[200])) -> (s32[200,20,1]) {
+  %a = (s32[200,20,1]{0,2,1:T(1,128)}, s32[200]{0:T(1024)}) parameter(0)
+  %win = s32[200,20,1]{0,2,1:T(1,128)} get-tuple-element(%a), index=0
+  %col = s32[200]{0:T(1024)} get-tuple-element(%a), index=1
+  %flat = s32[20,200]{1,0:T(8,128)S(1)} fusion(%win), kind=kLoop, calls=%fused_relayout
+  %line = s32[4000]{0:T(1024)} reshape(%flat)
+  %scattered = s32[4000]{0:T(1024)} fusion(%line, %i, %v), kind=kCustom, calls=%fused_scatter
+  %inplace = s32[200,20,1]{0,2,1:T(1,128)} fusion(%win, %col), kind=kLoop, calls=%fused_dus
+  %nested = (s32[200,20,1]{0,2,1:T(1,128)}) conditional(%p, %x, %y), branch_computations={%inner_skipped, %inner_taken}
+  ROOT %r = (s32[200,20,1]{0,2,1:T(1,128)}) tuple(%inplace)
+}
+
+%commit_skipped (b: (s32[200,20,1], s32[200])) -> (s32[200,20,1]) {
+  %b = (s32[200,20,1]{0,2,1:T(1,128)}, s32[200]{0:T(1024)}) parameter(0)
+  %same = s32[200,20,1]{0,2,1:T(1,128)} get-tuple-element(%b), index=0
+  ROOT %r2 = (s32[200,20,1]{0,2,1:T(1,128)}) tuple(%same)
+}
+
+%read_taken (c: (s32[200,20,1])) -> (s32[8]) {
+  %c = (s32[200,20,1]{0,2,1:T(1,128)S(1)}) parameter(0)
+  %got = s32[200,20,1]{0,2,1:T(1,128)S(1)} get-tuple-element(%c), index=0
+  %copy.9 = s32[200,20,1]{1,0,2:T(8,128)S(1)} copy(%got)
+  ROOT %r3 = (s32[8]{0:T(128)}) tuple(%z)
+}
+
+%read_skipped (d: (s32[200,20,1])) -> (s32[8]) {
+  %d = (s32[200,20,1]{0,2,1:T(1,128)S(1)}) parameter(0)
+  ROOT %r4 = (s32[8]{0:T(128)}) tuple(%z)
+}
+
+ENTRY %main (occ: s32[200,20,1]) -> (s32[200,20,1]) {
+  %occ = s32[200,20,1]{0,2,1:T(1,128)} parameter(0)
+  %copy-start.1 = (s32[200,20,1]{0,2,1:T(1,128)S(1)}, s32[200,20,1]{0,2,1:T(1,128)}, u32[]{:S(2)}) copy-start(%occ)
+  %copy-done.1 = s32[200,20,1]{0,2,1:T(1,128)S(1)} copy-done(%copy-start.1)
+  %cond.1 = (s32[8]{0:T(128)}) conditional(%p, %t0, %t1), branch_computations={%read_skipped, %read_taken}, metadata={op_name="jit(step)/threshold/cond" stack_frame_id=1}
+  %cond.2 = (s32[200,20,1]{0,2,1:T(1,128)}) conditional(%q, %t2, %t3), true_computation=%commit_taken, false_computation=%commit_skipped, metadata={op_name="jit(step)/commit/cond" stack_frame_id=2}
+  ROOT %res = (s32[200,20,1]{0,2,1:T(1,128)}) tuple(%w2)
+}
+"""
+
+
+def test_entry_report_walks_the_branches_of_the_conds():
+    """``entry_report`` on a hand-written program: the entry's prefetch is a
+    memory move; the ``threshold`` cond's branch copies the window into
+    another tiling; the ``commit`` cond's branch relayouts it in a fusion,
+    scatters into all of it, and a cond nested in it multiplies all of it;
+    the in-place ``dynamic-update-slice`` of one column is none of these."""
+    from benchmarks.decide_hlo_check import entry_report, violations
+
+    report = entry_report(_HLO, window_cells=4000)
+    assert report["entry_while"] == 0
+    assert report["window_layout_copies"] == []
+    assert [m.split(" = ")[0] for m in report["window_memory_moves"]] == [
+        "copy-start.1"]
+    names = {key: sorted(x.split(" = ")[0] for x in report[key])
+             for key in ("branch_layout_copies", "branch_window_scatters",
+                         "branch_window_passes")}
+    assert names == {
+        "branch_layout_copies": ["commit: flat", "threshold: copy.9"],
+        "branch_window_scatters": ["commit: scatter.1"],
+        "branch_window_passes": ["commit: reset", "commit: scattered"],
+    }
+    assert sorted(v.split(" = ")[0] for v in violations(report)) == [
+        "commit: flat", "commit: reset", "commit: scatter.1",
+        "commit: scattered"]  # threshold's one copy is the allowed one
+    # a smaller window than the program's: nothing is window-sized
+    quiet = entry_report(_HLO, window_cells=4001)
+    assert not any(quiet[key] for key in quiet if key != "entry_while")
+    assert violations(quiet) == []
+
+
 # -- the service's row paths on the flat serve state --------------------------
 
 def _service(clock, **kw):

@@ -827,6 +827,10 @@ def run_scenario(cfg: ScenarioConfig) -> dict:
         if lease_driver is not None:
             lease_driver.finish()
         chaos.disarm()
+        # the objective was this run's: the plane that measured it holds
+        # its copy, and whoever shares the process next reads the default
+        with SentinelConfig._lock:
+            SentinelConfig._props.pop(KEY_OBJECTIVE_MS, None)
 
     wall_s = round(time.time() - started_ms / 1000.0, 3)
     tl.flush()

@@ -38,6 +38,7 @@ PLAIN_COUNT = 1e6
 # needs a namespace whose window holds nothing but its own frames
 GUARD_NAMESPACES = ("ns60", "ns61", "ns62", "ns63")
 UNKNOWN_FLOW = 9_999_999
+BREAKER_RECOVERY_MS = 1000  # short, so that probe, recover and reopen fit
 SPECIAL_BASE = 1_000_000  # special flow ids start here, clear of the plain ones
 
 OK, BLOCKED, SHOULD_WAIT, NO_RULE, TOO_MANY, DEGRADED = 0, 1, 2, 3, 4, 12
@@ -113,7 +114,8 @@ class Lane:
 
         return [DegradeRule(
             self.breaker, DegradeStrategy.ERROR_COUNT, threshold=5,
-            min_request_amount=5, namespace="ns0",
+            min_request_amount=5, recovery_timeout_ms=BREAKER_RECOVERY_MS,
+            namespace="ns0",
         )]
 
     def param_rules(self):
@@ -342,23 +344,60 @@ def check_door(label, server, service, traffic, lane) -> None:
 
         # breaker: CLOSED passes; 8 reported exceptions (> 5, with at
         # least 5 completions) open it at the next request, which is shed
-        # with the rule's recovery timeout as retry-after
-        expect("breaker flow while CLOSED",
-               client.request_token(lane.breaker).status, TokenStatus.OK)
-        done = service.outcome_stats()["reported"] + 8
-        for _ in range(8):
-            client.record_outcome(lane.breaker, 5, exception=True)
-        if not client.flush_outcomes():
-            raise RuntimeError(f"{label}: outcome report not sent")
-        deadline = time.monotonic() + 10
-        while service.outcome_stats()["reported"] < done:
-            if time.monotonic() > deadline:
-                raise RuntimeError(f"{label}: outcome report never ingested")
-            time.sleep(0.02)
-        res = client.request_token(lane.breaker)
+        # with the rule's recovery timeout as retry-after; past the timeout
+        # one request passes as the probe and the next is shed; a good
+        # completion closes; tripped again, a bad one on the probe opens it
+        # for the whole timeout once more (ROADMAP Reach A1's ground)
+        def report(outcomes) -> None:
+            """Completions of the breaker flow, sent and seen ingested."""
+            done = ingested() + len(outcomes)
+            for rt_ms, failed in outcomes:
+                client.record_outcome(lane.breaker, rt_ms, exception=failed)
+            if not client.flush_outcomes():
+                raise RuntimeError(f"{label}: outcome report not sent")
+            deadline = time.monotonic() + 10
+            while ingested() < done:
+                if time.monotonic() > deadline:
+                    raise RuntimeError(
+                        f"{label}: outcome report never ingested")
+                time.sleep(0.005)
+
+        def ask():
+            return client.request_token(lane.breaker)
+
+        expect("breaker flow while CLOSED", ask().status, TokenStatus.OK)
+        t_report = time.perf_counter()
+        report([(5, True)] * 8)
+        say(f"  8 exceptions reported and ingested in "
+            f"{(time.perf_counter() - t_report) * 1e3:.1f} ms")
+        res = ask()
+        t_open = time.monotonic()
         expect("breaker flow after 8 exceptions", res.status,
                TokenStatus.DEGRADED)
-        expect("retry-after is the recovery timeout", res.remaining, 5000)
+        expect("retry-after is the recovery timeout", res.remaining,
+               BREAKER_RECOVERY_MS)
+        time.sleep(BREAKER_RECOVERY_MS / 1000 + 0.05)
+        expect("past the timeout one request passes as the probe",
+               ask().status, TokenStatus.OK)
+        expect("and the next is shed until a completion resolves it",
+               ask().status, TokenStatus.DEGRADED)
+        report([(5, False)])
+        expect("a good completion closes the breaker", ask().status,
+               TokenStatus.OK)
+        time.sleep(0.12)  # past the fence: the close hides its own bucket
+        report([(5, True)] * 8)
+        expect("tripped again", ask().status, TokenStatus.DEGRADED)
+        time.sleep(BREAKER_RECOVERY_MS / 1000 + 0.05)
+        expect("the second probe passes", ask().status, TokenStatus.OK)
+        report([(5, True)])
+        res = ask()
+        expect("a bad completion opens it again", res.status,
+               TokenStatus.DEGRADED)
+        expect("for most of the timeout still",
+               BREAKER_RECOVERY_MS * 0.6 < res.remaining
+               <= BREAKER_RECOVERY_MS, True)
+        say(f"  breaker lane took {time.monotonic() - t_open:.2f} s "
+            f"from its first trip")
     finally:
         client.close()
         server.stop()
@@ -368,6 +407,12 @@ def service_metrics():
     from sentinel_tpu.metrics.server import server_metrics
 
     return server_metrics()
+
+
+def ingested() -> int:
+    """Completion rows the outcome steps have scattered so far (a host
+    counter: ``outcome_stats()`` reads the whole window from the device)."""
+    return service_metrics().arm_totals()["outcome_step_rows_total"]
 
 
 def check_fused_inprocess(service, traffic, lane) -> None:

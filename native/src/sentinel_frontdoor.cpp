@@ -144,6 +144,7 @@ struct Control {
   int32_t fd;
   uint32_t gen;
   std::string payload;  // frame bytes (kind 0) or peer address (kind 1)
+  int64_t t_ns = 0;     // CLOCK_MONOTONIC when a frame (kind 0) was queued
 };
 
 struct Frontdoor {
@@ -201,6 +202,12 @@ struct Frontdoor {
     param.hashes.resize(hash_cap);
   }
 };
+
+int64_t mono_ns() {
+  timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return int64_t(ts.tv_sec) * 1000000000LL + ts.tv_nsec;
+}
 
 int64_t mono_ms() {
   timespec ts;
@@ -322,7 +329,8 @@ bool parse_frames(Frontdoor *s, Conn &c) {
         }
         s->controls.push_back(
             {0, c.fd, c.gen,
-             std::string(reinterpret_cast<const char *>(payload), flen)});
+             std::string(reinterpret_cast<const char *>(payload), flen),
+             mono_ns()});
         notify = true;
       }
       c.rpos += 2 + flen;
@@ -869,10 +877,13 @@ SN_EXPORT void sn_fd_send(void *h, int32_t fd, int32_t gen,
 }
 
 // Pop one control event. Returns its kind (0 frame, 1 open, 2 close) or -1
-// if none. payload_out receives up to max_len bytes; *len_out the true size.
+// if none. payload_out receives up to max_len bytes; *len_out the true size;
+// *t_ns_out the CLOCK_MONOTONIC ns (Python's time.monotonic_ns()) at which
+// the IO thread queued a frame, 0 for an open or close event.
 SN_EXPORT int32_t sn_fd_next_control(void *h, int32_t *fd_out,
                                      int32_t *gen_out, uint8_t *payload_out,
-                                     int32_t max_len, int32_t *len_out) {
+                                     int32_t max_len, int32_t *len_out,
+                                     int64_t *t_ns_out) {
   auto *s = static_cast<Frontdoor *>(h);
   bool unpark;
   Control c;
@@ -890,6 +901,7 @@ SN_EXPORT int32_t sn_fd_next_control(void *h, int32_t *fd_out,
   *gen_out = int32_t(c.gen);
   int32_t n = int32_t(c.payload.size());
   *len_out = n;
+  *t_ns_out = c.t_ns;
   if (n > 0 && n <= max_len) memcpy(payload_out, c.payload.data(), size_t(n));
   return c.kind;
 }

@@ -171,6 +171,7 @@ struct Control {
   int32_t fd;
   uint32_t gen;
   std::string payload;
+  int64_t t_ns = 0;  // CLOCK_MONOTONIC when a frame (kind 0) was queued
 };
 
 struct Segment {
@@ -496,7 +497,8 @@ bool drain_segment(ShmDoor *s, const std::shared_ptr<Segment> &seg) {
         }
         s->controls.push_back(
             {0, seg->id, seg->gen,
-             std::string(reinterpret_cast<const char *>(payload), flen)});
+             std::string(reinterpret_cast<const char *>(payload), flen),
+             mono_us() * 1000});
         s->bytes_in.fetch_add(flen, std::memory_order_relaxed);
         notify = true;
       }
@@ -860,9 +862,11 @@ SN_EXPORT void sn_shm_send(void *h, int32_t fd, int32_t gen,
   rsp_doorbell(seg.get());
 }
 
+// as sn_fd_next_control (*t_ns_out: when the poller queued a frame)
 SN_EXPORT int32_t sn_shm_next_control(void *h, int32_t *fd_out,
                                       int32_t *gen_out, uint8_t *payload_out,
-                                      int32_t max_len, int32_t *len_out) {
+                                      int32_t max_len, int32_t *len_out,
+                                      int64_t *t_ns_out) {
   auto *s = static_cast<ShmDoor *>(h);
   bool unpark;
   Control c;
@@ -879,6 +883,7 @@ SN_EXPORT int32_t sn_shm_next_control(void *h, int32_t *fd_out,
   *gen_out = int32_t(c.gen);
   int32_t n = int32_t(c.payload.size());
   *len_out = n;
+  *t_ns_out = c.t_ns;
   if (n > 0 && n <= max_len) memcpy(payload_out, c.payload.data(), size_t(n));
   return c.kind;
 }

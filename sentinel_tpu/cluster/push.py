@@ -73,9 +73,10 @@ class PushHub:
             return len(self._sinks)
 
     # -- broadcast core -----------------------------------------------------
-    def _broadcast(self, frame: bytes, type_name: str) -> int:
-        """Hand ``frame`` to every live sink; returns deliveries that did
-        not raise. Never blocks, never raises."""
+    def _broadcast(self, frame: bytes, type_name: str, frames: int = 1) -> int:
+        """Hand ``frame`` (``frames`` whole frames back to back) to every
+        live sink; returns deliveries that did not raise. Never blocks,
+        never raises."""
         if not self.enabled:
             return 0
         with self._lock:
@@ -93,9 +94,11 @@ class PushHub:
                 self._dropped += dropped
         if sent:
             with self._lock:
-                self._sent[type_name] = self._sent.get(type_name, 0) + sent
+                self._sent[type_name] = (
+                    self._sent.get(type_name, 0) + sent * frames
+                )
             try:
-                _SM().count_push_frame(type_name, sent)
+                _SM().count_push_frame(type_name, sent * frames)
             except Exception:
                 pass
         return sent
@@ -125,12 +128,28 @@ class PushHub:
     def push_breaker_flip(
         self, flow_id: int, state: int, retry_after_ms: int = 0
     ) -> int:
+        return self.push_breaker_flips([(flow_id, state, retry_after_ms)])
+
+    def push_breaker_flips(self, flips) -> int:
+        """The edges one breaker scan observed, ``(flow_id, state,
+        retry_after_ms)`` each, as ONE write per connection: the frames ride
+        back to back under one stamp. A scan that finds a whole phase of
+        breakers tripped (a hundred edges) costs its caller a join and a
+        send per connection, not an encode and a send per edge per
+        connection: the scan runs on a reply lane, between a verdict and
+        its caller."""
+        flips = list(flips)
+        if not flips or not self.enabled:
+            return 0
+        stamp = self._now_ms()
         return self._broadcast(
-            P.encode_push_breaker_flip(
-                next(self._xid), self._now_ms(), int(flow_id), int(state),
-                int(retry_after_ms),
+            b"".join(
+                P.encode_push_breaker_flip(
+                    next(self._xid), stamp, int(f), int(s), int(r)
+                )
+                for f, s, r in flips
             ),
-            "breaker_flip",
+            "breaker_flip", frames=len(flips),
         )
 
     def push_rule_epoch(self, epoch: int) -> int:

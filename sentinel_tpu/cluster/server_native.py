@@ -1229,7 +1229,10 @@ class NativeTokenServer:
                     if item is None:
                         break
                     got_any = True
-                    self._handle_control_item(door, item, params)
+                    self._handle_control_item(
+                        door, item, params,
+                        getattr(door, "last_control_ns", 0) or None,
+                    )
                     if len(params) >= self.max_batch:
                         break
                 if params:
@@ -1253,10 +1256,13 @@ class NativeTokenServer:
                 P.FlowResponse(req.xid, req.msg_type, st, rm, wt)
             ))
 
-    def _handle_control_item(self, door, item, params=None) -> None:
+    def _handle_control_item(self, door, item, params=None,
+                             t_door_ns=None) -> None:
         """One control event. A PARAM_FLOW request is not answered here but
         appended to ``params`` (the control loop's drain), to be decided
-        with the others queued beside it."""
+        with the others queued beside it. ``t_door_ns`` is the
+        ``monotonic_ns`` at which the door queued the frame: a completion
+        report's age counts from it."""
         kind, fd, gen, payload = item
         if kind == door.CTRL_OPEN:
             address = payload.decode("latin-1")
@@ -1376,7 +1382,7 @@ class NativeTokenServer:
                 # outcome columns replicate from the primary; counting here
                 # would double on promotion
                 return
-            self.service.report_outcomes(ofids, orts, oexcs, oxid)
+            self.service.report_outcomes(ofids, orts, oexcs, oxid, t_door_ns)
             return
         try:
             req = P.decode_request(payload)

@@ -17,6 +17,7 @@ from __future__ import annotations
 import itertools
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -44,17 +45,24 @@ from sentinel_tpu.engine import (
     unpack_verdicts,
 )
 from sentinel_tpu.engine.decide import (
+    ARM_BREAKER,
+    ARM_DEGRADED_ROWS,
+    ARM_GUARDED_ROWS,
     ARM_LIVE,
     ARM_OCCUPY,
     ARM_PACED_ROWS,
     ARM_PACING,
     ARM_PRIORITIZED_ROWS,
+    ARM_PROBES,
     ARM_SHAPED_ROWS,
     ARM_SHAPING,
+    ARM_TO_OPEN,
     HEAD_NOW,
     ROW_HEAD,
     unpack_arms,
 )
+from sentinel_tpu.engine import outcome as _outcome
+from sentinel_tpu.engine.outcome import TALLY_CLOSED, TALLY_REOPENED
 from sentinel_tpu.engine.param import (
     ParamConfig,
     explain_param_impl,
@@ -457,6 +465,7 @@ class DefaultTokenService(TokenService):
         self._degrade_rules_src: Dict[int, "DegradeRule"] = {}
         self._has_breakers = False
         self._breaker_slots: set = set()
+        self._breaker_fid: Dict[int, int] = {}  # breaker slot -> flow_id
         self._breaker_prev: Optional[np.ndarray] = None
         self._breaker_scan_ts = 0.0
         # namespaces this server explicitly serves (modifyNamespaceSet);
@@ -558,6 +567,15 @@ class DefaultTokenService(TokenService):
         # sentinel_outcome_dropped_total{reason} and the reconciliation
         # gate. All mutated under self._lock.
         self._outcome_step = None
+        # (rung, with br_* columns) pairs the outcome step is compiled for;
+        # a report whose pair is missing compiles it first, outside the
+        # service lock and before its clock is read (_ensure_outcome_warm)
+        self._outcome_warm: set = set()
+        self._outcome_warm_lock = threading.Lock()
+        self._outcome_seq = 0  # ingests so far, bumped under the lock
+        # tallies of issued outcome steps, not yet counted
+        # (_settle_outcome_tallies)
+        self._outcome_tallies: deque = deque()
         self._outcome_counts: Dict[str, object] = {
             "reported": 0,  # rows accepted and scattered
             "exceptions": 0,  # subset of reported with exc=1
@@ -744,10 +762,11 @@ class DefaultTokenService(TokenService):
             # and lease gating) and a fresh transition-scan mirror — slot
             # assignments may have moved, so the old mirror is meaningless
             self._has_breakers = bool(degrade)
-            self._breaker_slots = {
-                self._index.slot_of[d.flow_id] for d in degrade
+            self._breaker_fid = {
+                self._index.slot_of[d.flow_id]: d.flow_id for d in degrade
                 if d.flow_id in self._index.slot_of
             }
+            self._breaker_slots = set(self._breaker_fid)
             self._breaker_prev = None
             # re-place after the drain scatter: eager sharding propagation
             # through .at[].set isn't guaranteed to keep the flow layout
@@ -1042,6 +1061,16 @@ class DefaultTokenService(TokenService):
                     step = self._fused_step_fn(fdepth, uniform)
                     ws, _ = step(ws, self._table, block)
                     compiles += 1
+            # the outcome step, every rung of its padding ladder, where
+            # degrade rules are loaded (the decide programs above carry the
+            # breaker arm then, by the table's br_* columns): a breaker reads
+            # a report by the bucket of its clock, and a first report that
+            # compiled its step was seconds late for that. A table without
+            # breakers leaves the step to its first report
+            # (_ensure_outcome_warm: outside the lock, before the clock).
+            if self._has_breakers:
+                ws = self._warm_outcome_steps(ws, self._table, now)
+                compiles += len(self._outcome_rungs())
             # compile counts on the cluster stat log: a serving window
             # that shows more compiles than warmup recorded hit a cold
             # bucket (shape drift, ladder change) — visible, not silent.
@@ -1293,6 +1322,9 @@ class DefaultTokenService(TokenService):
                 rows, live & ARM_SHAPING, live & ARM_PACING,
                 live & ARM_OCCUPY, int(arms[ARM_SHAPED_ROWS]),
                 int(arms[ARM_PACED_ROWS]), int(arms[ARM_PRIORITIZED_ROWS]),
+                breaker=(live & ARM_BREAKER, int(arms[ARM_GUARDED_ROWS]),
+                         int(arms[ARM_DEGRADED_ROWS]), int(arms[ARM_PROBES]),
+                         int(arms[ARM_TO_OPEN])),
             )
         if _TR.ARMED:  # flight recorder: verdicts on the host and counted
             sid, aux = self._trace_sid, seq & 0x7FFFFFFF
@@ -3498,7 +3530,79 @@ class DefaultTokenService(TokenService):
             return out
 
     # -- rev-6 completion-outcome ingest (OUTCOME_REPORT wire op) ------------
-    def report_outcomes(self, flow_ids, rt_ms, exceptions, xid: int = 0) -> int:
+    _OUTCOME_MIN_RUNG = 64
+
+    def _outcome_rungs(self) -> Tuple[int, ...]:
+        """The padding ladder of the outcome step: 64, 256, ... up to the
+        first rung that holds a full report (a report rides a request frame,
+        so it has at most ``batch_size`` rows, and the wire's
+        ``MAX_OUTCOME_PER_FRAME``). A larger in-process report pads on up
+        the same ladder and compiles at its first use
+        (``_ensure_outcome_warm``)."""
+        from sentinel_tpu.cluster import protocol as P
+
+        most = min(self.config.batch_size, P.MAX_OUTCOME_PER_FRAME)
+        rungs = [self._OUTCOME_MIN_RUNG]
+        while rungs[-1] < most:
+            rungs.append(rungs[-1] * 4)
+        return tuple(rungs)
+
+    def _outcome_args(self, cap: int, table):
+        """All-padding arguments of one rung (what a warm call passes)."""
+        f = self.config.max_flows
+        br = (
+            (table.br_strategy, table.br_slow_rt_ms)
+            if table.br_strategy is not None else ()
+        )
+        return (
+            jnp.asarray(np.full(cap, f, np.int32)),
+            jnp.asarray(np.zeros(cap, np.int32)),
+            jnp.asarray(np.zeros(cap, np.int32)),
+            jnp.asarray(np.zeros(cap, bool)),
+        ), br
+
+    def _warm_outcome_steps(self, ws, table, now: int, rungs=None):
+        """Compile the outcome step for ``rungs`` (default: the whole
+        ladder) against ``table``'s shape (with its ``br_*`` columns or
+        without) on the throwaway state ``ws``, which the step donates;
+        returns the state that comes back."""
+        if self._outcome_step is None:
+            # looked up at the call: tests stand a slow compile in its place
+            self._outcome_step = _outcome.outcome_step_donating(
+                self.config, tally=True)
+        has_br = table.br_strategy is not None
+        for cap in (self._outcome_rungs() if rungs is None else rungs):
+            cols, br = self._outcome_args(cap, table)
+            ws, tally = self._outcome_step(ws, *cols, jnp.int32(now), *br)
+            jax.block_until_ready(tally)
+            self._outcome_warm.add((cap, has_br))
+        return ws
+
+    def _ensure_outcome_warm(self, cap: int) -> None:
+        """Compile the outcome step for rung ``cap`` and the rule table as
+        it stands, unless it is. No compile under the service lock, and none
+        between a step's clock and its launch: a report is written into the
+        bucket of the clock read under the lock, and a compile of seconds
+        after that read put it in a bucket already older than the breakers'
+        stat interval by the next decide step (ROADMAP Reach A1: OK where
+        DEGRADED was due, on the chip, where this compile takes seconds).
+        ``warmup()`` has normally been here first; this is for the service
+        nobody warmed, or whose degrade rules changed shape since."""
+        table = self._table
+        key = (cap, table.br_strategy is not None)
+        if key in self._outcome_warm:
+            return
+        with self._outcome_warm_lock:
+            if key not in self._outcome_warm:
+                with self._lock:
+                    now = self._engine_now()
+                self._warm_outcome_steps(
+                    self._place_state(make_state(self.config)), table, now,
+                    rungs=(cap,),
+                )
+
+    def report_outcomes(self, flow_ids, rt_ms, exceptions, xid: int = 0,
+                        t_in_ns: Optional[int] = None) -> int:
         """Ingest one batched completion report: validate at the wire
         boundary, scatter accepted rows into the per-flow outcome window via
         the donated fused step, and feed every host metric plane (timeline,
@@ -3506,7 +3610,9 @@ class DefaultTokenService(TokenService):
 
         Returns the number of rows accepted. Fire-and-forget from the wire's
         point of view — both doors call this with no response frame, so the
-        lease/request fast path stays at zero extra RPCs.
+        lease/request fast path stays at zero extra RPCs. ``t_in_ns``
+        (``time.monotonic_ns()``) is when the report reached the server, for
+        its age at ingest; absent, the call's own start.
 
         Wire-boundary validation (never scattered, counted into
         ``sentinel_outcome_dropped_total{reason}``):
@@ -3521,6 +3627,8 @@ class DefaultTokenService(TokenService):
         """
         from sentinel_tpu.cluster import protocol as P
 
+        if t_in_ns is None:
+            t_in_ns = time.monotonic_ns()
         flow_ids = np.asarray(flow_ids, np.int64).reshape(-1)
         k = int(flow_ids.shape[0])
         rt_in = np.asarray(rt_ms).reshape(-1)
@@ -3549,7 +3657,8 @@ class DefaultTokenService(TokenService):
         )
         # pad to a geometric shape ladder so the jitted scatter retraces a
         # bounded number of times, not once per distinct report size
-        cap = 64
+        # (_outcome_rungs: warmup() compiles the rungs a wire report reaches)
+        cap = self._OUTCOME_MIN_RUNG
         while cap < k:
             cap *= 4
         pad = cap - k
@@ -3566,62 +3675,71 @@ class DefaultTokenService(TokenService):
             [(exc_in & valid).astype(np.int32), np.zeros(pad, np.int32)]
         )
         valid_p = np.concatenate([valid, np.zeros(pad, bool)])
-        with self._lock:
-            for reason, n in drops:
-                if n:
-                    d = self._outcome_counts["dropped"]
-                    d[reason] = d.get(reason, 0) + n
-            self._outcome_counts["batches"] += 1
+        args = (jnp.asarray(slots_p), jnp.asarray(rt_p), jnp.asarray(exc_p),
+                jnp.asarray(valid_p))
+        # what earlier steps did to the breakers, where the device is done
+        # with them: never waited for here
+        self._settle_outcome_tallies(wait=False)
+        tally = None
+        t_prep = time.monotonic_ns()
+        while True:
             if n_ok:
-                if self._outcome_step is None:
-                    from sentinel_tpu.engine.outcome import (
-                        outcome_step_donating,
+                self._ensure_outcome_warm(cap)
+            with self._lock:
+                has_br = self._table.br_strategy is not None
+                if n_ok and (cap, has_br) not in self._outcome_warm:
+                    continue  # the rules changed shape while it compiled
+                t_locked = time.monotonic_ns()
+                seq = self._outcome_seq = self._outcome_seq + 1
+                for reason, n in drops:
+                    if n:
+                        d = self._outcome_counts["dropped"]
+                        d[reason] = d.get(reason, 0) + n
+                self._outcome_counts["batches"] += 1
+                if n_ok:
+                    now = self._engine_now()
+                    # breakers loaded: the step additionally counts the SLOW
+                    # channel against each flow's DegradeRule cutoff and
+                    # resolves HALF_OPEN probes (a separate jit trace; the
+                    # 6-arg form stays bit-identical to the pre-breaker step)
+                    br = (
+                        (self._table.br_strategy, self._table.br_slow_rt_ms)
+                        if has_br else ()
                     )
-
-                    self._outcome_step = outcome_step_donating(self.config)
-                now = self._engine_now()
-                if self._has_breakers:
-                    # breakers loaded: the step additionally counts the
-                    # SLOW channel against each flow's DegradeRule cutoff
-                    # and resolves HALF_OPEN probes (a separate jit trace;
-                    # the 6-arg form below stays bit-identical to the
-                    # pre-breaker step)
-                    self._state = self._outcome_step(
-                        self._state,
-                        jnp.asarray(slots_p),
-                        jnp.asarray(rt_p),
-                        jnp.asarray(exc_p),
-                        jnp.asarray(valid_p),
-                        jnp.int32(now),
-                        self._table.br_strategy,
-                        self._table.br_slow_rt_ms,
+                    self._state, tally = self._outcome_step(
+                        self._state, *args, jnp.int32(now), *br
                     )
-                else:
-                    self._state = self._outcome_step(
-                        self._state,
-                        jnp.asarray(slots_p),
-                        jnp.asarray(rt_p),
-                        jnp.asarray(exc_p),
-                        jnp.asarray(valid_p),
-                        jnp.int32(now),
-                    )
-                self._outcome_counts["reported"] += n_ok
-                n_exc = int((exc_in & valid).sum())
-                self._outcome_counts["exceptions"] += n_exc
-                self._outcome_counts["rt_sum_ms"] += int(rt[valid].sum())
-                if self._dirty is not None:
-                    touched = {int(s) for s in np.unique(slots[valid])}
-                    self._dirty.setdefault("outcome", set()).update(touched)
-                    if self._has_breakers:
-                        # a report can resolve a probe (HALF_OPEN →
-                        # CLOSED/OPEN), so reported breaker slots are
-                        # breaker-dirty too
-                        self._dirty.setdefault("breaker", set()).update(
-                            touched & self._breaker_slots
-                        )
-            ns_names, slot_ns = self._ns_snapshot
+                    self._outcome_counts["reported"] += n_ok
+                    n_exc = int((exc_in & valid).sum())
+                    self._outcome_counts["exceptions"] += n_exc
+                    self._outcome_counts["rt_sum_ms"] += int(rt[valid].sum())
+                    if self._dirty is not None:
+                        touched = {int(s) for s in np.unique(slots[valid])}
+                        self._dirty.setdefault("outcome", set()).update(touched)
+                        if self._has_breakers:
+                            # a report can resolve a probe (HALF_OPEN →
+                            # CLOSED/OPEN), so reported breaker slots are
+                            # breaker-dirty too
+                            self._dirty.setdefault("breaker", set()).update(
+                                touched & self._breaker_slots
+                            )
+                ns_names, slot_ns = self._ns_snapshot
+            break
+        t_out = time.monotonic_ns()
+        if tally is not None:
+            tally.copy_to_host_async()
+            self._outcome_tallies.append(tally)
+        _SM.outcome_lock_wait_ms.record((t_locked - t_prep) * 1e-6)
+        _SM.outcome_launch_ms.record((t_out - t_locked) * 1e-6)
+        _SM.outcome_age_ms.record(max(0, t_out - t_in_ns) * 1e-6)
+        _SM.count_outcome_report(n_ok)
         if _TR.ARMED:
-            _TR.record(_TR.OUTCOME, xid=xid, aux=n_ok)
+            # one span per report, door to step issued: both ends carry the
+            # ingest's sequence number. xid 0 on the step's own record: it
+            # is no data-plane event, and must not fall to the xid sample
+            sid = seq & 0x7FFF
+            _TR.record(_TR.OUTCOME_IN, xid=xid, shard=sid, aux=k, t_ns=t_in_ns)
+            _TR.record(_TR.OUTCOME, shard=sid, aux=n_ok, t_ns=t_out)
         if not n_ok:
             return 0
         log_cluster("outcome_reported", count=n_ok)
@@ -3651,6 +3769,25 @@ class DefaultTokenService(TokenService):
             plane.record_completion(name, rts, n_exception=n_exc_ns)
         return n_ok
 
+    def _settle_outcome_tallies(self, wait: bool) -> None:
+        """Count what finished outcome steps say they did to the breakers
+        (``engine.outcome.TALLY_*``; each tally was sent towards the host
+        when its step was issued). Without ``wait`` only those the device is
+        done with: the next report's ingest calls this, a step or more
+        later, and never blocks on the device; :meth:`outcome_stats` (every
+        scrape) waits for the rest."""
+        pending = self._outcome_tallies
+        while pending:
+            try:
+                if not wait and not pending[0].is_ready():
+                    return
+                said = np.asarray(pending.popleft())
+            except IndexError:
+                return  # another thread took the last one
+            _SM.count_breaker_resolved(
+                int(said[TALLY_CLOSED]), int(said[TALLY_REOPENED])
+            )
+
     def outcome_stats(self) -> Dict[str, object]:
         """Host snapshot of the outcome plane: ingest counters (the
         reconciliation gate's server-side truth) plus per-flow windowed
@@ -3664,6 +3801,7 @@ class DefaultTokenService(TokenService):
         )
         from sentinel_tpu.stats import window as W
 
+        self._settle_outcome_tallies(wait=True)
         with self._lock:
             c = self._outcome_counts
             out: Dict[str, object] = {
@@ -3728,13 +3866,19 @@ class DefaultTokenService(TokenService):
         when a batch actually produced DEGRADED verdicts)."""
         if not self._has_breakers:
             return
+        # the rate limit is looked at before the service lock is asked for:
+        # a materializer calls this for every dispatch that shed a row, and
+        # waiting for the lock there, to be told "not yet", puts every reply
+        # lane behind the decide and outcome launches
+        if not force and time.monotonic() - self._breaker_scan_ts < 1.0:
+            return
         edges: Dict[Tuple[int, int], int] = {}
         tripped: List[object] = []
         flips: List[Tuple[int, int]] = []  # (flow_id, new state) per edge
         with self._lock:
             now_s = time.monotonic()
             if not force and now_s - self._breaker_scan_ts < 1.0:
-                return
+                return  # another lane scanned while this one waited
             self._breaker_scan_ts = now_s
             st = np.array(np.asarray(self._state.breaker.state))
             prev = self._breaker_prev
@@ -3747,9 +3891,9 @@ class DefaultTokenService(TokenService):
             changed = np.nonzero(st != prev)[0]
             if changed.size == 0:
                 return
-            rev = {v: k for k, v in self._index.slot_of.items()}
+            rev = self._breaker_fid  # slot -> flow_id of the breaker slots
             for s in changed.tolist():
-                if s not in self._breaker_slots:
+                if s not in rev:
                     continue  # stale mirror rows of dropped rules
                 frm, to = int(prev[s]), int(st[s])
                 edges[(frm, to)] = edges.get((frm, to), 0) + 1
@@ -3769,12 +3913,20 @@ class DefaultTokenService(TokenService):
         # their local admission clocks (retry-after = the rule's recovery
         # timeout, the earliest the device could HALF_OPEN), CLOSED and
         # HALF_OPEN lift them so probe traffic reaches the wire again
-        for fid, to in flips:
-            retry = 0
-            if to == 1:
-                rule = self._degrade_rules_src.get(fid)
-                retry = int(getattr(rule, "recovery_timeout_ms", 0) or 0)
-            self._emit_push("push_breaker_flip", fid, to, retry)
+        # (one emit for the scan: its edges ride back to back in one write
+        # per connection, not an encode and a send per edge per connection
+        # on this reply lane; with the lock-first scan, in the cell
+        # breaker-mesh-100k.tenants-zipf-health-cycle-open at 80k rows/s:
+        # `account` 2.62 against 1.11 ms a dispatch, p95 7.55 against
+        # 5.85 ms, PERF.md section 6, PR 34)
+        if flips:
+            src = self._degrade_rules_src
+            self._emit_push("push_breaker_flips", [
+                (fid, to, int(getattr(
+                    src.get(fid), "recovery_timeout_ms", 0) or 0)
+                 if to == 1 else 0)
+                for fid, to in flips
+            ])
         if tripped:
             from sentinel_tpu.trace import blackbox as _blackbox
 

@@ -113,8 +113,14 @@ class VerdictBatch(NamedTuple):
 # ``lax.cond`` took its live branch; the rest count the rows the arms had
 # before them.
 ARM_LIVE, ARM_SHAPED_ROWS, ARM_PACED_ROWS, ARM_PRIORITIZED_ROWS = 0, 1, 2, 3
-ARM_FIELDS = 4
-ARM_SHAPING, ARM_PACING, ARM_OCCUPY = 1, 2, 4  # bits of an ARM_LIVE entry
+# the breaker arm (``engine.degrade.breaker_gate``): rows on a guarded flow,
+# rows answered DEGRADED, probe tickets given (moves to HALF_OPEN, a stale
+# probe armed again included) and flows tripped to OPEN. A move to CLOSED is
+# the outcome step's (``engine.outcome.TALLY_CLOSED``).
+ARM_GUARDED_ROWS, ARM_DEGRADED_ROWS, ARM_PROBES, ARM_TO_OPEN = 4, 5, 6, 7
+ARM_FIELDS = 8
+# bits of an ARM_LIVE entry
+ARM_SHAPING, ARM_PACING, ARM_OCCUPY, ARM_BREAKER = 1, 2, 4, 8
 ARM_ALL = ARM_SHAPING | ARM_PACING | ARM_OCCUPY
 # a status is at most 12: the arm fields ride above it in the status line's
 # first entries (no frame is shorter than the smallest serve bucket)
@@ -547,9 +553,10 @@ def _decide_core_arms(
       mixed acquire sizes, where greedy admission is not associative).
 
     Returns ``(state', verdicts, arms)``; ``arms`` (``int32[ARM_FIELDS]``) is
-    what the step's cond-gated arms did: which of the ``shaping``, ``pacing``
-    and ``occupy`` conds took its live branch, and the shaped, paced and
-    prioritized rows of the batch. The serve steps hand it out inside their
+    what the step's cond-gated arms did: which of the ``shaping``, ``pacing``,
+    ``occupy`` and ``breaker`` conds took its live branch, the shaped, paced
+    and prioritized rows of the batch, and what the breaker arm did with its
+    rows (``ARM_*``). The serve steps hand it out inside their
     packed verdicts (:func:`pack_verdicts`); :func:`_decide_core` drops it.
     """
     spec = flow_spec(config)
@@ -614,7 +621,7 @@ def _decide_core_arms(
     #     predicate.
     # ------------------------------------------------------------------
     with jax.named_scope("breaker"):
-        degraded, br_retry, breaker_ws = _breaker_gate(
+        degraded, br_retry, breaker_ws, br_said = _breaker_gate(
             config, spec, state, rules, now, safe_slot, active, flow_prefix, psum
         )
         active = active & ~degraded
@@ -991,8 +998,9 @@ def _decide_core_arms(
     verdicts = VerdictBatch(status=status, wait_ms=wait_ms, remaining=remaining)
     # the three cond predicates above and the rows behind them: ARM_* order
     arms = jnp.stack([
-        any_warm * ARM_SHAPING + any_pace * ARM_PACING + any_prio * ARM_OCCUPY,
-        n_shaped, n_paced, n_prio,
+        any_warm * ARM_SHAPING + any_pace * ARM_PACING + any_prio * ARM_OCCUPY
+        + br_said[0] * ARM_BREAKER,
+        n_shaped, n_paced, n_prio, *br_said[1:],
     ]).astype(jnp.int32)
     return new_state, verdicts, arms
 

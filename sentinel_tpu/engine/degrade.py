@@ -62,6 +62,12 @@ from sentinel_tpu.engine.state import (
 from sentinel_tpu.stats.window import NEVER
 
 
+# what the gate says it did (``breaker_gate``'s ``said``): whether it ran and
+# the rows it had, known outside its cond, then what it counted inside
+BR_COUNTED_FIELDS = 3  # rows shed, probe tickets given, flows tripped
+BR_SAID_FIELDS = 2 + BR_COUNTED_FIELDS
+
+
 def breaker_gate(
     config: EngineConfig,
     spec,
@@ -74,13 +80,18 @@ def breaker_gate(
     psum,  # mesh reduction (identity single-shard)
 ) -> tuple:
     """Evaluate breaker transitions for one batch; returns
-    ``(degraded, retry_ms, breaker')``.
+    ``(degraded, retry_ms, breaker', said)``.
 
     ``degraded`` rows must be stripped from ``active`` before admission
     (they write NO flow-window events, like namespace-guard refusals) and
     answer ``TokenStatus.DEGRADED`` with ``retry_ms`` in ``remaining``.
     All three outputs are local to the owner shard; the verdict psum
     stitches them exactly like the other owner-emitted statuses.
+
+    ``said`` (``int32[BR_SAID_FIELDS]``, the same on every shard) is what
+    the arm did, for the step's ``arms``: whether the gate ran, the rows on
+    a guarded flow, the rows shed, the probe tickets given (each a move to
+    HALF_OPEN, or a stale probe armed again) and the flows tripped to OPEN.
     """
     n = safe_slot.shape[0]
     if rules.br_strategy is None:
@@ -91,18 +102,21 @@ def breaker_gate(
             jnp.zeros((n,), bool),
             jnp.zeros((n,), jnp.int32),
             state.breaker,
+            jnp.zeros((BR_SAID_FIELDS,), jnp.int32),
         )
     f_local = rules.valid.shape[0]
     strat = rules.br_strategy[safe_slot].astype(jnp.int32)
     br_rows = active & (strat >= 0)
     # mesh-uniform predicate: the psum lives OUTSIDE the cond
-    any_br = jnp.any(psum(br_rows.astype(jnp.int32)) > 0)
+    n_guarded = jnp.sum(psum(br_rows.astype(jnp.int32)))
+    any_br = n_guarded > 0
 
     def gate_off(_):
         return (
             jnp.zeros((n,), bool),
             jnp.zeros((n,), jnp.int32),
             state.breaker,
+            jnp.zeros((BR_COUNTED_FIELDS,), jnp.int32),
         )
 
     def gate_on(_):
@@ -200,8 +214,23 @@ def breaker_gate(
             br.probe_ms.at[scat_open].set(jnp.int32(NEVER), mode="drop")
             .at[scat_half].set(now, mode="drop")
         )
+        # this shard's rows shed, tickets given and flows tripped (the
+        # first tripping row of a flow stands for it)
+        tripped = just_open & (flow_prefix(just_open.astype(jnp.float32)) == 0.0)
+        counted = jnp.stack([
+            jnp.sum(degraded.astype(jnp.int32)),
+            jnp.sum(is_probe.astype(jnp.int32)),
+            jnp.sum(tripped.astype(jnp.int32)),
+        ])
         return degraded, retry_ms, BreakerState(
             state=br_state, opened_ms=br_opened, probe_ms=br_probe
-        )
+        ), counted
 
-    return jax.lax.cond(any_br, gate_on, gate_off, None)
+    degraded, retry_ms, breaker, counted = jax.lax.cond(
+        any_br, gate_on, gate_off, None
+    )
+    # one three-entry collective, outside the cond like the predicate's
+    said = jnp.concatenate([
+        jnp.stack([any_br.astype(jnp.int32), n_guarded]), psum(counted)
+    ])
+    return degraded, retry_ms, breaker, said

@@ -72,7 +72,7 @@ def _resolve_probes(
     rt_ms: jax.Array,
     exc: jax.Array,
     now: jax.Array,
-) -> BreakerState:
+) -> tuple:
     """HALF_OPEN probe resolution — ``fromHalfOpenToClose`` / the error
     rollback: the FIRST report of each flow whose breaker sits HALF_OPEN
     with a live probe ticket decides the flow's fate. Success (fast for
@@ -87,8 +87,10 @@ def _resolve_probes(
     probe = br.probe_ms[gslot]
     live = in_rng & (st == BR_HALF_OPEN) & (probe != NEVER)
 
+    zero = jnp.int32(0)
+
     def off(_):
-        return br
+        return br, zero, zero
 
     def on(_):
         # first live report per flow in batch order wins the resolution
@@ -104,11 +106,12 @@ def _resolve_probes(
         )
         new_st = jnp.where(fail, BR_OPEN, BR_CLOSED).astype(jnp.int8)
         scat = jnp.where(elected, gslot, f)
+        reopened = jnp.sum((elected & fail).astype(jnp.int32))
         return BreakerState(
             state=br.state.at[scat].set(new_st, mode="drop"),
             opened_ms=br.opened_ms.at[scat].set(now, mode="drop"),
             probe_ms=br.probe_ms.at[scat].set(jnp.int32(NEVER), mode="drop"),
-        )
+        ), jnp.sum(elected.astype(jnp.int32)) - reopened, reopened
 
     return jax.lax.cond(jnp.any(live), on, off, None)
 
@@ -123,7 +126,9 @@ def _outcome_core(
     now: jax.Array,  # int32 engine ms
     br_strategy=None,  # int8 [F] rule column, or None (no breakers loaded)
     br_slow_rt_ms=None,  # int32 [F] rule column, or None
-) -> EngineState:
+) -> tuple:
+    """``(state', tally)``; ``tally`` is ``int32[TALLY_FIELDS]``: the
+    breakers the reports resolved (``TALLY_*``)."""
     spec = flow_spec(config)
     k = slots.shape[0]
     # invalid rows scatter to row F, which mode="drop" discards entirely
@@ -162,18 +167,28 @@ def _outcome_core(
         channel_ids=int(OutcomeChannel.RT_HIST0) + rt_bucket(rt_ms),
         values=ones,
     )
-    breaker = state.breaker
+    breaker, closed, reopened = state.breaker, jnp.int32(0), jnp.int32(0)
     if br_strategy is not None:
-        breaker = _resolve_probes(
+        breaker, closed, reopened = _resolve_probes(
             state.breaker, br_strategy, br_slow_rt_ms, gslot, in_rng,
             rt_ms, exc, now,
         )
-    return state._replace(outcome=ws, breaker=breaker)
+    tally = jnp.stack([closed, reopened]).astype(jnp.int32)
+    return state._replace(outcome=ws, breaker=breaker), tally
 
 
-def outcome_step_donating(config: EngineConfig):
+# what a tallying step says it did, ``int32[TALLY_FIELDS]``: HALF_OPEN
+# breakers a report closed, HALF_OPEN breakers a report reopened
+TALLY_CLOSED, TALLY_REOPENED = 0, 1
+TALLY_FIELDS = 2
+
+
+def outcome_step_donating(config: EngineConfig, tally: bool = False):
     """Build the jitted donated step ``(state, slots, rt, exc, valid, now)
-    -> state'``. The full EngineState is donated (the admission windows
+    -> state'``, or with ``tally`` ``-> (state', int32[TALLY_FIELDS])``: the
+    serving form, whose few bytes tell the host which breakers the step
+    resolved, without a read of the state. The full EngineState is donated
+    (the admission windows
     alias through untouched), mirroring ``decide_donating``'s contract:
     the caller's lock must make the passed state the only live reference.
 
@@ -182,6 +197,7 @@ def outcome_step_donating(config: EngineConfig):
     SLOW-channel scatter and HALF_OPEN probe resolution (a separate jit
     trace; the 6-arg form stays bit-identical to the pre-breaker step)."""
     def step(state, slots, rt, exc, valid, now, *br):
-        return _outcome_core(config, state, slots, rt, exc, valid, now, *br)
+        out = _outcome_core(config, state, slots, rt, exc, valid, now, *br)
+        return out if tally else out[0]
 
     return jax.jit(named(step, "outcome_step"), donate_argnums=(0,))

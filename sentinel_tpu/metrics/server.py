@@ -130,6 +130,15 @@ class ServerMetrics:
          "counters, SLO plane, timeline, stat log, breaker scan (ms)."),
         ("compile_ms",
          "Backend compiles (persistent-cache hits included), each (ms)."),
+        # the outcome path, one record per completion report
+        ("outcome_lock_wait_ms",
+         "Wait for the token service lock per outcome ingest (ms)."),
+        ("outcome_launch_ms",
+         "Service lock acquired to outcome step issued: drop counters, "
+         "engine clock, the jitted call, dirty-set (ms)."),
+        ("outcome_age_ms",
+         "A completion report's wait from the door (or the in-process "
+         "call) to the launch of the step that ingested it (ms)."),
     )
 
     _PARAM_COUNTERS = {
@@ -178,6 +187,37 @@ class ServerMetrics:
             "reached the pacing arm (cumulative).",
         "decide_prioritized_rows_total":
             "Prioritized rows in flow dispatches (cumulative).",
+        # the breaker arm (engine.decide.ARM_BREAKER and the four counts
+        # behind it), per flow dispatch
+        "decide_breaker_live_total":
+            "Flow dispatches whose step took the live branch of its breaker "
+            "cond: a row on a flow with a DegradeRule was in the batch "
+            "(cumulative).",
+        "decide_guarded_rows_total":
+            "Rows on a flow with a DegradeRule that reached the breaker arm "
+            "(cumulative).",
+        "decide_degraded_rows_total":
+            "Rows the breaker arm answered DEGRADED (cumulative).",
+        "breaker_probe_tickets_total":
+            "Probe tickets the breaker arm gave: moves OPEN to HALF_OPEN, "
+            "and stale probes armed again (cumulative).",
+        "breaker_to_open_total":
+            "Flows the breaker arm tripped CLOSED to OPEN (cumulative).",
+        # what the outcome steps say (engine.outcome.TALLY_*), counted when
+        # a later ingest or a scrape finds the step finished
+        "breaker_to_closed_total":
+            "HALF_OPEN breakers a completion report closed (cumulative).",
+        "breaker_reopened_total":
+            "HALF_OPEN breakers a completion report sent back to OPEN "
+            "(cumulative).",
+        "outcome_frames_total":
+            "Completion reports (OUTCOME_REPORT frames or in-process calls) "
+            "ingested (cumulative).",
+        "outcome_steps_total":
+            "Outcome steps launched: one per report with a valid row "
+            "(cumulative).",
+        "outcome_step_rows_total":
+            "Completion rows those steps scattered (cumulative).",
     }
 
     def __init__(self):
@@ -344,9 +384,12 @@ class ServerMetrics:
 
     def count_decide_arms(self, rows: int, shaping: bool, pacing: bool,
                           occupy: bool, shaped: int, paced: int,
-                          prioritized: int) -> None:
+                          prioritized: int, breaker=(0, 0, 0, 0, 0)) -> None:
         """One flow dispatch was accounted: which of its step's arms ran,
-        and the rows those arms had before them."""
+        and the rows those arms had before them. ``breaker`` is the breaker
+        arm's ``(live, guarded rows, degraded rows, probe tickets, flows
+        tripped)``."""
+        live, guarded, degraded, probes, to_open = breaker
         with self._arm_lock:
             a = self._arms
             a["decide_dispatch_total"] += 1
@@ -359,6 +402,28 @@ class ServerMetrics:
             a["decide_shaped_rows_total"] += shaped
             a["decide_paced_rows_total"] += paced
             a["decide_prioritized_rows_total"] += prioritized
+            a["decide_breaker_live_total"] += bool(live)
+            a["decide_guarded_rows_total"] += guarded
+            a["decide_degraded_rows_total"] += degraded
+            a["breaker_probe_tickets_total"] += probes
+            a["breaker_to_open_total"] += to_open
+
+    def count_outcome_report(self, rows: int) -> None:
+        """One completion report was ingested; ``rows`` valid rows went
+        into an outcome step (none: no step was launched)."""
+        with self._arm_lock:
+            a = self._arms
+            a["outcome_frames_total"] += 1
+            a["outcome_steps_total"] += bool(rows)
+            a["outcome_step_rows_total"] += rows
+
+    def count_breaker_resolved(self, closed: int, reopened: int) -> None:
+        """A finished outcome step's tally: the HALF_OPEN breakers its
+        report closed and sent back to OPEN."""
+        with self._arm_lock:
+            a = self._arms
+            a["breaker_to_closed_total"] += closed
+            a["breaker_reopened_total"] += reopened
 
     def arm_totals(self) -> Dict[str, int]:
         with self._arm_lock:

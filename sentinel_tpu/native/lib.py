@@ -115,6 +115,7 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
             [
                 P, ctypes.POINTER(I32), ctypes.POINTER(I32),
                 ctypes.POINTER(ctypes.c_uint8), I32, ctypes.POINTER(I32),
+                ctypes.POINTER(I64),
             ],
             I32,
         ),
@@ -158,6 +159,7 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
             [
                 P, ctypes.POINTER(I32), ctypes.POINTER(I32),
                 ctypes.POINTER(ctypes.c_uint8), I32, ctypes.POINTER(I32),
+                ctypes.POINTER(I64),
             ],
             I32,
         ),
@@ -509,6 +511,7 @@ class Frontdoor:
         self._tls = threading.local()
         self._ctrl_buf = ctypes.create_string_buffer(70000)
         self._ctrl_lock = threading.Lock()
+        self.last_control_ns = 0  # see next_control
         self._stopped = False
 
     def _bufs(self):
@@ -710,18 +713,23 @@ class Frontdoor:
         self._lib.sn_fd_close_conn(self._h, fd, gen)
 
     def next_control(self):
-        """``None`` or ``(kind, fd, gen, payload bytes)``."""
+        """``None`` or ``(kind, fd, gen, payload bytes)``. ``last_control_ns``
+        is then the ``time.monotonic_ns()`` at which the IO thread queued
+        that frame (0 for an open or close event): how long it waited for
+        its consumer."""
         fd = ctypes.c_int32()
         gen = ctypes.c_int32()
         ln = ctypes.c_int32()
+        t_ns = ctypes.c_int64()
         with self._ctrl_lock:
             kind = self._lib.sn_fd_next_control(
                 self._h, ctypes.byref(fd), ctypes.byref(gen),
                 ctypes.cast(self._ctrl_buf, ctypes.POINTER(ctypes.c_uint8)),
-                len(self._ctrl_buf), ctypes.byref(ln),
+                len(self._ctrl_buf), ctypes.byref(ln), ctypes.byref(t_ns),
             )
             if kind < 0:
                 return None
+            self.last_control_ns = t_ns.value
             # string_at copies only the written bytes — .raw would build
             # the full 70KB buffer as bytes for every 7-byte PING
             payload = (
@@ -822,6 +830,7 @@ class ShmDoor:
         self._tls = threading.local()
         self._ctrl_buf = ctypes.create_string_buffer(70000)
         self._ctrl_lock = threading.Lock()
+        self.last_control_ns = 0  # see next_control
         self._stopped = False
 
     _ptr = Frontdoor._ptr
@@ -926,9 +935,15 @@ class ShmDoor:
 
     def send(self, fd: int, gen: int, frame: bytes) -> None:
         # TCP frames carry a 2-byte length prefix; ring slots carry the
-        # payload with the slot len word playing the prefix's role
-        payload = frame[2:]
-        self._lib.sn_shm_send(self._h, fd, gen, payload, len(payload))
+        # payload with the slot len word playing the prefix's role. A
+        # caller may hand over several frames back to back (a push hub's
+        # batch): each gets a slot of its own
+        pos, end = 0, len(frame)
+        while pos + 2 <= end:
+            n = (frame[pos] << 8) | frame[pos + 1]
+            payload = frame[pos + 2:pos + 2 + n]
+            self._lib.sn_shm_send(self._h, fd, gen, payload, len(payload))
+            pos += 2 + n
 
     def set_idle_ttl(self, ttl_ms: int) -> None:
         # liveness is pid-based (the poller sweep), not activity-based
@@ -941,14 +956,16 @@ class ShmDoor:
         fd = ctypes.c_int32()
         gen = ctypes.c_int32()
         ln = ctypes.c_int32()
+        t_ns = ctypes.c_int64()
         with self._ctrl_lock:
             kind = self._lib.sn_shm_next_control(
                 self._h, ctypes.byref(fd), ctypes.byref(gen),
                 ctypes.cast(self._ctrl_buf, ctypes.POINTER(ctypes.c_uint8)),
-                len(self._ctrl_buf), ctypes.byref(ln),
+                len(self._ctrl_buf), ctypes.byref(ln), ctypes.byref(t_ns),
             )
             if kind < 0:
                 return None
+            self.last_control_ns = t_ns.value
             payload = (
                 ctypes.string_at(self._ctrl_buf, ln.value)
                 if ln.value > 0 else b""

@@ -55,7 +55,9 @@ MOVE = 12        # MOVE begin/commit/abort (aux = phase: 0/1/2)
 PROMOTE = 13     # standby promoted to primary
 BROWNOUT = 14    # admission ladder escalated (aux = level)
 SHM_POLL = 15    # shm ring door poll/doorbell activity (aux = frames)
-OUTCOME = 16     # batched completion report ingested (aux = rows accepted)
+OUTCOME = 16     # a completion report ingested: its outcome step issued and
+#                  the service lock released (aux = rows accepted; shard = the
+#                  ingest's sequence number, which joins it to OUTCOME_IN)
 PARAM_LANE = 1   # ``shard`` of the DEVICE_IN / DEVICE_OUT of a param dispatch
 # DEVICE_OUT of a flow dispatch: ``shard >> ARM_SHIFT`` holds the bits of the
 # decide step's cond-gated arms that took their live branch
@@ -74,6 +76,10 @@ LOCKED = 19      # service lock acquired
 READY = 20       # the verdict buffer on the host (step and copy finished)
 FETCHED = 21     # request-order verdict arrays built (unpack, unsort, MOVED)
 COMPILE = 22     # a backend compile ended (aux = ms)
+OUTCOME_IN = 23  # a completion report reached the server: t_ns is when its
+#                  door queued it, or the in-process call began (xid = the
+#                  report's; aux = its rows; shard as its OUTCOME's). The
+#                  span OUTCOME_IN -> OUTCOME is the report's age at ingest
 
 STAGE_NAMES: Dict[int, str] = {
     CLIENT_IN: "client_in",
@@ -98,6 +104,7 @@ STAGE_NAMES: Dict[int, str] = {
     READY: "ready",
     FETCHED: "fetched",
     COMPILE: "compile",
+    OUTCOME_IN: "outcome_in",
 }
 
 # one ring row: 24 bytes, fixed

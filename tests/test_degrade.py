@@ -50,6 +50,7 @@ NEVER = int(W.NEVER)
 SLOW = DegradeStrategy.SLOW_REQUEST_RATIO
 ERR_RATIO = DegradeStrategy.ERROR_RATIO
 ERR_COUNT = DegradeStrategy.ERROR_COUNT
+DEG = int(TokenStatus.DEGRADED)
 
 # max_flows divides the 8-device mesh evenly (4 slots per shard) and the
 # 24-flow fixture spans 6 shards, so the sharded run exercises real
@@ -643,3 +644,137 @@ class TestShardedParity:
                 )
         # the mesh rounds actually saw breaker traffic
         assert int((np.asarray(state.breaker.state) != BR_CLOSED).sum()) > 0
+
+
+# ---------------------------------------------------------------------------
+# The served path: the cases breaker-mesh-100k's probe holds through the door
+# (cellbench/families/breaker.py), here on DefaultTokenService with a manual
+# clock, each over the three strategies at the deployment's thresholds
+# ---------------------------------------------------------------------------
+def _completions(n_bad: int, n_good: int, strategy):
+    """``(rt_ms, exc)`` of ``n_bad`` completions the strategy counts and
+    ``n_good`` it does not (an RT of exactly the cutoff is not slow)."""
+    if strategy == SLOW:
+        return [51] * n_bad + [50] * n_good, [False] * (n_bad + n_good)
+    return [5] * (n_bad + n_good), [True] * n_bad + [False] * n_good
+
+
+@pytest.mark.parametrize("strategy, threshold, at, past", [
+    (SLOW, 0.6, (6, 4), (7, 3)),  # 6 of 10 is the ratio, 7 of 10 is over it
+    (ERR_RATIO, 0.5, (5, 5), (6, 4)),
+    (ERR_COUNT, 4.0, (4, 6), (5, 5)),
+], ids=["slow_ratio", "error_ratio", "error_count"])
+class TestServedPath:
+    REC = 2000
+    T0 = 1_700_000_000_000
+
+    def _service(self, clock, strategy, threshold):
+        from sentinel_tpu.cluster.token_service import DefaultTokenService
+
+        clock.set_ms(self.T0)
+        svc = DefaultTokenService(
+            EngineConfig(max_flows=16, max_namespaces=2, batch_size=64),
+            serve_buckets=(64,), fuse_depths=())
+        svc.load_rules([ClusterFlowRule(1, 1e9, namespace="a")])
+        svc.load_degrade_rules([DegradeRule(
+            1, strategy, threshold, slow_rt_ms=50, min_request_amount=5,
+            stat_interval_ms=1000, recovery_timeout_ms=self.REC,
+            namespace="a")])
+        svc.warmup()  # the engine's clock starts here: T0 is its ms 1
+        return svc
+
+    def _at(self, clock, t_ms: int) -> None:
+        clock.set_ms(self.T0 - 1 + t_ms)
+
+    def _ask(self, svc, n: int = 1):
+        status, remaining, _wait = svc.request_batch_arrays(np.ones(n, np.int64))
+        return status.tolist(), remaining.tolist()
+
+    def _tripped(self, clock, strategy, threshold, past):
+        svc = self._service(clock, strategy, threshold)
+        self._at(clock, 1000)
+        svc.report_outcomes([1] * 10, *_completions(*past, strategy))
+        self._at(clock, 1010)
+        assert self._ask(svc, 2) == ([DEG] * 2, [self.REC] * 2)
+        return svc
+
+    def test_the_threshold_is_passed_strictly(self, manual_clock, strategy,
+                                              threshold, at, past):
+        svc = self._service(manual_clock, strategy, threshold)
+        try:
+            self._at(manual_clock, 1000)
+            svc.report_outcomes([1] * 10, *_completions(*at, strategy))
+            self._at(manual_clock, 1010)
+            assert self._ask(svc, 3)[0] == [int(TokenStatus.OK)] * 3
+            # one more of the bad kind takes it over
+            svc.report_outcomes([1], *_completions(1, 0, strategy))
+            self._at(manual_clock, 1020)
+            assert self._ask(svc, 3) == ([DEG] * 3, [self.REC] * 3)
+        finally:
+            svc.close()
+
+    def test_min_request_amount_from_both_sides(self, manual_clock, strategy,
+                                                threshold, at, past):
+        svc = self._service(manual_clock, strategy, threshold)
+        try:
+            self._at(manual_clock, 1000)
+            # four completions, all bad: under min_request_amount 5
+            svc.report_outcomes([1] * 4, *_completions(4, 0, strategy))
+            self._at(manual_clock, 1010)
+            assert self._ask(svc)[0] == [int(TokenStatus.OK)]
+            # the fifth: five of five (ratio 1, count 5 over 4)
+            svc.report_outcomes([1], *_completions(1, 0, strategy))
+            self._at(manual_clock, 1020)
+            assert self._ask(svc) == ([DEG], [self.REC])
+        finally:
+            svc.close()
+
+    def test_one_probe_a_frame_after_the_timeout(self, manual_clock, strategy,
+                                                 threshold, at, past):
+        svc = self._tripped(manual_clock, strategy, threshold, past)
+        try:
+            self._at(manual_clock, 1010 + self.REC - 1)
+            assert self._ask(svc, 4) == ([DEG] * 4, [1] * 4)
+            self._at(manual_clock, 1010 + self.REC)
+            status, remaining = self._ask(svc, 64)
+            assert status == [int(TokenStatus.OK)] + [DEG] * 63
+            assert remaining[1:] == [self.REC] * 63
+        finally:
+            svc.close()
+
+    def test_a_sick_probe_rolls_back_and_a_healthy_one_closes(
+            self, manual_clock, strategy, threshold, at, past):
+        svc = self._tripped(manual_clock, strategy, threshold, past)
+        try:
+            t = 1010 + self.REC
+            self._at(manual_clock, t)
+            assert self._ask(svc)[0] == [int(TokenStatus.OK)]  # the probe
+            svc.report_outcomes([1], *_completions(1, 0, strategy))
+            self._at(manual_clock, t + 10)
+            assert self._ask(svc, 2) == ([DEG] * 2, [self.REC - 10] * 2)
+            t += self.REC
+            self._at(manual_clock, t)
+            assert self._ask(svc, 2)[0] == [int(TokenStatus.OK), DEG]
+            svc.report_outcomes([1], *_completions(0, 1, strategy))
+            self._at(manual_clock, t + 10)
+            assert self._ask(svc, 3)[0] == [int(TokenStatus.OK)] * 3
+        finally:
+            svc.close()
+
+    def test_the_fence_hides_what_was_reported_while_open(
+            self, manual_clock, strategy, threshold, at, past):
+        svc = self._tripped(manual_clock, strategy, threshold, past)
+        try:
+            # ten more bad completions three quarters through the timeout
+            self._at(manual_clock, 1010 + 3 * self.REC // 4)
+            svc.report_outcomes([1] * 10, *_completions(10, 0, strategy))
+            t = 1010 + self.REC + 50
+            self._at(manual_clock, t)
+            assert self._ask(svc)[0] == [int(TokenStatus.OK)]  # the probe
+            svc.report_outcomes([1], *_completions(0, 1, strategy))
+            # closed: the ten are inside the stat interval and before the
+            # fence, so they do not trip it again
+            self._at(manual_clock, t + 20)
+            assert self._ask(svc, 4)[0] == [int(TokenStatus.OK)] * 4
+        finally:
+            svc.close()

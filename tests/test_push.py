@@ -49,6 +49,16 @@ def _wait(predicate, what, timeout_s=3.0):
         time.sleep(0.02)
 
 
+def _split_pushes(data: bytes):
+    """The push frames of one write, each under its own length prefix."""
+    out, pos = [], 0
+    while pos < len(data):
+        n = int.from_bytes(data[pos:pos + 2], "big")
+        out.append(P.decode_push(data[pos + 2:pos + 2 + n]))
+        pos += 2 + n
+    return out
+
+
 class _Recorder:
     """A sink/hub stub that records everything and can be told to raise."""
 
@@ -85,6 +95,22 @@ class TestPushHub:
             P.MsgType.BREAKER_FLIP, FLOW, 1
         )
         assert push.stamp_ms > 0
+
+    def test_a_scan_s_flips_ride_one_write_per_sink(self):
+        hub = PushHub()
+        a, b = _Recorder(), _Recorder()
+        hub.attach("a", a.sink)
+        hub.attach("b", b.sink)
+        flips = [(FLOW, 1, 500), (FLOW + 1, 2, 0), (FLOW + 2, 0, 0)]
+        assert hub.push_breaker_flips(flips) == 2
+        assert len(a.frames) == len(b.frames) == 1  # one write each
+        got = [(p.flow_id, p.state, p.retry_after_ms, p.stamp_ms)
+               for p in _split_pushes(a.frames[0])]
+        assert [g[:3] for g in got] == flips
+        assert len({g[3] for g in got}) == 1  # one stamp for the scan
+        assert hub.stats()["sent"]["breaker_flip"] == 6  # frames, not writes
+        assert hub.push_breaker_flips([]) == 0
+        assert len(a.frames) == 1
 
     def test_raising_sink_drops_silently_and_counts(self):
         hub = PushHub()
@@ -158,6 +184,49 @@ class TestServiceEmitSites:
         revokes = [c for c in hub.calls if c[0] == "push_lease_revoke"]
         assert len(revokes) == 1
         assert revokes[0][1][0] == grant.lease_id
+
+    def test_breaker_scan_emits_its_edges_in_one_call(self, manual_clock):
+        from sentinel_tpu.engine.rules import DegradeRule, DegradeStrategy
+
+        manual_clock.set_ms(1_700_000_000_000)
+        svc = DefaultTokenService(CFG)
+        svc.load_rules([ClusterFlowRule(f, 1e9, G) for f in (1, 2, 3)])
+        svc.load_degrade_rules([
+            DegradeRule(f, DegradeStrategy.ERROR_COUNT, 5,
+                        recovery_timeout_ms=700 + f) for f in (1, 2)])
+        hub = _Recorder()
+        svc.attach_push_hub(hub)
+        bad = np.repeat([1, 2, 3], 8)  # flow 3 has no breaker
+        svc.report_outcomes(bad, np.full(24, 5), np.ones(24, bool))
+        manual_clock.advance(10)
+        svc.request_batch_arrays(np.array([1, 2, 3], np.int64),
+                                 np.ones(3, np.int32), np.zeros(3, bool))
+        svc._breaker_scan(force=True)
+        calls = [c for c in hub.calls if c[0].startswith("push_breaker")]
+        assert len(calls) == 1 and calls[0][0] == "push_breaker_flips"
+        # OPEN carries the rule's recovery timeout as the retry-after
+        assert sorted(calls[0][1][0]) == [(1, 1, 701), (2, 1, 702)]
+        svc._breaker_scan(force=True)  # nothing changed: nothing said
+        assert len([c for c in hub.calls
+                    if c[0].startswith("push_breaker")]) == 1
+
+    def test_shm_door_gives_each_frame_of_a_batch_its_own_slot(self):
+        from sentinel_tpu.native.lib import ShmDoor
+
+        sent = []
+
+        class Lib:
+            @staticmethod
+            def sn_shm_send(h, fd, gen, payload, n):
+                sent.append((fd, gen, bytes(payload[:n])))
+
+        door = object.__new__(ShmDoor)
+        door._lib, door._h = Lib, None
+        one = P.encode_push_breaker_flip(1, 111, 5, 1, 600)
+        two = P.encode_push_breaker_flip(2, 111, 6, 0, 0)
+        door.send(7, 3, one)
+        door.send(7, 3, one + two)
+        assert sent == [(7, 3, one[2:]), (7, 3, one[2:]), (7, 3, two[2:])]
 
     def test_emit_survives_a_raising_hub(self):
         svc = _service()

@@ -209,3 +209,13 @@ class TestScenarioArtifact:
     def test_phases_carry_wall_bounds(self, doc):
         for prev, cur in zip(doc["phases"], doc["phases"][1:]):
             assert prev["beginMs"] < prev["endMs"] <= cur["beginMs"] + 1000
+
+    def test_the_run_leaves_the_process_its_default_objective(self, doc):
+        """``run_scenario`` sets the p99 objective for its own SLO plane;
+        left set, every later SLO plane of the process (the next test file
+        on this worker) would read the scenario's 150 ms for the default."""
+        from sentinel_tpu.core.config import SentinelConfig
+        from sentinel_tpu.trace.slo import KEY_OBJECTIVE_MS
+
+        assert doc["objectiveMs"] == 150.0
+        assert SentinelConfig.get(KEY_OBJECTIVE_MS) is None

@@ -28,7 +28,7 @@ from sentinel_tpu.engine import (  # noqa: E402
     ClusterFlowRule, EngineConfig, build_rule_table, decide, make_batch,
     make_state, unpack_verdicts)
 from sentinel_tpu.engine.decide import (  # noqa: E402
-    ARM_ALL, ARM_LIVE, ARM_OCCUPY, ARM_PACED_ROWS, ARM_PACING,
+    ARM_ALL, ARM_FIELDS, ARM_LIVE, ARM_OCCUPY, ARM_PACED_ROWS, ARM_PACING,
     ARM_PRIORITIZED_ROWS, ARM_SHAPED_ROWS, ARM_SHAPING, ROW_HEAD, HEAD_NOW,
     alloc_packed_block, decide_fused_donating, pack_requests_into,
     unpack_arms)
@@ -172,7 +172,7 @@ def test_a_step_of_default_rows_says_no_arm_ran():
     block[ROW_HEAD, 0, HEAD_NOW] = 1_000
     _state, packed = decide_fused_donating(CFG, 2)(
         make_state(CFG), table, block)
-    assert unpack_arms(np.asarray(packed), 2).tolist() == [0, 0, 0, 0]
+    assert unpack_arms(np.asarray(packed), 2).tolist() == [0] * ARM_FIELDS
     status, _wait, _rem = unpack_verdicts(packed)
     # the arms ride above the status of a frame's first entries: the
     # statuses come out as they were

@@ -47,8 +47,10 @@ from sentinel_tpu import chaos
 from sentinel_tpu.cluster import protocol as P
 from sentinel_tpu.cluster.connection import ConnectionManager
 from sentinel_tpu.cluster.token_service import (
+    Materializer,
     TokenService,
     decide_param_requests,
+    halves,
 )
 from sentinel_tpu.core.log import record_log
 from sentinel_tpu.engine import TokenStatus
@@ -816,12 +818,15 @@ class NativeTokenServer:
         dispatch (``third`` the priorities) or a param dispatch (``third``
         the value hashes ``[n, k]``).
 
-        Returns ``(mat, release, overlapped)``: ``mat`` materializes the
-        verdicts and releases the permit (exactly once, even if the
-        materialize raises); ``release`` is the idempotent escape hatch
-        for paths that never call ``mat`` (dispatch exception handled by
-        the caller, abandoned-shutdown drop). ``overlapped`` reports
-        whether the permit wait found earlier work still in flight."""
+        Returns ``(mat, release, overlapped)``: ``mat`` is a
+        :class:`Materializer` whose read half reads the verdicts and gives
+        the permit back (exactly once, even if the read raises) and whose
+        account half is the dispatch's own, if it has one: the permit
+        bounds device work in flight and does not wait on counters.
+        ``release`` is the idempotent escape hatch for paths that never
+        read (dispatch exception handled by the caller, abandoned-shutdown
+        drop). ``overlapped`` reports whether the permit wait found earlier
+        work still in flight."""
         t0 = time.monotonic_ns()
         overlapped = self._acquire_device_permit()
         waited_ns = time.monotonic_ns() - t0
@@ -841,13 +846,15 @@ class NativeTokenServer:
             release()
             raise
 
-        def mat():
+        read, account = halves(inner)
+
+        def read_and_release():
             try:
-                return inner()
+                return read(), account
             finally:
                 release()
 
-        return mat, release, overlapped
+        return Materializer(read_and_release), release, overlapped
 
     def _device_loop(self) -> None:
         """Lane 2: the only thread issuing device work — dispatch order IS
@@ -1076,18 +1083,22 @@ class NativeTokenServer:
                             # scatter the dispatched slice back into full-
                             # width arrays so the reply lane's per-pull
                             # offsets stay valid
-                            def mat(
+                            def scatter(
                                 inner=inner, keep=keep, n=n_rows, hint=hint
                             ):
                                 status = np.full(n, _OVERLOAD, np.int8)
                                 remaining = np.zeros(n, np.int32)
                                 wait = np.full(n, hint, np.int32)
+                                account = None
                                 if inner is not None:
-                                    st, rm, wt = inner()
+                                    read, account = halves(inner)
+                                    st, rm, wt = read()
                                     status[keep] = st
                                     remaining[keep] = rm
                                     wait[keep] = wt
-                                return status, remaining, wait
+                                return (status, remaining, wait), account
+
+                            mat = Materializer(scatter)
                 except Exception:
                     record_log.exception("device step failed; failing batch")
                     if permit_rel is not None:
@@ -1129,15 +1140,28 @@ class NativeTokenServer:
 
     def _reply_loop(self) -> None:
         """Lane 3 (×``n_dispatchers``): block on the async verdicts, slice
-        them back per intake pull, submit to each pull's owning door. While
-        one reply thread waits on device results the device lane keeps
-        dispatching, and a second reply thread overlaps the next group's
-        encode. Consecutive pulls from the same door collapse into one
-        ``submit_many`` call — one outbox lock and one IO wakeup per run,
-        with the C++ scatter encode grouping same-connection frames across
-        pull boundaries. Once the verdicts are submitted (``sn_fd_submit``
-        copies synchronously) the pulls' staging blocks go back to the
-        intake pool."""
+        them back per intake pull, submit to each pull's owning door, and
+        only then count them. While one reply thread waits on device
+        results the device lane keeps dispatching, and a second reply
+        thread overlaps the next group's encode. Consecutive pulls from the
+        same door collapse into one ``submit_many`` call — one outbox lock
+        and one IO wakeup per run, with the C++ scatter encode grouping
+        same-connection frames across pull boundaries. Once the verdicts
+        are submitted (``sn_fd_submit`` copies synchronously) the pulls'
+        staging blocks go back to the intake pool.
+
+        Answer first, count after: per item the order is the
+        materializer's read half (``decide_ms``; the device permit comes
+        back at its end) -> ``submit_many`` per door run ->
+        ``overload.note_done`` -> ``write_ms`` -> staging release -> the
+        account half (``account_ms``, ``reply_first_total``). The account
+        half reads the verdict arrays and the slots the dispatch resolved,
+        none of which aliases a staging block. It runs here, on the lane
+        that read, in the order the lane took its items: every dispatch
+        that was read is counted once, a submit that raised or a stop
+        notwithstanding, and one whose read raised never. Counters are
+        complete once the lanes have drained (``stop()``); right after a
+        reply they may lack that reply's dispatch."""
         rq = self._reply_q
         while True:
             item = rq.get()
@@ -1148,9 +1172,11 @@ class NativeTokenServer:
             _SM.reply_queue_wait_ms.record(
                 (time.monotonic_ns() - t_put) * 1e-6
             )
+            # a foreign service's materializer has no account half
+            read, account = halves(mat)
             t0 = time.perf_counter()
             try:
-                status, remaining, wait = mat()
+                status, remaining, wait = read()
             except Exception:
                 record_log.exception("materialize failed; failing batch")
                 n = sum(lengths)
@@ -1199,6 +1225,11 @@ class NativeTokenServer:
             if pool is not None:
                 for p in pulls:
                     pool.release(p[6])
+            if account is not None:
+                try:
+                    account(True)
+                except Exception:
+                    record_log.exception("accounting failed after the reply")
 
     # -- control plane ------------------------------------------------------
     def _control_loop(self) -> None:

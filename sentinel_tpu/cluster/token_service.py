@@ -233,6 +233,48 @@ def decide_param_requests(service, requests, fail_status: int):
     return out
 
 
+class Materializer:
+    """What a dispatch hands back: the zero-arg callable that blocks on the
+    device and yields ``(status, remaining, wait)`` in request order, made
+    of two halves. :meth:`read` ends with the verdicts in host hands;
+    :meth:`account` then counts them (``DefaultTokenService._account``).
+    Calling the object is the two one after the other, so whoever calls it
+    whole sees counters that are complete when the call returns. The native
+    reply lane runs ``read()``, submits the reply, and only then
+    ``account(replied=True)``: nothing the accounting computes is in a reply.
+
+    ``read`` is the dispatch's own closure and returns ``(verdicts,
+    account)``: ``account`` takes ``replied``, or is None where there is
+    nothing to count. A dispatch is accounted at most once, and never when
+    its read raised."""
+
+    def __init__(self, read):
+        self._read = read
+        self._pending = None
+
+    def read(self):
+        verdicts, self._pending = self._read()
+        return verdicts
+
+    def account(self, replied: bool = False) -> None:
+        pending, self._pending = self._pending, None
+        if pending is not None:
+            pending(replied)
+
+    def __call__(self):
+        verdicts = self.read()
+        self.account()
+        return verdicts
+
+
+def halves(mat):
+    """``(read, account)`` of any materializer. A plain callable (a foreign
+    service's, a wrapper's) is read whole and has no account half."""
+    if isinstance(mat, Materializer):
+        return mat.read, mat.account
+    return mat, None
+
+
 class TokenService:
     """The SPI: local flow checkers and the transport both speak this."""
 
@@ -1131,8 +1173,9 @@ class DefaultTokenService(TokenService):
         prios: Optional[np.ndarray] = None,
     ):
         """Serving hot path, phase 1: host prep + device dispatch. Returns a
-        zero-arg **materializer** that blocks on the async dispatch and
-        yields ``(status, remaining, wait)`` in request order.
+        zero-arg **materializer** (a :class:`Materializer`: read half, then
+        account half) that blocks on the async dispatch and yields
+        ``(status, remaining, wait)`` in request order.
 
         The service lock covers ONLY the device dispatch + state swap — host
         prep (slot lookup, grouping sort, batch padding) runs before it and
@@ -1234,7 +1277,7 @@ class DefaultTokenService(TokenService):
         verdicts.copy_to_host_async()
         self._dispatched(t_enter, t_prep, t_locked, seq, n)
 
-        def _materialize():
+        def _read():
             # blocks on the async dispatch; runs outside the lock
             t_mat = time.monotonic_ns()
             t_ready, host = self._read_verdicts(verdicts)
@@ -1250,13 +1293,12 @@ class DefaultTokenService(TokenService):
                 ha_metrics().count_rebalance_redirects(
                     int(moved_mask.sum())
                 )
-            self._account(
-                status, wait, slots_ns, seq, n, t_enter, t_mat, t_ready,
-                arms=arms,
+            return (status, remaining, wait), partial(
+                self._account, status, wait, slots_ns, seq, n, t_enter,
+                t_mat, t_ready, time.monotonic_ns(), arms=arms,
             )
-            return status, remaining, wait
 
-        return _materialize
+        return Materializer(_read)
 
     @staticmethod
     def _read_verdicts(packed):
@@ -1288,16 +1330,24 @@ class DefaultTokenService(TokenService):
 
     def _account(
         self, status, wait, slots_ns, seq, rows, t_enter, t_mat, t_ready,
-        lane: int = 0, arms=None,
+        t_fetched, replied: bool = False, lane: int = 0, arms=None,
     ) -> None:
-        """The accounting tail of a materializer, and its three phases.
+        """The account half of a :class:`Materializer`, and the record of
+        its three phases. Nothing here is in a reply: whoever calls the
+        materializer whole runs it before the call returns, the native
+        reply lane after the dispatch's reply was submitted (``replied``;
+        counted in ``reply_first_total``), so there a reader of the
+        counters may assume nothing until the lane has counted.
         ``slots_ns`` is request-order and PRE-mask, so MOVED verdicts land
         on their namespace (a fused span passes its frames' slots as a
-        list, a param dispatch None); ``t_enter``/``t_mat``/``t_ready`` are the
-        ``monotonic_ns`` stamps of the dispatch's entry, the materializer's
-        entry and the verdict buffer reaching the host. ``arms`` is what a
-        flow dispatch's step said of its cond-gated arms (``unpack_arms``)."""
-        t_fetched = time.monotonic_ns()
+        list, a param dispatch None); ``t_enter``/``t_mat``/``t_ready``/
+        ``t_fetched`` are the ``monotonic_ns`` stamps of the dispatch's
+        entry, the read half's entry, the verdict buffer reaching the host
+        and the read half's end: the decision latency the SLO plane gets
+        ends there, and ``account_ms`` counts from this call's own start.
+        ``arms`` is what a flow dispatch's step said of its cond-gated arms
+        (``unpack_arms``)."""
+        t_account = time.monotonic_ns()
         if isinstance(slots_ns, list):
             slots_ns = np.concatenate(slots_ns)
         # per-namespace verdict counters (sentinel_server_verdicts_total):
@@ -1312,7 +1362,7 @@ class DefaultTokenService(TokenService):
             )
         _SM.record_verdict_batch(
             status, ns_idx, ns_names,
-            latency_ms=(time.monotonic_ns() - t_enter) * 1e-6,
+            latency_ms=(t_fetched - t_enter) * 1e-6,
             wait_ms=wait,
         )
         live = 0
@@ -1330,6 +1380,7 @@ class DefaultTokenService(TokenService):
             sid, aux = self._trace_sid, seq & 0x7FFFFFFF
             _TR.record(_TR.READY, shard=sid, aux=aux, t_ns=t_ready)
             _TR.record(_TR.FETCHED, shard=sid, aux=aux, t_ns=t_fetched)
+            _TR.record(_TR.ACCOUNT, shard=sid, aux=aux, t_ns=t_account)
             _TR.record(_TR.DEVICE_OUT, aux=rows,
                        shard=lane | live << _TR.ARM_SHIFT)
         # cluster server stat log (ClusterServerStatLogUtil analog): one
@@ -1353,7 +1404,9 @@ class DefaultTokenService(TokenService):
             self._breaker_scan()
         _SM.device_wait_ms.record((t_ready - t_mat) * 1e-6)
         _SM.fetch_ms.record((t_fetched - t_ready) * 1e-6)
-        _SM.account_ms.record((time.monotonic_ns() - t_fetched) * 1e-6)
+        _SM.account_ms.record((time.monotonic_ns() - t_account) * 1e-6)
+        if replied:
+            _SM.count_reply_first()
 
     def _dispatch_oversized(self, flow_ids, acq, pr, n, cap):
         """Split an oversized burst into ``cap``-sized frames and fold runs
@@ -1391,11 +1444,16 @@ class DefaultTokenService(TokenService):
                 )
             )
 
-        def _concat():
-            parts = [m() for m in mats]
-            return tuple(np.concatenate(ps) for ps in zip(*parts))
+        def _read():
+            parts = [m.read() for m in mats]
 
-        return _concat
+            def account(replied):
+                for m in mats:
+                    m.account(replied)
+
+            return tuple(np.concatenate(ps) for ps in zip(*parts)), account
+
+        return Materializer(_read)
 
     def _dispatch_fused(self, flow_ids, acq, pr, depth, cap):
         """Phase-1 dispatch of ``depth`` consecutive full-``cap`` frames as
@@ -1500,7 +1558,7 @@ class DefaultTokenService(TokenService):
         if _TR.ARMED:  # flight recorder: fused group submitted
             _TR.record(_TR.FUSE, aux=depth)
 
-        def _materialize():
+        def _read():
             # blocks on the async dispatch; runs outside the lock. The
             # buffer is [3, depth, cap] with the frames already contiguous
             # along the span, so the per-frame grouping sorts are undone as
@@ -1533,14 +1591,14 @@ class DefaultTokenService(TokenService):
             # per-namespace verdict counters + cluster stat log, once for
             # the whole span; span_ns is the PRE-mask slot span when a move
             # masked rows
-            self._account(
-                status, wait,
+            return (status, remaining, wait), partial(
+                self._account, status, wait,
                 span_ns if span_ns is not None else [p[0] for p in preps],
-                seq, total, t_enter, t_mat, t_ready, arms=arms,
+                seq, total, t_enter, t_mat, t_ready, time.monotonic_ns(),
+                arms=arms,
             )
-            return status, remaining, wait
 
-        return _materialize
+        return Materializer(_read)
 
     def request_batch(self, requests) -> List[TokenResult]:
         if not requests:
@@ -1788,7 +1846,8 @@ class DefaultTokenService(TokenService):
         """The hot-parameter serving path, phase 1: host prep + device
         dispatch of ``n`` requests of ``k`` values (``hashes int64[n, k]``).
         Returns a zero-arg **materializer** like
-        :meth:`dispatch_batch_arrays`, with the same phases and histograms.
+        :meth:`dispatch_batch_arrays`, with the same halves, phases and
+        histograms.
 
         Semantics are :meth:`request_params_token`'s, for every request: all
         values of a request are judged together, any blocked value blocks
@@ -1859,7 +1918,7 @@ class DefaultTokenService(TokenService):
         self._dispatched(t_enter, t_prep, t_locked, seq, n * k,
                          lane=_TR.PARAM_LANE)
 
-        def _materialize():
+        def _read():
             t_mat = time.monotonic_ns()
             parts = []
             for verdicts, (_b, m, _p) in zip(outs, packs):
@@ -1871,11 +1930,12 @@ class DefaultTokenService(TokenService):
                 n, n * k, int((status == int(TokenStatus.BLOCKED)).sum()),
                 int((req_slot < 0).sum()),
             )
-            self._account(status, wait, None, seq, n * k, t_enter, t_mat,
-                          t_ready, lane=_TR.PARAM_LANE)
-            return status, remaining, wait
+            return (status, remaining, wait), partial(
+                self._account, status, wait, None, seq, n * k, t_enter,
+                t_mat, t_ready, time.monotonic_ns(), lane=_TR.PARAM_LANE,
+            )
 
-        return _materialize
+        return Materializer(_read)
 
     # -- concurrent (semaphore) mode ----------------------------------------
     def load_concurrent_rules(self, rules) -> None:

@@ -99,8 +99,11 @@ class ServerMetrics:
     # phase histograms inside one dispatch: (member, what it covers). Always
     # on, one record per phase per dispatch, never per row. Over any window
     # permit_wait + prep + lock_wait + launch reconciles with dispatch_ms's
-    # sum and device_wait + fetch + account with decide_ms's. The first four
-    # are timed on the dispatching thread, the rest on the materializing one.
+    # sum. decide_ms's sum reconciles with device_wait + fetch on the native
+    # lane (it times the read half there: account runs after the reply) and
+    # holds account too where the materializer is called whole. The first
+    # four are timed on the dispatching thread, the rest on the
+    # materializing one.
     # (compile_ms is no phase; it rides the same four loops below.)
     _PHASES = (
         ("permit_wait_ms",
@@ -127,7 +130,8 @@ class ServerMetrics:
          "overlay; no copy from the device (ms)."),
         ("account_ms",
          "Always-on accounting per dispatch: namespace attribution, verdict "
-         "counters, SLO plane, timeline, stat log, breaker scan (ms)."),
+         "counters, SLO plane, timeline, stat log, breaker scan; on the "
+         "native lane after the reply was submitted (ms)."),
         ("compile_ms",
          "Backend compiles (persistent-cache hits included), each (ms)."),
         # the outcome path, one record per completion report
@@ -264,6 +268,8 @@ class ServerMetrics:
         # many found the device already done with the verdict buffer
         self._verdict_host_reads = 0
         self._verdict_copy_ready = 0
+        # dispatches whose account half ran after their reply was submitted
+        self._reply_first = 0
         self._verdict_read_lock = threading.Lock()
         # traffic-shaping waits: every SHOULD_WAIT verdict that carried a
         # positive wait hint (paced admission or priority occupy) — count
@@ -370,6 +376,18 @@ class ServerMetrics:
     def verdict_host_reads_total(self) -> int:
         with self._verdict_read_lock:
             return self._verdict_host_reads
+
+    def count_reply_first(self) -> None:
+        """One dispatch was accounted after its reply had been submitted:
+        the native reply lane ran the materializer's two halves apart.
+        Whoever calls a materializer whole never counts here."""
+        with self._verdict_read_lock:
+            self._reply_first += 1
+
+    @property
+    def reply_first_total(self) -> int:
+        with self._verdict_read_lock:
+            return self._reply_first
 
     def count_param_dispatch(self, requests: int, values: int, blocked: int,
                              no_rule: int) -> None:
@@ -915,6 +933,7 @@ class ServerMetrics:
             "compilesAfterWarmupTotal": self.compiles_after_warmup_total,
             "verdictHostReadsTotal": self.verdict_host_reads_total,
             "verdictCopyReadyTotal": self.verdict_copy_ready_total,
+            "replyFirstTotal": self.reply_first_total,
             "shedTotal": self.shed_total,
             "shedByReason": self.shed_totals(),
             "hostCopyBytesTotal": self.host_copy_bytes_total,
@@ -987,6 +1006,7 @@ class ServerMetrics:
         out["verdict_copy_ready_total"] = self.verdict_copy_ready_total
         out.update(self.param_totals())
         out.update(self.arm_totals())
+        out["reply_first_total"] = self.reply_first_total
         out["param_impl"], out["param_impl_reason"] = self.param_impl
         out["shed_total"] = self.shed_totals()
         out["host_copy_bytes_total"] = self.host_copy_bytes_total
@@ -1334,8 +1354,9 @@ class ServerMetrics:
              "Enqueue-to-batch-drain wait per queue item (ms).",
              self.queue_wait_ms),
             ("sentinel_server_decide_ms",
-             "Reply lane's whole materialize call per batch: wait for the "
-             "device, copies, unsort and the always-on accounting (ms).",
+             "Materialize per batch: wait for the device, unpack, unsort. "
+             "The native reply lane stops the clock there (account_ms runs "
+             "after the reply); the asyncio door's holds account_ms too (ms).",
              self.decide_ms),
             ("sentinel_server_write_ms",
              "Host write-out per batch: verdict encode + socket write (ms).",
@@ -1379,6 +1400,10 @@ class ServerMetrics:
               for name, value in self.param_totals().items()),
             *((name, self._ARM_COUNTERS[name], value)
               for name, value in self.arm_totals().items()),
+            ("reply_first_total",
+             "Dispatches accounted after their reply was submitted: the "
+             "native reply lane answers first and counts after "
+             "(cumulative).", self.reply_first_total),
         ):
             lines.append(f"# HELP sentinel_server_{name} {help_text}")
             lines.append(f"# TYPE sentinel_server_{name} counter")
@@ -1429,6 +1454,7 @@ class ServerMetrics:
         with self._verdict_read_lock:
             self._verdict_host_reads = 0
             self._verdict_copy_ready = 0
+            self._reply_first = 0
         with self._param_lock:
             self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
         with self._arm_lock:

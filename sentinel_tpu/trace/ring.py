@@ -44,7 +44,9 @@ DEVICE_IN = 4    # device step submitted, service lock released (aggregate,
 #                  dispatch, whose rows are its (request, value) rows)
 DEVICE_OUT = 5   # verdicts on the host AND counted (record_verdict_batch done;
 #                  the stat-log passes follow) (aggregate, xid=0; aux = rows;
-#                  shard = lane | the step's live arm bits << ARM_SHIFT)
+#                  shard = lane | the step's live arm bits << ARM_SHIFT). On
+#                  the native lane the counting runs after the reply was
+#                  submitted, so there it FOLLOWS the dispatch's REPLY_OUT
 REPLY_OUT = 6    # frame's reply encoded + submitted to its door (aux = rows)
 SHED = 7         # frame/rows refused (aux = shed-reason index)
 FUSE = 8         # fusion ladder stacked frames (aggregate; aux = depth)
@@ -69,12 +71,15 @@ ARM_SHIFT = 4
 # sequence number (taken under the service lock), so one dispatch's
 # boundaries join across the dispatching thread's ring and the
 # materializing thread's (``spans.dispatch_phases``). In time order:
-# PERMIT, PREP, LOCKED, DEVICE_IN | READY, FETCHED, DEVICE_OUT.
+# PERMIT, PREP, LOCKED, DEVICE_IN | READY, FETCHED, ACCOUNT, DEVICE_OUT
+# (the last four are written by the account half with their own stamps; on
+# the native lane the reply goes out between FETCHED and ACCOUNT).
 PERMIT = 17      # device permit acquired (native lane only; aux = wait, us)
 PREP = 18        # host prep done, about to ask for the service lock
 LOCKED = 19      # service lock acquired
 READY = 20       # the verdict buffer on the host (step and copy finished)
 FETCHED = 21     # request-order verdict arrays built (unpack, unsort, MOVED)
+ACCOUNT = 24     # the account half began (its end is DEVICE_OUT)
 COMPILE = 22     # a backend compile ended (aux = ms)
 OUTCOME_IN = 23  # a completion report reached the server: t_ns is when its
 #                  door queued it, or the in-process call began (xid = the
@@ -105,6 +110,7 @@ STAGE_NAMES: Dict[int, str] = {
     FETCHED: "fetched",
     COMPILE: "compile",
     OUTCOME_IN: "outcome_in",
+    ACCOUNT: "account",
 }
 
 # one ring row: 24 bytes, fixed

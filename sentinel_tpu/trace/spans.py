@@ -75,7 +75,7 @@ def completeness(spans: List[dict]) -> dict:
 # dispatch-side boundaries land in the dispatching thread's ring, the rest
 # in the materializing thread's; (service id, sequence number) joins them
 _PHASE_STAGES = {_R.PERMIT, _R.PREP, _R.LOCKED, _R.DEVICE_IN, _R.READY,
-                 _R.FETCHED, _R.DEVICE_OUT}
+                 _R.FETCHED, _R.ACCOUNT, _R.DEVICE_OUT}
 
 
 def dispatch_phases(since_ns: Optional[int] = None,
@@ -87,11 +87,13 @@ def dispatch_phases(since_ns: Optional[int] = None,
     ``permitWaitMs`` (the ``permit`` event's own aux), ``lockWaitMs``,
     ``launchMs`` (lock held, to ``device_in``), ``waitMs`` (``device_in`` →
     the verdict buffer on the host: reply-queue wait plus what was left of
-    the device step), ``fetchMs``, ``accountMs`` (to ``device_out``: the
-    verdict counters; the stat-log passes after it are in the always-on
-    ``account_ms`` only). ``complete`` when every boundary from ``prep`` to
-    ``device_out`` was found; a wrapped ring or a dispatch still in flight
-    leaves the missing phases None."""
+    the device step), ``fetchMs``, ``accountMs`` (``account``, the account
+    half's own start, to ``device_out``: the verdict counters; the stat-log
+    passes after it are in the always-on ``account_ms`` only; the reply the
+    native lane submits between ``fetched`` and ``account`` is in neither).
+    ``complete`` when every boundary from ``prep`` to ``device_out`` was
+    found; a wrapped ring or a dispatch still in flight leaves the missing
+    phases None."""
     by_thread: dict = {}
     for e in _R.events(since_ns=since_ns, stages=_PHASE_STAGES):
         by_thread.setdefault(e["thread"], []).append(e)
@@ -118,7 +120,7 @@ def dispatch_phases(since_ns: Optional[int] = None,
                     permit = None
             elif cur is not None and (e["shard"], e["aux"]) == (
                     cur["service"], cur["seq"]):
-                cur[st] = e["t_ns"]  # locked, fetched
+                cur[st] = e["t_ns"]  # locked, fetched, account
             elif cur is not None and st == "device_in" and "locked" in cur:
                 cur.setdefault("device_in", e["t_ns"])
                 cur["rows"] = e["aux"]
@@ -138,7 +140,7 @@ def dispatch_phases(since_ns: Optional[int] = None,
             "launchMs": ms(g("locked"), g("device_in")),
             "waitMs": ms(g("device_in"), g("ready")),
             "fetchMs": ms(g("ready"), g("fetched")),
-            "accountMs": ms(g("fetched"), g("device_out")),
+            "accountMs": ms(g("account"), g("device_out")),
             "complete": all(k in d for k in (
                 "prep", "locked", "device_in", "ready", "fetched",
                 "device_out")),

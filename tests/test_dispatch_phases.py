@@ -9,6 +9,7 @@ import logging
 import os
 import re
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -16,14 +17,21 @@ import numpy as np
 import pytest
 
 from sentinel_tpu.cluster.client import TokenClient
+from sentinel_tpu.cluster.server import TokenServer
 from sentinel_tpu.cluster.server_native import (
     NativeTokenServer,
     native_available,
 )
-from sentinel_tpu.cluster.token_service import DefaultTokenService
+from sentinel_tpu.cluster.token_service import (
+    ClusterParamFlowRule,
+    DefaultTokenService,
+    Materializer,
+    halves,
+)
 from sentinel_tpu.core.log import record_log
 from sentinel_tpu.engine import ClusterFlowRule, EngineConfig, pack_requests
 from sentinel_tpu.engine import make_state
+from sentinel_tpu.engine.param import ParamConfig
 from sentinel_tpu.engine.rules import ThresholdMode
 from sentinel_tpu.metrics.profiler import ProfilerHook
 from sentinel_tpu.metrics.server import server_metrics
@@ -41,7 +49,8 @@ SM = server_metrics()
 SERVICE_PHASES = ("prep_ms", "lock_wait_ms", "launch_ms", "device_wait_ms",
                   "fetch_ms", "account_ms")
 DISPATCH_SIDE = ("permit_wait_ms", "prep_ms", "lock_wait_ms", "launch_ms")
-DECIDE_SIDE = ("device_wait_ms", "fetch_ms", "account_ms")
+READ_SIDE = ("device_wait_ms", "fetch_ms")
+DECIDE_SIDE = READ_SIDE + ("account_ms",)
 LANE_DISPATCHES = 200
 ARMED_DISPATCHES = 50
 
@@ -64,6 +73,20 @@ def _counts():
 
 def _sums():
     return {k: v["sum"] for k, v in SM.snapshot()["stages"].items()}
+
+
+def _settled(read, want, timeout=10.0):
+    """The one bounded wait of a reader that follows a native-lane reply:
+    the reply lane answers first and counts after, so poll ``read()`` until
+    it gives ``want``. Returns the last reading either way."""
+    deadline = time.monotonic() + timeout
+    while (got := read()) != want and time.monotonic() < deadline:
+        time.sleep(0.002)
+    return got
+
+
+def _accounted():
+    return SM.account_ms.snapshot()["count"]
 
 
 # -- (a) one record per phase per dispatch ------------------------------------
@@ -120,20 +143,27 @@ def lane_run():
     run = {}
     try:
         def drive(n):
+            # the lane counts a dispatch after its reply: wait for each, so
+            # no account half runs beside the next dispatch and slows the
+            # lane's glue between the stamps (one GIL)
+            base = _accounted()
             for i in range(n):
                 out = client.request_batch_arrays(_ids(40, seed=i))
                 assert out is not None
+                assert _settled(_accounted, base + i + 1) == base + i + 1
 
         drive(1)  # settle
-        c0, s0 = _counts(), _sums()
+        c0, s0, f0 = _counts(), _sums(), SM.reply_first_total
         drive(LANE_DISPATCHES)  # disarmed: what the histograms cost alone
         c1, s1 = _counts(), _sums()
         run["counts"] = {k: c1[k] - c0[k] for k in c1}
+        run["counts"]["reply_first_total"] = SM.reply_first_total - f0
         run["sums"] = {k: s1[k] - s0[k] for k in s1}
         run["events_disarmed"] = ring.events()
         ring.arm(sample=1.0)
         drive(ARMED_DISPATCHES)
         run["phases"] = spans.dispatch_phases()
+        run["events"] = ring.events()
         run["threads"] = {e["thread"] for e in ring.events()}
     finally:
         client.close()
@@ -152,15 +182,62 @@ def test_every_phase_records_once_per_lane_dispatch(lane_run, phase):
     assert lane_run["counts"][phase] == LANE_DISPATCHES
 
 
-@pytest.mark.parametrize("whole,parts", [("dispatch_ms", DISPATCH_SIDE),
-                                         ("decide_ms", DECIDE_SIDE)])
-def test_the_phases_reconcile_with_the_stage_they_split(lane_run, whole,
+@pytest.fixture(scope="module")
+def whole_run():
+    """Off the lane: the asyncio door calls the materializer whole, inside
+    the ``decide_ms`` it starts before the dispatch."""
+    svc = _service()
+    server = TokenServer(svc, port=0, batch_window_ms=0.0)
+    server.start()
+    client = TokenClient("127.0.0.1", server.port, timeout_ms=5000)
+    try:
+        assert client.request_batch_arrays(_ids(40)) is not None  # settle
+        s0, f0 = _sums(), SM.reply_first_total
+        for i in range(LANE_DISPATCHES):
+            assert client.request_batch_arrays(_ids(40, seed=i)) is not None
+        s1 = _sums()
+    finally:
+        client.close()
+        server.stop()
+        svc.close()
+    return {"sums": {k: s1[k] - s0[k] for k in s1},
+            "reply_first": SM.reply_first_total - f0}
+
+
+# on the lane decide_ms is the read half (the accounting follows the reply);
+# a door that calls the materializer whole holds all three, and the dispatch
+@pytest.mark.parametrize("run,whole,parts", [
+    ("lane", "dispatch_ms", DISPATCH_SIDE),
+    ("lane", "decide_ms", READ_SIDE),
+    ("whole", "decide_ms", DISPATCH_SIDE[1:] + DECIDE_SIDE),
+])
+def test_the_phases_reconcile_with_the_stage_they_split(request, run, whole,
                                                         parts):
-    total = lane_run["sums"][whole]
-    split = sum(lane_run["sums"][p] for p in parts)
+    sums = request.getfixturevalue(run + "_run")["sums"]
+    total = sums[whole]
+    split = sum(sums[p] for p in parts)
     assert total > 0
     assert abs(split - total) <= 0.10 * total, (whole, total, {
-        p: lane_run["sums"][p] for p in parts})
+        p: sums[p] for p in parts})
+
+
+def test_the_lane_counts_every_dispatch_after_its_reply(lane_run, whole_run):
+    assert lane_run["counts"]["reply_first_total"] == LANE_DISPATCHES
+    assert whole_run["reply_first"] == 0  # counted before the door's write
+
+
+def test_device_out_follows_reply_out_and_account_ms_is_the_accounting_alone(
+        lane_run):
+    """In time order on each reply lane: a dispatch's ``fetched``, its
+    frame's ``reply_out``, its ``account`` and its ``device_out``."""
+    by_lane = {}
+    for e in lane_run["events"]:
+        if e["stage"] in ("fetched", "reply_out", "account", "device_out"):
+            by_lane.setdefault(e["thread"], []).append(e["stage"])
+    assert sum(map(len, by_lane.values())) == 4 * ARMED_DISPATCHES
+    for stages in by_lane.values():
+        assert stages == ["fetched", "reply_out", "account",
+                          "device_out"] * (len(stages) // 4)
 
 
 def test_armed_rings_yield_complete_chains_joined_across_threads(lane_run):
@@ -181,6 +258,223 @@ def test_armed_rings_yield_complete_chains_joined_across_threads(lane_run):
 
 def test_disarmed_rings_record_nothing(lane_run):
     assert lane_run["events_disarmed"] == []
+
+
+# -- answer first, count after (PR 35) ----------------------------------------
+SLOW_S = 0.2  # what the slowed account half sleeps
+PCFG = ParamConfig(max_param_rules=8, depth=4, width=512)
+
+
+def _slow_account(monkeypatch, seconds=SLOW_S):
+    real = DefaultTokenService._account
+
+    def slow(self, *a, **kw):
+        time.sleep(seconds)
+        return real(self, *a, **kw)
+
+    monkeypatch.setattr(DefaultTokenService, "_account", slow)
+
+
+def _counted():
+    """Everything one flow dispatch counts, by what counts it."""
+    arms = SM.arm_totals()
+    return {"account_ms": _accounted(),
+            "dispatches": arms["decide_dispatch_total"],
+            "rows": arms["decide_rows_total"],
+            "verdicts": sum(v["count"] for v in SM.snapshot()["verdicts"]),
+            "reads": SM.verdict_host_reads_total,
+            "reply_first": SM.reply_first_total}
+
+
+def _grew(before):
+    after = _counted()
+    return {k: after[k] - before[k] for k in after}
+
+
+@pytest.fixture
+def lane():
+    if not native_available():
+        pytest.skip("native library not built")
+    svc = _service()
+    server = NativeTokenServer(svc, port=0, idle_ttl_s=None)
+    server.start()
+    client = TokenClient("127.0.0.1", server.port, timeout_ms=5000)
+    base = _accounted()
+    assert client.request_batch_arrays(_ids(40)) is not None  # compiled
+    assert _settled(_accounted, base + 1) == base + 1
+    yield server, client
+    client.close()
+    server.stop()  # a second stop() is a no-op
+    svc.close()
+
+
+def test_a_reply_does_not_wait_for_a_slow_account_half(lane, monkeypatch):
+    _server, client = lane
+    _slow_account(monkeypatch)
+    took = []
+    for i in range(3):
+        before = _counted()
+        t0 = time.perf_counter()
+        assert client.request_batch_arrays(_ids(40, seed=i)) is not None
+        took.append(time.perf_counter() - t0)
+        # the reply is here and its dispatch is not counted yet ...
+        assert _grew(before)["account_ms"] == 0
+        # ... and after a bounded settle it is, whole
+        assert _settled(_accounted, before["account_ms"] + 1) == (
+            before["account_ms"] + 1)
+        assert _grew(before) == {"account_ms": 1, "dispatches": 1, "rows": 40,
+                                 "verdicts": 40, "reads": 1, "reply_first": 1}
+    assert min(took) < SLOW_S / 4, took
+
+
+@pytest.mark.parametrize("entry", ["request_batch_arrays",
+                                   "request_params_batch"])
+def test_a_synchronous_caller_has_counted_before_it_returns(entry,
+                                                            monkeypatch):
+    _slow_account(monkeypatch, 0.02)  # were it counted later, it would show
+    svc = DefaultTokenService(CFG, param_config=PCFG)
+    svc.load_rules([ClusterFlowRule(flow_id=1, count=50.0, mode=G)])
+    svc.load_param_rules([ClusterParamFlowRule(1, 5.0)])
+    try:
+        before = _counted()
+        if entry == "request_batch_arrays":
+            out = svc.request_batch_arrays(np.ones(10, np.int64))
+            want = {"account_ms": 1, "dispatches": 1, "rows": 10,
+                    "verdicts": 10, "reads": 1, "reply_first": 0}
+        else:
+            out = svc.request_params_batch(
+                np.ones(10, np.int64), np.ones(10, np.int32),
+                np.arange(10, dtype=np.int64).reshape(10, 1))
+            # no flow dispatch: the arm counters stand still
+            want = {"account_ms": 1, "dispatches": 0, "rows": 0,
+                    "verdicts": 10, "reads": 1, "reply_first": 0}
+        assert out[0].shape == (10,)
+        assert _grew(before) == want
+    finally:
+        svc.close()
+
+
+def test_a_materializer_is_one_body_in_two_halves():
+    svc = _service()
+    try:
+        before = _counted()
+        mat = svc.dispatch_batch_arrays(_ids(10))
+        assert isinstance(mat, Materializer)
+        read, account = halves(mat)
+        status, remaining, wait = read()
+        assert status.shape == remaining.shape == wait.shape == (10,)
+        assert _grew(before)["account_ms"] == 0  # read, not yet counted
+        account()
+        account()  # at most once
+        assert _grew(before) == {"account_ms": 1, "dispatches": 1, "rows": 10,
+                                 "verdicts": 10, "reads": 1, "reply_first": 0}
+        # a plain callable (a foreign service's, a wrapper's) is read whole
+        plain = lambda: "verdicts"  # noqa: E731
+        assert halves(plain) == (plain, None)
+        # the composite of an oversized burst follows from its parts
+        before = _counted()
+        whole = svc.dispatch_batch_arrays(_ids(6 * CAP))  # scan(4) + scan(2)
+        assert whole.read()[0].shape == (6 * CAP,)
+        assert _grew(before)["account_ms"] == 0
+        whole.account(True)
+        assert _grew(before) == {
+            "account_ms": 2, "dispatches": 2, "rows": 6 * CAP,
+            "verdicts": 6 * CAP, "reads": 2, "reply_first": 2}
+    finally:
+        svc.close()
+
+
+def test_a_read_that_raises_is_never_accounted(monkeypatch):
+    svc = _service()
+    try:
+        before = _counted()
+        mat = svc.dispatch_batch_arrays(_ids(10))
+        monkeypatch.setattr(
+            DefaultTokenService, "_read_verdicts",
+            staticmethod(lambda packed: 1 / 0))
+        with pytest.raises(ZeroDivisionError):
+            mat()
+        mat.account()
+        assert _grew(before)["account_ms"] == 0
+    finally:
+        svc.close()
+
+
+def test_the_permit_is_free_once_the_read_half_is_done():
+    if not native_available():
+        pytest.skip("native library not built")
+    svc = _service()
+    server = NativeTokenServer(svc, port=0, idle_ttl_s=None,
+                               max_device_inflight=1)
+    ids = _ids(10)
+    args = (ids, np.ones(10, np.int32), np.zeros(10, bool))
+    try:
+        before = _counted()
+        mat, release, _ = server._tracked_dispatch(
+            svc.dispatch_batch_arrays, *args)
+        assert server._device_inflight == 1
+        mat.read()
+        # the permit bounds device work in flight, not counters
+        assert server._device_inflight == 0
+        assert _grew(before)["account_ms"] == 0
+        mat.account(True)
+        release()  # idempotent with the read half's own release
+        assert server._device_inflight == 0
+        assert _grew(before)["account_ms"] == 1
+
+        def boom():
+            raise RuntimeError("device fell over")
+
+        mat, _release, _ = server._tracked_dispatch(lambda *a: boom, *args)
+        with pytest.raises(RuntimeError):
+            mat.read()
+        assert server._device_inflight == 0  # on the exception path too
+        mat.account(True)  # what the lane does next: nothing to count
+        assert _grew(before)["account_ms"] == 1
+    finally:
+        svc.close()
+
+
+def test_a_submit_that_raises_is_still_accounted_once(lane, monkeypatch):
+    server, client = lane
+    (door,) = server._doors
+    real, calls = door.submit_many, []
+
+    def flaky(*a):
+        calls.append(1)
+        if len(calls) == 1:
+            raise OSError("connection reset")
+        return real(*a)
+
+    monkeypatch.setattr(door, "submit_many", flaky, raising=False)
+    before = _counted()
+    lost = TokenClient("127.0.0.1", server.port, timeout_ms=300)
+    try:
+        assert lost.request_batch_arrays(_ids(40)) is None  # never answered
+    finally:
+        lost.close()
+    assert client.request_batch_arrays(_ids(40, seed=1)) is not None
+    assert _settled(_accounted, before["account_ms"] + 2) == (
+        before["account_ms"] + 2)
+    assert _grew(before) == {"account_ms": 2, "dispatches": 2, "rows": 80,
+                             "verdicts": 80, "reads": 2, "reply_first": 2}
+
+
+def test_stop_leaves_every_materialized_dispatch_counted_once(
+        lane, monkeypatch):
+    server, client = lane
+    _slow_account(monkeypatch, 0.05)
+    before = _counted()
+    n = 6
+    for i in range(n):  # each reply is here before its dispatch is counted
+        assert client.request_batch_arrays(_ids(40, seed=i)) is not None
+    assert _grew(before)["account_ms"] < n
+    server.stop()  # joins the reply lanes: no wait needed after it
+    want = {"account_ms": n, "dispatches": n, "rows": 40 * n,
+            "verdicts": 40 * n, "reads": n, "reply_first": n}
+    assert _grew(before) == want
+    time.sleep(0.1)
+    assert _grew(before) == want  # and nothing counts twice later
 
 
 def test_the_trace_command_serves_the_phases():

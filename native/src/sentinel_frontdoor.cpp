@@ -30,6 +30,16 @@
 // full, a connection's remaining bytes stay in its read buffer and its
 // EPOLLIN is parked until the next arena swap (the kernel's TCP window then
 // back-pressures the client, like netty's autoRead=false).
+//
+// Spans (all on CLOCK_MONOTONIC, which is Python's time.monotonic_ns()): a
+// data frame is stamped when the recv() that completed it returned
+// (FrameMeta.rx_ns), the stamp rides the pull out (f_rx_ns) and comes back
+// with the verdicts (sn_fd_submit), and the IO thread closes the span when
+// send() has taken the last byte of the reply's buffer. Three histograms are
+// counted where their span ends, on relaxed atomics (SpanHist, read by
+// sn_fd_span_stats): door_in (rx -> the pull about to return to its
+// caller), door_out (sn_fd_submit's entry -> last byte sent) and
+// door_residence (rx -> last byte sent). A stamp of 0 counts nothing.
 
 #include <arpa/inet.h>
 #include <errno.h>
@@ -102,13 +112,23 @@ inline void put32(uint8_t *p, uint32_t v) {
   p[3] = uint8_t(v);
 }
 
+// one outbound buffer: the wire bytes and, behind them in the same
+// allocation, the rx_ns stamp of every frame it answers (verdict buffers of
+// a caller that handed the stamps back; none otherwise)
+struct OutBuf {
+  std::string data;
+  size_t wire = 0;        // wire bytes at the head of data
+  int64_t submit_ns = 0;  // mono_ns() at the entry of sn_fd_submit
+};
+
 struct Conn {
   int fd = -1;
   uint32_t gen = 0;
-  int64_t last_active_ms = 0;  // CLOCK_MONOTONIC, for the idle sweep
-  std::vector<uint8_t> rbuf;   // unparsed inbound bytes
-  size_t rpos = 0;             // parse cursor into rbuf
-  std::deque<std::string> wq;  // queued outbound frames
+  int64_t last_rx_ns = 0;     // CLOCK_MONOTONIC of the last recv() with bytes:
+                              // the frames' rx stamp, and the idle sweep's
+  std::vector<uint8_t> rbuf;  // unparsed inbound bytes
+  size_t rpos = 0;            // parse cursor into rbuf
+  std::deque<OutBuf> wq;      // queued outbound buffers
   size_t woff = 0;             // offset into wq.front()
   bool want_write = false;     // EPOLLOUT armed
   bool paused = false;         // EPOLLIN parked (arena full)
@@ -125,6 +145,7 @@ struct FrameMeta {
   uint8_t type;    // kTypeFlow | kTypeBatchFlow | kTypeBatchParam
   uint8_t k;       // values per request (param frames; 0 otherwise)
   uint64_t seq;    // arrival order over both arenas
+  int64_t rx_ns;   // mono_ns() after the recv() that read its last byte
 };
 
 // decoded rows awaiting a pull; the param arena also holds value hashes
@@ -146,6 +167,24 @@ struct Control {
   std::string payload;  // frame bytes (kind 0) or peer address (kind 1)
   int64_t t_ns = 0;     // CLOCK_MONOTONIC when a frame (kind 0) was queued
 };
+
+// One span histogram: count, sum, max and a count per bucket of the bounds
+// the host handed over (sn_fd_set_span_bounds: its own LatencyHistogram's,
+// in ns; le-inclusive, the last bucket is the overflow; written once,
+// before n_bounds). Relaxed atomics: each value is its own monotonic
+// series, not one consistent snapshot.
+constexpr int kMaxSpanBounds = 128;
+struct SpanHist {
+  int64_t bounds[kMaxSpanBounds] = {};
+  std::atomic<int32_t> n_bounds{0};
+  std::atomic<uint64_t> count{0}, sum_ns{0}, max_ns{0};
+  std::atomic<uint64_t> buckets[kMaxSpanBounds + 1];
+  SpanHist() {
+    for (auto &b : buckets) b.store(0, std::memory_order_relaxed);
+  }
+};
+
+enum SpanKind { kDoorIn = 0, kDoorOut = 1, kDoorResidence = 2 };
 
 struct Frontdoor {
   int listen_fd = -1;
@@ -179,9 +218,11 @@ struct Frontdoor {
 
   // outbound handoff: Python-side submit() parks encoded frames here; the
   // IO thread moves them onto the conn write queues (guarded by mu)
-  std::vector<std::pair<std::pair<int32_t, uint32_t>, std::string>> outbox;
+  std::vector<std::pair<std::pair<int32_t, uint32_t>, OutBuf>> outbox;
 
   std::unordered_map<int, Conn> conns;  // IO thread only
+
+  SpanHist spans[3];  // by SpanKind, as sn_fd_span_stats numbers them
 
   // stats (relaxed)
   std::atomic<uint64_t> frames_in{0}, requests_in{0}, bytes_in{0},
@@ -209,10 +250,57 @@ int64_t mono_ns() {
   return int64_t(ts.tv_sec) * 1000000000LL + ts.tv_nsec;
 }
 
-int64_t mono_ms() {
-  timespec ts;
-  clock_gettime(CLOCK_MONOTONIC, &ts);
-  return int64_t(ts.tv_sec) * 1000 + ts.tv_nsec / 1000000;
+int64_t mono_ms() { return mono_ns() / 1000000; }
+
+// n spans of one length end here (negative: a clock that went back; skipped)
+void span_count(SpanHist &h, int64_t ns, uint64_t n) {
+  if (ns < 0) return;
+  const int64_t *b = h.bounds;
+  int32_t nb = h.n_bounds.load(std::memory_order_acquire);
+  size_t i = size_t(std::lower_bound(b, b + nb, ns) - b);
+  h.buckets[i].fetch_add(n, std::memory_order_relaxed);
+  h.count.fetch_add(n, std::memory_order_relaxed);
+  h.sum_ns.fetch_add(uint64_t(ns) * n, std::memory_order_relaxed);
+  uint64_t seen = h.max_ns.load(std::memory_order_relaxed);
+  while (uint64_t(ns) > seen &&
+         !h.max_ns.compare_exchange_weak(seen, uint64_t(ns),
+                                         std::memory_order_relaxed)) {
+  }
+}
+
+// door_in of the frames a pull just took: one search per run of frames
+// that one recv() completed
+void count_door_in(Frontdoor *s, const int64_t *rx, int32_t n_frames,
+                   int64_t wake_ns) {
+  for (int32_t i = 0; i < n_frames;) {
+    int32_t j = i + 1;
+    while (j < n_frames && rx[j] == rx[i]) ++j;
+    if (rx[i])
+      span_count(s->spans[kDoorIn], wake_ns - rx[i], uint64_t(j - i));
+    i = j;
+  }
+}
+
+// send() took the last byte of a verdict buffer: door_out and
+// door_residence of every stamped frame it answered
+void close_spans(Frontdoor *s, const OutBuf &buf) {
+  size_t n = (buf.data.size() - buf.wire) / sizeof(int64_t);
+  const char *tail = buf.data.data() + buf.wire;
+  int64_t done = mono_ns();
+  for (size_t i = 0; i < n;) {
+    int64_t rx, nxt;
+    memcpy(&rx, tail + i * sizeof(int64_t), sizeof(rx));
+    size_t j = i + 1;
+    for (; j < n; ++j) {
+      memcpy(&nxt, tail + j * sizeof(int64_t), sizeof(nxt));
+      if (nxt != rx) break;
+    }
+    if (rx) {
+      span_count(s->spans[kDoorOut], done - buf.submit_ns, uint64_t(j - i));
+      span_count(s->spans[kDoorResidence], done - rx, uint64_t(j - i));
+    }
+    i = j;
+  }
 }
 
 void epoll_mod(Frontdoor *s, Conn &c) {
@@ -235,7 +323,9 @@ void close_conn(Frontdoor *s, Conn &c) {
 }
 
 // Parse as many frames as the arena allows; returns false if the conn
-// should be closed (protocol error).
+// should be closed (protocol error). Every data frame parsed is stamped
+// c.last_rx_ns: the recv() that brought its last byte (a conn parked on a
+// full arena reads nothing more, so the stamp holds for what it buffered).
 bool parse_frames(Frontdoor *s, Conn &c) {
   bool notify = false;
   bool wake_self = false;
@@ -278,8 +368,10 @@ bool parse_frames(Frontdoor *s, Conn &c) {
           // empty batch frame: answer inline with an empty verdict frame —
           // wait_batch only wakes for n_requests > 0, so queuing a
           // zero-row FrameMeta would strand it (and its sender) forever
-          std::string rsp(size_t(2 + kHead + 2), '\0');
-          uint8_t *q = reinterpret_cast<uint8_t *>(&rsp[0]);
+          OutBuf rsp;
+          rsp.data.assign(size_t(2 + kHead + 2), '\0');
+          rsp.wire = rsp.data.size();
+          uint8_t *q = reinterpret_cast<uint8_t *>(&rsp.data[0]);
           put16(q, uint16_t(kHead + 2));
           put32(q + 2, uint32_t(xid));
           q[6] = type;
@@ -312,7 +404,7 @@ bool parse_frames(Frontdoor *s, Conn &c) {
         a.n_requests += size_t(n);
         a.n_hashes += size_t(n) * size_t(k);
         a.frames.push_back({c.fd, c.gen, xid, n, type, uint8_t(k),
-                            s->next_seq++});
+                            s->next_seq++, c.last_rx_ns});
         s->frames_in.fetch_add(1, std::memory_order_relaxed);
         s->requests_in.fetch_add(uint64_t(n), std::memory_order_relaxed);
         notify = true;
@@ -352,13 +444,14 @@ bool parse_frames(Frontdoor *s, Conn &c) {
 
 void flush_writes(Frontdoor *s, Conn &c) {
   while (!c.wq.empty()) {
-    const std::string &buf = c.wq.front();
-    ssize_t w = ::send(c.fd, buf.data() + c.woff, buf.size() - c.woff,
+    const OutBuf &buf = c.wq.front();
+    ssize_t w = ::send(c.fd, buf.data.data() + c.woff, buf.wire - c.woff,
                        MSG_NOSIGNAL);
     if (w > 0) {
       s->bytes_out.fetch_add(uint64_t(w), std::memory_order_relaxed);
       c.woff += size_t(w);
-      if (c.woff == buf.size()) {
+      if (c.woff == buf.wire) {
+        if (buf.data.size() > buf.wire) close_spans(s, buf);
         c.wq.pop_front();
         c.woff = 0;
       }
@@ -418,7 +511,7 @@ void io_loop(Frontdoor *s) {
           Conn &c = s->conns[cfd];
           c = Conn{};
           c.fd = cfd;
-          c.last_active_ms = mono_ms();
+          c.last_rx_ns = mono_ns();
           static std::atomic<uint32_t> gen_counter{1};
           c.gen = gen_counter.fetch_add(1);
           char ip[64];
@@ -465,7 +558,9 @@ void io_loop(Frontdoor *s) {
           ssize_t r = ::recv(fd, scratch, kReadChunk, 0);
           if (r > 0) {
             c.rbuf.insert(c.rbuf.end(), scratch, scratch + size_t(r));
-            c.last_active_ms = mono_ms();
+            // the one clock read of this recv(): the rx stamp of every
+            // frame it completes, and the idle sweep's mark
+            c.last_rx_ns = mono_ns();
             s->bytes_in.fetch_add(uint64_t(r), std::memory_order_relaxed);
             if (!parse_frames(s, c)) {
               closed = true;
@@ -505,7 +600,7 @@ void io_loop(Frontdoor *s) {
         s->last_sweep_ms = now;
         std::vector<int> stale;
         for (auto &kv : s->conns)
-          if (kv.second.open && now - kv.second.last_active_ms > ttl)
+          if (kv.second.open && now - kv.second.last_rx_ns / 1000000 > ttl)
             stale.push_back(kv.first);
         for (int fd : stale) {
           auto it = s->conns.find(fd);
@@ -519,7 +614,7 @@ void io_loop(Frontdoor *s) {
     // move submitted frames onto conn write queues + flush; also resume
     // parked conns after an arena swap
     if (drain_outbox) {
-      std::vector<std::pair<std::pair<int32_t, uint32_t>, std::string>> out;
+      std::vector<std::pair<std::pair<int32_t, uint32_t>, OutBuf>> out;
       bool resume;
       {
         std::lock_guard<std::mutex> lk(s->mu);
@@ -538,7 +633,7 @@ void io_loop(Frontdoor *s) {
         if (it == s->conns.end() || it->second.gen != item.first.second ||
             !it->second.open)
           continue;
-        if (item.second.empty()) {  // zero-length = host-requested close
+        if (item.second.data.empty()) {  // zero-length = host-requested close
           close_conn(s, it->second);
           s->conns.erase(it);
           continue;
@@ -679,7 +774,8 @@ int32_t take_frames(Arena &a, int64_t *ids, int32_t *counts,
                     uint8_t *prios, int64_t *hashes, int32_t max_n,
                     int32_t max_hashes, int32_t *f_fd, int32_t *f_gen,
                     int32_t *f_xid, int32_t *f_n, uint8_t *f_type,
-                    int32_t max_frames, int32_t *n_frames_out) {
+                    int64_t *f_rx_ns, int32_t max_frames,
+                    int32_t *n_frames_out) {
   size_t take_req = 0, n_take = 0, take_hashes = 0;
   const uint8_t k = a.frames.empty() ? 0 : a.frames.front().k;
   for (const FrameMeta &fm : a.frames) {
@@ -704,6 +800,7 @@ int32_t take_frames(Arena &a, int64_t *ids, int32_t *counts,
     f_xid[i] = a.frames[i].xid;
     f_n[i] = a.frames[i].n;
     f_type[i] = a.frames[i].type;
+    if (f_rx_ns) f_rx_ns[i] = a.frames[i].rx_ns;
   }
   // compact the remainder (rare: only when a burst exceeds caller capacity)
   size_t rest_req = a.n_requests - take_req;
@@ -724,6 +821,15 @@ int32_t take_frames(Arena &a, int64_t *ids, int32_t *counts,
   return int32_t(take_req);
 }
 
+// The end of a pull, mu released: the wake stamp and the frames' door_in.
+void pulled(Frontdoor *s, const int64_t *f_rx_ns, int32_t n_frames,
+            int64_t *wake_ns_out) {
+  if (!f_rx_ns && !wake_ns_out) return;
+  int64_t wake_ns = mono_ns();
+  if (f_rx_ns) count_door_in(s, f_rx_ns, n_frames, wake_ns);
+  if (wake_ns_out) *wake_ns_out = wake_ns;
+}
+
 }  // namespace
 
 // Block until data-plane requests are queued (or timeout/stop). Copies up
@@ -731,14 +837,19 @@ int32_t take_frames(Arena &a, int64_t *ids, int32_t *counts,
 // arrays and resets the arena. Returns the request count (0 on
 // timeout/stop); *n_frames_out receives the frame count. Whole frames only —
 // a frame never splits across two batches. Param frames are not seen here:
-// a host that serves them pulls with sn_fd_wait_any.
+// a host that serves them pulls with sn_fd_wait_any. f_rx_ns (may be null)
+// receives each frame's rx stamp, to be handed back to sn_fd_submit, and
+// *wake_ns_out (may be null) mono_ns() just before the return: what the
+// caller's own clock reads later than that, it spent getting back to run
+// (from Python: the ctypes return and the wait for the GIL).
 SN_EXPORT int32_t sn_fd_wait_batch(void *h, int32_t timeout_ms, int64_t *ids,
                                    int32_t *counts, uint8_t *prios,
                                    int32_t max_n, int32_t *f_fd,
                                    int32_t *f_gen, int32_t *f_xid,
                                    int32_t *f_n, uint8_t *f_type,
-                                   int32_t max_frames,
-                                   int32_t *n_frames_out) {
+                                   int64_t *f_rx_ns, int32_t max_frames,
+                                   int32_t *n_frames_out,
+                                   int64_t *wake_ns_out) {
   auto *s = static_cast<Frontdoor *>(h);
   std::unique_lock<std::mutex> lk(s->mu);
   if (s->flow.n_requests == 0) {
@@ -750,11 +861,12 @@ SN_EXPORT int32_t sn_fd_wait_batch(void *h, int32_t timeout_ms, int64_t *ids,
   *n_frames_out = 0;
   if (s->flow.n_requests == 0) return 0;
   int32_t n = take_frames(s->flow, ids, counts, prios, nullptr, max_n, 0,
-                          f_fd, f_gen, f_xid, f_n, f_type, max_frames,
-                          n_frames_out);
+                          f_fd, f_gen, f_xid, f_n, f_type, f_rx_ns,
+                          max_frames, n_frames_out);
   bool resume = s->arena_was_full;
   lk.unlock();
   if (resume) wake(s);  // unpark conns the full arena throttled
+  pulled(s, f_rx_ns, *n_frames_out, wake_ns_out);
   return n;
 }
 
@@ -767,8 +879,9 @@ SN_EXPORT int32_t sn_fd_wait_any(void *h, int32_t timeout_ms, int64_t *ids,
                                  int64_t *hashes, int32_t max_n,
                                  int32_t max_hashes, int32_t *f_fd,
                                  int32_t *f_gen, int32_t *f_xid, int32_t *f_n,
-                                 uint8_t *f_type, int32_t max_frames,
-                                 int32_t *n_frames_out, int32_t *k_out) {
+                                 uint8_t *f_type, int64_t *f_rx_ns,
+                                 int32_t max_frames, int32_t *n_frames_out,
+                                 int32_t *k_out, int64_t *wake_ns_out) {
   auto *s = static_cast<Frontdoor *>(h);
   std::unique_lock<std::mutex> lk(s->mu);
   auto queued = [s] { return s->flow.n_requests + s->param.n_requests > 0; };
@@ -787,11 +900,12 @@ SN_EXPORT int32_t sn_fd_wait_any(void *h, int32_t timeout_ms, int64_t *ids,
   Arena &a = take_param ? s->param : s->flow;
   if (take_param) *k_out = a.frames.front().k;
   int32_t n = take_frames(a, ids, counts, prios, hashes, max_n, max_hashes,
-                          f_fd, f_gen, f_xid, f_n, f_type, max_frames,
-                          n_frames_out);
+                          f_fd, f_gen, f_xid, f_n, f_type, f_rx_ns,
+                          max_frames, n_frames_out);
   bool resume = s->arena_was_full;
   lk.unlock();
   if (resume) wake(s);  // unpark conns the full arena throttled
+  pulled(s, f_rx_ns, *n_frames_out, wake_ns_out);
   return n;
 }
 
@@ -802,13 +916,17 @@ SN_EXPORT int32_t sn_fd_wait_any(void *h, int32_t timeout_ms, int64_t *ids,
 // per-writer buffer — one allocation, one outbox item, and (usually) one
 // send() per connection instead of one per frame. Pipelined clients queue
 // many frames per socket, so fused groups collapse to a handful of writes.
+// f_rx_ns (may be null) hands the frames' rx stamps back: they ride each
+// buffer, behind its wire bytes, to the send() that closes their spans.
 SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
                             const int32_t *f_gen, const int32_t *f_xid,
                             const int32_t *f_n, const uint8_t *f_type,
-                            const int8_t *status, const int32_t *remaining,
+                            const int64_t *f_rx_ns, const int8_t *status,
+                            const int32_t *remaining,
                             const int32_t *wait_ms) {
   auto *s = static_cast<Frontdoor *>(h);
-  std::vector<std::pair<std::pair<int32_t, uint32_t>, std::string>> staged;
+  const int64_t submit_ns = f_rx_ns ? mono_ns() : 0;
+  std::vector<std::pair<std::pair<int32_t, uint32_t>, OutBuf>> staged;
   size_t off = 0;
   for (int32_t i = 0; i < n_frames;) {
     // run of consecutive frames bound for one connection
@@ -821,9 +939,13 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
       total += (f_type[k] != kTypeFlow)
                    ? 2 + size_t(kHead) + 2 + size_t(f_n[k]) * kRspRow
                    : 2 + size_t(kHead) + kRspRow;
-    std::string buf;
-    buf.resize(total);
-    uint8_t *p = reinterpret_cast<uint8_t *>(&buf[0]);
+    OutBuf buf;
+    buf.wire = total;
+    buf.submit_ns = submit_ns;
+    size_t stamps = f_rx_ns ? size_t(run_end - i) * sizeof(int64_t) : 0;
+    buf.data.resize(total + stamps);
+    if (stamps) memcpy(&buf.data[total], f_rx_ns + i, stamps);
+    uint8_t *p = reinterpret_cast<uint8_t *>(&buf.data[0]);
     for (int32_t k = i; k < run_end; ++k) {
       int32_t n = f_n[k];
       if (f_type[k] != kTypeFlow) {  // BATCH_FLOW or BATCH_PARAM_FLOW rows
@@ -867,11 +989,12 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
 SN_EXPORT void sn_fd_send(void *h, int32_t fd, int32_t gen,
                           const uint8_t *data, int32_t len) {
   auto *s = static_cast<Frontdoor *>(h);
+  OutBuf buf;
+  buf.data.assign(reinterpret_cast<const char *>(data), size_t(len));
+  buf.wire = buf.data.size();
   {
     std::lock_guard<std::mutex> lk(s->mu);
-    s->outbox.emplace_back(
-        std::make_pair(fd, uint32_t(gen)),
-        std::string(reinterpret_cast<const char *>(data), size_t(len)));
+    s->outbox.emplace_back(std::make_pair(fd, uint32_t(gen)), std::move(buf));
   }
   wake(s);
 }
@@ -919,7 +1042,7 @@ SN_EXPORT void sn_fd_close_conn(void *h, int32_t fd, int32_t gen) {
   // zero-length payload the drain loop interprets as "close".
   {
     std::lock_guard<std::mutex> lk(s->mu);
-    s->outbox.emplace_back(std::make_pair(fd, uint32_t(gen)), std::string());
+    s->outbox.emplace_back(std::make_pair(fd, uint32_t(gen)), OutBuf());
   }
   wake(s);
 }
@@ -934,6 +1057,35 @@ SN_EXPORT void sn_fd_stats(void *h, uint64_t *out4) {
   out4[1] = s->requests_in.load(std::memory_order_relaxed);
   out4[2] = s->bytes_in.load(std::memory_order_relaxed);
   out4[3] = s->bytes_out.load(std::memory_order_relaxed);
+}
+
+// The bucket bounds of span histogram ``which`` (0 door_in, 1 door_out,
+// 2 door_residence), in ns, ascending: the host's own histogram's
+// (LatencyHistogram), handed over once, before traffic. Spans counted
+// before the call all land in bucket 0.
+SN_EXPORT void sn_fd_set_span_bounds(void *h, int32_t which,
+                                     const int64_t *bounds_ns, int32_t n) {
+  SpanHist &sh = static_cast<Frontdoor *>(h)->spans[which];
+  n = std::min(n, int32_t(kMaxSpanBounds));
+  memcpy(sh.bounds, bounds_ns, size_t(n) * sizeof(int64_t));
+  sh.n_bounds.store(n, std::memory_order_release);
+}
+
+// Span histogram ``which``: count, sum_ns, max_ns, then n_bounds + 1 bucket
+// counts (not cumulative; the last is the overflow). Returns the values
+// written (n_bounds + 4), or what that would be when max_out is too small.
+// Like sn_fd_stats, no one snapshot.
+SN_EXPORT int32_t sn_fd_span_stats(void *h, int32_t which, uint64_t *out,
+                                   int32_t max_out) {
+  SpanHist &sh = static_cast<Frontdoor *>(h)->spans[which];
+  int32_t nb = sh.n_bounds.load(std::memory_order_acquire);
+  if (nb + 4 > max_out) return nb + 4;
+  *out++ = sh.count.load(std::memory_order_relaxed);
+  *out++ = sh.sum_ns.load(std::memory_order_relaxed);
+  *out++ = sh.max_ns.load(std::memory_order_relaxed);
+  for (int32_t i = 0; i <= nb; ++i)
+    *out++ = sh.buckets[i].load(std::memory_order_relaxed);
+  return nb + 4;
 }
 
 // --- transport echo (bench/tests only) -----------------------------------
@@ -952,18 +1104,20 @@ SN_EXPORT void sn_fd_echo_start(void *h) {
     std::vector<int32_t> counts(kMaxN), f_fd(kMaxF), f_gen(kMaxF),
         f_xid(kMaxF), f_n(kMaxF), rem(kMaxN), wait(kMaxN, 0);
     std::vector<uint8_t> prios(kMaxN), f_type(kMaxF);
+    std::vector<int64_t> f_rx(kMaxF);
     std::vector<int8_t> status(kMaxN, 0);  // GRANTED
     int32_t nf = 0;
     while (!s->echo_stop.load(std::memory_order_acquire)) {
       int32_t n = sn_fd_wait_batch(h, 5, ids.data(), counts.data(),
                                    prios.data(), kMaxN, f_fd.data(),
                                    f_gen.data(), f_xid.data(), f_n.data(),
-                                   f_type.data(), kMaxF, &nf);
+                                   f_type.data(), f_rx.data(), kMaxF, &nf,
+                                   nullptr);
       if (n <= 0) continue;
       for (int32_t i = 0; i < n; ++i) rem[i] = counts[i];
       sn_fd_submit(h, nf, f_fd.data(), f_gen.data(), f_xid.data(),
-                   f_n.data(), f_type.data(), status.data(), rem.data(),
-                   wait.data());
+                   f_n.data(), f_type.data(), f_rx.data(), status.data(),
+                   rem.data(), wait.data());
     }
   });
 }

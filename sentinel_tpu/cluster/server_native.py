@@ -343,6 +343,13 @@ class NativeTokenServer:
             doors.append(self._shm_door)  # control loop + stats cover it
         self._doors = doors
         self._door = doors[0]
+        # the TCP doors count their spans (door_in / door_out /
+        # door_residence) on the registry's own bounds, and the registry
+        # folds what they counted in on every read
+        for d in doors[:self.intake_shards]:
+            for name in d.SPANS:
+                d.set_span_bounds(name, getattr(_SM, name).bounds)
+        _SM.register_door_spans(self._door_spans)
         if self.idle_ttl_s:
             for d in doors:
                 d.set_idle_ttl(int(self.idle_ttl_s * 1000))
@@ -458,6 +465,21 @@ class NativeTokenServer:
             self.host, self.port, self.intake_shards, self.n_dispatchers,
         )
 
+    def _door_spans(self) -> dict:
+        """The TCP doors' span counters, summed (``Frontdoor.span_stats``
+        shape): what ``ServerMetrics`` folds into ``door_in_ms``,
+        ``door_out_ms`` and ``door_residence_ms``."""
+        total: dict = {}
+        for d in self._doors[:self.intake_shards]:
+            for name, (n, sum_ms, max_ms, counts) in d.span_stats().items():
+                if name in total:
+                    n0, s0, m0, c0 = total[name]
+                    n, sum_ms, max_ms, counts = (
+                        n0 + n, s0 + sum_ms, max(m0, max_ms), c0 + counts
+                    )
+                total[name] = (n, sum_ms, max_ms, counts)
+        return total
+
     def _shm_stats_provider(self) -> dict:
         door = self._shm_door
         if door is None:
@@ -499,6 +521,10 @@ class NativeTokenServer:
             f_xid=np.empty(max_f, np.int32),
             f_n=np.empty(max_f, np.int32),
             f_type=np.empty(max_f, np.uint8),
+            # the door's stamps (monotonic_ns; 0 = none, the shm door):
+            # each frame's last byte read, and the pull about to return
+            f_rx_ns=np.empty(max_f, np.int64),
+            wake_ns=np.zeros(1, np.int64),
             # value hashes of a param pull (BATCH_PARAM_FLOW), request-major;
             # one max-size frame holds under 8192 of them
             hashes=np.empty(max(rows, 8192), np.int64),
@@ -577,6 +603,8 @@ class NativeTokenServer:
         self._stop.set()
         for d in self._doors:
             d.stop()
+        # the IO threads are joined: what they counted is final
+        _SM.unregister_door_spans(self._door_spans)
         for t in self._threads:
             t.join(timeout=5)
         self._threads = []
@@ -669,6 +697,15 @@ class NativeTokenServer:
                     break
                 if got is None:
                     continue
+                # the lane's one clock, the recorder's and the door's:
+                # first thing with the GIL back. What it reads past the
+                # door's own stamp, taken in C just before the pull
+                # returned to ctypes, went on that return and on waiting
+                # for the GIL (0: a door that does not stamp)
+                t_py = time.monotonic_ns()
+                wake_ns = int(block["wake_ns"][0])
+                if wake_ns:
+                    _SM.door_wake_ms.record((t_py - wake_ns) * 1e-6)
                 # nv: values per request of a param pull (its rows are the
                 # requests of BATCH_PARAM_FLOW frames), 0 for a flow pull
                 n, k, nv = got
@@ -677,7 +714,6 @@ class NativeTokenServer:
                     if chaos.should("frame_drop"):
                         _SM.count_shed("chaos_drop", n)
                         continue
-                t0 = time.perf_counter()
                 # normalize the wire prio bytes into the block's boolean
                 # row in place (clients send 0/1 but the wire admits any
                 # byte; masking downstream needs real booleans)
@@ -687,24 +723,23 @@ class NativeTokenServer:
                 # the one host copy this path pays: C arena → staging
                 # (13B/row + 17B/frame) plus the 1B/row bool normalize
                 _SM.count_copy_bytes(n * (14 + 8 * nv) + k * 17)
-                # pull = (rows..., frames, age stamp, owning door, block,
-                # value hashes [n, nv] of a param pull or None):
-                # the age stamp is the shed-by-age deadline proxy (the C++
-                # door strips the wire deadline); the door routes replies
-                # and refusals back to the shard that owns the connection
-                pull = (
-                    block["ids"][:n], block["counts"][:n], prios,
-                    (block["f_fd"][:k], block["f_gen"][:k],
-                     block["f_xid"][:k], block["f_n"][:k],
-                     block["f_type"][:k]),
-                    time.monotonic(), door, block,
-                    block["hashes"][:n * nv].reshape(n, nv) if nv else None,
+                # the frames of the pull, as the door takes them back: the
+                # sixth column is each frame's rx stamp, which the door
+                # closes its spans from when the reply has gone out
+                frames = (
+                    block["f_fd"][:k], block["f_gen"][:k],
+                    block["f_xid"][:k], block["f_n"][:k],
+                    block["f_type"][:k], block["f_rx_ns"][:k],
                 )
                 if _TR.ARMED:  # flight recorder: frames entered the host
                     if door is self._shm_door:
                         _TR.record(_TR.SHM_POLL, shard=shard, aux=n)
                     _TR.record_many(
-                        _TR.CLIENT_IN, pull[3][2], shard=shard, aux=n
+                        _TR.RX, frames[2], shard=shard, aux=n,
+                        t_ns=frames[5],
+                    )
+                    _TR.record_many(
+                        _TR.CLIENT_IN, frames[2], shard=shard, aux=n
                     )
                 if self.is_standby:
                     # unpromoted warm standby: data plane is closed. Refuse
@@ -714,13 +749,15 @@ class NativeTokenServer:
                     _SM.count_shed("standby", n)
                     if _TR.ARMED:
                         _TR.record_many(
-                            _TR.SHED, pull[3][2], shard=shard, aux=n
+                            _TR.SHED, frames[2], shard=shard, aux=n
                         )
                     status = np.full(n, _STANDBY, np.int8)
                     _SM.record_verdict_batch(status, None, ())
                     try:
+                        # an answer the intake lane gives itself is no
+                        # verdict's residence: the stamps stay behind
                         door.submit(
-                            pull[3], status, np.zeros(n, np.int32),
+                            frames[:5], status, np.zeros(n, np.int32),
                             np.zeros(n, np.int32),
                         )
                     except Exception:
@@ -735,13 +772,28 @@ class NativeTokenServer:
                     None if self.shed_age_ms is None
                     else self.shed_age_ms / 1000.0
                 )
+                # pull = (rows..., frames, age stamp, owning door, block,
+                # value hashes [n, nv] of a param pull or None, hand-over
+                # stamp): the age stamp (the lane's first clock read, ns)
+                # is the shed-by-age deadline proxy (the C++ door strips
+                # the wire deadline); the door routes replies and refusals
+                # back to the shard that owns the connection; the hand-over
+                # stamp ends intake_ms and starts the pull's queue_wait_ms,
+                # which so holds a put that blocks on a full queue
+                t_enq = time.monotonic_ns()
+                pull = (
+                    block["ids"][:n], block["counts"][:n], prios, frames,
+                    t_py, door, block,
+                    block["hashes"][:n * nv].reshape(n, nv) if nv else None,
+                    t_enq,
+                )
                 if self._lane_put(q, pull, give_up_after_s=give_up):
                     self._dispatch_sem.release()
                     if _TR.ARMED:
                         _TR.record_many(
-                            _TR.ENQUEUE, pull[3][2], shard=shard, aux=n
+                            _TR.ENQUEUE, frames[2], shard=shard, aux=n
                         )
-                    dt_ms = (time.perf_counter() - t0) * 1e3
+                    dt_ms = (t_enq - t_py) * 1e-6
                     _SM.intake_ms.record(dt_ms)
                     _SM.count_shard_pull(shard, n, dt_ms)
                     # the block now rides the pull; next cycle decodes
@@ -756,7 +808,7 @@ class NativeTokenServer:
                     _SM.count_shed("queue_full", n)
                     if _TR.ARMED:
                         _TR.record_many(
-                            _TR.SHED, pull[3][2], shard=shard, aux=n
+                            _TR.SHED, frames[2], shard=shard, aux=n
                         )
                     status = np.full(n, _OVERLOAD, np.int8)
                     wait = np.full(
@@ -775,7 +827,7 @@ class NativeTokenServer:
                     )
                     try:
                         door.submit(
-                            pull[3], status, np.zeros(n, np.int32), wait
+                            frames[:5], status, np.zeros(n, np.int32), wait
                         )
                     except Exception:
                         if not self._stop.is_set():
@@ -976,7 +1028,9 @@ class NativeTokenServer:
                 shed = None
                 n_deadline = 0
                 if self.shed_age_ms is not None:
-                    cutoff = time.monotonic() - self.shed_age_ms / 1000.0
+                    cutoff = time.monotonic_ns() - int(
+                        self.shed_age_ms * 1e6
+                    )
                     expired = np.array(
                         [p[4] < cutoff for p in pulls], bool
                     )
@@ -995,7 +1049,11 @@ class NativeTokenServer:
                         _TR.record_many(
                             _TR.DISPATCH, p[3][2], aux=len(pulls)
                         )
-                t0 = time.perf_counter()
+                # dispatch_ms starts where each pull's queue_wait_ms ends:
+                # the fusion collect, the concatenation of a fused group
+                # and the shed-by-age test above are queue wait, not
+                # dispatch (recorded below, past the span's end)
+                t0 = time.monotonic_ns()
                 permit_rel = None
                 overlapped = False
                 try:
@@ -1109,17 +1167,23 @@ class NativeTokenServer:
                         np.zeros(n, np.int32),
                         np.zeros(n, np.int32),
                     )
-                dt_ms = (time.perf_counter() - t0) * 1e3
+                t_put = time.monotonic_ns()
+                dt_ms = (t_put - t0) * 1e-6
                 _SM.dispatch_ms.record(dt_ms)
+                # one record per pull, never per frame or row, and outside
+                # the span that permit, prep, lock and launch split
+                for p in pulls:
+                    _SM.queue_wait_ms.record((t0 - p[8]) * 1e-6)
                 if overlapped:
                     # this group's whole dispatch arm ran while the prior
                     # group still computed — the pipelining win
                     _SM.count_overlap_saved_ms(dt_ms)
                 # the stamp rides the item: reply_queue_wait_ms runs from
-                # here to a reply lane's get() returning
+                # dispatch_ms's end (so it holds the records above and the
+                # put) to a reply lane's get() returning
                 if not self._lane_put(
                     self._reply_q,
-                    (pulls, lengths, mat, time.monotonic_ns()),
+                    (pulls, lengths, mat, t_put),
                 ):
                     # abandoned shutdown drop: nobody will materialize or
                     # answer these rows — account for them and park the
@@ -1169,12 +1233,16 @@ class NativeTokenServer:
                 rq.put(item)  # release sibling reply lanes
                 return
             pulls, lengths, mat, t_put = item
-            _SM.reply_queue_wait_ms.record(
-                (time.monotonic_ns() - t_put) * 1e-6
-            )
+            t_taken = time.monotonic_ns()
+            _SM.reply_queue_wait_ms.record((t_taken - t_put) * 1e-6)
+            if _TR.ARMED:  # flight recorder: a reply lane has the group
+                _TR.record(
+                    _TR.REPLY_TAKEN, t_ns=t_taken,
+                    aux=min((t_taken - t_put) // 1000, 2**31 - 1),
+                )
             # a foreign service's materializer has no account half
             read, account = halves(mat)
-            t0 = time.perf_counter()
+            t0 = time.monotonic_ns()
             try:
                 status, remaining, wait = read()
             except Exception:
@@ -1183,8 +1251,8 @@ class NativeTokenServer:
                 status = np.full(n, int(TokenStatus.FAIL), np.int8)
                 remaining = np.zeros(n, np.int32)
                 wait = np.zeros(n, np.int32)
-            t_write = time.perf_counter()
-            _SM.decide_ms.record((t_write - t0) * 1e3)
+            t_write = time.monotonic_ns()
+            _SM.decide_ms.record((t_write - t0) * 1e-6)
             off = 0
             i = 0
             n_pulls = len(pulls)
@@ -1209,7 +1277,10 @@ class NativeTokenServer:
                         remaining[off : off + span],
                         wait[off : off + span],
                     )
-                    if _TR.ARMED:  # flight recorder: replies on the wire
+                    # flight recorder: replies submitted to the door (parked
+                    # in its outbox; the IO thread's send() comes later and
+                    # ends the frames' door_out_ms and door_residence_ms)
+                    if _TR.ARMED:
                         for fr in frames_list:
                             _TR.record_many(
                                 _TR.REPLY_OUT, fr[2], aux=span
@@ -1220,7 +1291,7 @@ class NativeTokenServer:
                 off += span
                 i = j
             self.overload.note_done(off)
-            _SM.write_ms.record((time.perf_counter() - t_write) * 1e3)
+            _SM.write_ms.record((time.monotonic_ns() - t_write) * 1e-6)
             pool = self._staging
             if pool is not None:
                 for p in pulls:

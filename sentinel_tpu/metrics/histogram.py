@@ -19,6 +19,7 @@ from __future__ import annotations
 import math
 import threading
 from bisect import bisect_left
+from itertools import accumulate
 from typing import Dict, Optional, Sequence, Tuple
 
 
@@ -88,6 +89,29 @@ class LatencyHistogram:
             self._sum += v * n
             if v > self._max:
                 self._max = v
+
+    def merge(self, counts, total_sum: float, vmax: float) -> None:
+        """Fold in observations counted elsewhere on the same bounds (the
+        C++ door's span histograms): per-bucket counts, their sum and the
+        largest of them. Counts of another length are not on these bounds
+        and are left out."""
+        if len(counts) != len(self._counts):
+            return
+        with self._lock:
+            for i, c in enumerate(counts):
+                if c > 0:
+                    self._counts[i] += int(c)
+                    self._count += int(c)
+            self._sum += max(0.0, float(total_sum))
+            if vmax > self._max:
+                self._max = float(vmax)
+
+    def cumulative(self) -> Tuple[Tuple[float, ...], Tuple[int, ...], float]:
+        """``(bounds, cumulative counts, max)``: the count at or under each
+        bound and, last, the whole count. Two of these differ into a
+        window's histogram, which ``p50`` since process start cannot give."""
+        counts, _total, _s, vmax = self._frozen()
+        return self.bounds, tuple(accumulate(counts)), vmax
 
     # -- snapshot reads -----------------------------------------------------
     @property

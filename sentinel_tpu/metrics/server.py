@@ -224,9 +224,50 @@ class ServerMetrics:
             "Completion rows those steps scattered (cumulative).",
     }
 
+    # A verdict's time at the native TCP door, from the socket's last byte
+    # in to its last byte out, all on CLOCK_MONOTONIC (time.monotonic_ns):
+    #   door_in | door_wake | intake | queue_wait | dispatch_ms |
+    #   reply_queue_wait | decide_ms | (slice + submit call) | door_out
+    # and door_residence over the whole of it. door_in, door_out and
+    # door_residence are counted per frame by the door's own threads in C++
+    # (sentinel_frontdoor.cpp) and folded in here on every read
+    # (register_door_spans); door_wake is recorded per pull by the intake
+    # lane. A frame with no stamp (the shm door, an answer the intake lane
+    # gave itself) is in none of the door's three.
+    _DOOR_SPANS = (
+        ("door_in_ms",
+         "Native TCP door, per frame: the recv() that read its last byte to "
+         "the pull that took it about to return to its caller (decode, the "
+         "wait in the arena for an intake lane, the copy to staging) (ms)."),
+        ("door_wake_ms",
+         "Native TCP door, per pull: the pull returned in C to the intake "
+         "lane running again in Python: the ctypes return and the wait for "
+         "the GIL (ms)."),
+        ("door_out_ms",
+         "Native TCP door, per frame: its verdicts submitted to the door to "
+         "send() having taken the last byte of its reply (outbox, eventfd "
+         "wake, the IO thread's turn, EPOLLOUT stalls) (ms)."),
+        ("door_residence_ms",
+         "Native TCP door, per frame: last byte in to last byte out, the "
+         "server's own verdict latency (ms)."),
+    )
+
     def __init__(self):
         for name, _help in self._PHASES:
             setattr(self, name, LatencyHistogram(lo=0.001, hi=100_000.0))
+        # door_residence_ms is the one histogram whose quantiles over a
+        # window are read by difference (stage_snapshot): 20 bounds a decade
+        # from 0.1 ms, so that a median of 3 ms is read to about 0.1 ms and
+        # not to 1
+        for name, _help in self._DOOR_SPANS:
+            setattr(self, name, (
+                LatencyHistogram(lo=0.1, hi=10_000.0, per_decade=20)
+                if name == "door_residence_ms"
+                else LatencyHistogram(lo=0.001, hi=10_000.0)
+            ))
+        # readers of live doors' span counters -> their last reading
+        self._door_span_readers: Dict[Callable[[], dict], dict] = {}
+        self._door_span_lock = threading.Lock()
         # backend compiles seen by the program's own jax.monitoring listener
         # (core/compile_cache.py); after_warmup = ended after
         # DefaultTokenService.warmup() returned, i.e. while serving
@@ -245,6 +286,9 @@ class ServerMetrics:
         self._param_impl = ("", "")
         # stage histograms, all in milliseconds except batch_size (requests).
         # 1µs..10s covers a sub-100µs device step and a 1s cold compile alike.
+        # queue_wait_ms: per queue item on the asyncio door; on the native
+        # lane per pull, from its hand-over by the intake lane (where
+        # intake_ms ends) to the start of the dispatch_ms that took it
         self.queue_wait_ms = LatencyHistogram(lo=0.001, hi=10_000.0)
         self.decide_ms = LatencyHistogram(lo=0.001, hi=10_000.0)
         self.write_ms = LatencyHistogram(lo=0.001, hi=10_000.0)
@@ -746,6 +790,47 @@ class ServerMetrics:
         except Exception:
             return {}  # a torn-down service's reader must not 500 a scrape
 
+    # -- the native door's spans ---------------------------------------------
+    def register_door_spans(self, fn: Callable[[], dict]) -> None:
+        """Install a zero-arg reader of a live server's door span counters
+        (``Frontdoor.span_stats`` shape, cumulative, summed over its doors).
+        What the doors have counted is folded into ``door_in_ms``,
+        ``door_out_ms`` and ``door_residence_ms`` by difference on every
+        read of this registry, so the series stay monotonic across servers
+        and restarts; unregister (which folds once more) before the doors
+        go."""
+        with self._door_span_lock:
+            self._door_span_readers.setdefault(fn, {})
+
+    def unregister_door_spans(self, fn: Callable[[], dict]) -> None:
+        with self._door_span_lock:
+            if fn in self._door_span_readers:
+                self._fold_door_reader(fn)
+                del self._door_span_readers[fn]
+
+    def _fold_door_spans(self) -> None:
+        with self._door_span_lock:
+            for fn in self._door_span_readers:
+                self._fold_door_reader(fn)
+
+    def _fold_door_reader(self, fn) -> None:
+        try:
+            now = fn() or {}
+        except Exception:
+            return  # a torn-down door's reader must not 500 a scrape
+        last = self._door_span_readers[fn]
+        for name, (_count, sum_ms, max_ms, counts) in now.items():
+            prev = last.get(name)
+            if prev is None:
+                d_counts, d_sum, d_max = counts, sum_ms, max_ms
+            else:
+                # the door's max is since its start: it is this stretch's
+                # own only where it grew (a reset() is not undone by it)
+                d_counts, d_sum = counts - prev[1], sum_ms - prev[0]
+                d_max = max_ms if max_ms > prev[2] else 0.0
+            getattr(self, name).merge(d_counts, d_sum, d_max)
+            last[name] = (sum_ms, counts, max_ms)
+
     # -- shm front door provider --------------------------------------------
     def register_shm_provider(self, fn: Callable[[], dict]) -> None:
         """Install the zero-arg reader for the shm ring door's counters
@@ -920,6 +1005,7 @@ class ServerMetrics:
     def snapshot(self) -> dict:
         """JSON shape served by the ``clusterServerStats`` command — the
         same numbers the Prometheus surface renders."""
+        self._fold_door_spans()
         with self._verdict_lock:
             verdicts = [
                 {"verdict": v, "namespace": ns, "count": c}
@@ -971,14 +1057,18 @@ class ServerMetrics:
                 "fused_depth": self.fused_depth.snapshot(),
                 "wait_assigned_ms": self.wait_assigned_ms.snapshot(),
                 **{name: getattr(self, name).snapshot()
-                   for name, _help in self._PHASES},
+                   for name, _help in self._PHASES + self._DOOR_SPANS},
             },
             "waitAssignedTotal": self.wait_assigned_total,
             "gauges": self._gauge_values(),
         }
 
     def stage_snapshot(self) -> Dict[str, dict]:
-        """Trimmed per-stage view for bench artifacts: p50/p99/count."""
+        """Trimmed per-stage view for bench artifacts: p50/p99/count/sum;
+        of ``door_residence_ms`` also the bounds (``le``), the cumulative
+        bucket counts (``cum``, the last the whole count) and ``max``, so
+        that two snapshots differ into the window's own quantiles."""
+        self._fold_door_spans()
         out = {}
         for name, hist in (
             ("queue_wait_ms", self.queue_wait_ms),
@@ -989,7 +1079,8 @@ class ServerMetrics:
             ("dispatch_ms", self.dispatch_ms),
             ("fused_depth", self.fused_depth),
             ("wait_assigned_ms", self.wait_assigned_ms),
-            *((name, getattr(self, name)) for name, _help in self._PHASES),
+            *((name, getattr(self, name))
+              for name, _help in self._PHASES + self._DOOR_SPANS),
         ):
             snap = hist.snapshot()
             out[name] = {
@@ -999,6 +1090,10 @@ class ServerMetrics:
                 # bench derives lane occupancy from sum/wall
                 "sum": round(snap["sum"], 3),
             }
+        le, cum, vmax = self.door_residence_ms.cumulative()
+        out["door_residence_ms"].update(
+            le=list(le), cum=list(cum), max=vmax
+        )
         out["fused_frames_total"] = self.fused_frames_total
         out["compiles_total"] = self.compiles_total
         out["compiles_after_warmup_total"] = self.compiles_after_warmup_total
@@ -1019,6 +1114,7 @@ class ServerMetrics:
     def render(self) -> str:
         """``sentinel_server_*`` Prometheus exposition (no trailing
         newline; the exporter joins sections)."""
+        self._fold_door_spans()
         lines = [
             "# HELP sentinel_server_verdicts_total Cluster token verdicts "
             "by class and namespace (cumulative).",
@@ -1351,7 +1447,9 @@ class ServerMetrics:
             lines.append(f"sentinel_server_{name} {gauges[name]:g}")
         for name, help_text, hist in (
             ("sentinel_server_queue_wait_ms",
-             "Enqueue-to-batch-drain wait per queue item (ms).",
+             "Enqueue-to-batch-drain wait per queue item (asyncio door); "
+             "on the native lane per pull, intake hand-over to the start of "
+             "the dispatch that took it (ms).",
              self.queue_wait_ms),
             ("sentinel_server_decide_ms",
              "Materialize per batch: wait for the device, unpack, unsort. "
@@ -1378,7 +1476,7 @@ class ServerMetrics:
              "priority occupy delay (ms).",
              self.wait_assigned_ms),
             *((f"sentinel_server_{name}", help_text, getattr(self, name))
-              for name, help_text in self._PHASES),
+              for name, help_text in self._PHASES + self._DOOR_SPANS),
         ):
             lines.append(hist.render_prometheus(name, help_text))
         for name, help_text, value in (
@@ -1445,6 +1543,11 @@ class ServerMetrics:
         self.fused_depth.reset()
         self.wait_assigned_ms.reset()
         for name, _help in self._PHASES:
+            getattr(self, name).reset()
+        # what the live doors counted so far goes with the reset: fold it
+        # in first, so that only what they count from here on comes back
+        self._fold_door_spans()
+        for name, _help in self._DOOR_SPANS:
             getattr(self, name).reset()
         with self._compile_lock:
             self._compiles = 0

@@ -78,13 +78,16 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         "sn_fd_port": ([P], I32),
         "sn_fd_stop": ([P], None),
         "sn_fd_destroy": ([P], None),
+        # the frame columns end with f_rx_ns (i64: the frame's rx stamp),
+        # and the last out-value is wake_ns (door spans, see the .cpp head)
         "sn_fd_wait_batch": (
             [
                 P, I32, ctypes.POINTER(I64), ctypes.POINTER(I32),
                 ctypes.POINTER(ctypes.c_uint8), I32, ctypes.POINTER(I32),
                 ctypes.POINTER(I32), ctypes.POINTER(I32),
-                ctypes.POINTER(I32), ctypes.POINTER(ctypes.c_uint8), I32,
-                ctypes.POINTER(I32),
+                ctypes.POINTER(I32), ctypes.POINTER(ctypes.c_uint8),
+                ctypes.POINTER(I64), I32, ctypes.POINTER(I32),
+                ctypes.POINTER(I64),
             ],
             I32,
         ),
@@ -95,8 +98,9 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
                 ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(I64), I32,
                 I32, ctypes.POINTER(I32), ctypes.POINTER(I32),
                 ctypes.POINTER(I32), ctypes.POINTER(I32),
-                ctypes.POINTER(ctypes.c_uint8), I32, ctypes.POINTER(I32),
-                ctypes.POINTER(I32),
+                ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(I64), I32,
+                ctypes.POINTER(I32), ctypes.POINTER(I32),
+                ctypes.POINTER(I64),
             ],
             I32,
         ),
@@ -104,11 +108,17 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
             [
                 P, I32, ctypes.POINTER(I32), ctypes.POINTER(I32),
                 ctypes.POINTER(I32), ctypes.POINTER(I32),
-                ctypes.POINTER(ctypes.c_uint8),
+                ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(I64),
                 ctypes.POINTER(ctypes.c_int8), ctypes.POINTER(I32),
                 ctypes.POINTER(I32),
             ],
             None,
+        ),
+        "sn_fd_set_span_bounds": (
+            [P, I32, ctypes.POINTER(I64), I32], None
+        ),
+        "sn_fd_span_stats": (
+            [P, I32, ctypes.POINTER(ctypes.c_uint64), I32], I32
         ),
         "sn_fd_send": ([P, I32, I32, ctypes.c_char_p, I32], None),
         "sn_fd_next_control": (
@@ -477,9 +487,21 @@ class Frontdoor:
     blocked), runs the device step, and answers with :meth:`submit`.
     Control-plane frames (PING, param, concurrent) surface through
     :meth:`next_control`; replies go back via :meth:`send`.
+
+    Spans, all on ``time.monotonic_ns()``'s clock: a data frame is stamped
+    when the ``recv()`` that completed it returned. A pull hands the stamps
+    out as the frames' sixth column (``f_rx_ns``) beside ``wake_ns``, taken
+    in C just before the pull returns to ``ctypes``; :meth:`submit` hands
+    them back, and the IO thread closes each frame's span when ``send()``
+    has taken the last byte of its reply. :meth:`span_stats` reads the three
+    histograms the door counts where the spans end (:data:`SPANS`).
     """
 
     CTRL_FRAME, CTRL_OPEN, CTRL_CLOSE = 0, 1, 2
+    # rx -> pull about to return; submit entered -> last byte sent; rx ->
+    # last byte sent. The order of sn_fd_span_stats.
+    SPANS = ("door_in_ms", "door_out_ms", "door_residence_ms")
+    MAX_SPAN_BOUNDS = 128  # kMaxSpanBounds of sentinel_frontdoor.cpp
 
     def __init__(self, host: str = "127.0.0.1", port: int = 0,
                  arena_cap: int = 65536):
@@ -529,9 +551,26 @@ class Frontdoor:
                 f_xid=np.empty(cap, np.int32),
                 f_n=np.empty(cap, np.int32),
                 f_type=np.empty(cap, np.uint8),
+                f_rx_ns=np.empty(cap, np.int64),
+                wake_ns=np.zeros(1, np.int64),
             )
             self._tls.bufs = b
         return b
+
+    def _span_ptrs(self, staging: dict):
+        """The ``f_rx_ns`` column and the ``wake_ns`` cell of a staging
+        block, or nulls for a block without them (the door then neither
+        stamps the pull nor counts its ``door_in_ms``)."""
+        ptrs = staging.get("_span_ptrs")
+        if ptrs is None:
+            # made once per block (blocks are recycled; ``data_as`` costs
+            # microseconds) and kept beside the arrays they point into
+            rx, wake = staging.get("f_rx_ns"), staging.get("wake_ns")
+            ptrs = staging["_span_ptrs"] = (
+                None if rx is None else self._ptr(rx, ctypes.c_int64),
+                None if wake is None else self._ptr(wake, ctypes.c_int64),
+            )
+        return ptrs
 
     def _ptr(self, arr, ctype):
         return arr.ctypes.data_as(ctypes.POINTER(ctype))
@@ -561,14 +600,16 @@ class Frontdoor:
             self._ptr(b["f_xid"], ctypes.c_int32),
             self._ptr(b["f_n"], ctypes.c_int32),
             self._ptr(b["f_type"], ctypes.c_uint8),
+            self._ptr(b["f_rx_ns"], ctypes.c_int64),
             self.arena_cap, ctypes.byref(n_frames),
+            self._ptr(b["wake_ns"], ctypes.c_int64),
         )
         if n <= 0:
             return None
         k = n_frames.value
         frames = (
             b["f_fd"][:k], b["f_gen"][:k], b["f_xid"][:k], b["f_n"][:k],
-            b["f_type"][:k],
+            b["f_type"][:k], b["f_rx_ns"][:k],
         )
         return (
             b["ids"][:n], b["counts"][:n],
@@ -587,7 +628,8 @@ class Frontdoor:
         verdicts for those rows have been submitted. ``max_n`` additionally
         clamps to the staging row capacity, and the frame-array length
         bounds how many frames one pull may take (the remainder stays
-        queued)."""
+        queued). A block that has them gets the frames' rx stamps in
+        ``f_rx_ns[:k]`` and the pull's wake stamp in ``wake_ns[0]``."""
         from sentinel_tpu.cluster.protocol import MAX_BATCH_PER_FRAME
 
         cap = int(staging["ids"].shape[0])
@@ -598,6 +640,7 @@ class Frontdoor:
             max(int(max_n), MAX_BATCH_PER_FRAME), cap, self.arena_cap
         )
         n_frames = ctypes.c_int32()
+        rx, wake = self._span_ptrs(staging)
         n = self._lib.sn_fd_wait_batch(
             self._h, timeout_ms,
             self._ptr(staging["ids"], ctypes.c_int64),
@@ -609,7 +652,7 @@ class Frontdoor:
             self._ptr(staging["f_xid"], ctypes.c_int32),
             self._ptr(staging["f_n"], ctypes.c_int32),
             self._ptr(staging["f_type"], ctypes.c_uint8),
-            max_f, ctypes.byref(n_frames),
+            rx, max_f, ctypes.byref(n_frames), wake,
         )
         if n <= 0:
             return None
@@ -624,7 +667,8 @@ class Frontdoor:
         k)``: ``k`` is 0 for a flow pull, else the values per request of a
         param pull, whose hashes then fill ``staging["hashes"][:n * k]``
         request-major (a pull takes frames of one ``k`` and at most as
-        many values as that array holds)."""
+        many values as that array holds). The stamps as
+        :meth:`wait_batch_into` leaves them."""
         from sentinel_tpu.cluster.protocol import MAX_BATCH_PER_FRAME
 
         cap = int(staging["ids"].shape[0])
@@ -635,6 +679,7 @@ class Frontdoor:
         )
         n_frames = ctypes.c_int32()
         k = ctypes.c_int32()
+        rx, wake = self._span_ptrs(staging)
         n = self._lib.sn_fd_wait_any(
             self._h, timeout_ms,
             self._ptr(staging["ids"], ctypes.c_int64),
@@ -647,23 +692,33 @@ class Frontdoor:
             self._ptr(staging["f_xid"], ctypes.c_int32),
             self._ptr(staging["f_n"], ctypes.c_int32),
             self._ptr(staging["f_type"], ctypes.c_uint8),
-            int(staging["f_fd"].shape[0]), ctypes.byref(n_frames),
-            ctypes.byref(k),
+            rx, int(staging["f_fd"].shape[0]), ctypes.byref(n_frames),
+            ctypes.byref(k), wake,
         )
         if n <= 0:
             return None
         return n, n_frames.value, k.value
 
     def submit(self, frames, status, remaining, wait_ms) -> None:
-        """Encode + send verdict frames for a ``wait_batch`` result."""
+        """Encode + send verdict frames for a ``wait_batch`` result. A
+        frames tuple with the sixth column (``f_rx_ns``) hands the frames'
+        rx stamps back, and the door counts ``door_out_ms`` and
+        ``door_residence_ms`` of each frame whose stamp is not 0 when its
+        reply has gone out; five columns count nothing."""
         import numpy as np
 
         # every array binds to a local: an unnamed ascontiguousarray copy
         # would be freed the moment _ptr() returns, leaving sn_fd_submit
         # reading freed memory whenever a caller passes a non-contiguous
         # or wrongly-typed array
-        f_fd, f_gen, f_xid, f_n, f_type = frames
+        f_fd, f_gen, f_xid, f_n, f_type = frames[:5]
+        f_rx_ns = (
+            np.ascontiguousarray(frames[5], np.int64)
+            if len(frames) > 5 else None
+        )
         f_fd = np.ascontiguousarray(f_fd, np.int32)
+        if f_rx_ns is not None and len(f_rx_ns) != len(f_fd):
+            raise ValueError("one rx stamp per frame")
         f_gen = np.ascontiguousarray(f_gen, np.int32)
         f_xid = np.ascontiguousarray(f_xid, np.int32)
         f_n = np.ascontiguousarray(f_n, np.int32)
@@ -678,6 +733,7 @@ class Frontdoor:
             self._ptr(f_xid, ctypes.c_int32),
             self._ptr(f_n, ctypes.c_int32),
             self._ptr(f_type, ctypes.c_uint8),
+            None if f_rx_ns is None else self._ptr(f_rx_ns, ctypes.c_int64),
             self._ptr(status, ctypes.c_int8),
             self._ptr(remaining, ctypes.c_int32),
             self._ptr(wait_ms, ctypes.c_int32),
@@ -698,7 +754,7 @@ class Frontdoor:
             return self.submit(frames_list[0], status, remaining, wait_ms)
         merged = tuple(
             np.concatenate([np.asarray(fr[i]) for fr in frames_list])
-            for i in range(5)
+            for i in range(min(len(fr) for fr in frames_list))
         )
         self.submit(merged, status, remaining, wait_ms)
 
@@ -754,6 +810,45 @@ class Frontdoor:
             "frames_in": int(out[0]), "requests_in": int(out[1]),
             "bytes_in": int(out[2]), "bytes_out": int(out[3]),
         }
+
+    def set_span_bounds(self, name: str, bounds_ms) -> None:
+        """Hand the door the bucket bounds (ms, ascending; at most
+        :data:`MAX_SPAN_BOUNDS`) of the host's histogram for span histogram ``name`` (one of
+        :data:`SPANS`): once, at start. Without them a span still counts
+        into count, sum and max."""
+        import numpy as np
+
+        ns = np.ascontiguousarray(
+            np.rint(np.asarray(bounds_ms, np.float64) * 1e6), np.int64
+        )
+        if len(ns) > self.MAX_SPAN_BOUNDS:
+            raise ValueError(
+                f"{len(ns)} span bounds; the door holds "
+                f"{self.MAX_SPAN_BOUNDS}"
+            )
+        self._lib.sn_fd_set_span_bounds(
+            self._h, self.SPANS.index(name),
+            self._ptr(ns, ctypes.c_int64), len(ns),
+        )
+
+    def span_stats(self) -> dict:
+        """``{name: (count, sum_ms, max_ms, bucket counts)}`` for each of
+        :data:`SPANS`, cumulative since the door started; the bucket counts
+        are per bucket of the bounds handed over, the last the overflow.
+        Relaxed atomics like :meth:`stats`: no one consistent snapshot."""
+        import numpy as np
+
+        out = {}
+        buf = np.zeros(self.MAX_SPAN_BOUNDS + 4, np.uint64)
+        for which, name in enumerate(self.SPANS):
+            n = self._lib.sn_fd_span_stats(
+                self._h, which, self._ptr(buf, ctypes.c_uint64), len(buf)
+            )
+            out[name] = (
+                int(buf[0]), float(buf[1]) * 1e-6, float(buf[2]) * 1e-6,
+                buf[3:n].astype(np.int64),
+            )
+        return out
 
     def echo_start(self) -> None:
         """Bench/test helper: a pure-C wait→all-GRANTED-submit loop — the
@@ -872,6 +967,11 @@ class ShmDoor:
         )
         if n <= 0:
             return None
+        # the ring stamps nothing: 0 is "no stamp" to whoever reads the
+        # block's span cells (the intake lane, Frontdoor.submit)
+        if "f_rx_ns" in staging:
+            staging["f_rx_ns"][:n_frames.value] = 0
+            staging["wake_ns"][0] = 0
         return n, n_frames.value
 
     def wait_batch(self, timeout_ms: int = 100, max_n: Optional[int] = None):
@@ -910,7 +1010,7 @@ class ShmDoor:
     def submit(self, frames, status, remaining, wait_ms) -> None:
         import numpy as np
 
-        f_fd, f_gen, f_xid, f_n, f_type = frames
+        f_fd, f_gen, f_xid, f_n, f_type = frames[:5]  # a 6th: rx stamps
         f_fd = np.ascontiguousarray(f_fd, np.int32)
         f_gen = np.ascontiguousarray(f_gen, np.int32)
         f_xid = np.ascontiguousarray(f_xid, np.int32)

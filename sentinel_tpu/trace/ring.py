@@ -47,7 +47,9 @@ DEVICE_OUT = 5   # verdicts on the host AND counted (record_verdict_batch done;
 #                  shard = lane | the step's live arm bits << ARM_SHIFT). On
 #                  the native lane the counting runs after the reply was
 #                  submitted, so there it FOLLOWS the dispatch's REPLY_OUT
-REPLY_OUT = 6    # frame's reply encoded + submitted to its door (aux = rows)
+REPLY_OUT = 6    # frame's reply submitted to its door (aux = rows): parked in
+#                  the door's outbox, NOT on the wire; the native TCP door's
+#                  IO thread sends it later (its always-on ``door_out_ms``)
 SHED = 7         # frame/rows refused (aux = shed-reason index)
 FUSE = 8         # fusion ladder stacked frames (aggregate; aux = depth)
 LEASE = 9        # lease grant/renew/return on the server (aux = tokens)
@@ -71,9 +73,9 @@ ARM_SHIFT = 4
 # sequence number (taken under the service lock), so one dispatch's
 # boundaries join across the dispatching thread's ring and the
 # materializing thread's (``spans.dispatch_phases``). In time order:
-# PERMIT, PREP, LOCKED, DEVICE_IN | READY, FETCHED, ACCOUNT, DEVICE_OUT
-# (the last four are written by the account half with their own stamps; on
-# the native lane the reply goes out between FETCHED and ACCOUNT).
+# PERMIT, PREP, LOCKED, DEVICE_IN | REPLY_TAKEN, READY, FETCHED, ACCOUNT,
+# DEVICE_OUT (the last four are written by the account half with their own
+# stamps; on the native lane the reply goes out between FETCHED and ACCOUNT).
 PERMIT = 17      # device permit acquired (native lane only; aux = wait, us)
 PREP = 18        # host prep done, about to ask for the service lock
 LOCKED = 19      # service lock acquired
@@ -85,6 +87,15 @@ OUTCOME_IN = 23  # a completion report reached the server: t_ns is when its
 #                  door queued it, or the in-process call began (xid = the
 #                  report's; aux = its rows; shard as its OUTCOME's). The
 #                  span OUTCOME_IN -> OUTCOME is the report's age at ingest
+RX = 25          # the native TCP door read the frame's last byte: t_ns is the
+#                  door's own stamp (its IO thread's, back-dated as
+#                  OUTCOME_IN's), written by the intake lane beside CLIENT_IN
+#                  (aux = rows). RX -> CLIENT_IN is door_in + door_wake
+REPLY_TAKEN = 26  # a native reply lane's get() returned with a dispatched
+#                  group (aggregate, xid=0; aux = its wait in the reply queue,
+#                  us, as PERMIT's; the lane's next READY is its dispatch's).
+#                  Splits DEVICE_IN -> READY into reply-queue wait and what
+#                  was left of the device step
 
 STAGE_NAMES: Dict[int, str] = {
     CLIENT_IN: "client_in",
@@ -111,6 +122,8 @@ STAGE_NAMES: Dict[int, str] = {
     COMPILE: "compile",
     OUTCOME_IN: "outcome_in",
     ACCOUNT: "account",
+    RX: "rx",
+    REPLY_TAKEN: "reply_taken",
 }
 
 # one ring row: 24 bytes, fixed
@@ -201,13 +214,21 @@ def record(stage: int, xid: int = 0, shard: int = 0, aux: int = 0,
     )
 
 
-def record_many(stage: int, xids, shard: int = 0, aux: int = 0) -> None:
+def record_many(stage: int, xids, shard: int = 0, aux: int = 0,
+                t_ns=None) -> None:
     """One event per sampled xid in ``xids`` (a batch hop touching many
     frames). Python-loop cost is paid only while armed and only for
-    sampled xids."""
+    sampled xids. ``t_ns``, one stamp per xid, back-dates each event to a
+    boundary its owner could not write (a 0 leaves that xid out)."""
     r = _ring()
-    t = time.monotonic_ns()
     lim = _SAMPLE_LIMIT
+    if t_ns is not None:
+        for x, t in zip(xids, t_ns):
+            x, t = int(x), int(t)
+            if t and ((x * _HASH_MULT) & 0xFFFFFFFF) < lim:
+                r.write(t, stage, x, shard, aux)
+        return
+    t = time.monotonic_ns()
     for x in xids:
         x = int(x)
         if ((x * _HASH_MULT) & 0xFFFFFFFF) < lim:

@@ -74,8 +74,9 @@ def completeness(spans: List[dict]) -> dict:
 
 # dispatch-side boundaries land in the dispatching thread's ring, the rest
 # in the materializing thread's; (service id, sequence number) joins them
-_PHASE_STAGES = {_R.PERMIT, _R.PREP, _R.LOCKED, _R.DEVICE_IN, _R.READY,
-                 _R.FETCHED, _R.ACCOUNT, _R.DEVICE_OUT}
+_PHASE_STAGES = {_R.PERMIT, _R.PREP, _R.LOCKED, _R.DEVICE_IN,
+                 _R.REPLY_TAKEN, _R.READY, _R.FETCHED, _R.ACCOUNT,
+                 _R.DEVICE_OUT}
 
 
 def dispatch_phases(since_ns: Optional[int] = None,
@@ -86,8 +87,13 @@ def dispatch_phases(since_ns: Optional[int] = None,
     granted → prep done; None without the native lane's ``permit`` event),
     ``permitWaitMs`` (the ``permit`` event's own aux), ``lockWaitMs``,
     ``launchMs`` (lock held, to ``device_in``), ``waitMs`` (``device_in`` →
-    the verdict buffer on the host: reply-queue wait plus what was left of
-    the device step), ``fetchMs``, ``accountMs`` (``account``, the account
+    the verdict buffer on the host) and its two halves on the native lane,
+    which the always-on ``reply_queue_wait_ms`` and ``device_wait_ms`` keep
+    apart: ``replyQueueWaitMs`` (``device_in`` → ``reply_taken``, a reply
+    lane's ``get()`` returned) and ``deviceWaitMs`` (``reply_taken`` →
+    ``ready``: what was left of the device step); both None where no reply
+    lane took the dispatch (the asyncio door, a synchronous call).
+    ``fetchMs``, ``accountMs`` (``account``, the account
     half's own start, to ``device_out``: the verdict counters; the stat-log
     passes after it are in the always-on ``account_ms`` only; the reply the
     native lane submits between ``fetched`` and ``account`` is in neither).
@@ -104,11 +110,14 @@ def dispatch_phases(since_ns: Optional[int] = None,
 
     for thread, evs in by_thread.items():
         permit = None  # the last permit not yet claimed by a dispatch
+        taken = None  # the last reply_taken not yet claimed by a ``ready``
         cur = None  # the dispatch whose boundaries this thread is writing
         for e in evs:
             st = e["stage"]
             if st == "permit":
                 permit = e
+            elif st == "reply_taken":
+                taken = e
             elif st in ("prep", "ready"):
                 cur = out.setdefault((e["shard"], e["aux"]), {
                     "service": e["shard"], "seq": e["aux"]})
@@ -118,6 +127,9 @@ def dispatch_phases(since_ns: Optional[int] = None,
                     cur["permit"] = permit["t_ns"]
                     cur["permitWaitMs"] = permit["aux"] / 1e3
                     permit = None
+                if st == "ready" and taken is not None:
+                    cur["reply_taken"] = taken["t_ns"]
+                    taken = None
             elif cur is not None and (e["shard"], e["aux"]) == (
                     cur["service"], cur["seq"]):
                 cur[st] = e["t_ns"]  # locked, fetched, account
@@ -139,6 +151,8 @@ def dispatch_phases(since_ns: Optional[int] = None,
             "lockWaitMs": ms(g("prep"), g("locked")),
             "launchMs": ms(g("locked"), g("device_in")),
             "waitMs": ms(g("device_in"), g("ready")),
+            "replyQueueWaitMs": ms(g("device_in"), g("reply_taken")),
+            "deviceWaitMs": ms(g("reply_taken"), g("ready")),
             "fetchMs": ms(g("ready"), g("fetched")),
             "accountMs": ms(g("account"), g("device_out")),
             "complete": all(k in d for k in (

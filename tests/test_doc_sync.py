@@ -444,6 +444,12 @@ class TestPerfRecordSync:
         "decide_rows_total",
         "decide_all_arms_live_total",
         "decide_shaped_rows_total",
+        # the door's two halves and the native lane's queue wait (PR 38)
+        "door_in_ms",
+        "door_wake_ms",
+        "door_out_ms",
+        "door_residence_ms",
+        "queue_wait_ms",
     ])
     def test_record_names_what_the_program_snapshots(self, name):
         from sentinel_tpu.metrics.server import ServerMetrics

@@ -9,8 +9,9 @@ step alone at a deployment's size.
    with ``impl="jax"`` and, where Mosaic takes the kernel, ``impl="pallas"``.
 2. The serve step of every serve bucket of the configuration, on a sketch of
    its own size: what ``impl="auto"`` resolves to there and why, milliseconds
-   a step (chained on the donated state, one blocking read at the end), and
-   the device's peak memory.
+   a step (chained on the donated state, one blocking read at the end), the
+   device's time a step and that of the ``param_commit`` scope's operations
+   from a short profile (PR 39), and the device's peak memory.
 
 Exits 2 without a TPU, 1 on a mismatch. Its times are of the step alone, one
 thread, nothing else on the host: not a cell's.
@@ -65,11 +66,49 @@ def exactness() -> int:
     return bad
 
 
+def _commit_device_ms(profile_dir: str, program: str, hlo: str):
+    """``(device ms a run of ``program``, ms a run in the operations of the
+    ``param_commit`` scope, [[op, us a run]] of the six longest)`` from the
+    profiler's trace; the scope of an operation is read off the compiled
+    program's own text (``op_name`` in its metadata). ``(None, None, [])``
+    where the trace holds no TPU plane."""
+    import re
+
+    from cellbench import trace as T
+
+    scope = {m.group(1): m.group(2) for m in re.finditer(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?op_name=\"([^\"]*)\"", hlo, re.M)}
+    try:
+        planes = T.Trace(T.find_xplane(profile_dir)).devices
+    except (FileNotFoundError, ValueError):
+        planes = {}
+    for plane in planes.values():
+        names, start, dur = plane["modules"]
+        mine = [i for i, n in enumerate(names) if str(n).startswith(program)]
+        if not mine:
+            continue
+        ops = {}
+        for name, d in zip(*plane["ops"][::2]):
+            name = T.short_name(name)
+            if "param_commit" in scope.get(name, ""):
+                ops[name] = ops.get(name, 0.0) + d
+        runs = len(mine)
+        return (float(dur[mine].sum()) / runs / 1e6,
+                sum(ops.values()) / runs / 1e6,
+                [[op, ns / runs / 1e3] for op, ns in
+                 sorted(ops.items(), key=lambda kv: -kv[1])[:6]])
+    return None, None, []
+
+
 def step_alone(config_file: str) -> None:
+    import shutil
+    import tempfile
+
     import jax
     import numpy as np
 
     from sentinel_tpu.engine.param import (
+        ROW_THRESHOLD,
         ParamConfig,
         explain_param_impl,
         hash_indices,
@@ -112,6 +151,32 @@ def step_alone(config_file: str) -> None:
               f"ms/step, {times[1]:.3f} ms/step over a bucket boundary; "
               f"blocked {int((np.asarray(verdicts)[0] == 1).sum())} of "
               f"{bucket}", flush=True)
+        # 20 steps more under the profiler, with a threshold no row meets:
+        # every row is admitted, so every step commits bucket x depth cells
+        # (the timed steps above refuse most rows once a value has its 5)
+        packed[ROW_THRESHOLD] = np.float32(2.0**30).view(np.int32)
+        hlo = step.lower(state, packed).compile().as_text()
+        profile = tempfile.mkdtemp(prefix="param_step_")
+        try:
+            jax.profiler.start_trace(profile)
+            for _ in range(20):
+                state, verdicts = step(state, packed)
+            jax.block_until_ready(verdicts)
+            jax.profiler.stop_trace()
+            device, commit, ops = _commit_device_ms(
+                profile, f"jit_param_decide_b{bucket}", hlo)
+        finally:
+            shutil.rmtree(profile, ignore_errors=True)
+        if device is None:
+            print(f"commit b{bucket}: not measured (no TPU plane in the "
+                  f"profile)", flush=True)
+        else:
+            cells = bucket * cfg.depth
+            print(f"commit b{bucket}: device {device:.4f} ms/step with every "
+                  f"row admitted, of it param_commit {commit:.4f} ms "
+                  f"({commit * 1e6 / cells:.0f} ns a cell of {cells}): "
+                  + ", ".join(
+                      f"{op} {us:.1f} us" for op, us in ops), flush=True)
     stats = jax.devices()[0].memory_stats() or {}
     print(f"peak_bytes_in_use {stats.get('peak_bytes_in_use')}, "
           f"bytes_in_use {stats.get('bytes_in_use')}", flush=True)

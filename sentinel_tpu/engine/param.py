@@ -363,7 +363,11 @@ def make_param_step(config: ParamConfig, bucket: int, kernel: str):
     **flat** (``make_param_state(config, flat=True)``): the step updates the
     sketch in place and touches what its rows touch (plus, once per
     ``bucket_ms``, the plane of the bucket that went stale), with no layout
-    conversion around the scatter. The plain count-min core on XLA and the
+    conversion around the gathers or the commit: the gathers take the flat
+    cells, and the commit (``ops/cms_commit.py``, PR 39: the admitted
+    pairs sorted by cell, each touched row of 128 cells read, added to and
+    written back once by DMA) sees the same bytes as rows of 128 through a
+    bitcast. The plain count-min core on XLA and the
     slim twin's steps work on the flat cells; any other kernel (Pallas,
     SALSA) is handed its 4-D view inside the step. ``verdicts`` holds (status, remaining, wait_ms) per
     *request*, in request order: a request passes only if every one of its
@@ -491,10 +495,11 @@ def _param_decide_jax(
 
     ``state.counts`` is ``[P, B, depth, width]`` or the same cells flat
     (``make_param_state(config, flat=True)``), and comes back as it came.
-    The work is done on the flat cells either way: the TPU's scatter wants
-    its operand flat, and converting a tiled 4-D sketch there and back costs
-    two copies of the whole sketch a call (at 0.5 GiB, 2.6 ms), which a
-    caller that keeps the sketch flat (the serve step) never pays.
+    The work is done on the flat cells either way: the TPU's gather wants
+    its operand flat (and the commit's rows of 128 are a bitcast of it),
+    and converting a tiled 4-D sketch there and back costs two copies of
+    the whole sketch a call (at 0.5 GiB, 2.6 ms), which a caller that keeps
+    the sketch flat (the serve step) never pays.
     """
     shape = state.counts.shape
     flat, starts, admit, estimate = _cms_flat(
@@ -509,7 +514,20 @@ def _cms_flat(config, counts, starts, rule_slot, idx, acquire, threshold,
               valid, now):
     """The plain count-min core on flat cells ``int32[P*B*depth*width]``
     (cell ``(p, b, d, w)`` at ``((p*B + b)*depth + d)*width + w``):
-    ``-> (counts', starts', admit, estimate)``."""
+    ``-> (counts', starts', admit, estimate)``.
+
+    The commit is ``ops/cms_commit.py`` (PR 39) and the only one this core
+    has, on every backend (off the TPU the same kernel runs under the Pallas
+    interpreter). What it replaced was ``counts.at[cells].add(acquires,
+    mode="drop")``: on the TPU a read-modify-write per update against HBM,
+    one after the other, 92 ns a cell in the step and 110 alone, which was
+    half of ``hot-param-1k``'s device time. Sorted, pre-reduced and
+    duplicate-free indices with ``unique_indices=True`` cost the same 110
+    ns; ``indices_are_sorted=True`` made it a pass over the whole sketch
+    (1.6 ms at any batch); writing ``old + total`` by a ``set`` cost 128.
+    The kernel sorts the pairs and moves each touched row of 128 cells by
+    DMA, a chunk's worth in flight: 36 ns a cell (my chip runs, PR 39:
+    PERF.md section 6)."""
     now = jnp.asarray(now, jnp.int32)
     P, B, D, W = fat_shape(config)
     size = P * B * D * W
@@ -527,7 +545,11 @@ def _cms_flat(config, counts, starts, rule_slot, idx, acquire, threshold,
             cell = jax.lax.iota(jnp.int32, size)
             return jnp.where((cell // (D * W)) % B == cur_idx, 0, c)
 
-        counts = jax.lax.cond(stale, clear, lambda c: c, counts)
+        # the barrier keeps the compiler from moving the commit's view of
+        # the cells as rows of 128 into the branches: at 64 rows it did, and
+        # answered with a copy of the whole sketch on every call (PR 39)
+        counts = jax.lax.optimization_barrier(
+            jax.lax.cond(stale, clear, lambda c: c, counts))
         starts = starts.at[cur_idx].set(cur_start)
         age = now - starts
         bucket_ok = (age >= 0) & (age < config.interval_ms)  # [B]
@@ -576,11 +598,16 @@ def _cms_flat(config, counts, starts, rule_slot, idx, acquire, threshold,
             )
 
     with jax.named_scope("param_commit"):
-        # scatter admitted acquires into all depth lanes of the current
-        # bucket; refused rows go out of bounds and are dropped
+        # admitted acquires into all depth lanes of the current bucket;
+        # refused rows go past the end and are dropped
+        from sentinel_tpu.ops.cms_commit import commit_cells
+
         cur_cell = base + cur_idx * (D * W)
-        counts = counts.at[jnp.where(admit[:, None], cur_cell, size)].add(
-            acq[:, None].repeat(D, 1), mode="drop"
+        counts = commit_cells(
+            counts,
+            jnp.where(admit[:, None], cur_cell, size).reshape(-1),
+            jnp.broadcast_to(acq[:, None], cur_cell.shape).reshape(-1),
+            interpret=jax.default_backend() != "tpu",
         )
 
     return counts, starts, admit, estimate

@@ -9,13 +9,20 @@ arrays, CAS window loops — map to device kernels here, not to C/C++):
 - :mod:`sentinel_tpu.ops.cms_pallas` — the count-min-sketch decide+update
   kernel: whole sketch resident in VMEM, gathers/scatters expressed as
   one-hot MXU matmuls.
+- :mod:`sentinel_tpu.ops.cms_commit` — the plain count-min core's commit
+  (PR 39): the batch's ``(cell, amount)`` pairs sorted, each touched row of
+  128 cells of the HBM-resident sketch read, added to and written back once
+  by DMA.
 
-Every kernel has a pure-jax reference implementation elsewhere in the tree
+The first two have a pure-jax reference implementation elsewhere in the tree
 (`engine/prefix.py`, `engine/param.py`). A kernel a config selects is
 compiled by Mosaic or raises: nothing here derives ``interpret=`` from the
 backend. The CPU parity tests ask for the interpreter themselves
 (``interpret=True`` on the kernel entry points, or the ``pallas_interpret``
-fixture in ``tests/conftest.py`` around a config-selected step).
+fixture in ``tests/conftest.py`` around a config-selected step). The commit
+kernel is no config's choice: it is the only commit the XLA core
+(``impl="jax"``) has, so its one caller (``engine/param._cms_flat``) runs the
+same kernel under the Pallas interpreter where the backend is not a TPU.
 """
 
 import jax

@@ -28,6 +28,9 @@ from sentinel_tpu.core.clock import ManualClock
 from sentinel_tpu.engine import EngineConfig, TokenStatus
 from sentinel_tpu.engine.param import (
     ParamConfig,
+    _cms_flat,
+    _param_decide_jax,
+    fat_shape,
     make_param_state,
     make_param_step,
     pack_param_rows,
@@ -308,6 +311,139 @@ def test_a_stale_bucket_is_cleared_once_and_only_its_plane():
     assert counts[:, 0].sum() == 4 and counts[:, 1].sum() == 4
     state = one(state, 1_200)        # same bucket: not cleared again
     assert cells(state)[0, 0].sum() == 8
+
+
+def _flat_reference(counts, starts, slot, idx, acquire, threshold, valid,
+                    now):
+    """``_cms_flat`` as plain numpy on the same flat cells: the lazy roll,
+    the windowed estimate, the in-batch admission (three passes over the
+    earlier admitted rows of the same (rule, index tuple), as the step
+    makes them) and the commit as one ``np.add.at``: an update a cell and
+    lane, in batch order, duplicates and all."""
+    n_p, n_b, depth, width = fat_shape(PCFG)
+    counts, starts = counts.copy(), starts.copy()
+    cur = (now // PCFG.bucket_ms) % n_b
+    cur_start = now - now % PCFG.bucket_ms
+    view = counts.reshape(n_p, n_b, depth, width)
+    if starts[cur] != cur_start:
+        view[:, cur] = 0
+    starts[cur] = cur_start
+    age = now - starts
+    bucket_ok = (age >= 0) & (age < PCFG.interval_ms)
+    live = valid & (slot >= 0)
+    lanes = np.arange(depth)
+    estimate = np.zeros(len(slot), np.int32)
+    for i in np.flatnonzero(live):
+        per = view[slot[i], :, lanes, idx[i]]  # [depth, buckets]
+        estimate[i] = (per * bucket_ok[None, :]).sum(1).min()
+    keys = [(int(slot[i]), *idx[i].tolist()) if live[i] else None
+            for i in range(len(slot))]
+    acq = acquire.astype(np.int32)
+    admit = live.copy()
+    for _ in range(3):
+        seen, prefix = {}, np.zeros(len(slot), np.float32)
+        for i, key in enumerate(keys):
+            prefix[i] = seen.get(key, 0)
+            if admit[i]:
+                seen[key] = seen.get(key, 0) + int(acq[i])
+        admit = live & (estimate.astype(np.float32) + prefix
+                        + acq.astype(np.float32) <= threshold)
+    cell = ((slot[:, None] * n_b + cur) * depth + lanes[None]) * width + idx
+    np.add.at(counts, cell[admit].reshape(-1), np.repeat(acq[admit], depth))
+    return counts, starts, admit
+
+
+def _commit_batch(rows, seed):
+    """``rows`` seeded rows over every rule slot at the tests' geometry
+    (8 slots x 512 cells a lane: from 1,024 rows up most cells are hit by
+    several rows), a tenth of them on one value."""
+    rng = np.random.default_rng(seed)
+    slot = rng.integers(0, PCFG.max_param_rules, rows).astype(np.int32)
+    idx = rng.integers(0, PCFG.width, (rows, PCFG.depth)).astype(np.int32)
+    crowd = rng.random(rows) < 0.1
+    slot[crowd], idx[crowd] = 5, idx[0]
+    return dict(slot=slot, idx=idx,
+                acquire=np.ones(rows, np.int32),
+                threshold=np.full(rows, 6.0, np.float32),
+                valid=np.ones(rows, bool))
+
+
+def _commit_cases():
+    def batch(slot, idx, acquire=1, threshold=50.0, valid=True):
+        n = len(slot)
+        return dict(
+            slot=np.asarray(slot, np.int32),
+            idx=np.asarray(idx, np.int32).reshape(n, PCFG.depth),
+            acquire=np.broadcast_to(np.int32(acquire), (n,)).copy(),
+            threshold=np.broadcast_to(np.float32(threshold), (n,)).copy(),
+            valid=np.broadcast_to(np.bool_(valid), (n,)).copy())
+
+    rng = np.random.default_rng(39)
+    one, other = [1, 7, 3, 4], [2, 7, 5, 6]  # they meet in lane 1 alone
+    mixed = _commit_batch(64, 1)
+    mixed["acquire"] = rng.integers(1, 6, 64).astype(np.int32)
+    mixed["threshold"][:] = 11.0
+    padded = _commit_batch(64, 2)
+    padded["slot"][40:] = -1           # padding, as pack_param_rows leaves it
+    padded["valid"][::3] = False
+    padded["valid"][40:] = False
+    cases = {
+        # 64 rows on one (rule, value): 40 pass, every lane's cell reads 40
+        "many_rows_on_one_value": [(100, batch([2] * 64, [one] * 64,
+                                               threshold=40.0))],
+        "two_values_meet_in_one_lane": [(100, batch(
+            [3] * 20, [one, other] * 10, acquire=2))],
+        "every_row_refused": [(100, batch([1] * 64, [one] * 64,
+                                          threshold=0.0))],
+        "padding_and_invalid_rows": [(100, padded)],
+        "acquires_above_one": [(100, mixed)],
+        # bucket 0, bucket 1, then bucket 0 again: stale, rolled, committed
+        "across_a_bucket_boundary": [(100, _commit_batch(64, 3)),
+                                     (600, _commit_batch(64, 4)),
+                                     (1_100, _commit_batch(64, 5)),
+                                     (1_200, _commit_batch(64, 6))],
+        "rows_64": [(100, _commit_batch(64, 7))] * 2,
+        "rows_1024": [(100, _commit_batch(1024, 8))] * 2,
+        "rows_4096": [(100, _commit_batch(4096, 9))] * 2,
+    }
+    return cases
+
+
+@pytest.mark.parametrize("entry", ["flat", "4d"])
+@pytest.mark.parametrize("case", sorted(_commit_cases()))
+def test_the_commit_leaves_the_cells_np_add_at_leaves(case, entry):
+    """ISSUE 39: the commit writes each touched cell once, from a sorted,
+    pre-reduced, duplicate-free batch; the cells it leaves are bit for bit
+    what a serial add of every admitted (row, lane) leaves, through the flat
+    core and through the 4-D entry ``_param_decide_jax`` alike."""
+    import jax
+    from functools import partial
+
+    if entry == "flat":
+        core = jax.jit(partial(_cms_flat, PCFG))
+    want_counts = np.zeros(int(np.prod(fat_shape(PCFG))), np.int32)
+    want_starts = np.asarray(make_param_state(PCFG).starts)
+    got_counts, got_starts = want_counts.copy(), want_starts.copy()
+    for now, rows in _commit_cases()[case]:
+        want_counts, want_starts, want_admit = _flat_reference(
+            want_counts, want_starts, now=now, **rows)
+        args = (rows["slot"], rows["idx"], rows["acquire"],
+                rows["threshold"], rows["valid"], np.int32(now))
+        if entry == "flat":
+            got_counts, got_starts, admit, _est = core(
+                got_counts, got_starts, *args)
+        else:
+            state = make_param_state(PCFG)._replace(
+                starts=got_starts,
+                counts=got_counts.reshape(fat_shape(PCFG)))
+            state, admit, _est = _param_decide_jax(PCFG, state, *args)
+            got_counts, got_starts = state.counts.reshape(-1), state.starts
+        got_counts, got_starts = np.asarray(got_counts), np.asarray(got_starts)
+        assert np.asarray(admit).tolist() == want_admit.tolist()
+        assert got_starts.tolist() == want_starts.tolist()
+        assert got_counts.dtype == np.int32
+        assert np.array_equal(got_counts, want_counts)
+    assert case == "every_row_refused" or want_counts.any()
 
 
 def test_the_donated_sketch_survives_exports_between_dispatches(clock):

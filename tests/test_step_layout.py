@@ -294,6 +294,54 @@ def test_compiled_cond_branches_move_no_window(compiled_for_v5e, program, scope)
                    for c in report["branch_layout_copies"]), report
 
 
+@pytest.mark.parametrize("bucket", [64, 1024])
+def test_compiled_param_step_moves_no_sketch(compiled_for_v5e, bucket):
+    """``hot-param-1k``'s serve step, compiled for the described v5e: Mosaic
+    takes the commit kernel at the deployment's size (sublane rolls, row
+    DMAs; the interpreter of the CPU tests would take anything), the flat
+    512 MiB sketch reaches it as a bitcast, and no computation holds a
+    copy, reshape, transpose, scatter or fusion that yields the whole
+    sketch: it is touched by the stale branch of ``param_roll`` and by the
+    kernel's own DMAs, nothing else (PR 27: a layout copy of it costs
+    2.6 ms a call; PR 39: without the barrier behind ``param_roll``'s cond
+    the 64-row program carried the kernel's view into both branches and
+    copied the sketch on every call)."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmarks.decide_hlo_check import _instructions, describe_v5e
+    from sentinel_tpu.engine import param as P
+
+    del compiled_for_v5e  # this process holds libtpu, or the fixture skipped
+    on = SingleDeviceSharding(describe_v5e().devices[0])
+    with open(os.path.join(os.path.dirname(__file__), "..", "cellbench",
+                           "configs", "hot-param-1k.json")) as f:
+        cfg = P.ParamConfig(**json.load(f)["param"])
+    cells = int(np.prod(P.fat_shape(cfg)))
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        state = jax.eval_shape(lambda: P.make_param_state(cfg, flat=True))
+        packed = jax.ShapeDtypeStruct(
+            (P.packed_lines(cfg), bucket), jnp.int32)
+        text = P.make_param_step(cfg, bucket, "jax").lower(
+            *jax.tree.map(described, (state, packed))).compile().as_text()
+    whole = re.compile(rf"s32\[({cells}|{cells // 128},128)\]")
+    made = {}
+    for name, type_text, op, rest in _instructions(text):
+        if whole.search(type_text) and "/param_roll/" not in rest:
+            made.setdefault(op, []).append(name)
+    assert set(made) <= {"parameter", "bitcast", "tuple", "custom-call",
+                         "get-tuple-element", "opt-barrier"}, made
+    assert len(made["custom-call"]) == 1
+
+
 _HLO = """HloModule jit_step, is_scheduled=true
 
 %fused_scatter (p0: s32[4000], p1: s32[8], p2: s32[8]) -> s32[4000] {

@@ -38,6 +38,21 @@ JAX-free, numpy only (the generator processes import it):
                         ``probe.send(*cols)``, compare every verdict with the
                         family's plain reference and ``probe.record(name,
                         rows, mismatches, seconds)``
+    Session(traffic, dep, seed, proc, n_connections)
+                        optional: frames whose bytes depend on the replies
+                        the same generator has read (a release that names
+                        its acquire's token, a report of admitted calls
+                        only). One per generator process; it may return
+                        None where the traffic file asks for none
+        encode(ci, xid, *cols_of_a_frame) -> bytes
+                        at send time, on the sender's thread, in
+                        ``encode_batch``'s place (``ci``: the connection)
+        back(ci, xid, cols_of_the_frame, reply_rows, t)
+                        on the connection's reader thread, before the
+                        ledger counts the rows; ``reply_rows`` in the whole
+                        ``BATCH_REPLIES`` row layout, as many as came
+        lost(ci, xid)   optional: no reply will come (the frame was
+                        skipped, timed out, or its connection died)
 
 With the program (the server process only; imports inside the functions):
 
@@ -53,7 +68,25 @@ With the program (the server process only; imports inside the functions):
     CONTROLS            ``{name: wrap_service}``: the controls of
                         ``control.py``, each a broken guarantee
 
-``flow.py`` (with ``flow_reference.py``) is the first family and the only one
-``BENCHMARK.json`` uses; ``tests/extra/families/paramflow.py`` is a second,
-kept as a fixture that proves the seam on the program as it is.
+A family without ``Session`` has every frame of an open loop encoded before
+the window (``traffic.encode_frames``) and ``encode_batch`` called in the
+closed loop, as before there were sessions. With one, ``loadgen`` keeps the
+schedule, the xids, the in-flight window, the failure accounting, the
+latency and the ledger, and asks the session for a frame's bytes right
+before it sends them (warm-up and bursts too: a session is warm when the
+window starts); the send lag is then stamped after the encode, so
+``client.send_lag_p99_ms`` holds what the session costs. The rows a frame is
+counted by are the ``Mix``'s; what a session puts in front of them (a
+report, a run of releases) is bytes the ledger does not count. One
+connection's ``encode`` and ``back`` run on different threads in the open
+loop: a session locks what they share. ``msg: single`` with a session raises
+when the generator is built.
+
+``flow.py`` (with ``flow_reference.py``) is the first family; ``hotparam.py``,
+``shaped.py`` and ``breaker.py`` are the other three ``BENCHMARK.json`` uses,
+and none of its cells runs a session (``breaker.py`` has one, built only for
+a traffic file that states ``"reports": "admitted"``).
+``tests/extra/families/paramflow.py`` and ``semaphore.py`` are fixtures: the
+first proves the seam on the program as it is, the second the session (token
+ids a reply carries, released in front of a later frame).
 """

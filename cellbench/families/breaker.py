@@ -13,8 +13,13 @@ completes and is reported, DEGRADED or not*; a real client reports admitted
 calls only. That inflates the outcome rows by about the DEGRADED share and
 lets a row that was no probe resolve a HALF_OPEN probe; the device's work per
 report and per transition is a deployment's. The plain reference is given the
-same inputs. (Reports that depend on verdicts need a generator that reads its
-replies before it encodes: a ``benchmark`` issue, ROADMAP Reach A2.)
+same inputs. A traffic file that states ``"reports": "admitted"`` reports as
+upstream does (``AdmittedReports``, below): its frames are encoded at send
+time by a session (``families/__init__.py``), and the report in front of a
+connection's frame holds the completions of the rows of that connection's
+earlier frames whose verdicts have come back OK or SHOULD_WAIT, with the
+outcome the health script gave them; a DEGRADED, BLOCKED or failed row
+completes nothing. Without the key (the benchmark's cell) nothing changes.
 
 Layout. As ``flow.py``: plain flow ``i`` belongs to namespace ``ns{i %
 namespaces}`` with popularity rank ``i // namespaces``; the hottest ranks are
@@ -38,7 +43,8 @@ of the layout; xid ``-1 - xid``, outside every range replies are paired by)
 followed by the BATCH_FLOW frame. The server answers no report, and
 ``wire.Splitter`` skips any type the family does not name.
 
-Mix parameters: the flow family's, plus ``health``, the script of the
+Mix parameters: the flow family's, plus ``reports`` (``"admitted"``: above;
+absent: every guarded row) and ``health``, the script of the
 dependencies behind the guarded flows. It is **the file's, keyed on
 (namespace, rank), never on the seed**; the seed draws which rows a frame
 holds and the per-row draws inside a phase.
@@ -133,13 +139,14 @@ from __future__ import annotations
 
 import functools
 import sys
+import threading
 import time
 
 import numpy as np
 
 from cellbench import probe, traffic, wire
 from cellbench.deploy import (BLOCKED, DECIDED, DEFAULT, DEGRADED, OK,
-                              RATE_LIMITER)
+                              RATE_LIMITER, SHOULD_WAIT)
 from cellbench.families import breaker_reference, flow
 
 PROBE_BASE = flow.PROBE_BASE
@@ -203,6 +210,51 @@ def encode_singles(first_xid: int, flow_ids, counts, rt_ms, exc, told_ids,
     if (np.asarray(told_rt) >= 0).any():
         raise ValueError("a one-row frame cannot carry a completion report")
     return wire.encode_singles(first_xid, flow_ids, counts)
+
+
+class AdmittedReports:
+    """The session of a mix whose ``reports`` are ``admitted``: a call
+    completes only if it was let through. ``back`` keeps, per connection,
+    the completions (the frame's own ``rt_ms`` and ``exc`` columns) of the
+    rows answered OK or SHOULD_WAIT; ``encode`` puts all that the connection
+    has kept in front of its next request frame, as reports of at most
+    ``MAX_ROWS_PER_FRAME`` rows, and forgets it. The frame's ``told_*``
+    columns (the previous frame's rows, verdicts unseen) are not read. A
+    frame that is lost completes nothing: there is nothing to forget."""
+
+    def __init__(self, n_connections: int):
+        self.locks = [threading.Lock() for _ in range(n_connections)]
+        self.done = [[] for _ in range(n_connections)]  # (ids, rt, exc) each
+
+    def encode(self, ci: int, xid: int, flow_ids, counts, *_rest) -> bytes:
+        with self.locks[ci]:
+            kept, self.done[ci] = self.done[ci], []
+        out = b""
+        if kept:
+            ids, rt, exc = (np.concatenate(col) for col in zip(*kept))
+            for at in range(0, len(ids), MAX_ROWS_PER_FRAME):
+                to = at + MAX_ROWS_PER_FRAME
+                out += encode_report(xid, ids[at:to], rt[at:to], exc[at:to])
+        return out + wire.encode_batch(xid, flow_ids, counts)
+
+    def back(self, ci: int, xid: int, cols, reply_rows, t: float) -> None:
+        m = min(len(reply_rows), len(cols[0]))
+        st = reply_rows["status"][:m]
+        ids, rt, exc = cols[0][:m], cols[2][:m], cols[3][:m]
+        let = (rt >= 0) & ((st == OK) | (st == SHOULD_WAIT))
+        if let.any():
+            with self.locks[ci]:
+                self.done[ci].append((ids[let], rt[let], exc[let]))
+
+
+def Session(tr: dict, dep, seed: int, proc: int, n_connections: int):
+    """``AdmittedReports`` where the traffic file states ``"reports":
+    "admitted"``; None (every guarded row reports, frames encoded before the
+    window) where it does not state the key."""
+    reports = tr.get("reports")
+    if reports not in (None, "admitted"):
+        raise ValueError(f"reports: {reports!r}; 'admitted' or no key")
+    return None if reports is None else AdmittedReports(n_connections)
 
 
 class Deployment(flow.Deployment):

@@ -1,7 +1,9 @@
 """One load-generator process: numpy and sockets, never JAX.
 
 Started by ``cellbench/run.py`` with a plan file; builds every frame it will
-send from the seed while the server warms up, connects when the server's
+send from the seed while the server warms up (a family with a ``Session``
+has its frames' rows drawn then and their bytes made at send time, from the
+replies read so far: ``families/__init__.py``), connects when the server's
 port file appears, then takes commands on standard input (``warm``,
 ``burst <rows>``, ``measure <t0> <seconds>``, ``quit``) and answers each with
 one JSON line. The measured window's raw samples go to an ``.npz`` beside
@@ -27,6 +29,7 @@ Failure accounting (ISSUE 23, A.3), all of it at the client:
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import socket
@@ -181,6 +184,16 @@ class Generator:
         self.timeout_s = float(self.t["timeout_ms"]) / 1000.0
         self.next_xid = 1 + self.proc * 200_000_000
         self.conns = []
+        # frames whose bytes depend on replies: the family's, if it has one
+        # and the traffic file asks for it (``families/__init__.py``)
+        make = getattr(self.fam, "Session", None)
+        self.session = None if make is None else make(
+            self.t, self.dep, self.seed, self.proc,
+            int(self.t["connections"]))
+        if self.session is not None and self.single:
+            raise ValueError(
+                "a family's Session encodes batch frames; one-row frames are "
+                "sent a run at a time as a packed array (msg: single)")
         self._build(float(plan["seconds"]), float(plan["warm_seconds"]))
 
     # -- frames, made before the server is up -------------------------------
@@ -219,17 +232,26 @@ class Generator:
                  seconds: float) -> Ledger:
         n = len(due)
         x0 = self._xids(n)
+        conns = self.conns
+        ses = self.session
+        lost = None
         if self.single:  # one packed array, frame k at ``enc[k]``
             enc = self.fam.encode_singles(x0, *[col[:, 0] for col in cols])
-        else:
-            enc = traffic.encode_frames(self.fam, cols, x0)
+        elif ses is None:
+            enc = traffic.encode_frames(self.fam, cols, x0).__getitem__
+        else:  # frame k's bytes when it is sent, from the replies so far
+            def enc(k: int) -> bytes:
+                return ses.encode(k % len(conns), x0 + k,
+                                  *[col[k] for col in cols])
+            if hasattr(ses, "lost"):
+                def lost(k: int) -> None:
+                    ses.lost(k % len(conns), x0 + k)
         led = Ledger(self.dep, t0, t0 + seconds)
         led.attempted = n * self.rows
         due_abs = t0 + due
         was_sent = np.zeros(n, bool)
         replied = np.zeros(n, bool)
         state = {"inflight": 0, "done": False}
-        conns = self.conns
 
         def reader(c: Conn) -> None:
             while not state["done"] and not c.dead:
@@ -256,6 +278,9 @@ class Generator:
                     m = min(len(rows), self.rows)
                     if m < self.rows:
                         led.fail("short_reply", self.rows - m)
+                    if ses is not None:
+                        ses.back(k % len(conns), xid,
+                                 [col[k] for col in cols], rows, now)
                     led.rows_back(now, now - due_abs[k],
                                   [col[k][:m] for col in cols],
                                   rows["status"][:m], rows["remaining"][:m])
@@ -265,8 +290,12 @@ class Generator:
         for th in threads:
             th.start()
         lag = np.zeros(n)
-        send = self._send_singles if self.single else self._send_frames
-        send(led, state, replied, was_sent, lag, enc, due_abs, window)
+        if self.single:
+            self._send_singles(led, state, replied, was_sent, lag, enc,
+                               due_abs, window)
+        else:
+            self._send_frames(led, state, replied, was_sent, lag, enc, lost,
+                              due_abs, window)
         deadline = max(due_abs[-1], time.monotonic()) + self.timeout_s
         while time.monotonic() < deadline and not replied.all():
             if all(c.dead for c in conns):
@@ -275,19 +304,25 @@ class Generator:
         state["done"] = True
         for th in threads:
             th.join(timeout=2.0)
-        lost = int((~replied).sum())
-        if lost:
+        unanswered = np.flatnonzero(~replied)
+        if len(unanswered):
             dead_conn = np.array([conns[k % len(conns)].dead
-                                  for k in np.flatnonzero(~replied)])
+                                  for k in unanswered])
             led.fail("connection", int(dead_conn.sum()) * self.rows)
             led.fail("timeout", int((~dead_conn).sum()) * self.rows)
+            if lost is not None:
+                for k in unanswered:
+                    lost(int(k))
         led.lags.append(lag[was_sent])
         return led
 
-    def _send_frames(self, led, state, replied, was_sent, lag, enc, due_abs,
-                     window: int) -> None:
+    def _send_frames(self, led, state, replied, was_sent, lag, enc, lost,
+                     due_abs, window: int) -> None:
         """The open loop's sender for batch frames: frame k at its due time,
-        on connection ``k % connections``."""
+        on connection ``k % connections``. ``enc(k)`` is its bytes: made
+        before the window, or by the family's session now, which the lag
+        (taken after it) then holds; ``lost(k)`` (or None) tells a session
+        of a frame that no reply will come for."""
         conns = self.conns
         for k in range(len(due_abs)):
             wait = due_abs[k] - time.monotonic()
@@ -298,22 +333,21 @@ class Generator:
                 full = state["inflight"] >= window
                 if not full and not c.dead:
                     state["inflight"] += 1
-            if full:
-                led.fail("skipped", self.rows)
+            if full or c.dead:
+                led.fail("skipped" if full else "connection", self.rows)
                 replied[k] = True
-                continue
-            if c.dead:
-                led.fail("connection", self.rows)
-                replied[k] = True
-                continue
-            now = time.monotonic()
-            was_sent[k] = True
-            lag[k] = now - due_abs[k]
-            if not c.send(enc[k]):
+            else:
+                data = enc(k)
+                was_sent[k] = True
+                lag[k] = time.monotonic() - due_abs[k]
+                if c.send(data):
+                    continue
                 with led.lock:
                     state["inflight"] -= 1
                     replied[k] = True
                 led.fail("connection", self.rows)
+            if lost is not None:
+                lost(k)
 
     def _send_singles(self, led, state, replied, was_sent, lag, enc, due_abs,
                       window: int) -> None:
@@ -406,6 +440,9 @@ class Generator:
         pending = {}  # xid -> (pool index, sent at)
         j = 0
         attempted = 0
+        ses = self.session
+        encode = (self.fam.encode_batch if ses is None
+                  else functools.partial(ses.encode, ci))
 
         def send_one() -> bool:
             nonlocal j, attempted
@@ -417,10 +454,7 @@ class Generator:
                 return False
             pending[xid] = (p, now)
             attempted += self.rows
-            if not c.send(self.fam.encode_batch(
-                    xid, *[col[p] for col in cols])):
-                return False
-            return True
+            return c.send(encode(xid, *[col[p] for col in cols]))
 
         for _ in range(depth):
             send_one()
@@ -440,6 +474,8 @@ class Generator:
                 m = min(len(rows), self.rows)
                 if m < self.rows:
                     led.fail("short_reply", self.rows - m)
+                if ses is not None:
+                    ses.back(ci, xid, [col[p] for col in cols], rows, now)
                 led.rows_back(now, now - at, [col[p][:m] for col in cols],
                               rows["status"][:m], rows["remaining"][:m])
                 send_one()
@@ -448,6 +484,9 @@ class Generator:
         if pending:
             led.fail("connection" if c.dead else "timeout",
                      len(pending) * self.rows)
+            if hasattr(ses, "lost"):
+                for xid in pending:
+                    ses.lost(ci, xid)
 
     def _xids_block(self) -> int:
         """A range of xids for one connection's closed loop."""

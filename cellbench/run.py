@@ -637,8 +637,9 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
 
     t = tr.Trace(tr.find_xplane(sliced["dir"]))
     flow = [e for e in sliced["events"] if e["stage"] in (
-        "client_in", "enqueue", "permit", "prep", "locked", "dispatch",
-        "device_in", "ready", "fetched", "device_out", "reply_out")]
+        "rx", "client_in", "enqueue", "permit", "prep", "locked", "dispatch",
+        "device_in", "reply_taken", "ready", "fetched", "reply_out",
+        "account", "device_out")]
     reduced = tr.reduce(
         t, sliced["lo_ns"], sliced["hi_ns"],
         np.asarray([e["t_ns"] for e in flow], np.float64),

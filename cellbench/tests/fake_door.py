@@ -14,11 +14,25 @@ import numpy as np
 from cellbench import deploy, wire
 
 
+# the semaphore fixture's frames (``extra/families/semaphore.py`` writes its
+# own copy): ACQUIRE is BATCH_FLOW's request under another type, answered
+# with a token id a row; RELEASE is ``n:u16`` then ``token_id:i64`` a row
+ACQUIRE, RELEASE = 40, 41
+ACQ_ROW = np.dtype([("status", "i1"), ("remaining", ">i4"), ("wait_ms", ">i4"),
+                    ("token_id", ">i8")])
+
+
 class FakeDoor:
     """Answers BATCH_FLOW and FLOW frames from ``decide(ids, acq) ->
     (status, remaining, wait_ms)``. ``stall=(after_frames, seconds)`` sleeps
     once; ``shed_from`` answers OVERLOAD from that frame on for ``shed_n``
-    frames; ``drop_at`` closes the connection on receiving that frame."""
+    frames; ``drop_at`` closes the connection on receiving that frame.
+
+    An ACQUIRE frame is answered as a BATCH_FLOW frame, each row that passed
+    with a token id no other row gets: ``issued[id]`` is ``(the frame's xid,
+    when its reply was handed to the socket)``. A RELEASE frame is not
+    answered: ``released`` holds ``(id, when it came)`` for every id of
+    every RELEASE frame, known or not, in the order they came."""
 
     def __init__(self, decide, stall=None, shed_from=None, shed_n=0,
                  drop_at=None):
@@ -27,6 +41,7 @@ class FakeDoor:
         self.drop_at = drop_at
         self.frames = 0
         self.lock = threading.Lock()
+        self.issued, self.released = {}, []
         self.sock = socket.socket()
         self.sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         self.sock.bind(("127.0.0.1", 0))
@@ -67,6 +82,17 @@ class FakeDoor:
                         np.zeros(n, np.int32), np.full(n, 5, np.int32))
             return self.decide(ids, acq)
 
+    def _issue(self, xid: int, status) -> np.ndarray:
+        """A fresh id for every row that passed, 0 for the others."""
+        ids = np.zeros(len(status), np.int64)
+        ok = np.flatnonzero(np.asarray(status) == deploy.OK)
+        now = time.monotonic()
+        with self.lock:
+            first = 1 + len(self.issued)
+            ids[ok] = first + np.arange(len(ok))
+            self.issued.update((i, (xid, now)) for i in ids[ok].tolist())
+        return ids
+
     def _serve(self, conn) -> None:
         buf = bytearray()
         try:
@@ -80,13 +106,24 @@ class FakeDoor:
                     if len(buf) < 2 + flen:
                         break
                     xid, mtype = struct.unpack_from(">ib", buf, 2)
-                    if mtype == wire.BATCH_FLOW:
+                    if mtype == RELEASE:
+                        n = struct.unpack_from(">H", buf, 7)[0]
+                        ids = np.frombuffer(bytes(buf[9:9 + 8 * n]), ">i8")
+                        del buf[:2 + flen]
+                        now = time.monotonic()
+                        with self.lock:
+                            self.released += [(i, now) for i in ids.tolist()]
+                        continue
+                    if mtype in (wire.BATCH_FLOW, ACQUIRE):
                         n = struct.unpack_from(">H", buf, 7)[0]
                         rows = np.frombuffer(bytes(buf[9:9 + 13 * n]),
                                              wire.REQ_ROW)
-                    else:
+                    elif mtype == wire.FLOW:
                         n = 1
                         rows = np.frombuffer(bytes(buf[7:20]), wire.REQ_ROW)
+                    else:  # a frame that is not answered (a report)
+                        del buf[:2 + flen]
+                        continue
                     del buf[:2 + flen]
                     out = self._answer(rows["flow_id"].astype(np.int64),
                                        rows["count"].astype(np.int32))
@@ -94,14 +131,17 @@ class FakeDoor:
                         conn.close()
                         return
                     status, remaining, wait = out
-                    rsp = np.empty(n, wire.RSP_ROW)
+                    rsp = np.empty(n, ACQ_ROW if mtype == ACQUIRE
+                                   else wire.RSP_ROW)
                     rsp["status"], rsp["remaining"] = status, remaining
                     rsp["wait_ms"] = wait
-                    if mtype == wire.BATCH_FLOW:
-                        head = struct.pack(">HibH", 7 + 9 * n, xid,
-                                           wire.BATCH_FLOW, n)
-                    else:
+                    if mtype == ACQUIRE:
+                        rsp["token_id"] = self._issue(xid, rsp["status"])
+                    if mtype == wire.FLOW:
                         head = struct.pack(">Hib", 14, xid, wire.FLOW)
+                    else:
+                        head = struct.pack(">HibH", 7 + rsp.itemsize * n, xid,
+                                           mtype, n)
                     conn.sendall(head + rsp.tobytes())
         except OSError:
             return

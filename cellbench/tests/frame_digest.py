@@ -1,7 +1,9 @@
 """A digest of everything one generator process would send: the encoded
 frames and, in an open loop, their due times. ``data/frame_digests.json``
 holds the digests taken from the tree before the families (PR 25's), for the
-five traffic files of ``cellbench/traffic/`` and seeds 1-3."""
+five flow mixes of ``cellbench/traffic/`` and seeds 1-3, and those taken from
+the tree before sessions (PR 39's) for the mixes of the three other families'
+cells, so that every cell's bytes and due times are pinned."""
 
 from __future__ import annotations
 
@@ -16,7 +18,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CONFIG_OF = {"tenants-zipf-open": "mesh-100k", "tenants-zipf-burst": "mesh-100k",
              "zipf-hiccup": "mesh-100k", "sidecar-sat": "mesh-100k",
-             "single-token": "demo-cluster-1k"}
+             "single-token": "demo-cluster-1k",
+             "keys-zipf-open": "hot-param-1k",
+             "tenants-zipf-prio-open": "shaped-mesh-100k",
+             "tenants-zipf-health-cycle-open": "breaker-mesh-100k"}
 
 
 def digest(mix: str, seed: int, proc: int) -> str:

@@ -16,14 +16,16 @@ import time
 import numpy as np
 import pytest
 
-from cellbench import deploy, outcome_roofline, probe, run, traffic, wire
-from cellbench.deploy import BLOCKED, DEGRADED, OK, TOO_MANY
+from cellbench import (deploy, loadgen, outcome_roofline, probe, run, traffic,
+                       wire)
+from cellbench.deploy import BLOCKED, DEGRADED, OK, SHOULD_WAIT, TOO_MANY
 from cellbench.families import breaker, breaker_reference, flow
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 EXTRA = os.path.join(HERE, "extra")
 BENCH = os.path.dirname(HERE)
 CELL = "tiny-breaker.tiny-health-cycle-open"
+ADMITTED_CELL = "tiny-breaker.tiny-health-cycle-admitted-open"
 REAL_CELL = "breaker-mesh-100k.tenants-zipf-health-cycle-open"
 CHECKS = ("tight", "big", "guard", "paced", "trip_ratio", "trip_slow",
           "trip_count", "open_holds", "probe_one", "recover", "rollback",
@@ -46,9 +48,10 @@ def breaker_manifest(tmp) -> str:
         "file": os.path.relpath(
             os.path.join(EXTRA, "configs", "tiny-breaker.json"), tmp),
         "why": "test"})
-    bench["workloads"].append({
-        "name": CELL, "config": "tiny-breaker",
-        "traffic": "tiny-health-cycle-open", "chips": 1, "why": "test"})
+    for cell in (CELL, ADMITTED_CELL):
+        bench["workloads"].append({
+            "name": cell, "config": "tiny-breaker",
+            "traffic": cell.split(".")[1], "chips": 1, "why": "test"})
     real = deploy.load_json(os.path.join(os.path.dirname(BENCH),
                                          "BENCHMARK.json"))
     have = {m["name"] for m in bench["per_layer"]}
@@ -66,9 +69,8 @@ def tiny_breaker() -> breaker.Deployment:
                        [BENCH])
 
 
-def tiny_mix() -> dict:
-    return deploy.load_json(os.path.join(EXTRA, "traffic",
-                                         "tiny-health-cycle-open.json"))
+def tiny_mix(name: str = "tiny-health-cycle-open") -> dict:
+    return deploy.load_json(os.path.join(EXTRA, "traffic", name + ".json"))
 
 
 def real_cell():
@@ -810,3 +812,318 @@ def test_the_manifest_holds_the_cell_as_the_issue_names_it():
         "error_ratio_threshold": 0.5, "error_count_threshold": 4,
         "stat_interval_ms": 1000, "min_request_amount": 5,
         "recovery_timeout_ms": 2000}
+
+
+# -- reports of admitted calls only: the session ------------------------------------
+INGEST_SETTLE_S = 5.0  # a report is ingested well inside this
+
+
+def ingested_rows() -> int:
+    """Completion rows the outcome steps of this process's server took."""
+    from sentinel_tpu.metrics.server import server_metrics
+
+    return int(server_metrics().arm_totals()["outcome_step_rows_total"])
+
+
+def reports_of(raw: bytes) -> list:
+    """The rows of every OUTCOME_REPORT frame in one send."""
+    out = []
+    while raw:
+        flen = struct.unpack_from(">H", raw, 0)[0]
+        if raw[6] == breaker.OUTCOME_REPORT:
+            n = struct.unpack_from(">H", raw, 7)[0]
+            out.append(np.frombuffer(raw[9:2 + flen], breaker.OUTCOME_ROW, n))
+        raw = raw[2 + flen:]
+    return out
+
+
+def test_the_session_is_built_for_a_file_that_asks_for_it_and_no_other():
+    dep = tiny_breaker()
+    assert breaker.Session(tiny_mix(), dep, 1, 0, 2) is None
+    got = breaker.Session(tiny_mix("tiny-health-cycle-admitted-open"), dep,
+                          1, 0, 2)
+    assert isinstance(got, breaker.AdmittedReports)
+    with pytest.raises(ValueError, match="reports"):
+        breaker.Session(dict(tiny_mix(), reports="some"), dep, 1, 0, 2)
+    # the benchmark's admitted mix is cell 7's file with that key
+    mine = deploy.load_json(os.path.join(
+        BENCH, "traffic", "tenants-zipf-health-cycle-admitted-open.json"))
+    theirs = real_cell()[1]
+    assert mine.pop("reports") == "admitted"
+    assert mine.pop("name") == theirs.pop("name").replace("-open",
+                                                         "-admitted-open")
+    assert mine == theirs
+    assert json.dumps(tiny_mix("tiny-health-cycle-admitted-open")).replace(
+        '"reports": "admitted", ', "").replace("admitted-", "") == json.dumps(
+            tiny_mix())
+
+
+def test_a_report_holds_the_completions_of_the_rows_let_through():
+    ses = breaker.AdmittedReports(2)
+    ids = np.array([8, 16, 24, 7001, 32, 40], np.int64)
+    acq = np.ones(6, np.int32)
+    rt = np.array([5, 90, 7, -1, 9, 11], np.int32)  # 7001 is not guarded
+    exc = np.array([0, 0, 1, 0, 0, 1], np.uint8)
+    told = (np.zeros(6, np.int64), np.full(6, 55, np.int32),
+            np.ones(6, np.uint8))  # the frame before, verdicts unseen: unread
+    cols = (ids, acq, rt, exc) + told
+    first = ses.encode(0, 100, *cols)
+    assert first == wire.encode_batch(100, ids, acq)  # nothing to tell yet
+    rsp = np.zeros(6, wire.RSP_ROW)
+    rsp["status"] = [OK, DEGRADED, SHOULD_WAIT, OK, BLOCKED, OK]
+    ses.back(0, 100, cols, rsp, 1.0)
+    ses.back(0, 101, cols, rsp[:2], 1.1)  # a short reply: the rows that came
+    assert ses.encode(1, 200, *cols) == first.replace(  # another connection
+        struct.pack(">i", 100), struct.pack(">i", 200), 1)
+    raw = ses.encode(0, 102, *cols)
+    (rep,) = reports_of(raw)
+    assert rep["flow_id"].tolist() == [8, 24, 40, 8]
+    assert rep["rt_ms"].tolist() == [5, 7, 11, 5]
+    assert rep["exc"].tolist() == [0, 1, 1, 0]
+    assert struct.unpack_from(">i", raw, 2)[0] == breaker.report_xid(102)
+    assert raw.endswith(wire.encode_batch(102, ids, acq))
+    assert ses.encode(0, 103, *cols) == wire.encode_batch(103, ids, acq)
+
+
+def test_more_completions_than_a_frame_holds_go_out_as_several_reports():
+    ses = breaker.AdmittedReports(1)
+    n = breaker.MAX_ROWS_PER_FRAME
+    ids = np.arange(n + 10, dtype=np.int64)
+    cols = (ids, np.ones(len(ids), np.int32),
+            np.full(len(ids), 5, np.int32), np.zeros(len(ids), np.uint8))
+    rsp = np.zeros(len(ids), wire.RSP_ROW)
+    ses.back(0, 1, cols, rsp, 0.0)
+    raw = ses.encode(0, 2, ids[:4], np.ones(4, np.int32))
+    a, b = reports_of(raw)
+    assert len(a) == n and len(b) == 10
+    assert np.concatenate([a["flow_id"], b["flow_id"]]).tolist() == ids.tolist()
+    assert raw.endswith(wire.encode_batch(2, ids[:4], np.ones(4, np.int32)))
+    assert len(raw) == 2 * 9 + 13 * len(ids) + 9 + 13 * 4  # three whole frames
+
+
+class EveryRow(breaker.AdmittedReports):
+    """The control: today's reports through a session. Every row on a
+    guarded flow completes, whatever it was answered."""
+
+    def back(self, ci, xid, cols, reply_rows, t):
+        passed = reply_rows.copy()
+        passed["status"] = OK
+        super().back(ci, xid, cols, passed, t)
+
+
+@pytest.fixture(scope="module")
+def tiny_server():
+    """The tiny breaker deployment behind the native door, in this process."""
+    import jax
+
+    from cellbench import server
+
+    kept = dict(breaker._RUN)
+    dep = tiny_breaker()
+    built = server.build(dep, jax.devices()[:1], lambda msg: None)
+    try:
+        # as a run does: the fused depths a backlog reaches compile now
+        breaker.drive_before_window(
+            built, tiny_mix("tiny-health-cycle-admitted-open"), dep, 31, [],
+            lambda msg: None)
+        yield dep, built
+    finally:
+        built.close()
+        breaker._RUN.clear()
+        breaker._RUN.update(kept)
+
+
+def drive_admitted(dep, built, tmp_path, monkeypatch, session_class,
+                   seconds: float = 4.0):
+    """A warm-up and a window of the tiny admitted mix from a generator in
+    this process, its session a ``session_class`` that is watched: ``(the
+    window's summary, completion rows the service ingested, rows in the
+    reports sent, rows on guarded flows answered OK or SHOULD_WAIT, of any
+    answer, rows the session still kept at the end)``."""
+    seen = {"let": 0, "guarded": 0, "told": 0}
+    lock = threading.Lock()
+
+    class Watched(session_class):
+        def back(self, ci, xid, cols, reply_rows, t):
+            st = reply_rows["status"]
+            guarded = dep.is_guarded(cols[0][:len(st)])
+            with lock:
+                seen["let"] += int((guarded & ((st == OK)
+                                               | (st == SHOULD_WAIT))).sum())
+                seen["guarded"] += int(guarded.sum())
+            super().back(ci, xid, cols, reply_rows, t)
+
+        def encode(self, ci, xid, *cols):
+            raw = super().encode(ci, xid, *cols)
+            with lock:
+                seen["told"] += sum(len(r) for r in reports_of(raw))
+            return raw
+
+    monkeypatch.setattr(breaker, "AdmittedReports", Watched)
+    gen = loadgen.Generator({
+        "traffic": tiny_mix("tiny-health-cycle-admitted-open"), "seed": 31,
+        "proc": 0, "seconds": seconds, "warm_seconds": 1.5,
+        "port_file": str(tmp_path / "port"), "family_dirs": [BENCH],
+        "config_file": os.path.join(EXTRA, "configs", "tiny-breaker.json")})
+    assert isinstance(gen.session, Watched)
+    before = ingested_rows()
+    gen.connect(built.server.port)
+    try:
+        gen.cmd_warm()
+        summary = gen.cmd_measure(time.monotonic() + 0.05, seconds,
+                                  str(tmp_path / "r.npz"))
+    finally:
+        for c in gen.conns:
+            c.close()
+    kept = sum(len(ids) for conn in gen.session.done for ids, _rt, _e in conn)
+    deadline = time.monotonic() + INGEST_SETTLE_S
+    while ingested_rows() - before < seen["told"] and time.monotonic() < deadline:
+        time.sleep(0.01)
+    time.sleep(0.1)  # nothing more may come
+    return summary, ingested_rows() - before, seen["told"], seen["let"], seen[
+        "guarded"], kept
+
+
+def test_the_service_ingests_the_rows_let_through_and_no_other(
+        tiny_server, tmp_path, monkeypatch):
+    dep, built = tiny_server
+    s, ingested, told, let, guarded, kept = drive_admitted(
+        dep, built, tmp_path, monkeypatch, breaker.AdmittedReports)
+    assert s["decided"] > 0.9 * s["attempted"] and s["status_hist"][DEGRADED]
+    assert 0 < let < guarded  # breakers tripped: some calls were refused
+    # exactly: on the wire what was let through (less what no later frame
+    # carried), in the service what was on the wire
+    assert told == let - kept and ingested == told
+
+
+def test_a_session_that_reports_every_row_is_caught(tiny_server, tmp_path,
+                                                    monkeypatch):
+    dep, built = tiny_server
+    s, ingested, told, let, guarded, kept = drive_admitted(
+        dep, built, tmp_path, monkeypatch, EveryRow)
+    assert s["decided"] > 0.9 * s["attempted"] and 0 < let < guarded
+    assert ingested == told == guarded - kept
+    assert ingested != let - kept and ingested - (let - kept) == guarded - let
+
+
+def test_the_admitted_cell_runs_through_the_harness(tmp_path):
+    """The generator is a child process here, as on the chip: the plan
+    names the file, the file asks for the session."""
+    lines = []
+    result = run.run_cell(breaker_manifest(str(tmp_path)), ADMITTED_CELL,
+                          seed=2_147_483_743, seconds=6.0, trace=0,
+                          require_chip=False, out=lines.append)
+    assert result["correct"] is True and result["failed"] == 0, (
+        result["compared"], [ln for ln in lines if "window " in ln])
+    assert result["attempted"] == 600 * 64
+    assert "DEGRADED 0;" not in [ln for ln in lines if "status OK" in ln][0]
+    assert not any("COMPILED INSIDE THE WINDOW" in ln for ln in lines)
+
+
+def test_the_reference_replays_a_run_of_admitted_reports(tmp_path):
+    """The tiny admitted mix through the native door a frame at a time on a
+    clock this test moves (frame ``k`` at its due time), the session between
+    each reply and the next frame; then the plain reference over the same
+    frames, told what upstream's client would tell it: the completions of
+    the rows let through, worked out here from the verdicts. A frame's
+    report and its request travel different lanes with no order between
+    them, so a frame is held to the reference under either order. Compared:
+    which rows are DEGRADED and every retry-after, and which rows the
+    namespace guard refused; OK against BLOCKED on a metered flow is the
+    probe's ``tight`` to hold, one acquire size a flow (a frame of mixed
+    sizes on one flow is not admitted greedily by the program)."""
+    import copy
+
+    import jax
+
+    from cellbench import server
+    from sentinel_tpu.core import clock as clock_mod
+
+    dep = tiny_breaker()
+    tr = tiny_mix("tiny-health-cycle-admitted-open")
+    gen = loadgen.Generator({
+        "traffic": tr, "seed": 47, "proc": 0, "seconds": 8.0,
+        "warm_seconds": 1.5, "port_file": str(tmp_path / "port"),
+        "family_dirs": [BENCH],
+        "config_file": os.path.join(EXTRA, "configs", "tiny-breaker.json")})
+    due, cols = gen.main
+    ses, n_conn = gen.session, int(tr["connections"])
+    kept = dict(breaker._RUN)
+    mc = clock_mod.ManualClock(1_700_000_000_000)
+    prev = clock_mod.set_clock(mc)
+    built = socks = None
+    frames = []  # (t_ms, the frame's columns, status, remaining)
+    try:
+        built = server.build(dep, jax.devices()[:1], lambda msg: None)
+        socks = [socket.create_connection(("127.0.0.1", built.server.port))
+                 for _ in range(n_conn)]
+        splits = [wire.Splitter(breaker.SINGLE_REPLIES, breaker.BATCH_REPLIES)
+                  for _ in socks]
+        base, told = ingested_rows(), 0
+        t0 = mc.now_ms() + 1000
+        for k in range(len(due)):
+            deadline = time.monotonic() + INGEST_SETTLE_S
+            while ingested_rows() - base < told:  # the last report is in
+                assert time.monotonic() < deadline, "a report was not ingested"
+                time.sleep(0.001)
+            mc.set_ms(t0 + int(round(due[k] * 1000)))
+            ci, frame = k % n_conn, [col[k] for col in cols]
+            raw = ses.encode(ci, 1000 + k, *frame)
+            told += sum(len(r) for r in reports_of(raw))
+            socks[ci].sendall(raw)
+            got = []
+            while not got:
+                got = splits[ci].feed(socks[ci].recv(1 << 16))[0]
+            (xid, rows), = got
+            assert xid == 1000 + k and len(rows) == len(frame[0])
+            ses.back(ci, xid, frame, rows, time.monotonic())
+            frames.append((mc.now_ms() - built.service._epoch_ms, frame,
+                           rows["status"].copy(), rows["remaining"].copy()))
+    finally:
+        for sock in socks or ():
+            sock.close()
+        if built is not None:
+            built.close()
+        clock_mod.set_clock(prev)
+        breaker._RUN.clear()
+        breaker._RUN.update(kept)
+    ref = breaker_reference.for_deployment(dep)
+    waiting = [[] for _ in range(n_conn)]  # completions a connection owes
+    mismatches = degraded = reports_first = 0
+    differ = []  # (frame, flow, served, wanted, retry served, retry wanted)
+    for k, (t, frame, status, remaining) in enumerate(frames):
+        ids, acq, rt, exc = frame[:4]
+        owed, waiting[k % n_conn] = waiting[k % n_conn], []
+        best = None
+        for report_first in (True, False):
+            trial = copy.deepcopy(ref)
+            if report_first:
+                for done in owed:
+                    trial.report(t, *done)
+            want, rest = trial.decide_frame(t, ids, acq)
+            if not report_first:
+                for done in owed:
+                    trial.report(t, *done)
+            want, rest = np.asarray(want), np.asarray(rest)
+            bad = np.flatnonzero(
+                ((status == DEGRADED) != (want == DEGRADED))
+                | ((status == TOO_MANY) != (want == TOO_MANY))
+                | ((want == DEGRADED) & (remaining != rest)))
+            if best is None or len(bad) < best[0]:
+                best = (len(bad), trial, report_first,
+                        [(k, int(ids[i]), int(status[i]), int(want[i]),
+                          int(remaining[i]), int(rest[i])) for i in bad])
+            if not len(bad):
+                break
+        mismatches += best[0]
+        differ += best[3]
+        ref = best[1]
+        reports_first += best[2] and bool(owed)
+        degraded += int((status == DEGRADED).sum())
+        let = (rt >= 0) & ((status == OK) | (status == SHOULD_WAIT))
+        if let.any():
+            waiting[k % n_conn].append((ids[let], rt[let], exc[let]))
+    assert mismatches == 0, differ[:20]
+    assert degraded > 200 and ref.moves["open"] >= 8
+    assert ref.moves["close"] > 0 and ref.moves["rollback"] > 0
+    assert ref.reported == told

@@ -49,6 +49,32 @@ def test_gaps_are_named_by_the_stage_before_them():
                     ["after_device_in", 3.0 / 1e9]]
 
 
+def test_the_counting_after_the_last_reply_is_no_request():
+    """Since PR 35 a reply lane answers first and counts after: ``account``
+    and ``device_out`` follow ``reply_out``. A gap behind them with nothing
+    more recorded is ``no_request``; one that the counting's next stage falls
+    into, or behind an ``account`` whose reply is still to go (a synchronous
+    caller's order), keeps its name; ``rx`` and ``reply_taken`` (PR 38) name
+    a gap like any stage."""
+    stages = ["rx", "client_in", "device_in", "reply_taken", "ready",
+              "fetched", "reply_out", "account", "device_out",
+              "rx", "fetched", "account", "device_out", "reply_out"]
+    stages_t = np.array([1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0, 8.0, 9.0,
+                         50.0, 51.0, 52.0, 53.0, 54.0])
+    gaps = trace.name_gaps(
+        np.array([0.5, 4.5, 7.5, 8.5, 9.5, 50.5, 52.5, 53.5, 54.5]),
+        np.array([0.2, 0.3, 0.6, 0.65, 40.0, 0.1, 0.15, 0.25, 9.0]),
+        stages_t, stages, n=9)
+    assert dict((round(s * 1e9, 2), name) for name, s in gaps) == {
+        0.2: "no_request", 0.3: "after_reply_taken", 0.6: "after_reply_out",
+        0.65: "after_account", 40.0: "no_request", 0.1: "after_rx",
+        0.15: "after_account", 0.25: "after_device_out", 9.0: "no_request"}
+    # not quiet: a request came in before the gap ended
+    gaps = trace.name_gaps(np.array([9.5]), np.array([45.0]), stages_t,
+                           stages)
+    assert gaps == [["after_device_out", 45.0 / 1e9]]
+
+
 @pytest.mark.skipif(not os.path.exists(RECORDED), reason="no recorded trace")
 def test_recorded_trace_reduces_to_the_numbers_read_by_hand():
     t = trace.Trace(RECORDED)

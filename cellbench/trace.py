@@ -128,16 +128,33 @@ def top_by_time(names, start, dur, lo: float, hi: float, n: int = 10):
 def name_gaps(gap_start, gap_len, stage_t_ns, stage_names, n: int = 10):
     """The ``n`` longest gaps, each named by the last flight-recorder stage
     before it began: ``[["after_<stage>", seconds]]``; ``no_request`` when no
-    frame was anywhere in the pipeline."""
+    frame was anywhere in the pipeline: nothing was recorded yet, or the
+    last thing recorded before the gap belongs to a dispatch whose reply
+    had gone out (``reply_out``, or the account half that follows it on the
+    native lane: ``account``, then ``device_out``) and nothing more was
+    recorded until the gap's end."""
     order = np.argsort(-gap_len)[:n]
     out = []
     for i in order:
         at = np.searchsorted(stage_t_ns, gap_start[i], side="right") - 1
-        label = ("no_request" if at < 0 or stage_names[at] == "reply_out"
+        label = ("no_request" if at < 0 or _replied(stage_names, at)
                  and _quiet(stage_t_ns, at, gap_start[i] + gap_len[i])
                  else "after_" + stage_names[at])
         out.append([label, float(gap_len[i]) / 1e9])
     return out
+
+
+# what a reply lane records after a dispatch's reply has left (PR 35)
+_AFTER_REPLY = ("account", "device_out")
+
+
+def _replied(stage_names, at: int) -> bool:
+    """True when event ``at`` is a ``reply_out``, or the counting that
+    follows one: ``account`` / ``device_out`` with a ``reply_out`` right
+    before them."""
+    while at > 0 and stage_names[at] in _AFTER_REPLY:
+        at -= 1
+    return stage_names[at] == "reply_out"
 
 
 def _quiet(stage_t_ns, at: int, gap_end: float) -> bool:

@@ -13,7 +13,12 @@ corpus strategy:
 - MUTATED valid frames (bit flips in length/type/n/rows — the hardest class,
   since most of the frame still parses), BATCH_FLOW and, codec rev 8,
   BATCH_PARAM_FLOW (type 27: ``n:u16 k:u8`` and rows of ``13 + 8k`` bytes,
-  so a flipped ``k`` moves every row boundary);
+  so a flipped ``k`` moves every row boundary) and, codec rev 9,
+  BATCH_CONCURRENT_ACQUIRE / _RELEASE (types 28 and 29, whose body must be
+  exactly its rows: one byte more or less closes the connection; an
+  acquire frame is answered up to ``MAX_ACQUIRE_ROWS`` rows, the most whose
+  17-byte reply rows fit a frame, and closes the connection past it:
+  ``acquire_bound_case``);
 - TRUNCATED valid frames followed by socket close mid-frame;
 - oversize declared n vs actual payload;
 - valid frames delivered 1–3 bytes at a time interleaved with garbage
@@ -59,11 +64,68 @@ def _valid_param_frame(xid: int, n: int, k: int) -> bytes:
     return struct.pack(">H", len(payload)) + payload
 
 
+def _valid_acquire_frame(xid: int, n: int) -> bytes:
+    """One BATCH_CONCURRENT_ACQUIRE frame: BATCH_FLOW's bytes, type 28."""
+    f = bytearray(_valid_batch_frame(xid, n))
+    f[6] = 28
+    return bytes(f)
+
+
+def _valid_release_frame(xid: int, n: int) -> bytes:
+    """One BATCH_CONCURRENT_RELEASE frame: ``n`` token ids."""
+    payload = struct.pack(">iBH", xid, 29, n) + struct.pack(
+        f">{n}q", *(random.randrange(1 << 40) for _ in range(n)))
+    return struct.pack(">H", len(payload)) + payload
+
+
 def _valid_frame(xid: int, n: int, rng: random.Random) -> bytes:
-    """A valid batch frame of either data-plane kind."""
-    if rng.randrange(2):
+    """A valid batch frame of any data-plane kind."""
+    kind = rng.randrange(4)
+    if kind == 0:
         return _valid_param_frame(xid, n, rng.randrange(1, 5))
+    if kind == 1:
+        return _valid_acquire_frame(xid, n)
+    if kind == 2:
+        return _valid_release_frame(xid, n)
     return _valid_batch_frame(xid, n)
+
+
+# the most acquire rows whose reply (17 B a row) fits a 65535-byte frame;
+# the request's 13-byte rows would hold 5040 (protocol.MAX_ACQUIRE_PER_FRAME)
+MAX_ACQUIRE_ROWS = (65535 - 7) // 17
+MAX_REQUEST_ROWS = (65535 - 7) // 13
+
+
+def acquire_bound_case(port: int, timeout: float = 10.0) -> bool:
+    """The acquire frame's row bound: ``MAX_ACQUIRE_ROWS`` rows are answered
+    in one frame whose length prefix is whole; one row more, and the most
+    the request's own rows allow, close the connection unanswered (a reply
+    of their rows would wrap the u16 length and misframe the stream)."""
+    with socket.create_connection(("127.0.0.1", port), timeout=timeout) as s:
+        s.settimeout(timeout)
+        s.sendall(_valid_acquire_frame(11, MAX_ACQUIRE_ROWS))
+        want = 2 + 7 + MAX_ACQUIRE_ROWS * 17
+        buf = b""
+        while len(buf) < want:
+            chunk = s.recv(1 << 16)
+            if not chunk:
+                return False
+            buf += chunk
+        if (len(buf) != want
+                or struct.unpack(">HiBH", buf[:9])
+                != (want - 2, 11, 28, MAX_ACQUIRE_ROWS)):
+            return False
+    for n in (MAX_ACQUIRE_ROWS + 1, MAX_REQUEST_ROWS):
+        with socket.create_connection(("127.0.0.1", port),
+                                      timeout=timeout) as s:
+            s.settimeout(timeout)
+            s.sendall(_valid_acquire_frame(12, n))
+            try:
+                if s.recv(64) != b"":
+                    return False
+            except ConnectionResetError:
+                pass  # closed with our bytes unread
+    return True
 
 
 def _valid_flow_frame(xid: int) -> bytes:
@@ -80,14 +142,17 @@ def _mutate(frame: bytes, rng: random.Random) -> bytes:
 
 
 def _oracle_roundtrip(port: int, timeout: float = 5.0) -> bool:
-    """One valid BATCH_FLOW and one valid BATCH_PARAM_FLOW round trip on a
-    fresh connection: four verdict rows each, under the request's type."""
+    """One valid BATCH_FLOW, BATCH_PARAM_FLOW, BATCH_CONCURRENT_ACQUIRE and
+    BATCH_CONCURRENT_RELEASE round trip on a fresh connection: four verdict
+    rows each, under the request's type and in its row size."""
     with socket.create_connection(("127.0.0.1", port), timeout=timeout) as s:
         s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         s.settimeout(timeout)
-        for xid, mtype, frame in (
-            (7, 5, _valid_batch_frame(xid=7, n=4)),
-            (8, 27, _valid_param_frame(xid=8, n=4, k=2)),
+        for xid, mtype, frame, row in (
+            (7, 5, _valid_batch_frame(xid=7, n=4), 9),
+            (8, 27, _valid_param_frame(xid=8, n=4, k=2), 9),
+            (9, 28, _valid_acquire_frame(xid=9, n=4), 17),
+            (10, 29, _valid_release_frame(xid=10, n=4), 1),
         ):
             s.sendall(frame)
             buf = b""
@@ -99,7 +164,7 @@ def _oracle_roundtrip(port: int, timeout: float = 5.0) -> bool:
                 buf += chunk
             flen = struct.unpack(">H", buf[:2])[0]
             got = struct.unpack(">iBH", buf[2:9])
-            if got != (xid, mtype, 4) or flen != 7 + 4 * 9:
+            if got != (xid, mtype, 4) or flen != 7 + 4 * row:
                 return False
         return True
     return False
@@ -121,9 +186,14 @@ def _fuzz_one_conn(port: int, rng: random.Random) -> None:
             elif kind == 2:  # truncated frame, close mid-parse
                 f = _valid_frame(1, rng.randrange(1, 64), rng)
                 s.sendall(f[: rng.randrange(1, len(f))])
+            elif kind == 3 and rng.randrange(8) == 0:
+                # whole acquire rows, more of them than a reply can answer
+                s.sendall(_valid_acquire_frame(1, rng.randrange(
+                    MAX_ACQUIRE_ROWS + 1, MAX_REQUEST_ROWS + 1)))
             elif kind == 3:  # oversize declared n vs actual rows
                 n_claim = rng.randrange(64, 5000)
-                head = (struct.pack(">iBH", 1, 5, n_claim)
+                head = (struct.pack(">iBH", 1, rng.choice((5, 28, 29)),
+                                    n_claim)
                         if rng.randrange(2) else
                         struct.pack(">iBHB", 1, 27, n_claim,
                                     rng.randrange(0, 256)))
@@ -131,7 +201,9 @@ def _fuzz_one_conn(port: int, rng: random.Random) -> None:
                 s.sendall(struct.pack(">H", len(payload)) + payload)
             else:  # drip-feed a valid frame in tiny chunks, then garbage
                 f = (_valid_batch_frame(3, 8) + _valid_flow_frame(4)
-                     + _valid_param_frame(5, 8, 3))
+                     + _valid_param_frame(5, 8, 3)
+                     + _valid_release_frame(6, 5)
+                     + _valid_acquire_frame(7, 8))
                 i = 0
                 while i < len(f):
                     step = rng.randrange(1, 4)
@@ -165,6 +237,7 @@ def run_fuzz(iters: int = 200, seed: int = 0, arena_cap: int = 65536,
         NativeTokenServer,
         native_available,
     )
+    from sentinel_tpu.cluster.concurrent import ConcurrentFlowRule
     from sentinel_tpu.cluster.token_service import DefaultTokenService
     from sentinel_tpu.engine import ClusterFlowRule, EngineConfig
     from sentinel_tpu.engine.rules import ThresholdMode
@@ -177,6 +250,9 @@ def run_fuzz(iters: int = 200, seed: int = 0, arena_cap: int = 65536,
         ClusterFlowRule(flow_id=i, count=1e9, mode=ThresholdMode.GLOBAL)
         for i in range(64)
     ])
+    svc.load_concurrent_rules(
+        [ConcurrentFlowRule(i, 4, resource_timeout_ms=200)
+         for i in range(64)])
     server = NativeTokenServer(svc, port=0, idle_ttl_s=None,
                                arena_cap=arena_cap)
     server.start()
@@ -184,6 +260,7 @@ def run_fuzz(iters: int = 200, seed: int = 0, arena_cap: int = 65536,
     checks = 0
     try:
         assert _oracle_roundtrip(server.port), "server dead before fuzz"
+        assert acquire_bound_case(server.port), "acquire row bound"
         for i in range(iters):
             _fuzz_one_conn(server.port, rng)
             if (i + 1) % oracle_every == 0:
@@ -192,6 +269,7 @@ def run_fuzz(iters: int = 200, seed: int = 0, arena_cap: int = 65536,
                     f"(seed {seed})"
                 )
                 checks += 1
+        assert acquire_bound_case(server.port), "acquire row bound"
         assert _oracle_roundtrip(server.port), "server dead after fuzz"
         stats = server.stats()
     finally:
@@ -229,7 +307,8 @@ def run_fuzz_raw(iters: int = 300, seed: int = 0,
     )
 
     def dispatch():
-        # flow pulls and param pulls alike: every row GRANTED
+        # flow, param and concurrency pulls alike: every row GRANTED (a
+        # release row's status byte is then 0 too; the oracle reads sizes)
         while not stop.is_set():
             got = door.wait_any_into(block, timeout_ms=50)
             if got is None:
@@ -254,6 +333,7 @@ def run_fuzz_raw(iters: int = 300, seed: int = 0,
     checks = 0
     try:
         assert _oracle_roundtrip(door.port), "front door dead before fuzz"
+        assert acquire_bound_case(door.port), "acquire row bound"
         for i in range(iters):
             _fuzz_one_conn(door.port, rng)
             if (i + 1) % oracle_every == 0:
@@ -262,6 +342,7 @@ def run_fuzz_raw(iters: int = 300, seed: int = 0,
                     f"(seed {seed})"
                 )
                 checks += 1
+        assert acquire_bound_case(door.port), "acquire row bound"
         assert _oracle_roundtrip(door.port), "front door dead after fuzz"
         stats = door.stats()
     finally:

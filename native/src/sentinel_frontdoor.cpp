@@ -20,6 +20,17 @@
 //     sn_fd_wait_any hands out whichever arena's head frame arrived first,
 //     and of the param arena a run of frames with one k. Replies are
 //     BATCH_FLOW's rows under type 27.
+//   BATCH_CONCURRENT_ACQUIRE (type 28, codec rev 9): BATCH_FLOW's request
+//     rows; BATCH_CONCURRENT_RELEASE (type 29): n:u16 then n×token_id:i64,
+//     a row each with the id in the flow_id column. Both go to the
+//     concurrency arena in arrival order, so a pull is a connection-ordered
+//     run of release ids and acquire rows (sn_fd_wait_any: *k_out = -1) and
+//     never mixes with flow or param rows. A body that is not exactly its
+//     rows closes the connection, and so does an acquire frame of more rows
+//     than a reply frame holds (kMaxAcquireRows: the reply's rows are the
+//     wider, and its u16 length would wrap). Replies: an acquire's rows are FLOW's with
+//     token_id:i64 behind them (sn_fd_submit's token_ids), a release's one
+//     status byte a row.
 // Control plane (forwarded to Python, rare): PING, PARAM_FLOW,
 //   CONCURRENT_ACQUIRE/RELEASE, plus open/close connection events so the
 //   host keeps its ConnectionManager (namespace groups, idle sweep) exact.
@@ -78,6 +89,12 @@ constexpr int kRspRow = 9;         // status:i8 + remaining:i32 + wait:i32
 constexpr uint8_t kTypeFlow = 1;
 constexpr uint8_t kTypeBatchFlow = 5;
 constexpr uint8_t kTypeBatchParam = 27;
+constexpr uint8_t kTypeBatchAcquire = 28;
+constexpr uint8_t kTypeBatchRelease = 29;
+constexpr int kAcquireRspRow = 17;  // kRspRow + token_id:i64
+// an acquire's reply rows are wider than its request rows: the most rows
+// whose reply still fits a frame (protocol.MAX_ACQUIRE_PER_FRAME, 3854)
+constexpr int32_t kMaxAcquireRows = (65535 - kHead - 2) / kAcquireRspRow;
 // one max-size param frame holds at most (65535 - 8) / 21 * 1 .. 8190 values
 constexpr size_t kMaxFrameValues = 8192;
 constexpr size_t kMaxFrame = 65535;
@@ -142,9 +159,10 @@ struct FrameMeta {
   uint32_t gen;
   int32_t xid;
   int32_t n;       // requests in this frame
-  uint8_t type;    // kTypeFlow | kTypeBatchFlow | kTypeBatchParam
+  uint8_t type;    // kTypeFlow | kTypeBatchFlow | kTypeBatchParam |
+                   // kTypeBatchAcquire | kTypeBatchRelease
   uint8_t k;       // values per request (param frames; 0 otherwise)
-  uint64_t seq;    // arrival order over both arenas
+  uint64_t seq;    // arrival order over the arenas
   int64_t rx_ns;   // mono_ns() after the recv() that read its last byte
 };
 
@@ -201,10 +219,12 @@ struct Frontdoor {
   std::mutex mu;
   std::condition_variable cv;  // signaled when arena/control non-empty
 
-  // request arenas (guarded by mu): flow rows, and param rows
+  // request arenas (guarded by mu): flow rows, param rows, and the rows
+  // of the concurrency frames (acquire rows; a release row is its token id
+  // in the flow_id column)
   size_t cap;
   size_t hash_cap;
-  Arena flow, param;
+  Arena flow, param, conc;
   uint64_t next_seq = 0;
   bool arena_was_full = false;
 
@@ -234,7 +254,7 @@ struct Frontdoor {
 
   explicit Frontdoor(size_t arena_cap)
       : cap(arena_cap), hash_cap(std::max(arena_cap, kMaxFrameValues)) {
-    for (Arena *a : {&flow, &param}) {
+    for (Arena *a : {&flow, &param, &conc}) {
       a->flow_ids.resize(cap);
       a->counts.resize(cap);
       a->prios.resize(cap);
@@ -341,11 +361,21 @@ bool parse_frames(Frontdoor *s, Conn &c) {
       const uint8_t *payload = p + 2;
       uint8_t type = payload[4];
       if (type == kTypeBatchFlow || type == kTypeFlow ||
-          type == kTypeBatchParam) {
+          type == kTypeBatchParam || type == kTypeBatchAcquire ||
+          type == kTypeBatchRelease) {
         int32_t n;
         int32_t k = 0;  // values per request: param frames only
         const uint8_t *rows;
-        if (type == kTypeBatchParam) {
+        if (type == kTypeBatchAcquire || type == kTypeBatchRelease) {
+          // rev 9: the body is exactly its rows (runt or over-long: closed)
+          if (flen < size_t(kHead + 2)) return false;
+          n = be16(payload + kHead);
+          size_t row = type == kTypeBatchRelease ? 8 : size_t(kReqRow);
+          if (flen != size_t(kHead + 2) + size_t(n) * row) return false;
+          // more rows than a reply frame can answer: closed as well
+          if (type == kTypeBatchAcquire && n > kMaxAcquireRows) return false;
+          rows = payload + kHead + 2;
+        } else if (type == kTypeBatchParam) {
           if (flen < size_t(kHead + 3)) return false;
           n = be16(payload + kHead);
           k = payload[kHead + 2];
@@ -383,7 +413,10 @@ bool parse_frames(Frontdoor *s, Conn &c) {
           wake_self = true;
           continue;
         }
-        Arena &a = type == kTypeBatchParam ? s->param : s->flow;
+        Arena &a = type == kTypeBatchParam ? s->param
+                   : (type == kTypeBatchAcquire || type == kTypeBatchRelease)
+                       ? s->conc
+                       : s->flow;
         if (a.n_requests + size_t(n) > s->cap ||
             a.n_hashes + size_t(n) * size_t(k) > s->hash_cap) {
           // arena full: park this conn; bytes stay buffered
@@ -394,12 +427,20 @@ bool parse_frames(Frontdoor *s, Conn &c) {
         }
         size_t base = a.n_requests;
         int64_t *hv = k ? a.hashes.data() + a.n_hashes : nullptr;
-        for (int32_t i = 0; i < n; ++i) {
-          a.flow_ids[base + i] = be64(rows);
-          a.counts[base + i] = be32(rows + 8);
-          a.prios[base + i] = rows[12];
-          rows += kReqRow;
-          for (int32_t j = 0; j < k; ++j, rows += 8) *hv++ = be64(rows);
+        if (type == kTypeBatchRelease) {  // a row is its token id
+          for (int32_t i = 0; i < n; ++i, rows += 8) {
+            a.flow_ids[base + i] = be64(rows);
+            a.counts[base + i] = 0;
+            a.prios[base + i] = 0;
+          }
+        } else {
+          for (int32_t i = 0; i < n; ++i) {
+            a.flow_ids[base + i] = be64(rows);
+            a.counts[base + i] = be32(rows + 8);
+            a.prios[base + i] = rows[12];
+            rows += kReqRow;
+            for (int32_t j = 0; j < k; ++j, rows += 8) *hv++ = be64(rows);
+          }
         }
         a.n_requests += size_t(n);
         a.n_hashes += size_t(n) * size_t(k);
@@ -621,6 +662,7 @@ void io_loop(Frontdoor *s) {
         out.swap(s->outbox);
         bool arena_ok = s->arena_was_full && s->flow.n_requests < s->cap &&
                         s->param.n_requests < s->cap &&
+                        s->conc.n_requests < s->cap &&
                         s->param.n_hashes < s->hash_cap;
         if (arena_ok) s->arena_was_full = false;
         bool ctrl_ok =
@@ -870,10 +912,12 @@ SN_EXPORT int32_t sn_fd_wait_batch(void *h, int32_t timeout_ms, int64_t *ids,
   return n;
 }
 
-// sn_fd_wait_batch for a host that serves both kinds of rows: one pull is
-// either flow rows (*k_out = 0) or param rows (*k_out = values per request,
-// their hashes in ``hashes`` as [n, k]), never both: the arena whose head
-// frame arrived first is served. max_hashes bounds the values of one pull.
+// sn_fd_wait_batch for a host that serves every kind of rows: one pull is
+// flow rows (*k_out = 0), param rows (*k_out = values per request, their
+// hashes in ``hashes`` as [n, k]) or the rows of concurrency frames (*k_out
+// = -1: f_type says which frames are releases, whose rows carry a token id
+// in ``ids``), never two kinds: the arena whose head frame arrived first is
+// served. max_hashes bounds the values of one pull.
 SN_EXPORT int32_t sn_fd_wait_any(void *h, int32_t timeout_ms, int64_t *ids,
                                  int32_t *counts, uint8_t *prios,
                                  int64_t *hashes, int32_t max_n,
@@ -884,7 +928,9 @@ SN_EXPORT int32_t sn_fd_wait_any(void *h, int32_t timeout_ms, int64_t *ids,
                                  int32_t *k_out, int64_t *wake_ns_out) {
   auto *s = static_cast<Frontdoor *>(h);
   std::unique_lock<std::mutex> lk(s->mu);
-  auto queued = [s] { return s->flow.n_requests + s->param.n_requests > 0; };
+  auto queued = [s] {
+    return s->flow.n_requests + s->param.n_requests + s->conc.n_requests > 0;
+  };
   if (!queued()) {
     s->cv.wait_for(lk, std::chrono::milliseconds(timeout_ms), [s, &queued] {
       return queued() || s->stopping.load(std::memory_order_acquire);
@@ -893,12 +939,15 @@ SN_EXPORT int32_t sn_fd_wait_any(void *h, int32_t timeout_ms, int64_t *ids,
   *n_frames_out = 0;
   *k_out = 0;
   if (!queued()) return 0;
-  bool take_param =
-      s->param.n_requests > 0 &&
-      (s->flow.n_requests == 0 ||
-       s->param.frames.front().seq < s->flow.frames.front().seq);
-  Arena &a = take_param ? s->param : s->flow;
-  if (take_param) *k_out = a.frames.front().k;
+  // the arena whose head frame arrived first
+  Arena *first = nullptr;
+  for (Arena *c : {&s->flow, &s->param, &s->conc})
+    if (c->n_requests > 0 &&
+        (!first || c->frames.front().seq < first->frames.front().seq))
+      first = c;
+  Arena &a = *first;
+  if (first == &s->param) *k_out = a.frames.front().k;
+  if (first == &s->conc) *k_out = -1;
   int32_t n = take_frames(a, ids, counts, prios, hashes, max_n, max_hashes,
                           f_fd, f_gen, f_xid, f_n, f_type, f_rx_ns,
                           max_frames, n_frames_out);
@@ -923,8 +972,15 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
                             const int32_t *f_n, const uint8_t *f_type,
                             const int64_t *f_rx_ns, const int8_t *status,
                             const int32_t *remaining,
-                            const int32_t *wait_ms) {
+                            const int32_t *wait_ms,
+                            const int64_t *token_ids) {
   auto *s = static_cast<Frontdoor *>(h);
+  // bytes of one reply row of a batch frame, by its type
+  auto rsp_row = [](uint8_t type) -> size_t {
+    return type == kTypeBatchAcquire   ? size_t(kAcquireRspRow)
+           : type == kTypeBatchRelease ? size_t(1)
+                                       : size_t(kRspRow);
+  };
   const int64_t submit_ns = f_rx_ns ? mono_ns() : 0;
   std::vector<std::pair<std::pair<int32_t, uint32_t>, OutBuf>> staged;
   size_t off = 0;
@@ -937,7 +993,7 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
     size_t total = 0;
     for (int32_t k = i; k < run_end; ++k)
       total += (f_type[k] != kTypeFlow)
-                   ? 2 + size_t(kHead) + 2 + size_t(f_n[k]) * kRspRow
+                   ? 2 + size_t(kHead) + 2 + size_t(f_n[k]) * rsp_row(f_type[k])
                    : 2 + size_t(kHead) + kRspRow;
     OutBuf buf;
     buf.wire = total;
@@ -948,17 +1004,24 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
     uint8_t *p = reinterpret_cast<uint8_t *>(&buf.data[0]);
     for (int32_t k = i; k < run_end; ++k) {
       int32_t n = f_n[k];
-      if (f_type[k] != kTypeFlow) {  // BATCH_FLOW or BATCH_PARAM_FLOW rows
-        size_t payload = size_t(kHead) + 2 + size_t(n) * kRspRow;
+      if (f_type[k] != kTypeFlow) {  // the rows of a batch frame
+        const size_t rsz = rsp_row(f_type[k]);
+        size_t payload = size_t(kHead) + 2 + size_t(n) * rsz;
         put16(p, uint16_t(payload));
         put32(p + 2, uint32_t(f_xid[k]));
         p[6] = f_type[k];
         put16(p + 7, uint16_t(n));
         uint8_t *row = p + 9;
-        for (int32_t j = 0; j < n; ++j, row += kRspRow) {
+        for (int32_t j = 0; j < n; ++j, row += rsz) {
           row[0] = uint8_t(status[off + size_t(j)]);
+          if (rsz == 1) continue;  // a release's row is its status
           put32(row + 1, uint32_t(remaining[off + size_t(j)]));
           put32(row + 5, uint32_t(wait_ms[off + size_t(j)]));
+          if (rsz == size_t(kAcquireRspRow)) {
+            uint64_t id = token_ids ? uint64_t(token_ids[off + size_t(j)]) : 0;
+            put32(row + 9, uint32_t(id >> 32));
+            put32(row + 13, uint32_t(id));
+          }
         }
         p += 2 + payload;
       } else {  // single FLOW response
@@ -1117,7 +1180,7 @@ SN_EXPORT void sn_fd_echo_start(void *h) {
       for (int32_t i = 0; i < n; ++i) rem[i] = counts[i];
       sn_fd_submit(h, nf, f_fd.data(), f_gen.data(), f_xid.data(),
                    f_n.data(), f_type.data(), f_rx.data(), status.data(),
-                   rem.data(), wait.data());
+                   rem.data(), wait.data(), nullptr);
     }
   });
 }

@@ -23,9 +23,8 @@ _EXPORTS = {
     "TokenResult": "sentinel_tpu.cluster.token_service",
     "TokenService": "sentinel_tpu.cluster.token_service",
     "DefaultTokenService": "sentinel_tpu.cluster.token_service",
-    "ConcurrencyManager": "sentinel_tpu.cluster.concurrent",
     "ConcurrentFlowRule": "sentinel_tpu.cluster.concurrent",
-    "ExpiryTask": "sentinel_tpu.cluster.concurrent",
+    "ConcurrentPlane": "sentinel_tpu.cluster.concurrent",
     "ClusterMode": "sentinel_tpu.cluster.api",
     "get_mode": "sentinel_tpu.cluster.api",
     "set_client": "sentinel_tpu.cluster.api",

@@ -379,7 +379,9 @@ class TokenClient(TokenService):
                             pending.event.set()
                         continue
                     if mtype in (P.MsgType.BATCH_FLOW,
-                                 P.MsgType.BATCH_PARAM_FLOW):
+                                 P.MsgType.BATCH_PARAM_FLOW,
+                                 P.MsgType.BATCH_CONCURRENT_ACQUIRE,
+                                 P.MsgType.BATCH_CONCURRENT_RELEASE):
                         # copy + store the raw payload; the waiting thread
                         # decodes (spreads the vectorized decode across
                         # callers). Frames whose waiter already gave up
@@ -1087,6 +1089,90 @@ class TokenClient(TokenService):
         finally:
             for xid, _, _, _ in pendings:
                 self._pending.pop(xid, None)
+
+    def _concurrent_frames(self, n: int, chunk: int, encode,
+                           timeout_ms: Optional[int]):
+        """``n`` rows over pipelined rev-9 frames of at most ``chunk`` rows
+        (``encode(xid, lo, hi)`` gives a frame's bytes): (status,
+        remaining, wait_ms, token_ids) in request order, or None on send
+        failure/timeout."""
+        import numpy as np
+
+        budget = (timeout_ms or self.timeout_ms) / 1000.0
+        pendings = []
+        try:
+            for lo in range(0, n, chunk):
+                hi = min(lo + chunk, n)
+                xid = next(self._xid)
+                pending = _Pending()
+                self._pending[xid] = pending
+                pendings.append((xid, pending, lo, hi))
+                if not self._send(encode(xid, lo, hi)):
+                    return None
+                self._count_rpc()
+            status = np.empty(n, np.int8)
+            remaining = np.empty(n, np.int32)
+            wait = np.empty(n, np.int32)
+            token_ids = np.empty(n, np.int64)
+            deadline = time.monotonic() + budget
+            for xid, pending, lo, hi in pendings:
+                if not pending.event.wait(max(deadline - time.monotonic(), 0)):
+                    return None
+                payload = pending.response
+                if not isinstance(payload, (bytes, bytearray)):
+                    return None  # connection died mid-batch
+                try:
+                    _, st, rem, wt, ids = (
+                        P.decode_batch_concurrent_response(payload))
+                except Exception:
+                    return None
+                if st.shape[0] != hi - lo:
+                    return None
+                status[lo:hi] = st
+                remaining[lo:hi] = rem
+                wait[lo:hi] = wt
+                token_ids[lo:hi] = ids
+            return status, remaining, wait, token_ids
+        finally:
+            for xid, _, _, _ in pendings:
+                self._pending.pop(xid, None)
+
+    def request_concurrent_batch(self, flow_ids, counts=None,
+                                 timeout_ms: Optional[int] = None):
+        """``n`` concurrency acquires over BATCH_CONCURRENT_ACQUIRE frames
+        (codec rev 9): (status int8[n], remaining int32[n], wait_ms
+        int32[n], token_ids int64[n]) in request order (the id 0 where the
+        row did not pass), or None on send failure/timeout. Batches past
+        one frame are pipelined like :meth:`request_params_batch`'s."""
+        import numpy as np
+
+        flow_ids = np.asarray(flow_ids, dtype=np.int64)
+        n = flow_ids.shape[0]
+        counts = np.broadcast_to(
+            np.asarray(1 if counts is None else counts, np.int32), (n,)
+        )
+        return self._concurrent_frames(
+            n, P.MAX_ACQUIRE_PER_FRAME,
+            lambda xid, lo, hi: P.encode_batch_concurrent_acquire(
+                xid, flow_ids[lo:hi], counts[lo:hi]),
+            timeout_ms,
+        )
+
+    def release_concurrent_batch(self, token_ids,
+                                 timeout_ms: Optional[int] = None):
+        """``n`` token ids given back over BATCH_CONCURRENT_RELEASE frames:
+        status int8[n] (RELEASE_OK or ALREADY_RELEASE) in request order, or
+        None on send failure/timeout."""
+        import numpy as np
+
+        token_ids = np.asarray(token_ids, dtype=np.int64)
+        out = self._concurrent_frames(
+            token_ids.shape[0], P.MAX_RELEASE_PER_FRAME,
+            lambda xid, lo, hi: P.encode_batch_concurrent_release(
+                xid, token_ids[lo:hi]),
+            timeout_ms,
+        )
+        return None if out is None else out[0]
 
     def request_batch(self, requests) -> list:
         """List-of-(flow_id, acquire, prioritized) → List[TokenResult]

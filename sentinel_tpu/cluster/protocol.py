@@ -50,6 +50,27 @@ frame with ``k = 0`` or a short body is malformed and closes the connection,
 an empty frame (``n = 0``) is answered in line. Servers before rev 8 reject
 the type byte.
 
+BATCH_CONCURRENT_ACQUIRE / BATCH_CONCURRENT_RELEASE (codec rev 9, types 28
+and 29; TPU extension): the batch frames of CONCURRENT_ACQUIRE and
+CONCURRENT_RELEASE (types 3 and 4, the reference client's, whose bytes are
+unchanged). An acquire frame is BATCH_FLOW's request under type 28 (``n:uint16``
++ n × ``(flow_id:int64, count:int32, priority:uint8)``); its response's rows
+are FLOW's with the token id behind them: ``n:uint16`` + n ×
+``(status:int8, remaining:int32, wait_ms:int32, token_id:int64)``, the id 0
+where the row did not pass (``ConcurrentFlowAcquireResponseData``). The
+response's rows are the wider (17 bytes against 13), so an acquire frame
+carries at most ``MAX_ACQUIRE_PER_FRAME`` = 3,854 rows, what one response
+frame answers; the encoder refuses more, the client chunks at it, and both
+doors close a connection that sends more. A release
+frame is ``n:uint16`` + n × ``token_id:int64`` (at most 8,191 a frame) and IS
+answered, as upstream answers a release: ``n:uint16`` + n × ``status:int8``
+(RELEASE_OK or ALREADY_RELEASE). Both doors decide the rows of both frames,
+and of drained single type-3 / type-4 frames, through ONE batched service
+entry (``TokenService.request_concurrent_batch``); the native door decodes
+them on its data plane into an arena of their own. A body shorter or longer
+than its header declares is malformed and closes the connection; an empty
+frame is answered in line. Servers before rev 9 reject the type bytes.
+
 Codec rev 3 — replication frames (``sentinel_tpu.ha.replication``): a
 primary token server streams state to warm standbys over the SAME wire as
 the data plane (both front doors route the new type bytes to their control
@@ -207,8 +228,9 @@ from sentinel_tpu import chaos as _chaos
 
 # codec revision this build speaks: 2 deadline trailer, 3 REPL, 4 MOVE,
 # 5 LEASE + HIER share ops, 6 OUTCOME_REPORT, 7 PUSH control plane,
-# 8 BATCH_PARAM_FLOW (the doc revisions above)
-WIRE_REV = 8
+# 8 BATCH_PARAM_FLOW, 9 BATCH_CONCURRENT_ACQUIRE / _RELEASE (the doc
+# revisions above)
+WIRE_REV = 9
 
 # 2-byte big-endian length prefix caps a frame at 65535 bytes; single-request
 # messages keep the reference's 1024-byte budget, BATCH_FLOW frames use the
@@ -252,6 +274,19 @@ def max_param_rows_per_frame(k: int) -> int:
         BATCH_REQ_DTYPE.itemsize + 8 * int(k)
     )
 
+
+# rev-9 concurrency batch frames: an acquire's request rows are BATCH_FLOW's,
+# its response rows FLOW's with the token id behind them; a release is ids
+# in, one status byte a row out
+CONCURRENT_RSP_DTYPE = np.dtype([("status", "i1"), ("remaining", ">i4"),
+                                 ("wait_ms", ">i4"), ("token_id", ">i8")])
+MAX_RELEASE_PER_FRAME = (MAX_FRAME - _HEAD.size - _BATCH_N.size) // 8
+# an acquire's response rows (17 B) are wider than its request rows (13 B):
+# a frame carries only as many rows as its response frame can answer, and
+# both doors close a connection that sends more (the native door's
+# kMaxAcquireRows)
+MAX_ACQUIRE_PER_FRAME = (
+    MAX_FRAME - _HEAD.size - _BATCH_N.size) // CONCURRENT_RSP_DTYPE.itemsize
 
 # rev-6 outcome rows: (flow_id, rt_ms, exc) — same 13-byte shape discipline
 # as BATCH_REQ_DTYPE so one frame coalesces ~5000 completions
@@ -312,6 +347,9 @@ class MsgType(enum.IntEnum):
     BROWNOUT_ADVISORY = 26
     # codec rev 8: the batch frame of PARAM_FLOW (data plane on both doors)
     BATCH_PARAM_FLOW = 27
+    # codec rev 9: the batch frames of CONCURRENT_ACQUIRE / _RELEASE
+    BATCH_CONCURRENT_ACQUIRE = 28
+    BATCH_CONCURRENT_RELEASE = 29
 
 
 # front doors route these type bytes to the replication applier instead of
@@ -606,6 +644,122 @@ def decode_batch_param_request(payload: bytes):
         rows["prio"].astype(bool),
         rows["hashes"].astype(np.int64).reshape(n, k),
     )
+
+
+def encode_batch_concurrent_acquire(xid: int, flow_ids, counts=None,
+                                    prios=None) -> bytes:
+    """One BATCH_CONCURRENT_ACQUIRE frame: BATCH_FLOW's request bytes under
+    type 28 (no deadline trailer: the body's length is exact), of at most
+    ``MAX_ACQUIRE_PER_FRAME`` rows."""
+    n = np.asarray(flow_ids).shape[0]
+    if n > MAX_ACQUIRE_PER_FRAME:
+        raise ValueError(
+            f"{n} acquire rows exceed {MAX_ACQUIRE_PER_FRAME} a frame")
+    raw = bytearray(encode_batch_request(xid, flow_ids, counts, prios))
+    raw[_LEN.size + 4] = MsgType.BATCH_CONCURRENT_ACQUIRE
+    return bytes(raw)
+
+
+def _exact_rows(payload: bytes, what: str, row_bytes: int) -> int:
+    """``n`` of a rev-9 frame whose body must be exactly ``n`` rows."""
+    off = _HEAD.size + _BATCH_N.size
+    if len(payload) < off:
+        raise ValueError(f"runt {what} frame")
+    (n,) = _BATCH_N.unpack_from(payload, _HEAD.size)
+    if len(payload) != off + n * row_bytes:
+        raise ValueError(
+            f"malformed {what} frame: {n} rows declared, "
+            f"{len(payload) - off} body bytes"
+        )
+    return n
+
+
+def decode_batch_concurrent_acquire(payload: bytes):
+    """BATCH_CONCURRENT_ACQUIRE payload → (xid, flow_ids int64[N], counts
+    int32[N], prios bool[N]). Raises ``ValueError`` on a body that is not
+    exactly its rows, or holds more rows than a response frame answers."""
+    n = _exact_rows(payload, "BATCH_CONCURRENT_ACQUIRE",
+                    BATCH_REQ_DTYPE.itemsize)
+    if n > MAX_ACQUIRE_PER_FRAME:
+        raise ValueError(
+            f"BATCH_CONCURRENT_ACQUIRE of {n} rows: a response frame "
+            f"answers at most {MAX_ACQUIRE_PER_FRAME}"
+        )
+    xid, _ = _HEAD.unpack_from(payload, 0)
+    rows = np.frombuffer(payload, dtype=BATCH_REQ_DTYPE, count=n,
+                         offset=_HEAD.size + _BATCH_N.size)
+    return (xid, rows["flow_id"].astype(np.int64),
+            rows["count"].astype(np.int32), rows["prio"].astype(bool))
+
+
+def encode_batch_concurrent_release(xid: int, token_ids) -> bytes:
+    """One BATCH_CONCURRENT_RELEASE frame: ``n:uint16`` + n ids."""
+    ids = np.asarray(token_ids, dtype=">i8")
+    n = ids.shape[0]
+    if n > MAX_RELEASE_PER_FRAME:
+        raise ValueError(
+            f"{n} token ids exceed {MAX_RELEASE_PER_FRAME} a frame")
+    return (
+        _LEN.pack(_HEAD.size + _BATCH_N.size + 8 * n)
+        + _HEAD.pack(xid, MsgType.BATCH_CONCURRENT_RELEASE)
+        + _BATCH_N.pack(n)
+        + ids.tobytes()
+    )
+
+
+def decode_batch_concurrent_release(payload: bytes):
+    """BATCH_CONCURRENT_RELEASE payload → (xid, token_ids int64[N])."""
+    n = _exact_rows(payload, "BATCH_CONCURRENT_RELEASE", 8)
+    xid, _ = _HEAD.unpack_from(payload, 0)
+    ids = np.frombuffer(payload, dtype=">i8", count=n,
+                        offset=_HEAD.size + _BATCH_N.size)
+    return xid, ids.astype(np.int64)
+
+
+def encode_batch_concurrent_response(xid: int, msg_type: int, status,
+                                     remaining=None, wait_ms=None,
+                                     token_ids=None) -> bytes:
+    """The response of a rev-9 frame: an acquire's rows (status, remaining,
+    wait_ms, token id), or under type 29 a release's (status alone)."""
+    status = np.asarray(status, dtype=np.int8)
+    n = status.shape[0]
+    if msg_type == MsgType.BATCH_CONCURRENT_RELEASE:
+        body = status.tobytes()
+    else:
+        rows = np.zeros(n, dtype=CONCURRENT_RSP_DTYPE)
+        rows["status"] = status
+        if remaining is not None:
+            rows["remaining"] = remaining
+        if wait_ms is not None:
+            rows["wait_ms"] = wait_ms
+        if token_ids is not None:
+            rows["token_id"] = token_ids
+        body = rows.tobytes()
+    return (
+        _LEN.pack(_HEAD.size + _BATCH_N.size + len(body))
+        + _HEAD.pack(xid, msg_type)
+        + _BATCH_N.pack(n)
+        + body
+    )
+
+
+def decode_batch_concurrent_response(payload: bytes):
+    """A rev-9 response payload → (xid, status int8[N], remaining int32[N],
+    wait_ms int32[N], token_ids int64[N]); a release's response has zeros
+    beside its statuses."""
+    xid, mtype = _HEAD.unpack_from(payload, 0)
+    (n,) = _BATCH_N.unpack_from(payload, _HEAD.size)
+    off = _HEAD.size + _BATCH_N.size
+    if mtype == MsgType.BATCH_CONCURRENT_RELEASE:
+        status = np.frombuffer(payload, dtype=np.int8, count=n, offset=off)
+        zero = np.zeros(n, np.int32)
+        return xid, status.copy(), zero, zero, np.zeros(n, np.int64)
+    rows = np.frombuffer(payload, dtype=CONCURRENT_RSP_DTYPE, count=n,
+                         offset=off)
+    return (xid, rows["status"].astype(np.int8),
+            rows["remaining"].astype(np.int32),
+            rows["wait_ms"].astype(np.int32),
+            rows["token_id"].astype(np.int64))
 
 
 def encode_outcome_report(xid: int, flow_ids, rt_ms, excs) -> bytes:

@@ -104,7 +104,7 @@ class RoutingTokenClient(TokenService):
         # a pod can serve several, and AVG_LOCAL counts need every one
         self._declared: Dict[str, set] = {}
         # concurrent-mode: per-pod token ids are local counters (each pod's
-        # ConcurrencyManager counts from 1), so the router namespaces the
+        # concurrency plane numbers its ids from 1), so the router namespaces the
         # ids it returns by embedding a pod number in the high bits — the
         # caller-visible id is globally unique and release routes exactly
         self._pod_nums: Dict[str, int] = {}  # pod_id → 1-based number
@@ -363,7 +363,9 @@ class RoutingTokenClient(TokenService):
         return result
 
     # pod number lives in bits 48+ of the caller-visible token id; pod-local
-    # ids below 2^48 (a per-pod counter would take >8900 years at 1M acq/s)
+    # ids below 2^48 (a pod's id is generation x max_tokens + slot, one slot
+    # an acquire row: 2^48 takes 8.9 years at 1M acquire rows/s; an id past
+    # it is handed on unprefixed)
     _POD_ID_SHIFT = 48
     _LOCAL_ID_MASK = (1 << 48) - 1
 

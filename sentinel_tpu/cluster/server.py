@@ -42,6 +42,7 @@ from sentinel_tpu.cluster import protocol as P
 from sentinel_tpu.cluster.connection import ConnectionManager
 from sentinel_tpu.cluster.token_service import (
     TokenService,
+    concurrent_batch_entry,
     decide_param_requests,
 )
 from sentinel_tpu.core.log import record_log
@@ -409,6 +410,48 @@ class _LoopWorker:
                         writer.write(P.encode_batch_response(
                             pxid, *verdicts,
                             msg_type=P.MsgType.BATCH_PARAM_FLOW,
+                        ))
+                        await writer.drain()
+                        continue
+                    if mtype in (P.MsgType.BATCH_CONCURRENT_ACQUIRE,
+                                 P.MsgType.BATCH_CONCURRENT_RELEASE):
+                        # codec rev 9: the rows of the frame go to the
+                        # service's batched concurrency entry in one call,
+                        # from this connection's reader (so a release is
+                        # applied before the acquire frame behind it)
+                        release = mtype == P.MsgType.BATCH_CONCURRENT_RELEASE
+                        try:
+                            if release:
+                                cxid, cids = (
+                                    P.decode_batch_concurrent_release(payload)
+                                )
+                                ccnts = None
+                            else:
+                                cxid, cids, ccnts, _cp = (
+                                    P.decode_batch_concurrent_acquire(payload)
+                                )
+                        except Exception:
+                            record_log.warning(
+                                "bad concurrent batch frame; closing"
+                            )
+                            return
+                        srv.connections.touch(address)
+                        k = len(cids)
+                        try:
+                            if srv.is_standby:
+                                verdicts = (np.full(k, _STANDBY, np.int8),)
+                            else:
+                                verdicts = await asyncio.to_thread(
+                                    concurrent_batch_entry(srv.service),
+                                    cids, ccnts, np.full(k, release),
+                                )
+                        except Exception:
+                            record_log.exception("concurrent batch failed")
+                            verdicts = (
+                                np.full(k, int(TokenStatus.FAIL), np.int8),
+                            )
+                        writer.write(P.encode_batch_concurrent_response(
+                            cxid, mtype, *verdicts
                         ))
                         await writer.drain()
                         continue

@@ -10,10 +10,16 @@ per DEVICE STEP, regardless of how many frames or connections fed it.
 This is the netty-pipeline analog (``NettyTransportServer.java:73-101``)
 taken to its TPU conclusion: the host's job is to keep the device fed.
 
-Control-plane frames (PING handshake, PARAM_FLOW, CONCURRENT_*) and
-open/close events surface through a low-rate poll thread so namespace
-connection groups (AVG_LOCAL scaling) and the host-side paths stay exactly
-as in the asyncio server. API-compatible with ``TokenServer`` (start/stop/
+Control-plane frames (PING handshake, single PARAM_FLOW and CONCURRENT_*
+frames) and open/close events surface through a low-rate poll thread so
+namespace connection groups (AVG_LOCAL scaling) and the host-side paths stay
+exactly as in the asyncio server; the single param and concurrency frames a
+door has queued are drained and decided through the services' batched
+entries. The batch frames of both (BATCH_PARAM_FLOW, codec rev 8;
+BATCH_CONCURRENT_ACQUIRE / _RELEASE, codec rev 9) are data plane on the TCP
+door: a pull is flow rows, param rows or concurrency rows, never two kinds,
+and a dispatch is one kind. The shm door stays flow-only.
+API-compatible with ``TokenServer`` (start/stop/
 port/connections/tuning_kwargs) so ``apply_cluster_mode`` and the benches
 can switch via ``native=True``.
 
@@ -49,6 +55,8 @@ from sentinel_tpu.cluster.connection import ConnectionManager
 from sentinel_tpu.cluster.token_service import (
     Materializer,
     TokenService,
+    concurrent_batch_entry,
+    decide_concurrent_requests,
     decide_param_requests,
     halves,
 )
@@ -63,6 +71,14 @@ from sentinel_tpu.trace.slo import slo_plane as _slo_plane
 _SM = server_metrics()
 _OVERLOAD = int(TokenStatus.OVERLOAD)
 _STANDBY = int(TokenStatus.STANDBY)
+_TYPE_BATCH_RELEASE = int(P.MsgType.BATCH_CONCURRENT_RELEASE)
+# single frames the control loop drains a queue at a time and decides
+# through the services' batched entries (_answer_params,
+# _answer_concurrent)
+_DRAINED_SINGLES = frozenset({
+    P.MsgType.PARAM_FLOW, P.MsgType.CONCURRENT_ACQUIRE,
+    P.MsgType.CONCURRENT_RELEASE,
+})
 
 
 def native_available() -> bool:
@@ -707,7 +723,10 @@ class NativeTokenServer:
                 if wake_ns:
                     _SM.door_wake_ms.record((t_py - wake_ns) * 1e-6)
                 # nv: values per request of a param pull (its rows are the
-                # requests of BATCH_PARAM_FLOW frames), 0 for a flow pull
+                # requests of BATCH_PARAM_FLOW frames), 0 for a flow pull,
+                # -1 for a concurrency pull (the rows of
+                # BATCH_CONCURRENT_ACQUIRE / _RELEASE frames in arrival
+                # order; a release row's id column holds its token id)
                 n, k, nv = got
                 if chaos.ARMED:
                     chaos.maybe_sleep("lane_delay")
@@ -722,7 +741,7 @@ class NativeTokenServer:
                 )
                 # the one host copy this path pays: C arena → staging
                 # (13B/row + 17B/frame) plus the 1B/row bool normalize
-                _SM.count_copy_bytes(n * (14 + 8 * nv) + k * 17)
+                _SM.count_copy_bytes(n * (14 + 8 * max(nv, 0)) + k * 17)
                 # the frames of the pull, as the door takes them back: the
                 # sixth column is each frame's rx stamp, which the door
                 # closes its spans from when the reply has gone out
@@ -774,7 +793,7 @@ class NativeTokenServer:
                 )
                 # pull = (rows..., frames, age stamp, owning door, block,
                 # value hashes [n, nv] of a param pull or None, hand-over
-                # stamp): the age stamp (the lane's first clock read, ns)
+                # stamp, nv): the age stamp (the lane's first clock read, ns)
                 # is the shed-by-age deadline proxy (the C++ door strips
                 # the wire deadline); the door routes replies and refusals
                 # back to the shard that owns the connection; the hand-over
@@ -784,8 +803,9 @@ class NativeTokenServer:
                 pull = (
                     block["ids"][:n], block["counts"][:n], prios, frames,
                     t_py, door, block,
-                    block["hashes"][:n * nv].reshape(n, nv) if nv else None,
-                    t_enq,
+                    block["hashes"][:n * nv].reshape(n, nv)
+                    if nv > 0 else None,
+                    t_enq, nv,
                 )
                 if self._lane_put(q, pull, give_up_after_s=give_up):
                     self._dispatch_sem.release()
@@ -867,8 +887,9 @@ class NativeTokenServer:
 
     def _tracked_dispatch(self, dispatch, ids, counts, third):
         """Issue one device dispatch under the inflight bound: a flow
-        dispatch (``third`` the priorities) or a param dispatch (``third``
-        the value hashes ``[n, k]``).
+        dispatch (``third`` the priorities), a param dispatch (``third``
+        the value hashes ``[n, k]``) or a concurrency dispatch (``third``
+        the rows that are releases).
 
         Returns ``(mat, release, overlapped)``: ``mat`` is a
         :class:`Materializer` whose read half reads the verdicts and gives
@@ -939,10 +960,15 @@ class NativeTokenServer:
         # is one or the other: a queued pull of another kind (or of another
         # number of values per request) waits in ``held`` for the next turn
         param_dispatch = getattr(service, "dispatch_params_batch", None)
+        # ... or, third, the rows of concurrency frames (kind -1): the
+        # release ids and acquire rows of its pulls in their arrival order,
+        # which the service applies releases first (a release is never
+        # applied later than an acquire frame behind it on its connection)
+        conc_dispatch = getattr(service, "dispatch_concurrent_batch", None)
         held = None
 
         def kind(pull) -> int:
-            return 0 if pull[7] is None else pull[7].shape[1]
+            return pull[9]
 
         def pop_next():
             # every sem permit has a queued item behind it and this lane
@@ -1012,7 +1038,16 @@ class NativeTokenServer:
                     if hashes is not None:
                         hashes = np.concatenate([p[7] for p in pulls])
                         _SM.count_copy_bytes(hashes.nbytes)
-                if hashes is None:
+                is_conc = kind(item) < 0
+                if is_conc:
+                    # which rows are releases: whole frames, by their type
+                    dispatch = conc_dispatch
+                    third = np.concatenate([
+                        np.repeat(p[3][4] == _TYPE_BATCH_RELEASE, p[3][3])
+                        for p in pulls
+                    ])
+                    sync = concurrent_batch_entry(service)
+                elif hashes is None:
                     # what a dispatch takes beside ids and counts, and what
                     # answers for a service without the dispatch/materialize
                     # split
@@ -1036,13 +1071,19 @@ class NativeTokenServer:
                     )
                     if expired.any():
                         shed = np.repeat(expired, lengths)
+                        if is_conc:
+                            # a release is never shed: refusing it would
+                            # hold its tokens until they expire
+                            shed &= ~third
                         n_deadline = int(shed.sum())
+                        if not n_deadline:
+                            shed = None
                 level = self.overload.level()
                 # tenant attribution is the flow table's; param rules have
                 # none on this lane
                 ns_fn = (
                     getattr(service, "namespace_index", None)
-                    if hashes is None else None
+                    if hashes is None and not is_conc else None
                 )
                 if _TR.ARMED:  # flight recorder: fused group dispatched
                     for p in pulls:
@@ -1064,6 +1105,11 @@ class NativeTokenServer:
                         deg = self.overload.shed_mask(prios, level)
                         if shed is not None:
                             deg = deg | shed
+                        if is_conc:
+                            # no local answer can carry a token: the whole
+                            # pull is refused, releases too (their tokens
+                            # expire unless the client asks again)
+                            deg = np.ones(n_rows, bool)
                         status, remaining, wait = (
                             self.overload.degrade_verdicts(deg)
                         )
@@ -1093,6 +1139,8 @@ class NativeTokenServer:
                                 prios, level,
                                 ns_idx=ns_pair[0], ns_names=ns_pair[1],
                             )
+                            if is_conc:
+                                m = m & ~third
                             mask = m if mask is None else (mask | m)
                             if not mask.any():
                                 mask = None
@@ -1148,13 +1196,18 @@ class NativeTokenServer:
                                 remaining = np.zeros(n, np.int32)
                                 wait = np.full(n, hint, np.int32)
                                 account = None
+                                tokens = ()
                                 if inner is not None:
                                     read, account = halves(inner)
-                                    st, rm, wt = read()
+                                    st, rm, wt, *tok = read()
                                     status[keep] = st
                                     remaining[keep] = rm
                                     wait[keep] = wt
-                                return (status, remaining, wait), account
+                                    if tok:  # a concurrency dispatch's ids
+                                        tokens = (np.zeros(n, np.int64),)
+                                        tokens[0][keep] = tok[0]
+                                return (status, remaining, wait,
+                                        *tokens), account
 
                             mat = Materializer(scatter)
                 except Exception:
@@ -1244,13 +1297,16 @@ class NativeTokenServer:
             read, account = halves(mat)
             t0 = time.monotonic_ns()
             try:
-                status, remaining, wait = read()
+                # a concurrency dispatch's verdicts carry a fourth array,
+                # the token ids of its acquire rows
+                status, remaining, wait, *tok = read()
             except Exception:
                 record_log.exception("materialize failed; failing batch")
                 n = sum(lengths)
                 status = np.full(n, int(TokenStatus.FAIL), np.int8)
                 remaining = np.zeros(n, np.int32)
                 wait = np.zeros(n, np.int32)
+                tok = ()
             t_write = time.monotonic_ns()
             _SM.decide_ms.record((t_write - t0) * 1e-6)
             off = 0
@@ -1276,6 +1332,7 @@ class NativeTokenServer:
                         status[off : off + span],
                         remaining[off : off + span],
                         wait[off : off + span],
+                        *(t[off : off + span] for t in tok),
                     )
                     # flight recorder: replies submitted to the door (parked
                     # in its outbox; the IO thread's send() comes later and
@@ -1315,12 +1372,17 @@ class NativeTokenServer:
         # request: what a door has queued is drained, the PARAM_FLOW
         # requests among it are set aside in queue order, and each run of
         # equal value counts is decided by ONE call of the service's
-        # batched entry (_answer_params).
+        # batched entry (_answer_params). Single CONCURRENT_ACQUIRE /
+        # _RELEASE frames are set aside the same way in a list of their
+        # own (_answer_concurrent).
         doors = list(self._doors)
         while not self._stop.is_set():
             got_any = False
             for door in doors:
-                params = []  # (fd, gen, request, address) in queue order
+                # (fd, gen, request, address) in queue order: the single
+                # PARAM_FLOW frames, and the single CONCURRENT_ACQUIRE /
+                # _RELEASE frames
+                params, conc = [], []
                 while True:
                     try:
                         item = door.next_control()
@@ -1332,13 +1394,15 @@ class NativeTokenServer:
                         break
                     got_any = True
                     self._handle_control_item(
-                        door, item, params,
+                        door, item, params, conc,
                         getattr(door, "last_control_ns", 0) or None,
                     )
-                    if len(params) >= self.max_batch:
+                    if len(params) + len(conc) >= self.max_batch:
                         break
                 if params:
                     self._answer_params(door, params)
+                if conc:
+                    self._answer_concurrent(door, conc)
             if not got_any:
                 self._stop.wait(0.002)
 
@@ -1358,11 +1422,31 @@ class NativeTokenServer:
                 P.FlowResponse(req.xid, req.msg_type, st, rm, wt)
             ))
 
-    def _handle_control_item(self, door, item, params=None,
+    def _answer_concurrent(self, door, items) -> None:
+        """Decide the drained single CONCURRENT_ACQUIRE / CONCURRENT_RELEASE
+        requests of one door, in queue order, through ONE call of the
+        service's batched concurrency entry (the entry the data plane's
+        batch frames reach: one gauge, whichever frame asks)."""
+        reqs = [req for _fd, _gen, req, _addr in items]
+        if self.is_standby:
+            verdicts = [(_STANDBY, 0, 0, 0)] * len(reqs)
+        else:
+            verdicts = decide_concurrent_requests(
+                self.service, reqs,
+                [r.msg_type == P.MsgType.CONCURRENT_RELEASE for r in reqs],
+                int(TokenStatus.FAIL),
+            )
+        for (fd, gen, req, _addr), (st, rm, wt, tok) in zip(items, verdicts):
+            door.send(fd, gen, P.encode_response(
+                P.FlowResponse(req.xid, req.msg_type, st, rm, wt, tok)
+            ))
+
+    def _handle_control_item(self, door, item, params, conc,
                              t_door_ns=None) -> None:
         """One control event. A PARAM_FLOW request is not answered here but
-        appended to ``params`` (the control loop's drain), to be decided
-        with the others queued beside it. ``t_door_ns`` is the
+        appended to ``params``, a CONCURRENT_ACQUIRE / _RELEASE request to
+        ``conc`` (the control loop's drains), to be decided with the
+        others queued beside it. ``t_door_ns`` is the
         ``monotonic_ns`` at which the door queued the frame: a completion
         report's age counts from it."""
         kind, fd, gen, payload = item
@@ -1492,10 +1576,10 @@ class NativeTokenServer:
             record_log.warning("bad control frame; closing %s", address)
             door.close_conn(fd, gen)
             return
-        if (params is not None and not isinstance(req, P.Ping)
-                and req.msg_type == P.MsgType.PARAM_FLOW):
+        if not isinstance(req, P.Ping) and req.msg_type in _DRAINED_SINGLES:
             self.connections.touch(address)
-            params.append((fd, gen, req, address))
+            drain = params if req.msg_type == P.MsgType.PARAM_FLOW else conc
+            drain.append((fd, gen, req, address))
             return
         try:
             rsp = self._handle_control(req, address)
@@ -1590,7 +1674,6 @@ class NativeTokenServer:
         )
 
     def _handle_control(self, req, address: str) -> P.FlowResponse:
-        service = self.service
         if isinstance(req, P.Ping):
             count = self.connections.add(req.namespace, address)
             return P.FlowResponse(req.xid, P.MsgType.PING, 0, remaining=count)
@@ -1599,17 +1682,8 @@ class NativeTokenServer:
             # control-lane verdicts get the same closed-door refusal as the
             # data plane (PING above still answers: standbys stay pingable)
             return P.FlowResponse(req.xid, req.msg_type, _STANDBY)
-        if req.msg_type == P.MsgType.CONCURRENT_ACQUIRE:
-            r = service.request_concurrent_token(
-                req.flow_id, req.count, req.prioritized
-            )
-            return P.FlowResponse(
-                req.xid, req.msg_type, int(r.status), r.remaining, r.wait_ms,
-                r.token_id,
-            )
-        if req.msg_type == P.MsgType.CONCURRENT_RELEASE:
-            r = service.release_concurrent_token(req.flow_id)
-            return P.FlowResponse(req.xid, req.msg_type, int(r.status))
+        # (PARAM_FLOW and CONCURRENT_ACQUIRE / _RELEASE never come here: the
+        # control loop drains them into the services' batched entries)
         return P.FlowResponse(req.xid, req.msg_type, int(TokenStatus.FAIL))
 
     def stats(self) -> dict:

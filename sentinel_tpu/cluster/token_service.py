@@ -233,6 +233,37 @@ def decide_param_requests(service, requests, fail_status: int):
     return out
 
 
+def concurrent_batch_entry(service):
+    """The entry a door decides concurrency rows through: as
+    :func:`params_batch_entry`, the served object's own
+    ``request_concurrent_batch`` where its class defines one, else the SPI
+    default over its one-row calls."""
+    if getattr(type(service), "request_concurrent_batch", None) is not None:
+        return service.request_concurrent_batch
+    return partial(TokenService.request_concurrent_batch, service)
+
+
+def decide_concurrent_requests(service, requests, is_release,
+                               fail_status: int):
+    """Single CONCURRENT_ACQUIRE / CONCURRENT_RELEASE requests (objects with
+    ``flow_id`` and ``count``; a release's ``flow_id`` slot carries its
+    token id) in queue order -> ``[(status, remaining, wait_ms, token_id)]``:
+    one call of the batched entry for all of them. A call that raises
+    answers ``fail_status``."""
+    n = len(requests)
+    try:
+        status, remaining, wait, token_ids = concurrent_batch_entry(service)(
+            np.fromiter((r.flow_id for r in requests), np.int64, n),
+            np.fromiter((r.count for r in requests), np.int32, n),
+            np.asarray(is_release, bool),
+        )
+        return list(zip(status.tolist(), remaining.tolist(), wait.tolist(),
+                        token_ids.tolist()))
+    except Exception:
+        record_log.exception("CONCURRENT requests failed")
+        return [(fail_status, 0, 0, 0)] * n
+
+
 class Materializer:
     """What a dispatch hands back: the zero-arg callable that blocks on the
     device and yields ``(status, remaining, wait)`` in request order, made
@@ -343,6 +374,29 @@ class TokenService:
     def release_concurrent_token(self, token_id: int) -> TokenResult:
         raise NotImplementedError
 
+    def request_concurrent_batch(self, ids, counts=None, is_release=None):
+        """Array form of the two calls above: row ``i`` acquires
+        ``counts[i]`` on flow ``ids[i]`` or, where ``is_release[i]``,
+        releases token ``ids[i]`` -> (status int8[n], remaining int32[n],
+        wait_ms int32[n], token_ids int64[n]) in request order. The doors
+        speak this for BATCH_CONCURRENT_ACQUIRE / _RELEASE frames and for
+        drained single frames; the default asks one row at a time, so any
+        SPI implementation serves them."""
+        n = len(ids)
+        results = [
+            self.release_concurrent_token(int(ids[i]))
+            if is_release is not None and is_release[i]
+            else self.request_concurrent_token(
+                int(ids[i]), 1 if counts is None else int(counts[i]))
+            for i in range(n)
+        ]
+        return (
+            np.fromiter((int(r.status) for r in results), np.int8, n),
+            np.fromiter((r.remaining for r in results), np.int32, n),
+            np.zeros(n, np.int32),
+            np.fromiter((r.token_id for r in results), np.int64, n),
+        )
+
 
 @dataclass(frozen=True)
 class LeaseResult:
@@ -406,6 +460,7 @@ class DefaultTokenService(TokenService):
         fuse_depths: Optional[Sequence[int]] = (8, 4, 2),
         lease_ttl_ms: int = 500,
         lease_fraction: float = 0.5,
+        concurrent_max_tokens: int = 1 << 20,
     ):
         self.config = config or EngineConfig()
         _compile_cache.install_compile_listener()
@@ -536,12 +591,16 @@ class DefaultTokenService(TokenService):
                 _self()
             )
         )
-        # concurrent (semaphore) mode — host-side by design, see
-        # sentinel_tpu.cluster.concurrent
-        from sentinel_tpu.cluster.concurrent import ConcurrencyManager
-
-        self.concurrency = ConcurrencyManager()
-        self._expiry = None  # background sweep; started on first rule load
+        # concurrent (semaphore) mode: the plane (cluster.concurrent,
+        # engine.concurrent) is allocated by the first load of concurrency
+        # rules and never before; its timer ticks the expiry scan when no
+        # dispatch does
+        self._concurrent_max_tokens = int(concurrent_max_tokens)
+        self._conc = None
+        self._conc_timer: Optional[threading.Thread] = None
+        self._conc_timer_stop = threading.Event()
+        self._conc_timer_closed = False  # close() until reopen()
+        self._conc_last_step_ns = 0
         # warm-standby replication hooks (ha.replication): dirty-slot sets
         # collected by the dispatch paths since the last export_delta().
         # None until replication_enable() — the serving hot path pays one
@@ -986,9 +1045,10 @@ class DefaultTokenService(TokenService):
         """``ConnectionManager`` callback: AVG_LOCAL thresholds scale with it.
         Counts persist across rule reloads. Namespaces no rule uses are
         remembered host-side but allocate no device slot."""
-        self.concurrency.set_connected_count(max(1, int(n)), namespace)
         with self._lock:
             self._connected[namespace] = max(1, int(n))
+            if self._conc is not None:
+                self._conc.connected_changed(namespace, self._connected)
             ns = self._index.ns_of.get(namespace)
             if ns is None:
                 return  # no rule in this namespace yet; applied on next load
@@ -1056,6 +1116,11 @@ class DefaultTokenService(TokenService):
                     pstarts == _PNEVER, pstarts, pstarts - jnp.int32(delta)
                 )
             )
+            if self._conc is not None:
+                # a token's expiry is engine-ms too (a free slot's is unread)
+                cs = self._conc.state
+                self._conc.state = cs._replace(
+                    tok_expire=cs.tok_expire - jnp.int32(delta))
             self._epoch_ms += delta
             now -= delta
         return now
@@ -1131,6 +1196,18 @@ class DefaultTokenService(TokenService):
                 ps, verdicts = self._param_step_fn(bucket)(ps, packed)
             jax.block_until_ready(verdicts)
             del ps
+            # the concurrency step, every serve bucket, on a throwaway
+            # plane: where concurrency rules are loaded, and only there
+            if self._conc is not None:
+                from sentinel_tpu.engine import concurrent as _CE
+
+                cs = _CE.make_concurrent_state(self._conc.config)
+                for bucket in self._serve_buckets:
+                    cs, verdicts = self._conc.step_fn(bucket)(
+                        cs, _CE.pack_concurrent_rows(
+                            bucket, (), (), (), (), now))
+                jax.block_until_ready(verdicts)
+                del cs
         # from here on a compile is one in front of live traffic: counted
         # (compiles_after_warmup_total) and logged by name
         _SM.set_warm(True)
@@ -1939,38 +2016,202 @@ class DefaultTokenService(TokenService):
 
     # -- concurrent (semaphore) mode ----------------------------------------
     def load_concurrent_rules(self, rules) -> None:
-        self.concurrency.load_rules(rules)
-        # the acquire-path sweep is bounded (64 entries), so a crashed client
-        # holding permits behind long-TTL live tokens needs the background
-        # sweep (RegularExpireStrategy analog) to reclaim them
-        if rules and self._expiry is None:
-            from sentinel_tpu.cluster.concurrent import ExpiryTask
+        """``ConcurrentFlowRule``s, replacing the set that was loaded. The
+        first non-empty load allocates the plane (``held``, the token table
+        and expiry on the device: ``engine/concurrent.py``) and starts its
+        timer; a service that never loads one holds nothing of it. Live
+        tokens survive a reload: a flow whose rule went answers NO_RULE and
+        keeps draining by release and expiry."""
+        from sentinel_tpu.cluster.concurrent import ConcurrentPlane
 
-            self._expiry = ExpiryTask(self.concurrency)
-            self._expiry.start()
+        rules = list(rules)
+        with self._rules_mutex, self._lock:
+            if self._conc is None:
+                if not rules:
+                    return
+                self._conc = ConcurrentPlane(
+                    self.config.max_flows, self._concurrent_max_tokens,
+                    self._serve_buckets,
+                )
+            self._conc.load_rules(rules, self._connected)
+        self._start_concurrent_timer()
+
+    def current_concurrent_rules(self) -> list:
+        with self._lock:
+            return [] if self._conc is None else list(
+                self._conc.rules.values())
+
+    def _start_concurrent_timer(self) -> None:
+        """The timer behind the expiry guarantee: a step of the plane at
+        least every ``TICK_MS``, so that the scan goes round the token ring
+        within ``EXPIRY_SLACK_MS`` with no traffic as well."""
+        from sentinel_tpu.cluster.concurrent import TICK_MS
+
+        if (self._conc is None or self._conc_timer is not None
+                or self._conc_timer_closed):
+            return
+        stop = self._conc_timer_stop = threading.Event()
+
+        def run():
+            while not stop.wait(TICK_MS / 1000.0):
+                idle_ns = time.monotonic_ns() - self._conc_last_step_ns
+                if idle_ns >= TICK_MS * 1_000_000:
+                    try:
+                        self.concurrent_tick()
+                    except Exception:
+                        record_log.exception("concurrent tick failed")
+
+        self._conc_timer = threading.Thread(
+            target=run, name="sentinel-concurrent-tick", daemon=True
+        )
+        self._conc_timer.start()
 
     def close(self) -> None:
-        if self._expiry is not None:
-            self._expiry.stop()
-            self._expiry = None
+        self._conc_timer_closed = True  # a later rule load starts none
+        timer, self._conc_timer = self._conc_timer, None
+        if timer is not None:
+            self._conc_timer_stop.set()
+            timer.join(timeout=5)
 
     def reopen(self) -> None:
         """Re-arm background resources after a close() when the service is
         put back behind a transport (e.g. a token-server port move reuses
         the service): without this, concurrent-mode tokens held by crashed
-        clients would only be reclaimed by the bounded acquire-path sweep."""
-        if self._expiry is None and self.concurrency.has_rules():
-            from sentinel_tpu.cluster.concurrent import ExpiryTask
+        clients would never be reclaimed while no request arrives."""
+        self._conc_timer_closed = False
+        self._start_concurrent_timer()
 
-            self._expiry = ExpiryTask(self.concurrency)
-            self._expiry.start()
+    def concurrent_tick(self) -> int:
+        """One step of the plane with no rows: the expiry scan's next
+        block. Returns the tokens it reclaimed. The timer calls it when no
+        dispatch has stepped the plane for ``TICK_MS``; tests with a clock
+        of their own call it by hand (after ``close()`` stopped the
+        timer)."""
+        from sentinel_tpu.engine import concurrent as _CE
+
+        plane = self._conc
+        if plane is None:
+            return 0
+        bucket = self._serve_buckets[0]
+        packed = _CE.pack_concurrent_rows(bucket, (), (), (), ())
+        step = plane.step_fn(bucket)
+        with self._lock:
+            packed[_CE.ROW_HEAD, _CE.HEAD_NOW] = self._engine_now()
+            plane.state, verdicts = step(plane.state, packed)
+            self._conc_last_step_ns = time.monotonic_ns()
+        misc = np.asarray(verdicts)[_CE.OUT_MISC]  # no op on the device
+        expired = int(misc[_CE.MISC_EXPIRED])
+        _SM.count_concurrent_step(0, 0, 0, 0, expired, 0,
+                                  int(misc[_CE.MISC_LIVE]), tick=True)
+        return expired
+
+    def request_concurrent_batch(self, ids, counts=None, is_release=None):
+        """Acquire and release rows in one call: ``(status int8[n],
+        remaining int32[n], wait_ms int32[n], token_ids int64[n])`` in
+        request order. Dispatch + materialize; pipelining callers use
+        :meth:`dispatch_concurrent_batch`."""
+        return self.dispatch_concurrent_batch(ids, counts, is_release)()
+
+    def dispatch_concurrent_batch(self, ids, counts=None, is_release=None):
+        """The concurrency serving path, phase 1: host prep + device
+        dispatch of ``n`` rows. Row ``i`` is an acquire of ``counts[i]`` on
+        flow ``ids[i]``, or, where ``is_release[i]``, a release of token
+        ``ids[i]``. Returns a zero-arg **materializer** like
+        :meth:`dispatch_batch_arrays`, with the same halves, phases and
+        histograms; its verdicts carry a fourth array, the token ids
+        (``int64``; 0 where the row is not an acquire that passed).
+
+        The releases of a dispatch are applied before its acquires (a
+        release can only free room), the acquires in row order:
+        ``cluster/concurrent.py`` has the semantics, ``engine/concurrent.py``
+        the step. Rows travel as one packed host array a step; a kind's
+        rows past the largest serve bucket are cut into steps launched
+        under one hold of the lock."""
+        t_enter = time.monotonic_ns()
+        ids = np.asarray(ids, np.int64)
+        n = ids.shape[0]
+        rel = (np.zeros(n, bool) if is_release is None
+               else np.asarray(is_release, bool))
+        plane = self._conc
+        if plane is None or n == 0:
+            # no concurrency rule was ever loaded: nothing is allocated,
+            # nothing compiled, and every flow is without a rule
+            def _ruleless():
+                status = np.where(
+                    rel, int(TokenStatus.ALREADY_RELEASE),
+                    int(TokenStatus.NO_RULE_EXISTS)).astype(np.int8)
+                zero = np.zeros(n, np.int32)
+                return status, zero, zero, np.zeros(n, np.int64)
+
+            return _ruleless
+        acq = (np.ones(n, np.int32) if counts is None
+               else np.broadcast_to(np.asarray(counts, np.int32), (n,)))
+        lookup = plane.lookup
+        parts = plane.prep(lookup, ids, acq, rel)
+        steps = [plane.step_fn(b) for b, *_rest in parts]
+        t_prep = time.monotonic_ns()
+        with self._lock:
+            t_locked = time.monotonic_ns()
+            seq = self._dispatch_seq = self._dispatch_seq + 1
+            if plane.lookup is not lookup:
+                # rules reloaded between prep and step: the slots moved
+                parts = plane.prep(plane.lookup, ids, acq, rel)
+                steps = [plane.step_fn(b) for b, *_rest in parts]
+            now = self._engine_now()
+            outs = []
+            for step, (_b, packed, _a, _r) in zip(steps, parts):
+                packed[-1, 0] = now  # engine.concurrent ROW_HEAD, HEAD_NOW
+                plane.state, verdicts = step(plane.state, packed)
+                outs.append(verdicts)
+            self._conc_last_step_ns = t_locked
+        for verdicts in outs:
+            verdicts.copy_to_host_async()
+        self._dispatched(t_enter, t_prep, t_locked, seq, n,
+                         lane=_TR.CONCURRENT_LANE)
+        n_rel = int(rel.sum())
+
+        def _read():
+            t_mat = time.monotonic_ns()
+            hosts = []
+            for verdicts in outs:
+                ready = verdicts.is_ready()
+                hosts.append(np.asarray(verdicts))
+                t_ready = time.monotonic_ns()
+                _SM.count_verdict_read(ready)
+            status, remaining, token_ids, expired, full, live = (
+                plane.unpack(n, parts, hosts))
+            _SM.count_concurrent_step(
+                n - n_rel, n_rel,
+                int((status == int(TokenStatus.BLOCKED)).sum()),
+                int((status == int(TokenStatus.ALREADY_RELEASE)).sum()),
+                expired, full, live,
+            )
+            wait = np.zeros(n, np.int32)
+            return (status, remaining, wait, token_ids), partial(
+                self._account, status, wait, None, seq, n, t_enter, t_mat,
+                t_ready, time.monotonic_ns(), lane=_TR.CONCURRENT_LANE,
+            )
+
+        return Materializer(_read)
 
     def request_concurrent_token(self, flow_id, acquire=1, prioritized=False):
-        r = self.concurrency.acquire(flow_id, acquire, prioritized)
-        return TokenResult(r.status, r.remaining, 0, r.token_id)
+        """The one-row case of :meth:`request_concurrent_batch`."""
+        status, remaining, _wait, token_ids = self.request_concurrent_batch(
+            np.array([flow_id], np.int64), np.array([acquire], np.int32))
+        return TokenResult(TokenStatus(int(status[0])), int(remaining[0]), 0,
+                           int(token_ids[0]))
 
     def release_concurrent_token(self, token_id):
-        return TokenResult(self.concurrency.release(token_id))
+        status, _r, _w, _t = self.request_concurrent_batch(
+            np.array([token_id], np.int64), None, np.ones(1, bool))
+        return TokenResult(TokenStatus(int(status[0])))
+
+    def concurrent_stats(self) -> Dict[str, object]:
+        """The plane read to the host: ``held`` and ``level`` by flow id,
+        the live tokens by id (``ConcurrentPlane.snapshot``); empty where
+        no concurrency rule was ever loaded."""
+        with self._lock:
+            return {} if self._conc is None else self._conc.snapshot()
 
     # -- live rebalance (cluster.rebalance backing) --------------------------
     def _rebuild_moving_snap(self) -> None:

@@ -24,9 +24,7 @@ import numpy as np
 from sentinel_tpu.core import clock as _clock
 from sentinel_tpu.metrics.histogram import LatencyHistogram
 
-# TokenStatus codes that appear on the flow batch path → series label.
-# (RELEASE_OK / ALREADY_RELEASE ride the host-side concurrent path, which
-# answers per-request, not per-batch — they never reach this counter.)
+# TokenStatus codes that appear on the batch paths → series label.
 VERDICT_NAMES: Dict[int, str] = {
     0: "pass",            # OK
     1: "block",           # BLOCKED
@@ -34,6 +32,8 @@ VERDICT_NAMES: Dict[int, str] = {
     3: "no_rule",         # NO_RULE_EXISTS
     4: "too_many_request",  # namespace guard tripped
     5: "fail",            # device step failed / degraded
+    6: "release_ok",      # a concurrency token given back
+    7: "already_release",  # a release of an id that holds nothing
     8: "overload",        # admission refused: queue full / deadline / brownout
     9: "standby",         # unpromoted warm standby refused to decide
     10: "moved",          # namespace rebalanced away: redirect to new owner
@@ -160,6 +160,29 @@ class ServerMetrics:
             "answered without touching the sketch (cumulative).",
     }
 
+    # the concurrency lane (DefaultTokenService.dispatch_concurrent_batch)
+    _CONCURRENT_COUNTERS = {
+        "concurrent_dispatch_total":
+            "Concurrency dispatches: calls of the batched entry that "
+            "reached the device step, idle ticks left out (cumulative).",
+        "concurrent_acquire_rows_total":
+            "Acquire rows those dispatches carried (cumulative).",
+        "concurrent_release_rows_total":
+            "Release rows those dispatches carried (cumulative).",
+        "concurrent_blocked_total":
+            "Acquire rows answered BLOCKED: the flow's level was held "
+            "(cumulative).",
+        "concurrent_already_release_total":
+            "Release rows answered ALREADY_RELEASE: released, expired, "
+            "never issued, or the slot reused since (cumulative).",
+        "concurrent_expired_total":
+            "Tokens reclaimed by expiry, dispatches and idle ticks "
+            "(cumulative).",
+        "concurrent_table_full_total":
+            "Acquire rows answered FAIL because their slot of the token "
+            "ring still held a live token (cumulative).",
+    }
+
     # what the decide step says of its cond-gated arms, per flow dispatch
     # (engine.decide.ARM_*): the step hands the predicates and row counts out
     # inside its packed verdicts, the service counts them here
@@ -284,6 +307,11 @@ class ServerMetrics:
         self._arm_lock = threading.Lock()
         self._arms = dict.fromkeys(self._ARM_COUNTERS, 0)
         self._param_impl = ("", "")
+        # the concurrency lane's counters, and its gauge of live tokens
+        # (what the last step said it left)
+        self._concurrent_lock = threading.Lock()
+        self._concurrent = dict.fromkeys(self._CONCURRENT_COUNTERS, 0)
+        self._concurrent_live = 0
         # stage histograms, all in milliseconds except batch_size (requests).
         # 1µs..10s covers a sub-100µs device step and a 1s cold compile alike.
         # queue_wait_ms: per queue item on the asyncio door; on the native
@@ -443,6 +471,30 @@ class ServerMetrics:
             p["param_values_total"] += int(values)
             p["param_blocked_total"] += int(blocked)
             p["param_no_rule_total"] += int(no_rule)
+
+    def count_concurrent_step(self, acquires: int, releases: int,
+                              blocked: int, already: int, expired: int,
+                              table_full: int, live: int,
+                              tick: bool = False) -> None:
+        """One concurrency step was read back: a dispatch, or an idle tick
+        (which counts what it expired and nothing else)."""
+        with self._concurrent_lock:
+            c = self._concurrent
+            if not tick:
+                c["concurrent_dispatch_total"] += 1
+                c["concurrent_acquire_rows_total"] += int(acquires)
+                c["concurrent_release_rows_total"] += int(releases)
+                c["concurrent_blocked_total"] += int(blocked)
+                c["concurrent_already_release_total"] += int(already)
+                c["concurrent_table_full_total"] += int(table_full)
+            c["concurrent_expired_total"] += int(expired)
+            self._concurrent_live = int(live)
+
+    def concurrent_totals(self) -> Dict[str, int]:
+        """The lane's counters and the gauge ``concurrent_tokens_live``."""
+        with self._concurrent_lock:
+            return dict(self._concurrent,
+                        concurrent_tokens_live=self._concurrent_live)
 
     def count_decide_arms(self, rows: int, shaping: bool, pacing: bool,
                           occupy: bool, shaped: int, paced: int,
@@ -1101,6 +1153,7 @@ class ServerMetrics:
         out["verdict_copy_ready_total"] = self.verdict_copy_ready_total
         out.update(self.param_totals())
         out.update(self.arm_totals())
+        out.update(self.concurrent_totals())
         out["reply_first_total"] = self.reply_first_total
         out["param_impl"], out["param_impl_reason"] = self.param_impl
         out["shed_total"] = self.shed_totals()
@@ -1498,6 +1551,9 @@ class ServerMetrics:
               for name, value in self.param_totals().items()),
             *((name, self._ARM_COUNTERS[name], value)
               for name, value in self.arm_totals().items()),
+            *((name, self._CONCURRENT_COUNTERS[name], value)
+              for name, value in self.concurrent_totals().items()
+              if name in self._CONCURRENT_COUNTERS),
             ("reply_first_total",
              "Dispatches accounted after their reply was submitted: the "
              "native reply lane answers first and counts after "
@@ -1506,6 +1562,11 @@ class ServerMetrics:
             lines.append(f"# HELP sentinel_server_{name} {help_text}")
             lines.append(f"# TYPE sentinel_server_{name} counter")
             lines.append(f"sentinel_server_{name} {value}")
+        lines.append("# HELP sentinel_server_concurrent_tokens_live "
+                     "Concurrency tokens held, as the last step left them.")
+        lines.append("# TYPE sentinel_server_concurrent_tokens_live gauge")
+        lines.append("sentinel_server_concurrent_tokens_live "
+                     f"{self.concurrent_totals()['concurrent_tokens_live']}")
         kernel, reason = self.param_impl
         if kernel:
             reason = reason.replace("\\", "/").replace('"', "'").replace(
@@ -1562,6 +1623,9 @@ class ServerMetrics:
             self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
         with self._arm_lock:
             self._arms = dict.fromkeys(self._ARM_COUNTERS, 0)
+        with self._concurrent_lock:
+            self._concurrent = dict.fromkeys(self._CONCURRENT_COUNTERS, 0)
+            self._concurrent_live = 0
         with self._verdict_lock:
             self._verdicts.clear()
             self._wait_assigned = 0

@@ -92,6 +92,7 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
             I32,
         ),
         # flow OR param rows of one pull (codec rev 8, BATCH_PARAM_FLOW)
+        # ... or of concurrency frames (rev 9: k_out = -1)
         "sn_fd_wait_any": (
             [
                 P, I32, ctypes.POINTER(I64), ctypes.POINTER(I32),
@@ -110,7 +111,7 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
                 ctypes.POINTER(I32), ctypes.POINTER(I32),
                 ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(I64),
                 ctypes.POINTER(ctypes.c_int8), ctypes.POINTER(I32),
-                ctypes.POINTER(I32),
+                ctypes.POINTER(I32), ctypes.POINTER(I64),
             ],
             None,
         ),
@@ -498,6 +499,8 @@ class Frontdoor:
     """
 
     CTRL_FRAME, CTRL_OPEN, CTRL_CLOSE = 0, 1, 2
+    # ``f_type`` of the frames of a concurrency pull (codec rev 9)
+    TYPE_BATCH_ACQUIRE, TYPE_BATCH_RELEASE = 28, 29
     # rx -> pull about to return; submit entered -> last byte sent; rx ->
     # last byte sent. The order of sn_fd_span_stats.
     SPANS = ("door_in_ms", "door_out_ms", "door_residence_ms")
@@ -661,14 +664,17 @@ class Frontdoor:
     def wait_any_into(self, staging: dict, timeout_ms: int = 100,
                       max_n: Optional[int] = None):
         """:meth:`wait_batch_into` for a host that serves hot-parameter
-        rows too: one pull is either flow rows or the rows of
-        BATCH_PARAM_FLOW frames, never both (the door hands out whichever
+        and concurrency rows too: one pull is flow rows, the rows of
+        BATCH_PARAM_FLOW frames, or the rows of BATCH_CONCURRENT_ACQUIRE /
+        _RELEASE frames, never two kinds (the door hands out whichever
         arrived first). Returns ``None`` on timeout, else ``(n, frames,
-        k)``: ``k`` is 0 for a flow pull, else the values per request of a
+        k)``: ``k`` is 0 for a flow pull, the values per request of a
         param pull, whose hashes then fill ``staging["hashes"][:n * k]``
         request-major (a pull takes frames of one ``k`` and at most as
-        many values as that array holds). The stamps as
-        :meth:`wait_batch_into` leaves them."""
+        many values as that array holds), or -1 for a concurrency pull: a
+        connection-ordered run of frames, of which those with ``f_type``
+        :data:`TYPE_BATCH_RELEASE` carry a token id a row in ``ids``. The
+        stamps as :meth:`wait_batch_into` leaves them."""
         from sentinel_tpu.cluster.protocol import MAX_BATCH_PER_FRAME
 
         cap = int(staging["ids"].shape[0])
@@ -699,12 +705,15 @@ class Frontdoor:
             return None
         return n, n_frames.value, k.value
 
-    def submit(self, frames, status, remaining, wait_ms) -> None:
+    def submit(self, frames, status, remaining, wait_ms,
+               token_ids=None) -> None:
         """Encode + send verdict frames for a ``wait_batch`` result. A
         frames tuple with the sixth column (``f_rx_ns``) hands the frames'
         rx stamps back, and the door counts ``door_out_ms`` and
         ``door_residence_ms`` of each frame whose stamp is not 0 when its
-        reply has gone out; five columns count nothing."""
+        reply has gone out; five columns count nothing. ``token_ids``
+        (int64, a row each) fills the wider reply rows of
+        BATCH_CONCURRENT_ACQUIRE frames; None writes 0 there."""
         import numpy as np
 
         # every array binds to a local: an unnamed ascontiguousarray copy
@@ -726,6 +735,8 @@ class Frontdoor:
         status = np.ascontiguousarray(status, np.int8)
         remaining = np.ascontiguousarray(remaining, np.int32)
         wait_ms = np.ascontiguousarray(wait_ms, np.int32)
+        if token_ids is not None:
+            token_ids = np.ascontiguousarray(token_ids, np.int64)
         self._lib.sn_fd_submit(
             self._h, len(f_fd),
             self._ptr(f_fd, ctypes.c_int32),
@@ -737,9 +748,12 @@ class Frontdoor:
             self._ptr(status, ctypes.c_int8),
             self._ptr(remaining, ctypes.c_int32),
             self._ptr(wait_ms, ctypes.c_int32),
+            None if token_ids is None
+            else self._ptr(token_ids, ctypes.c_int64),
         )
 
-    def submit_many(self, frames_list, status, remaining, wait_ms) -> None:
+    def submit_many(self, frames_list, status, remaining, wait_ms,
+                    token_ids=None) -> None:
         """Answer SEVERAL ``wait_batch`` pulls with one native call.
 
         ``frames_list`` holds each pull's frame-metadata tuple, in the same
@@ -751,12 +765,13 @@ class Frontdoor:
         import numpy as np
 
         if len(frames_list) == 1:
-            return self.submit(frames_list[0], status, remaining, wait_ms)
+            return self.submit(frames_list[0], status, remaining, wait_ms,
+                               token_ids)
         merged = tuple(
             np.concatenate([np.asarray(fr[i]) for fr in frames_list])
             for i in range(min(len(fr) for fr in frames_list))
         )
-        self.submit(merged, status, remaining, wait_ms)
+        self.submit(merged, status, remaining, wait_ms, token_ids)
 
     def send(self, fd: int, gen: int, frame: bytes) -> None:
         self._lib.sn_fd_send(self._h, fd, gen, frame, len(frame))
@@ -1007,9 +1022,11 @@ class ShmDoor:
             b["prios"][:n].astype(bool), frames,
         )
 
-    def submit(self, frames, status, remaining, wait_ms) -> None:
+    def submit(self, frames, status, remaining, wait_ms,
+               token_ids=None) -> None:
         import numpy as np
 
+        # (token_ids: Frontdoor.submit's; the ring carries flow rows only)
         f_fd, f_gen, f_xid, f_n, f_type = frames[:5]  # a 6th: rx stamps
         f_fd = np.ascontiguousarray(f_fd, np.int32)
         f_gen = np.ascontiguousarray(f_gen, np.int32)

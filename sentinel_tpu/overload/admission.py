@@ -165,6 +165,9 @@ class AdmissionController:
         self._admit_frac = 1.0
         self._next_eval = 0.0
         self._over_since: Optional[float] = None
+        # the least inflight since the last evaluation: pressure that
+        # dipped under the headroom between two looks was not sustained
+        self._low = 0
         self._rng = random.Random(seed)
         # rebalance advisories (cluster.rebalance): last advice emitted, a
         # baseline of per-namespace verdict totals to diff rates against,
@@ -190,6 +193,8 @@ class AdmissionController:
             self._inflight -= int(n)
             if self._inflight < 0:  # lost accounting must not wedge shedding
                 self._inflight = 0
+            if self._inflight < self._low:
+                self._low = self._inflight
 
     @property
     def inflight(self) -> int:
@@ -214,6 +219,7 @@ class AdmissionController:
         with self._lock:
             self._next_eval = now + cfg.recheck_ms / 1000.0
             inflight = self._inflight
+            low, self._low = self._low, inflight
         bdp = self.estimated_bdp()
         if inflight > bdp * cfg.headroom_degrade:
             level = BrownoutLevel.DEGRADE
@@ -222,11 +228,17 @@ class AdmissionController:
         else:
             level = BrownoutLevel.NORMAL
         # escalation needs SUSTAINED pressure (a draining burst recovers
-        # before the window elapses); recovery is immediate
+        # before the window elapses); recovery is immediate. Sustained means
+        # inflight never fell back under the shed headroom since pressure
+        # was first seen: evaluations come with dispatches, so a burst that
+        # drained into an idle gap (a warm-up's, before a measured window)
+        # was last looked at while it was high, and the next pull over the
+        # headroom, seconds later, is a fresh spike and not its continuation
         if level is BrownoutLevel.NORMAL:
             self._over_since = None
         else:
-            if self._over_since is None:
+            if (self._over_since is None
+                    or low <= bdp * cfg.headroom_shed):
                 self._over_since = now
             if (now - self._over_since) * 1000.0 < cfg.sustain_ms:
                 level = BrownoutLevel.NORMAL

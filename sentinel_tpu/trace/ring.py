@@ -63,6 +63,8 @@ OUTCOME = 16     # a completion report ingested: its outcome step issued and
 #                  the service lock released (aux = rows accepted; shard = the
 #                  ingest's sequence number, which joins it to OUTCOME_IN)
 PARAM_LANE = 1   # ``shard`` of the DEVICE_IN / DEVICE_OUT of a param dispatch
+# ... and of a concurrency dispatch (acquire and release rows; aux = both)
+CONCURRENT_LANE = 2
 # DEVICE_OUT of a flow dispatch: ``shard >> ARM_SHIFT`` holds the bits of the
 # decide step's cond-gated arms that took their live branch
 # (``engine.decide.ARM_SHAPING | ARM_PACING | ARM_OCCUPY``), ``shard &

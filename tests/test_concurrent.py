@@ -1,138 +1,183 @@
-"""Cluster concurrency (semaphore) mode tests.
+"""Cluster concurrency (semaphore) mode: the semantics, stated once and held
+against both what serves them (``DefaultTokenService``'s plane on the device,
+``engine/concurrent.py``) and their plain reference
+(``cellbench/families/concurrent_reference.py``).
 
 Mirrors the reference's ``ConcurrentClusterFlowCheckerTest`` /
 ``CurrentConcurrencyManagerTest`` / ``TokenCacheNodeManagerTest`` strategy:
 checker semantics with an explicit clock, expiry without real sleeps, and
-(beyond the reference) one wire-level round-trip test.
+(beyond the reference) one wire-level round-trip test. Until PR 41 these
+cases drove ``ConcurrencyManager``, the Python dict the service then served
+from; the three that timed that dict's sweep budget (its amortized sweep per
+acquire, its bounded scan behind a wall of long-lived tokens, its thread's
+lifecycle) went with it: ``tests/test_concurrent_batch.py`` holds their
+successors (the ring's scan, two timeouts in one table, the timer).
 """
+
+import os
+import sys
 
 import pytest
 
-from sentinel_tpu.cluster.concurrent import (
-    ConcurrencyManager,
-    ConcurrentFlowRule,
-    ExpiryTask,
-)
 from sentinel_tpu.cluster.client import TokenClient
+from sentinel_tpu.cluster.concurrent import ConcurrentFlowRule
 from sentinel_tpu.cluster.server import TokenServer
 from sentinel_tpu.cluster.token_service import DefaultTokenService
 from sentinel_tpu.engine import EngineConfig, TokenStatus
 from sentinel_tpu.engine.rules import ThresholdMode
 
-T0 = 1_700_000_000_000
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from cellbench.families import concurrent_reference as R  # noqa: E402
+
+RULES = [
+    ConcurrentFlowRule(flow_id=1, concurrency_level=3),
+    ConcurrentFlowRule(flow_id=2, concurrency_level=2,
+                       mode=ThresholdMode.AVG_LOCAL),
+    ConcurrentFlowRule(flow_id=3, concurrency_level=5,
+                       resource_timeout_ms=100),
+    ConcurrentFlowRule(flow_id=4, concurrency_level=10,
+                       resource_timeout_ms=1000),
+]
 
 
-@pytest.fixture
-def mgr():
-    m = ConcurrencyManager()
-    m.load_rules(
-        [
-            ConcurrentFlowRule(flow_id=1, concurrency_level=3),
-            ConcurrentFlowRule(
-                flow_id=2, concurrency_level=2, mode=ThresholdMode.AVG_LOCAL
-            ),
-            ConcurrentFlowRule(flow_id=3, concurrency_level=5, resource_timeout_ms=100),
-        ]
-    )
-    return m
+class Served:
+    """The service's plane, one row a call, on the test's clock."""
+
+    def __init__(self, clock):
+        self.clock = clock
+        self.svc = DefaultTokenService(
+            EngineConfig(max_flows=8, max_namespaces=2, batch_size=8),
+            serve_buckets=(8,), concurrent_max_tokens=64)
+        self.svc.load_concurrent_rules(RULES)
+        self.svc.close()  # no timer: expiry runs when the test says
+
+    def acquire(self, flow, count=1):
+        r = self.svc.request_concurrent_token(flow, count)
+        return int(r.status), r.remaining, r.token_id
+
+    def release(self, token):
+        return int(self.svc.release_concurrent_token(token).status)
+
+    def expire(self):
+        return self.svc.concurrent_tick()
+
+    def held(self, flow):
+        return self.svc.concurrent_stats()["held"][flow]
+
+    def connected(self, n):
+        self.svc.connected_count_changed("default", n)
+
+
+class Plain:
+    """The plain reference, on the same clock."""
+
+    def __init__(self, clock):
+        self.clock, self.n = clock, 1
+        self.ref = R.Reference({}, {r.flow_id: r.resource_timeout_ms
+                                    for r in RULES})
+        self.connected(1)
+
+    def now(self):
+        return self.clock.now_ms() - 1_700_000_000_000
+
+    def acquire(self, flow, count=1):
+        return self.ref.acquire(self.now(), flow, count)
+
+    def release(self, token):
+        return self.ref.release(token)
+
+    def expire(self):
+        return self.ref.expire(self.now())
+
+    def held(self, flow):
+        return self.ref.held.get(flow, 0)
+
+    def connected(self, n):
+        self.ref.levels = {
+            r.flow_id: r.concurrency_level * (
+                1 if r.mode == ThresholdMode.GLOBAL else n) for r in RULES}
+
+
+OK, BLOCKED, NO_RULE = 0, 1, 3
+RELEASE_OK, ALREADY = 6, 7
+
+
+@pytest.fixture(params=[Served, Plain], ids=["served", "reference"])
+def sem(request, manual_clock):
+    return request.param(manual_clock)
 
 
 class TestAcquireRelease:
-    def test_admit_up_to_level_then_block(self, mgr):
-        results = [mgr.acquire(1, now_ms=T0) for _ in range(4)]
-        assert [r.status for r in results[:3]] == [TokenStatus.OK] * 3
-        assert results[3].status == TokenStatus.BLOCKED
-        assert mgr.now_calls(1) == 3
-        assert results[0].remaining == 2 and results[2].remaining == 0
+    def test_admit_up_to_level_then_block(self, sem):
+        results = [sem.acquire(1) for _ in range(4)]
+        assert [r[0] for r in results] == [OK] * 3 + [BLOCKED]
+        assert sem.held(1) == 3
+        assert results[0][1] == 2 and results[2][1] == 0
+        assert len({r[2] for r in results[:3]}) == 3 and results[3][2] == 0
 
-    def test_release_frees_permit(self, mgr):
-        r1 = mgr.acquire(1, now_ms=T0)
-        assert mgr.release(r1.token_id) == TokenStatus.RELEASE_OK
-        assert mgr.now_calls(1) == 0
-        assert mgr.acquire(1, now_ms=T0).status == TokenStatus.OK
+    def test_release_frees_permit(self, sem):
+        _s, _r, token = sem.acquire(1)
+        assert sem.release(token) == RELEASE_OK
+        assert sem.held(1) == 0
+        assert sem.acquire(1)[0] == OK
 
-    def test_double_release_is_idempotent(self, mgr):
-        r = mgr.acquire(1, now_ms=T0)
-        assert mgr.release(r.token_id) == TokenStatus.RELEASE_OK
-        assert mgr.release(r.token_id) == TokenStatus.ALREADY_RELEASE
-        assert mgr.now_calls(1) == 0  # no double decrement
+    def test_double_release_is_idempotent(self, sem):
+        _s, _r, token = sem.acquire(1)
+        assert sem.release(token) == RELEASE_OK
+        assert sem.release(token) == ALREADY
+        assert sem.held(1) == 0  # no double decrement
 
-    def test_weighted_acquire(self, mgr):
-        assert mgr.acquire(1, acquire=2, now_ms=T0).status == TokenStatus.OK
-        assert mgr.acquire(1, acquire=2, now_ms=T0).status == TokenStatus.BLOCKED
-        assert mgr.acquire(1, acquire=1, now_ms=T0).status == TokenStatus.OK
+    def test_weighted_acquire(self, sem):
+        assert sem.acquire(1, 2)[0] == OK
+        assert sem.acquire(1, 2)[0] == BLOCKED
+        assert sem.acquire(1, 1)[0] == OK
 
-    def test_no_rule(self, mgr):
-        assert mgr.acquire(99, now_ms=T0).status == TokenStatus.NO_RULE_EXISTS
+    def test_no_rule(self, sem):
+        assert sem.acquire(99) == (NO_RULE, 0, 0)
 
-    def test_avg_local_scales_with_connected_count(self, mgr):
+    def test_avg_local_scales_with_connected_count(self, sem):
         # level 2 × 3 clients = 6 permits
-        mgr.set_connected_count(3)
-        results = [mgr.acquire(2, now_ms=T0) for _ in range(7)]
-        assert sum(r.status == TokenStatus.OK for r in results) == 6
-        assert results[6].status == TokenStatus.BLOCKED
+        sem.connected(3)
+        results = [sem.acquire(2) for _ in range(7)]
+        assert sum(r[0] == OK for r in results) == 6
+        assert results[6][0] == BLOCKED
 
 
 class TestExpiry:
-    def test_expired_tokens_reclaimed(self, mgr):
+    def test_expired_tokens_reclaimed(self, sem):
         for _ in range(5):
-            assert mgr.acquire(3, now_ms=T0).status == TokenStatus.OK
-        assert mgr.acquire(3, now_ms=T0).status == TokenStatus.BLOCKED
-        # resource_timeout_ms=100: all expire by T0+101
-        reclaimed = mgr.expire(now_ms=T0 + 101)
-        assert reclaimed == 5
-        assert mgr.now_calls(3) == 0
-        assert mgr.acquire(3, now_ms=T0 + 101).status == TokenStatus.OK
+            assert sem.acquire(3)[0] == OK
+        assert sem.acquire(3)[0] == BLOCKED
+        # resource_timeout_ms=100: not at 99 ms, all by 100
+        sem.clock.advance(99)
+        assert sem.expire() == 0
+        sem.clock.advance(1)
+        assert sem.expire() == 5
+        assert sem.held(3) == 0
+        assert sem.acquire(3)[0] == OK
 
-    def test_release_after_expiry_reports_already_release(self, mgr):
-        r = mgr.acquire(3, now_ms=T0)
-        mgr.expire(now_ms=T0 + 200)
-        assert mgr.release(r.token_id) == TokenStatus.ALREADY_RELEASE
-        assert mgr.now_calls(3) == 0
+    def test_release_after_expiry_reports_already_release(self, sem):
+        _s, _r, token = sem.acquire(3)
+        sem.clock.advance(200)
+        sem.expire()
+        assert sem.release(token) == ALREADY
+        assert sem.held(3) == 0
 
-    def test_acquire_sweeps_amortized(self, mgr):
-        # a crashed client's stale permits are reclaimed by the next acquire
-        for _ in range(5):
-            mgr.acquire(3, now_ms=T0)
-        r = mgr.acquire(3, now_ms=T0 + 150)  # after TTL: sweep frees all 5
-        assert r.status == TokenStatus.OK
-        assert mgr.now_calls(3) == 1
+    def test_a_token_past_its_time_can_be_released_until_it_is_reclaimed(
+            self, sem):
+        _s, _r, token = sem.acquire(3)
+        sem.clock.advance(200)  # expiry has not run yet
+        assert sem.release(token) == RELEASE_OK
+        assert sem.expire() == 0 and sem.held(3) == 0
 
-    def test_mixed_ttls_sweep_all_expired(self):
-        m = ConcurrencyManager()
-        m.load_rules(
-            [
-                ConcurrentFlowRule(1, 10, resource_timeout_ms=1000),
-                ConcurrentFlowRule(2, 10, resource_timeout_ms=50),
-            ]
-        )
-        m.acquire(1, now_ms=T0)  # long TTL issued first
-        m.acquire(2, now_ms=T0)  # short TTL second
-        assert m.expire(now_ms=T0 + 100) == 1  # only flow 2's token expired
-        assert m.now_calls(1) == 1 and m.now_calls(2) == 0
-
-    def test_full_scan_reclaims_behind_long_ttl_wall(self):
-        # expired short-TTL tokens sitting behind >limit live long-TTL tokens
-        # must still be reclaimed by the unbounded background sweep
-        m = ConcurrencyManager()
-        m.load_rules(
-            [
-                ConcurrentFlowRule(1, 500, resource_timeout_ms=3_600_000),
-                ConcurrentFlowRule(2, 5, resource_timeout_ms=100),
-            ]
-        )
-        for _ in range(200):  # long-TTL wall issued first
-            m.acquire(1, now_ms=T0)
-        for _ in range(5):
-            m.acquire(2, now_ms=T0)
-        assert m.expire(now_ms=T0 + 200, limit=64) == 0  # bounded scan misses
-        assert m.expire(now_ms=T0 + 200) == 5  # full scan reclaims
-        assert m.now_calls(2) == 0 and m.now_calls(1) == 200
-
-    def test_expiry_task_lifecycle(self, mgr):
-        task = ExpiryTask(mgr, interval_s=0.01)
-        task.start()
-        task.stop()  # no deadlock / thread leak
+    def test_mixed_ttls_expire_each_at_its_own_time(self, sem):
+        sem.acquire(4)  # long TTL issued first
+        sem.acquire(3)  # short TTL second
+        sem.clock.advance(100)
+        assert sem.expire() == 1  # only flow 3's token expired
+        assert sem.held(4) == 1 and sem.held(3) == 0
+        sem.clock.advance(900)
+        assert sem.expire() == 1 and sem.held(4) == 0
 
 
 class TestWire:
@@ -155,3 +200,4 @@ class TestWire:
         finally:
             client.close()
             server.stop()
+            svc.close()

@@ -658,7 +658,7 @@ class TestDatasourceClusterAssignment:
         service.load_concurrent_rules(
             [ConcurrentFlowRule(flow_id=4, concurrency_level=2)]
         )
-        assert service._expiry is not None
+        assert service._conc_timer.is_alive()
         sock = s.socket()
         sock.bind(("0.0.0.0", 0))
         new_port = sock.getsockname()[1]
@@ -667,5 +667,5 @@ class TestDatasourceClusterAssignment:
         moved = H._EMBEDDED_SERVER["server"]
         assert moved.port == new_port
         assert moved.service is service
-        # stop() closed the expiry sweeper; the restart must re-arm it
-        assert service._expiry is not None
+        # stop() closed the expiry timer; the restart must re-arm it
+        assert service._conc_timer.is_alive()

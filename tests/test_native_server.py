@@ -427,7 +427,7 @@ class TestNativeMixedSoak:
         # the device table stores float32, so compare in float32
         assert np.asarray(svc._table.ns_max_qps).min() == np.float32(1e12)
         # semaphore fully released after the soak
-        assert svc.concurrency.now_calls(9) == 0
+        assert svc.concurrent_stats()["held"].get(9, 0) == 0
         # freelist quiescence: every staging block acquired on the soak's
         # shed/deadline/reply paths came back to the pool. Once the lanes
         # drain, outstanding must equal exactly the one block each intake

@@ -167,6 +167,32 @@ class TestAdmissionController:
         ctl.note_enqueued(100)
         assert ctl.level() == BrownoutLevel.NORMAL
 
+    def test_a_burst_that_drained_between_two_looks_is_not_sustained(self):
+        """Evaluations come with dispatches. A burst seen over the headroom
+        once, drained with nothing looking (the pause between a warm-up and
+        a measured window), and a second spike long after: the second is a
+        fresh spike, not half a second of pressure. (PR 41's cell lost its
+        window's first 7 frames to SHED_LOW this way in 2 of 36 windows: the
+        session's held-up releases make its first pull a large one.)"""
+        cfg = OverloadConfig(
+            headroom_shed=2.0, headroom_degrade=4.0, min_bdp=10.0,
+            recheck_ms=0.0, sustain_ms=40.0,
+        )
+        ctl = AdmissionController(config=cfg, metrics=ServerMetrics())
+        ctl.note_enqueued(100)
+        assert ctl.level() == BrownoutLevel.NORMAL  # seen high once
+        ctl.note_done(100)  # drained; nobody looked
+        time.sleep(0.06)
+        ctl.note_enqueued(30)
+        assert ctl.level() == BrownoutLevel.NORMAL  # a fresh spike
+        # and from here pressure that stays is still shed
+        time.sleep(0.06)
+        assert ctl.level() == BrownoutLevel.SHED_LOW
+        # a dip that no evaluation saw restarts the clock as well
+        ctl.note_done(25)
+        ctl.note_enqueued(25)
+        assert ctl.level() == BrownoutLevel.NORMAL
+
     def test_disabled_never_sheds(self):
         cfg = OverloadConfig(enabled=False, min_bdp=1.0)
         ctl = AdmissionController(config=cfg, metrics=ServerMetrics())

@@ -9,7 +9,7 @@ sharded step exactly like a single-chip one — clients cannot tell.
 This demo exercises the REAL serving path, not a demo fork of it: the
 mesh-backed service runs the same donating sharded step, greedy fusion
 ladder (oversized pulls fold into one ``lax.scan``-of-``shard_map`` device
-dispatch), prep cache, and staging freelists as production serving — the
+dispatch), host prep, and staging freelists as production serving — the
 mesh only changes the step function (``docs/PERF.md`` "Pod serving"). The
 same layout snapshots and delta-replicates to standbys of any mesh shape
 (``docs/CLUSTER_HA.md``).

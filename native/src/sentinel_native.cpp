@@ -20,6 +20,8 @@
 #include <cstdint>
 #include <cstring>
 #include <new>
+#include <utility>
+#include <vector>
 
 #if defined(_WIN32)
 #define SN_EXPORT extern "C" __declspec(dllexport)
@@ -538,4 +540,144 @@ SN_EXPORT int32_t sn_batch_encode_rsp(int32_t xid, int32_t n,
     put32(row + 5, uint32_t(wait_ms[i]));
   }
   return int32_t(payload_len + 2);
+}
+
+// ---------------------------------------------------------------------------
+// Flow prep (cluster/token_service.py, phase `prep`): one frame's flow ids,
+// acquires and priorities into the decide step's packed host argument
+// (engine/decide.py: lines ROW_SLOT / ROW_ACQUIRE / ROW_FLAGS / ROW_HEAD) in
+// ONE call with the GIL released. The contract is byte identity with the
+// numpy path that stays as fallback, re-prep and reference: `_lookup_from`
+// (np.searchsorted into the lookup snapshot's sorted keys), `_prep_batch`
+// (np.argsort(slots, kind="stable"), None where the slots arrive ascending)
+// and `pack_requests` (slot -1 / acquire 0 / flags 0 beyond n). Every array
+// is the caller's; only the sort's scratch lives here, per thread.
+
+namespace {
+
+constexpr int kLanes = 16;     // lookups in flight together: a binary search
+                               // is a chain of dependent loads, sixteen
+                               // chains keep the memory system busy
+constexpr int kDigitBits = 11; // radix digit: 2048 counters a pass
+constexpr int32_t kFlagPrioritized = 1, kFlagValid = 2;  // decide.FLAG_*
+
+// slots[i] = tab[j] where keys[j] == ids[i], else -1: the leftmost j with
+// keys[j] >= ids[i], as np.searchsorted(keys, ids) finds it.
+void lookup_slots(const int64_t *keys, const int32_t *tab, int64_t n_keys,
+                  const int64_t *ids, int64_t n, int32_t *slots) {
+  if (n_keys == 0) {
+    for (int64_t i = 0; i < n; ++i) slots[i] = -1;
+    return;
+  }
+  for (int64_t at = 0; at < n; at += kLanes) {
+    const int lanes = int(n - at < kLanes ? n - at : kLanes);
+    int64_t lo[kLanes];
+    for (int l = 0; l < lanes; ++l) lo[l] = 0;
+    // branchless lower bound, all lanes one halving at a time
+    for (int64_t len = n_keys; len > 1;) {
+      const int64_t half = len >> 1;
+      for (int l = 0; l < lanes; ++l)
+        lo[l] += keys[lo[l] + half - 1] < ids[at + l] ? half : 0;
+      len -= half;
+    }
+    for (int l = 0; l < lanes; ++l) {
+      // lo is the last candidate; one more step where it is still below
+      const int64_t j = lo[l] + (keys[lo[l]] < ids[at + l] ? 1 : 0);
+      slots[at + l] = j < n_keys && keys[j] == ids[at + l] ? tab[j] : -1;
+    }
+  }
+}
+
+struct SortScratch {
+  std::vector<uint32_t> hist;
+  std::vector<int32_t> ping, pong;
+};
+
+}  // namespace
+
+// keys[n_keys] ascending with their slots tab[n_keys]; the frame ids / acq /
+// pr (bool bytes) of n >= 1 rows; packed: line l of the argument starts at
+// packed + l * stride and is `width` >= n wide (stride == width for an array
+// of its own, depth * width inside a fused staging block). Writes slots[n]
+// in request order, the three request lines grouped by ascending slot (-1
+// first, ties in arrival order) and padded, the head line zeroed where
+// zero_head, and order[n] (entry k of the lines is request order[k]) unless
+// the slots arrived ascending. Returns bit 0: ascending (order not written),
+// bit 1: every acquire the same.
+SN_EXPORT int32_t sn_flow_prep(const int64_t *keys, const int32_t *tab,
+                               int64_t n_keys, const int64_t *ids,
+                               const int32_t *acq, const uint8_t *pr,
+                               int64_t n, int64_t width, int64_t stride,
+                               int32_t zero_head, int32_t *slots,
+                               int64_t *order, int32_t *packed) {
+  lookup_slots(keys, tab, n_keys, ids, n, slots);
+  bool ascending = true;
+  int32_t top = -1;
+  int32_t a_lo = acq[0], a_hi = acq[0];
+  for (int64_t i = 0; i < n; ++i) {
+    if (i && slots[i] < slots[i - 1]) ascending = false;
+    if (slots[i] > top) top = slots[i];
+    if (acq[i] < a_lo) a_lo = acq[i];
+    if (acq[i] > a_hi) a_hi = acq[i];
+  }
+  int32_t *p_slot = packed, *p_acq = packed + stride;
+  int32_t *p_flags = packed + 2 * stride;
+  auto emit = [&](int64_t k, int64_t i) {  // request i is entry k of the lines
+    p_slot[k] = slots[i];
+    p_acq[k] = acq[i];
+    p_flags[k] = (pr[i] ? kFlagPrioritized : 0) | kFlagValid;
+  };
+  if (ascending) {
+    for (int64_t i = 0; i < n; ++i) emit(i, i);
+  } else {
+    // stable LSD radix sort of the row numbers on slot + 1 (so that -1, no
+    // rule, sorts first as it does for argsort), in as few digits as the
+    // frame's largest slot needs
+    static thread_local SortScratch scratch;
+    int bits = 1;
+    while (bits < 32 && (uint32_t(top) + 1) >> bits) ++bits;
+    const int passes = (bits + kDigitBits - 1) / kDigitBits;
+    const int digit = (bits + passes - 1) / passes;
+    const uint32_t mask = (uint32_t(1) << digit) - 1;
+    const size_t radix = size_t(1) << digit;
+    scratch.hist.assign(size_t(passes) * radix, 0);
+    uint32_t *hist = scratch.hist.data();
+    for (int64_t i = 0; i < n; ++i) {
+      const uint32_t key = uint32_t(slots[i]) + 1;
+      for (int p = 0; p < passes; ++p)
+        ++hist[p * radix + ((key >> (p * digit)) & mask)];
+    }
+    for (int p = 0; p < passes; ++p) {
+      uint32_t run = 0;
+      for (size_t b = 0; b < radix; ++b) {
+        const uint32_t count = hist[p * radix + b];
+        hist[p * radix + b] = run;
+        run += count;
+      }
+    }
+    scratch.ping.resize(size_t(n));
+    scratch.pong.resize(size_t(n));
+    int32_t *src = scratch.ping.data(), *dst = scratch.pong.data();
+    for (int64_t i = 0; i < n; ++i) src[i] = int32_t(i);
+    for (int p = 0; p < passes; ++p) {
+      uint32_t *at = hist + p * radix;
+      for (int64_t k = 0; k < n; ++k) {
+        const int32_t i = src[k];
+        dst[at[((uint32_t(slots[i]) + 1) >> (p * digit)) & mask]++] = i;
+      }
+      std::swap(src, dst);
+    }
+    for (int64_t k = 0; k < n; ++k) {
+      order[k] = src[k];
+      emit(k, src[k]);
+    }
+  }
+  for (int64_t k = n; k < width; ++k) {
+    p_slot[k] = -1;
+    p_acq[k] = 0;
+    p_flags[k] = 0;
+  }
+  if (zero_head)
+    std::memset(packed + 3 * stride, 0, size_t(width) * sizeof(int32_t));
+  return int32_t(ascending) | int32_t(a_lo == a_hi) << 1;
 }

@@ -18,6 +18,7 @@ import itertools
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -74,77 +75,24 @@ from sentinel_tpu.engine.param import (
 from sentinel_tpu.engine.rules import RuleIndex
 from sentinel_tpu.metrics.server import server_metrics
 from sentinel_tpu.metrics.stat_logger import log_cluster
+from sentinel_tpu.native import lib as _native
 from sentinel_tpu.trace import ring as _TR
 
 _SM = server_metrics()
 # flight-recorder identity of a service (the ``shard`` field of its phase
 # events): dispatch sequence numbers are per service
 _SERVICE_IDS = itertools.count(1)
-
-
-class _PrepCache:
-    """Bounded LRU memo of the host-side batch prep — the ``lookup_slots``
-    resolution plus the grouping argsort and the padded, packed request
-    array (``pack_requests``) — keyed by the exact (flow_ids, acquires,
-    prios) byte content and the lookup snapshot identity. Closed-loop
-    clients (and real sidecar fleets) resend the same hot flow-id vectors
-    frame after frame, so the hit path replaces an O(n log n) sort + four
-    array passes with one memcmp verification.
-
-    A rule reload swaps the lookup snapshot, which changes the key and
-    naturally invalidates every entry (dead entries age out of the LRU).
-    Entries are shared across dispatches and only read; the packed array
-    is kept READ-ONLY, a template: a step's host argument also carries the
-    dispatch's clock, which therefore goes into a copy (an earlier
-    dispatch's step may still be reading its argument when the next clock
-    is written: the CPU backend aliases an aligned numpy argument
-    outright; the TPU's copies during the call, PERF.md section 6).
-    """
-
-    def __init__(self, capacity: int = 64):
-        from collections import OrderedDict
-
-        self.capacity = int(capacity)
-        self._map: "OrderedDict" = OrderedDict()
-        self._lock = threading.Lock()
-        self.hits = 0
-        self.misses = 0
-
-    def get(self, snap_keys, cap: int, flow_ids, acq, pr):
-        key = (
-            id(snap_keys), cap, hash(flow_ids.tobytes()),
-            hash(acq.tobytes()), hash(pr.tobytes()),
-        )
-        with self._lock:
-            hit = self._map.get(key)
-            if hit is not None:
-                self._map.move_to_end(key)
-        if hit is not None:
-            c_ids, c_acq, c_pr, slots, order, packed = hit
-            # content verification: `hash` collisions must never hand a
-            # different request vector someone else's slot assignment
-            if (
-                np.array_equal(c_ids, flow_ids)
-                and np.array_equal(c_acq, acq)
-                and np.array_equal(c_pr, pr)
-            ):
-                self.hits += 1
-                return key, (slots, order, packed)
-        self.misses += 1
-        return key, None
-
-    def put(self, key, flow_ids, acq, pr, slots, order, packed) -> None:
-        # copies: callers may hand views into reused front-door buffers
-        packed.flags.writeable = False
-        entry = (
-            np.array(flow_ids), np.array(acq), np.array(pr),
-            slots, order, packed,
-        )
-        with self._lock:
-            self._map[key] = entry
-            self._map.move_to_end(key)
-            while len(self._map) > self.capacity:
-                self._map.popitem(last=False)
+# The host's turn (DefaultTokenService._host_turn). numpy gives the GIL up
+# inside any loop over more elements than _TURN_ROWS
+# (NPY_BEGIN_THREADS_THRESHOLDED), so the account half of a larger frame hands
+# the GIL over at each of its fifty numpy calls and a launch beside it takes
+# it each time: both then cost two to three times their CPU (PERF.md section
+# 6, PR 43). A smaller frame's account half holds the GIL from end to end,
+# has nothing to hand over and would only lose by waiting. The wait is
+# bounded: the turn spaces two halves of host work, it orders nothing, and a
+# holder that the machine stopped must not stop the dispatch lane.
+_TURN_ROWS = 500
+_TURN_WAIT_S = 0.002
 
 
 @dataclass(frozen=True)
@@ -518,8 +466,11 @@ class DefaultTokenService(TokenService):
         # materialization (the device has definitely consumed the host
         # buffer by then).
         self._fused_staging: Dict[int, object] = {}
-        self._prep_cache = _PrepCache()
         self._lock = threading.Lock()
+        # taken in turn by a flow dispatch's launch and by the account half
+        # of a flow dispatch, BEFORE the service lock and never needed to
+        # make progress (_host_turn)
+        self._turn = threading.Lock()
         # outer mutex for rule read-modify-write sequences: a namespace
         # replacement (merge current rules + load) must be atomic against a
         # concurrent replacement of ANOTHER namespace, or the later load
@@ -703,6 +654,25 @@ class DefaultTokenService(TokenService):
         # under self._lock (see _emit_push).
         self._push_hubs: List[object] = []
 
+    @contextmanager
+    def _host_turn(self, rows: int):
+        """A flow dispatch's launch (service lock, jitted call, the start of
+        the verdicts' copy) and the account half of one do not run beside
+        each other where ``rows`` is more than ``_TURN_ROWS``: whoever comes
+        second sleeps on ``self._turn``, wanting no GIL, for at most
+        ``_TURN_WAIT_S``, then goes ahead regardless. Until PR 43 the numpy
+        prep's 1.5 ms outside the GIL kept the two apart by accident; the
+        native pass took that away and with it a closed loop's rate
+        (``mesh-100k.sidecar-sat``: launch 0.92 -> 2.2 ms, account 1.5 ->
+        4.0). On the dispatch side the wait lies inside ``lock_wait_ms``, on
+        the other inside ``account_ms``."""
+        got = rows > _TURN_ROWS and self._turn.acquire(timeout=_TURN_WAIT_S)
+        try:
+            yield
+        finally:
+            if got:
+                self._turn.release()
+
     @staticmethod
     def _prep_batch(cfg, slots, acq, pr):
         """Build the device batch; returns ``(order, packed)``: the step's
@@ -710,8 +680,10 @@ class DefaultTokenService(TokenService):
         grouping order, None when slots arrived ascending-SORTED (stable
         argsort would be the identity) — skipping an O(n log n) sort and
         three fancy-index passes each way. Grouped-but-unsorted input
-        still sorts. Shared by the hot prep and the rare rules-reloaded
-        re-prep so the two can't diverge."""
+        still sorts. With :meth:`_lookup_from` the numpy form of the hot
+        prep: what runs where the native library is not built, the rare
+        re-preps under the lock (rules reloaded, MOVING rows), and the
+        reference ``native.lib.flow_prep`` is held to, byte for byte."""
         sorted_already = bool((slots[:-1] <= slots[1:]).all())
         if sorted_already:
             return None, pack_requests(cfg, slots, acq, pr)
@@ -817,23 +789,6 @@ class DefaultTokenService(TokenService):
                 ),
             )
         return pool
-
-    def _prep_cached(self, lookup_snap, cfg, bucket, flow_ids, acq, pr):
-        """Host prep with the hot-vector memo: ``(slots, order, packed)``
-        for one engine frame, served from :class:`_PrepCache` when the same
-        (flow_ids, acquires, prios) vector was prepped against the same
-        lookup snapshot before. ``packed`` is the cache's read-only
-        template: a dispatch copies it (into its own array or a staging
-        row) before the clock goes in."""
-        key, hit = self._prep_cache.get(
-            lookup_snap[0], bucket, flow_ids, acq, pr
-        )
-        if hit is not None:
-            return hit
-        slots = self._lookup_from(lookup_snap, flow_ids)
-        order, packed = self._prep_batch(cfg, slots, acq, pr)
-        self._prep_cache.put(key, flow_ids, acq, pr, slots, order, packed)
-        return slots, order, packed
 
     # -- rule management (ClusterFlowRuleManager analog) --------------------
     def load_rules(
@@ -1298,61 +1253,75 @@ class DefaultTokenService(TokenService):
         # so greedy admission order within a flow is arrival order) and
         # detect the uniform-acquire common case — together they skip the
         # device argsort and the iterative admission refinement (see
-        # decide()'s grouped/uniform flags)
-        uniform = bool(acq.min() == acq.max())
+        # decide()'s grouped/uniform flags). ``packed`` is allocated for
+        # this dispatch and written by nobody once the clock is in: the
+        # step may still be reading it when the next frame is prepped (the
+        # CPU backend aliases an aligned numpy argument outright, on a mesh
+        # too; the TPU's runtime has copied it when the call returns, one
+        # chip and a 2x2 mesh alike: benchmarks/arg_overwrite_drill.py,
+        # PERF.md section 6, PR 43). No pool and no per-thread output array
+        # may ever stand here
         # smallest compiled shape bucket that fits this batch
         bucket = next(b for b in self._serve_buckets if n <= b)
         cfg = self.config._replace(batch_size=bucket)
-        slots, order, template = self._prep_cached(
-            lookup_snap, cfg, bucket, flow_ids, acq, pr
-        )
-        packed = template.copy()  # this dispatch's own: it takes the clock
+        prep = _native.flow_prep(lookup_snap, flow_ids, acq, pr, bucket)
+        if prep is None:  # the library is not built: the same in numpy
+            uniform = bool(acq.min() == acq.max())
+            slots = self._lookup_from(lookup_snap, flow_ids)
+            order, packed = self._prep_batch(cfg, slots, acq, pr)
+        else:
+            slots, order, packed, uniform = prep
         step = self._step_fn(bucket, uniform)
         slots_ns = slots  # pre-mask slots: verdict→namespace attribution
         moved_mask = moved_epochs = None
         t_prep = time.monotonic_ns()
-        # -- device step: the only serialized section --
-        with self._lock:
-            t_locked = time.monotonic_ns()
-            seq = self._dispatch_seq = self._dispatch_seq + 1
-            if self._lookup is not lookup_snap:
-                # rules reloaded between prep and step: slot assignments may
-                # have moved, so redo the slot-dependent prep against the
-                # live table (rare, and still under the lock — the same
-                # atomicity load_rules callers had before the narrowing)
-                slots = self._lookup_from(self._lookup, flow_ids)
-                slots_ns = slots
-                order, packed = self._prep_batch(cfg, slots, acq, pr)
-            mv = self._moving_snap
-            if mv is not None:
-                # live rebalance: rows of a MOVING namespace are masked out
-                # of the device batch — their counters never move (the
-                # zero-over-admission half of the lossless move) — and the
-                # materializer overlays MOVED. Checked under the lock so a
-                # begin_move strictly orders against every dispatch.
-                moved_mask, moved_epochs = self._moving_mask_for(slots, mv)
-                if moved_mask is not None:
-                    slots = np.where(
-                        moved_mask, np.int32(-1), slots
-                    ).astype(np.int32)
+        # -- device step: the only serialized section (the host's turn
+        # first: no large account half counts beside the launch) --
+        with self._host_turn(n):
+            with self._lock:
+                t_locked = time.monotonic_ns()
+                seq = self._dispatch_seq = self._dispatch_seq + 1
+                if self._lookup is not lookup_snap:
+                    # rules reloaded between prep and step: slot assignments
+                    # may have moved, so redo the slot-dependent prep against
+                    # the live table (rare, and still under the lock — the
+                    # same atomicity load_rules callers had before the
+                    # narrowing)
+                    slots = self._lookup_from(self._lookup, flow_ids)
+                    slots_ns = slots
                     order, packed = self._prep_batch(cfg, slots, acq, pr)
-            # the clock rides the one host argument; read under the lock,
-            # written into an array only this dispatch holds
-            packed[ROW_HEAD, HEAD_NOW] = self._engine_now()
-            self._state, verdicts = step(self._state, self._table, packed)
-            if self._dirty is not None:
-                touched = np.unique(slots[slots >= 0]).tolist()
-                self._dirty["flow"].update(touched)
-                if self._has_breakers:
-                    # breaker transitions only happen for batched rows, so
-                    # touched ∩ breaker-slots is exactly the dirty set
-                    self._dirty.setdefault("breaker", set()).update(
-                        s for s in touched if s in self._breaker_slots
-                    )
-        # the verdicts' one copy to the host starts now, behind the step on
-        # the device's queue, not when a reply lane gets round to asking
-        verdicts.copy_to_host_async()
-        self._dispatched(t_enter, t_prep, t_locked, seq, n)
+                mv = self._moving_snap
+                if mv is not None:
+                    # live rebalance: rows of a MOVING namespace are masked
+                    # out of the device batch — their counters never move
+                    # (the zero-over-admission half of the lossless move) —
+                    # and the materializer overlays MOVED. Checked under the
+                    # lock so a begin_move strictly orders against every
+                    # dispatch.
+                    moved_mask, moved_epochs = self._moving_mask_for(slots, mv)
+                    if moved_mask is not None:
+                        slots = np.where(
+                            moved_mask, np.int32(-1), slots
+                        ).astype(np.int32)
+                        order, packed = self._prep_batch(cfg, slots, acq, pr)
+                # the clock rides the one host argument; read under the lock,
+                # written into an array only this dispatch holds
+                packed[ROW_HEAD, HEAD_NOW] = self._engine_now()
+                self._state, verdicts = step(self._state, self._table, packed)
+                if self._dirty is not None:
+                    touched = np.unique(slots[slots >= 0]).tolist()
+                    self._dirty["flow"].update(touched)
+                    if self._has_breakers:
+                        # breaker transitions only happen for batched rows, so
+                        # touched ∩ breaker-slots is exactly the dirty set
+                        self._dirty.setdefault("breaker", set()).update(
+                            s for s in touched if s in self._breaker_slots
+                        )
+            # the verdicts' one copy to the host starts now, behind the step on
+            # the device's queue, not when a reply lane gets round to asking
+            verdicts.copy_to_host_async()
+        self._dispatched(t_enter, t_prep, t_locked, seq, n,
+                         native_prep=prep is not None)
 
         def _read():
             # blocks on the async dispatch; runs outside the lock
@@ -1390,13 +1359,16 @@ class DefaultTokenService(TokenService):
         return t_ready, host.reshape(3, -1)
 
     def _dispatched(self, t_enter, t_prep, t_locked, seq, rows,
-                    lane: int = 0) -> None:
+                    lane: int = 0, native_prep: bool = False) -> None:
         """One dispatch left the service lock: its three dispatch-side
         phases (``monotonic_ns`` stamps of entry, prep done, lock acquired)
         go to the always-on histograms and, armed, to the flight recorder
-        with the same stamps."""
+        with the same stamps. ``native_prep``: a flow dispatch whose prep
+        was the native pass (``prep_native_total``)."""
         t_out = time.monotonic_ns()
         _SM.prep_ms.record((t_prep - t_enter) * 1e-6)
+        if native_prep:
+            _SM.count_prep_native()
         _SM.lock_wait_ms.record((t_locked - t_prep) * 1e-6)
         _SM.launch_ms.record((t_out - t_locked) * 1e-6)
         if _TR.ARMED:
@@ -1423,58 +1395,64 @@ class DefaultTokenService(TokenService):
         and the read half's end: the decision latency the SLO plane gets
         ends there, and ``account_ms`` counts from this call's own start.
         ``arms`` is what a flow dispatch's step said of its cond-gated arms
-        (``unpack_arms``)."""
+        (``unpack_arms``). A flow dispatch's counting waits for the host's
+        turn (:meth:`_host_turn`), inside ``account_ms``."""
         t_account = time.monotonic_ns()
-        if isinstance(slots_ns, list):
-            slots_ns = np.concatenate(slots_ns)
-        # per-namespace verdict counters (sentinel_server_verdicts_total):
-        # attribute each request's verdict to its rule's namespace via the
-        # lock-free slot→namespace snapshot
-        if slots_ns is None:  # a param dispatch: no flow slots
-            ns_idx, ns_names = None, ()
-        else:
-            ns_names, slot_ns = self._ns_snapshot
-            ns_idx = np.where(
-                slots_ns >= 0, slot_ns[np.maximum(slots_ns, 0)], np.int32(-1)
+        # a flow dispatch's counting takes the host's turn: it never runs
+        # beside a launch (the breaker scan below takes the service lock and
+        # stays outside it)
+        with self._host_turn(rows if slots_ns is not None else 0):
+            if isinstance(slots_ns, list):
+                slots_ns = np.concatenate(slots_ns)
+            # per-namespace verdict counters (sentinel_server_verdicts_total):
+            # attribute each request's verdict to its rule's namespace via the
+            # lock-free slot→namespace snapshot
+            if slots_ns is None:  # a param dispatch: no flow slots
+                ns_idx, ns_names = None, ()
+            else:
+                ns_names, slot_ns = self._ns_snapshot
+                ns_idx = np.where(
+                    slots_ns >= 0, slot_ns[np.maximum(slots_ns, 0)],
+                    np.int32(-1),
+                )
+            _SM.record_verdict_batch(
+                status, ns_idx, ns_names,
+                latency_ms=(t_fetched - t_enter) * 1e-6,
+                wait_ms=wait,
             )
-        _SM.record_verdict_batch(
-            status, ns_idx, ns_names,
-            latency_ms=(t_fetched - t_enter) * 1e-6,
-            wait_ms=wait,
-        )
-        live = 0
-        if arms is not None:
-            live = int(arms[ARM_LIVE])
-            _SM.count_decide_arms(
-                rows, live & ARM_SHAPING, live & ARM_PACING,
-                live & ARM_OCCUPY, int(arms[ARM_SHAPED_ROWS]),
-                int(arms[ARM_PACED_ROWS]), int(arms[ARM_PRIORITIZED_ROWS]),
-                breaker=(live & ARM_BREAKER, int(arms[ARM_GUARDED_ROWS]),
-                         int(arms[ARM_DEGRADED_ROWS]), int(arms[ARM_PROBES]),
-                         int(arms[ARM_TO_OPEN])),
-            )
-        if _TR.ARMED:  # flight recorder: verdicts on the host and counted
-            sid, aux = self._trace_sid, seq & 0x7FFFFFFF
-            _TR.record(_TR.READY, shard=sid, aux=aux, t_ns=t_ready)
-            _TR.record(_TR.FETCHED, shard=sid, aux=aux, t_ns=t_fetched)
-            _TR.record(_TR.ACCOUNT, shard=sid, aux=aux, t_ns=t_account)
-            _TR.record(_TR.DEVICE_OUT, aux=rows,
-                       shard=lane | live << _TR.ARM_SHIFT)
-        # cluster server stat log (ClusterServerStatLogUtil analog): one
-        # aggregated counter per verdict class per window
-        n_degraded = 0
-        for event, code in (
-            ("pass", int(TokenStatus.OK)),
-            ("block", int(TokenStatus.BLOCKED)),
-            ("occupied", int(TokenStatus.SHOULD_WAIT)),
-            ("tooManyRequest", int(TokenStatus.TOO_MANY_REQUEST)),
-            ("degraded", int(TokenStatus.DEGRADED)),
-        ):
-            hits = int((status == code).sum())
-            if hits:
-                log_cluster(event, count=hits)
-                if event == "degraded":
-                    n_degraded = hits
+            live = 0
+            if arms is not None:
+                live = int(arms[ARM_LIVE])
+                _SM.count_decide_arms(
+                    rows, live & ARM_SHAPING, live & ARM_PACING,
+                    live & ARM_OCCUPY, int(arms[ARM_SHAPED_ROWS]),
+                    int(arms[ARM_PACED_ROWS]), int(arms[ARM_PRIORITIZED_ROWS]),
+                    breaker=(live & ARM_BREAKER, int(arms[ARM_GUARDED_ROWS]),
+                             int(arms[ARM_DEGRADED_ROWS]),
+                             int(arms[ARM_PROBES]), int(arms[ARM_TO_OPEN])),
+                )
+            if _TR.ARMED:  # flight recorder: verdicts on the host and counted
+                sid, aux = self._trace_sid, seq & 0x7FFFFFFF
+                _TR.record(_TR.READY, shard=sid, aux=aux, t_ns=t_ready)
+                _TR.record(_TR.FETCHED, shard=sid, aux=aux, t_ns=t_fetched)
+                _TR.record(_TR.ACCOUNT, shard=sid, aux=aux, t_ns=t_account)
+                _TR.record(_TR.DEVICE_OUT, aux=rows,
+                           shard=lane | live << _TR.ARM_SHIFT)
+            # cluster server stat log (ClusterServerStatLogUtil analog): one
+            # aggregated counter per verdict class per window
+            n_degraded = 0
+            for event, code in (
+                ("pass", int(TokenStatus.OK)),
+                ("block", int(TokenStatus.BLOCKED)),
+                ("occupied", int(TokenStatus.SHOULD_WAIT)),
+                ("tooManyRequest", int(TokenStatus.TOO_MANY_REQUEST)),
+                ("degraded", int(TokenStatus.DEGRADED)),
+            ):
+                hits = int((status == code).sum())
+                if hits:
+                    log_cluster(event, count=hits)
+                    if event == "degraded":
+                        n_degraded = hits
         if n_degraded:
             # breaker activity observed: fold the device transitions
             # into the host transition counters / blackbox plane
@@ -1538,10 +1516,10 @@ class DefaultTokenService(TokenService):
         see :func:`decide_fused_donating`). Returns a materializer yielding
         request-order ``(status, remaining, wait)`` for the whole span.
 
-        Each frame is prepped independently (slot lookup + grouping sort,
-        through the prep cache) and its packed request lines laid into row
-        ``f`` of ONE ``[lines, depth, cap]`` staging block, the step's one
-        host argument; the single device call then replaces ``depth``
+        Each frame is prepped independently (slot lookup + grouping sort)
+        and its packed request lines written straight into row ``f`` of ONE
+        ``[lines, depth, cap]`` staging block, the step's one host
+        argument; the single device call then replaces ``depth``
         dispatches. The fused group shares one ``now`` — frames in one
         pull arrived together, so this only collapses sub-millisecond
         clock skew a per-frame loop would have read anyway.
@@ -1552,25 +1530,14 @@ class DefaultTokenService(TokenService):
         # frames; mixed spans scan the general (refining) body for every
         # frame, which is still correct for the uniform ones among them
         uniform = bool(acq.min() == acq.max())
-        cfg = self.config  # fused frames are exactly batch_size-shaped
         pool = self._fused_block_pool(depth)
         block = pool.acquire()
         frames = [slice(f * cap, (f + 1) * cap) for f in range(depth)]
-        preps = []
-        for f, sl in enumerate(frames):
-            p = self._prep_cached(
-                lookup_snap, cfg, cap, flow_ids[sl], acq[sl], pr[sl]
-            )
-            # the zero-alloc replacement for a per-dispatch np.stack; the
-            # head line stays the block's own
-            block[:ROW_HEAD, f] = p[2][:ROW_HEAD]
-            preps.append(p)
 
         def _restage(f, slots_f):
-            # the rare re-preps under the lock: bypass the cache (its
-            # entries are keyed by snapshot identity, so stale hits are
-            # impossible, but re-prepping directly keeps the rare path
-            # simple) and write straight into the staging rows
+            # the numpy prep, straight into the staging rows: the rare
+            # re-preps under the lock, and every frame where the native
+            # library is not built
             sl = frames[f]
             if bool((slots_f[:-1] <= slots_f[1:]).all()):
                 order_f = None
@@ -1581,56 +1548,73 @@ class DefaultTokenService(TokenService):
                     block, f, slots_f[order_f], acq[sl][order_f],
                     pr[sl][order_f],
                 )
-            return slots_f, order_f, None
+            return slots_f, order_f
 
+        # (slots, order) a frame; the zero-alloc replacement for a
+        # per-dispatch np.stack: the head line stays the block's own
+        preps, native_prep = [], True
+        for f, sl in enumerate(frames):
+            prep = _native.flow_prep(
+                lookup_snap, flow_ids[sl], acq[sl], pr[sl], cap,
+                out=block[:, f],
+            )
+            if prep is None:  # the library is not built: the same in numpy
+                native_prep = False
+                prep = _restage(
+                    f, self._lookup_from(lookup_snap, flow_ids[sl])
+                )
+            preps.append(prep[:2])
         step = self._fused_step_fn(depth, uniform)
         moved_span = moved_epochs_span = span_ns = None
         t_prep = time.monotonic_ns()
-        # -- device step: the only serialized section --
-        with self._lock:
-            t_locked = time.monotonic_ns()
-            seq = self._dispatch_seq = self._dispatch_seq + 1
-            if self._lookup is not lookup_snap:
-                # rules reloaded between prep and step (see
-                # dispatch_batch_arrays): redo slot-dependent prep against
-                # the live table
-                preps = [
-                    _restage(
-                        f, self._lookup_from(self._lookup, flow_ids[sl])
-                    )
-                    for f, sl in enumerate(frames)
-                ]
-            mv = self._moving_snap
-            if mv is not None:
-                # live rebalance (see dispatch_batch_arrays): mask MOVING-
-                # namespace rows out of every staged frame so the fused
-                # step never counts their tokens, and remember the span
-                # mask for the MOVED overlay
-                span0 = np.concatenate([p[0] for p in preps])
-                m, eps = self._moving_mask_for(span0, mv)
-                if m is not None:
-                    moved_span, moved_epochs_span, span_ns = m, eps, span0
+        # -- device step: the only serialized section (the host's turn
+        # first, see dispatch_batch_arrays) --
+        with self._host_turn(depth * cap):
+            with self._lock:
+                t_locked = time.monotonic_ns()
+                seq = self._dispatch_seq = self._dispatch_seq + 1
+                if self._lookup is not lookup_snap:
+                    # rules reloaded between prep and step (see
+                    # dispatch_batch_arrays): redo slot-dependent prep against
+                    # the live table
                     preps = [
                         _restage(
-                            f,
-                            np.where(
-                                m[sl], np.int32(-1), span0[sl]
-                            ).astype(np.int32),
+                            f, self._lookup_from(self._lookup, flow_ids[sl])
                         )
                         for f, sl in enumerate(frames)
                     ]
-            block[ROW_HEAD, 0, HEAD_NOW] = self._engine_now()
-            self._state, verdicts = step(self._state, self._table, block)
-            if self._dirty is not None:
-                span = np.concatenate([p[0] for p in preps])
-                touched = np.unique(span[span >= 0]).tolist()
-                self._dirty["flow"].update(touched)
-                if self._has_breakers:
-                    self._dirty.setdefault("breaker", set()).update(
-                        s for s in touched if s in self._breaker_slots
-                    )
-        verdicts.copy_to_host_async()  # see dispatch_batch_arrays
-        self._dispatched(t_enter, t_prep, t_locked, seq, depth * cap)
+                mv = self._moving_snap
+                if mv is not None:
+                    # live rebalance (see dispatch_batch_arrays): mask MOVING-
+                    # namespace rows out of every staged frame so the fused
+                    # step never counts their tokens, and remember the span
+                    # mask for the MOVED overlay
+                    span0 = np.concatenate([p[0] for p in preps])
+                    m, eps = self._moving_mask_for(span0, mv)
+                    if m is not None:
+                        moved_span, moved_epochs_span, span_ns = m, eps, span0
+                        preps = [
+                            _restage(
+                                f,
+                                np.where(
+                                    m[sl], np.int32(-1), span0[sl]
+                                ).astype(np.int32),
+                            )
+                            for f, sl in enumerate(frames)
+                        ]
+                block[ROW_HEAD, 0, HEAD_NOW] = self._engine_now()
+                self._state, verdicts = step(self._state, self._table, block)
+                if self._dirty is not None:
+                    span = np.concatenate([p[0] for p in preps])
+                    touched = np.unique(span[span >= 0]).tolist()
+                    self._dirty["flow"].update(touched)
+                    if self._has_breakers:
+                        self._dirty.setdefault("breaker", set()).update(
+                            s for s in touched if s in self._breaker_slots
+                        )
+            verdicts.copy_to_host_async()  # see dispatch_batch_arrays
+        self._dispatched(t_enter, t_prep, t_locked, seq, depth * cap,
+                         native_prep=native_prep)
         _SM.record_fused(depth)
         if _TR.ARMED:  # flight recorder: fused group submitted
             _TR.record(_TR.FUSE, aux=depth)
@@ -1651,7 +1635,7 @@ class DefaultTokenService(TokenService):
                 span_order = np.concatenate([
                     np.arange(f * cap, (f + 1) * cap) if order_f is None
                     else order_f + f * cap
-                    for f, (_s, order_f, _b) in enumerate(preps)
+                    for f, (_s, order_f) in enumerate(preps)
                 ])
             status, wait, remaining = unpack_verdicts(
                 host, order=span_order

@@ -334,8 +334,12 @@ def alloc_packed_block(config: EngineConfig, depth: int) -> np.ndarray:
     HEAD_NOW]``. Freelist-recycled by the fused dispatcher
     (`cluster.protocol.StagingPool`) once the group's verdicts are on the
     host, never sooner: the CPU backend aliases an aligned numpy argument
-    outright, so a block in flight must not be written (the TPU's copies
-    during the call; PERF.md section 6)."""
+    outright, on one device and replicated over a mesh alike, so a block in
+    flight must not be written. The TPU's runtime has taken its copy when
+    the call returns, on one chip (0 of 320 calls decided on bytes
+    overwritten right after it) and with the argument replicated over a 2x2
+    mesh (0 of 128): ``benchmarks/arg_overwrite_drill.py``, PR 43; PERF.md
+    section 6."""
     return np.zeros((PACKED_LINES, depth, config.batch_size), np.int32)
 
 

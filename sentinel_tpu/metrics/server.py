@@ -342,6 +342,8 @@ class ServerMetrics:
         self._verdict_copy_ready = 0
         # dispatches whose account half ran after their reply was submitted
         self._reply_first = 0
+        # flow dispatches whose host prep was the native pass
+        self._prep_native = 0
         self._verdict_read_lock = threading.Lock()
         # traffic-shaping waits: every SHOULD_WAIT verdict that carried a
         # positive wait hint (paced admission or priority occupy) — count
@@ -460,6 +462,19 @@ class ServerMetrics:
     def reply_first_total(self) -> int:
         with self._verdict_read_lock:
             return self._reply_first
+
+    def count_prep_native(self) -> None:
+        """One flow dispatch was prepped by the native pass
+        (``sn_flow_prep``). A dispatch prepped in numpy, because the library
+        is not built, does not count: over ``prep_ms``'s count this says
+        whether the mechanism engaged."""
+        with self._verdict_read_lock:
+            self._prep_native += 1
+
+    @property
+    def prep_native_total(self) -> int:
+        with self._verdict_read_lock:
+            return self._prep_native
 
     def count_param_dispatch(self, requests: int, values: int, blocked: int,
                              no_rule: int) -> None:
@@ -1072,6 +1087,7 @@ class ServerMetrics:
             "verdictHostReadsTotal": self.verdict_host_reads_total,
             "verdictCopyReadyTotal": self.verdict_copy_ready_total,
             "replyFirstTotal": self.reply_first_total,
+            "prepNativeTotal": self.prep_native_total,
             "shedTotal": self.shed_total,
             "shedByReason": self.shed_totals(),
             "hostCopyBytesTotal": self.host_copy_bytes_total,
@@ -1155,6 +1171,7 @@ class ServerMetrics:
         out.update(self.arm_totals())
         out.update(self.concurrent_totals())
         out["reply_first_total"] = self.reply_first_total
+        out["prep_native_total"] = self.prep_native_total
         out["param_impl"], out["param_impl_reason"] = self.param_impl
         out["shed_total"] = self.shed_totals()
         out["host_copy_bytes_total"] = self.host_copy_bytes_total
@@ -1558,6 +1575,11 @@ class ServerMetrics:
              "Dispatches accounted after their reply was submitted: the "
              "native reply lane answers first and counts after "
              "(cumulative).", self.reply_first_total),
+            ("prep_native_total",
+             "Flow dispatches whose host prep was the native pass; beside "
+             "prep_ms's count a shortfall says the library is not built "
+             "and numpy prepped them (cumulative).",
+             self.prep_native_total),
         ):
             lines.append(f"# HELP sentinel_server_{name} {help_text}")
             lines.append(f"# TYPE sentinel_server_{name} counter")
@@ -1619,6 +1641,7 @@ class ServerMetrics:
             self._verdict_host_reads = 0
             self._verdict_copy_ready = 0
             self._reply_first = 0
+            self._prep_native = 0
         with self._param_lock:
             self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
         with self._arm_lock:

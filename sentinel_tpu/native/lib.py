@@ -202,6 +202,17 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         lib._sn_has_shm = True
     except AttributeError:
         lib._sn_has_shm = False
+    # flow prep (sentinel_native.cpp), resolved defensively for the same
+    # reason. Every pointer goes in as an integer (``arr.ctypes.data``): a
+    # ``data_as`` object costs microseconds each, ten times a dispatch
+    try:
+        lib.sn_flow_prep.argtypes = [
+            P, P, I64, P, P, P, I64, I64, I64, I32, P, P, P,
+        ]
+        lib.sn_flow_prep.restype = I32
+        lib._sn_has_flow_prep = True
+    except AttributeError:
+        lib._sn_has_flow_prep = False
     return lib
 
 
@@ -287,6 +298,43 @@ def shm_available() -> bool:
     rebuild with ``python -m sentinel_tpu.native.build``)."""
     lib = load()
     return lib is not None and bool(getattr(lib, "_sn_has_shm", False))
+
+
+def flow_prep(snapshot, flow_ids, acq, pr, width: int, out=None):
+    """One flow frame's host prep in ONE native pass with the GIL released
+    (``sn_flow_prep``): slot lookup in ``snapshot`` (the token service's
+    ``(sorted int64 keys, int32 slots)``), the stable ascending grouping and
+    the padded request lines of the decide step's packed argument. Returns
+    ``(slots, order, packed, uniform)``: ``slots`` in request order, ``order``
+    None where they arrived ascending, ``uniform`` whether every acquire is
+    the same; value for value and byte for byte what
+    ``DefaultTokenService._lookup_from`` + ``_prep_batch`` give
+    (``tests/test_native_prep.py``). ``packed`` is a fresh
+    ``int32[4, width]`` (``engine.decide.pack_requests``'s form, clock 0)
+    that only the caller holds, or ``out``, a frame's ``[4, width]`` view of
+    a fused staging block, whose head line is then left alone. None where
+    the library is absent or older than this entry: the caller preps in
+    numpy."""
+    lib = load()
+    if lib is None or not lib._sn_has_flow_prep:
+        return None
+    import numpy as np
+
+    keys, tab = snapshot
+    flow_ids = np.ascontiguousarray(flow_ids, np.int64)
+    acq = np.ascontiguousarray(acq, np.int32)
+    pr = np.ascontiguousarray(pr, np.bool_)
+    n = flow_ids.shape[0]
+    packed = np.empty((4, width), np.int32) if out is None else out
+    slots = np.empty(n, np.int32)
+    order = np.empty(n, np.int64)
+    flags = lib.sn_flow_prep(
+        keys.ctypes.data, tab.ctypes.data, keys.shape[0],
+        flow_ids.ctypes.data, acq.ctypes.data, pr.ctypes.data, n, width,
+        packed.strides[0] // 4, out is None, slots.ctypes.data,
+        order.ctypes.data, packed.ctypes.data,
+    )
+    return slots, None if flags & 1 else order, packed, bool(flags & 2)
 
 
 def batch_decode_req(payload: bytes):

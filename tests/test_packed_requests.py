@@ -7,7 +7,7 @@ the state, the rule table and exactly ONE further argument; (c) the packed
 serve steps against the library entry (``decide`` with a ``RequestBatch``)
 on ``tests/decide_golden.py``'s streams, verdicts and final state leaf by
 leaf, single shard and over the CPU mesh; (d) a dispatch's clock is its
-own: a prep-cache hit, the rules-reloaded re-prep.
+own: the same frame again, the rules-reloaded re-prep.
 """
 
 import os
@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sentinel_tpu.cluster.token_service import DefaultTokenService, _PrepCache
+from sentinel_tpu.cluster import token_service
+from sentinel_tpu.cluster.token_service import DefaultTokenService
 from sentinel_tpu.engine import (
     ClusterFlowRule,
     EngineConfig,
@@ -265,35 +266,19 @@ def _service(count=5.0, **kw):
     return svc
 
 
-def test_a_prep_cache_entry_cannot_take_a_clock():
-    cache = _PrepCache()
-    ids, acq, pr = np.arange(8), np.ones(8, np.int32), np.zeros(8, bool)
-    packed = pack_requests(CFG, np.arange(8, dtype=np.int32))
-    key, hit = cache.get((), 64, ids, acq, pr)
-    assert hit is None
-    cache.put(key, ids, acq, pr, "slots", None, packed)
-    _key, (_s, _o, template) = cache.get((), 64, ids, acq, pr)
-    for shared in (packed, template):  # the packer's array and every hit
-        with pytest.raises(ValueError):
-            shared[ROW_HEAD, HEAD_NOW] = 111
-    own = template.copy()
-    own[ROW_HEAD, HEAD_NOW] = 222  # what a dispatch does
-    assert template[ROW_HEAD, HEAD_NOW] == 0
-
-
 @pytest.mark.parametrize("frames", (1, 2), ids=("frame", "fused"))
 def test_byte_identical_frames_are_each_decided_at_their_own_clock(
         manual_clock, frames):
-    """A prep-cache hit must not carry one dispatch's clock into another:
-    five tokens a second, the same frame dispatched once per window from
+    """One dispatch's clock must not reach another that holds the same
+    bytes: five tokens a second, the same frame dispatched once per window from
     two threads and read back later, as the lanes do. Every dispatch sees
     a fresh window and passes five; one decided at a neighbour's clock
     would find that window already spent."""
     svc = _service(count=5.0, fuse_depths=(2,))
     ids = np.full(frames * CFG.batch_size, 1, np.int64)
-    svc.request_batch_arrays(ids)  # compile, and fill the prep cache
+    svc.request_batch_arrays(ids)  # compile
     manual_clock.sleep(2000)
-    rounds, hits0 = 8, svc._prep_cache.hits
+    rounds = 8
     calls = []  # (the step's host argument, the clock in it at the call)
 
     def spy(build):
@@ -337,7 +322,6 @@ def test_byte_identical_frames_are_each_decided_at_their_own_clock(
         t.join(60)
     assert not errors, errors
     assert not any(t.is_alive() for t in lanes)
-    assert svc._prep_cache.hits >= hits0 + rounds * frames
     # whatever the backend does with a numpy argument after the call
     # returns (the CPU's aliases an aligned one), no dispatch wrote into an
     # array another was given: each went in with its own clock, and a
@@ -358,7 +342,7 @@ def test_byte_identical_frames_are_each_decided_at_their_own_clock(
 
 @pytest.mark.parametrize("frames", (1, 2), ids=("frame", "fused"))
 def test_the_rules_reloaded_re_prep_answers_with_the_packed_form(
-        manual_clock, frames):
+        manual_clock, monkeypatch, frames):
     """Rules reloaded between prep and the lock: the dispatch re-preps
     against the live table under the lock and is decided at its clock."""
     n = frames * CFG.batch_size
@@ -368,18 +352,18 @@ def test_the_rules_reloaded_re_prep_answers_with_the_packed_form(
     want_svc.load_rules([ClusterFlowRule(flow_id=i, count=9.0, mode=G)
                          for i in range(3, 12)])
     svc = _service(count=7.0, fuse_depths=(2,))
-    prep = svc._prep_cached
+    prep, reloaded = token_service._native.flow_prep, []
 
-    def prep_then_reload(*args):
-        out = prep(*args)
-        if svc._prep_cached is not prep:  # once, after the first frame
-            svc._prep_cached = prep
+    def prep_then_reload(*args, **kw):
+        out = prep(*args, **kw)  # None where the library is not built
+        if not reloaded:  # once, after the first frame's native pass
+            reloaded.append(True)
             svc.load_rules([ClusterFlowRule(flow_id=i, count=9.0, mode=G)
                             for i in range(3, 12)])
         return out
 
     manual_clock.sleep(5000)
-    svc._prep_cached = prep_then_reload
+    monkeypatch.setattr(token_service._native, "flow_prep", prep_then_reload)
     got = svc.request_batch_arrays(ids, acq)
     want = want_svc.request_batch_arrays(ids, acq)
     for g, w, name in zip(got, want, ("status", "remaining", "wait")):

@@ -442,3 +442,115 @@ class TestMeshBackedService:
         assert svc.request_token(2).status == TokenStatus.OK
         assert svc.request_token(1).status == TokenStatus.OK
         svc.close()
+
+
+class TestServedPathUnderTheMesh:
+    """The served path (``DefaultTokenService(mesh=...)``: host prep, one
+    replicated host argument, sharded step, psum stitch) against a
+    single-device service from the same rules on the same clock, on the
+    traffic the mesh cell sends: unsorted Zipf frames of about 1,000 rows,
+    mixed acquires, two frames in flight as the lanes keep them. PR 42's
+    native prep passed every CPU test and over-admitted on four chips; what a
+    CPU can hold the path to is held here."""
+
+    SCFG = EngineConfig(max_flows=2048, max_namespaces=4, batch_size=1024)
+    # Zipf rank -> flow id (100 of them have no rule); the four hottest
+    # flows are metered, flow -> count
+    HOT = np.random.default_rng(43).permutation(1600)
+    METERED = dict(zip(HOT[:4].tolist(), (400.0, 200.0, 120.0, 60.0)))
+
+    def _service(self, mesh):
+        from sentinel_tpu.cluster.token_service import DefaultTokenService
+
+        svc = DefaultTokenService(self.SCFG, mesh=mesh)
+        svc.load_rules(
+            [ClusterFlowRule(flow_id=f, count=self.METERED.get(f, 1e9),
+                             mode=G) for f in range(1500)],
+            ns_max_qps=1e12,
+        )
+        return svc
+
+    @staticmethod
+    def _spy(svc, calls):
+        """Every step's host argument, with the bytes it went in with."""
+        build = svc._step_fn
+
+        def built(*key):
+            step = build(*key)
+
+            def call(state, rules, packed):
+                calls.append((packed, packed.tobytes()))
+                return step(state, rules, packed)
+
+            return call
+
+        svc._step_fn = built
+
+    @pytest.mark.parametrize("overwrite", (False, True),
+                             ids=("plain", "arguments_overwritten"))
+    def test_unsorted_zipf_frames_answer_as_one_device_does(
+            self, mesh, manual_clock, overwrite):
+        """Verdicts equal row for row and no metered flow over its count in
+        a window. ``arguments_overwritten`` is Tentpole 1's drill as a test:
+        the caller's arrays are overwritten the moment a dispatch returns
+        (a door recycles its decode block), so nothing a dispatch keeps may
+        alias them; and in both cases each step's host argument is its
+        dispatch's own, still holding the bytes it was handed over with
+        when every verdict is read (the CPU backend aliases an aligned
+        argument, on the mesh too: a write after the clock would be read)."""
+        rng, hot = np.random.default_rng(44), self.HOT
+        services = {"mesh": self._service(mesh), "one": self._service(None)}
+        calls = {name: [] for name in services}
+        for name, svc in services.items():
+            svc.warmup()
+            self._spy(svc, calls[name])
+        frames, pending, got = 24, [], {name: [] for name in services}
+        sent = []
+
+        def read(mats):
+            for name, mat in mats.items():
+                got[name].append(mat())
+
+        for _k in range(frames):
+            n = int(rng.integers(960, 1025))
+            ids = hot[np.minimum(rng.zipf(1.1, n) - 1, 1599)].astype(np.int64)
+            acq = rng.integers(1, 4, n).astype(np.int32)
+            pr = np.zeros(n, bool)
+            assert (np.diff(ids) < 0).any()  # unsorted
+            sent.append((ids.copy(), acq.copy()))
+            mats = {}
+            for name, svc in services.items():
+                args = (ids.copy(), acq.copy(), pr.copy())
+                mats[name] = svc.dispatch_batch_arrays(*args)
+                if overwrite:
+                    args[0][:] = 3
+                    args[1][:] = 7
+                    args[2][:] = True
+            pending.append(mats)
+            if len(pending) == 2:  # two in flight, the older read first
+                read(pending.pop(0))
+            manual_clock.sleep(100)
+        while pending:
+            read(pending.pop(0))
+        for k, (a, b) in enumerate(zip(got["mesh"], got["one"])):
+            for x, y, what in zip(a, b, ("status", "remaining", "wait")):
+                np.testing.assert_array_equal(
+                    x, y, err_msg=f"{what} of frame {k}")
+        # tokens a metered flow was let have, frame by frame; any 9 frames
+        # in a row lie inside one 1 s window of ten 100 ms buckets
+        ok = int(TokenStatus.OK)
+        for flow, count in self.METERED.items():
+            per_frame = np.array([
+                int(acq[(ids == flow) & (st[0] == ok)].sum())
+                for (ids, acq), st in zip(sent, got["mesh"])])
+            assert per_frame.sum() > count  # demand was there to refuse
+            spans = np.convolve(per_frame, np.ones(9, np.int64))
+            assert spans.max() <= count, (flow, spans.max(), count)
+        for name, seen in calls.items():
+            assert len(seen) == frames
+            for i, (packed, handed_over) in enumerate(seen):
+                assert packed.tobytes() == handed_over, (name, i)
+                assert not any(np.shares_memory(packed, other)
+                               for other, _b in seen[:i]), (name, i)
+        for svc in services.values():
+            svc.close()

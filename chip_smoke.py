@@ -294,6 +294,75 @@ def check_guard(send, traffic, lane) -> None:
            int((status == TOO_MANY).sum()), n - NS_MAX_QPS)
 
 
+def check_single_param_frames(label, server, lane, n_values: int = 50,
+                              per_value: int = 100) -> None:
+    """The lane of single PARAM_FLOW frames (type 2, what the reference's
+    own client sends: one request a frame) beside the batched one: 5,000
+    frames pipelined on one raw connection, ``n_values`` values of the
+    lane's rule asked ``per_value`` times each in turn. Of every value the
+    first 5 in frame order pass and the rest are BLOCKED, while the frames
+    fall inside one window; every frame is answered once, under type 2, by
+    its own xid; the native door counts them all on its data plane and its
+    control loop sees none."""
+    import socket
+
+    from sentinel_tpu.cluster import protocol as P
+
+    n = n_values * per_value
+    for attempt in range(3):
+        base = 500_000 + attempt * n_values  # fresh values every attempt
+        values = base + np.arange(n) % n_values
+        blob = b"".join(
+            P.encode_request(P.FlowRequest(
+                1 + i, lane.param, 1, False, P.MsgType.PARAM_FLOW,
+                (int(v),)))
+            for i, v in enumerate(values))
+        door0, ctl0 = server.stats(), service_metrics().param_single_totals()
+        reader, frames = P.FrameReader(), []
+        with socket.create_connection(("127.0.0.1", server.port),
+                                      timeout=60) as sock:
+            t0 = time.perf_counter()
+            sock.sendall(blob)
+            while len(frames) < n:
+                data = sock.recv(1 << 18)
+                if not data:
+                    raise RuntimeError(f"{label}: connection closed after "
+                                       f"{len(frames)} of {n} replies")
+                frames += reader.feed(data)
+            took = time.perf_counter() - t0
+        if took < 0.45:  # two buckets of 500 ms: one window holds it all
+            break
+        say(f"  {n} single PARAM_FLOW frames took {took * 1e3:.0f} ms, "
+            f"past one bucket of the window: asked again on fresh values")
+    else:
+        raise RuntimeError(f"{label}: {n} single frames never fit a window")
+    replies = [P.decode_response(f) for f in frames]
+    expect("single PARAM_FLOW frames: reply types",
+           {int(r.msg_type) for r in replies}, {int(P.MsgType.PARAM_FLOW)})
+    expect("single PARAM_FLOW frames: every xid once",
+           sorted(r.xid for r in replies), list(range(1, n + 1)))
+    status = np.zeros(n, np.int8)
+    for r in replies:
+        status[r.xid - 1] = r.status
+    want = np.where(np.arange(n) // n_values < 5, OK, BLOCKED)
+    early = int(((status == BLOCKED) & (want == OK)).sum())
+    expect("single PARAM_FLOW frames: differences other than an early "
+           "refusal", int((status != want).sum()) - early, 0)
+    if early > 20:  # the sketch may refuse early, within its stated share
+        raise AssertionError(f"{early} of {n} rows refused early (limit 20)")
+    door1, ctl1 = server.stats(), service_metrics().param_single_totals()
+    expect("single PARAM_FLOW frames the door took on its data plane",
+           door1["param_single_frames_in"] - door0["param_single_frames_in"],
+           n)
+    expect("single PARAM_FLOW frames that reached the control loop",
+           ctl1["param_control_frames_total"]
+           - ctl0["param_control_frames_total"], 0)
+    pulls = ctl1["param_single_pulls_total"] - ctl0["param_single_pulls_total"]
+    say(f"  {n} single PARAM_FLOW frames through {label}: "
+        f"{took * 1e3:.1f} ms, {early} refused early, "
+        f"{n / max(pulls, 1):.0f} frames a pull")
+
+
 def check_door(label, server, service, traffic, lane) -> None:
     from sentinel_tpu.cluster.client import TokenClient
     from sentinel_tpu.engine import TokenStatus
@@ -341,6 +410,9 @@ def check_door(label, server, service, traffic, lane) -> None:
         expect("param rule, another value",
                client.request_params_token(lane.param, 1, [77]).status,
                TokenStatus.OK)
+
+        if hasattr(server, "stats"):  # the native door's data plane
+            check_single_param_frames(label, server, lane)
 
         # breaker: CLOSED passes; 8 reported exceptions (> 5, with at
         # least 5 completions) open it at the next request, which is shed

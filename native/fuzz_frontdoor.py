@@ -18,7 +18,11 @@ corpus strategy:
   exactly its rows: one byte more or less closes the connection; an
   acquire frame is answered up to ``MAX_ACQUIRE_ROWS`` rows, the most whose
   17-byte reply rows fit a frame, and closes the connection past it:
-  ``acquire_bound_case``);
+  ``acquire_bound_case``), and the reference client's single frames that
+  are data plane since PR 45: PARAM_FLOW (type 2: FLOW's body, ``n:u8``,
+  then ``n`` hashes, so a flipped ``n`` declares values the body lacks and
+  closes the connection) and CONCURRENT_ACQUIRE / _RELEASE (types 3 and 4,
+  FLOW's body);
 - TRUNCATED valid frames followed by socket close mid-frame;
 - oversize declared n vs actual payload;
 - valid frames delivered 1–3 bytes at a time interleaved with garbage
@@ -78,9 +82,25 @@ def _valid_release_frame(xid: int, n: int) -> bytes:
     return struct.pack(">H", len(payload)) + payload
 
 
+def _valid_single_frame(xid: int, mtype: int, k: int = 0) -> bytes:
+    """One single frame of FLOW's body under ``mtype`` (1, 3 or 4), or a
+    PARAM_FLOW frame (2) with ``k`` value hashes behind ``n:u8``."""
+    payload = struct.pack(">iB", xid, mtype) + struct.pack(
+        ">qiB", random.randrange(0, 64), 1, 0)
+    if mtype == 2:
+        payload += struct.pack(f">B{k}q", k, *(random.randrange(1 << 40)
+                                               for _ in range(k)))
+    return struct.pack(">H", len(payload)) + payload
+
+
 def _valid_frame(xid: int, n: int, rng: random.Random) -> bytes:
-    """A valid batch frame of any data-plane kind."""
-    kind = rng.randrange(4)
+    """A valid frame of any data-plane kind: a batch frame of ``n`` rows or
+    a single frame."""
+    kind = rng.randrange(6)
+    if kind == 4:
+        return _valid_single_frame(xid, 2, rng.randrange(0, 6))
+    if kind == 5:
+        return _valid_single_frame(xid, rng.choice((1, 3, 4)))
     if kind == 0:
         return _valid_param_frame(xid, n, rng.randrange(1, 5))
     if kind == 1:
@@ -144,7 +164,8 @@ def _mutate(frame: bytes, rng: random.Random) -> bytes:
 def _oracle_roundtrip(port: int, timeout: float = 5.0) -> bool:
     """One valid BATCH_FLOW, BATCH_PARAM_FLOW, BATCH_CONCURRENT_ACQUIRE and
     BATCH_CONCURRENT_RELEASE round trip on a fresh connection: four verdict
-    rows each, under the request's type and in its row size."""
+    rows each, under the request's type and in its row size; then one of
+    each single frame (PARAM_FLOW with values and with none)."""
     with socket.create_connection(("127.0.0.1", port), timeout=timeout) as s:
         s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         s.settimeout(timeout)
@@ -165,6 +186,20 @@ def _oracle_roundtrip(port: int, timeout: float = 5.0) -> bool:
             flen = struct.unpack(">H", buf[:2])[0]
             got = struct.unpack(">iBH", buf[2:9])
             if got != (xid, mtype, 4) or flen != 7 + 4 * row:
+                return False
+        # the single frames: one verdict each, FLOW's reply under the
+        # request's type, an acquire's with its token id behind it
+        for xid, mtype, k, size in ((11, 1, 0, 14), (12, 2, 3, 14),
+                                    (13, 2, 0, 14), (14, 3, 0, 22),
+                                    (15, 4, 0, 14)):
+            s.sendall(_valid_single_frame(xid, mtype, k))
+            buf = b""
+            while len(buf) < 2 + size:
+                chunk = s.recv(4096)
+                if not chunk:
+                    return False
+                buf += chunk
+            if struct.unpack(">HiB", buf[:7]) != (size, xid, mtype):
                 return False
         return True
     return False
@@ -203,7 +238,10 @@ def _fuzz_one_conn(port: int, rng: random.Random) -> None:
                 f = (_valid_batch_frame(3, 8) + _valid_flow_frame(4)
                      + _valid_param_frame(5, 8, 3)
                      + _valid_release_frame(6, 5)
-                     + _valid_acquire_frame(7, 8))
+                     + _valid_acquire_frame(7, 8)
+                     + _valid_single_frame(8, 2, 2)
+                     + _valid_single_frame(9, 3)
+                     + _valid_single_frame(10, 4))
                 i = 0
                 while i < len(f):
                     step = rng.randrange(1, 4)
@@ -324,6 +362,13 @@ def run_fuzz_raw(iters: int = 300, seed: int = 0,
             item = door.next_control()
             if item is None:
                 time.sleep(0.002)
+                continue
+            kind, fd, gen, payload = item
+            # a PARAM_FLOW frame with no value is the control plane's: OK
+            if (kind == door.CTRL_FRAME and len(payload) >= 5
+                    and payload[4] == 2):
+                body = payload[:5] + struct.pack(">bii", 0, 0, 0)
+                door.send(fd, gen, struct.pack(">H", len(body)) + body)
 
     threads = [threading.Thread(target=dispatch, daemon=True),
                threading.Thread(target=control, daemon=True)]

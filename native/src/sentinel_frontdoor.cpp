@@ -1,9 +1,11 @@
 // Native TCP front door for the token server: the netty-pipeline analog
 // (``NettyTransportServer.java:73-101``: LengthFieldBasedFrameDecoder →
 // request decoder → handler → writeAndFlush) re-expressed as an epoll loop
-// that decodes BATCH_FLOW/FLOW frames STRAIGHT into a shared request arena
-// and encodes verdict frames back without Python touching a single byte of
-// the data plane. Python's role shrinks to one call per *device step*:
+// that decodes every request frame that asks for a verdict (the reference
+// client's four single types and this wire's batch frames) STRAIGHT into a
+// shared request arena and encodes verdict frames back without Python
+// touching a single byte of the data plane. Python's role shrinks to one
+// call per *device step*:
 // ``wait_batch`` (blocks, GIL released) → run the jitted decision kernel →
 // ``submit`` (verdict arrays in, frames out).
 //
@@ -31,9 +33,24 @@
 //     wider, and its u16 length would wrap). Replies: an acquire's rows are FLOW's with
 //     token_id:i64 behind them (sn_fd_submit's token_ids), a release's one
 //     status byte a row.
-// Control plane (forwarded to Python, rare): PING, PARAM_FLOW,
-//   CONCURRENT_ACQUIRE/RELEASE, plus open/close connection events so the
-//   host keeps its ConnectionManager (namespace groups, idle sweep) exact.
+//   PARAM_FLOW (type 2, the reference client's requestParamToken frame):
+//     FLOW's body, n_params:u8, then n_params×hash:i64 → the param arena as
+//     a one-row frame with k = n_params, stamped like any data frame. The
+//     arena's rule stands (a pull is a run of frames with one k), so single
+//     and batch frames of one k share a pull; each is answered in its own
+//     layout: a single frame by FLOW's reply under type 2. A body shorter
+//     than its values closes the connection as a runt frame does; a frame
+//     with no value at all is not a row of the sketch and goes to the
+//     control plane, which answers it as it always has.
+//   CONCURRENT_ACQUIRE / CONCURRENT_RELEASE (types 3 and 4, the reference
+//     client's): FLOW's fixed body (a release's flow_id slot carries its
+//     token id) → the concurrency arena as a one-row frame, in arrival
+//     order with the batch frames. Replies: FLOW's under the frame's own
+//     type, an acquire's with token_id:i64 behind it.
+// Control plane (forwarded to Python, rare): PING, a PARAM_FLOW frame with
+//   no value, every other type (replication, moves, leases, shares, outcome
+//   reports), plus open/close connection events so the host keeps its
+//   ConnectionManager (namespace groups, idle sweep) exact.
 //
 // Threading: one IO thread owns epoll, all sockets, and all writes. Python
 // threads call wait_batch/submit/control APIs guarded by a mutex + eventfd
@@ -87,6 +104,9 @@ constexpr int kHead = 5;           // xid:i32 + type:u8
 constexpr int kReqRow = 13;        // flow_id:i64 + count:i32 + prio:u8
 constexpr int kRspRow = 9;         // status:i8 + remaining:i32 + wait:i32
 constexpr uint8_t kTypeFlow = 1;
+constexpr uint8_t kTypeParamFlow = 2;
+constexpr uint8_t kTypeConcAcquire = 3;
+constexpr uint8_t kTypeConcRelease = 4;
 constexpr uint8_t kTypeBatchFlow = 5;
 constexpr uint8_t kTypeBatchParam = 27;
 constexpr uint8_t kTypeBatchAcquire = 28;
@@ -128,6 +148,12 @@ inline void put32(uint8_t *p, uint32_t v) {
   p[2] = uint8_t(v >> 8);
   p[3] = uint8_t(v);
 }
+// the reference client's one-request frames: one row, answered by a frame
+// of FLOW's reply layout under the request's own type
+inline bool is_single(uint8_t type) {
+  return type == kTypeFlow || type == kTypeParamFlow ||
+         type == kTypeConcAcquire || type == kTypeConcRelease;
+}
 
 // one outbound buffer: the wire bytes and, behind them in the same
 // allocation, the rx_ns stamp of every frame it answers (verdict buffers of
@@ -159,8 +185,10 @@ struct FrameMeta {
   uint32_t gen;
   int32_t xid;
   int32_t n;       // requests in this frame
-  uint8_t type;    // kTypeFlow | kTypeBatchFlow | kTypeBatchParam |
-                   // kTypeBatchAcquire | kTypeBatchRelease
+  uint8_t type;    // the wire's: a single type (kTypeFlow, kTypeParamFlow,
+                   // kTypeConcAcquire, kTypeConcRelease: n = 1) or a batch
+                   // type (kTypeBatchFlow, kTypeBatchParam,
+                   // kTypeBatchAcquire, kTypeBatchRelease)
   uint8_t k;       // values per request (param frames; 0 otherwise)
   uint64_t seq;    // arrival order over the arenas
   int64_t rx_ns;   // mono_ns() after the recv() that read its last byte
@@ -247,6 +275,9 @@ struct Frontdoor {
   // stats (relaxed)
   std::atomic<uint64_t> frames_in{0}, requests_in{0}, bytes_in{0},
       bytes_out{0};
+  // single PARAM_FLOW frames decoded into the param arena
+  // (sn_fd_param_single_frames)
+  std::atomic<uint64_t> param_single_in{0};
 
   // idle reaping (ScanIdleConnectionTask analog), 0 = disabled
   std::atomic<int64_t> idle_ttl_ms{0};
@@ -360,9 +391,20 @@ bool parse_frames(Frontdoor *s, Conn &c) {
       if (avail < 2 + flen) break;
       const uint8_t *payload = p + 2;
       uint8_t type = payload[4];
-      if (type == kTypeBatchFlow || type == kTypeFlow ||
-          type == kTypeBatchParam || type == kTypeBatchAcquire ||
-          type == kTypeBatchRelease) {
+      // the values of a single PARAM_FLOW frame (FLOW's body, n_params:u8,
+      // then the hashes); a body shorter than they are closes the conn
+      int32_t single_k = 0;
+      if (type == kTypeParamFlow) {
+        if (flen < size_t(kHead + kReqRow + 1)) return false;
+        single_k = payload[kHead + kReqRow];
+        if (flen < size_t(kHead + kReqRow + 1) + 8 * size_t(single_k))
+          return false;
+      }
+      // data plane: every type that asks for verdicts, but a PARAM_FLOW
+      // frame with no value (no row of the sketch: the control plane's)
+      if (type == kTypeBatchFlow || type == kTypeBatchParam ||
+          type == kTypeBatchAcquire || type == kTypeBatchRelease ||
+          (is_single(type) && (type != kTypeParamFlow || single_k > 0))) {
         int32_t n;
         int32_t k = 0;  // values per request: param frames only
         const uint8_t *rows;
@@ -389,8 +431,11 @@ bool parse_frames(Frontdoor *s, Conn &c) {
           if (flen < size_t(kHead + 2) + size_t(n) * kReqRow) return false;
           rows = payload + kHead + 2;
         } else {
+          // a single frame: FLOW's body (a CONCURRENT_RELEASE's flow_id
+          // slot carries its token id), a PARAM_FLOW's values behind it
           if (flen < size_t(kHead + kReqRow)) return false;
           n = 1;
+          k = single_k;
           rows = payload + kHead;
         }
         int32_t xid = be32(payload);
@@ -413,10 +458,10 @@ bool parse_frames(Frontdoor *s, Conn &c) {
           wake_self = true;
           continue;
         }
-        Arena &a = type == kTypeBatchParam ? s->param
-                   : (type == kTypeBatchAcquire || type == kTypeBatchRelease)
-                       ? s->conc
-                       : s->flow;
+        const bool conc = type == kTypeBatchAcquire ||
+                          type == kTypeBatchRelease ||
+                          type == kTypeConcAcquire || type == kTypeConcRelease;
+        Arena &a = k ? s->param : conc ? s->conc : s->flow;
         if (a.n_requests + size_t(n) > s->cap ||
             a.n_hashes + size_t(n) * size_t(k) > s->hash_cap) {
           // arena full: park this conn; bytes stay buffered
@@ -439,6 +484,7 @@ bool parse_frames(Frontdoor *s, Conn &c) {
             a.counts[base + i] = be32(rows + 8);
             a.prios[base + i] = rows[12];
             rows += kReqRow;
+            if (type == kTypeParamFlow) ++rows;  // n_params
             for (int32_t j = 0; j < k; ++j, rows += 8) *hv++ = be64(rows);
           }
         }
@@ -448,6 +494,8 @@ bool parse_frames(Frontdoor *s, Conn &c) {
                             s->next_seq++, c.last_rx_ns});
         s->frames_in.fetch_add(1, std::memory_order_relaxed);
         s->requests_in.fetch_add(uint64_t(n), std::memory_order_relaxed);
+        if (type == kTypeParamFlow)
+          s->param_single_in.fetch_add(1, std::memory_order_relaxed);
         notify = true;
       } else {
         // control plane: hand the raw payload to Python. Bounded: a peer
@@ -874,7 +922,7 @@ void pulled(Frontdoor *s, const int64_t *f_rx_ns, int32_t n_frames,
 
 }  // namespace
 
-// Block until data-plane requests are queued (or timeout/stop). Copies up
+// Block until flow requests are queued (or timeout/stop). Copies up
 // to max_n FLOW / BATCH_FLOW requests + their frame list into the caller's
 // arrays and resets the arena. Returns the request count (0 on
 // timeout/stop); *n_frames_out receives the frame count. Whole frames only —
@@ -915,9 +963,11 @@ SN_EXPORT int32_t sn_fd_wait_batch(void *h, int32_t timeout_ms, int64_t *ids,
 // sn_fd_wait_batch for a host that serves every kind of rows: one pull is
 // flow rows (*k_out = 0), param rows (*k_out = values per request, their
 // hashes in ``hashes`` as [n, k]) or the rows of concurrency frames (*k_out
-// = -1: f_type says which frames are releases, whose rows carry a token id
-// in ``ids``), never two kinds: the arena whose head frame arrived first is
-// served. max_hashes bounds the values of one pull.
+// = -1: f_type says which frames are releases, types 4 and 29, whose rows
+// carry a token id in ``ids``), never two kinds: the arena whose head frame
+// arrived first is served. A pull holds single and batch frames alike, and
+// f_type tells sn_fd_submit each one's reply layout. max_hashes bounds the
+// values of one pull.
 SN_EXPORT int32_t sn_fd_wait_any(void *h, int32_t timeout_ms, int64_t *ids,
                                  int32_t *counts, uint8_t *prios,
                                  int64_t *hashes, int32_t max_n,
@@ -981,6 +1031,11 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
            : type == kTypeBatchRelease ? size_t(1)
                                        : size_t(kRspRow);
   };
+  // bytes of a single frame's reply body: FLOW's, and behind it the token
+  // id of a CONCURRENT_ACQUIRE
+  auto single_rsp = [](uint8_t type) -> size_t {
+    return size_t(kRspRow) + (type == kTypeConcAcquire ? 8 : 0);
+  };
   const int64_t submit_ns = f_rx_ns ? mono_ns() : 0;
   std::vector<std::pair<std::pair<int32_t, uint32_t>, OutBuf>> staged;
   size_t off = 0;
@@ -992,9 +1047,9 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
       ++run_end;
     size_t total = 0;
     for (int32_t k = i; k < run_end; ++k)
-      total += (f_type[k] != kTypeFlow)
+      total += !is_single(f_type[k])
                    ? 2 + size_t(kHead) + 2 + size_t(f_n[k]) * rsp_row(f_type[k])
-                   : 2 + size_t(kHead) + kRspRow;
+                   : 2 + size_t(kHead) + single_rsp(f_type[k]);
     OutBuf buf;
     buf.wire = total;
     buf.submit_ns = submit_ns;
@@ -1004,7 +1059,7 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
     uint8_t *p = reinterpret_cast<uint8_t *>(&buf.data[0]);
     for (int32_t k = i; k < run_end; ++k) {
       int32_t n = f_n[k];
-      if (f_type[k] != kTypeFlow) {  // the rows of a batch frame
+      if (!is_single(f_type[k])) {  // the rows of a batch frame
         const size_t rsz = rsp_row(f_type[k]);
         size_t payload = size_t(kHead) + 2 + size_t(n) * rsz;
         put16(p, uint16_t(payload));
@@ -1024,14 +1079,19 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
           }
         }
         p += 2 + payload;
-      } else {  // single FLOW response
-        size_t payload = size_t(kHead) + kRspRow;
+      } else {  // a single frame's response, under the request's type
+        size_t payload = size_t(kHead) + single_rsp(f_type[k]);
         put16(p, uint16_t(payload));
         put32(p + 2, uint32_t(f_xid[k]));
-        p[6] = kTypeFlow;
+        p[6] = f_type[k];
         p[7] = uint8_t(status[off]);
         put32(p + 8, uint32_t(remaining[off]));
         put32(p + 12, uint32_t(wait_ms[off]));
+        if (f_type[k] == kTypeConcAcquire) {
+          uint64_t id = token_ids ? uint64_t(token_ids[off]) : 0;
+          put32(p + 16, uint32_t(id >> 32));
+          put32(p + 20, uint32_t(id));
+        }
         p += 2 + payload;
       }
       off += size_t(n);
@@ -1047,8 +1107,8 @@ SN_EXPORT void sn_fd_submit(void *h, int32_t n_frames, const int32_t *f_fd,
   wake(s);
 }
 
-// Enqueue an arbitrary pre-encoded frame (control-plane responses: PING
-// replies, param/concurrent verdicts — Python encodes those).
+// Enqueue an arbitrary pre-encoded frame (control-plane responses: PING,
+// lease, share and replication replies, pushes — Python encodes those).
 SN_EXPORT void sn_fd_send(void *h, int32_t fd, int32_t gen,
                           const uint8_t *data, int32_t len) {
   auto *s = static_cast<Frontdoor *>(h);
@@ -1120,6 +1180,14 @@ SN_EXPORT void sn_fd_stats(void *h, uint64_t *out4) {
   out4[1] = s->requests_in.load(std::memory_order_relaxed);
   out4[2] = s->bytes_in.load(std::memory_order_relaxed);
   out4[3] = s->bytes_out.load(std::memory_order_relaxed);
+}
+
+// Single PARAM_FLOW frames decoded into the param arena (relaxed, like
+// sn_fd_stats). What the control loop still answers of them it counts
+// itself (ServerMetrics.param_control_frames_total).
+SN_EXPORT uint64_t sn_fd_param_single_frames(void *h) {
+  return static_cast<Frontdoor *>(h)->param_single_in.load(
+      std::memory_order_relaxed);
 }
 
 // The bucket bounds of span histogram ``which`` (0 door_in, 1 door_out,

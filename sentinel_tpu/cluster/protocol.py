@@ -9,6 +9,16 @@ a 2-byte big-endian length prefix (``LengthFieldBasedFrameDecoder(1024,0,2,0,2)`
 Request types (``ClusterConstants.java:24-28``): PING=0, FLOW=1, PARAM_FLOW=2,
 CONCURRENT_ACQUIRE=3, CONCURRENT_RELEASE=4.
 
+Which types are data plane on the native TCP door (decoded in C++ into a
+request arena, decided a pull at a time by one batched service entry,
+answered by the door's own encoder): the four single request types above
+and the batch frames BATCH_FLOW, BATCH_PARAM_FLOW and
+BATCH_CONCURRENT_ACQUIRE / _RELEASE below. Everything else (PING, a
+PARAM_FLOW frame with no value, replication, moves, leases, shares, outcome
+reports, pushes) is control plane: forwarded to Python a frame at a time.
+The shm door's data plane is FLOW and BATCH_FLOW alone; the asyncio door
+decodes every frame in Python. No frame's bytes differ between the doors.
+
 Flow request data  = ``flow_id:int64, count:int32, priority:uint8``
 (``FlowRequestDataWriter.java:35-37``); flow responses carry
 ``status:int8, remaining:int32, wait_ms:int32`` (the reference moves status in
@@ -44,10 +54,14 @@ row (``n:uint16`` + n × ``(status:int8, remaining:int32, wait_ms:int32)``),
 under type 27, verdicts in request order. Only hashes cross the wire, as for
 PARAM_FLOW, which (type 2, the reference client's frame) is unchanged. Both
 doors decide the rows of every frame through ONE batched service entry
-(``TokenService.request_params_batch``): the native door decodes type 27 on
-its data plane and frames of every connection coalesce into one pull; a
-frame with ``k = 0`` or a short body is malformed and closes the connection,
-an empty frame (``n = 0``) is answered in line. Servers before rev 8 reject
+(``TokenService.request_params_batch``): the native door decodes types 27
+and 2 on its data plane and frames of every connection coalesce into one
+pull (a run of frames with one ``k``: a single frame is a one-row frame
+with ``k`` = its number of values, answered by FLOW's response under type
+2); a batch frame with ``k = 0`` or a short body is malformed and closes
+the connection, as does a single frame whose body is shorter than its
+values; an empty batch frame (``n = 0``) is answered in line, and a single
+frame with no value passes (OK), answered by the control plane. Servers before rev 8 reject
 the type byte.
 
 BATCH_CONCURRENT_ACQUIRE / BATCH_CONCURRENT_RELEASE (codec rev 9, types 28
@@ -65,9 +79,10 @@ doors close a connection that sends more. A release
 frame is ``n:uint16`` + n × ``token_id:int64`` (at most 8,191 a frame) and IS
 answered, as upstream answers a release: ``n:uint16`` + n × ``status:int8``
 (RELEASE_OK or ALREADY_RELEASE). Both doors decide the rows of both frames,
-and of drained single type-3 / type-4 frames, through ONE batched service
+and of single type-3 / type-4 frames, through ONE batched service
 entry (``TokenService.request_concurrent_batch``); the native door decodes
-them on its data plane into an arena of their own. A body shorter or longer
+all four on its data plane into an arena of their own, a single frame as a
+one-row frame in arrival order with the batch frames. A body shorter or longer
 than its header declares is malformed and closes the connection; an empty
 frame is answered in line. Servers before rev 9 reject the type bytes.
 
@@ -229,7 +244,9 @@ from sentinel_tpu import chaos as _chaos
 # codec revision this build speaks: 2 deadline trailer, 3 REPL, 4 MOVE,
 # 5 LEASE + HIER share ops, 6 OUTCOME_REPORT, 7 PUSH control plane,
 # 8 BATCH_PARAM_FLOW, 9 BATCH_CONCURRENT_ACQUIRE / _RELEASE (the doc
-# revisions above)
+# revisions above). PR 45 made the single types 2, 3 and 4 data plane on the
+# native TCP door: where a frame is decoded, not what it holds; no frame's
+# bytes changed and the revision stays 9
 WIRE_REV = 9
 
 # 2-byte big-endian length prefix caps a frame at 65535 bytes; single-request

@@ -2,7 +2,7 @@
 
 The round-3 gap: the asyncio front door served ~1/8 of the device kernel's
 ceiling — per-frame Python costs dominated. Here the whole per-frame path
-(socket reads, length-prefixed framing, BATCH_FLOW/FLOW decode, verdict
+(socket reads, length-prefixed framing, request decode, verdict
 frame encode, socket writes, idle reaping) lives in
 ``native/src/sentinel_frontdoor.cpp``; Python's serving loop is one blocking
 ``wait_batch`` → ``TokenService.request_batch_arrays`` → ``submit`` cycle
@@ -10,15 +10,27 @@ per DEVICE STEP, regardless of how many frames or connections fed it.
 This is the netty-pipeline analog (``NettyTransportServer.java:73-101``)
 taken to its TPU conclusion: the host's job is to keep the device fed.
 
-Control-plane frames (PING handshake, single PARAM_FLOW and CONCURRENT_*
-frames) and open/close events surface through a low-rate poll thread so
-namespace connection groups (AVG_LOCAL scaling) and the host-side paths stay
-exactly as in the asyncio server; the single param and concurrency frames a
-door has queued are drained and decided through the services' batched
-entries. The batch frames of both (BATCH_PARAM_FLOW, codec rev 8;
-BATCH_CONCURRENT_ACQUIRE / _RELEASE, codec rev 9) are data plane on the TCP
-door: a pull is flow rows, param rows or concurrency rows, never two kinds,
-and a dispatch is one kind. The shm door stays flow-only.
+Every frame that asks for verdicts is data plane on the TCP door: the
+reference client's four single request types (FLOW, PARAM_FLOW,
+CONCURRENT_ACQUIRE, CONCURRENT_RELEASE: one request a frame) and this
+wire's batch frames (BATCH_FLOW; BATCH_PARAM_FLOW, codec rev 8;
+BATCH_CONCURRENT_ACQUIRE / _RELEASE, codec rev 9). A pull is flow rows,
+param rows or concurrency rows, never two kinds, and a dispatch is one
+kind; a single frame is a one-row frame of its kind's arena, so single and
+batch frames share pulls, dispatches, serve buckets, permits and reply lanes
+(a param pull is a run of frames with one number of values a request), and
+the door answers each frame in its own layout under its own type. STANDBY,
+brownout, age shed and overload answer a single PARAM_FLOW or CONCURRENT_*
+frame as they answer a single FLOW frame.
+
+Control-plane frames (PING handshake, replication, moves, leases, shares,
+completion reports) and open/close events surface through a low-rate poll
+thread so namespace connection groups (AVG_LOCAL scaling) and the host-side
+paths stay exactly as in the asyncio server. The shm door stays flow-only:
+the single PARAM_FLOW and CONCURRENT_* frames of its clients still reach
+the poll thread, as does a TCP door's PARAM_FLOW frame that carries no value
+(no row of the sketch); what a door has queued of them is drained and
+decided through the services' batched entries.
 API-compatible with ``TokenServer`` (start/stop/
 port/connections/tuning_kwargs) so ``apply_cluster_mode`` and the benches
 can switch via ``native=True``.
@@ -27,8 +39,9 @@ Serving pipeline: three decoupled lanes with bounded handoff queues,
 instead of one thread doing wait→step→submit in series. The **intake
 lane** pulls decoded frames from the C++ door and hands copies to the
 **device lane**, which drains everything queued (bounded by
-``fuse_depth`` pulls of host prep), concatenates it, and issues ONE
-dispatch — the token service's fusion ladder then folds full engine
+``fuse_depth`` pulls of host prep; a group that is still under one full
+pull of rows, a trickle of one-row frames, keeps folding past it),
+concatenates it, and issues ONE dispatch — the token service's fusion ladder then folds full engine
 frames into a single chained ``lax.scan`` device step, so the fixed
 per-dispatch overhead is paid once per fused group. ``n_dispatchers``
 **reply lanes** block on the async verdicts, slice them back per pull, and
@@ -59,6 +72,7 @@ from sentinel_tpu.cluster.token_service import (
     decide_concurrent_requests,
     decide_param_requests,
     halves,
+    params_batch_entry,
 )
 from sentinel_tpu.core.log import record_log
 from sentinel_tpu.engine import TokenStatus
@@ -71,9 +85,14 @@ from sentinel_tpu.trace.slo import slo_plane as _slo_plane
 _SM = server_metrics()
 _OVERLOAD = int(TokenStatus.OVERLOAD)
 _STANDBY = int(TokenStatus.STANDBY)
+# ``f_type`` (the wire's type byte) of the frames whose rows are releases,
+# batch and single, and of a single PARAM_FLOW frame
+_TYPE_RELEASE = int(P.MsgType.CONCURRENT_RELEASE)
 _TYPE_BATCH_RELEASE = int(P.MsgType.BATCH_CONCURRENT_RELEASE)
-# single frames the control loop drains a queue at a time and decides
-# through the services' batched entries (_answer_params,
+_TYPE_PARAM_FLOW = int(P.MsgType.PARAM_FLOW)
+# single frames that still reach the control loop (the shm door's, and a TCP
+# door's PARAM_FLOW frame with no value), drained a queue at a time and
+# decided through the services' batched entries (_answer_params,
 # _answer_concurrent)
 _DRAINED_SINGLES = frozenset({
     P.MsgType.PARAM_FLOW, P.MsgType.CONCURRENT_ACQUIRE,
@@ -151,7 +170,8 @@ class NativeTokenServer:
         self.intake_shards = max(1, int(intake_shards))
         # fuse_depth bounds how many queued intake pulls the device lane
         # folds into one dispatch (each pull is itself up to max_batch
-        # rows) — the host-prep budget of the adaptive frame fusion
+        # rows) — the host-prep budget of the adaptive frame fusion; a
+        # group still under max_batch rows in all keeps folding past it
         self.fuse_depth = max(1, int(fuse_depth))
         # double-buffering bound: fused groups dispatched but not yet
         # materialized. 2 overlaps the next group's host prep (queue
@@ -723,10 +743,12 @@ class NativeTokenServer:
                 if wake_ns:
                     _SM.door_wake_ms.record((t_py - wake_ns) * 1e-6)
                 # nv: values per request of a param pull (its rows are the
-                # requests of BATCH_PARAM_FLOW frames), 0 for a flow pull,
-                # -1 for a concurrency pull (the rows of
-                # BATCH_CONCURRENT_ACQUIRE / _RELEASE frames in arrival
-                # order; a release row's id column holds its token id)
+                # requests of BATCH_PARAM_FLOW frames and of single
+                # PARAM_FLOW frames, a row each), 0 for a flow pull, -1 for
+                # a concurrency pull (the rows of BATCH_CONCURRENT_ACQUIRE /
+                # _RELEASE and of single CONCURRENT_ACQUIRE / _RELEASE
+                # frames in arrival order; a release row's id column holds
+                # its token id)
                 n, k, nv = got
                 if chaos.ARMED:
                     chaos.maybe_sleep("lane_delay")
@@ -955,16 +977,29 @@ class NativeTokenServer:
         rr = 0
         service = self.service
         flow_dispatch = getattr(service, "dispatch_batch_arrays", None)
+
+        def own(name):
+            # a dispatch half counts only where the served object's own
+            # class defines it: a wrapper that alters the one-row SPI calls
+            # and delegates the rest (``__getattr__``) is asked through
+            # them, as ``params_batch_entry`` asks it, and not past them.
+            # Single frames come this way since PR 45, and a one-row call
+            # is what such a wrapper sees of a single frame
+            if getattr(type(service), name, None) is None:
+                return None
+            return getattr(service, name)
+
         # a pull is either flow rows or param rows (the rows of
-        # BATCH_PARAM_FLOW frames, with their value hashes), and a dispatch
-        # is one or the other: a queued pull of another kind (or of another
-        # number of values per request) waits in ``held`` for the next turn
-        param_dispatch = getattr(service, "dispatch_params_batch", None)
+        # BATCH_PARAM_FLOW and single PARAM_FLOW frames, with their value
+        # hashes), and a dispatch is one or the other: a queued pull of
+        # another kind (or of another number of values per request) waits
+        # in ``held`` for the next turn
+        param_dispatch = own("dispatch_params_batch")
         # ... or, third, the rows of concurrency frames (kind -1): the
         # release ids and acquire rows of its pulls in their arrival order,
         # which the service applies releases first (a release is never
         # applied later than an acquire frame behind it on its connection)
-        conc_dispatch = getattr(service, "dispatch_concurrent_batch", None)
+        conc_dispatch = own("dispatch_concurrent_batch")
         held = None
 
         def kind(pull) -> int:
@@ -1005,11 +1040,19 @@ class NativeTokenServer:
                         break
                     continue
                 pulls = [item]
+                rows = len(item[0])
                 # adaptive frame fusion: everything already queued joins
                 # this dispatch. Idle queues → depth 1 (no added latency);
-                # backlog → deep fused step (max amortization).
+                # backlog → deep fused step (max amortization). The depth
+                # is a budget of host prep, counted in pulls of up to
+                # max_batch rows: pulls that together are less than ONE
+                # such pull (a trickle of one-row frames, a few a pull) do
+                # not use it up, or a dispatch's fixed cost is paid once
+                # every fuse_depth tiny pulls and the lane saturates on
+                # their number, not their rows (PERF.md section 6, PR 45)
                 stop_after = False
-                while len(pulls) < self.fuse_depth:
+                while (len(pulls) < self.fuse_depth
+                       or 1 < self.fuse_depth and rows < self.max_batch):
                     if not sem.acquire(blocking=False):
                         break
                     nxt = pop_next()
@@ -1025,6 +1068,7 @@ class NativeTokenServer:
                         held = nxt
                         break
                     pulls.append(nxt)
+                    rows += len(nxt[0])
                 hashes = item[7]
                 if len(pulls) == 1:
                     ids, counts, prios = item[0], item[1], item[2]
@@ -1043,7 +1087,8 @@ class NativeTokenServer:
                     # which rows are releases: whole frames, by their type
                     dispatch = conc_dispatch
                     third = np.concatenate([
-                        np.repeat(p[3][4] == _TYPE_BATCH_RELEASE, p[3][3])
+                        np.repeat((p[3][4] == _TYPE_BATCH_RELEASE)
+                                  | (p[3][4] == _TYPE_RELEASE), p[3][3])
                         for p in pulls
                     ])
                     sync = concurrent_batch_entry(service)
@@ -1055,7 +1100,17 @@ class NativeTokenServer:
                     sync = getattr(service, "request_batch_arrays", None)
                 else:
                     dispatch, third = param_dispatch, hashes
-                    sync = service.request_params_batch
+                    sync = params_batch_entry(service)
+                    # the reference client's one-request frames among them
+                    singles = [
+                        int(np.count_nonzero(p[3][4] == _TYPE_PARAM_FLOW))
+                        for p in pulls
+                    ]
+                    if any(singles):
+                        _SM.count_param_singles(
+                            sum(singles), sum(1 for m in singles if m),
+                            sum(singles) * kind(item),
+                        )
                 lengths = [len(p[0]) for p in pulls]
                 n_rows = len(ids)
                 # deadline proxy: pulls older than shed_age_ms are answered
@@ -1367,14 +1422,16 @@ class NativeTokenServer:
         # per-door namespacing — only the REPLY must go out through the
         # door that owns the connection
         #
-        # Single PARAM_FLOW frames (the reference client's, variable
-        # length) arrive here too. They are not answered one dispatch a
-        # request: what a door has queued is drained, the PARAM_FLOW
-        # requests among it are set aside in queue order, and each run of
-        # equal value counts is decided by ONE call of the service's
-        # batched entry (_answer_params). Single CONCURRENT_ACQUIRE /
-        # _RELEASE frames are set aside the same way in a list of their
-        # own (_answer_concurrent).
+        # The TCP doors serve single PARAM_FLOW and CONCURRENT_ACQUIRE /
+        # _RELEASE frames on their data plane; those of the shm door's
+        # clients, and a TCP door's PARAM_FLOW frame with no value, arrive
+        # here. They are not answered one dispatch a request: what a door
+        # has queued is drained, the PARAM_FLOW requests among it are set
+        # aside in queue order, and each run of equal value counts is
+        # decided by ONE call of the service's batched entry
+        # (_answer_params). Single CONCURRENT_ACQUIRE / _RELEASE frames are
+        # set aside the same way in a list of their own
+        # (_answer_concurrent).
         doors = list(self._doors)
         while not self._stop.is_set():
             got_any = False
@@ -1411,6 +1468,7 @@ class NativeTokenServer:
         queue order, through ``decide_param_requests``: one call of the
         service's batched entry per run of equal value counts."""
         reqs = [req for _fd, _gen, req, _addr in params]
+        _SM.count_param_control_frames(len(reqs))
         if self.is_standby:
             verdicts = [(_STANDBY, 0, 0)] * len(reqs)
         else:

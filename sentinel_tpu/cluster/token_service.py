@@ -548,6 +548,7 @@ class DefaultTokenService(TokenService):
         # dispatch does
         self._concurrent_max_tokens = int(concurrent_max_tokens)
         self._conc = None
+        self._warmed = False  # warmup() has run: later loads compile at once
         self._conc_timer: Optional[threading.Thread] = None
         self._conc_timer_stop = threading.Event()
         self._conc_timer_closed = False  # close() until reopen()
@@ -1154,18 +1155,22 @@ class DefaultTokenService(TokenService):
             # the concurrency step, every serve bucket, on a throwaway
             # plane: where concurrency rules are loaded, and only there
             if self._conc is not None:
-                from sentinel_tpu.engine import concurrent as _CE
-
-                cs = _CE.make_concurrent_state(self._conc.config)
-                for bucket in self._serve_buckets:
-                    cs, verdicts = self._conc.step_fn(bucket)(
-                        cs, _CE.pack_concurrent_rows(
-                            bucket, (), (), (), (), now))
-                jax.block_until_ready(verdicts)
-                del cs
+                self._warm_concurrent_steps(self._conc, now)
         # from here on a compile is one in front of live traffic: counted
         # (compiles_after_warmup_total) and logged by name
+        self._warmed = True
         _SM.set_warm(True)
+
+    def _warm_concurrent_steps(self, plane, now: int = 0) -> None:
+        """Compile the concurrency step of every serve bucket on a
+        throwaway plane (the step donates its state)."""
+        from sentinel_tpu.engine import concurrent as _CE
+
+        cs = _CE.make_concurrent_state(plane.config)
+        for bucket in self._serve_buckets:
+            cs, verdicts = plane.step_fn(bucket)(
+                cs, _CE.pack_concurrent_rows(bucket, (), (), (), (), now))
+        jax.block_until_ready(verdicts)
 
     def request_token(self, flow_id, acquire=1, prioritized=False) -> TokenResult:
         return self.request_batch([(flow_id, acquire, prioritized)])[0]
@@ -2009,15 +2014,25 @@ class DefaultTokenService(TokenService):
         from sentinel_tpu.cluster.concurrent import ConcurrentPlane
 
         rules = list(rules)
-        with self._rules_mutex, self._lock:
-            if self._conc is None:
+        with self._rules_mutex:
+            plane = self._conc
+            if plane is None:
                 if not rules:
                     return
-                self._conc = ConcurrentPlane(
+                plane = ConcurrentPlane(
                     self.config.max_flows, self._concurrent_max_tokens,
                     self._serve_buckets,
                 )
-            self._conc.load_rules(rules, self._connected)
+                if self._warmed:
+                    # a service that is serving compiles the plane's steps
+                    # here, on the loader's thread and outside the serving
+                    # lock: the first concurrency frame shares the native
+                    # device lane with every other kind of row, and a
+                    # compile there would age what queues behind it
+                    self._warm_concurrent_steps(plane)
+            with self._lock:
+                self._conc = plane
+                plane.load_rules(rules, self._connected)
         self._start_concurrent_timer()
 
     def current_concurrent_rules(self) -> list:

@@ -160,6 +160,29 @@ class ServerMetrics:
             "answered without touching the sketch (cumulative).",
     }
 
+    # the reference client's one-request PARAM_FLOW frames (type 2) on the
+    # native door: served on its data plane, counted by the device lane a
+    # dispatch at a time; those the control loop still answers (the shm
+    # door's, and a TCP door's frame with no value) are counted there
+    _PARAM_SINGLE_COUNTERS = {
+        "param_single_frames_total":
+            "Single PARAM_FLOW frames the native door's data plane pulls "
+            "carried to a hot-parameter dispatch (cumulative).",
+        "param_single_pulls_total":
+            "Data-plane pulls that carried at least one single PARAM_FLOW "
+            "frame (cumulative).",
+        "param_single_dispatch_total":
+            "Hot-parameter dispatches of the native device lane that "
+            "carried at least one single PARAM_FLOW frame (cumulative).",
+        "param_single_rows_total":
+            "(request, value) rows of the single PARAM_FLOW frames those "
+            "dispatches carried (cumulative).",
+        "param_control_frames_total":
+            "Single PARAM_FLOW frames the native server's control loop "
+            "answered: none of a TCP door's that carry a value "
+            "(cumulative).",
+    }
+
     # the concurrency lane (DefaultTokenService.dispatch_concurrent_batch)
     _CONCURRENT_COUNTERS = {
         "concurrent_dispatch_total":
@@ -304,6 +327,7 @@ class ServerMetrics:
         # resolved to for the serving geometry, with the reason
         self._param_lock = threading.Lock()
         self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
+        self._param_single = dict.fromkeys(self._PARAM_SINGLE_COUNTERS, 0)
         self._arm_lock = threading.Lock()
         self._arms = dict.fromkeys(self._ARM_COUNTERS, 0)
         self._param_impl = ("", "")
@@ -487,6 +511,23 @@ class ServerMetrics:
             p["param_blocked_total"] += int(blocked)
             p["param_no_rule_total"] += int(no_rule)
 
+    def count_param_singles(self, frames: int, pulls: int,
+                            rows: int) -> None:
+        """One hot-parameter dispatch of the native device lane carried
+        ``frames`` single PARAM_FLOW frames of ``rows`` (request, value)
+        rows, which came in ``pulls`` of its pulls."""
+        with self._param_lock:
+            p = self._param_single
+            p["param_single_frames_total"] += int(frames)
+            p["param_single_pulls_total"] += int(pulls)
+            p["param_single_dispatch_total"] += 1
+            p["param_single_rows_total"] += int(rows)
+
+    def count_param_control_frames(self, frames: int) -> None:
+        """The control loop answered ``frames`` single PARAM_FLOW frames."""
+        with self._param_lock:
+            self._param_single["param_control_frames_total"] += int(frames)
+
     def count_concurrent_step(self, acquires: int, releases: int,
                               blocked: int, already: int, expired: int,
                               table_full: int, live: int,
@@ -561,6 +602,10 @@ class ServerMetrics:
     def param_totals(self) -> Dict[str, int]:
         with self._param_lock:
             return dict(self._param)
+
+    def param_single_totals(self) -> Dict[str, int]:
+        with self._param_lock:
+            return dict(self._param_single)
 
     def set_param_impl(self, kernel: str, reason: str) -> None:
         """What ``ParamConfig.impl`` resolved to for the serving geometry."""
@@ -1168,6 +1213,7 @@ class ServerMetrics:
         out["verdict_host_reads_total"] = self.verdict_host_reads_total
         out["verdict_copy_ready_total"] = self.verdict_copy_ready_total
         out.update(self.param_totals())
+        out.update(self.param_single_totals())
         out.update(self.arm_totals())
         out.update(self.concurrent_totals())
         out["reply_first_total"] = self.reply_first_total
@@ -1566,6 +1612,8 @@ class ServerMetrics:
              "(cumulative).", self.verdict_copy_ready_total),
             *((name, self._PARAM_COUNTERS[name], value)
               for name, value in self.param_totals().items()),
+            *((name, self._PARAM_SINGLE_COUNTERS[name], value)
+              for name, value in self.param_single_totals().items()),
             *((name, self._ARM_COUNTERS[name], value)
               for name, value in self.arm_totals().items()),
             *((name, self._CONCURRENT_COUNTERS[name], value)
@@ -1644,6 +1692,8 @@ class ServerMetrics:
             self._prep_native = 0
         with self._param_lock:
             self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
+            self._param_single = dict.fromkeys(
+                self._PARAM_SINGLE_COUNTERS, 0)
         with self._arm_lock:
             self._arms = dict.fromkeys(self._ARM_COUNTERS, 0)
         with self._concurrent_lock:

@@ -202,6 +202,15 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         lib._sn_has_shm = True
     except AttributeError:
         lib._sn_has_shm = False
+    # single PARAM_FLOW frames on the data plane (sentinel_frontdoor.cpp),
+    # resolved defensively for the same reason: a library from before the
+    # counter serves none there and counts none
+    try:
+        lib.sn_fd_param_single_frames.argtypes = [P]
+        lib.sn_fd_param_single_frames.restype = ctypes.c_uint64
+        lib._sn_has_param_singles = True
+    except AttributeError:
+        lib._sn_has_param_singles = False
     # flow prep (sentinel_native.cpp), resolved defensively for the same
     # reason. Every pointer goes in as an integer (``arr.ctypes.data``): a
     # ``data_as`` object costs microseconds each, ten times a dispatch
@@ -534,8 +543,12 @@ class Frontdoor:
     One IO thread owns sockets, framing, decode, and response writes; Python
     pulls whole request batches with :meth:`wait_batch` (GIL released while
     blocked), runs the device step, and answers with :meth:`submit`.
-    Control-plane frames (PING, param, concurrent) surface through
-    :meth:`next_control`; replies go back via :meth:`send`.
+    Every frame that asks for verdicts is data plane: the reference
+    client's single frames (FLOW, PARAM_FLOW, CONCURRENT_ACQUIRE / _RELEASE)
+    as one-row frames beside the batch frames of their arena, each answered
+    in its own layout by :meth:`submit`. Control-plane frames (PING, a
+    PARAM_FLOW frame with no value, replication, moves, leases, reports)
+    surface through :meth:`next_control`; replies go back via :meth:`send`.
 
     Spans, all on ``time.monotonic_ns()``'s clock: a data frame is stamped
     when the ``recv()`` that completed it returned. A pull hands the stamps
@@ -547,7 +560,9 @@ class Frontdoor:
     """
 
     CTRL_FRAME, CTRL_OPEN, CTRL_CLOSE = 0, 1, 2
-    # ``f_type`` of the frames of a concurrency pull (codec rev 9)
+    # ``f_type`` of the batch frames of a concurrency pull (codec rev 9);
+    # every ``f_type`` is the wire's own type byte (``protocol.MsgType``),
+    # the reference client's single frames (types 1-4) included
     TYPE_BATCH_ACQUIRE, TYPE_BATCH_RELEASE = 28, 29
     # rx -> pull about to return; submit entered -> last byte sent; rx ->
     # last byte sent. The order of sn_fd_span_stats.
@@ -718,10 +733,12 @@ class Frontdoor:
         arrived first). Returns ``None`` on timeout, else ``(n, frames,
         k)``: ``k`` is 0 for a flow pull, the values per request of a
         param pull, whose hashes then fill ``staging["hashes"][:n * k]``
-        request-major (a pull takes frames of one ``k`` and at most as
-        many values as that array holds), or -1 for a concurrency pull: a
-        connection-ordered run of frames, of which those with ``f_type``
-        :data:`TYPE_BATCH_RELEASE` carry a token id a row in ``ids``. The
+        request-major (a pull takes frames of one ``k``, single PARAM_FLOW
+        frames and BATCH_PARAM_FLOW frames alike, and at most as many
+        values as that array holds), or -1 for a concurrency pull: a
+        connection-ordered run of frames, of which those with an ``f_type``
+        of CONCURRENT_RELEASE (4) or :data:`TYPE_BATCH_RELEASE` carry a
+        token id a row in ``ids``. The
         stamps as :meth:`wait_batch_into` leaves them."""
         from sentinel_tpu.cluster.protocol import MAX_BATCH_PER_FRAME
 
@@ -761,7 +778,8 @@ class Frontdoor:
         ``door_residence_ms`` of each frame whose stamp is not 0 when its
         reply has gone out; five columns count nothing. ``token_ids``
         (int64, a row each) fills the wider reply rows of
-        BATCH_CONCURRENT_ACQUIRE frames; None writes 0 there."""
+        BATCH_CONCURRENT_ACQUIRE frames and the reply of a single
+        CONCURRENT_ACQUIRE frame; None writes 0 there."""
         import numpy as np
 
         # every array binds to a local: an unnamed ascontiguousarray copy
@@ -869,10 +887,16 @@ class Frontdoor:
         self._lib.sn_fd_stats(
             self._h, self._ptr(out, ctypes.c_uint64)
         )
-        return {
+        stats = {
             "frames_in": int(out[0]), "requests_in": int(out[1]),
             "bytes_in": int(out[2]), "bytes_out": int(out[3]),
         }
+        if getattr(self._lib, "_sn_has_param_singles", False):
+            # single PARAM_FLOW frames decoded into the param arena
+            stats["param_single_frames_in"] = int(
+                self._lib.sn_fd_param_single_frames(self._h)
+            )
+        return stats
 
     def set_span_bounds(self, name: str, bounds_ms) -> None:
         """Hand the door the bucket bounds (ms, ascending; at most

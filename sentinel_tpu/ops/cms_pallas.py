@@ -49,6 +49,27 @@ from jax.experimental.pallas import tpu as pltpu
 MAX_BATCH = 1024
 _VMEM_LIMIT = 64 * 1024 * 1024
 
+
+def vmem_bytes(N: int, P: int, D: int, W: int, cell_bytes: int = 4) -> int:
+    """About what the kernel keeps in VMEM at once: the ``[N, N]`` f32 mask,
+    ``D`` ``[N, W]`` one-hots beside the gathered ``[N, W]`` rows, and one
+    ``[P, W]`` plane of the sketch. A geometry past ``_VMEM_LIMIT`` is
+    refused by this sum, before Mosaic is asked: at ``hot-param-1k``'s 1,024
+    rules x 16,384 cells the plane alone is the whole limit, and the
+    compiler does not come back with its refusal within ten minutes
+    (PERF.md section 6, PR 45)."""
+    return 4 * N * N + 4 * (D + 1) * N * W + cell_bytes * P * W
+
+
+def refuse_past_vmem(N: int, P: int, D: int, W: int, limit: int,
+                     cell_bytes: int = 4) -> None:
+    need = vmem_bytes(N, P, D, W, cell_bytes)
+    if need > limit:
+        raise ValueError(
+            f"param sketch {P} x {D} x {W} at {N} rows needs about "
+            f"{need >> 20} MiB of VMEM; the pallas kernel's limit is "
+            f"{limit >> 20} MiB")
+
 # The one-hot matmuls carry integer counts through the MXU; its default
 # single bf16 pass keeps 8 mantissa bits (cells above 256 would round).
 # HIGHEST keeps integer-valued f32 exact up to 2^24.
@@ -201,6 +222,8 @@ def cms_decide_update_pallas(
     N = rule_slot.shape[0]
     if N > MAX_BATCH:
         raise ValueError(f"param batch {N} exceeds pallas cap {MAX_BATCH}")
+    if not interpret:
+        refuse_past_vmem(N, P, D, W, _VMEM_LIMIT)
     if refine_iters % 2 == 0:
         raise ValueError("refine_iters must be odd (no-overshoot guarantee)")
 

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from sentinel_tpu.ops.cms_pallas import refuse_past_vmem
 from sentinel_tpu.sketch.salsa import CAP, MERGE_CEIL, SAT
 
 MAX_BATCH = 1024
@@ -248,6 +249,9 @@ def salsa_decide_update_pallas(
     N = rule_slot.shape[0]
     if N > MAX_BATCH:
         raise ValueError(f"param batch {N} exceeds pallas cap {MAX_BATCH}")
+    # the same sum as ops/cms_pallas.py, on this kernel's int16 plane
+    if not interpret:
+        refuse_past_vmem(N, P, D, C, _VMEM_LIMIT, cell_bytes=2)
     if refine_iters % 2 == 0:
         raise ValueError("refine_iters must be odd (no-overshoot guarantee)")
 

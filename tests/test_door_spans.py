@@ -163,12 +163,18 @@ def test_one_frames_stamps_are_in_order_on_monotonic_ns(door):
     _read_reply(sock, n)
     t_after = time.monotonic_ns()
     assert _settled(lambda: d.span_stats()["door_residence_ms"][0], 1) == 1
+    t_counted = time.monotonic_ns()
     stats = d.span_stats()
     # one frame: its sums are its spans, and give back the door's stamps
     done_ns = rx_ns + round(stats["door_residence_ms"][1] * 1e6)
     submit_ns = done_ns - round(stats["door_out_ms"][1] * 1e6)
     assert t_send <= rx_ns <= wake_ns <= t_py <= t_before
-    assert t_before <= submit_ns <= done_ns <= t_after
+    # the IO thread stamps a frame done after send() returned, and the reply
+    # may be read before that: what bounds done_ns is the count that follows
+    # it, not the reply (7 of 3,000 frames read done_ns 0.1-158 us past
+    # t_after on a loaded machine)
+    assert t_before <= submit_ns <= done_ns <= t_counted
+    assert submit_ns <= t_after
     assert stats["door_in_ms"][0] == 1
     assert round(stats["door_in_ms"][1] * 1e6) == wake_ns - rx_ns
     for name in DOOR:  # the bucket counts are of the one frame
@@ -210,7 +216,7 @@ def served():
     ids = np.arange(1, 9, dtype=np.int64)
     run = {}
     try:
-        base = _residence_count()
+        base, accounted = _residence_count(), SM.account_ms.count
         assert client.request_batch_arrays(ids) is not None  # settle
         assert _settled(_residence_count, base + 1) == base + 1
         c0, s0 = _counts(), _stages()
@@ -221,6 +227,12 @@ def served():
         run["before"], run["after"] = s0, _stages()
         run["disarmed_events"] = ring.events(
             stages={ring.RX, ring.REPLY_TAKEN})
+        # the reply lane answers first and counts after: the last disarmed
+        # frame's account half must be through before the recorder is armed,
+        # or its DEVICE_OUT counts among the armed frames' below and the
+        # recorder is disarmed before the last armed frame's reply_out (seen
+        # on a loaded machine)
+        _settled(lambda: SM.account_ms.count, accounted + 1 + FRAMES)
         ring.arm()
         t_armed = time.monotonic_ns()
         for _ in range(FRAMES):

@@ -242,7 +242,9 @@ class TestNativeFrontdoor:
                                             timeout=5)
             sock.settimeout(5)
             n_sent = 10_000  # > kMaxControls (8192)
-            frame = struct.pack(">H", 5) + struct.pack(">IB", 7, 2)
+            # a bare PING: PARAM_FLOW (type 2) is data plane since PR 45,
+            # and a frame of it this short closes its connection
+            frame = struct.pack(">H", 5) + struct.pack(">IB", 7, 0)
             blob = frame * n_sent
             sender = threading.Thread(
                 target=sock.sendall, args=(blob,), daemon=True
@@ -318,8 +320,8 @@ class TestFrontdoorFuzz:
 
 class TestNativeMixedSoak:
     def test_mixed_planes_under_reload(self, native_server):
-        """Data-plane BATCH_FLOW, control-plane PARAM_FLOW and
-        CONCURRENT acquire/release, all interleaved over several
+        """BATCH_FLOW, single PARAM_FLOW and single CONCURRENT
+        acquire/release (data plane all, since PR 45), interleaved over several
         connections while rules reload continuously: the arena, control
         queue, pipelined dispatch, and rules mutex must never hand back a
         non-OK verdict for the always-loaded rules, raise, or wedge a

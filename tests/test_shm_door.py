@@ -234,6 +234,11 @@ class TestClientDeath:
             assert line.strip() == "READY", (
                 f"child failed: {proc.stderr.read()}"
             )
+            # a client that has sent nothing yet (advance 0) is known to
+            # the door only from its next directory sweep: wait for that
+            deadline = time.monotonic() + 3.0
+            while _segments(server) < 1 and time.monotonic() < deadline:
+                time.sleep(0.02)
             assert _segments(server) >= 1
             proc.send_signal(signal.SIGKILL)
             proc.wait(timeout=10)

@@ -845,14 +845,13 @@ def run_scenario(cfg: ScenarioConfig) -> dict:
     sm = server_metrics()
     counter_pass: Dict[str, int] = {}
     counter_block: Dict[str, int] = {}
-    with sm._verdict_lock:
-        for (v, ns), c in sm._verdicts.items():
-            if ns.startswith("rls:"):
-                continue
-            if v == "pass":
-                counter_pass[ns] = counter_pass.get(ns, 0) + c
-            elif v == "block":
-                counter_block[ns] = counter_block.get(ns, 0) + c
+    for (v, ns), c in sm.verdict_totals().items():
+        if ns.startswith("rls:"):
+            continue
+        if v == "pass":
+            counter_pass[ns] = counter_pass.get(ns, 0) + c
+        elif v == "block":
+            counter_block[ns] = counter_block.get(ns, 0) + c
     tl_sums = _series_sums(samples)
     recon_diffs = {}
     for ns in set(counter_pass) | set(counter_block) | set(tl_sums):

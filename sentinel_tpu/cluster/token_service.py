@@ -18,7 +18,6 @@ import itertools
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -82,17 +81,14 @@ _SM = server_metrics()
 # flight-recorder identity of a service (the ``shard`` field of its phase
 # events): dispatch sequence numbers are per service
 _SERVICE_IDS = itertools.count(1)
-# The host's turn (DefaultTokenService._host_turn). numpy gives the GIL up
-# inside any loop over more elements than _TURN_ROWS
-# (NPY_BEGIN_THREADS_THRESHOLDED), so the account half of a larger frame hands
-# the GIL over at each of its fifty numpy calls and a launch beside it takes
-# it each time: both then cost two to three times their CPU (PERF.md section
-# 6, PR 43). A smaller frame's account half holds the GIL from end to end,
-# has nothing to hand over and would only lose by waiting. The wait is
-# bounded: the turn spaces two halves of host work, it orders nothing, and a
-# holder that the machine stopped must not stop the dispatch lane.
-_TURN_ROWS = 500
-_TURN_WAIT_S = 0.002
+# the cluster stat log's events of a dispatch, and the verdict each counts
+_STAT_LOG_EVENTS = (
+    ("pass", int(TokenStatus.OK)),
+    ("block", int(TokenStatus.BLOCKED)),
+    ("occupied", int(TokenStatus.SHOULD_WAIT)),
+    ("tooManyRequest", int(TokenStatus.TOO_MANY_REQUEST)),
+    ("degraded", int(TokenStatus.DEGRADED)),
+)
 
 
 @dataclass(frozen=True)
@@ -467,10 +463,6 @@ class DefaultTokenService(TokenService):
         # buffer by then).
         self._fused_staging: Dict[int, object] = {}
         self._lock = threading.Lock()
-        # taken in turn by a flow dispatch's launch and by the account half
-        # of a flow dispatch, BEFORE the service lock and never needed to
-        # make progress (_host_turn)
-        self._turn = threading.Lock()
         # outer mutex for rule read-modify-write sequences: a namespace
         # replacement (merge current rules + load) must be atomic against a
         # concurrent replacement of ANOTHER namespace, or the later load
@@ -484,10 +476,11 @@ class DefaultTokenService(TokenService):
         # swapped atomically on rule load, read lock-free on the hot path
         self._lookup = (np.empty(0, np.int64), np.empty(0, np.int32))
         # slot → namespace row snapshot for per-namespace verdict counters,
-        # same atomic-swap discipline: (names tuple, int32[max_flows] of
-        # namespace indices, -1 where the slot holds no rule)
+        # same atomic-swap discipline: (names tuple, int32[max_flows + 1]
+        # of namespace indices, -1 where the slot holds no rule and in the
+        # last entry, which is what slot -1 (no rule) indexes)
         self._ns_snapshot: Tuple[Tuple[str, ...], np.ndarray] = (
-            (), np.full(self.config.max_flows, -1, np.int32),
+            (), np.full(self.config.max_flows + 1, -1, np.int32),
         )
         self._epoch_ms: Optional[int] = None
         self._connected: Dict[str, int] = {}  # namespace → client count
@@ -654,25 +647,6 @@ class DefaultTokenService(TokenService):
         # are fire-and-forget through non-blocking sinks — safe to call
         # under self._lock (see _emit_push).
         self._push_hubs: List[object] = []
-
-    @contextmanager
-    def _host_turn(self, rows: int):
-        """A flow dispatch's launch (service lock, jitted call, the start of
-        the verdicts' copy) and the account half of one do not run beside
-        each other where ``rows`` is more than ``_TURN_ROWS``: whoever comes
-        second sleeps on ``self._turn``, wanting no GIL, for at most
-        ``_TURN_WAIT_S``, then goes ahead regardless. Until PR 43 the numpy
-        prep's 1.5 ms outside the GIL kept the two apart by accident; the
-        native pass took that away and with it a closed loop's rate
-        (``mesh-100k.sidecar-sat``: launch 0.92 -> 2.2 ms, account 1.5 ->
-        4.0). On the dispatch side the wait lies inside ``lock_wait_ms``, on
-        the other inside ``account_ms``."""
-        got = rows > _TURN_ROWS and self._turn.acquire(timeout=_TURN_WAIT_S)
-        try:
-            yield
-        finally:
-            if got:
-                self._turn.release()
 
     @staticmethod
     def _prep_batch(cfg, slots, acq, pr):
@@ -842,7 +816,7 @@ class DefaultTokenService(TokenService):
             ns_names = [""] * n_ns
             for ns_name, row in self._index.ns_of.items():
                 ns_names[row] = ns_name
-            slot_ns = np.full(self.config.max_flows, -1, np.int32)
+            slot_ns = np.full(self.config.max_flows + 1, -1, np.int32)
             for r in rules:
                 slot_ns[self._index.slot_of[r.flow_id]] = (
                     self._index.ns_of[r.namespace]
@@ -1280,51 +1254,49 @@ class DefaultTokenService(TokenService):
         slots_ns = slots  # pre-mask slots: verdict→namespace attribution
         moved_mask = moved_epochs = None
         t_prep = time.monotonic_ns()
-        # -- device step: the only serialized section (the host's turn
-        # first: no large account half counts beside the launch) --
-        with self._host_turn(n):
-            with self._lock:
-                t_locked = time.monotonic_ns()
-                seq = self._dispatch_seq = self._dispatch_seq + 1
-                if self._lookup is not lookup_snap:
-                    # rules reloaded between prep and step: slot assignments
-                    # may have moved, so redo the slot-dependent prep against
-                    # the live table (rare, and still under the lock — the
-                    # same atomicity load_rules callers had before the
-                    # narrowing)
-                    slots = self._lookup_from(self._lookup, flow_ids)
-                    slots_ns = slots
+        # -- device step: the only serialized section --
+        with self._lock:
+            t_locked = time.monotonic_ns()
+            seq = self._dispatch_seq = self._dispatch_seq + 1
+            if self._lookup is not lookup_snap:
+                # rules reloaded between prep and step: slot assignments
+                # may have moved, so redo the slot-dependent prep against
+                # the live table (rare, and still under the lock — the
+                # same atomicity load_rules callers had before the
+                # narrowing)
+                slots = self._lookup_from(self._lookup, flow_ids)
+                slots_ns = slots
+                order, packed = self._prep_batch(cfg, slots, acq, pr)
+            mv = self._moving_snap
+            if mv is not None:
+                # live rebalance: rows of a MOVING namespace are masked
+                # out of the device batch — their counters never move
+                # (the zero-over-admission half of the lossless move) —
+                # and the materializer overlays MOVED. Checked under the
+                # lock so a begin_move strictly orders against every
+                # dispatch.
+                moved_mask, moved_epochs = self._moving_mask_for(slots, mv)
+                if moved_mask is not None:
+                    slots = np.where(
+                        moved_mask, np.int32(-1), slots
+                    ).astype(np.int32)
                     order, packed = self._prep_batch(cfg, slots, acq, pr)
-                mv = self._moving_snap
-                if mv is not None:
-                    # live rebalance: rows of a MOVING namespace are masked
-                    # out of the device batch — their counters never move
-                    # (the zero-over-admission half of the lossless move) —
-                    # and the materializer overlays MOVED. Checked under the
-                    # lock so a begin_move strictly orders against every
-                    # dispatch.
-                    moved_mask, moved_epochs = self._moving_mask_for(slots, mv)
-                    if moved_mask is not None:
-                        slots = np.where(
-                            moved_mask, np.int32(-1), slots
-                        ).astype(np.int32)
-                        order, packed = self._prep_batch(cfg, slots, acq, pr)
-                # the clock rides the one host argument; read under the lock,
-                # written into an array only this dispatch holds
-                packed[ROW_HEAD, HEAD_NOW] = self._engine_now()
-                self._state, verdicts = step(self._state, self._table, packed)
-                if self._dirty is not None:
-                    touched = np.unique(slots[slots >= 0]).tolist()
-                    self._dirty["flow"].update(touched)
-                    if self._has_breakers:
-                        # breaker transitions only happen for batched rows, so
-                        # touched ∩ breaker-slots is exactly the dirty set
-                        self._dirty.setdefault("breaker", set()).update(
-                            s for s in touched if s in self._breaker_slots
-                        )
-            # the verdicts' one copy to the host starts now, behind the step on
-            # the device's queue, not when a reply lane gets round to asking
-            verdicts.copy_to_host_async()
+            # the clock rides the one host argument; read under the lock,
+            # written into an array only this dispatch holds
+            packed[ROW_HEAD, HEAD_NOW] = self._engine_now()
+            self._state, verdicts = step(self._state, self._table, packed)
+            if self._dirty is not None:
+                touched = np.unique(slots[slots >= 0]).tolist()
+                self._dirty["flow"].update(touched)
+                if self._has_breakers:
+                    # breaker transitions only happen for batched rows, so
+                    # touched ∩ breaker-slots is exactly the dirty set
+                    self._dirty.setdefault("breaker", set()).update(
+                        s for s in touched if s in self._breaker_slots
+                    )
+        # the verdicts' one copy to the host starts now, behind the step on
+        # the device's queue, not when a reply lane gets round to asking
+        verdicts.copy_to_host_async()
         self._dispatched(t_enter, t_prep, t_locked, seq, n,
                          native_prep=prep is not None)
 
@@ -1400,64 +1372,51 @@ class DefaultTokenService(TokenService):
         and the read half's end: the decision latency the SLO plane gets
         ends there, and ``account_ms`` counts from this call's own start.
         ``arms`` is what a flow dispatch's step said of its cond-gated arms
-        (``unpack_arms``). A flow dispatch's counting waits for the host's
-        turn (:meth:`_host_turn`), inside ``account_ms``."""
+        (``unpack_arms``). The dispatch is counted here and fanned out per
+        namespace later (``ServerMetrics.record_verdict_batch``)."""
         t_account = time.monotonic_ns()
-        # a flow dispatch's counting takes the host's turn: it never runs
-        # beside a launch (the breaker scan below takes the service lock and
-        # stays outside it)
-        with self._host_turn(rows if slots_ns is not None else 0):
-            if isinstance(slots_ns, list):
-                slots_ns = np.concatenate(slots_ns)
-            # per-namespace verdict counters (sentinel_server_verdicts_total):
-            # attribute each request's verdict to its rule's namespace via the
-            # lock-free slot→namespace snapshot
-            if slots_ns is None:  # a param dispatch: no flow slots
-                ns_idx, ns_names = None, ()
-            else:
-                ns_names, slot_ns = self._ns_snapshot
-                ns_idx = np.where(
-                    slots_ns >= 0, slot_ns[np.maximum(slots_ns, 0)],
-                    np.int32(-1),
-                )
-            _SM.record_verdict_batch(
-                status, ns_idx, ns_names,
-                latency_ms=(t_fetched - t_enter) * 1e-6,
-                wait_ms=wait,
+        if isinstance(slots_ns, list):
+            slots_ns = np.concatenate(slots_ns)
+        # per-namespace verdict counters (sentinel_server_verdicts_total):
+        # attribute each request's verdict to its rule's namespace via the
+        # lock-free slot→namespace snapshot (slot -1 reads its last entry)
+        if slots_ns is None:  # a param dispatch: no flow slots
+            ns_idx, ns_names = None, ()
+        else:
+            ns_names, slot_ns = self._ns_snapshot
+            ns_idx = slot_ns[slots_ns]
+        by_code = _SM.record_verdict_batch(
+            status, ns_idx, ns_names,
+            latency_ms=(t_fetched - t_enter) * 1e-6,
+            wait_ms=wait,
+        )
+        live = 0
+        if arms is not None:
+            live = int(arms[ARM_LIVE])
+            _SM.count_decide_arms(
+                rows, live & ARM_SHAPING, live & ARM_PACING,
+                live & ARM_OCCUPY, int(arms[ARM_SHAPED_ROWS]),
+                int(arms[ARM_PACED_ROWS]), int(arms[ARM_PRIORITIZED_ROWS]),
+                breaker=(live & ARM_BREAKER, int(arms[ARM_GUARDED_ROWS]),
+                         int(arms[ARM_DEGRADED_ROWS]),
+                         int(arms[ARM_PROBES]), int(arms[ARM_TO_OPEN])),
             )
-            live = 0
-            if arms is not None:
-                live = int(arms[ARM_LIVE])
-                _SM.count_decide_arms(
-                    rows, live & ARM_SHAPING, live & ARM_PACING,
-                    live & ARM_OCCUPY, int(arms[ARM_SHAPED_ROWS]),
-                    int(arms[ARM_PACED_ROWS]), int(arms[ARM_PRIORITIZED_ROWS]),
-                    breaker=(live & ARM_BREAKER, int(arms[ARM_GUARDED_ROWS]),
-                             int(arms[ARM_DEGRADED_ROWS]),
-                             int(arms[ARM_PROBES]), int(arms[ARM_TO_OPEN])),
-                )
-            if _TR.ARMED:  # flight recorder: verdicts on the host and counted
-                sid, aux = self._trace_sid, seq & 0x7FFFFFFF
-                _TR.record(_TR.READY, shard=sid, aux=aux, t_ns=t_ready)
-                _TR.record(_TR.FETCHED, shard=sid, aux=aux, t_ns=t_fetched)
-                _TR.record(_TR.ACCOUNT, shard=sid, aux=aux, t_ns=t_account)
-                _TR.record(_TR.DEVICE_OUT, aux=rows,
-                           shard=lane | live << _TR.ARM_SHIFT)
-            # cluster server stat log (ClusterServerStatLogUtil analog): one
-            # aggregated counter per verdict class per window
-            n_degraded = 0
-            for event, code in (
-                ("pass", int(TokenStatus.OK)),
-                ("block", int(TokenStatus.BLOCKED)),
-                ("occupied", int(TokenStatus.SHOULD_WAIT)),
-                ("tooManyRequest", int(TokenStatus.TOO_MANY_REQUEST)),
-                ("degraded", int(TokenStatus.DEGRADED)),
-            ):
-                hits = int((status == code).sum())
-                if hits:
-                    log_cluster(event, count=hits)
-                    if event == "degraded":
-                        n_degraded = hits
+        if _TR.ARMED:  # flight recorder: verdicts on the host and counted
+            sid, aux = self._trace_sid, seq & 0x7FFFFFFF
+            _TR.record(_TR.READY, shard=sid, aux=aux, t_ns=t_ready)
+            _TR.record(_TR.FETCHED, shard=sid, aux=aux, t_ns=t_fetched)
+            _TR.record(_TR.ACCOUNT, shard=sid, aux=aux, t_ns=t_account)
+            _TR.record(_TR.DEVICE_OUT, aux=rows,
+                       shard=lane | live << _TR.ARM_SHIFT)
+        # cluster server stat log (ClusterServerStatLogUtil analog): one
+        # aggregated counter per verdict class per window
+        n_degraded = 0
+        if by_code is not None:
+            by_code = by_code.tolist()
+            for event, code in _STAT_LOG_EVENTS:
+                if by_code[code]:
+                    log_cluster(event, count=by_code[code])
+            n_degraded = by_code[TokenStatus.DEGRADED]
         if n_degraded:
             # breaker activity observed: fold the device transitions
             # into the host transition counters / blackbox plane
@@ -1572,52 +1531,50 @@ class DefaultTokenService(TokenService):
         step = self._fused_step_fn(depth, uniform)
         moved_span = moved_epochs_span = span_ns = None
         t_prep = time.monotonic_ns()
-        # -- device step: the only serialized section (the host's turn
-        # first, see dispatch_batch_arrays) --
-        with self._host_turn(depth * cap):
-            with self._lock:
-                t_locked = time.monotonic_ns()
-                seq = self._dispatch_seq = self._dispatch_seq + 1
-                if self._lookup is not lookup_snap:
-                    # rules reloaded between prep and step (see
-                    # dispatch_batch_arrays): redo slot-dependent prep against
-                    # the live table
+        # -- device step: the only serialized section --
+        with self._lock:
+            t_locked = time.monotonic_ns()
+            seq = self._dispatch_seq = self._dispatch_seq + 1
+            if self._lookup is not lookup_snap:
+                # rules reloaded between prep and step (see
+                # dispatch_batch_arrays): redo slot-dependent prep against
+                # the live table
+                preps = [
+                    _restage(
+                        f, self._lookup_from(self._lookup, flow_ids[sl])
+                    )
+                    for f, sl in enumerate(frames)
+                ]
+            mv = self._moving_snap
+            if mv is not None:
+                # live rebalance (see dispatch_batch_arrays): mask MOVING-
+                # namespace rows out of every staged frame so the fused
+                # step never counts their tokens, and remember the span
+                # mask for the MOVED overlay
+                span0 = np.concatenate([p[0] for p in preps])
+                m, eps = self._moving_mask_for(span0, mv)
+                if m is not None:
+                    moved_span, moved_epochs_span, span_ns = m, eps, span0
                     preps = [
                         _restage(
-                            f, self._lookup_from(self._lookup, flow_ids[sl])
+                            f,
+                            np.where(
+                                m[sl], np.int32(-1), span0[sl]
+                            ).astype(np.int32),
                         )
                         for f, sl in enumerate(frames)
                     ]
-                mv = self._moving_snap
-                if mv is not None:
-                    # live rebalance (see dispatch_batch_arrays): mask MOVING-
-                    # namespace rows out of every staged frame so the fused
-                    # step never counts their tokens, and remember the span
-                    # mask for the MOVED overlay
-                    span0 = np.concatenate([p[0] for p in preps])
-                    m, eps = self._moving_mask_for(span0, mv)
-                    if m is not None:
-                        moved_span, moved_epochs_span, span_ns = m, eps, span0
-                        preps = [
-                            _restage(
-                                f,
-                                np.where(
-                                    m[sl], np.int32(-1), span0[sl]
-                                ).astype(np.int32),
-                            )
-                            for f, sl in enumerate(frames)
-                        ]
-                block[ROW_HEAD, 0, HEAD_NOW] = self._engine_now()
-                self._state, verdicts = step(self._state, self._table, block)
-                if self._dirty is not None:
-                    span = np.concatenate([p[0] for p in preps])
-                    touched = np.unique(span[span >= 0]).tolist()
-                    self._dirty["flow"].update(touched)
-                    if self._has_breakers:
-                        self._dirty.setdefault("breaker", set()).update(
-                            s for s in touched if s in self._breaker_slots
-                        )
-            verdicts.copy_to_host_async()  # see dispatch_batch_arrays
+            block[ROW_HEAD, 0, HEAD_NOW] = self._engine_now()
+            self._state, verdicts = step(self._state, self._table, block)
+            if self._dirty is not None:
+                span = np.concatenate([p[0] for p in preps])
+                touched = np.unique(span[span >= 0]).tolist()
+                self._dirty["flow"].update(touched)
+                if self._has_breakers:
+                    self._dirty.setdefault("breaker", set()).update(
+                        s for s in touched if s in self._breaker_slots
+                    )
+        verdicts.copy_to_host_async()  # see dispatch_batch_arrays
         self._dispatched(t_enter, t_prep, t_locked, seq, depth * cap,
                          native_prep=native_prep)
         _SM.record_fused(depth)
@@ -2238,9 +2195,7 @@ class DefaultTokenService(TokenService):
         (reads the live ``_ns_snapshot``)."""
         mask_arr, epoch_arr = mv
         _names, slot_ns = self._ns_snapshot
-        ns_idx = np.where(
-            slots >= 0, slot_ns[np.maximum(slots, 0)], np.int32(-1)
-        )
+        ns_idx = slot_ns[slots]  # slot -1 reads the last entry, -1
         m = (ns_idx >= 0) & mask_arr[np.maximum(ns_idx, 0)]
         if not m.any():
             return None, None
@@ -2355,10 +2310,7 @@ class DefaultTokenService(TokenService):
             self._lookup, np.asarray(flow_ids, np.int64)
         )
         names, slot_ns = self._ns_snapshot
-        idx = np.where(
-            slots >= 0, slot_ns[np.maximum(slots, 0)], np.int32(-1)
-        )
-        return idx, names
+        return slot_ns[slots], names  # slot -1 reads the last entry, -1
 
     # -- rev-7 push plane (server→client control frames) ---------------------
     def attach_push_hub(self, hub) -> None:

@@ -17,12 +17,16 @@ Everything here renders under the ``sentinel_server_*`` prefix via
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, List, Optional, Tuple
+import time
+from bisect import bisect_left
+from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
 from sentinel_tpu.core import clock as _clock
 from sentinel_tpu.metrics.histogram import LatencyHistogram
+from sentinel_tpu.metrics.timeline import _EDGES as _TIMELINE_EDGES
+from sentinel_tpu.metrics.timeline import timeline
 
 # TokenStatus codes that appear on the batch paths → series label.
 VERDICT_NAMES: Dict[int, str] = {
@@ -52,6 +56,64 @@ SHED_REASONS = (
 )
 
 NO_RULE_NAMESPACE = "(no-rule)"  # requests whose flow_id has no loaded rule
+
+# refusal verdict → the SLO-plane shed reason it is attributed under; every
+# other verdict is a served row (it waited for a device step: it has the
+# dispatch's latency)
+_SLO_SHED_REASONS = {"overload": "overload", "too_many_request":
+                     "namespace_guard", "moved": "moved",
+                     "degraded": "degraded"}
+
+# The count matrix of one dispatch: a row per TokenStatus code up to the
+# largest VERDICT_NAMES names, a column per namespace of the dispatch's
+# ns_names snapshot behind column 0, (no-rule). _CODE_ROW takes a status
+# byte to its row; a byte that names no verdict goes to a row past the
+# matrix, which is cut off.
+_N_CODES = max(VERDICT_NAMES) + 1
+_CODE_ROW = np.full(256, _N_CODES, np.intp)
+_CODE_ROW[list(VERDICT_NAMES)] = list(VERDICT_NAMES)
+_SHED_CODES = tuple(
+    (code, _SLO_SHED_REASONS[name]) for code, name in VERDICT_NAMES.items()
+    if name in _SLO_SHED_REASONS
+)
+_SERVED_CODES = np.array(
+    [code for code, name in VERDICT_NAMES.items()
+     if name not in _SLO_SHED_REASONS], np.intp,
+)
+_PASS, _BLOCK, _SHOULD_WAIT = 0, 1, 2  # the timeline's own columns
+
+
+class _PendingAccount:
+    """What the dispatches of ONE wall second, attributed under ONE
+    ``ns_names`` snapshot, have deposited and the sinks have not seen: the
+    summed count matrix and, per namespace column, what the SLO plane and
+    the timeline need of each dispatch's one latency. Guarded by
+    ``ServerMetrics._verdict_lock``; folded by ``ServerMetrics._fold``."""
+
+    __slots__ = ("sec", "names", "verdicts", "slo_lat", "tl_lat", "lat_rows",
+                 "over", "lat_sum", "lat_max", "wait_counts", "wait_sum",
+                 "wait_max")
+
+    def __init__(self, sec: int, names: Tuple[str, ...], n_slo_buckets: int,
+                 n_wait_buckets: int):
+        self.sec = sec
+        self.names = names
+        cols = len(names) + 1
+        self.verdicts = np.zeros((_N_CODES, cols), np.int64)
+        # served rows that carried a latency: by DECISION_BOUNDS bucket
+        # (a latency no histogram takes, negative or NaN, is in none), by
+        # the timeline's _EDGES bucket, all of them, and those over the
+        # objective; the latencies' sum over rows and their largest
+        self.slo_lat = np.zeros((n_slo_buckets, cols), np.int64)
+        self.tl_lat = np.zeros((len(_TIMELINE_EDGES) + 1, cols), np.int64)
+        self.lat_rows = np.zeros(cols, np.int64)
+        self.over = np.zeros(cols, np.int64)
+        self.lat_sum = np.zeros(cols, np.float64)
+        self.lat_max = np.zeros(cols, np.float64)
+        # positive wait hints by wait_assigned_ms bucket
+        self.wait_counts = np.zeros(n_wait_buckets, np.int64)
+        self.wait_sum = 0.0
+        self.wait_max = 0.0
 
 
 def _escape(label: str) -> str:
@@ -372,10 +434,20 @@ class ServerMetrics:
         # traffic-shaping waits: every SHOULD_WAIT verdict that carried a
         # positive wait hint (paced admission or priority occupy) — count
         # plus the distribution of assigned waits (whole ms, ≥ 1)
-        self.wait_assigned_ms = LatencyHistogram(lo=1.0, hi=60_000.0)
+        self._wait_assigned_ms = LatencyHistogram(lo=1.0, hi=60_000.0)
+        self._wait_bounds = np.asarray(self._wait_assigned_ms.bounds)
         self._wait_assigned = 0
         self._verdicts: Dict[Tuple[str, str], int] = {}
         self._verdict_lock = threading.Lock()
+        # what record_verdict_batch has deposited and not yet fanned out to
+        # the dict above, the SLO plane and the timeline: at most one
+        # second's worth (the deposit of a later second folds it), folded
+        # by every reader first. _fold_lock is held from taking the record
+        # to the last sink's update, so a reader that waited for it has
+        # everything deposited before it asked
+        self._pending: Optional[_PendingAccount] = None
+        self._fold_lock = threading.Lock()
+        self._account_folds = 0
         self._rate = _RateWindow()
         # shed accounting: frames the server refused (answered OVERLOAD) or
         # dropped (deadline blown, abandoned lane), by reason — the number
@@ -655,6 +727,7 @@ class ServerMetrics:
 
     @property
     def wait_assigned_total(self) -> int:
+        self._fold_pending()
         with self._verdict_lock:
             return self._wait_assigned
 
@@ -730,15 +803,26 @@ class ServerMetrics:
         with self._verdict_lock:
             self._verdicts[key] = self._verdicts.get(key, 0) + n
 
+    def verdict_totals(self) -> Dict[Tuple[str, str], int]:
+        """Cumulative verdicts by ``(verdict, namespace)``."""
+        self._fold_pending()
+        with self._verdict_lock:
+            return dict(self._verdicts)
+
     def verdict_totals_by_namespace(self) -> Dict[str, int]:
         """Cumulative verdicts served per namespace, all verdict classes
         summed — the admission gate diffs successive reads to rank the
         hottest namespaces for its rebalance advisories."""
         out: Dict[str, int] = {}
-        with self._verdict_lock:
-            for (_verdict, ns), count in self._verdicts.items():
-                out[ns] = out.get(ns, 0) + count
+        for (_verdict, ns), count in self.verdict_totals().items():
+            out[ns] = out.get(ns, 0) + count
         return out
+
+    @property
+    def wait_assigned_ms(self) -> LatencyHistogram:
+        """Wait assigned per SHOULD_WAIT verdict, pending deposits in."""
+        self._fold_pending()
+        return self._wait_assigned_ms
 
     def record_verdict_batch(
         self,
@@ -747,103 +831,169 @@ class ServerMetrics:
         ns_names: Tuple[str, ...],
         latency_ms: Optional[float] = None,
         wait_ms: Optional[np.ndarray] = None,
-    ) -> None:
+        now_s: Optional[int] = None,
+    ) -> Optional[np.ndarray]:
         """Count one materialized batch: ``status`` int8[N] TokenStatus
         codes, ``ns_idx`` int32[N] namespace row per request (-1 → no rule;
-        None → attribute everything to ``(no-rule)``). Vectorized — a few
-        masked bincounts per batch, never a Python loop over requests.
+        None → attribute everything to ``(no-rule)``). Returns the batch's
+        rows by status code, int64[_N_CODES] (None for an empty batch).
+
+        Counted at deposit, fanned out at the fold: the batch is reduced to
+        one count matrix ``[status code, namespace + 1]`` (one bincount on
+        a combined key, never a Python loop over requests or namespaces)
+        and added into the pending record of its wall second; the
+        per-namespace updates of the verdict counters, the SLO plane and
+        the timeline happen once per pending record, when a deposit of the
+        next second or of another ``ns_names`` snapshot arrives and before
+        every read (:meth:`_fold_pending`), so each read is exact.
 
         ``latency_ms`` (decision latency shared by the whole batch) feeds
         the per-tenant SLO plane; refusal statuses are attributed there as
         sheds either way. ``wait_ms`` int32[N] (the verdicts' wait hints)
         feeds the assigned-wait counter/histogram — only positive hints
-        count, and only SHOULD_WAIT verdicts carry them."""
+        count, and only SHOULD_WAIT verdicts carry them. ``now_s`` stands
+        in for the wall second (tests)."""
+        # trace.slo imports this package: it cannot be imported with it
+        from sentinel_tpu.trace.slo import DECISION_BOUNDS, slo_plane
+
         status = np.asarray(status)
         n = int(status.shape[0])
         if n == 0:
-            return
+            return None
         self._rate.add(n)
+        waits = None
         if wait_ms is not None:
             w = np.asarray(wait_ms)
-            wmask = w > 0
-            n_wait = int(wmask.sum())
-            if n_wait:
-                with self._verdict_lock:
-                    self._wait_assigned += n_wait
-                # batches repeat few distinct waits; record value-grouped
-                for v, c in zip(*np.unique(w[wmask], return_counts=True)):
-                    self.wait_assigned_ms.record(float(v), int(c))
-        updates: Dict[Tuple[str, str], int] = {}
-        for code, vname in VERDICT_NAMES.items():
-            mask = status == code
-            hits = int(mask.sum())
-            if not hits:
-                continue
-            if ns_idx is None or not len(ns_names):
-                updates[(vname, NO_RULE_NAMESPACE)] = hits
-                continue
-            counts = np.bincount(
-                ns_idx[mask] + 1, minlength=len(ns_names) + 1
-            )
-            if counts[0]:
-                updates[(vname, NO_RULE_NAMESPACE)] = int(counts[0])
-            for j in np.nonzero(counts[1:])[0]:
-                updates[(vname, ns_names[int(j)])] = int(counts[1 + j])
-        with self._verdict_lock:
-            for key, v in updates.items():
-                self._verdicts[key] = self._verdicts.get(key, 0) + v
-        self._feed_slo(updates, latency_ms)
+            w = w[w > 0]
+            if w.size:
+                waits = (
+                    np.bincount(np.searchsorted(self._wait_bounds, w),
+                                minlength=len(self._wait_bounds) + 1),
+                    float(w.sum()), float(w.max()),
+                )
+        key = _CODE_ROW[status.astype(np.uint8)]
+        named = ns_idx is not None and len(ns_names) > 0
+        width = len(ns_names) + 1 if named else 1
+        if named:
+            key = key * width + (np.asarray(ns_idx) + 1)
+        counts = np.bincount(key, minlength=(_N_CODES + 1) * width)[
+            :_N_CODES * width].reshape(_N_CODES, width)
+        by_code = counts.sum(axis=1)
+        lat = None
+        if latency_ms is not None:
+            # what the sinks need of the one latency, per namespace column:
+            # the served rows, in the two sinks' buckets
+            lat = float(latency_ms)
+            served = counts[_SERVED_CODES].sum(axis=0)
+            tl_bucket = int(np.searchsorted(_TIMELINE_EDGES, lat))
+            slo_bucket = bisect_left(DECISION_BOUNDS, lat) if lat >= 0 else -1
+            is_over = lat > slo_plane().objective_ms
+        while True:
+            with self._verdict_lock:
+                sec = int(time.time()) if now_s is None else int(now_s)
+                pend = self._pending
+                if pend is None:
+                    pend = self._pending = _PendingAccount(
+                        sec, ns_names if named else (),
+                        len(DECISION_BOUNDS) + 1, len(self._wait_bounds) + 1)
+                # an unnamed batch has column 0 alone, which every record has
+                if pend.sec == sec and (
+                        not named or ns_names is pend.names
+                        or ns_names == pend.names):
+                    pend.verdicts[:, :width] += counts
+                    if lat is not None:
+                        pend.lat_rows[:width] += served
+                        pend.tl_lat[tl_bucket, :width] += served
+                        if slo_bucket >= 0:
+                            pend.slo_lat[slo_bucket, :width] += served
+                            pend.lat_sum[:width] += lat * served
+                        if is_over:
+                            pend.over[:width] += served
+                        np.maximum(pend.lat_max[:width], lat * (served > 0),
+                                   out=pend.lat_max[:width])
+                    if waits is not None:
+                        pend.wait_counts += waits[0]
+                        pend.wait_sum += waits[1]
+                        pend.wait_max = max(pend.wait_max, waits[2])
+                    return by_code
+            # another second's record, or another snapshot's: fold it first
+            self._fold_pending(pend)
 
-    # refusal verdict → the SLO-plane shed reason it is attributed under
-    _SLO_SHED_REASONS = {"overload": "overload", "too_many_request":
-                         "namespace_guard", "moved": "moved",
-                         "degraded": "degraded"}
+    def _fold_pending(self, stale: Optional[_PendingAccount] = None) -> None:
+        """Hand the pending record to the sinks. Every reader of what
+        :meth:`record_verdict_batch` counts calls this first. A depositor
+        names the ``stale`` record it met: if another thread folded that
+        one meanwhile, the record that took its place stays."""
+        with self._fold_lock:
+            with self._verdict_lock:
+                pend = self._pending
+                if pend is None or (stale is not None and pend is not stale):
+                    return
+                self._pending = None
+            self._fold(pend)
 
-    def _feed_slo(
-        self,
-        updates: Dict[Tuple[str, str], int],
-        latency_ms: Optional[float],
-    ) -> None:
-        """Per-tenant SLO + timeline accounting off the verdict-batch
-        updates: served rows record the batch's decision latency, refusals
-        record as sheds (each row lands in exactly one window bucket —
-        served OR shed). The timeline's shed column is fed from
-        ``SloPlane.record_shed`` (which this calls), so timeline sums
-        reconcile with both ``sentinel_server_verdicts_total`` and
-        ``sentinel_slo_shed_total`` deltas."""
-        from sentinel_tpu.metrics.timeline import timeline
+    def _fold(self, pend: _PendingAccount) -> None:
+        """The per-namespace fan-out of one pending record: verdict totals,
+        the assigned-wait histogram, each touched tenant's SLO histogram,
+        burn windows and shed counts, the timeline's second. Caller holds
+        ``_fold_lock``."""
         from sentinel_tpu.trace.slo import slo_plane
 
+        names = (NO_RULE_NAMESPACE,) + pend.names
+        v = pend.verdicts
+        rows, cols = np.nonzero(v)
+        with self._verdict_lock:
+            totals = self._verdicts
+            for code, j, c in zip(rows.tolist(), cols.tolist(),
+                                  v[rows, cols].tolist()):
+                key = (VERDICT_NAMES[code], names[j])
+                totals[key] = totals.get(key, 0) + c
+            self._wait_assigned += int(pend.wait_counts.sum())
+            self._account_folds += 1
+        if pend.wait_max:
+            self._wait_assigned_ms.merge(
+                pend.wait_counts.tolist(), pend.wait_sum, pend.wait_max)
+        # each row lands in exactly one window bucket, served OR shed, and
+        # in one timeline column: pass, block, shed, other, waited. So
+        # timeline sums reconcile with sentinel_server_verdicts_total and
+        # with sentinel_slo_shed_total deltas
+        served = v[_SERVED_CODES].sum(axis=0)
+        shed = [(reason, v[code]) for code, reason in _SHED_CODES]
+        n_shed = sum(col for _reason, col in shed)
+        tl_counts = np.stack([
+            v[_PASS], v[_BLOCK], n_shed,
+            served - v[_PASS] - v[_BLOCK] - v[_SHOULD_WAIT],
+            v[_SHOULD_WAIT],
+        ])
         plane = slo_plane()
-        tl = timeline()
-        served: Dict[str, int] = {}
-        # timeline columns per namespace: [pass, block, other, waited]
-        cols: Dict[str, List[int]] = {}
-        for (vname, ns), v in updates.items():
-            reason = self._SLO_SHED_REASONS.get(vname)
-            if reason is not None:
-                plane.record_shed(ns, reason, v)
-                continue
-            served[ns] = served.get(ns, 0) + v
-            c = cols.setdefault(ns, [0, 0, 0, 0])
-            if vname == "pass":
-                c[0] += v
-            elif vname == "block":
-                c[1] += v
-            elif vname == "should_wait":
-                # delayed admission (pacing / priority occupy): served, but
-                # attributed in its own column so a paced tenant's wall
-                # shows shaping, not mystery "other" traffic
-                c[3] += v
-                plane.record_waited(ns, v)
-            else:
-                c[2] += v
-        for ns, c in cols.items():
-            tl.record(ns, n_pass=c[0], n_block=c[1], n_other=c[2],
-                      latency_ms=latency_ms, n_waited=c[3])
-        if latency_ms is not None:
-            for ns, v in served.items():
-                plane.record(ns, latency_ms, v)
+        lat_rows, over = pend.lat_rows.tolist(), pend.over.tolist()
+        lat_sum, lat_max = pend.lat_sum.tolist(), pend.lat_max.tolist()
+        waited = v[_SHOULD_WAIT].tolist()
+        shed = [(reason, col.tolist()) for reason, col in shed]
+        tl_rows = []
+        for j in np.nonzero(served + n_shed)[0].tolist():
+            ns = names[j]
+            tl_rows.append((ns, tl_counts[:, j],
+                            pend.tl_lat[:, j] if lat_rows[j] else None,
+                            lat_max[j]))
+            ns_shed = [(reason, col[j]) for reason, col in shed if col[j]]
+            if lat_rows[j] or ns_shed or waited[j]:
+                plane.fold(
+                    ns, pend.sec,
+                    lat_counts=pend.slo_lat[:, j].tolist() if lat_rows[j]
+                    else None,
+                    lat_sum=lat_sum[j], lat_max=lat_max[j],
+                    lat_rows=lat_rows[j], over=over[j], shed=ns_shed,
+                    waited=waited[j],
+                )
+        timeline().fold(pend.sec, tl_rows)
+
+    @property
+    def account_folds_total(self) -> int:
+        """Pending records folded: beside ``account_ms``'s count, how many
+        dispatches one per-namespace fan-out stood for."""
+        with self._verdict_lock:
+            return self._account_folds
 
     def count_rls(self, domain: str, ok_n: int, over_n: int) -> None:
         """Envoy RLS responses, per domain. The descriptors already counted
@@ -1118,6 +1268,7 @@ class ServerMetrics:
         """JSON shape served by the ``clusterServerStats`` command — the
         same numbers the Prometheus surface renders."""
         self._fold_door_spans()
+        self._fold_pending()
         with self._verdict_lock:
             verdicts = [
                 {"verdict": v, "namespace": ns, "count": c}
@@ -1133,6 +1284,7 @@ class ServerMetrics:
             "verdictCopyReadyTotal": self.verdict_copy_ready_total,
             "replyFirstTotal": self.reply_first_total,
             "prepNativeTotal": self.prep_native_total,
+            "accountFoldsTotal": self.account_folds_total,
             "shedTotal": self.shed_total,
             "shedByReason": self.shed_totals(),
             "hostCopyBytesTotal": self.host_copy_bytes_total,
@@ -1168,7 +1320,7 @@ class ServerMetrics:
                 "intake_ms": self.intake_ms.snapshot(),
                 "dispatch_ms": self.dispatch_ms.snapshot(),
                 "fused_depth": self.fused_depth.snapshot(),
-                "wait_assigned_ms": self.wait_assigned_ms.snapshot(),
+                "wait_assigned_ms": self._wait_assigned_ms.snapshot(),
                 **{name: getattr(self, name).snapshot()
                    for name, _help in self._PHASES + self._DOOR_SPANS},
             },
@@ -1182,6 +1334,7 @@ class ServerMetrics:
         bucket counts (``cum``, the last the whole count) and ``max``, so
         that two snapshots differ into the window's own quantiles."""
         self._fold_door_spans()
+        self._fold_pending()
         out = {}
         for name, hist in (
             ("queue_wait_ms", self.queue_wait_ms),
@@ -1191,7 +1344,7 @@ class ServerMetrics:
             ("intake_ms", self.intake_ms),
             ("dispatch_ms", self.dispatch_ms),
             ("fused_depth", self.fused_depth),
-            ("wait_assigned_ms", self.wait_assigned_ms),
+            ("wait_assigned_ms", self._wait_assigned_ms),
             *((name, getattr(self, name))
               for name, _help in self._PHASES + self._DOOR_SPANS),
         ):
@@ -1218,6 +1371,7 @@ class ServerMetrics:
         out.update(self.concurrent_totals())
         out["reply_first_total"] = self.reply_first_total
         out["prep_native_total"] = self.prep_native_total
+        out["account_folds_total"] = self.account_folds_total
         out["param_impl"], out["param_impl_reason"] = self.param_impl
         out["shed_total"] = self.shed_totals()
         out["host_copy_bytes_total"] = self.host_copy_bytes_total
@@ -1231,6 +1385,7 @@ class ServerMetrics:
         """``sentinel_server_*`` Prometheus exposition (no trailing
         newline; the exporter joins sections)."""
         self._fold_door_spans()
+        self._fold_pending()
         lines = [
             "# HELP sentinel_server_verdicts_total Cluster token verdicts "
             "by class and namespace (cumulative).",
@@ -1590,7 +1745,7 @@ class ServerMetrics:
             ("sentinel_server_wait_assigned_ms",
              "Wait assigned per SHOULD_WAIT verdict: paced admission or "
              "priority occupy delay (ms).",
-             self.wait_assigned_ms),
+             self._wait_assigned_ms),
             *((f"sentinel_server_{name}", help_text, getattr(self, name))
               for name, help_text in self._PHASES + self._DOOR_SPANS),
         ):
@@ -1628,6 +1783,12 @@ class ServerMetrics:
              "prep_ms's count a shortfall says the library is not built "
              "and numpy prepped them (cumulative).",
              self.prep_native_total),
+            ("account_folds_total",
+             "Per-namespace fan-outs of the verdict accounting: dispatches "
+             "are counted at deposit and folded into the verdict counters, "
+             "the SLO plane and the timeline once a wall second and before "
+             "every read; beside account_ms's count, the dispatches one "
+             "fold stood for (cumulative).", self.account_folds_total),
         ):
             lines.append(f"# HELP sentinel_server_{name} {help_text}")
             lines.append(f"# TYPE sentinel_server_{name} counter")
@@ -1672,7 +1833,6 @@ class ServerMetrics:
         self.intake_ms.reset()
         self.dispatch_ms.reset()
         self.fused_depth.reset()
-        self.wait_assigned_ms.reset()
         for name, _help in self._PHASES:
             getattr(self, name).reset()
         # what the live doors counted so far goes with the reset: fold it
@@ -1699,9 +1859,12 @@ class ServerMetrics:
         with self._concurrent_lock:
             self._concurrent = dict.fromkeys(self._CONCURRENT_COUNTERS, 0)
             self._concurrent_live = 0
-        with self._verdict_lock:
+        with self._fold_lock, self._verdict_lock:
+            self._pending = None  # what nobody has read goes unread
             self._verdicts.clear()
             self._wait_assigned = 0
+            self._account_folds = 0
+        self._wait_assigned_ms.reset()
         with self._shed_lock:
             self._shed.clear()
         with self._shard_lock:
@@ -1730,6 +1893,13 @@ class ServerMetrics:
 
 
 _SINGLETON = ServerMetrics()
+
+
+def fold_pending_accounts() -> None:
+    """The SLO plane's and the timeline's readers call this first: the
+    process-wide registry hands them what it counted at deposit. (Another
+    ``ServerMetrics`` folds on its own reads and deposits.)"""
+    _SINGLETON._fold_pending()
 
 
 def server_metrics() -> ServerMetrics:

@@ -49,6 +49,16 @@ _EDGES = np.geomspace(0.01, 10_000.0, 37)
 _N_LAT = len(_EDGES)  # searchsorted index 0.._N_LAT (last = overflow)
 
 
+def _fold_pending_accounts() -> None:
+    """Every reader of the timeline and of the SLO plane folds first: what
+    ``ServerMetrics`` has counted at deposit and not yet handed over to
+    them (``metrics.server.fold_pending_accounts``; that module imports
+    this one, hence the late import)."""
+    from sentinel_tpu.metrics.server import fold_pending_accounts
+
+    fold_pending_accounts()
+
+
 @dataclass
 class TimelineSample:
     """One (second, namespace) point — the line unit of the timeline log,
@@ -192,6 +202,13 @@ class MetricTimeline:
         self._flushed_upto = 0
 
     # -- recording ----------------------------------------------------------
+    def _ring(self, namespace: str) -> _NsRing:
+        """``namespace``'s ring, made on first use. Caller holds the lock."""
+        ring = self._rings.get(namespace)
+        if ring is None:
+            ring = self._rings[namespace] = _NsRing(self.window_s)
+        return ring
+
     def record(self, namespace: str, n_pass: int = 0, n_block: int = 0,
                n_shed: int = 0, n_other: int = 0,
                latency_ms: Optional[float] = None,
@@ -217,9 +234,7 @@ class MetricTimeline:
             return
         sec = int(now_s if now_s is not None else time.time())
         with self._lock:
-            ring = self._rings.get(namespace)
-            if ring is None:
-                ring = self._rings.setdefault(namespace, _NsRing(self.window_s))
+            ring = self._ring(namespace)
             i = ring.slot(sec)
             c = ring.counts[i]
             c[0] += max(0, n_pass)
@@ -242,6 +257,27 @@ class MetricTimeline:
         if self.writer is not None and sec - 1 > self._flushed_upto:
             self.flush(upto_s=sec - 1)
 
+    def fold(self, sec: int, rows) -> None:
+        """A second that ``ServerMetrics`` counted at deposit, handed over
+        in one call and under one lock: ``rows`` yields ``(namespace,
+        (pass, block, shed, other, waited), lat, lat_max)`` per touched
+        namespace, ``lat`` the served rows by :data:`_EDGES` bucket (None:
+        no dispatch carried a latency) and ``lat_max`` the largest of those
+        latencies. What :meth:`record` did once per dispatch and
+        namespace."""
+        with self._lock:
+            for namespace, counts, lat, lat_max in rows:
+                ring = self._ring(namespace)
+                i = ring.slot(sec)
+                ring.counts[i, :5] += counts
+                if lat is not None:
+                    ring.lat[i] += lat
+                    if lat_max > ring.lat_max[i]:
+                        ring.lat_max[i] = lat_max
+        # every second before ``sec`` was folded before this one was begun
+        if self.writer is not None and sec - 1 > self._flushed_upto:
+            self._flush(sec - 1)
+
     # -- persistence --------------------------------------------------------
     def flush(self, upto_s: Optional[int] = None) -> int:
         """Write every completed second in ``(_flushed_upto, upto_s]`` to
@@ -250,8 +286,12 @@ class MetricTimeline:
         log agree to the last second."""
         if self.writer is None:
             return 0
+        _fold_pending_accounts()
         if upto_s is None:
             upto_s = int(time.time())
+        return self._flush(upto_s)
+
+    def _flush(self, upto_s: int) -> int:
         n = 0
         with self._lock:
             lo = max(self._flushed_upto + 1, upto_s - self.window_s + 1)
@@ -273,6 +313,7 @@ class MetricTimeline:
               namespace: Optional[str] = None) -> List[TimelineSample]:
         """In-memory window read, time-ordered (namespace-ordered within a
         second)."""
+        _fold_pending_accounts()
         if end_ms is None:
             end_ms = int(time.time() * 1000)
         lo = begin_ms // 1000
@@ -315,11 +356,13 @@ class MetricTimeline:
         return out[:max_lines]
 
     def namespaces(self) -> List[str]:
+        _fold_pending_accounts()
         with self._lock:
             return sorted(self._rings)
 
     def status(self) -> dict:
         """The ``clusterServerStats`` ``timeline`` block."""
+        _fold_pending_accounts()
         with self._lock:
             names = sorted(self._rings)
             last = 0
@@ -485,6 +528,7 @@ def configure_timeline(base_dir: Optional[str] = None,
     """Replace the singleton with an explicitly configured timeline
     (benches point it at their artifact directory before the run)."""
     global _HUB
+    _fold_pending_accounts()  # into the timeline that goes, not this one
     with _HUB_LOCK:
         writer = TimelineWriter(base_dir) if base_dir else None
         _HUB = MetricTimeline(window_s=window_s, writer=writer)
@@ -493,6 +537,7 @@ def configure_timeline(base_dir: Optional[str] = None,
 
 def reset_timeline_for_tests() -> None:
     global _HUB
+    _fold_pending_accounts()
     with _HUB_LOCK:
         if _HUB is not None and _HUB.writer is not None:
             _HUB.writer.close()

@@ -32,7 +32,8 @@ import threading
 import time
 from typing import Dict, Iterable, List, Optional
 
-from sentinel_tpu.metrics.histogram import LatencyHistogram
+from sentinel_tpu.metrics.histogram import LatencyHistogram, log_buckets
+from sentinel_tpu.metrics.timeline import _fold_pending_accounts
 
 KEY_OBJECTIVE_MS = "sentinel.tpu.slo.p99.ms"
 # completion-RT objective: the p99 bound on what protected calls REPORT
@@ -44,6 +45,11 @@ KEY_RT_OBJECTIVE_MS = "sentinel.tpu.slo.rt.p99.ms"
 BUDGET_FRACTION = 0.01
 
 _WINDOWS = (("1m", 60), ("1h", 3600))
+
+# decision latency in ms; log buckets fine enough to resolve a 2ms objective
+# (0.01ms..10s, 5/decade). Every tenant's histogram is on these bounds, and
+# ``ServerMetrics`` buckets a dispatch's latency on them when it deposits
+DECISION_BOUNDS = log_buckets(0.01, 10_000.0, 5)
 
 
 class _BurnWindow:
@@ -85,9 +91,7 @@ class _Tenant:
                  "rt_hist", "rt_windows", "completed", "exceptions")
 
     def __init__(self):
-        # decision latency in ms; log buckets fine enough to resolve a
-        # 2ms objective (0.01ms..10s, 5/decade)
-        self.hist = LatencyHistogram(lo=0.01, hi=10_000.0, per_decade=5)
+        self.hist = LatencyHistogram(bounds=DECISION_BOUNDS)
         self.windows = {name: _BurnWindow(s) for name, s in _WINDOWS}
         self.shed: Dict[str, int] = {}
         # SHOULD_WAIT verdicts: served-with-delay (pacing / priority
@@ -181,14 +185,12 @@ class SloPlane:
             t.exceptions += max(0, int(n_exception))
 
     def record_shed(self, namespace: str, reason: str, n: int = 1) -> None:
-        """n rows refused for this tenant (OVERLOAD verdicts, brownout
-        sheds, namespace guards). A shed burns the whole budget for those
-        requests: counted as over-objective in the burn windows too.
-        Every shed path in the process funnels through here (door-level
-        ``record_shed_indexed`` and the verdict counter's refusal
-        statuses alike), so this is also the single feed point for the
-        metric timeline's ``shed`` column — each refused row lands there
-        exactly once."""
+        """n rows refused for this tenant at a door (queue full, brownout,
+        degrade: rows that never reach a dispatch). A shed burns the whole
+        budget for those requests: counted as over-objective in the burn
+        windows too. Feeds the metric timeline's ``shed`` column as well;
+        a dispatch's refusal verdicts reach both through :meth:`fold`, so
+        each refused row lands there exactly once."""
         if n <= 0:
             return
         t = self._tenant(namespace)
@@ -199,6 +201,33 @@ class SloPlane:
         from sentinel_tpu.metrics.timeline import timeline
 
         timeline().record(namespace, n_shed=n)
+
+    def fold(self, namespace: str, now_s: int, lat_counts=None,
+             lat_sum: float = 0.0, lat_max: float = 0.0, lat_rows: int = 0,
+             over: int = 0, shed=(), waited: int = 0) -> None:
+        """One tenant's share of a second that ``ServerMetrics`` counted
+        at deposit, handed over in one call: what :meth:`record`,
+        :meth:`record_shed` and :meth:`record_waited` did once per dispatch.
+        ``lat_counts`` are the served rows by :data:`DECISION_BOUNDS`
+        bucket with their latencies' ``lat_sum`` and ``lat_max``;
+        ``lat_rows`` of them carried a latency, ``over`` one above the
+        objective; ``shed`` is ``(reason, rows)`` pairs of refusal
+        verdicts (the timeline's shed column is the caller's to feed);
+        ``waited`` SHOULD_WAIT rows. The burn windows take it all under
+        ``now_s``, the second it was deposited in."""
+        t = self._tenant(namespace)
+        if lat_counts is not None:
+            t.hist.merge(lat_counts, lat_sum, lat_max)
+        n_shed = 0
+        if shed or waited:
+            with self._lock:
+                for reason, n in shed:
+                    t.shed[reason] = t.shed.get(reason, 0) + n
+                    n_shed += n
+                t.waited += waited
+        if lat_rows or n_shed:
+            for w in t.windows.values():
+                w.record(lat_rows + n_shed, over + n_shed, now_s)
 
     def record_shed_indexed(self, ns_idx, ns_names, reason: str) -> None:
         """Vectorized shed attribution off a ``(ns_idx, ns_names)`` pair
@@ -217,6 +246,7 @@ class SloPlane:
 
     # -- reading ------------------------------------------------------------
     def burn_rates(self, namespace: str) -> Dict[str, Optional[float]]:
+        _fold_pending_accounts()
         t = self._tenants.get(namespace)
         out: Dict[str, Optional[float]] = {}
         for name, _s in _WINDOWS:
@@ -232,6 +262,7 @@ class SloPlane:
     def snapshot(self) -> dict:
         """The ``clusterServerStats``/black-box shape (and
         :func:`merge_fleet` input)."""
+        _fold_pending_accounts()
         with self._lock:
             names = list(self._tenants)
         tenants = {}
@@ -282,6 +313,7 @@ class SloPlane:
 
     def render(self) -> str:
         """Prometheus 0.0.4 exposition of the whole plane."""
+        _fold_pending_accounts()
         lines = [
             "# HELP sentinel_slo_objective_ms Configured per-tenant p99 "
             "latency objective.",
@@ -484,5 +516,6 @@ def slo_plane() -> SloPlane:
 
 def reset_slo_plane_for_tests() -> None:
     global _PLANE
+    _fold_pending_accounts()  # into the plane that goes, not the next one
     with _PLANE_LOCK:
         _PLANE = None

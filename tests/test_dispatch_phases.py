@@ -52,6 +52,10 @@ DISPATCH_SIDE = ("permit_wait_ms", "prep_ms", "lock_wait_ms", "launch_ms")
 READ_SIDE = ("device_wait_ms", "fetch_ms")
 DECIDE_SIDE = READ_SIDE + ("account_ms",)
 LANE_DISPATCHES = 200
+# what lies between the stamps of a stage's phases, per dispatch: 0.015 ms
+# (decide_ms) to 0.037 (dispatch_ms) on an idle sandbox, where a tenth of a
+# 40-row dispatch's 0.15 / 0.37 ms is no more than that
+GLUE_MS = 0.08
 ARMED_DISPATCHES = 50
 
 
@@ -217,8 +221,13 @@ def test_the_phases_reconcile_with_the_stage_they_split(request, run, whole,
     total = sums[whole]
     split = sum(sums[p] for p in parts)
     assert total > 0
-    assert abs(split - total) <= 0.10 * total, (whole, total, {
-        p: sums[p] for p in parts})
+    # a tenth of the whole, or GLUE_MS a dispatch where that is more: the
+    # stamps' own glue, a handful of bytecodes each, does not shrink with
+    # a 40-row dispatch, and under a loaded machine a GIL hand-over lands
+    # between two of them
+    assert abs(split - total) <= max(0.10 * total,
+                                     GLUE_MS * LANE_DISPATCHES), (
+        whole, total, {p: sums[p] for p in parts})
 
 
 def test_the_lane_counts_every_dispatch_after_its_reply(lane_run, whole_run):

@@ -260,8 +260,7 @@ class TestSingletonAndFeed:
             t = tl_sums.setdefault(s.namespace, [0, 0])
             t[0] += s.passed
             t[1] += s.blocked
-        with m._verdict_lock:
-            counters = dict(m._verdicts)
+        counters = m.verdict_totals()
         for ns in ("a", "b", "c"):
             assert tl_sums[ns][0] == counters.get(("pass", ns), 0)
             assert tl_sums[ns][1] == counters.get(("block", ns), 0)

@@ -561,8 +561,24 @@ constexpr int kLanes = 16;     // lookups in flight together: a binary search
 constexpr int kDigitBits = 11; // radix digit: 2048 counters a pass
 constexpr int32_t kFlagPrioritized = 1, kFlagValid = 2;  // decide.FLAG_*
 
-// slots[i] = tab[j] where keys[j] == ids[i], else -1: the leftmost j with
-// keys[j] >= ids[i], as np.searchsorted(keys, ids) finds it.
+// pos[l] = the leftmost j with keys[j] >= vals[l] (n_keys where there is
+// none), as np.searchsorted(keys, vals) finds it: of `lanes` <= kLanes
+// values in n_keys >= 1 ascending keys.
+inline void lower_bounds(const int64_t *keys, int64_t n_keys,
+                         const int64_t *vals, int lanes, int64_t *pos) {
+  for (int l = 0; l < lanes; ++l) pos[l] = 0;
+  // branchless, all lanes one halving at a time
+  for (int64_t len = n_keys; len > 1;) {
+    const int64_t half = len >> 1;
+    for (int l = 0; l < lanes; ++l)
+      pos[l] += keys[pos[l] + half - 1] < vals[l] ? half : 0;
+    len -= half;
+  }
+  // pos is the last candidate; one more step where it is still below
+  for (int l = 0; l < lanes; ++l) pos[l] += keys[pos[l]] < vals[l] ? 1 : 0;
+}
+
+// slots[i] = tab[j] where keys[j] == ids[i], else -1.
 void lookup_slots(const int64_t *keys, const int32_t *tab, int64_t n_keys,
                   const int64_t *ids, int64_t n, int32_t *slots) {
   if (n_keys == 0) {
@@ -571,18 +587,10 @@ void lookup_slots(const int64_t *keys, const int32_t *tab, int64_t n_keys,
   }
   for (int64_t at = 0; at < n; at += kLanes) {
     const int lanes = int(n - at < kLanes ? n - at : kLanes);
-    int64_t lo[kLanes];
-    for (int l = 0; l < lanes; ++l) lo[l] = 0;
-    // branchless lower bound, all lanes one halving at a time
-    for (int64_t len = n_keys; len > 1;) {
-      const int64_t half = len >> 1;
-      for (int l = 0; l < lanes; ++l)
-        lo[l] += keys[lo[l] + half - 1] < ids[at + l] ? half : 0;
-      len -= half;
-    }
+    int64_t pos[kLanes];
+    lower_bounds(keys, n_keys, ids + at, lanes, pos);
     for (int l = 0; l < lanes; ++l) {
-      // lo is the last candidate; one more step where it is still below
-      const int64_t j = lo[l] + (keys[lo[l]] < ids[at + l] ? 1 : 0);
+      const int64_t j = pos[l];
       slots[at + l] = j < n_keys && keys[j] == ids[at + l] ? tab[j] : -1;
     }
   }
@@ -680,4 +688,127 @@ SN_EXPORT int32_t sn_flow_prep(const int64_t *keys, const int32_t *tab,
   if (zero_head)
     std::memset(packed + 3 * stride, 0, size_t(width) * sizeof(int32_t));
   return int32_t(ascending) | int32_t(a_lo == a_hi) << 1;
+}
+
+// ---------------------------------------------------------------------------
+// Param prep (cluster/token_service.py, phase `prep`, the hot-parameter
+// lane): one chunk of whole requests (n requests of k value hashes each)
+// into the param step's packed host argument (engine/param.py: lines
+// ROW_SLOT / ROW_ACQUIRE / ROW_THRESHOLD, `depth` index lines, the slim
+// twin's, the head line) in ONE call with the GIL released. The contract is
+// byte identity with the numpy path that stays as fallback and reference:
+// `DefaultTokenService._param_rows` (three np.searchsorted with their clamp
+// and equality test, `hash_indices` for the sketch and for the slim twin)
+// and `pack_param_rows`. Every array is the caller's.
+
+namespace {
+
+// engine/param.py `hash_indices`: splitmix64's finaliser on hash + lane
+// constant, modulo the width, all in wrapping uint64
+constexpr uint64_t kMix = 0x9E3779B97F4A7C15ull;
+constexpr uint64_t kFin1 = 0xBF58476D1CE4E5B9ull;
+constexpr uint64_t kFin2 = 0x94D049BB133111EBull;
+
+struct CellLanes {  // one sketch's index lines: `depth` lanes from `salt`
+  int32_t *line;    // the first of them in the packed argument
+  int depth;
+  uint64_t salt, width, mask;  // mask: width - 1 where a power of two, else 0
+
+  CellLanes(int32_t *first, int d, int64_t s, int64_t w)
+      : line(first), depth(d), salt(uint64_t(s)), width(uint64_t(w)),
+        mask((width & (width - 1)) == 0 ? width - 1 : 0) {}
+
+  void write(uint64_t h, int64_t col, int64_t stride) const {
+    for (int j = 0; j < depth; ++j) {
+      uint64_t x = h + (salt + uint64_t(j) + 1) * kMix;
+      x = (x ^ (x >> 30)) * kFin1;
+      x = (x ^ (x >> 27)) * kFin2;
+      x ^= x >> 31;
+      line[j * stride + col] = int32_t(mask ? x & mask : x % width);
+    }
+  }
+};
+
+inline int32_t float_bits(const float *p) {
+  int32_t bits;
+  std::memcpy(&bits, p, sizeof bits);
+  return bits;
+}
+
+}  // namespace
+
+// The look-up snapshot of `_param_tables`: fids[n_rules] ascending with
+// their slots and counts; item_hashes[n_hashes] ascending and unique;
+// item_keys[n_items] ascending (slot * n_hashes + rank of the hash) with
+// item_thr. The chunk: flow_ids[n], acq[n], hashes[n * k] request-major,
+// n * k <= bucket, bucket >= 3. Writes req_slot[n] (-1: no rule) and every
+// cell of packed[(4 + depth + slim_depth) * bucket]: a (request, value) row
+// a column, slot -1 and zeros beyond the last row, the head line
+// (0, k, n, 0...): the caller writes the clock. slim_depth 0: no slim lines.
+SN_EXPORT void sn_param_prep(
+    const int64_t *fids, const int32_t *slots, const float *counts,
+    int64_t n_rules, const int64_t *item_hashes, int64_t n_hashes,
+    const int64_t *item_keys, const float *item_thr, int64_t n_items,
+    const int64_t *flow_ids, const int32_t *acq, const int64_t *hashes,
+    int64_t n, int64_t k, int64_t bucket, int32_t depth, int64_t width,
+    int32_t slim_depth, int64_t slim_width, int64_t slim_salt,
+    int32_t *req_slot, int32_t *packed) {
+  const int64_t rows = n * k;
+  int32_t *p_slot = packed, *p_acq = packed + bucket;
+  int32_t *p_thr = packed + 2 * bucket;
+  const CellLanes fat(packed + 3 * bucket, depth, 0, width);
+  const CellLanes slim(packed + (3 + depth) * bucket, slim_depth, slim_salt,
+                       slim_width);
+  // per request: its rule's slot, and that slot, the acquire and the rule's
+  // count on each of its k columns
+  for (int64_t at = 0; at < n; at += kLanes) {
+    const int lanes = int(n - at < kLanes ? n - at : kLanes);
+    int64_t pos[kLanes];
+    if (n_rules) lower_bounds(fids, n_rules, flow_ids + at, lanes, pos);
+    for (int l = 0; l < lanes; ++l) {
+      const int64_t i = at + l, j = n_rules ? pos[l] : 0;
+      const bool found = j < n_rules && fids[j] == flow_ids[i];
+      const int32_t slot = found ? slots[j] : -1;
+      const int32_t count = found ? float_bits(counts + j) : 0;
+      req_slot[i] = slot;
+      for (int64_t c = i * k; c < i * k + k; ++c) {
+        p_slot[c] = slot;
+        p_acq[c] = acq[i];
+        p_thr[c] = count;
+      }
+    }
+  }
+  // per row: the item's threshold where (slot, hash) is an item, and the
+  // sketch's cells
+  for (int64_t at = 0; at < rows; at += kLanes) {
+    const int lanes = int(rows - at < kLanes ? rows - at : kLanes);
+    if (n_items) {
+      int64_t rank[kLanes], key[kLanes], pos[kLanes];
+      lower_bounds(item_hashes, n_hashes, hashes + at, lanes, rank);
+      for (int l = 0; l < lanes; ++l)
+        key[l] = int64_t(p_slot[at + l]) * n_hashes + rank[l];
+      lower_bounds(item_keys, n_items, key, lanes, pos);
+      for (int l = 0; l < lanes; ++l) {
+        const bool known =
+            rank[l] < n_hashes && item_hashes[rank[l]] == hashes[at + l];
+        if (known && p_slot[at + l] >= 0 && pos[l] < n_items &&
+            item_keys[pos[l]] == key[l])
+          p_thr[at + l] = float_bits(item_thr + pos[l]);
+      }
+    }
+    for (int l = 0; l < lanes; ++l) {
+      const uint64_t h = uint64_t(hashes[at + l]);
+      fat.write(h, at + l, bucket);
+      slim.write(h, at + l, bucket);
+    }
+  }
+  const int64_t lines = 4 + depth + slim_depth;
+  for (int64_t c = rows; c < bucket; ++c) p_slot[c] = -1;
+  for (int64_t line = 1; line < lines - 1; ++line)
+    std::memset(packed + line * bucket + rows, 0,
+                size_t(bucket - rows) * sizeof(int32_t));
+  int32_t *head = packed + (lines - 1) * bucket;
+  std::memset(head, 0, size_t(bucket) * sizeof(int32_t));
+  head[1] = int32_t(k);
+  head[2] = int32_t(n);
 }

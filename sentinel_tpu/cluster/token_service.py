@@ -70,6 +70,7 @@ from sentinel_tpu.engine.param import (
     make_param_state,
     make_param_step,
     pack_param_rows,
+    prep_geometry,
 )
 from sentinel_tpu.engine.rules import RuleIndex
 from sentinel_tpu.metrics.server import server_metrics
@@ -514,6 +515,7 @@ class DefaultTokenService(TokenService):
         self.namespace_set: set = set()
         # hot-param sketch path (ClusterParamFlowChecker analog)
         self.param_config = param_config or ParamConfig()
+        self._param_geometry = prep_geometry(self.param_config)
         self._param_state = make_param_state(self.param_config)
         self._param_rules: Dict[int, Tuple[int, float, Dict[int, float]]] = {}
         self._param_free = list(range(self.param_config.max_param_rules - 1, -1, -1))
@@ -1340,12 +1342,13 @@ class DefaultTokenService(TokenService):
         """One dispatch left the service lock: its three dispatch-side
         phases (``monotonic_ns`` stamps of entry, prep done, lock acquired)
         go to the always-on histograms and, armed, to the flight recorder
-        with the same stamps. ``native_prep``: a flow dispatch whose prep
-        was the native pass (``prep_native_total``)."""
+        with the same stamps. ``native_prep``: a dispatch whose prep was
+        the native pass (``prep_native_total``, of the flow lane;
+        ``param_prep_native_total``, of the hot-parameter lane)."""
         t_out = time.monotonic_ns()
         _SM.prep_ms.record((t_prep - t_enter) * 1e-6)
         if native_prep:
-            _SM.count_prep_native()
+            _SM.count_prep_native(param=lane == _TR.PARAM_LANE)
         _SM.lock_wait_ms.record((t_locked - t_prep) * 1e-6)
         _SM.launch_ms.record((t_out - t_locked) * 1e-6)
         if _TR.ARMED:
@@ -1895,28 +1898,49 @@ class DefaultTokenService(TokenService):
                 return np.zeros(n, np.int8), zero, zero
 
             return _trivial
-        acq = np.broadcast_to(np.asarray(acquires, np.int32), (n,))
+        acq = np.asarray(acquires, np.int32)
+        if acq.shape != (n,):  # one acquire for every request
+            acq = np.broadcast_to(acq, (n,))
         cfg = self.param_config
         per = max(1, self._serve_buckets[-1] // k)  # requests per chunk
-        cuts = list(range(0, n, per))
+        chunks = []  # (lo, hi, serve bucket) of whole requests
+        for lo in range(0, n, per):
+            hi = min(n, lo + per)
+            chunks.append((lo, hi, self._param_bucket((hi - lo) * k)))
 
-        def prep(lookup):
+        def prep_numpy(lookup):
             req_slot, row_slot, row_acq, thr, idx, idx_slim = (
                 self._param_rows(lookup, cfg, flow_ids, acq, hashes)
             )
             packs = []
-            for lo in cuts:
-                hi = min(n, lo + per)
+            for lo, hi, bucket in chunks:
                 r = slice(lo * k, hi * k)
-                bucket = self._param_bucket((hi - lo) * k)
                 packs.append((bucket, hi - lo, pack_param_rows(
                     cfg, bucket, row_slot[r], row_acq[r], thr[r], idx[r],
                     None if idx_slim is None else idx_slim[r], 0, k, hi - lo,
                 )))
-            return req_slot, packs
+            return req_slot, packs, False
+
+        def prep(lookup):
+            """``(req_slot, [(bucket, requests, packed)], native)``: a
+            chunk a call of the native pass, each ``packed`` a fresh array
+            that only this dispatch holds (the ownership rule of
+            ``dispatch_batch_arrays``)."""
+            slots, packs = [], []
+            for lo, hi, bucket in chunks:
+                done = _native.param_prep(
+                    lookup, flow_ids[lo:hi], acq[lo:hi], hashes[lo:hi],
+                    bucket, self._param_geometry,
+                )
+                if done is None:  # the library is not built: the same in numpy
+                    return prep_numpy(lookup)
+                slots.append(done[0])
+                packs.append((bucket, hi - lo, done[1]))
+            req_slot = slots[0] if len(slots) == 1 else np.concatenate(slots)
+            return req_slot, packs, True
 
         lookup = self._param_lookup
-        req_slot, packs = prep(lookup)
+        req_slot, packs, native_prep = prep(lookup)
         steps = [self._param_step_fn(b) for b, _m, _p in packs]
         t_prep = time.monotonic_ns()
         with self._lock:
@@ -1925,7 +1949,7 @@ class DefaultTokenService(TokenService):
             if self._param_lookup is not lookup:
                 # param rules reloaded between prep and step: redo the
                 # slot-dependent prep against the live tables
-                req_slot, packs = prep(self._param_lookup)
+                req_slot, packs, native_prep = prep(self._param_lookup)
             now = self._engine_now()
             outs = []
             for step, (_b, _m, packed) in zip(steps, packs):
@@ -1939,7 +1963,7 @@ class DefaultTokenService(TokenService):
         for verdicts in outs:
             verdicts.copy_to_host_async()
         self._dispatched(t_enter, t_prep, t_locked, seq, n * k,
-                         lane=_TR.PARAM_LANE)
+                         lane=_TR.PARAM_LANE, native_prep=native_prep)
 
         def _read():
             t_mat = time.monotonic_ns()

@@ -335,6 +335,17 @@ def packed_lines(config: ParamConfig) -> int:
     return ROW_IDX + config.depth + slim + 1
 
 
+def prep_geometry(config: ParamConfig) -> tuple:
+    """``(depth, cell_width, slim_depth, slim_width, slim_salt)``: what the
+    host prep of a batch takes from the config (``native.lib.param_prep``);
+    ``slim_depth`` 0 where the twin is off."""
+    from sentinel_tpu.sketch.slim import SLIM_SALT
+
+    slim = config.slim_depth if config.slim_enabled else 0
+    return (config.depth, config.cell_width, slim, config.slim_width,
+            SLIM_SALT)
+
+
 def pack_param_rows(config: ParamConfig, bucket: int, slots, acquires,
                     thresholds, idx, idx_slim, now: int, k: int,
                     n_requests: int) -> np.ndarray:

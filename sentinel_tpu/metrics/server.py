@@ -428,8 +428,10 @@ class ServerMetrics:
         self._verdict_copy_ready = 0
         # dispatches whose account half ran after their reply was submitted
         self._reply_first = 0
-        # flow dispatches whose host prep was the native pass
+        # flow dispatches whose host prep was the native pass, and the
+        # hot-parameter lane's
         self._prep_native = 0
+        self._param_prep_native = 0
         self._verdict_read_lock = threading.Lock()
         # traffic-shaping waits: every SHOULD_WAIT verdict that carried a
         # positive wait hint (paced admission or priority occupy) — count
@@ -559,18 +561,27 @@ class ServerMetrics:
         with self._verdict_read_lock:
             return self._reply_first
 
-    def count_prep_native(self) -> None:
+    def count_prep_native(self, param: bool = False) -> None:
         """One flow dispatch was prepped by the native pass
-        (``sn_flow_prep``). A dispatch prepped in numpy, because the library
-        is not built, does not count: over ``prep_ms``'s count this says
-        whether the mechanism engaged."""
+        (``sn_flow_prep``), or with ``param`` one hot-parameter dispatch
+        (``sn_param_prep``). A dispatch prepped in numpy, because the
+        library is not built, does not count: over ``prep_ms``'s count this
+        says whether the mechanism engaged."""
         with self._verdict_read_lock:
-            self._prep_native += 1
+            if param:
+                self._param_prep_native += 1
+            else:
+                self._prep_native += 1
 
     @property
     def prep_native_total(self) -> int:
         with self._verdict_read_lock:
             return self._prep_native
+
+    @property
+    def param_prep_native_total(self) -> int:
+        with self._verdict_read_lock:
+            return self._param_prep_native
 
     def count_param_dispatch(self, requests: int, values: int, blocked: int,
                              no_rule: int) -> None:
@@ -1284,6 +1295,7 @@ class ServerMetrics:
             "verdictCopyReadyTotal": self.verdict_copy_ready_total,
             "replyFirstTotal": self.reply_first_total,
             "prepNativeTotal": self.prep_native_total,
+            "paramPrepNativeTotal": self.param_prep_native_total,
             "accountFoldsTotal": self.account_folds_total,
             "shedTotal": self.shed_total,
             "shedByReason": self.shed_totals(),
@@ -1371,6 +1383,7 @@ class ServerMetrics:
         out.update(self.concurrent_totals())
         out["reply_first_total"] = self.reply_first_total
         out["prep_native_total"] = self.prep_native_total
+        out["param_prep_native_total"] = self.param_prep_native_total
         out["account_folds_total"] = self.account_folds_total
         out["param_impl"], out["param_impl_reason"] = self.param_impl
         out["shed_total"] = self.shed_totals()
@@ -1783,6 +1796,10 @@ class ServerMetrics:
              "prep_ms's count a shortfall says the library is not built "
              "and numpy prepped them (cumulative).",
              self.prep_native_total),
+            ("param_prep_native_total",
+             "Hot-parameter dispatches whose host prep was the native pass; "
+             "beside param_dispatch_total, as prep_native_total "
+             "(cumulative).", self.param_prep_native_total),
             ("account_folds_total",
              "Per-namespace fan-outs of the verdict accounting: dispatches "
              "are counted at deposit and folded into the verdict counters, "
@@ -1850,6 +1867,7 @@ class ServerMetrics:
             self._verdict_copy_ready = 0
             self._reply_first = 0
             self._prep_native = 0
+            self._param_prep_native = 0
         with self._param_lock:
             self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
             self._param_single = dict.fromkeys(

@@ -222,6 +222,16 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         lib._sn_has_flow_prep = True
     except AttributeError:
         lib._sn_has_flow_prep = False
+    # param prep (sentinel_native.cpp), as flow prep
+    try:
+        lib.sn_param_prep.argtypes = [
+            P, P, P, I64, P, I64, P, P, I64, P, P, P, I64, I64, I64, I32,
+            I64, I32, I64, I64, P, P,
+        ]
+        lib.sn_param_prep.restype = None
+        lib._sn_has_param_prep = True
+    except AttributeError:
+        lib._sn_has_param_prep = False
     return lib
 
 
@@ -344,6 +354,49 @@ def flow_prep(snapshot, flow_ids, acq, pr, width: int, out=None):
         order.ctypes.data, packed.ctypes.data,
     )
     return slots, None if flags & 1 else order, packed, bool(flags & 2)
+
+
+def param_prep(snapshot, flow_ids, acq, hashes, bucket: int, geometry):
+    """One chunk of whole hot-parameter requests (``hashes int64[n, k]``)
+    prepped in ONE native pass with the GIL released (``sn_param_prep``):
+    the rule and item look-ups in ``snapshot`` (the token service's
+    ``_param_tables``: six C-contiguous arrays), the sketch's cell indices
+    and the param step's packed argument. ``geometry`` is ``(depth,
+    cell_width, slim_depth, slim_width, slim_salt)`` of the service's
+    ``ParamConfig``, ``slim_depth`` 0 where the twin is off. Returns
+    ``(req_slot int32[n], packed)``, byte for byte what
+    ``DefaultTokenService._param_rows`` + ``engine.param.pack_param_rows``
+    give (``tests/test_native_param_prep.py``); ``packed`` is a fresh
+    ``int32[4 + depth + slim_depth, bucket]`` with the clock at 0 that only
+    the caller holds. None where the library is absent or older than this
+    entry: the caller preps in numpy."""
+    lib = load()
+    if lib is None or not lib._sn_has_param_prep:
+        return None
+    import numpy as np
+
+    fids, slots, counts, item_hashes, item_keys, item_thr = snapshot
+    flow_ids = np.ascontiguousarray(flow_ids, np.int64)
+    acq = np.ascontiguousarray(acq, np.int32)
+    hashes = np.ascontiguousarray(hashes, np.int64)
+    n, k = hashes.shape
+    depth, width, slim_depth, slim_width, slim_salt = geometry
+    if flow_ids.shape != (n,) or acq.shape != (n,) or not (
+            0 < n * k <= bucket and bucket >= 3):
+        raise ValueError(
+            f"param_prep: {flow_ids.shape} ids, {acq.shape} acquires and "
+            f"{hashes.shape} hashes do not make a chunk of bucket {bucket}")
+    req_slot = np.empty(n, np.int32)
+    packed = np.empty((4 + depth + slim_depth, bucket), np.int32)
+    lib.sn_param_prep(
+        fids.ctypes.data, slots.ctypes.data, counts.ctypes.data,
+        fids.shape[0], item_hashes.ctypes.data, item_hashes.shape[0],
+        item_keys.ctypes.data, item_thr.ctypes.data, item_keys.shape[0],
+        flow_ids.ctypes.data, acq.ctypes.data, hashes.ctypes.data, n, k,
+        bucket, depth, width, slim_depth, slim_width, slim_salt,
+        req_slot.ctypes.data, packed.ctypes.data,
+    )
+    return req_slot, packed
 
 
 def batch_decode_req(payload: bytes):

@@ -563,4 +563,5 @@ def test_the_manifest_entry_agrees_with_the_reader_file():
         m["unit"], m["layer"], m["moves"], m["source"])
     assert m["better"] == "higher"
     assert m["workloads"] == by_name["service.native_prep_share"]["workloads"]
-    assert doc["per_layer"][-1] is m  # appended, nothing moved
+    # appended by PR 46, nothing moved: what later PRs add comes after
+    assert doc["per_layer"][62] is m

@@ -78,13 +78,18 @@ from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
 from sentinel_tpu import chaos as _chaos
 from sentinel_tpu.cluster import protocol as P
+from sentinel_tpu.cluster.state_codec import COLUMNS
 from sentinel_tpu.core import clock as _clock
 from sentinel_tpu.core.log import record_log
 from sentinel_tpu.core.property import DynamicProperty
+from sentinel_tpu.engine.state import MOVE
 from sentinel_tpu.ha.snapshot import _dec_array, _enc_array
 from sentinel_tpu.metrics.ha import ha_metrics
 
 MOVE_STATE_VERSION = 1
+# export_namespace_state keys holding numpy arrays: the key of every state
+# column a MOVE carries
+_ARRAY_KEYS = tuple(c.move_key for c in COLUMNS if MOVE in c.docs)
 
 
 # -- shard map ----------------------------------------------------------------
@@ -225,29 +230,14 @@ def encode_move_state_blob(doc: Dict[str, object]) -> bytes:
             for r in doc["param_rules"]
         ],
         "flow_ids": [int(f) for f in doc["flow_ids"]],
-        "flow_sums": _enc_array(doc["flow_sums"]),
-        "occupy_sums": _enc_array(doc["occupy_sums"]),
-        "ns_sum": _enc_array(doc["ns_sum"]),
         "param_fids": [int(f) for f in doc["param_fids"]],
-        "param_sums": _enc_array(doc["param_sums"]),
+        # the state columns a MOVE carries (an export from before a family
+        # has no key for it — the destination then starts those flows cold)
+        **{k: _enc_array(doc[k]) for k in _ARRAY_KEYS if k in doc},
     }
-    # shaper clocks (relative-to-export-now; absent in pre-shaping exports)
-    for k in (
-        "shaping_lpt_rel", "shaping_warm_tokens", "shaping_warm_filled_rel"
-    ):
-        if k in doc:
-            out[k] = _enc_array(doc[k])
-    # the breaker plane: its rules, the moved flows' completion windows,
-    # and the state columns with relative clocks (absent in pre-breaker
-    # exports — the destination then starts those flows CLOSED/cold)
+    # the breaker plane's rules move with its state columns
     if doc.get("degrade_rules"):
         out["degrade_rules"] = [_enc_degrade(d) for d in doc["degrade_rules"]]
-    for k in (
-        "outcome_sums", "breaker_state",
-        "breaker_opened_rel", "breaker_probe_rel",
-    ):
-        if k in doc:
-            out[k] = _enc_array(doc[k])
     return zlib.compress(json.dumps(out, separators=(",", ":")).encode())
 
 
@@ -279,24 +269,8 @@ def decode_move_state_blob(blob: bytes) -> Dict[str, object]:
                 for r in out["param_rules"]
             ],
             "flow_ids": [int(f) for f in out["flow_ids"]],
-            "flow_sums": _dec_array(out["flow_sums"]),
-            "occupy_sums": _dec_array(out["occupy_sums"]),
-            "ns_sum": _dec_array(out["ns_sum"]),
             "param_fids": [int(f) for f in out["param_fids"]],
-            "param_sums": _dec_array(out["param_sums"]),
-            **{
-                k: _dec_array(out[k])
-                for k in (
-                    "shaping_lpt_rel",
-                    "shaping_warm_tokens",
-                    "shaping_warm_filled_rel",
-                    "outcome_sums",
-                    "breaker_state",
-                    "breaker_opened_rel",
-                    "breaker_probe_rel",
-                )
-                if k in out
-            },
+            **{k: _dec_array(out[k]) for k in _ARRAY_KEYS if k in out},
             **(
                 {
                     "degrade_rules": [

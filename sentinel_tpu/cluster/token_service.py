@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sentinel_tpu import chaos as _chaos
+from sentinel_tpu.cluster import state_codec
 from sentinel_tpu.core import clock as _clock
 from sentinel_tpu.core import compile_cache as _compile_cache
 from sentinel_tpu.core.log import record_log
@@ -833,10 +834,7 @@ class DefaultTokenService(TokenService):
             # replication sender into a full-snapshot resync
             self._state_gen += 1
             if self._dirty is not None:
-                self._dirty = {
-                    "flow": set(), "param": set(), "param_fat": set(),
-                    "outcome": set(), "breaker": set(),
-                }
+                self._dirty = state_codec.fresh_dirty()
             # leases pin flow_id → slot; a reload may have reassigned the
             # slot or dropped the rule, so re-resolve every outstanding
             # lease and revoke those whose rule vanished (their LEASED
@@ -1003,56 +1001,8 @@ class DefaultTokenService(TokenService):
             self._epoch_ms = wall - 1  # keep engine time strictly positive
         now = wall - self._epoch_ms
         if now > self._REBASE_AFTER_MS:
-            from sentinel_tpu.engine.param import NEVER as _PNEVER
-            from sentinel_tpu.stats.window import rebase
-
-            from sentinel_tpu.stats.window import NEVER as _WNEVER
-
             delta = now - 60_000  # keep the last minute of history addressable
-            shp = self._state.shaping
-            brk = self._state.breaker
-            d32 = jnp.int32(delta)
-            self._state = EngineState(
-                flow=rebase(self._state.flow, delta),
-                occupy=rebase(self._state.occupy, delta),
-                ns=rebase(self._state.ns, delta),
-                # the shaper clocks are engine-ms too; NEVER stays NEVER
-                shaping=shp._replace(
-                    lpt=jnp.where(shp.lpt == _WNEVER, shp.lpt, shp.lpt - d32),
-                    warm_filled=jnp.where(
-                        shp.warm_filled == _WNEVER,
-                        shp.warm_filled,
-                        shp.warm_filled - d32,
-                    ),
-                ),
-                outcome=rebase(self._state.outcome, delta),
-                # breaker fence/ticket clocks share the engine epoch; the
-                # state column is epoch-free and passes through untouched
-                breaker=brk._replace(
-                    opened_ms=jnp.where(
-                        brk.opened_ms == _WNEVER,
-                        brk.opened_ms,
-                        brk.opened_ms - d32,
-                    ),
-                    probe_ms=jnp.where(
-                        brk.probe_ms == _WNEVER,
-                        brk.probe_ms,
-                        brk.probe_ms - d32,
-                    ),
-                ),
-            )
-            # the param sketch's starts are engine-ms too
-            pstarts = self._param_state.starts
-            self._param_state = self._param_state._replace(
-                starts=jnp.where(
-                    pstarts == _PNEVER, pstarts, pstarts - jnp.int32(delta)
-                )
-            )
-            if self._conc is not None:
-                # a token's expiry is engine-ms too (a free slot's is unread)
-                cs = self._conc.state
-                self._conc.state = cs._replace(
-                    tok_expire=cs.tok_expire - jnp.int32(delta))
+            state_codec.rebase(self, delta)
             self._epoch_ms += delta
             now -= delta
         return now
@@ -1697,10 +1647,7 @@ class DefaultTokenService(TokenService):
             # invalidate any delta collected against the old generation
             self._state_gen += 1
             if self._dirty is not None:
-                self._dirty = {
-                    "flow": set(), "param": set(), "param_fat": set(),
-                    "outcome": set(), "breaker": set(),
-                }
+                self._dirty = state_codec.fresh_dirty()
 
     def load_namespace_param_rules(
         self, namespace: str, rules: List[ClusterParamFlowRule]
@@ -2788,156 +2735,13 @@ class DefaultTokenService(TokenService):
         """The *slim* representation of one namespace for a live move: its
         rules plus per-row **live-window sums** (flow/occupy event sums, the
         namespace guard row, and the param CMS cells), not the raw ring
-        buckets. Sums are ring- and epoch-free, so the destination can fold
-        them into its OWN current bucket regardless of clock skew or ring
-        phase — the fat-update/slim-query split of SF-sketch applied to the
-        handoff (ISSUE 8). Rules come back as rule objects; the rebalance
-        codec serializes them."""
-        from sentinel_tpu.engine.state import flow_spec
-        from sentinel_tpu.stats import window as W
-
-        with self._rules_mutex, self._lock:
-            rules = list(self._rules_by_ns.get(namespace, {}).values())
-            param_rules = [
-                r for r in self._param_rules_src.values()
-                if r.namespace == namespace
-            ]
-            now = self._engine_now()
-            spec = flow_spec(self.config)
-            fsum = np.asarray(
-                W.window_sum_all(spec, self._state.flow, jnp.int32(now))
-            )
-            osum = np.asarray(
-                W.window_sum_all(spec, self._state.occupy, jnp.int32(now))
-            )
-            nsum = np.asarray(
-                W.window_sum_all(spec, self._state.ns, jnp.int32(now))
-            )
-            # completion-outcome columns move with the flow like the shaper
-            # clocks from PR 15: live-window sums fold into the destination's
-            # current bucket, so RT/exception telemetry (and the breakers it
-            # will feed) survives a MOVE without a ring-phase contract
-            outsum = np.asarray(
-                W.window_sum_all(spec, self._state.outcome, jnp.int32(now))
-            )
-            from sentinel_tpu.stats.window import NEVER as _WNEVER
-
-            lpt_h = np.asarray(self._state.shaping.lpt)
-            wtok_h = np.asarray(self._state.shaping.warm_tokens)
-            wfill_h = np.asarray(self._state.shaping.warm_filled)
-            # breaker columns move with the flow like the shaper clocks: an
-            # OPEN breaker must stay OPEN at the destination, its recovery
-            # clock re-anchored to the destination's epoch
-            br_st_h = np.asarray(self._state.breaker.state)
-            br_op_h = np.asarray(self._state.breaker.opened_ms)
-            br_pr_h = np.asarray(self._state.breaker.probe_ms)
-            degrade_rules = [
-                d for d in self._degrade_rules_src.values()
-                if d.namespace == namespace
-            ]
-            flow_ids: List[int] = []
-            frows: List[np.ndarray] = []
-            orows: List[np.ndarray] = []
-            outrows: List[np.ndarray] = []
-            lpt_rel: List[int] = []
-            wtok_rows: List[float] = []
-            wfill_rel: List[int] = []
-            br_state_rows: List[int] = []
-            br_opened_rel: List[int] = []
-            br_probe_rel: List[int] = []
-
-            def _rel(v: int) -> int:
-                return int(_WNEVER) if v == int(_WNEVER) else int(v) - now
-
-            # breaker-only flows (a DegradeRule with no flow rule) still own
-            # a slot and breaker state; walk the union so they move too
-            exported = {r.flow_id for r in rules}
-            movers = list(rules) + [
-                d for d in degrade_rules if d.flow_id not in exported
-            ]
-            for r in movers:
-                slot = self._index.slot_of.get(r.flow_id)
-                if slot is None:
-                    continue
-                flow_ids.append(int(r.flow_id))
-                frows.append(fsum[slot])
-                orows.append(osum[slot])
-                outrows.append(outsum[slot])
-                # shaper clocks ship RELATIVE to now — the destination's
-                # engine epoch is its own; NEVER stays NEVER
-                lpt_rel.append(_rel(int(lpt_h[slot])))
-                wtok_rows.append(float(wtok_h[slot]))
-                wfill_rel.append(_rel(int(wfill_h[slot])))
-                br_state_rows.append(int(br_st_h[slot]))
-                br_opened_rel.append(_rel(int(br_op_h[slot])))
-                br_probe_rel.append(_rel(int(br_pr_h[slot])))
-            row = self._index.ns_of.get(namespace)
-            doc: Dict[str, object] = {
-                "namespace": namespace,
-                "wall_ms": int(_clock.now_ms()),
-                "interval_ms": int(spec.interval_ms),
-                "rules": rules,
-                "param_rules": param_rules,
-                "flow_ids": flow_ids,
-                "flow_sums": (
-                    np.stack(frows) if frows
-                    else np.zeros((0, fsum.shape[1]), fsum.dtype)
-                ),
-                "occupy_sums": (
-                    np.stack(orows) if orows
-                    else np.zeros((0, osum.shape[1]), osum.dtype)
-                ),
-                "outcome_sums": (
-                    np.stack(outrows) if outrows
-                    else np.zeros((0, outsum.shape[1]), outsum.dtype)
-                ),
-                "ns_sum": (
-                    np.array(nsum[row]) if row is not None
-                    else np.zeros(nsum.shape[1], nsum.dtype)
-                ),
-                "shaping_lpt_rel": np.asarray(lpt_rel, np.int64),
-                "shaping_warm_tokens": np.asarray(wtok_rows, np.float32),
-                "shaping_warm_filled_rel": np.asarray(wfill_rel, np.int64),
-                "degrade_rules": degrade_rules,
-                "breaker_state": np.asarray(br_state_rows, np.int8),
-                "breaker_opened_rel": np.asarray(br_opened_rel, np.int64),
-                "breaker_probe_rel": np.asarray(br_probe_rel, np.int64),
-            }
-            # param sketch: per-slot live-window cell sums [depth, cells] —
-            # summed over DECODED cells (sketch.decoded_counts_np), so the
-            # wire document is plain int sums whatever the in-memory
-            # encoding (int32 cms or int16 SALSA pairs). The sketch is
-            # linear over decoded values, so summing live buckets preserves
-            # every estimate the destination will read.
-            from sentinel_tpu.sketch import decoded_counts_np
-
-            pfids: List[int] = []
-            prows: List[np.ndarray] = []
-            if param_rules:
-                pstarts = np.asarray(self._param_state.starts)
-                pcounts = decoded_counts_np(
-                    self.param_config, self._param_state.counts
-                )
-                age = now - pstarts
-                live = (age >= 0) & (age < self.param_config.interval_ms)
-                for r in param_rules:
-                    entry = self._param_rules.get(r.flow_id)
-                    if entry is None:
-                        continue
-                    pfids.append(int(r.flow_id))
-                    prows.append(
-                        pcounts[entry[0], live].sum(axis=0).astype(np.int64)
-                    )
-            doc["param_fids"] = pfids
-            doc["param_sums"] = (
-                np.stack(prows) if prows
-                else np.zeros(
-                    (0, self.param_config.depth,
-                     self.param_config.cell_width),
-                    np.int64,
-                )
-            )
-            return doc
+        buckets, and the shaper and breaker clocks relative to now. Sums
+        are ring- and epoch-free, so the destination can fold them into its
+        OWN current bucket regardless of clock skew or ring phase — the
+        fat-update/slim-query split of SF-sketch applied to the handoff
+        (ISSUE 8). Rules come back as rule objects; the rebalance codec
+        serializes them."""
+        return state_codec.export_namespace_state(self, namespace)
 
     def import_namespace_state(self, doc: Dict[str, object]) -> None:
         """Install an :meth:`export_namespace_state` capture into THIS
@@ -2947,228 +2751,16 @@ class DefaultTokenService(TokenService):
         destination's first window sum over an imported row equals the
         source's last — admission resumes exactly where the source
         stopped."""
-        from sentinel_tpu.engine.state import EngineState as _ES
-        from sentinel_tpu.engine.state import flow_spec
-
-        namespace = str(doc["namespace"])
-        rules = list(doc["rules"])
-        param_rules = list(doc["param_rules"])
-        degrade_rules = list(doc.get("degrade_rules", ()))
-        with self._rules_mutex:
-            self.load_namespace_rules(namespace, rules)
-            if degrade_rules:
-                # the namespace's breakers move with it: rules first (slots
-                # + br_* columns), then the state columns re-anchor below
-                self.load_namespace_degrade_rules(namespace, degrade_rules)
-            if param_rules:
-                self.load_namespace_param_rules(namespace, param_rules)
-            with self._lock:
-                now = self._engine_now()
-                spec = flow_spec(self.config)
-                flow_ids = [int(f) for f in doc.get("flow_ids", [])]
-                slots = (
-                    np.asarray(
-                        [self._index.slot_of[f] for f in flow_ids], np.int32
-                    )
-                    if flow_ids else None
-                )
-                flow = self._fold_into_current(
-                    self._state.flow, spec, now, slots, doc["flow_sums"]
-                )
-                occupy = self._fold_into_current(
-                    self._state.occupy, spec, now, slots, doc["occupy_sums"]
-                )
-                # pre-outcome blobs carry no key — moved flows start with an
-                # empty completion window, the conservative default
-                out_sums = doc.get("outcome_sums")
-                outcome = (
-                    self._fold_into_current(
-                        self._state.outcome, spec, now, slots, out_sums
-                    )
-                    if out_sums is not None and slots is not None
-                    else self._state.outcome
-                )
-                row = self._index.ns_of.get(namespace)
-                ns = self._fold_into_current(
-                    self._state.ns, spec, now,
-                    None if row is None else [row],
-                    None if row is None else np.asarray(doc["ns_sum"])[None],
-                )
-                # re-anchor the moved shaper clocks to THIS engine's epoch:
-                # the blob ships them relative to the source's export now
-                # (pre-shaping blobs simply carry no keys — clocks start
-                # cold, the conservative default)
-                shaping = self._state.shaping
-                lpt_rel = doc.get("shaping_lpt_rel")
-                if lpt_rel is not None and flow_ids:
-                    from sentinel_tpu.stats.window import NEVER as _WNEVER
-
-                    lpt_h = np.asarray(shaping.lpt).copy()
-                    wtok_h = np.asarray(shaping.warm_tokens).copy()
-                    wfill_h = np.asarray(shaping.warm_filled).copy()
-                    wtok_in = np.asarray(doc["shaping_warm_tokens"])
-                    wfill_in = np.asarray(doc["shaping_warm_filled_rel"])
-                    lpt_in = np.asarray(lpt_rel)
-                    for i, s in enumerate(np.asarray(slots)):
-                        lpt_h[s] = (
-                            int(_WNEVER) if lpt_in[i] == int(_WNEVER)
-                            else int(np.clip(
-                                now + int(lpt_in[i]), int(_WNEVER), 2**30
-                            ))
-                        )
-                        wtok_h[s] = wtok_in[i]
-                        wfill_h[s] = (
-                            int(_WNEVER) if wfill_in[i] == int(_WNEVER)
-                            else int(np.clip(
-                                now + int(wfill_in[i]), int(_WNEVER), 2**30
-                            ))
-                        )
-                    shaping = shaping._replace(
-                        lpt=jnp.asarray(lpt_h),
-                        warm_tokens=jnp.asarray(wtok_h),
-                        warm_filled=jnp.asarray(wfill_h),
-                    )
-                # re-anchor the moved breaker columns the same way: state
-                # verbatim, clocks shipped relative to the source's export
-                # now (pre-breaker blobs carry no key — breakers start
-                # CLOSED, which only under-protects until the stat window
-                # refills, never over-admits the destination's own flows)
-                breaker = self._state.breaker
-                br_state_in = doc.get("breaker_state")
-                if br_state_in is not None and flow_ids:
-                    from sentinel_tpu.stats.window import NEVER as _WNEVER
-
-                    bst_h = np.asarray(breaker.state).copy()
-                    bop_h = np.asarray(breaker.opened_ms).copy()
-                    bpr_h = np.asarray(breaker.probe_ms).copy()
-                    bst_in = np.asarray(br_state_in)
-                    bop_in = np.asarray(doc["breaker_opened_rel"])
-                    bpr_in = np.asarray(doc["breaker_probe_rel"])
-
-                    def _anchor(rel: int) -> int:
-                        return (
-                            int(_WNEVER) if rel == int(_WNEVER)
-                            else int(np.clip(
-                                now + int(rel), int(_WNEVER), 2**30
-                            ))
-                        )
-
-                    for i, s in enumerate(np.asarray(slots)):
-                        bst_h[s] = bst_in[i]
-                        bop_h[s] = _anchor(int(bop_in[i]))
-                        bpr_h[s] = _anchor(int(bpr_in[i]))
-                    breaker = breaker._replace(
-                        state=jnp.asarray(bst_h),
-                        opened_ms=jnp.asarray(bop_h),
-                        probe_ms=jnp.asarray(bpr_h),
-                    )
-                    # drop the stale transition mirror: the next scan
-                    # re-baselines from CLOSED, so moved-in OPEN breakers
-                    # surface as closed→open edges on the destination
-                    self._breaker_prev = None
-                self._state = self._place_state(
-                    _ES(flow=flow, occupy=occupy, ns=ns, shaping=shaping,
-                        outcome=outcome, breaker=breaker)
-                )
-                pfids = [int(f) for f in doc.get("param_fids", [])]
-                if pfids:
-                    from sentinel_tpu.sketch import fold_param_sums
-
-                    prow = np.asarray(
-                        [self._param_rules[f][0] for f in pfids], np.int32
-                    )
-                    self._param_state = fold_param_sums(
-                        self.param_config, self._param_state, now, prow,
-                        doc["param_sums"],
-                    )
-                    # the fold lands in the FAT sketch only — the slim twin
-                    # never saw the source's touches. Mark the rows for a
-                    # one-shot fat shipment so a delta-fed standby doesn't
-                    # miss the moved-in window (moves are rare; one fat row
-                    # per moved rule, not per tick).
-                    if self._dirty is not None:
-                        self._dirty["param"].update(int(r) for r in prow)
-                        self._dirty.setdefault("param_fat", set()).update(
-                            int(r) for r in prow
-                        )
+        state_codec.import_namespace_state(self, doc)
 
     # -- state snapshot / restore (ha.snapshot backing) ----------------------
     def export_state(self) -> Dict[str, object]:
         """Device→host capture of everything a warm standby needs to resume
-        counting: rule sources, slot assignments, the flow/occupy/ns window
-        tensors, the CMS param sketch, and the engine epoch. Arrays come
-        back as host numpy copies; keys are stable (``ha.snapshot`` encodes
-        them into the versioned artifact)."""
-
-        def _win(ws) -> Dict[str, np.ndarray]:
-            return {
-                "starts": np.asarray(ws.starts),
-                "counts": np.asarray(ws.counts),
-            }
-
-        with self._rules_mutex, self._lock:
-            now = self._engine_now()  # pins the epoch, runs a due rebase
-            return {
-                "engine_now": int(now),
-                "epoch_ms": int(self._epoch_ms),
-                "wall_ms": int(_clock.now_ms()),
-                "ns_max_qps": float(self._ns_max_qps),
-                "connected": dict(self._connected),
-                "namespace_set": sorted(self.namespace_set),
-                "rules": [
-                    r for m in self._rules_by_ns.values() for r in m.values()
-                ],
-                "param_rules": list(self._param_rules_src.values()),
-                "degrade_rules": list(self._degrade_rules_src.values()),
-                "slot_of": dict(self._index.slot_of),
-                "ns_of": dict(self._index.ns_of),
-                "param_slot_of": {
-                    fid: slot
-                    for fid, (slot, _, _) in self._param_rules.items()
-                },
-                "flow": _win(self._state.flow),
-                "occupy": _win(self._state.occupy),
-                "ns": _win(self._state.ns),
-                # per-flow completion-outcome windows (rt_sum / complete /
-                # exception / RT histogram channels; same ring epoch)
-                "outcome": _win(self._state.outcome),
-                # per-flow shaper clocks (engine-ms; same epoch as starts)
-                "shaping": {
-                    "lpt": np.asarray(self._state.shaping.lpt),
-                    "warm_tokens": np.asarray(
-                        self._state.shaping.warm_tokens
-                    ),
-                    "warm_filled": np.asarray(
-                        self._state.shaping.warm_filled
-                    ),
-                },
-                # per-flow circuit-breaker columns (state machine + engine-ms
-                # clocks; clocks share the exported epoch, so restore is
-                # bit-exact on the same service and remaps by flow_id)
-                "breaker": {
-                    "state": np.asarray(self._state.breaker.state),
-                    "opened_ms": np.asarray(self._state.breaker.opened_ms),
-                    "probe_ms": np.asarray(self._state.breaker.probe_ms),
-                },
-                "param": {
-                    "starts": np.asarray(self._param_state.starts),
-                    # fat cells ship RAW (bit-exact restore — for SALSA the
-                    # in-band merge encoding rides inside the int16 cells),
-                    # plus the slim twin, its authority flags, and the
-                    # per-slot merge counters
-                    "counts": np.asarray(self._param_state.counts),
-                    "slim": np.asarray(self._param_state.slim),
-                    "slim_auth": np.asarray(self._param_state.slim_auth),
-                    "merges": np.asarray(self._param_state.merges),
-                },
-                # hierarchy ledger piggyback (pure JSON; absent when no
-                # coordinator is co-located). A standby imports it into ITS
-                # attached coordinator so promotion inherits the share map.
-                **(
-                    {"hier": self.hierarchy.export_doc()}
-                    if self.hierarchy is not None else {}
-                ),
-            }
+        counting: rule sources, slot assignments, every leaf of the engine
+        state and the param sketch, and the engine epoch. Arrays come back
+        as host numpy copies; keys are stable (``ha.snapshot`` encodes them
+        into the versioned artifact)."""
+        return state_codec.export_state(self)
 
     def import_state(self, state: Dict[str, object]) -> None:
         """Restore an :meth:`export_state` capture into THIS service.
@@ -3182,195 +2774,7 @@ class DefaultTokenService(TokenService):
         expire naturally via the mask-on-read reads. Geometry (window/sketch
         shapes) must match this service's config; mismatch raises
         ``ValueError`` before anything mutates."""
-        from sentinel_tpu.engine.state import EngineState as _ES
-        from sentinel_tpu.stats.window import WindowState as _WS
-
-        def _check(name: str, got, want) -> np.ndarray:
-            arr = np.asarray(got)
-            if arr.shape != tuple(want.shape):
-                raise ValueError(
-                    f"snapshot geometry mismatch: {name} {arr.shape} "
-                    f"!= {tuple(want.shape)}"
-                )
-            return arr
-
-        with self._rules_mutex:
-            rules = list(state["rules"])
-            param_rules = list(state["param_rules"])
-            with self._lock:
-                cur = self._state
-                flow_c = _check("flow.counts", state["flow"]["counts"],
-                                cur.flow.counts)
-                flow_s = _check("flow.starts", state["flow"]["starts"],
-                                cur.flow.starts)
-                occ_c = _check("occupy.counts", state["occupy"]["counts"],
-                               cur.occupy.counts)
-                occ_s = _check("occupy.starts", state["occupy"]["starts"],
-                               cur.occupy.starts)
-                ns_c = _check("ns.counts", state["ns"]["counts"],
-                              cur.ns.counts)
-                ns_s = _check("ns.starts", state["ns"]["starts"],
-                              cur.ns.starts)
-                p_c = _check("param.counts", state["param"]["counts"],
-                             self._param_state.counts)
-                p_s = _check("param.starts", state["param"]["starts"],
-                             self._param_state.starts)
-                # slim/merge keys are tolerated absent (pre-sketch-subsystem
-                # snapshots) — they default to zeros of this service's
-                # geometry
-                p_slim = state["param"].get("slim")
-                if p_slim is not None:
-                    p_slim = _check("param.slim", p_slim,
-                                    self._param_state.slim)
-                p_auth = state["param"].get("slim_auth")
-                p_merges = state["param"].get("merges")
-                # pre-shaping snapshots carry no shaper clocks — restore
-                # them cold (NEVER/0), which is the conservative default
-                shaping_doc = state.get("shaping")
-                # pre-outcome snapshots carry no completion windows —
-                # restore them empty (cold), same tolerant-absent discipline
-                outcome_doc = state.get("outcome")
-                # pre-breaker snapshots carry no breaker columns — restore
-                # CLOSED everywhere (under-protects until the stat window
-                # refills; never wrongly rejects)
-                breaker_doc = state.get("breaker")
-                if outcome_doc is not None:
-                    out_c = _check("outcome.counts", outcome_doc["counts"],
-                                   cur.outcome.counts)
-                    out_s = _check("outcome.starts", outcome_doc["starts"],
-                                   cur.outcome.starts)
-                else:
-                    out_c = np.zeros(
-                        tuple(cur.outcome.counts.shape),
-                        np.asarray(cur.outcome.counts[:0]).dtype,
-                    )
-                    out_s = np.asarray(cur.outcome.starts)
-            with self._lock:
-                # degrade rules must be in place BEFORE load_rules so the
-                # rebuilt RuleTable carries the br_* columns the restored
-                # breaker state refers to
-                self._degrade_rules_src = {
-                    d.flow_id: d for d in state.get("degrade_rules", ())
-                }
-            self.load_rules(
-                rules,
-                ns_max_qps=float(state["ns_max_qps"]),
-                connected=dict(state["connected"]),
-            )
-            self.load_param_rules(param_rules)
-            with self._lock:
-                self.namespace_set |= set(state["namespace_set"])
-                # remap flow/occupy rows: snapshot slot → this service's slot
-                old_slot = state["slot_of"]
-                new_flow_c = np.zeros_like(flow_c)
-                new_occ_c = np.zeros_like(occ_c)
-                new_out_c = np.zeros_like(out_c)
-                from sentinel_tpu.stats.window import NEVER as _WNEVER
-
-                n_flows = self.config.max_flows
-                new_lpt = np.full(n_flows, int(_WNEVER), np.int32)
-                new_wtok = np.zeros(n_flows, np.float32)
-                new_wfill = np.full(n_flows, int(_WNEVER), np.int32)
-                new_br_st = np.zeros(n_flows, np.int8)
-                new_br_op = np.full(n_flows, int(_WNEVER), np.int32)
-                new_br_pr = np.full(n_flows, int(_WNEVER), np.int32)
-                for fid, new in self._index.slot_of.items():
-                    old = old_slot.get(fid)
-                    if old is None:
-                        continue
-                    new_flow_c[new] = flow_c[old]
-                    new_occ_c[new] = occ_c[old]
-                    new_out_c[new] = out_c[old]
-                    if shaping_doc is not None:
-                        new_lpt[new] = np.asarray(shaping_doc["lpt"])[old]
-                        new_wtok[new] = np.asarray(
-                            shaping_doc["warm_tokens"]
-                        )[old]
-                        new_wfill[new] = np.asarray(
-                            shaping_doc["warm_filled"]
-                        )[old]
-                    if breaker_doc is not None:
-                        new_br_st[new] = np.asarray(
-                            breaker_doc["state"]
-                        )[old]
-                        new_br_op[new] = np.asarray(
-                            breaker_doc["opened_ms"]
-                        )[old]
-                        new_br_pr[new] = np.asarray(
-                            breaker_doc["probe_ms"]
-                        )[old]
-                # namespace guard rows remap by name
-                old_ns = state["ns_of"]
-                new_ns_c = np.zeros_like(ns_c)
-                for name, new in self._index.ns_of.items():
-                    old = old_ns.get(name)
-                    if old is not None:
-                        new_ns_c[new] = ns_c[old]
-                # param sketch rows remap via the param slot maps (fat row,
-                # slim row, and merge counter move together; the [B] global
-                # slim-authority flags copy verbatim)
-                old_pslot = state["param_slot_of"]
-                new_p_c = np.zeros_like(p_c)
-                new_p_slim = np.zeros(
-                    self._param_state.slim.shape,
-                    np.asarray(self._param_state.slim).dtype,
-                )
-                new_p_merges = np.zeros(
-                    self._param_state.merges.shape, np.int32
-                )
-                for fid, (new, _, _) in self._param_rules.items():
-                    old = old_pslot.get(fid)
-                    if old is not None:
-                        new_p_c[new] = p_c[old]
-                        if p_slim is not None:
-                            new_p_slim[new] = p_slim[old]
-                        if p_merges is not None:
-                            new_p_merges[new] = np.asarray(p_merges)[old]
-                from sentinel_tpu.engine.state import (
-                    BreakerState as _BRS,
-                    ShapingState as _SHS,
-                )
-
-                self._state = self._place_state(_ES(
-                    flow=_WS(jnp.asarray(flow_s), jnp.asarray(new_flow_c)),
-                    occupy=_WS(jnp.asarray(occ_s), jnp.asarray(new_occ_c)),
-                    ns=_WS(jnp.asarray(ns_s), jnp.asarray(new_ns_c)),
-                    shaping=_SHS(
-                        lpt=jnp.asarray(new_lpt),
-                        warm_tokens=jnp.asarray(new_wtok),
-                        warm_filled=jnp.asarray(new_wfill),
-                    ),
-                    outcome=_WS(jnp.asarray(out_s), jnp.asarray(new_out_c)),
-                    breaker=_BRS(
-                        state=jnp.asarray(new_br_st),
-                        opened_ms=jnp.asarray(new_br_op),
-                        probe_ms=jnp.asarray(new_br_pr),
-                    ),
-                ))
-                # re-baseline the transition mirror from CLOSED so the
-                # restore surfaces still-open breakers as closed→open edges
-                self._breaker_prev = None
-                self._param_state = self._param_state._replace(
-                    starts=jnp.asarray(p_s),
-                    counts=jnp.asarray(new_p_c),
-                    slim=jnp.asarray(new_p_slim),
-                    slim_auth=(
-                        jnp.asarray(np.asarray(p_auth, bool))
-                        if p_auth is not None
-                        else jnp.zeros_like(self._param_state.slim_auth)
-                    ),
-                    merges=jnp.asarray(new_p_merges),
-                )
-                # resume the snapshot's engine timeline: wall − epoch keeps
-                # advancing, so windows older than interval_ms expire on the
-                # next read instead of resurrecting stale quota
-                self._epoch_ms = int(state["epoch_ms"])
-        # hierarchy ledger piggyback: a standby with an attached (idle)
-        # coordinator inherits the primary's share map, so promotion keeps
-        # every pod's share continuous
-        hier_doc = state.get("hier")
-        if hier_doc is not None and self.hierarchy is not None:
-            self.hierarchy.import_doc(hier_doc)
+        state_codec.import_state(self, state)
 
     # -- warm-standby delta replication (ha.replication backing) -------------
     def replication_enable(self) -> None:
@@ -3378,10 +2782,7 @@ class DefaultTokenService(TokenService):
         Idempotent; until called the dispatch paths skip the bookkeeping."""
         with self._lock:
             if self._dirty is None:
-                self._dirty = {
-                    "flow": set(), "param": set(), "param_fat": set(),
-                    "outcome": set(), "breaker": set(),
-                }
+                self._dirty = state_codec.fresh_dirty()
 
     def replication_disable(self) -> None:
         with self._lock:
@@ -3399,139 +2800,15 @@ class DefaultTokenService(TokenService):
 
         Returns a compact host-side document: the shared window ``starts``
         ring vectors (``[n_buckets]`` each — always shipped, they advance
-        with engine time), plus per-dirty-slot ``counts`` rows keyed by
-        flow_id / namespace name / param flow_id so the standby can land
-        them on its OWN slot assignment. ``gen`` is the generation the rows
-        were collected under; ``epoch_ms`` pins the engine timeline the
-        starts are relative to (the standby refuses a delta from a foreign
-        epoch). An idle tick returns a starts-only document — the sender's
-        liveness heartbeat. Destructive: the dirty sets are cleared, so a
-        sender that fails to deliver must fall back to a full snapshot."""
-        with self._rules_mutex, self._lock:
-            if self._dirty is None:
-                raise RuntimeError("replication tracking not enabled")
-            flow_slots = sorted(self._dirty["flow"])
-            param_slots = sorted(self._dirty["param"])
-            param_fat_slots = sorted(self._dirty.get("param_fat", ()))
-            outcome_slots = sorted(self._dirty.get("outcome", ()))
-            breaker_slots = sorted(self._dirty.get("breaker", ()))
-            self._dirty = {
-                "flow": set(), "param": set(), "param_fat": set(),
-                "outcome": set(), "breaker": set(),
-            }
-            now = self._engine_now()  # pins the epoch, runs a due rebase
-            delta: Dict[str, object] = {
-                "gen": int(self._state_gen),
-                "engine_now": int(now),
-                "epoch_ms": int(self._epoch_ms),
-                "wall_ms": int(_clock.now_ms()),
-                "flow_starts": np.asarray(self._state.flow.starts),
-                "occupy_starts": np.asarray(self._state.occupy.starts),
-                "ns_starts": np.asarray(self._state.ns.starts),
-                "outcome_starts": np.asarray(self._state.outcome.starts),
-                "param_starts": np.asarray(self._param_state.starts),
-            }
-            # row gathers go through the shard-aware host collector: on a
-            # mesh it walks addressable shards and numpy-gathers each one's
-            # slab (the delta's row keys stay GLOBAL slots, so the wire
-            # document is identical whatever mesh produced it); single-shard
-            # it is one host copy + numpy index. Either way no device gather
-            # kernel — the dirty set's size varies every tick, and a device
-            # gather would pay a fresh XLA compile per distinct row count.
-            from sentinel_tpu.parallel.sharding import host_rows
-            if flow_slots:
-                sl = np.asarray(flow_slots, np.int32)
-                rev = {v: k for k, v in self._index.slot_of.items()}
-                delta["flow_ids"] = [int(rev[s]) for s in flow_slots]
-                delta["flow_counts"] = host_rows(self._state.flow.counts, sl)
-                delta["occupy_counts"] = host_rows(
-                    self._state.occupy.counts, sl
-                )
-                # shaper clocks ride the same dirty-row keying; values are
-                # engine-ms in the shared epoch the delta already pins
-                delta["shaping_lpt"] = host_rows(
-                    self._state.shaping.lpt, sl
-                )
-                delta["shaping_warm_tokens"] = host_rows(
-                    self._state.shaping.warm_tokens, sl
-                )
-                delta["shaping_warm_filled"] = host_rows(
-                    self._state.shaping.warm_filled, sl
-                )
-                # namespace guard rows these slots feed
-                ns_names, slot_ns = self._ns_snapshot
-                rows = sorted(
-                    {int(slot_ns[s]) for s in flow_slots if slot_ns[s] >= 0}
-                )
-                if rows:
-                    delta["ns_names"] = [ns_names[r] for r in rows]
-                    delta["ns_counts"] = host_rows(
-                        self._state.ns.counts, np.asarray(rows, np.int32)
-                    )
-            if outcome_slots:
-                # completion-outcome rows ride the same dirty-row keying,
-                # tracked separately from flow rows — admission traffic and
-                # completion reports dirty different slots on different
-                # cadences, and mixing the sets would ship full flow rows
-                # for every piggy-backed outcome batch
-                osl = np.asarray(outcome_slots, np.int32)
-                orev = {v: k for k, v in self._index.slot_of.items()}
-                delta["outcome_fids"] = [int(orev[s]) for s in outcome_slots]
-                delta["outcome_counts"] = host_rows(
-                    self._state.outcome.counts, osl
-                )
-            if breaker_slots:
-                # breaker columns ship raw engine-ms clocks — the standby
-                # shares the epoch (checked on apply), so no re-anchoring.
-                # Only touched∩breaker slots land here: transitions can only
-                # occur for rows that were batched or reported this tick.
-                bsl = np.asarray(breaker_slots, np.int32)
-                brev = {v: k for k, v in self._index.slot_of.items()}
-                delta["breaker_fids"] = [int(brev[s]) for s in breaker_slots]
-                delta["breaker_state"] = host_rows(
-                    self._state.breaker.state, bsl
-                )
-                delta["breaker_opened"] = host_rows(
-                    self._state.breaker.opened_ms, bsl
-                )
-                delta["breaker_probe"] = host_rows(
-                    self._state.breaker.probe_ms, bsl
-                )
-            if param_slots:
-                pr = np.asarray(param_slots, np.int32)
-                prev = {
-                    s: fid for fid, (s, _, _) in self._param_rules.items()
-                }
-                delta["param_fids"] = [int(prev[s]) for s in param_slots]
-                if self.param_config.slim_enabled:
-                    # SF-sketch split: the every-tick wire document ships
-                    # the SLIM twin rows, not the fat update sketch —
-                    # that's the sentinel_repl_bytes_total cut (the fat
-                    # rows still ship in full snapshots for bit-exact
-                    # bootstrap). Rows a MOVE import just folded are the
-                    # exception: their mass exists only in the fat sketch,
-                    # so they ride along once, keyed separately.
-                    delta["param_slim"] = host_rows(
-                        self._param_state.slim, pr
-                    )
-                    if param_fat_slots:
-                        fr = np.asarray(param_fat_slots, np.int32)
-                        delta["param_fat_fids"] = [
-                            int(prev[s]) for s in param_fat_slots
-                        ]
-                        delta["param_counts"] = host_rows(
-                            self._param_state.counts, fr
-                        )
-                else:
-                    delta["param_counts"] = host_rows(
-                        self._param_state.counts, pr
-                    )
-            if self.hierarchy is not None:
-                # hier ledger rides every tick as plain JSON (non-array keys
-                # pass through encode_delta_blob untouched); it's tiny — one
-                # entry per (global flow × pod)
-                delta["hier"] = self.hierarchy.export_doc()
-            return delta
+        with engine time), plus per-dirty-slot rows keyed by flow_id /
+        namespace name / param flow_id so the standby can land them on its
+        OWN slot assignment. ``gen`` is the generation the rows were
+        collected under; ``epoch_ms`` pins the engine timeline the starts
+        are relative to (the standby refuses a delta from a foreign epoch).
+        An idle tick returns a starts-only document — the sender's liveness
+        heartbeat. Destructive: the dirty sets are cleared, so a sender
+        that fails to deliver must fall back to a full snapshot."""
+        return state_codec.export_delta(self)
 
     def apply_replication_delta(self, delta: Dict[str, object]) -> None:
         """Scatter a primary's :meth:`export_delta` into THIS (standby)
@@ -3542,206 +2819,7 @@ class DefaultTokenService(TokenService):
         engine epoch, raises ``ValueError``: both mean the standby's base
         state predates a reload on the primary, and the caller must answer
         NEED_SNAPSHOT rather than apply rows against the wrong baseline."""
-        from sentinel_tpu.engine.state import EngineState as _ES
-        from sentinel_tpu.stats.window import WindowState as _WS
-
-        def _rotate(ws, new_starts):
-            """Mirror the primary's ring rotation on rows the delta does NOT
-            carry: when the primary advanced ``starts[b]`` it zeroed column
-            ``b`` for every resource (window.py rotation), so any local row
-            whose column still holds counts from the previous occupancy of
-            that ring slot must be zeroed too — otherwise applying the new
-            starts would resurrect those stale counts as current-window
-            traffic. Dirty rows are scattered with authoritative values
-            afterwards, so pre-zeroing them is harmless."""
-            changed = np.asarray(ws.starts) != np.asarray(new_starts)
-            if not changed.any():
-                return ws
-            keep = jnp.asarray((~changed).astype(np.int32))
-            shape = (1, keep.shape[0]) + (1,) * (ws.counts.ndim - 2)
-            return ws._replace(
-                counts=ws.counts * keep.reshape(shape).astype(
-                    ws.counts.dtype
-                )
-            )
-
-        with self._rules_mutex, self._lock:
-            if (
-                self._epoch_ms is None
-                or int(delta["epoch_ms"]) != self._epoch_ms
-            ):
-                raise ValueError("replication epoch mismatch")
-            flow = _rotate(self._state.flow, delta["flow_starts"])
-            occupy = _rotate(self._state.occupy, delta["occupy_starts"])
-            ns = _rotate(self._state.ns, delta["ns_starts"])
-            # pre-outcome senders ship no outcome_starts: keep the local
-            # ring untouched (it is empty on such a standby anyway)
-            out_starts = delta.get("outcome_starts")
-            outcome = (
-                _rotate(self._state.outcome, out_starts)
-                if out_starts is not None else self._state.outcome
-            )
-            flow_ids = delta.get("flow_ids")
-            shaping = self._state.shaping
-            if flow_ids:
-                slots = []
-                for fid in flow_ids:
-                    s = self._index.slot_of.get(int(fid))
-                    if s is None:
-                        raise ValueError(f"delta names unknown flow {fid}")
-                    slots.append(s)
-                sl = jnp.asarray(np.asarray(slots, np.int32))
-                flow = flow._replace(
-                    counts=flow.counts.at[sl].set(
-                        jnp.asarray(delta["flow_counts"])
-                    )
-                )
-                occupy = occupy._replace(
-                    counts=occupy.counts.at[sl].set(
-                        jnp.asarray(delta["occupy_counts"])
-                    )
-                )
-                if "shaping_lpt" in delta:
-                    # shaper clocks are raw engine-ms: the epoch check above
-                    # already guarantees both sides share the timeline
-                    shaping = shaping._replace(
-                        lpt=shaping.lpt.at[sl].set(
-                            jnp.asarray(delta["shaping_lpt"])
-                        ),
-                        warm_tokens=shaping.warm_tokens.at[sl].set(
-                            jnp.asarray(delta["shaping_warm_tokens"])
-                        ),
-                        warm_filled=shaping.warm_filled.at[sl].set(
-                            jnp.asarray(delta["shaping_warm_filled"])
-                        ),
-                    )
-            outcome_fids = delta.get("outcome_fids")
-            if outcome_fids:
-                oslots = []
-                for fid in outcome_fids:
-                    s = self._index.slot_of.get(int(fid))
-                    if s is None:
-                        raise ValueError(f"delta names unknown flow {fid}")
-                    oslots.append(s)
-                osl = jnp.asarray(np.asarray(oslots, np.int32))
-                outcome = outcome._replace(
-                    counts=outcome.counts.at[osl].set(
-                        jnp.asarray(delta["outcome_counts"])
-                    )
-                )
-            breaker = self._state.breaker
-            breaker_fids = delta.get("breaker_fids")
-            if breaker_fids:
-                bslots = []
-                for fid in breaker_fids:
-                    s = self._index.slot_of.get(int(fid))
-                    if s is None:
-                        raise ValueError(f"delta names unknown flow {fid}")
-                    bslots.append(s)
-                bsl = jnp.asarray(np.asarray(bslots, np.int32))
-                # clocks are raw engine-ms; the epoch check above already
-                # guarantees both sides share the timeline
-                breaker = breaker._replace(
-                    state=breaker.state.at[bsl].set(
-                        jnp.asarray(delta["breaker_state"])
-                    ),
-                    opened_ms=breaker.opened_ms.at[bsl].set(
-                        jnp.asarray(delta["breaker_opened"])
-                    ),
-                    probe_ms=breaker.probe_ms.at[bsl].set(
-                        jnp.asarray(delta["breaker_probe"])
-                    ),
-                )
-            ns_names = delta.get("ns_names")
-            if ns_names:
-                rows = []
-                for name in ns_names:
-                    r = self._index.ns_of.get(name)
-                    if r is None:
-                        raise ValueError(
-                            f"delta names unknown namespace {name!r}"
-                        )
-                    rows.append(r)
-                nr = jnp.asarray(np.asarray(rows, np.int32))
-                ns = ns._replace(
-                    counts=ns.counts.at[nr].set(
-                        jnp.asarray(delta["ns_counts"])
-                    )
-                )
-            self._state = self._place_state(_ES(
-                flow=_WS(jnp.asarray(delta["flow_starts"]), flow.counts),
-                occupy=_WS(
-                    jnp.asarray(delta["occupy_starts"]), occupy.counts
-                ),
-                ns=_WS(jnp.asarray(delta["ns_starts"]), ns.counts),
-                shaping=shaping,
-                outcome=(
-                    _WS(jnp.asarray(out_starts), outcome.counts)
-                    if out_starts is not None else outcome
-                ),
-                breaker=breaker,
-            ))
-            pstate = _rotate(self._param_state, delta["param_starts"])
-            pcounts = pstate.counts
-            pslim, pauth = pstate.slim, pstate.slim_auth
-            # mirror the ring rotation on the slim twin too: a rotated
-            # column's slim cells describe a dead window — zero them and
-            # drop the bucket's authority flag
-            pchanged = (
-                np.asarray(self._param_state.starts)
-                != np.asarray(delta["param_starts"])
-            )
-            if pchanged.any():
-                keep = jnp.asarray((~pchanged).astype(np.int32))
-                pslim = pslim * keep.reshape(1, -1, 1, 1).astype(pslim.dtype)
-                pauth = pauth & jnp.asarray(~pchanged)
-
-            def _prows(fids):
-                rows = []
-                for fid in fids:
-                    entry = self._param_rules.get(int(fid))
-                    if entry is None:
-                        raise ValueError(
-                            f"delta names unknown param rule {fid}"
-                        )
-                    rows.append(entry[0])
-                return jnp.asarray(np.asarray(rows, np.int32))
-
-            param_fids = delta.get("param_fids")
-            if param_fids:
-                if "param_slim" in delta:
-                    # SF split: deltas carry slim twin rows. Landing any
-                    # makes every live bucket slim-authoritative — the
-                    # decide path then serves fat + slim, which
-                    # double-counts at most one snapshot-to-delta gap
-                    # (over-estimate, the safe direction) and converges to
-                    # fat-only as the flagged buckets rotate off the ring.
-                    pr = _prows(param_fids)
-                    pslim = pslim.at[pr].set(
-                        jnp.asarray(delta["param_slim"])
-                    )
-                    pauth = jnp.ones_like(pauth)
-                    fat_fids = delta.get("param_fat_fids")
-                    if fat_fids:
-                        fr = _prows(fat_fids)
-                        pcounts = pcounts.at[fr].set(
-                            jnp.asarray(delta["param_counts"])
-                        )
-                elif "param_counts" in delta:
-                    pr = _prows(param_fids)
-                    pcounts = pcounts.at[pr].set(
-                        jnp.asarray(delta["param_counts"])
-                    )
-            self._param_state = self._param_state._replace(
-                starts=jnp.asarray(delta["param_starts"]), counts=pcounts,
-                slim=pslim, slim_auth=pauth,
-            )
-        # hier ledger piggyback: landed OUTSIDE the counter locks (the
-        # coordinator has its own) and only when a coordinator is attached —
-        # an old standby without one ignores the key, like any unknown key
-        hier_doc = delta.get("hier")
-        if hier_doc is not None and self.hierarchy is not None:
-            self.hierarchy.import_doc(hier_doc)
+        state_codec.apply_replication_delta(self, delta)
 
     # -- introspection (FetchClusterMetricCommandHandler analog) ------------
     def sketch_stats(self) -> Dict[str, object]:

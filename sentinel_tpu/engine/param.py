@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sentinel_tpu.engine.state import DELTA, SNAPSHOT, Column
+
 # Mixing constants for the host-side index derivation (splitmix64 finalizer
 # per depth lane — public-domain construction).
 _MIX = np.uint64(0x9E3779B97F4A7C15)
@@ -111,6 +113,26 @@ class ParamState(NamedTuple):
 
 
 NEVER = jnp.int32(-(2**30))
+
+# The sketch's leaves in the table of state columns (``engine.state.Column``
+# says what an entry means). The fat counters are a window like any other,
+# keyed by param rule, but three things about them stay code, in
+# ``cluster.state_codec``: a MOVE sums *decoded* cells, a delta ships the
+# slim twin in their place while the twin is on, and the authority flags
+# follow the buckets a delta touched rather than being shipped. The twin,
+# its flags and the merge counters ride no MOVE blob; the flags and the
+# counters no delta either.
+PARAM_COLUMNS = (
+    Column("param", "starts", None, "clock", None, "param_starts",
+           frozenset({SNAPSHOT, DELTA})),
+    Column("param", "counts", "param", "window", "param", "param"),
+    Column("param", "slim", "param", "value", "param", "param_slim",
+           frozenset({SNAPSHOT, DELTA})),
+    Column("param", "slim_auth", None, "value", None, "param_slim_auth",
+           frozenset({SNAPSHOT})),
+    Column("param", "merges", "param", "value", None, "param_merges",
+           frozenset({SNAPSHOT})),
+)
 
 
 def fat_shape(config: ParamConfig) -> tuple:

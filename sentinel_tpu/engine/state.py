@@ -10,7 +10,8 @@ per-flowId ``ClusterMetric`` LeapArrays (``metric/ClusterMetric.java:28-79``)
 from __future__ import annotations
 
 import enum
-from typing import NamedTuple
+import typing
+from typing import Callable, FrozenSet, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +135,124 @@ class EngineState(NamedTuple):
     shaping: ShapingState  # [F] per-flow shaper clocks
     outcome: WindowState  # [F, B, N_OUTCOME_CHANNELS] completion outcomes
     breaker: BreakerState  # [F] per-flow circuit-breaker columns
+
+
+# -- the table of state columns ---------------------------------------------
+# The three documents a leaf of the state can ride: the snapshot a standby
+# boots from, the replication delta it is kept warm with, and the MOVE blob
+# a namespace changes servers in.
+SNAPSHOT, DELTA, MOVE = "snapshot", "delta", "move"
+_ALL_DOCS = frozenset({SNAPSHOT, DELTA, MOVE})
+
+
+class Column(NamedTuple):
+    """One leaf of the device state, and all that the code around the step
+    needs to know of it: the snapshot, delta and MOVE codecs
+    (``cluster.state_codec``), their blob encoders (``ha.snapshot``,
+    ``ha.replication``, ``cluster.rebalance``), the engine clock's re-base
+    and the mesh placement (``parallel.sharding``) are loops over these
+    entries and name no leaf themselves. What a leaf is when nothing has
+    happened to it is not here: cold is what :func:`make_state` gives, and
+    the importers start from that.
+
+    ``key`` is what names a row durably, across slot assignments:
+    ``"flow"`` a flow id (``RuleIndex.slot_of``), ``"namespace"`` a
+    namespace's name (``RuleIndex.ns_of``), ``"param"`` a param rule's flow
+    id (the service's ``_param_rules``), ``None`` a leaf without such rows
+    (a ring's ``starts``), which is copied whole. On a mesh a leaf keyed by
+    flow is sharded along the flow axis and every other one replicated.
+
+    ``kind`` is how a value travels between two engine clocks.
+    ``"window"``: the ``counts`` of a ring whose bucket clocks are the
+    family's ``starts``; a MOVE ships the live window's sums and folds them
+    into the destination's current bucket, and a delta zeroes the buckets
+    whose start moved. ``"clock"``: int32 engine-ms with ``NEVER``; the
+    re-base shifts it, a MOVE ships its distance from the source's now.
+    ``"value"``: copied as it is.
+
+    ``dirty`` is the service's dirty set whose slots ship the leaf's rows in
+    a delta (a leaf keyed by namespace rides the rows its flows' slots
+    feed); ``None`` for a leaf that a delta ships whole or not at all.
+    ``wire`` is the short name the flat documents use (:attr:`delta_key`,
+    :attr:`move_key`); a snapshot nests ``field`` under ``family``.
+    ``docs`` is where the leaf rides, for those that do not ride all three
+    (a MOVE blob is ring-free, so nothing unkeyed rides it)."""
+
+    family: str
+    field: str
+    key: Optional[str]
+    kind: str
+    dirty: Optional[str]
+    wire: str
+    docs: FrozenSet[str] = _ALL_DOCS
+
+    @property
+    def name(self) -> str:
+        return f"{self.family}.{self.field}"
+
+    @property
+    def delta_key(self) -> str:
+        return f"{self.wire}_counts" if self.kind == "window" else self.wire
+
+    @property
+    def move_key(self) -> str:
+        """One namespace moves at a time, so a leaf keyed by namespace
+        ships its one row (``ns_sum``) and the others a row per id."""
+        if self.kind == "window":
+            return self.wire + ("_sum" if self.key == "namespace" else "_sums")
+        return self.wire + "_rel" if self.kind == "clock" else self.wire
+
+
+def _window(family: str, key: str, dirty: str) -> tuple:
+    """A window's two leaves. Its ``starts`` go whole into snapshots and
+    deltas; a MOVE blob is ring-free and has none."""
+    return (
+        Column(family, "starts", None, "clock", None, f"{family}_starts",
+               frozenset({SNAPSHOT, DELTA})),
+        Column(family, "counts", key, "window", dirty, family),
+    )
+
+
+STATE_COLUMNS = (
+    *_window("flow", "flow", "flow"),
+    *_window("occupy", "flow", "flow"),
+    *_window("ns", "namespace", "flow"),
+    Column("shaping", "lpt", "flow", "clock", "flow", "shaping_lpt"),
+    Column("shaping", "warm_tokens", "flow", "value", "flow",
+           "shaping_warm_tokens"),
+    Column("shaping", "warm_filled", "flow", "clock", "flow",
+           "shaping_warm_filled"),
+    # completion reports dirty other slots, on another cadence, than
+    # admission does: a set of its own keeps a delta from shipping a whole
+    # flow row for every piggy-backed report
+    *_window("outcome", "flow", "outcome"),
+    # transitions happen only on rows that were batched or reported, so the
+    # touched slots that carry a breaker are exactly the ones to ship
+    Column("breaker", "state", "flow", "value", "breaker", "breaker_state"),
+    Column("breaker", "opened_ms", "flow", "clock", "breaker",
+           "breaker_opened"),
+    Column("breaker", "probe_ms", "flow", "clock", "breaker",
+           "breaker_probe"),
+)
+
+
+# family -> the NamedTuple its leaves live in
+_FAMILY_TYPES = typing.get_type_hints(EngineState)
+
+
+def leaf(state: "EngineState", column: Column):
+    return getattr(getattr(state, column.family), column.field)
+
+
+def state_of(leaf_of: Callable[[Column], object]) -> "EngineState":
+    """The :class:`EngineState` whose leaf for each column is
+    ``leaf_of(column)``."""
+    return EngineState(**{
+        family: _FAMILY_TYPES[family](**{
+            c.field: leaf_of(c) for c in STATE_COLUMNS if c.family == family
+        })
+        for family in EngineState._fields
+    })
 
 
 def flow_spec(config: EngineConfig) -> WindowSpec:

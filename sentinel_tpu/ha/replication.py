@@ -58,9 +58,11 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from sentinel_tpu import chaos as _chaos
 from sentinel_tpu.cluster import protocol as P
+from sentinel_tpu.cluster.state_codec import COLUMNS
 from sentinel_tpu.core import clock as _clock
 from sentinel_tpu.core.config import SentinelConfig
 from sentinel_tpu.core.log import record_log
+from sentinel_tpu.engine.state import DELTA
 from sentinel_tpu.ha.snapshot import (
     _dec_array,
     _enc_array,
@@ -73,22 +75,9 @@ DELTA_VERSION = 1
 KEY_REPL_INTERVAL_MS = "sentinel.tpu.ha.repl.interval.ms"
 KEY_PROMOTE_AFTER_MS = "sentinel.tpu.ha.repl.promote.after.ms"
 
-# export_delta keys holding numpy arrays (everything else is JSON-native)
-_ARRAY_KEYS = frozenset(
-    {
-        "flow_starts", "occupy_starts", "ns_starts", "param_starts",
-        "flow_counts", "occupy_counts", "ns_counts", "param_counts",
-        "param_slim",  # SF slim-twin rows: the param payload when slim is on
-        # shaper clocks (raw engine-ms, same dirty-row keying as flow_counts)
-        "shaping_lpt", "shaping_warm_tokens", "shaping_warm_filled",
-        # completion-outcome columns (own dirty set: reporting cadence is
-        # decoupled from the admission windows')
-        "outcome_starts", "outcome_counts",
-        # circuit-breaker columns (own dirty set: transitions happen only
-        # on batched/reported rows, so touched∩breaker is exact)
-        "breaker_state", "breaker_opened", "breaker_probe",
-    }
-)
+# export_delta keys holding numpy arrays (everything else is JSON-native):
+# the key of every state column a delta carries
+_ARRAY_KEYS = frozenset(c.delta_key for c in COLUMNS if DELTA in c.docs)
 
 
 # -- blob codecs --------------------------------------------------------------

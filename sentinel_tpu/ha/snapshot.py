@@ -39,7 +39,9 @@ import numpy as np
 from sentinel_tpu.core import clock as _clock
 from sentinel_tpu.core.config import SentinelConfig
 from sentinel_tpu.core.log import record_log
+from sentinel_tpu.cluster.state_codec import COLUMNS
 from sentinel_tpu.cluster.token_service import ClusterParamFlowRule
+from sentinel_tpu.engine.state import SNAPSHOT
 from sentinel_tpu.engine.rules import (
     decode_degrade_rule,
     decode_rule,
@@ -49,6 +51,10 @@ from sentinel_tpu.engine.rules import (
 from sentinel_tpu.metrics.ha import ha_metrics
 
 SNAPSHOT_VERSION = 1
+# the families of state columns a snapshot nests its arrays under
+_FAMILIES = tuple(dict.fromkeys(
+    c.family for c in COLUMNS if SNAPSHOT in c.docs
+))
 KEY_SNAPSHOT_PERIOD_S = "sentinel.tpu.ha.snapshot.period.s"
 
 _PREFIX = "sentinel-snapshot-"
@@ -111,24 +117,10 @@ def encode_snapshot(state: Dict[str, object]) -> Dict[str, object]:
         "param_slot_of": {
             str(k): int(v) for k, v in state["param_slot_of"].items()
         },
-        "flow": _enc_win(state["flow"]),
-        "occupy": _enc_win(state["occupy"]),
-        "ns": _enc_win(state["ns"]),
-        "param": _enc_win(state["param"]),
-        # per-flow shaper clocks (absent in pre-shaping snapshots; the
-        # importer then starts those slots cold)
-        **(
-            {"shaping": _enc_win(state["shaping"])}
-            if "shaping" in state else {}
-        ),
-        # per-flow completion-outcome columns (absent in pre-outcome
-        # snapshots; the importer then starts those columns cold)
-        **(
-            {"outcome": _enc_win(state["outcome"])}
-            if "outcome" in state else {}
-        ),
-        # circuit-breaker rules + state columns (absent in pre-breaker
-        # snapshots; the importer then restores every breaker CLOSED)
+        # the state columns, family by family (a snapshot from before a
+        # family carries no key for it; the importer starts it cold)
+        **{f: _enc_win(state[f]) for f in _FAMILIES if f in state},
+        # circuit-breaker rules (absent in pre-breaker snapshots)
         **(
             {
                 "degrade_rules": [
@@ -136,10 +128,6 @@ def encode_snapshot(state: Dict[str, object]) -> Dict[str, object]:
                 ],
             }
             if "degrade_rules" in state else {}
-        ),
-        **(
-            {"breaker": _enc_win(state["breaker"])}
-            if "breaker" in state else {}
         ),
         # hierarchy-coordinator ledger piggyback (already JSON-safe; absent
         # when no coordinator is co-located with this pod)
@@ -178,18 +166,7 @@ def decode_snapshot(doc: Dict[str, object]) -> Dict[str, object]:
         "param_slot_of": {
             int(k): int(v) for k, v in doc["param_slot_of"].items()
         },
-        "flow": _dec_win(doc["flow"]),
-        "occupy": _dec_win(doc["occupy"]),
-        "ns": _dec_win(doc["ns"]),
-        "param": _dec_win(doc["param"]),
-        **(
-            {"shaping": _dec_win(doc["shaping"])}
-            if "shaping" in doc else {}
-        ),
-        **(
-            {"outcome": _dec_win(doc["outcome"])}
-            if "outcome" in doc else {}
-        ),
+        **{f: _dec_win(doc[f]) for f in _FAMILIES if f in doc},
         **(
             {
                 "degrade_rules": [
@@ -197,10 +174,6 @@ def decode_snapshot(doc: Dict[str, object]) -> Dict[str, object]:
                 ],
             }
             if "degrade_rules" in doc else {}
-        ),
-        **(
-            {"breaker": _dec_win(doc["breaker"])}
-            if "breaker" in doc else {}
         ),
         **({"hier": doc["hier"]} if "hier" in doc else {}),
     }

@@ -31,8 +31,7 @@ from sentinel_tpu.engine.decide import (
     unpack_requests,
 )
 from sentinel_tpu.engine.rules import RuleTable
-from sentinel_tpu.engine.state import BreakerState, EngineState, ShapingState
-from sentinel_tpu.stats.window import WindowState
+from sentinel_tpu.engine.state import EngineState, state_of
 
 def make_flow_mesh(devices=None, axis: str = "flows") -> Mesh:
     devices = devices if devices is not None else jax.devices()
@@ -40,18 +39,10 @@ def make_flow_mesh(devices=None, axis: str = "flows") -> Mesh:
 
 
 def _state_specs(axis: str) -> EngineState:
-    return EngineState(
-        flow=WindowState(starts=P(), counts=P(axis)),
-        occupy=WindowState(starts=P(), counts=P(axis)),
-        ns=WindowState(starts=P(), counts=P()),
-        shaping=ShapingState(
-            lpt=P(axis), warm_tokens=P(axis), warm_filled=P(axis)
-        ),
-        outcome=WindowState(starts=P(), counts=P(axis)),
-        breaker=BreakerState(
-            state=P(axis), opened_ms=P(axis), probe_ms=P(axis)
-        ),
-    )
+    """A leaf whose rows are flows is sharded along the flow axis; a leaf
+    keyed by namespace or by nothing (every ring's ``starts``) is
+    replicated."""
+    return state_of(lambda c: P(axis) if c.key == "flow" else P())
 
 
 def _rules_specs(axis: str, br: bool = True) -> RuleTable:

@@ -25,7 +25,7 @@ Key differences from the JVM design, driven by XLA semantics:
 - **Engine-relative int32 time.** Timestamps are milliseconds since an
   engine-chosen epoch so they fit int32 without enabling jax x64 (which would
   change dtype defaults for embedding applications). int32 ms wraps after
-  ~24.8 days; hosts re-base the epoch with :func:`rebase` well before that
+  ~24.8 days; hosts re-base the epoch with :func:`shift_clock` well before that
   (a single subtraction over ``starts``).
 
 All functions are pure, jit-compatible, and take ``now`` explicitly (the test
@@ -278,11 +278,12 @@ def avg_qps(spec: WindowSpec, total: jax.Array) -> jax.Array:
     return total.astype(jnp.float32) * (1000.0 / spec.interval_ms)
 
 
-def rebase(ws: WindowState, delta_ms: int) -> WindowState:
-    """Shift the engine epoch forward by ``delta_ms`` (host maintenance op, run
-    well before int32 engine-ms wraps at ~24.8 days)."""
-    starts = jnp.where(ws.starts == NEVER, ws.starts, ws.starts - jnp.int32(delta_ms))
-    return WindowState(starts=starts, counts=ws.counts)
+def shift_clock(clock: jax.Array, delta_ms: int) -> jax.Array:
+    """An engine-ms array (a ring's ``starts``, a shaper or breaker clock)
+    after the engine epoch moved forward by ``delta_ms``; ``NEVER`` stays
+    ``NEVER``. The host's maintenance op, run well before int32 engine-ms
+    wraps at ~24.8 days."""
+    return jnp.where(clock == NEVER, clock, clock - jnp.int32(delta_ms))
 
 
 # ---------------------------------------------------------------------------

@@ -492,3 +492,31 @@ class TestPerfRecordSync:
         assert f"jit_{outcome_step_donating(cfg).__name__}" == (
             "jit_outcome_step"
         )
+
+
+class TestStateColumnSync:
+    """The state's pytrees ↔ the table of state columns. Snapshot, delta,
+    MOVE blob, the clock's re-base and the mesh placement are loops over
+    the table (``cluster/state_codec.py``), so a leaf that ``EngineState``
+    or ``ParamState`` gains without an entry would ride none of them: it
+    fails here and not in a failover."""
+
+    def test_the_table_lists_the_leaves_the_pytrees_declare(self):
+        import typing
+
+        from sentinel_tpu.engine.param import PARAM_COLUMNS, ParamState
+        from sentinel_tpu.engine.state import STATE_COLUMNS, EngineState
+
+        declared = [
+            f"{family}.{field}"
+            for family, leaves in typing.get_type_hints(EngineState).items()
+            for field in leaves._fields
+        ] + [f"param.{field}" for field in ParamState._fields]
+        listed = [c.name for c in STATE_COLUMNS + PARAM_COLUMNS]
+        assert sorted(listed) == sorted(declared)
+        assert len(set(listed)) == len(listed)
+        assert {c.family for c in STATE_COLUMNS} == set(EngineState._fields)
+        # every window's counts are bucketed by a starts of its own family
+        for c in STATE_COLUMNS + PARAM_COLUMNS:
+            if c.kind == "window":
+                assert f"{c.family}.starts" in listed

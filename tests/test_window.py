@@ -424,5 +424,5 @@ class TestFutureWindows:
     def test_rebase(self):
         ws = W.make_window(SPEC, R, N_EVENTS)
         ws = add_pass(ws, 10_000, res=0, n=5)
-        ws2 = W.rebase(ws, 4_000)
+        ws2 = ws._replace(starts=W.shift_clock(ws.starts, 4_000))
         assert pass_sum(ws2, 6_000)[0] == 5

@@ -90,6 +90,20 @@ _STANDBY = int(TokenStatus.STANDBY)
 _TYPE_RELEASE = int(P.MsgType.CONCURRENT_RELEASE)
 _TYPE_BATCH_RELEASE = int(P.MsgType.BATCH_CONCURRENT_RELEASE)
 _TYPE_PARAM_FLOW = int(P.MsgType.PARAM_FLOW)
+# the flight recorder's lane of each kind of dispatch the device lane makes
+# (``ServerMetrics.LANE_KINDS``)
+_LANE_OF = {"flow": 0, "param": _TR.PARAM_LANE,
+            "concurrent": _TR.CONCURRENT_LANE}
+
+
+def _lane_kind(pull) -> str:
+    """Which of ``ServerMetrics.LANE_KINDS`` a pull's rows are, from its
+    count of values a request: 0 flow rows, above 0 hot-parameter rows,
+    below 0 the rows of concurrency frames."""
+    nv = pull[9]
+    return "flow" if nv == 0 else "param" if nv > 0 else "concurrent"
+
+
 # single frames that still reach the control loop (the shm door's, and a TCP
 # door's PARAM_FLOW frame with no value), drained a queue at a time and
 # decided through the services' batched entries (_answer_params,
@@ -1001,6 +1015,10 @@ class NativeTokenServer:
         # applied later than an acquire frame behind it on its connection)
         conc_dispatch = own("dispatch_concurrent_batch")
         held = None
+        # the turn between kinds, counted and changing nothing: when the
+        # pull in ``held`` was set aside, and the kind of the last dispatch
+        held_at = held_since = 0
+        last_kind = None
 
         def kind(pull) -> int:
             return pull[9]
@@ -1024,8 +1042,9 @@ class NativeTokenServer:
 
         try:
             while True:
-                if held is not None:
-                    item, held = held, None
+                from_held = held is not None
+                if from_held:
+                    item, held, held_since = held, None, held_at
                 else:
                     if not sem.acquire(timeout=0.5):
                         if self._abandon.is_set():
@@ -1066,6 +1085,7 @@ class NativeTokenServer:
                         continue
                     if kind(nxt) != kind(item):
                         held = nxt
+                        held_at = time.monotonic_ns()
                         break
                     pulls.append(nxt)
                     rows += len(nxt[0])
@@ -1280,8 +1300,26 @@ class NativeTokenServer:
                 _SM.dispatch_ms.record(dt_ms)
                 # one record per pull, never per frame or row, and outside
                 # the span that permit, prep, lock and launch split
+                waited_ms = 0.0
                 for p in pulls:
-                    _SM.queue_wait_ms.record((t0 - p[8]) * 1e-6)
+                    wait_ms = (t0 - p[8]) * 1e-6
+                    _SM.queue_wait_ms.record(wait_ms)
+                    waited_ms += wait_ms
+                # the lane's turn: its kind, whether the kind changed, and
+                # what a pull set aside for this turn waited in ``held``
+                switched = last_kind is not None and kind(item) != last_kind
+                last_kind = kind(item)
+                held_ns = t0 - held_since if from_held else 0
+                lane = _lane_kind(item)
+                _SM.count_lane_turn(
+                    lane, n_rows, len(pulls), waited_ms, switched,
+                    held_ns * 1e-6 if from_held else None,
+                )
+                if _TR.ARMED and (switched or from_held):
+                    _TR.record(
+                        _TR.LANE_TURN, shard=_LANE_OF[lane],
+                        aux=min(held_ns // 1000, 2**31 - 1), t_ns=t0,
+                    )
                 if overlapped:
                     # this group's whole dispatch arm ran while the prior
                     # group still computed — the pipelining win
@@ -1363,7 +1401,9 @@ class NativeTokenServer:
                 wait = np.zeros(n, np.int32)
                 tok = ()
             t_write = time.monotonic_ns()
-            _SM.decide_ms.record((t_write - t0) * 1e-6)
+            decide_ms = (t_write - t0) * 1e-6
+            _SM.decide_ms.record(decide_ms)
+            _SM.count_lane_decide(_lane_kind(pulls[0]), decide_ms)
             off = 0
             i = 0
             n_pulls = len(pulls)

@@ -268,6 +268,84 @@ class ServerMetrics:
             "ring still held a live token (cumulative).",
     }
 
+    # the native device lane's turns (server_native._device_loop): a
+    # dispatch is one kind of pull (flow rows, hot-parameter rows,
+    # concurrency rows), a queued pull of another kind waits in ``held`` for
+    # the next turn and cuts the fusion short. Per kind, and what the turn
+    # between kinds costs; sums of milliseconds are floats
+    LANE_KINDS = ("flow", "param", "concurrent")
+    _LANE_TURN_COUNTERS = {
+        "lane_turns_flow_total":
+            "Dispatches of the native device lane whose pulls were "
+            "flow rows (cumulative).",
+        "lane_turns_param_total":
+            "Dispatches of the native device lane whose pulls were "
+            "hot-parameter rows (cumulative).",
+        "lane_turns_concurrent_total":
+            "Dispatches of the native device lane whose pulls were "
+            "concurrency rows (cumulative).",
+        "lane_turn_rows_flow_total":
+            "Rows the lane's dispatches of flow rows carried "
+            "(cumulative).",
+        "lane_turn_rows_param_total":
+            "Rows the lane's dispatches of hot-parameter rows carried "
+            "(cumulative).",
+        "lane_turn_rows_concurrent_total":
+            "Rows the lane's dispatches of concurrency rows carried "
+            "(cumulative).",
+        "lane_turn_pulls_flow_total":
+            "Pulls the lane's dispatches of flow rows carried: the "
+            "count beside lane_queue_wait_ms_flow_total (cumulative).",
+        "lane_turn_pulls_param_total":
+            "Pulls the lane's dispatches of hot-parameter rows "
+            "carried: the count beside lane_queue_wait_ms_param_total "
+            "(cumulative).",
+        "lane_turn_pulls_concurrent_total":
+            "Pulls the lane's dispatches of concurrency rows carried: "
+            "the count beside lane_queue_wait_ms_concurrent_total "
+            "(cumulative).",
+        "lane_queue_wait_ms_flow_total":
+            "queue_wait_ms summed over the pulls of flow rows, ms "
+            "(cumulative).",
+        "lane_queue_wait_ms_param_total":
+            "queue_wait_ms summed over the pulls of hot-parameter "
+            "rows, ms (cumulative).",
+        "lane_queue_wait_ms_concurrent_total":
+            "queue_wait_ms summed over the pulls of concurrency rows, "
+            "ms (cumulative).",
+        "lane_decides_flow_total":
+            "Dispatches of flow rows whose verdicts a native reply "
+            "lane has read: the count beside "
+            "lane_decide_ms_flow_total (cumulative).",
+        "lane_decides_param_total":
+            "Dispatches of hot-parameter rows whose verdicts a native "
+            "reply lane has read: the count beside "
+            "lane_decide_ms_param_total (cumulative).",
+        "lane_decides_concurrent_total":
+            "Dispatches of concurrency rows whose verdicts a native "
+            "reply lane has read: the count beside "
+            "lane_decide_ms_concurrent_total (cumulative).",
+        "lane_decide_ms_flow_total":
+            "decide_ms summed over the lane's dispatches of flow "
+            "rows, ms (cumulative).",
+        "lane_decide_ms_param_total":
+            "decide_ms summed over the lane's dispatches of "
+            "hot-parameter rows, ms (cumulative).",
+        "lane_decide_ms_concurrent_total":
+            "decide_ms summed over the lane's dispatches of "
+            "concurrency rows, ms (cumulative).",
+        "lane_kind_switches_total":
+            "Dispatches of the native device lane whose kind differed "
+            "from the dispatch before (cumulative).",
+        "lane_held_turns_total":
+            "Dispatches that began from the pull the turn before had "
+            "set aside in held, because it was of another kind "
+            "(cumulative).",
+        "lane_held_wait_ms_total":
+            "Time those pulls waited in held, from their pop to the "
+            "start of their own dispatch_ms, ms (cumulative).",
+    }
+
     # what the decide step says of its cond-gated arms, per flow dispatch
     # (engine.decide.ARM_*): the step hands the predicates and row counts out
     # inside its packed verdicts, the service counts them here
@@ -398,6 +476,8 @@ class ServerMetrics:
         self._concurrent_lock = threading.Lock()
         self._concurrent = dict.fromkeys(self._CONCURRENT_COUNTERS, 0)
         self._concurrent_live = 0
+        self._lane_turn_lock = threading.Lock()
+        self._lane_turn = dict.fromkeys(self._LANE_TURN_COUNTERS, 0)
         # stage histograms, all in milliseconds except batch_size (requests).
         # 1µs..10s covers a sub-100µs device step and a 1s cold compile alike.
         # queue_wait_ms: per queue item on the asyncio door; on the native
@@ -634,6 +714,36 @@ class ServerMetrics:
         with self._concurrent_lock:
             return dict(self._concurrent,
                         concurrent_tokens_live=self._concurrent_live)
+
+    def count_lane_turn(self, kind: str, rows: int, pulls: int,
+                        queue_wait_ms: float, switched: bool,
+                        held_wait_ms: Optional[float] = None) -> None:
+        """The native device lane dispatched ``pulls`` pulls of ``kind``
+        (one of ``LANE_KINDS``) with ``rows`` rows, whose queue waits add up
+        to ``queue_wait_ms``; ``switched``: the dispatch before was of
+        another kind; ``held_wait_ms``: how long the first pull had waited
+        in ``held``, None when the turn did not begin from it."""
+        with self._lane_turn_lock:
+            t = self._lane_turn
+            t[f"lane_turns_{kind}_total"] += 1
+            t[f"lane_turn_rows_{kind}_total"] += int(rows)
+            t[f"lane_turn_pulls_{kind}_total"] += int(pulls)
+            t[f"lane_queue_wait_ms_{kind}_total"] += queue_wait_ms
+            t["lane_kind_switches_total"] += bool(switched)
+            if held_wait_ms is not None:
+                t["lane_held_turns_total"] += 1
+                t["lane_held_wait_ms_total"] += held_wait_ms
+
+    def count_lane_decide(self, kind: str, ms: float) -> None:
+        """A reply lane read the verdicts of one ``kind`` dispatch in
+        ``ms`` (what it recorded in ``decide_ms``)."""
+        with self._lane_turn_lock:
+            self._lane_turn[f"lane_decides_{kind}_total"] += 1
+            self._lane_turn[f"lane_decide_ms_{kind}_total"] += ms
+
+    def lane_turn_totals(self) -> Dict[str, float]:
+        with self._lane_turn_lock:
+            return dict(self._lane_turn)
 
     def count_decide_arms(self, rows: int, shaping: bool, pacing: bool,
                           occupy: bool, shaped: int, paced: int,
@@ -1381,6 +1491,7 @@ class ServerMetrics:
         out.update(self.param_single_totals())
         out.update(self.arm_totals())
         out.update(self.concurrent_totals())
+        out.update(self.lane_turn_totals())
         out["reply_first_total"] = self.reply_first_total
         out["prep_native_total"] = self.prep_native_total
         out["param_prep_native_total"] = self.param_prep_native_total
@@ -1787,6 +1898,8 @@ class ServerMetrics:
             *((name, self._CONCURRENT_COUNTERS[name], value)
               for name, value in self.concurrent_totals().items()
               if name in self._CONCURRENT_COUNTERS),
+            *((name, self._LANE_TURN_COUNTERS[name], value)
+              for name, value in self.lane_turn_totals().items()),
             ("reply_first_total",
              "Dispatches accounted after their reply was submitted: the "
              "native reply lane answers first and counts after "
@@ -1877,6 +1990,8 @@ class ServerMetrics:
         with self._concurrent_lock:
             self._concurrent = dict.fromkeys(self._CONCURRENT_COUNTERS, 0)
             self._concurrent_live = 0
+        with self._lane_turn_lock:
+            self._lane_turn = dict.fromkeys(self._LANE_TURN_COUNTERS, 0)
         with self._fold_lock, self._verdict_lock:
             self._pending = None  # what nobody has read goes unread
             self._verdicts.clear()

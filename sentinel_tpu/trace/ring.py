@@ -98,6 +98,11 @@ REPLY_TAKEN = 26  # a native reply lane's get() returned with a dispatched
 #                  us, as PERMIT's; the lane's next READY is its dispatch's).
 #                  Splits DEVICE_IN -> READY into reply-queue wait and what
 #                  was left of the device step
+LANE_TURN = 27   # the native device lane begins a dispatch of another kind
+#                  than its last, or from the pull it had set aside in
+#                  ``held`` (aggregate, xid=0; shard = the kind's lane: 0 flow,
+#                  PARAM_LANE, CONCURRENT_LANE; aux = us that pull waited in
+#                  ``held``, 0 when the turn did not begin from it)
 
 STAGE_NAMES: Dict[int, str] = {
     CLIENT_IN: "client_in",
@@ -126,6 +131,7 @@ STAGE_NAMES: Dict[int, str] = {
     ACCOUNT: "account",
     RX: "rx",
     REPLY_TAKEN: "reply_taken",
+    LANE_TURN: "lane_turn",
 }
 
 # one ring row: 24 bytes, fixed

@@ -89,10 +89,20 @@ def test_what_the_parent_cannot_read_here_lists_the_accepted_cells():
     check refuses a parent's traced line that lacks an accepted metric asked
     of the cell. Those metrics list the eight accepted cells, whole and in
     the file's order; a later PR, whose parent serves the cell on the data
-    plane, appends the ninth."""
+    plane, appends the ninth. (PR 49 appended its own cell, the tenth, which
+    its parent serves with batch frames.)"""
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         bench = json.load(f)
-    accepted = [w["name"] for w in bench["workloads"] if w["name"] != CELL]
+    accepted = [
+        "mesh-100k.tenants-zipf-open", "demo-cluster-1k.single-token",
+        "mesh-100k.sidecar-sat", "mesh-100k-pod4.tenants-zipf-open",
+        "hot-param-1k.keys-zipf-open",
+        "shaped-mesh-100k.tenants-zipf-prio-open",
+        "breaker-mesh-100k.tenants-zipf-health-cycle-open",
+        "concurrent-mesh-100k.tenants-zipf-hold-open"]
+    assert accepted == [w["name"] for w in bench["workloads"]][:8]
+    # PR 49's cell, whose parent serves it on the data plane, is the last
+    later = ["param-mesh-100k.tenants-zipf-callers-open"]
     nothing_to_read = {
         "door.intake_avg_ms", "door.rx_to_pull_avg_ms",
         "door.pull_wake_avg_ms", "door.submit_to_wire_avg_ms",
@@ -102,9 +112,9 @@ def test_what_the_parent_cannot_read_here_lists_the_accepted_cells():
         "lane.reply_queue_wait_avg_ms", "lane.fused_frames_per_dispatch",
         "service.decide_avg_ms", "client.outside_server_p50_ms"}
     lists = {m["name"]: m.get("workloads") for m in bench["per_layer"]}
-    assert len(accepted) == 8
+    assert len(accepted) == 8 and CELL not in accepted
     for name in nothing_to_read:
-        assert lists[name] == accepted, name
+        assert lists[name] == accepted + later, name
 
 
 # -- the mix at a tiny size ------------------------------------------------------

@@ -80,6 +80,8 @@ class Lane:
         self.warm_paced = base + 13  # WARM_UP_RATE_LIMITER
         self.breaker = base + 14  # plain rule + DegradeRule
         self.param = base + 15  # ClusterParamFlowRule, 5 per value
+        self.conc = base + 16  # ConcurrentFlowRule, 3 calls in flight
+        self.conc_wide = base + 17  # ConcurrentFlowRule, 1,000
         self.guard_ns = guard_ns
         self._next_tight = 0
 
@@ -123,6 +125,12 @@ class Lane:
 
         return [ClusterParamFlowRule(self.param, 5.0, namespace="ns0")]
 
+    def concurrent_rules(self):
+        from sentinel_tpu.cluster.concurrent import ConcurrentFlowRule
+
+        return [ConcurrentFlowRule(self.conc, 3, namespace="ns0"),
+                ConcurrentFlowRule(self.conc_wide, 1000, namespace="ns0")]
+
 
 def build_service(lanes, mesh_chips: int):
     """100k rules over 64 namespaces on the device, warmed up bare."""
@@ -158,6 +166,8 @@ def build_service(lanes, mesh_chips: int):
         ns_max_qps=float(NS_MAX_QPS),
     )
     service.load_param_rules([p for lane in lanes for p in lane.param_rules()])
+    service.load_concurrent_rules([c for lane in lanes
+                                   for c in lane.concurrent_rules()])
     n_rules = len(service.current_rules())
     expect("flow rules loaded", n_rules, N_FLOWS)
     say(f"  rule load {time.perf_counter() - t0:.1f}s: {n_plain} plain GLOBAL "
@@ -363,6 +373,49 @@ def check_single_param_frames(label, server, lane, n_values: int = 50,
         f"{n / max(pulls, 1):.0f} frames a pull")
 
 
+def check_concurrency(label, client, lane) -> None:
+    """The concurrency lane: a rule of 3 calls in flight asked by the
+    reference's single frames (types 3 and 4: one-row dispatches), then one
+    of 1,000 by a batch frame of 1,024 acquires and the batch release of
+    what it issued. Every dispatch of the lane was prepped by the native
+    pass (``sn_concurrent_prep``): a shortfall is a build without the
+    entry."""
+    from sentinel_tpu.engine import TokenStatus
+
+    m = service_metrics()
+    d0 = m.concurrent_totals()["concurrent_dispatch_total"]
+    n0 = m.concurrent_prep_native_total
+    got = [client.request_concurrent_token(lane.conc) for _ in range(4)]
+    expect("concurrency rule of 3, asked 4 times", [r.status for r in got],
+           [TokenStatus.OK] * 3 + [TokenStatus.BLOCKED])
+    expect("a release of a live token",
+           client.release_concurrent_token(got[0].token_id).status,
+           TokenStatus.RELEASE_OK)
+    expect("the same token again",
+           client.release_concurrent_token(got[0].token_id).status,
+           TokenStatus.ALREADY_RELEASE)
+    again = client.request_concurrent_token(lane.conc)
+    expect("the room it freed", again.status, TokenStatus.OK)
+    out = client.request_concurrent_batch(np.full(1024, lane.conc_wide))
+    if out is None:
+        raise RuntimeError(f"{label}: concurrency batch timed out or failed")
+    status, _remaining, _wait, tokens = out
+    expect("1024 acquires on a rule of 1,000: OK",
+           int((status == OK).sum()), 1000)
+    expect("... and BLOCKED", int((status == BLOCKED).sum()), 24)
+    back = np.concatenate([tokens[tokens != 0],
+                           [r.token_id for r in (*got[1:3], again)]])
+    released = client.release_concurrent_batch(back)
+    expect("every token given back: RELEASE_OK",
+           int((released == int(TokenStatus.RELEASE_OK)).sum()), len(back))
+    dispatches = m.concurrent_totals()["concurrent_dispatch_total"] - d0
+    native = m.concurrent_prep_native_total - n0
+    say(f"  concurrency lane through {label}: {dispatches} dispatches, "
+        f"concurrent_prep_native_total +{native}")
+    expect("concurrency dispatches the native pass prepped", native,
+           dispatches)
+
+
 def check_door(label, server, service, traffic, lane) -> None:
     from sentinel_tpu.cluster.client import TokenClient
     from sentinel_tpu.engine import TokenStatus
@@ -413,6 +466,8 @@ def check_door(label, server, service, traffic, lane) -> None:
 
         if hasattr(server, "stats"):  # the native door's data plane
             check_single_param_frames(label, server, lane)
+
+        check_concurrency(label, client, lane)
 
         # breaker: CLOSED passes; 8 reported exceptions (> 5, with at
         # least 5 completions) open it at the next request, which is shed

@@ -601,6 +601,59 @@ struct SortScratch {
   std::vector<int32_t> ping, pong;
 };
 
+inline SortScratch &sort_scratch() {
+  static thread_local SortScratch scratch;
+  return scratch;
+}
+
+// The width in bits of `top`, at least 1.
+inline int key_bits(uint64_t top) {
+  int bits = 1;
+  while (bits < 64 && top >> bits) ++bits;
+  return bits;
+}
+
+// The numbers 0 .. n-1 in ascending key(i), ties in ascending i
+// (np.argsort(kind="stable")'s order): a stable LSD radix sort in as few
+// digits as `bits`, the width of the largest key, needs. The order lives in
+// the thread's scratch until the thread sorts again.
+template <class Key>
+const int32_t *radix_order(int64_t n, int bits, Key key) {
+  SortScratch &scratch = sort_scratch();
+  const int passes = (bits + kDigitBits - 1) / kDigitBits;
+  const int digit = (bits + passes - 1) / passes;
+  const uint32_t mask = (uint32_t(1) << digit) - 1;
+  const size_t radix = size_t(1) << digit;
+  scratch.hist.assign(size_t(passes) * radix, 0);
+  uint32_t *hist = scratch.hist.data();
+  for (int64_t i = 0; i < n; ++i) {
+    const auto k = key(i);
+    for (int p = 0; p < passes; ++p)
+      ++hist[p * radix + ((k >> (p * digit)) & mask)];
+  }
+  for (int p = 0; p < passes; ++p) {
+    uint32_t run = 0;
+    for (size_t b = 0; b < radix; ++b) {
+      const uint32_t count = hist[p * radix + b];
+      hist[p * radix + b] = run;
+      run += count;
+    }
+  }
+  scratch.ping.resize(size_t(n));
+  scratch.pong.resize(size_t(n));
+  int32_t *src = scratch.ping.data(), *dst = scratch.pong.data();
+  for (int64_t i = 0; i < n; ++i) src[i] = int32_t(i);
+  for (int p = 0; p < passes; ++p) {
+    uint32_t *at = hist + p * radix;
+    for (int64_t k = 0; k < n; ++k) {
+      const int32_t i = src[k];
+      dst[at[(key(i) >> (p * digit)) & mask]++] = i;
+    }
+    std::swap(src, dst);
+  }
+  return src;
+}
+
 }  // namespace
 
 // keys[n_keys] ascending with their slots tab[n_keys]; the frame ids / acq /
@@ -638,43 +691,11 @@ SN_EXPORT int32_t sn_flow_prep(const int64_t *keys, const int32_t *tab,
   if (ascending) {
     for (int64_t i = 0; i < n; ++i) emit(i, i);
   } else {
-    // stable LSD radix sort of the row numbers on slot + 1 (so that -1, no
-    // rule, sorts first as it does for argsort), in as few digits as the
-    // frame's largest slot needs
-    static thread_local SortScratch scratch;
-    int bits = 1;
-    while (bits < 32 && (uint32_t(top) + 1) >> bits) ++bits;
-    const int passes = (bits + kDigitBits - 1) / kDigitBits;
-    const int digit = (bits + passes - 1) / passes;
-    const uint32_t mask = (uint32_t(1) << digit) - 1;
-    const size_t radix = size_t(1) << digit;
-    scratch.hist.assign(size_t(passes) * radix, 0);
-    uint32_t *hist = scratch.hist.data();
-    for (int64_t i = 0; i < n; ++i) {
-      const uint32_t key = uint32_t(slots[i]) + 1;
-      for (int p = 0; p < passes; ++p)
-        ++hist[p * radix + ((key >> (p * digit)) & mask)];
-    }
-    for (int p = 0; p < passes; ++p) {
-      uint32_t run = 0;
-      for (size_t b = 0; b < radix; ++b) {
-        const uint32_t count = hist[p * radix + b];
-        hist[p * radix + b] = run;
-        run += count;
-      }
-    }
-    scratch.ping.resize(size_t(n));
-    scratch.pong.resize(size_t(n));
-    int32_t *src = scratch.ping.data(), *dst = scratch.pong.data();
-    for (int64_t i = 0; i < n; ++i) src[i] = int32_t(i);
-    for (int p = 0; p < passes; ++p) {
-      uint32_t *at = hist + p * radix;
-      for (int64_t k = 0; k < n; ++k) {
-        const int32_t i = src[k];
-        dst[at[((uint32_t(slots[i]) + 1) >> (p * digit)) & mask]++] = i;
-      }
-      std::swap(src, dst);
-    }
+    // the row numbers sorted on slot + 1 (so that -1, no rule, sorts first
+    // as it does for argsort)
+    const int32_t *src = radix_order(
+        n, key_bits(uint32_t(top) + 1),
+        [&](int64_t i) { return uint32_t(slots[i]) + 1; });
     for (int64_t k = 0; k < n; ++k) {
       order[k] = src[k];
       emit(k, src[k]);
@@ -811,4 +832,120 @@ SN_EXPORT void sn_param_prep(
   std::memset(head, 0, size_t(bucket) * sizeof(int32_t));
   head[1] = int32_t(k);
   head[2] = int32_t(n);
+}
+
+// ---------------------------------------------------------------------------
+// Concurrent prep (cluster/concurrent.py `ConcurrentPlane.prep`, phase `prep`
+// of the concurrency lane): one dispatch's acquire and release rows into the
+// packed host arguments of its steps (engine/concurrent.py: lines ROW_SLOT /
+// ROW_COUNT / ROW_TOK_SLOT / ROW_TOK_GEN / ROW_HEAD) in ONE call with the GIL
+// released. The contract is byte identity with the numpy body that stays as
+// fallback and reference: np.flatnonzero of the two kinds, the
+// np.searchsorted look-up of the acquires' flows, `split_token_ids`, the two
+// np.argsort(kind="stable") a step and `pack_concurrent_rows`. Every output
+// is the caller's; only scratch lives here, per thread.
+
+namespace {
+
+constexpr int32_t kPadSlot = -2;  // engine.concurrent PAD_SLOT
+
+struct ConcurrentScratch {
+  std::vector<int32_t> acq_at, rel_at;      // row numbers, arrival order
+  std::vector<int64_t> flow_ids;            // of the acquire rows
+  std::vector<int32_t> slots;               // ... and their rule slots
+};
+
+}  // namespace
+
+// keys[n_keys] ascending with their slots tab[n_keys] (the plane's look-up
+// snapshot); ids / counts / is_release (bool bytes) of the dispatch's n rows:
+// n_acq acquires of counts[i] on flow ids[i], n - n_acq releases of token
+// ids[i]; max_tokens: the ring's slots (`split_token_ids`). plan[n_steps][6]:
+// a step's acquires [a_lo, a_hi) and releases [r_lo, r_hi), each counted
+// within its kind in arrival order, its bucket (>= 3, and no narrower than
+// either run) and the address of its int32[5 * bucket] argument. Writes every
+// cell of every argument (the clock 0: the caller writes it), and per kind
+// the row numbers in the order of the steps' lines: acq_rows[n_acq], each
+// step's run sorted by slot (-1, no rule, first), rel_rows[n - n_acq], each
+// step's run sorted by token id; ties in arrival order. Returns 0, or -1
+// where n_acq is not the number of acquire rows (nothing usable is written).
+SN_EXPORT int32_t sn_concurrent_prep(
+    const int64_t *keys, const int32_t *tab, int64_t n_keys,
+    const int64_t *ids, const int32_t *counts, const uint8_t *is_release,
+    int64_t n, int64_t n_acq, int64_t max_tokens, const int64_t *plan,
+    int64_t n_steps, int64_t *acq_rows, int64_t *rel_rows) {
+  static thread_local ConcurrentScratch scratch;
+  scratch.acq_at.resize(size_t(n));
+  scratch.rel_at.resize(size_t(n));
+  scratch.flow_ids.resize(size_t(n));
+  scratch.slots.resize(size_t(n));
+  int32_t *acq_at = scratch.acq_at.data(), *rel_at = scratch.rel_at.data();
+  int64_t *flow_ids = scratch.flow_ids.data();
+  int32_t *slots = scratch.slots.data();
+  int64_t seen = 0;
+  for (int64_t i = 0, r = 0; i < n; ++i) {
+    if (is_release[i]) {
+      rel_at[r++] = int32_t(i);
+    } else {
+      flow_ids[seen] = ids[i];
+      acq_at[seen++] = int32_t(i);
+    }
+  }
+  if (seen != n_acq) return -1;  // before anything of the caller's is written
+  lookup_slots(keys, tab, n_keys, flow_ids, n_acq, slots);
+  for (int64_t s = 0; s < n_steps; ++s) {
+    const int64_t *step = plan + 6 * s;
+    const int64_t a_lo = step[0], a = step[1] - a_lo;
+    const int64_t r_lo = step[2], r = step[3] - r_lo;
+    const int64_t bucket = step[4];
+    int32_t *p_slot = reinterpret_cast<int32_t *>(uintptr_t(step[5]));
+    int32_t *p_count = p_slot + bucket, *p_tok_slot = p_slot + 2 * bucket;
+    int32_t *p_tok_gen = p_slot + 3 * bucket, *head = p_slot + 4 * bucket;
+    // the acquires, grouped by slot
+    int32_t top = -1;
+    for (int64_t k = 0; k < a; ++k)
+      if (slots[a_lo + k] > top) top = slots[a_lo + k];
+    const int32_t *order = radix_order(
+        a, key_bits(uint32_t(top) + 1),
+        [&](int64_t k) { return uint32_t(slots[a_lo + k]) + 1; });
+    for (int64_t k = 0; k < a; ++k) {
+      const int64_t at = a_lo + order[k];
+      p_slot[k] = slots[at];
+      p_count[k] = counts[acq_at[at]];
+      acq_rows[a_lo + k] = acq_at[at];
+    }
+    for (int64_t k = a; k < bucket; ++k) p_slot[k] = kPadSlot;
+    std::memset(p_count + a, 0, size_t(bucket - a) * sizeof(int32_t));
+    // the releases, sorted by token id: signed order is unsigned order with
+    // the sign bit flipped, and the keys are taken from the run's least
+    const uint64_t sign = uint64_t(1) << 63;
+    auto token = [&](int64_t k) {
+      return uint64_t(ids[rel_at[r_lo + k]]) ^ sign;
+    };
+    uint64_t least = ~uint64_t(0), most = 0;
+    for (int64_t k = 0; k < r; ++k) {
+      const uint64_t t = token(k);
+      if (t < least) least = t;
+      if (t > most) most = t;
+    }
+    order = radix_order(r, key_bits(r ? most - least : 0),
+                        [&](int64_t k) { return token(k) - least; });
+    for (int64_t k = 0; k < r; ++k) {
+      const int32_t row = rel_at[r_lo + order[k]];
+      // split_token_ids: slot -1, generation 0 for an id that is not one
+      // (0, negative, or past what 31 bits of generation reach)
+      const int64_t id = ids[row];
+      const int64_t gen = id > 0 ? id / max_tokens : 0;
+      const bool bad = id <= 0 || gen > INT32_MAX;
+      p_tok_slot[k] = bad ? -1 : int32_t(id % max_tokens);
+      p_tok_gen[k] = bad ? 0 : int32_t(gen);
+      rel_rows[r_lo + k] = row;
+    }
+    for (int64_t k = r; k < bucket; ++k) p_tok_slot[k] = -1;
+    std::memset(p_tok_gen + r, 0, size_t(bucket - r) * sizeof(int32_t));
+    std::memset(head, 0, size_t(bucket) * sizeof(int32_t));
+    head[1] = int32_t(a);
+    head[2] = int32_t(r);
+  }
+  return 0;
 }

@@ -50,6 +50,7 @@ import numpy as np
 
 from sentinel_tpu.engine import concurrent as E
 from sentinel_tpu.engine.rules import ThresholdMode
+from sentinel_tpu.native import lib as _native
 
 DEFAULT_RESOURCE_TIMEOUT_MS = 2_000  # ClusterFlowConfig#resourceTimeout default
 # A token past its time is reclaimed within this: the expiry scan goes round
@@ -189,14 +190,44 @@ class ConcurrentPlane:
     def bucket_for(self, rows: int) -> int:
         return next(b for b in self.buckets if rows <= b)
 
+    def step_plan(self, n_acq: int, n_rel: int):
+        """The steps of a dispatch of ``n_acq`` acquires and ``n_rel``
+        releases: ``(a_lo, a_hi, r_lo, r_hi, bucket)`` a step, each kind's
+        rows counted in arrival order. One step unless a kind's rows pass
+        the largest bucket. The releases' steps come first: the last of
+        them also carries the first chunk of the acquires."""
+        cap = self.buckets[-1]
+        rel_chunks = [(lo, min(lo + cap, n_rel))
+                      for lo in range(0, n_rel, cap)] or [(0, 0)]
+        acq_chunks = [(lo, min(lo + cap, n_acq))
+                      for lo in range(0, n_acq, cap)] or [(0, 0)]
+        plan = []
+        for k in range(len(rel_chunks) - 1 + len(acq_chunks)):
+            r_lo, r_hi = rel_chunks[k] if k < len(rel_chunks) else (0, 0)
+            j = k - (len(rel_chunks) - 1)
+            a_lo, a_hi = acq_chunks[j] if j >= 0 else (0, 0)
+            plan.append((a_lo, a_hi, r_lo, r_hi,
+                         self.bucket_for(max(a_hi - a_lo, r_hi - r_lo, 1))))
+        return plan
+
     def prep(self, lookup, ids: np.ndarray, counts: np.ndarray,
              is_release: np.ndarray):
-        """A dispatch's rows as the packed arguments of its steps (one
-        unless a kind's rows pass the largest bucket), with what
-        :meth:`unpack` needs to put the verdicts back in request order. The
-        releases' steps come first: the last of them also carries the first
-        chunk of the acquires."""
-        cap = self.buckets[-1]
+        """A dispatch's rows as the packed arguments of its steps
+        (:meth:`step_plan`), with what :meth:`unpack` needs to put the
+        verdicts back in request order, and whether the native pass prepped
+        them (``native.lib.concurrent_prep``, one call with the GIL
+        released) or, where the library is not built, :meth:`prep_numpy`:
+        the same bytes either way."""
+        n_rel = int(np.count_nonzero(is_release))
+        plan = self.step_plan(len(ids) - n_rel, n_rel)
+        parts = _native.concurrent_prep(lookup, ids, counts, is_release,
+                                        self.config.max_tokens, plan)
+        if parts is not None:
+            return parts, True
+        return self.prep_numpy(lookup, ids, counts, is_release, plan), False
+
+    def prep_numpy(self, lookup, ids, counts, is_release, plan):
+        """:meth:`prep` in numpy: the fallback, and the tests' reference."""
         rel_rows = np.flatnonzero(is_release)
         acq_rows = np.flatnonzero(~is_release)
         fids, fslots = lookup
@@ -209,20 +240,11 @@ class ConcurrentPlane:
             slots = np.full(len(acq_rows), E.NO_SLOT, np.int32)
         acq_counts = counts[acq_rows]
         tok_slot, tok_gen = E.split_token_ids(self.config, ids[rel_rows])
-        rel_chunks = [(lo, min(lo + cap, len(rel_rows)))
-                      for lo in range(0, len(rel_rows), cap)] or [(0, 0)]
-        acq_chunks = [(lo, min(lo + cap, len(acq_rows)))
-                      for lo in range(0, len(acq_rows), cap)] or [(0, 0)]
-        n_steps = len(rel_chunks) - 1 + len(acq_chunks)
         parts = []
-        for k in range(n_steps):
-            r_lo, r_hi = rel_chunks[k] if k < len(rel_chunks) else (0, 0)
-            j = k - (len(rel_chunks) - 1)
-            a_lo, a_hi = acq_chunks[j] if j >= 0 else (0, 0)
+        for a_lo, a_hi, r_lo, r_hi, bucket in plan:
             order_a = np.argsort(slots[a_lo:a_hi], kind="stable")
             ids_r = ids[rel_rows[r_lo:r_hi]]
             order_r = np.argsort(ids_r, kind="stable")
-            bucket = self.bucket_for(max(a_hi - a_lo, r_hi - r_lo, 1))
             packed = E.pack_concurrent_rows(
                 bucket, slots[a_lo:a_hi][order_a],
                 acq_counts[a_lo:a_hi][order_a],

@@ -1294,11 +1294,12 @@ class DefaultTokenService(TokenService):
         go to the always-on histograms and, armed, to the flight recorder
         with the same stamps. ``native_prep``: a dispatch whose prep was
         the native pass (``prep_native_total``, of the flow lane;
-        ``param_prep_native_total``, of the hot-parameter lane)."""
+        ``param_prep_native_total``, of the hot-parameter lane;
+        ``concurrent_prep_native_total``, of the concurrency lane)."""
         t_out = time.monotonic_ns()
         _SM.prep_ms.record((t_prep - t_enter) * 1e-6)
         if native_prep:
-            _SM.count_prep_native(param=lane == _TR.PARAM_LANE)
+            _SM.count_prep_native(lane)
         _SM.lock_wait_ms.record((t_locked - t_prep) * 1e-6)
         _SM.launch_ms.record((t_out - t_locked) * 1e-6)
         if _TR.ARMED:
@@ -2074,7 +2075,7 @@ class DefaultTokenService(TokenService):
         acq = (np.ones(n, np.int32) if counts is None
                else np.broadcast_to(np.asarray(counts, np.int32), (n,)))
         lookup = plane.lookup
-        parts = plane.prep(lookup, ids, acq, rel)
+        parts, native_prep = plane.prep(lookup, ids, acq, rel)
         steps = [plane.step_fn(b) for b, *_rest in parts]
         t_prep = time.monotonic_ns()
         with self._lock:
@@ -2082,7 +2083,7 @@ class DefaultTokenService(TokenService):
             seq = self._dispatch_seq = self._dispatch_seq + 1
             if plane.lookup is not lookup:
                 # rules reloaded between prep and step: the slots moved
-                parts = plane.prep(plane.lookup, ids, acq, rel)
+                parts, native_prep = plane.prep(plane.lookup, ids, acq, rel)
                 steps = [plane.step_fn(b) for b, *_rest in parts]
             now = self._engine_now()
             outs = []
@@ -2094,7 +2095,7 @@ class DefaultTokenService(TokenService):
         for verdicts in outs:
             verdicts.copy_to_host_async()
         self._dispatched(t_enter, t_prep, t_locked, seq, n,
-                         lane=_TR.CONCURRENT_LANE)
+                         lane=_TR.CONCURRENT_LANE, native_prep=native_prep)
         n_rel = int(rel.sum())
 
         def _read():

@@ -508,10 +508,9 @@ class ServerMetrics:
         self._verdict_copy_ready = 0
         # dispatches whose account half ran after their reply was submitted
         self._reply_first = 0
-        # flow dispatches whose host prep was the native pass, and the
-        # hot-parameter lane's
-        self._prep_native = 0
-        self._param_prep_native = 0
+        # dispatches whose host prep was the native pass, by lane (the
+        # trace ring's numbers: flow, hot-parameter, concurrency)
+        self._prep_native = [0, 0, 0]
         self._verdict_read_lock = threading.Lock()
         # traffic-shaping waits: every SHOULD_WAIT verdict that carried a
         # positive wait hint (paced admission or priority occupy) — count
@@ -641,27 +640,30 @@ class ServerMetrics:
         with self._verdict_read_lock:
             return self._reply_first
 
-    def count_prep_native(self, param: bool = False) -> None:
-        """One flow dispatch was prepped by the native pass
-        (``sn_flow_prep``), or with ``param`` one hot-parameter dispatch
-        (``sn_param_prep``). A dispatch prepped in numpy, because the
-        library is not built, does not count: over ``prep_ms``'s count this
-        says whether the mechanism engaged."""
+    def count_prep_native(self, lane: int = 0) -> None:
+        """One dispatch of ``lane`` (``trace.ring``'s number: 0 flow,
+        ``PARAM_LANE``, ``CONCURRENT_LANE``) was prepped by its native pass
+        (``sn_flow_prep`` / ``sn_param_prep`` / ``sn_concurrent_prep``). A
+        dispatch prepped in numpy, because the library is not built, does
+        not count: over ``prep_ms``'s count this says whether the mechanism
+        engaged."""
         with self._verdict_read_lock:
-            if param:
-                self._param_prep_native += 1
-            else:
-                self._prep_native += 1
+            self._prep_native[lane] += 1
 
     @property
     def prep_native_total(self) -> int:
         with self._verdict_read_lock:
-            return self._prep_native
+            return self._prep_native[0]
 
     @property
     def param_prep_native_total(self) -> int:
         with self._verdict_read_lock:
-            return self._param_prep_native
+            return self._prep_native[1]
+
+    @property
+    def concurrent_prep_native_total(self) -> int:
+        with self._verdict_read_lock:
+            return self._prep_native[2]
 
     def count_param_dispatch(self, requests: int, values: int, blocked: int,
                              no_rule: int) -> None:
@@ -1406,6 +1408,7 @@ class ServerMetrics:
             "replyFirstTotal": self.reply_first_total,
             "prepNativeTotal": self.prep_native_total,
             "paramPrepNativeTotal": self.param_prep_native_total,
+            "concurrentPrepNativeTotal": self.concurrent_prep_native_total,
             "accountFoldsTotal": self.account_folds_total,
             "shedTotal": self.shed_total,
             "shedByReason": self.shed_totals(),
@@ -1495,6 +1498,8 @@ class ServerMetrics:
         out["reply_first_total"] = self.reply_first_total
         out["prep_native_total"] = self.prep_native_total
         out["param_prep_native_total"] = self.param_prep_native_total
+        out["concurrent_prep_native_total"] = (
+            self.concurrent_prep_native_total)
         out["account_folds_total"] = self.account_folds_total
         out["param_impl"], out["param_impl_reason"] = self.param_impl
         out["shed_total"] = self.shed_totals()
@@ -1913,6 +1918,10 @@ class ServerMetrics:
              "Hot-parameter dispatches whose host prep was the native pass; "
              "beside param_dispatch_total, as prep_native_total "
              "(cumulative).", self.param_prep_native_total),
+            ("concurrent_prep_native_total",
+             "Concurrency dispatches whose host prep was the native pass; "
+             "beside concurrent_dispatch_total, as prep_native_total "
+             "(cumulative).", self.concurrent_prep_native_total),
             ("account_folds_total",
              "Per-namespace fan-outs of the verdict accounting: dispatches "
              "are counted at deposit and folded into the verdict counters, "
@@ -1979,8 +1988,7 @@ class ServerMetrics:
             self._verdict_host_reads = 0
             self._verdict_copy_ready = 0
             self._reply_first = 0
-            self._prep_native = 0
-            self._param_prep_native = 0
+            self._prep_native = [0, 0, 0]
         with self._param_lock:
             self._param = dict.fromkeys(self._PARAM_COUNTERS, 0)
             self._param_single = dict.fromkeys(

@@ -232,6 +232,15 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         lib._sn_has_param_prep = True
     except AttributeError:
         lib._sn_has_param_prep = False
+    # concurrent prep (sentinel_native.cpp), as flow prep
+    try:
+        lib.sn_concurrent_prep.argtypes = [
+            P, P, I64, P, P, P, I64, I64, I64, P, I64, P, P,
+        ]
+        lib.sn_concurrent_prep.restype = I32
+        lib._sn_has_concurrent_prep = True
+    except AttributeError:
+        lib._sn_has_concurrent_prep = False
     return lib
 
 
@@ -397,6 +406,61 @@ def param_prep(snapshot, flow_ids, acq, hashes, bucket: int, geometry):
         req_slot.ctypes.data, packed.ctypes.data,
     )
     return req_slot, packed
+
+
+def concurrent_prep(lookup, ids, counts, is_release, max_tokens: int, plan):
+    """One concurrency dispatch's rows prepped in ONE native pass with the
+    GIL released (``sn_concurrent_prep``): the two kinds split in arrival
+    order, the acquires' flows looked up in ``lookup`` (the plane's
+    ``(sorted int64 flow ids, int32 slots)``), the releases' token ids split
+    by ``max_tokens`` (``engine.concurrent.split_token_ids``), each step's
+    runs sorted (acquires by slot, releases by token id, ties in arrival
+    order) and its packed argument written. ``plan`` is
+    ``ConcurrentPlane.step_plan``'s: ``(a_lo, a_hi, r_lo, r_hi, bucket)`` a
+    step. Returns the ``parts`` of ``ConcurrentPlane.prep``, ``(bucket,
+    packed, acq_rows, rel_rows)`` a step, byte for byte what its numpy body
+    gives (``tests/test_native_concurrent_prep.py``); every ``packed`` is a
+    fresh ``int32[5, bucket]`` with the clock at 0 that only the caller
+    holds. None where the library is absent or older than this entry: the
+    caller preps in numpy."""
+    lib = load()
+    if lib is None or not lib._sn_has_concurrent_prep:
+        return None
+    import numpy as np
+
+    keys, tab = lookup
+    ids = np.ascontiguousarray(ids, np.int64)
+    counts = np.ascontiguousarray(counts, np.int32)
+    is_release = np.ascontiguousarray(is_release, np.bool_)
+    n = ids.shape[0]
+    n_acq = plan[-1][1]  # the acquires' last chunk is the last step's
+    n_rel = n - n_acq
+    if counts.shape != (n,) or is_release.shape != (n,) or n_rel < 0:
+        raise ValueError(
+            f"concurrent_prep: {ids.shape} ids, {counts.shape} counts and "
+            f"{is_release.shape} kinds against a plan of {n_acq} acquires")
+    acq_rows, rel_rows = np.empty(n_acq, np.int64), np.empty(n_rel, np.int64)
+    parts, steps = [], []
+    for a_lo, a_hi, r_lo, r_hi, bucket in plan:
+        if not (0 <= a_lo <= a_hi <= n_acq and 0 <= r_lo <= r_hi <= n_rel
+                and bucket >= max(a_hi - a_lo, r_hi - r_lo, 3)):
+            raise ValueError(
+                f"concurrent_prep: step {(a_lo, a_hi, r_lo, r_hi, bucket)} "
+                f"of {n_acq} acquires and {n_rel} releases")
+        packed = np.empty((5, bucket), np.int32)
+        parts.append((bucket, packed, acq_rows[a_lo:a_hi],
+                      rel_rows[r_lo:r_hi]))
+        steps.append((a_lo, a_hi, r_lo, r_hi, bucket, packed.ctypes.data))
+    steps = np.array(steps, np.int64)
+    if lib.sn_concurrent_prep(
+            keys.ctypes.data, tab.ctypes.data, keys.shape[0], ids.ctypes.data,
+            counts.ctypes.data, is_release.ctypes.data, n, n_acq, max_tokens,
+            steps.ctypes.data, len(parts), acq_rows.ctypes.data,
+            rel_rows.ctypes.data):
+        raise ValueError(
+            f"concurrent_prep: the plan's {n_acq} acquires are not the "
+            f"frame's")
+    return parts
 
 
 def batch_decode_req(payload: bytes):

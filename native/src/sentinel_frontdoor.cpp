@@ -50,7 +50,11 @@
 // Control plane (forwarded to Python, rare): PING, a PARAM_FLOW frame with
 //   no value, every other type (replication, moves, leases, shares, outcome
 //   reports), plus open/close connection events so the host keeps its
-//   ConnectionManager (namespace groups, idle sweep) exact.
+//   ConnectionManager (namespace groups, idle sweep) exact. After every
+//   push to the control queue the door rings the server's bell
+//   (sn_bell_*, below; sn_fd_set_bell), outside its own mutex: the host's
+//   one control thread sleeps on the bell, not on this door's cv, which
+//   every data frame notifies.
 //
 // Threading: one IO thread owns epoll, all sockets, and all writes. Python
 // threads call wait_batch/submit/control APIs guarded by a mutex + eventfd
@@ -125,6 +129,18 @@ constexpr size_t kMaxControls = 8192;
 
 struct Frontdoor;
 void wake(Frontdoor *s);
+
+// The control lane's bell: one a server, rung by every door of it (the TCP
+// doors here, the shm door through sn_bell_ring) after a push to its
+// control queue, waited on by the host's one control thread with the GIL
+// released. The generation is what keeps a ring between the waiter's last
+// empty look at the queues and its wait from being lost: a wait returns at
+// once when the generation is not the one the waiter saw last.
+struct Bell {
+  std::mutex mu;
+  std::condition_variable cv;
+  uint64_t generation = 0;  // guarded by mu
+};
 
 inline uint16_t be16(const uint8_t *p) {
   return uint16_t(p[0]) << 8 | uint16_t(p[1]);
@@ -258,6 +274,9 @@ struct Frontdoor {
 
   std::deque<Control> controls;  // guarded by mu
   bool controls_was_full = false;  // guarded by mu
+  // rung after every push to controls, outside mu (sn_fd_set_bell); null
+  // until the host sets one
+  std::atomic<Bell *> bell{nullptr};
 
   // listener parking after accept failure (EMFILE etc): level-triggered
   // epoll would otherwise spin the IO thread at 100% until an fd frees
@@ -302,6 +321,19 @@ int64_t mono_ns() {
 }
 
 int64_t mono_ms() { return mono_ns() / 1000000; }
+
+void bell_ring(Bell *b) {
+  {
+    std::lock_guard<std::mutex> lk(b->mu);
+    ++b->generation;
+  }
+  b->cv.notify_all();
+}
+
+// a control event was queued (call with s->mu released)
+void ring_bell(Frontdoor *s) {
+  if (Bell *b = s->bell.load(std::memory_order_acquire)) bell_ring(b);
+}
 
 // n spans of one length end here (negative: a clock that went back; skipped)
 void span_count(SpanHist &h, int64_t ns, uint64_t n) {
@@ -371,6 +403,7 @@ void close_conn(Frontdoor *s, Conn &c) {
     s->controls.push_back({2, c.fd, c.gen, std::string()});
   }
   s->cv.notify_all();
+  ring_bell(s);
 }
 
 // Parse as many frames as the arena allows; returns false if the conn
@@ -379,6 +412,7 @@ void close_conn(Frontdoor *s, Conn &c) {
 // full arena reads nothing more, so the stamp holds for what it buffered).
 bool parse_frames(Frontdoor *s, Conn &c) {
   bool notify = false;
+  bool control = false;  // a control frame was queued: ring the bell
   bool wake_self = false;
   {
     std::lock_guard<std::mutex> lk(s->mu);
@@ -513,6 +547,7 @@ bool parse_frames(Frontdoor *s, Conn &c) {
              std::string(reinterpret_cast<const char *>(payload), flen),
              mono_ns()});
         notify = true;
+        control = true;
       }
       c.rpos += 2 + flen;
     }
@@ -525,6 +560,7 @@ bool parse_frames(Frontdoor *s, Conn &c) {
     c.rpos = 0;
   }
   if (notify) s->cv.notify_all();
+  if (control) ring_bell(s);
   // schedule an outbox drain for inline responses (parse runs on the IO
   // thread; the eventfd write makes the next epoll_wait return at once)
   if (wake_self) wake(s);
@@ -615,6 +651,7 @@ void io_loop(Frontdoor *s) {
             s->controls.push_back({1, cfd, c.gen, c.peer});
           }
           s->cv.notify_all();
+          ring_bell(s);
         }
         continue;
       }
@@ -1155,6 +1192,41 @@ SN_EXPORT int32_t sn_fd_next_control(void *h, int32_t *fd_out,
 SN_EXPORT void sn_fd_set_idle_ttl(void *h, int64_t ttl_ms) {
   static_cast<Frontdoor *>(h)->idle_ttl_ms.store(ttl_ms,
                                                  std::memory_order_relaxed);
+}
+
+// The control lane's bell (struct Bell). sn_bell_wait blocks until the
+// generation is no longer ``seen`` or ``timeout_ms`` passed, and returns the
+// generation it found: the caller's next ``seen``. A wait that a ring ends
+// (or finds rung already) sleeps ``settle_ms`` more before it returns, so
+// that the waiter does not touch the interpreter in the moment the frame
+// arrived (server_native._control_loop says why); a time-out does not, nor
+// does ``timeout_ms`` 0, which only reads. A bell outlives every door it
+// was handed to.
+SN_EXPORT void *sn_bell_new() { return new (std::nothrow) Bell(); }
+
+SN_EXPORT void sn_bell_free(void *b) { delete static_cast<Bell *>(b); }
+
+SN_EXPORT void sn_bell_ring(void *b) { bell_ring(static_cast<Bell *>(b)); }
+
+SN_EXPORT uint64_t sn_bell_wait(void *h, uint64_t seen, int32_t timeout_ms,
+                                int32_t settle_ms) {
+  auto *b = static_cast<Bell *>(h);
+  std::unique_lock<std::mutex> lk(b->mu);
+  bool rung = b->cv.wait_for(lk, std::chrono::milliseconds(timeout_ms),
+                             [&] { return b->generation != seen; });
+  if (rung && timeout_ms > 0 && settle_ms > 0) {
+    lk.unlock();
+    std::this_thread::sleep_for(std::chrono::milliseconds(settle_ms));
+    lk.lock();
+  }
+  return b->generation;
+}
+
+// Ring ``bell`` (sn_bell_new's, or null for none) after every push to this
+// door's control queue.
+SN_EXPORT void sn_fd_set_bell(void *h, void *bell) {
+  static_cast<Frontdoor *>(h)->bell.store(static_cast<Bell *>(bell),
+                                          std::memory_order_release);
 }
 
 // Close one connection from the host side (e.g. an operator kick).

@@ -63,6 +63,9 @@
 
 #define SN_EXPORT extern "C" __attribute__((visibility("default")))
 
+// the control lane's bell (sentinel_frontdoor.cpp)
+extern "C" void sn_bell_ring(void *bell);
+
 namespace {
 
 constexpr int kHead = 5;     // xid:i32 + type:u8
@@ -219,6 +222,9 @@ struct ShmDoor {
   bool arena_was_full = false;
   std::deque<Control> controls;
   bool controls_was_full = false;
+  // the server's control bell (sentinel_frontdoor.cpp's sn_bell_*), rung
+  // after every push to controls, outside mu; null until sn_shm_set_bell
+  std::atomic<void *> bell{nullptr};
 
   std::mutex segs_mu;  // the map only; segments pin via shared_ptr
   std::unordered_map<int32_t, std::shared_ptr<Segment>> segs;
@@ -249,6 +255,11 @@ struct ShmDoor {
     frames.reserve(4096);
   }
 };
+
+// a control event was queued (call with s->mu released)
+void ring_bell(ShmDoor *s) {
+  if (void *b = s->bell.load(std::memory_order_acquire)) sn_bell_ring(b);
+}
 
 void ring_server_doorbell(ShmDoor *s) {
   if (!s->ctl) return;
@@ -317,6 +328,7 @@ void drop_segment(ShmDoor *s, const std::shared_ptr<Segment> &seg) {
     s->controls.push_back({2, seg->id, seg->gen, std::string()});
   }
   s->cv.notify_all();
+  ring_bell(s);
 }
 
 // Validate + attach one segment file. Returns true if attached.
@@ -377,6 +389,7 @@ bool attach_segment(ShmDoor *s, const std::string &name) {
     s->controls.push_back({1, seg->id, seg->gen, std::move(peer)});
   }
   s->cv.notify_all();
+  ring_bell(s);
   return true;
 }
 
@@ -419,6 +432,7 @@ bool drain_segment(ShmDoor *s, const std::shared_ptr<Segment> &seg) {
   }
   bool progress = false;
   bool notify = false;
+  bool control = false;  // a control frame was queued: ring the bell
   bool violated = false;
   std::vector<std::pair<int32_t, std::string>> inline_rsps;  // empty batches
   {
@@ -501,6 +515,7 @@ bool drain_segment(ShmDoor *s, const std::shared_ptr<Segment> &seg) {
              mono_us() * 1000});
         s->bytes_in.fetch_add(flen, std::memory_order_relaxed);
         notify = true;
+        control = true;
       }
       ++head;
       progress = true;
@@ -508,6 +523,7 @@ bool drain_segment(ShmDoor *s, const std::shared_ptr<Segment> &seg) {
   }
   if (progress) seg->hdr->req_head.store(head, std::memory_order_release);
   if (notify) s->cv.notify_all();
+  if (control) ring_bell(s);
   if (!inline_rsps.empty()) {
     std::lock_guard<std::mutex> lk(seg->w_mu);
     for (auto &pr : inline_rsps)
@@ -886,6 +902,12 @@ SN_EXPORT int32_t sn_shm_next_control(void *h, int32_t *fd_out,
   *t_ns_out = c.t_ns;
   if (n > 0 && n <= max_len) memcpy(payload_out, c.payload.data(), size_t(n));
   return c.kind;
+}
+
+// Ring ``bell`` (sn_bell_new's, or null for none) after every push to this
+// door's control queue.
+SN_EXPORT void sn_shm_set_bell(void *h, void *bell) {
+  static_cast<ShmDoor *>(h)->bell.store(bell, std::memory_order_release);
 }
 
 SN_EXPORT void sn_shm_close_conn(void *h, int32_t fd, int32_t gen) {

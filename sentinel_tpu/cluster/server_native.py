@@ -24,11 +24,12 @@ brownout, age shed and overload answer a single PARAM_FLOW or CONCURRENT_*
 frame as they answer a single FLOW frame.
 
 Control-plane frames (PING handshake, replication, moves, leases, shares,
-completion reports) and open/close events surface through a low-rate poll
-thread so namespace connection groups (AVG_LOCAL scaling) and the host-side
+completion reports) and open/close events surface through one low-rate
+control thread, which sleeps on a bell the doors ring when they queue one,
+so namespace connection groups (AVG_LOCAL scaling) and the host-side
 paths stay exactly as in the asyncio server. The shm door stays flow-only:
 the single PARAM_FLOW and CONCURRENT_* frames of its clients still reach
-the poll thread, as does a TCP door's PARAM_FLOW frame that carries no value
+the control thread, as does a TCP door's PARAM_FLOW frame that carries no value
 (no row of the sketch); what a door has queued of them is drained and
 decided through the services' batched entries.
 API-compatible with ``TokenServer`` (start/stop/
@@ -94,6 +95,13 @@ _TYPE_PARAM_FLOW = int(P.MsgType.PARAM_FLOW)
 # (``ServerMetrics.LANE_KINDS``)
 _LANE_OF = {"flow": 0, "param": _TR.PARAM_LANE,
             "concurrent": _TR.CONCURRENT_LANE}
+# the longest the control thread sleeps on the doors' bell before it looks
+# at ``_stop`` again: the lanes' own cadence (``wait_any_into``'s default)
+_CONTROL_WAIT_MS = 100
+# how long the control thread stays asleep after a ring before it looks at
+# its doors, ms: what the 2 ms poll cost an event at the mean
+# (``_control_loop`` says what the wait is good for)
+_CONTROL_SETTLE_MS = 1
 
 
 def _lane_kind(pull) -> str:
@@ -217,6 +225,7 @@ class NativeTokenServer:
         )
         self._door = None  # door 0 (back-compat handle; owns self.port)
         self._doors: List = []
+        self._bell = None  # the doors' control bell (start())
         self._threads: List[threading.Thread] = []
         self._lane_threads: List[threading.Thread] = []
         self._stop = threading.Event()
@@ -393,6 +402,15 @@ class NativeTokenServer:
             doors.append(self._shm_door)  # control loop + stats cover it
         self._doors = doors
         self._door = doors[0]
+        # one bell a server: every door rings it after a push to its control
+        # queue, the control thread sleeps on it. None where the loaded
+        # library is older than the entry: the thread then polls
+        from sentinel_tpu.native.lib import control_bell
+
+        self._bell = control_bell()
+        if self._bell is not None:
+            for d in doors:
+                d.set_bell(self._bell)
         # the TCP doors count their spans (door_in / door_out /
         # door_residence) on the registry's own bounds, and the registry
         # folds what they counted in on every read
@@ -651,6 +669,8 @@ class NativeTokenServer:
                     for p in pulls:
                         pool.release(p[6])
         self._stop.set()
+        if self._bell is not None:
+            self._bell.ring()  # the control thread leaves its wait at once
         for d in self._doors:
             d.stop()
         # the IO threads are joined: what they counted is final
@@ -666,6 +686,7 @@ class NativeTokenServer:
         self._doors = []
         self._door = None
         self._shm_door = None
+        self._bell = None
         # the door closed every socket without emitting CTRL_CLOSE (the
         # control thread is already down), so deregister the clients here —
         # a restart would otherwise inherit phantom connections that keep
@@ -1456,11 +1477,26 @@ class NativeTokenServer:
 
     # -- control plane ------------------------------------------------------
     def _control_loop(self) -> None:
-        # one poll thread covers every shard door: control traffic is
-        # low-rate (handshakes, params, repl frames), and (fd, gen) keys
-        # are globally unique across doors, so the session maps need no
+        # one thread covers every shard door: control traffic is low-rate
+        # (handshakes, params, repl frames), and (fd, gen) keys are
+        # globally unique across doors, so the session maps need no
         # per-door namespacing — only the REPLY must go out through the
-        # door that owns the connection
+        # door that owns the connection. It does not poll: when no door
+        # had anything it sleeps in native code, the GIL released, on the
+        # bell every door rings after a push to its control queue
+        # (native.lib.Bell), and wakes for an event, for stop(), or after
+        # _CONTROL_WAIT_MS. The bell's generation, read before the last
+        # empty drain, is what the wait compares: a ring since then
+        # returns it at once. Where the loaded library is older than the
+        # bell it keeps the 2 ms poll it had. A ring does not wake it at
+        # once: the wait stays asleep _CONTROL_SETTLE_MS more, in native
+        # code, before it returns. A report comes in the same send as the
+        # data frame behind it, and handled in that very moment its
+        # ingest shares the GIL with that frame's intake, prep and launch
+        # (0.7 ms of a verdict's 3.9 on the chip's host, PERF.md section
+        # 6, PR 51). The poll, by coming 0-2 ms late, had kept most of
+        # them apart; this is its mean. What arrives while a drain is
+        # being answered waits for nothing, as before.
         #
         # The TCP doors serve single PARAM_FLOW and CONCURRENT_ACQUIRE /
         # _RELEASE frames on their data plane; those of the shm door's
@@ -1473,6 +1509,9 @@ class NativeTokenServer:
         # set aside the same way in a list of their own
         # (_answer_concurrent).
         doors = list(self._doors)
+        bell = self._bell
+        seen = 0  # the bell's generation before the last drain
+        woke = False  # this drain follows a return of the wait
         while not self._stop.is_set():
             got_any = False
             for door in doors:
@@ -1500,7 +1539,16 @@ class NativeTokenServer:
                     self._answer_params(door, params)
                 if conc:
                     self._answer_concurrent(door, conc)
-            if not got_any:
+            if woke:
+                _SM.count_control_wakeup(idle=not got_any)
+            woke = not got_any
+            if bell is not None:
+                # after a drain that found something only read the
+                # generation (what rang during it is no news to the next
+                # drain) and drain again before any sleep
+                seen = bell.wait(seen, 0 if got_any else _CONTROL_WAIT_MS,
+                                 _CONTROL_SETTLE_MS)
+            elif not got_any:
                 self._stop.wait(0.002)
 
     def _answer_params(self, door, params) -> None:

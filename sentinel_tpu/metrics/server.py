@@ -245,6 +245,18 @@ class ServerMetrics:
             "(cumulative).",
     }
 
+    # the native server's control thread (server_native._control_loop): it
+    # sleeps on the doors' bell and drains their control queues when it wakes
+    _CONTROL_COUNTERS = {
+        "control_wakeups_total":
+            "Returns of the native control thread's wait: a door rang the "
+            "bell, or the 100 ms time-out passed (every 2 ms sleep, where "
+            "the library has no bell) (cumulative).",
+        "control_idle_wakeups_total":
+            "Those after which no door had a control event queued: the "
+            "time-outs of an idle control plane (cumulative).",
+    }
+
     # the concurrency lane (DefaultTokenService.dispatch_concurrent_batch)
     _CONCURRENT_COUNTERS = {
         "concurrent_dispatch_total":
@@ -478,6 +490,8 @@ class ServerMetrics:
         self._concurrent_live = 0
         self._lane_turn_lock = threading.Lock()
         self._lane_turn = dict.fromkeys(self._LANE_TURN_COUNTERS, 0)
+        self._control_lock = threading.Lock()
+        self._control = dict.fromkeys(self._CONTROL_COUNTERS, 0)
         # stage histograms, all in milliseconds except batch_size (requests).
         # 1µs..10s covers a sub-100µs device step and a 1s cold compile alike.
         # queue_wait_ms: per queue item on the asyncio door; on the native
@@ -692,6 +706,17 @@ class ServerMetrics:
         """The control loop answered ``frames`` single PARAM_FLOW frames."""
         with self._param_lock:
             self._param_single["param_control_frames_total"] += int(frames)
+
+    def count_control_wakeup(self, idle: bool) -> None:
+        """The native control thread's wait returned; ``idle``: the drain
+        that followed found no control event on any door."""
+        with self._control_lock:
+            self._control["control_wakeups_total"] += 1
+            self._control["control_idle_wakeups_total"] += bool(idle)
+
+    def control_totals(self) -> Dict[str, int]:
+        with self._control_lock:
+            return dict(self._control)
 
     def count_concurrent_step(self, acquires: int, releases: int,
                               blocked: int, already: int, expired: int,
@@ -1397,6 +1422,7 @@ class ServerMetrics:
                 {"verdict": v, "namespace": ns, "count": c}
                 for (v, ns), c in sorted(self._verdicts.items())
             ]
+        control = self.control_totals()
         return {
             "verdicts": verdicts,
             "verdictsPerSec": self._rate.rate(),
@@ -1410,6 +1436,8 @@ class ServerMetrics:
             "paramPrepNativeTotal": self.param_prep_native_total,
             "concurrentPrepNativeTotal": self.concurrent_prep_native_total,
             "accountFoldsTotal": self.account_folds_total,
+            "controlWakeupsTotal": control["control_wakeups_total"],
+            "controlIdleWakeupsTotal": control["control_idle_wakeups_total"],
             "shedTotal": self.shed_total,
             "shedByReason": self.shed_totals(),
             "hostCopyBytesTotal": self.host_copy_bytes_total,
@@ -1495,6 +1523,7 @@ class ServerMetrics:
         out.update(self.arm_totals())
         out.update(self.concurrent_totals())
         out.update(self.lane_turn_totals())
+        out.update(self.control_totals())
         out["reply_first_total"] = self.reply_first_total
         out["prep_native_total"] = self.prep_native_total
         out["param_prep_native_total"] = self.param_prep_native_total
@@ -1905,6 +1934,8 @@ class ServerMetrics:
               if name in self._CONCURRENT_COUNTERS),
             *((name, self._LANE_TURN_COUNTERS[name], value)
               for name, value in self.lane_turn_totals().items()),
+            *((name, self._CONTROL_COUNTERS[name], value)
+              for name, value in self.control_totals().items()),
             ("reply_first_total",
              "Dispatches accounted after their reply was submitted: the "
              "native reply lane answers first and counts after "
@@ -2000,6 +2031,8 @@ class ServerMetrics:
             self._concurrent_live = 0
         with self._lane_turn_lock:
             self._lane_turn = dict.fromkeys(self._LANE_TURN_COUNTERS, 0)
+        with self._control_lock:
+            self._control = dict.fromkeys(self._CONTROL_COUNTERS, 0)
         with self._fold_lock, self._verdict_lock:
             self._pending = None  # what nobody has read goes unread
             self._verdicts.clear()

@@ -241,6 +241,22 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         lib._sn_has_concurrent_prep = True
     except AttributeError:
         lib._sn_has_concurrent_prep = False
+    # the control lane's bell (sentinel_frontdoor.cpp; sn_shm_set_bell in
+    # sentinel_shm.cpp), as flow prep
+    try:
+        U64 = ctypes.c_uint64
+        lib.sn_bell_new.argtypes, lib.sn_bell_new.restype = [], P
+        lib.sn_bell_free.argtypes, lib.sn_bell_free.restype = [P], None
+        lib.sn_bell_ring.argtypes, lib.sn_bell_ring.restype = [P], None
+        lib.sn_bell_wait.argtypes = [P, U64, I32, I32]
+        lib.sn_bell_wait.restype = U64
+        lib.sn_fd_set_bell.argtypes, lib.sn_fd_set_bell.restype = [P, P], None
+        if lib._sn_has_shm:
+            lib.sn_shm_set_bell.argtypes = [P, P]
+            lib.sn_shm_set_bell.restype = None
+        lib._sn_has_bell = True
+    except AttributeError:
+        lib._sn_has_bell = False
     return lib
 
 
@@ -461,6 +477,50 @@ def concurrent_prep(lookup, ids, counts, is_release, max_tokens: int, plan):
             f"concurrent_prep: the plan's {n_acq} acquires are not the "
             f"frame's")
     return parts
+
+
+class Bell:
+    """The control lane's bell (``sn_bell_*``, ``sentinel_frontdoor.cpp``):
+    a mutex, a condition variable of its own and a generation counter. A
+    door handed the bell (``set_bell``) rings it after every push to its
+    control queue; the server's one control thread sleeps in :meth:`wait`
+    with the GIL released. Made by :func:`control_bell`."""
+
+    def __init__(self, lib: ctypes.CDLL):
+        self._lib = lib
+        self._h = lib.sn_bell_new()
+        if not self._h:
+            raise MemoryError("sn_bell_new")
+
+    def wait(self, seen: int, timeout_ms: int, settle_ms: int = 0) -> int:
+        """Block until the generation is no longer ``seen`` or
+        ``timeout_ms`` passed; the generation found, which is the caller's
+        next ``seen``. A ring since the caller read ``seen`` returns at
+        once: no wake-up is lost between a look at the queues and the wait.
+        A wait that a ring ended stays asleep ``settle_ms`` more (still off
+        the GIL) before it returns; one that timed out does not.
+        ``timeout_ms`` 0 only reads the generation."""
+        return self._lib.sn_bell_wait(self._h, seen, timeout_ms, settle_ms)
+
+    def ring(self) -> None:
+        self._lib.sn_bell_ring(self._h)
+
+    def __del__(self):
+        # a door keeps the bell it was handed (``set_bell``), so no door's
+        # IO thread is left to ring a freed one
+        h = getattr(self, "_h", None)
+        if h:
+            self._lib.sn_bell_free(h)
+            self._h = None
+
+
+def control_bell() -> Optional[Bell]:
+    """A new :class:`Bell`. None where the library is absent or older than
+    the entry: the control thread then polls its doors."""
+    lib = load()
+    if lib is None or not lib._sn_has_bell:
+        return None
+    return Bell(lib)
 
 
 def batch_decode_req(payload: bytes):
@@ -966,6 +1026,12 @@ class Frontdoor:
     def close_conn(self, fd: int, gen: int) -> None:
         self._lib.sn_fd_close_conn(self._h, fd, gen)
 
+    def set_bell(self, bell: Bell) -> None:
+        """Ring ``bell`` after every push to this door's control queue
+        (what :meth:`next_control` pops). The door keeps the bell alive."""
+        self._lib.sn_fd_set_bell(self._h, bell._h)
+        self._bell = bell
+
     def next_control(self):
         """``None`` or ``(kind, fd, gen, payload bytes)``. ``last_control_ns``
         is then the ``time.monotonic_ns()`` at which the IO thread queued
@@ -1257,6 +1323,11 @@ class ShmDoor:
 
     def close_conn(self, fd: int, gen: int) -> None:
         self._lib.sn_shm_close_conn(self._h, fd, gen)
+
+    def set_bell(self, bell: Bell) -> None:
+        """As :meth:`Frontdoor.set_bell`."""
+        self._lib.sn_shm_set_bell(self._h, bell._h)
+        self._bell = bell
 
     def next_control(self):
         fd = ctypes.c_int32()

@@ -450,6 +450,9 @@ class TestPerfRecordSync:
         "door_out_ms",
         "door_residence_ms",
         "queue_wait_ms",
+        # the control thread's wake-ups (PR 51)
+        "control_wakeups_total",
+        "control_idle_wakeups_total",
     ])
     def test_record_names_what_the_program_snapshots(self, name):
         from sentinel_tpu.metrics.server import ServerMetrics

@@ -112,8 +112,10 @@ def test_the_cell_and_its_mix_are_what_the_issue_states():
 
 def test_the_cell_is_listed_where_its_readers_find_something():
     lists = {m["name"]: m.get("workloads") for m in _bench()["per_layer"]}
-    assert [m["name"] for m in _bench()["per_layer"]][-7:] == list(
-        NEW_READERS)
+    # appended as one run, in this order; later PRs append behind them
+    names = [m["name"] for m in _bench()["per_layer"]]
+    at = names.index(NEW_READERS[0])
+    assert names[at:at + len(NEW_READERS)] == list(NEW_READERS)
     for name in NEW_READERS:
         assert lists[name] == [CELL], name
     for name in ("param_step_roofline", "step.param_device_ms_per_dispatch",
